@@ -81,12 +81,7 @@ class TrainState(struct.PyTreeNode):
         cacheless cold start.
         """
         if jit_init is None:
-            try:
-                jit_init = bool(
-                    getattr(jax.config, "jax_compilation_cache_dir", None)
-                )
-            except Exception:  # noqa: BLE001 — config drift: keep eager
-                jit_init = False
+            jit_init = bool(jax.config.jax_compilation_cache_dir)
         if jit_init:
             variables = jax.jit(
                 lambda r, s: model.init(r, s, **(init_kwargs or {}))

@@ -122,9 +122,9 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument(
         "--xla-cache-dir", type=str, default=None,
         help="persistent XLA compilation cache dir for relaunch-to-"
-        "first-step MTTR (default <workdir>/xla_cache unless the "
-        "process already configured one; '' disables) — README "
-        "'Performance'",
+        "first-step MTTR (default <checkout>/.xla_cache; "
+        "JAX_COMPILATION_CACHE_DIR, when set, wins; '' disables) — "
+        "README 'Performance'",
     )
     p.add_argument(
         "--aot-compile", action=argparse.BooleanOptionalAction,

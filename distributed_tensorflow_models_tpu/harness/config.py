@@ -149,13 +149,15 @@ class ExperimentConfig:
     # Restart-MTTR knobs (harness/startup.py; README "Performance").
     # xla_cache_dir: persistent XLA compilation cache for the production
     # path — a supervisor relaunch deserializes the train-step program
-    # instead of recompiling it.  None = default to <workdir>/xla_cache
-    # unless the process already configured a cache (that setting wins);
-    # "" disables.  aot_compile: lower().compile() the train-step
+    # instead of recompiling it.  JAX_COMPILATION_CACHE_DIR, when set,
+    # places the cache and wins over a path here; None = the fixed
+    # <checkout>/.xla_cache; "" disables (startup.apply_compile_cache).
+    # aot_compile: lower().compile() the train-step
     # program on a background thread *while the checkpoint restore
     # runs*, so a relaunch overlaps its two dominant serial costs; the
     # executable is bit-identical to the jit path's and a batch-spec
-    # mismatch falls back to jit with only a wasted background compile.
+    # mismatch falls back to jit with only a wasted background compile
+    # (a failed compile is re-raised, not fallen back from).
     xla_cache_dir: Optional[str] = None
     aot_compile: bool = True
     # Flight-recorder / event-trace knobs (telemetry/trace.py; README

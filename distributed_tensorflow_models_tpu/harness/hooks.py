@@ -372,14 +372,13 @@ class TelemetryHook(Hook):
         self._nproc = (
             jax.process_count() if process_count is None else process_count
         )
-        try:
-            # Whole-mesh peak: the FLOPs numerator is the global SPMD
-            # program's cost, so the denominator is per-chip peak x all
-            # participating devices (bench.py's global/per-chip split).
-            peak = telemetry.peak_flops(jax.devices()[0].device_kind)
-            self._peak = peak and peak * len(jax.devices())
-        except Exception:  # noqa: BLE001 — telemetry must never crash
-            self._peak = None
+        # Whole-mesh peak: the FLOPs numerator is the global SPMD
+        # program's cost, so the denominator is per-chip peak x all
+        # participating devices (bench.py's global/per-chip split).
+        # None on the CPU; an unlisted accelerator raises here, at fit
+        # start, rather than logging mfu 0.0 for the whole run.
+        peak = telemetry.peak_flops(jax.devices()[0].device_kind)
+        self._peak = peak and peak * len(jax.devices())
         self._last: Optional[tuple[float, int, dict]] = None
         self.last_emitted: Optional[dict] = None
 

@@ -6,13 +6,12 @@ the first XLA compile of the train-step program.  Both are attackable
 without touching training semantics:
 
 - **Persistent compilation cache** (:func:`apply_compile_cache`): the
-  jax on-disk cache the test suite has used since PR 4
-  (``tests/conftest.py``) wired into the *production* path — a relaunch
-  of the same config deserializes the train-step program instead of
-  recompiling it.  ``ExperimentConfig.xla_cache_dir`` controls it:
-  ``None`` defaults to ``<workdir>/xla_cache`` (unless the process
-  already configured a cache — an explicit operator/test setting wins),
-  an explicit path is used as-is, and ``""`` disables.
+  jax on-disk cache, placed by one helper for every entry point — a
+  relaunch of the same config deserializes the train-step program
+  instead of recompiling it.  ``JAX_COMPILATION_CACHE_DIR`` places it
+  from outside; otherwise ``ExperimentConfig.xla_cache_dir`` names a
+  path, ``None`` means the fixed ``<checkout>/.xla_cache``, and ``""``
+  disables.
 - **AOT compile overlapped with restore** (:class:`AotTrainStep`): the
   train-step program is ``.lower().compile()``'d on a background thread
   *while the main thread restores the checkpoint*, against input specs
@@ -55,74 +54,68 @@ PyTree = Any
 # Persistent compilation cache
 # --------------------------------------------------------------------------
 
-# Same thresholds the test conftest uses: cache programs costing >= 0.5 s
-# to compile, and let XLA cache its internal artifacts too.
+# Cache programs costing >= 0.5 s to compile, and let XLA cache its
+# internal artifacts too.
 _MIN_COMPILE_TIME_S = 0.5
+
+# Where the cache lives when nothing outside places it: a fixed,
+# git-ignored directory at the root of the checkout, derived from this
+# package's location.  The directory is part of the cache key (XLA's own
+# caches are addressed by path), so it must not move between runs —
+# never a workdir, a temp name, a pid or a time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".xla_cache",
+)
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def configured_cache_dir() -> Optional[str]:
     """The process's currently configured jax compilation cache dir (or
     None)."""
-    try:
-        import jax
+    import jax
 
-        return getattr(jax.config, "jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 — config introspection must not raise
-        return None
+    return jax.config.jax_compilation_cache_dir
 
 
-def apply_compile_cache(
-    xla_cache_dir: Optional[str], workdir: str
-) -> Optional[str]:
-    """Resolve and apply the production compile-cache knob; returns the
-    active cache dir (None = disabled).
+def apply_compile_cache(xla_cache_dir: Optional[str] = None) -> Optional[str]:
+    """The one place the persistent compilation cache is placed; returns
+    the active cache dir (None = disabled).  ``fit``, the serving worker,
+    ``chip_smoke.py``, ``bench.py``'s children and ``tests/conftest.py``
+    all come through here.
 
-    Resolution: an explicit non-empty ``xla_cache_dir`` is applied
-    as-is; ``""`` disables the cache (even one configured earlier in the
-    process); ``None`` defaults to ``<workdir>/xla_cache`` — *unless*
-    the process already configured a cache dir (test conftest, operator
-    sitecustomize), which then stays in force: an explicit setting must
-    not be silently redirected at every ``fit``, and the test suite's
-    shared cache is exactly what keeps its many tiny fits fast.
+    Resolution: ``""`` disables the cache.  Otherwise, when
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache stays there — whoever
+    runs the program placed it, and an ``xla_cache_dir`` that disagrees
+    is ignored with a warning.  Otherwise an explicit ``xla_cache_dir``
+    is used as-is, and ``None`` means :data:`DEFAULT_CACHE_DIR`.
 
     Must run before the first trace of the run (``fit`` calls it before
     ``build_state``, whose ``model.init`` is the first compile).
-    Best-effort: cache-config knob names drift across jax versions, and
-    the cache is an optimization — never the thing that kills training.
     """
     import jax
 
     if xla_cache_dir == "":
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:  # noqa: BLE001
-            log.debug("could not disable the compilation cache", exc_info=True)
-        else:
-            log.info("persistent XLA compilation cache disabled")
+        jax.config.update("jax_compilation_cache_dir", None)
+        log.info("persistent XLA compilation cache disabled")
         return None
-    if xla_cache_dir is None:
-        existing = configured_cache_dir()
-        if existing:
-            log.debug(
-                "persistent XLA compilation cache already configured at %s; "
-                "keeping it", existing,
-            )
-            return existing
-        xla_cache_dir = os.path.join(os.path.abspath(workdir), "xla_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", xla_cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", _MIN_COMPILE_TIME_S
-        )
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:  # noqa: BLE001 — knob names drift across jax versions
+    placed = os.environ.get(CACHE_DIR_ENV)
+    if placed and xla_cache_dir and xla_cache_dir != placed:
         log.warning(
-            "could not enable the persistent XLA compilation cache at %s",
-            xla_cache_dir, exc_info=True,
+            "%s=%s is set; ignoring xla_cache_dir=%s",
+            CACHE_DIR_ENV, placed, xla_cache_dir,
         )
-        return None
-    log.info("persistent XLA compilation cache at %s", xla_cache_dir)
-    return xla_cache_dir
+    path = placed or xla_cache_dir or DEFAULT_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", _MIN_COMPILE_TIME_S
+    )
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    log.info("persistent XLA compilation cache at %s", path)
+    return path
 
 
 def cache_entry_count(cache_dir: Optional[str]) -> int:
@@ -258,9 +251,11 @@ class AotTrainStep:
     cold-start cost the overlap failed to hide, and the caller accounts
     it (plus the first dispatch) as the run's compile event.
 
-    Any failure (spec mismatch at trace time, an AOT-unsupported
-    backend) disables the handle with one warning; training proceeds on
-    the jit path unchanged.
+    Only a batch-signature mismatch falls back to the jit path.  A
+    failure of the compile itself (a trace-time error, a program the
+    device's compiler refuses) is re-raised from ``acquire`` on the main
+    thread: the jit path would compile the same program and fail the
+    same way, later and less legibly.
     """
 
     def __init__(
@@ -308,7 +303,7 @@ class AotTrainStep:
         entries_before = cache_entry_count(self._cache_dir)
         try:
             self._exe = self._fn.lower(*self._args).compile()
-        except BaseException as e:  # noqa: BLE001 — surfaced at acquire()
+        except BaseException as e:  # noqa: BLE001 — re-raised by acquire()
             self._error = e
             return
         finally:
@@ -356,18 +351,23 @@ class AotTrainStep:
                 args={"label": self._label},
             )
         if self._error is not None:
-            log.warning(
-                "AOT %s compile failed (%s); falling back to the jit path",
-                self._label, self._error,
-            )
-            self._disabled = True
-            self._error = None
-            return None, False
+            raise self._error
         if self._exe is None:  # thread never ran (start() skipped)
             self._disabled = True
             return None, False
         first, self._used = (not self._used), True
         return self._exe, first
+
+    def executable(self, sig: tuple):
+        """The compiled program if this handle serves ``sig``, else None.
+        No first-use accounting: the instrumented step reads the
+        program's cost analysis through this after its first call, by
+        which time ``acquire`` has already waited for the compile."""
+        if self._disabled or sig != self._sig:
+            return None
+        if self._thread.is_alive():
+            self._thread.join()
+        return self._exe
 
     def disable(self) -> None:
         """Stop offering the executable (the instrumented step calls this

@@ -692,6 +692,13 @@ class LMServer:
 
     def _run(self) -> None:
         try:
+            # Same cache placement as fit: the engine's two programs are
+            # the serving side's whole compile cost at (re)start.
+            from distributed_tensorflow_models_tpu.harness import (
+                startup as startuplib,
+            )
+
+            startuplib.apply_compile_cache()
             engine = self._engine_factory()
             # Adopt the engine into this server's registry unless the
             # factory attached its own — otherwise the prefill/decode

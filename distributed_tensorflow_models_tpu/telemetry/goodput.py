@@ -32,10 +32,10 @@ from typing import Optional
 
 from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
-# Peak dense bf16 FLOPs/sec per chip by device_kind prefix (public specs;
-# the same table bench.py uses — kept in both places deliberately:
-# bench.py is a self-contained subprocess-spawned script that must not
-# import the package under a wedged backend).
+# Peak dense bf16 FLOPs/sec per chip, by jax ``device_kind`` prefix — the
+# one table (bench.py imports it).  Source: Google Cloud TPU documentation,
+# per-chip specifications of each generation (v4 275, v5e 197, v5p 459,
+# v6e 918 TFLOP/s bf16).  A v5e chip reports ``device_kind`` "TPU v5 lite".
 PEAK_BF16_FLOPS = (
     ("TPU v6", 918e12),
     ("TPU v5 lite", 197e12),
@@ -47,18 +47,24 @@ PEAK_BF16_FLOPS = (
 
 
 def peak_flops(kind: Optional[str]) -> Optional[float]:
-    """Peak bf16 FLOPs/sec for a jax ``device_kind``; None when unknown
-    (CPU hosts — MFU then reports 0.0 rather than a made-up number).
-    ``DTM_PEAK_FLOPS`` overrides for unlisted accelerators."""
+    """Peak bf16 FLOPs/sec for a jax ``device_kind``.  None on the CPU
+    (``"cpu"`` or no device: MFU then reports 0.0 rather than a made-up
+    number).  An accelerator that is not in the table raises — an MFU
+    silently reading 0.0 on a chip is a wrong number, not a missing one;
+    ``DTM_PEAK_FLOPS`` supplies the peak for an unlisted accelerator."""
     env = os.environ.get("DTM_PEAK_FLOPS")
     if env:
         return float(env)
-    if not kind:
+    if not kind or kind.lower() == "cpu":
         return None
     for prefix, peak in PEAK_BF16_FLOPS:
         if kind.startswith(prefix):
             return peak
-    return None
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {kind!r}: add it to "
+        "telemetry.goodput.PEAK_BF16_FLOPS (with its source) or set "
+        "DTM_PEAK_FLOPS"
+    )
 
 
 def device_kind() -> Optional[str]:

@@ -25,7 +25,7 @@ records the measured core count) and decode+fetch-latency (each batch's
 record fetch blocks in the worker, the remote-storage regime of real TPU
 input hosts — the pool overlaps fetch with decode on any host).
 """
-# Runnable from anywhere (same idiom as recompute_mfu.py).
+# Runnable from anywhere.
 import argparse
 import glob
 import hashlib

@@ -13,7 +13,7 @@ Usage: python experiments/run_convergence.py --config ptb_small \
            --steps 2000 [--force-cpu]
 """
 
-# Runnable from anywhere (same idiom as recompute_mfu.py).
+# Runnable from anywhere.
 import os
 import sys
 

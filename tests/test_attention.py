@@ -834,9 +834,7 @@ class TestBlockwiseQChunked:
 
 
 def test_auto_impl_is_blockwise():
-    """auto == blockwise bit-for-bit (the measured end-to-end training
-    winner on every banked hardware shape — TPU_BENCH_r3.md); flash
-    stays opt-in."""
+    """auto == blockwise bit-for-bit; flash stays opt-in."""
     q, k, v = _qkv(T=256)
     a = attnlib.attention(q, k, v, causal=True, impl="auto")
     b = attnlib.attention(q, k, v, causal=True, impl="blockwise")

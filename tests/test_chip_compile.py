@@ -1,0 +1,136 @@
+"""Chip-less compiles for a described TPU v5e (2x2).
+
+The TPU compiler is installed with jax and compiles for a topology that
+is described, not attached (``on-chip-measurement`` guide, section 2):
+it refuses what the chip's compiler would refuse — a misaligned slice,
+too much VMEM, a program that does not fit — at no chip time.  These
+cases keep the main path's Pallas kernels, at real shapes, and one
+data-parallel step over four devices compiling on every later PR.
+
+Nothing runs and nothing here is a measurement.  Only the fast compiles
+are kept (about a second or two each); the whole ResNet-50 b256 step
+(~40 s) and the ``conv2d_mxu`` gradient at 56x56x64 (~18 s) stay in the
+builder's rehearsal.  The persistent cache is switched off around the
+cases: an executable compiled for a described chip is written to it but
+cannot be read back without one, and the next run would warn.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from distributed_tensorflow_models_tpu.core import mesh as meshlib
+from distributed_tensorflow_models_tpu.core import train_loop
+from distributed_tensorflow_models_tpu.core.train_state import TrainState
+from distributed_tensorflow_models_tpu.models import get_model
+from distributed_tensorflow_models_tpu.ops import attention as attnlib
+from distributed_tensorflow_models_tpu.ops import optim
+from distributed_tensorflow_models_tpu.ops.conv_mxu import conv2d_mxu
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topo
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        compilation_cache.reset_cache()
+
+
+def _flash_fwd_bwd(q, k, v):
+    def loss(q, k, v):
+        # Positional: (causal, scale, block_q, block_kv, interpret).
+        out = attnlib.flash_attention(q, k, v, True, None, None, None, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _conv_fwd(x, kernel):
+    return conv2d_mxu(x, kernel, (1, 1), "SAME", interpret=False)
+
+
+@pytest.mark.parametrize(
+    "fn,shapes,n_kernels",
+    [
+        pytest.param(
+            _flash_fwd_bwd, [(16, 512, 8, 64)] * 3, 3,
+            id="flash_fwd_bwd_b16_t512",
+        ),
+        pytest.param(
+            _flash_fwd_bwd, [(4, 2048, 8, 64)] * 3, 3,
+            id="flash_fwd_bwd_b4_t2048",
+        ),
+        # Two ResNet-50 3x3 classes (stage 1 and stage 2).
+        pytest.param(
+            _conv_fwd, [(32, 56, 56, 64), (3, 3, 64, 64)], 1,
+            id="conv2d_mxu_56x56x64",
+        ),
+        pytest.param(
+            _conv_fwd, [(32, 28, 28, 128), (3, 3, 128, 128)], 1,
+            id="conv2d_mxu_28x28x128",
+        ),
+    ],
+)
+def test_pallas_kernel_compiles_for_v5e(v5e, fn, shapes, n_kernels):
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    args = [
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in shapes
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # Mosaic compiled the kernels (interpret mode leaves no custom call).
+    assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+def test_data_parallel_step_compiles_over_four_chips(v5e):
+    """A small conv model's donated train step over a 4-device mesh of
+    the described chips: the batch is split, the parameters replicated,
+    and the gradient all-reduce is in the compiled program."""
+    mesh = meshlib.data_parallel_mesh(v5e.devices)
+    assert mesh.devices.size == 4
+    model = get_model("lenet")
+    state = TrainState.create(
+        model, optim.sgd(0.1), jax.random.key(0),
+        jnp.zeros((2, 28, 28, 1), jnp.float32), jit_init=False,
+    )
+    step = train_loop.make_train_step(
+        train_loop.classification_loss_fn(model.apply), donate=True
+    )
+    replicated = NamedSharding(mesh, P())
+    by_batch = NamedSharding(mesh, P(meshlib.AxisNames.DATA))
+
+    def spec(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    abstract_state = jax.tree.map(lambda x: spec(x, replicated), state)
+    batch = {
+        "image": jax.ShapeDtypeStruct(
+            (64, 28, 28, 1), jnp.float32, sharding=by_batch
+        ),
+        "label": jax.ShapeDtypeStruct((64,), jnp.int32, sharding=by_batch),
+    }
+    compiled = step.lower(
+        abstract_state, batch, spec(jax.random.key(0), replicated)
+    ).compile()
+    assert "all-reduce" in compiled.as_text()
+    # Donation reached the compiler: the state's bytes alias the output.
+    assert compiled.memory_analysis().alias_size_in_bytes > 0

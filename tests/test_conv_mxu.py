@@ -58,7 +58,7 @@ CASES = [
 
 # Inception-v3's oddest Pallas-routed classes (VERDICT r3 #8): the full
 # 24-class multiset was swept once in interpret mode at the true spatial
-# dims (experiments/MXU_VALIDATION_r4.md, max rel err 1.8e-6); this
+# dims (builder reading from an earlier round, max rel err 1.8e-6); this
 # curated subset pins the Mosaic-legality edges that sweep exposed —
 # prime 17x17 spatial with asymmetric 1x7/7x1 taps, channel counts with
 # no 128-multiple divisor (320, 448 -> channel-full out blocks), the

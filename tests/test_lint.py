@@ -720,7 +720,7 @@ PKG_ROOT = os.path.join(REPO_ROOT, "distributed_tensorflow_models_tpu")
 
 _PROBE_RACE_CLASS = '''
 
-class _ProbeRelay:
+class _ProbePump:
     def __init__(self):
         self._inflight = 0
         self._worker = threading.Thread(target=self._pump, daemon=True)
@@ -765,7 +765,7 @@ def test_probe_server_unguarded_thread_counter(tmp_path):
         "shared-state-race",
     )
     assert len(hits) == 1, hits
-    assert "_ProbeRelay._inflight" in hits[0].message
+    assert "_ProbePump._inflight" in hits[0].message
 
 
 def test_probe_heartbeat_without_beat_lock(tmp_path):

@@ -22,6 +22,9 @@ def native():
         pytest.skip(f"native build failed: {r.stderr[-500:]}")
     from distributed_tensorflow_models_tpu.data import native_loader
 
+    # The library is not in a checkout (it is built here): an earlier
+    # test in this process may have probed for it and cached "absent".
+    native_loader._TRIED = False
     if not native_loader.available():
         pytest.skip("native library not loadable")
     return native_loader

@@ -140,9 +140,7 @@ def test_aot_step_bit_identical_to_jit_multi(mesh8):
     )
 
 
-def test_aot_mismatch_and_failure_fall_back(mesh8, caplog):
-    import logging
-
+def test_aot_mismatch_falls_back_and_failure_propagates(mesh8):
     state, loss, batch = _tiny_setup(mesh8)
     jit_fn = train_loop.make_train_step(loss)
     rng = jax.random.key(0)
@@ -160,7 +158,8 @@ def test_aot_mismatch_and_failure_fall_back(mesh8, caplog):
     aot.disable()
     assert aot.acquire(good_sig) == (None, False)
 
-    # A trace-time failure disables the handle with one warning.
+    # A failed compile is re-raised where the step is first asked for:
+    # the jit path would only fail the same way later.
     def broken(state, batch, rng):
         raise RuntimeError("boom at trace time")
 
@@ -168,9 +167,9 @@ def test_aot_mismatch_and_failure_fall_back(mesh8, caplog):
         jax.jit(broken), (state, _spec_of(batch(0)), rng),
         registry=telemetry.MetricsRegistry(),
     ).start()
-    with caplog.at_level(logging.WARNING, logger="dtm"):
-        assert bad.acquire(good_sig) == (None, False)
-    assert "falling back to the jit path" in caplog.text
+    assert bad.acquire(wrong_sig) == (None, False)
+    with pytest.raises(RuntimeError, match="boom at trace time"):
+        bad.acquire(good_sig)
 
 
 def test_jit_init_bit_identical_to_eager(mesh8):
@@ -240,29 +239,52 @@ def test_dominant_chunk_len_mirrors_chunk_shrink_triggers():
 # --------------------------------------------------------------------------
 
 
-def test_apply_compile_cache_resolution(tmp_path):
+@pytest.mark.parametrize(
+    "case", ["env_set", "unset_default", "explicit", "disabled"]
+)
+def test_apply_compile_cache_resolution(case, tmp_path, monkeypatch):
+    """One helper places the cache: ``JAX_COMPILATION_CACHE_DIR`` wins
+    when set, else an explicit path, else the fixed in-checkout
+    directory (never a workdir or the cwd); ``""`` disables."""
     old = startuplib.configured_cache_dir()
+    placed = str(tmp_path / "placed-from-outside")
+    explicit = str(tmp_path / "cache-x")
     try:
-        # An already-configured cache (the test conftest's) wins over the
-        # workdir default — fit must not redirect the suite's shared
-        # cache at every run.
-        assert old  # conftest configured it
-        assert startuplib.apply_compile_cache(None, str(tmp_path)) == old
-        # Explicit path is applied as-is.
-        explicit = str(tmp_path / "cache-x")
-        assert startuplib.apply_compile_cache(
-            explicit, str(tmp_path)
-        ) == explicit
-        assert startuplib.configured_cache_dir() == explicit
-        # "" disables, even a previously configured cache.
-        assert startuplib.apply_compile_cache("", str(tmp_path)) is None
-        assert not startuplib.configured_cache_dir()
-        # Nothing configured + None -> the workdir default.
-        assert startuplib.apply_compile_cache(
-            None, str(tmp_path)
-        ) == str(tmp_path / "xla_cache")
+        if case == "env_set":
+            monkeypatch.setenv(startuplib.CACHE_DIR_ENV, placed)
+            # Neither the default nor a config path moves a cache that
+            # was placed from outside.
+            assert startuplib.apply_compile_cache() == placed
+            assert startuplib.apply_compile_cache(explicit) == placed
+            assert startuplib.configured_cache_dir() == placed
+            # ... but "" still switches it off.
+            assert startuplib.apply_compile_cache("") is None
+            assert startuplib.configured_cache_dir() is None
+        elif case == "unset_default":
+            monkeypatch.delenv(startuplib.CACHE_DIR_ENV, raising=False)
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            want = os.path.join(repo, ".xla_cache")
+            # The same fixed path whichever workdir a run sits in.
+            for workdir in ("run-a", "run-b"):
+                (tmp_path / workdir).mkdir()
+                monkeypatch.chdir(tmp_path / workdir)
+                assert startuplib.apply_compile_cache() == want
+                assert startuplib.configured_cache_dir() == want
+            assert not list(tmp_path.rglob("*xla_cache*"))
+        elif case == "explicit":
+            monkeypatch.delenv(startuplib.CACHE_DIR_ENV, raising=False)
+            assert startuplib.apply_compile_cache(explicit) == explicit
+            assert startuplib.configured_cache_dir() == explicit
+        else:
+            monkeypatch.delenv(startuplib.CACHE_DIR_ENV, raising=False)
+            assert old  # conftest configured it
+            assert startuplib.apply_compile_cache("") is None
+            assert startuplib.configured_cache_dir() is None
     finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+        # Back to what conftest configured, through the same helper
+        # (the one place that writes the setting).
+        monkeypatch.undo()
+        startuplib.apply_compile_cache(old or "")
 
 
 def test_cli_startup_knob_overrides():

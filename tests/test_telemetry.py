@@ -364,8 +364,12 @@ def test_mfu_scales_by_device_count():
 def test_peak_flops_lookup(monkeypatch):
     assert telemetry.peak_flops("TPU v5e") == 197e12
     assert telemetry.peak_flops("TPU v4 lite") == 275e12
+    assert telemetry.peak_flops("TPU v5 lite") == 197e12  # a v5e's kind
     assert telemetry.peak_flops("cpu") is None
     assert telemetry.peak_flops(None) is None
+    # An accelerator the table does not list is an error, not mfu 0.0.
+    with pytest.raises(ValueError, match="TPU v9"):
+        telemetry.peak_flops("TPU v9")
     monkeypatch.setenv("DTM_PEAK_FLOPS", "1e12")
     assert telemetry.peak_flops("anything") == 1e12
 
