@@ -168,9 +168,7 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
                 rngs=dict(rngs),
                 mutable=["losses"],
             )
-            nll = jnp.mean(
-                losslib.softmax_cross_entropy(logits, batch["targets"])
-            )
+            nll = jnp.mean(losslib.token_xent(logits, batch["targets"]))
         aux = sum(
             jnp.sum(leaf)
             for leaf in jax.tree_util.tree_leaves(updated.get("losses", {}))
@@ -302,6 +300,17 @@ def _jit_multi_step(
     return jax.jit(multi_step_fn, donate_argnums=(0,) if donate else ())
 
 
+def _abstract(args: PyTree) -> PyTree:
+    """Shape, dtype and sharding of every array leaf: enough to lower
+    the same program again after the buffers are gone."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+        if isinstance(x, jax.Array)
+        else x,
+        args,
+    )
+
+
 def program_flops(lowered, compiled=None) -> float:
     """GLOBAL FLOPs of one call of the ``lowered`` program, from XLA's
     cost analysis.
@@ -388,6 +397,12 @@ class InstrumentedStep:
         self._aot = aot
         self._flops_by_sig: dict = {}
         self.flops_per_step: Optional[float] = None
+        # What the calls ran, per batch signature, for the scope map
+        # (:meth:`executables`): the AOT executable itself, or the
+        # abstract arguments of a jit call (its executable is the jit
+        # cache's own and can only be had by lowering again).
+        self._aot_ran: dict = {}
+        self._jit_ran: dict = {}
 
     @staticmethod
     def _signature(batch) -> tuple:
@@ -436,6 +451,10 @@ class InstrumentedStep:
             exe, aot_first = self._aot.acquire(sig)
             if exe is not None:
                 fn, used_aot = exe, True
+                self._aot_ran[sig] = exe
+        if not used_aot and sig not in self._jit_ran:
+            # Before the call: donation deletes the state's buffers.
+            self._jit_ran[sig] = _abstract((state, batch, rng))
         try:
             out = fn(state, batch, rng)
         except TypeError:
@@ -453,6 +472,8 @@ class InstrumentedStep:
                 "back to the jit path", exc_info=True,
             )
             self._aot.disable()
+            self._aot_ran.pop(sig, None)
+            self._jit_ran[sig] = _abstract((state, batch, rng))
             out = self._fn(state, batch, rng)
         dt = time.perf_counter() - t0
         compiled = aot_first or (
@@ -485,6 +506,20 @@ class InstrumentedStep:
             flops = self._record_flops(sig, lowered)
         if flops:
             self._registry.counter(telemetry.FLOPS_TOTAL).inc(flops * steps)
+        return out
+
+    def executables(self) -> list:
+        """The compiled programs the calls so far ran, one per batch
+        signature: the AOT executable where it served the signature,
+        else the jit program lowered and compiled again from the call's
+        abstract arguments (a read of the persistent cache for a program
+        worth caching; the tracing and lowering are paid in full).  For
+        reading after the run (``fit`` writes the scope map from their
+        text under ``cfg.trace_export``), never on the step path."""
+        out = list(self._aot_ran.values())
+        lower = getattr(self._fn, "lower", None)
+        if lower is not None:
+            out += [lower(*args).compile() for args in self._jit_ran.values()]
         return out
 
     def __call__(self, state, batch, rng):
@@ -585,6 +620,14 @@ def per_step_rngs(
     }
 
 
+# ``jax.named_scope`` of everything after the gradients (gradient norm,
+# clipping, ``tx.update``, ``apply_updates``, EMA): a path element of
+# every such instruction's ``op_name`` in the compiled step, which
+# ``step_scopes_p<i>.json`` carries to the device trace (PERF.md section 3).
+OPTIMIZER_SCOPE = "optimizer"
+
+
+@jax.named_scope(OPTIMIZER_SCOPE)
 def apply_gradients(state: TrainState, grads: PyTree, aux: dict) -> TrainState:
     """Optimizer update + state advance from one grad computation's output.
 
@@ -628,7 +671,8 @@ def make_train_step_fn(
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         (_, aux), grads = grad_fn(state.params, state, batch, rngs)
         metrics = dict(aux.get("metrics", {}))
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            metrics["grad_norm"] = optax.global_norm(grads)
         return apply_gradients(state, grads, aux), metrics
 
     return step_fn

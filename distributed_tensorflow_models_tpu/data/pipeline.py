@@ -29,7 +29,11 @@ Telemetry: all stages record into an injectable
 ``pipeline/host_queue_depth`` + ``pipeline/producer_wait`` from the host
 producer, ``pipeline/worker_busy/<i>`` per-worker utilization +
 ``pipeline/reassembly_wait`` from the pool, ``pipeline/prefetch_fill`` +
-``pipeline/prefetch_depth`` from the device stage.  High producer wait =
+``pipeline/prefetch_depth`` from the device stage.  Those are the stages'
+*waits*; their two pieces of *work* are timed once per batch:
+``pipeline/assemble`` (the dataset producing a batch, in the serial
+producer or in whichever pool worker ran it) and ``pipeline/shard`` (the
+host-to-device placement, with ``pipeline/bytes``).  High producer wait =
 consumer-bound (healthy); high prefetch-fill p95 = the host stream is the
 bottleneck — then worker_busy vs reassembly_wait splits "pool too small /
 decode-bound" from "serial cursor-bound" (README "Performance").
@@ -49,18 +53,20 @@ PyTree = Any
 
 log = logging.getLogger("dtm")
 
-# Pipeline stage waits below this duration are not traced (they still
-# land in the timers): the tracer's ring exists to hold *stalls* for the
-# flight recorder / fleet timeline, and a healthy pipeline's thousands of
-# sub-millisecond waits would evict exactly the events a post-mortem
-# needs.
-_TRACE_STALL_MIN_S = 1e-3
+# Every stage times its waits and its work with
+# ``MetricsRegistry.record_since``: always into the timer, into the
+# tracer's ring only from a millisecond up.  The ring exists to hold
+# *stalls* for the flight recorder / fleet timeline, and a healthy
+# pipeline's thousands of sub-millisecond records would evict exactly
+# the events a post-mortem needs.
 
 
-def _trace_stall(reg, name: str, dur_s: float, t0_mono: float) -> None:
-    tr = reg.trace
-    if tr.enabled and dur_s >= _TRACE_STALL_MIN_S:
-        tr.complete(name, dur_s, ts_mono=t0_mono)
+def _batch_bytes(batch: PyTree) -> int:
+    import jax
+
+    return sum(
+        getattr(x, "nbytes", 0) for x in jax.tree_util.tree_leaves(batch)
+    )
 
 
 class _Stop:
@@ -217,7 +223,16 @@ class HostPipeline:
     def _run(self) -> None:
         reg = self._registry
         try:
-            for batch in self._dataset:
+            batches = iter(self._dataset)
+            while True:
+                # The dataset's own work for one batch (gather, decode,
+                # augment): what a pool worker's ``assemble`` does.
+                t0 = time.perf_counter()
+                try:
+                    batch = next(batches)
+                except StopIteration:
+                    break
+                reg.record_since(telemetry.ASSEMBLE, t0)
                 state = (
                     self._dataset.get_state()
                     if hasattr(self._dataset, "get_state")
@@ -229,9 +244,7 @@ class HostPipeline:
                 delivered = self._put_stop_aware(
                     self._buffer, (batch, state)
                 )
-                dt = time.perf_counter() - t0
-                reg.timer(telemetry.PRODUCER_WAIT).record(dt)
-                _trace_stall(reg, telemetry.PRODUCER_WAIT, dt, t0)
+                reg.record_since(telemetry.PRODUCER_WAIT, t0)
                 reg.gauge(telemetry.HOST_QUEUE_DEPTH).set(
                     self._buffer.qsize()
                 )
@@ -298,6 +311,7 @@ class HostPipeline:
             t0 = time.perf_counter()
             try:
                 payload = self._dataset.assemble(work)
+                reg.record_since(telemetry.ASSEMBLE, t0)
             except BaseException as e:
                 payload = _Failure(e)
             now = time.perf_counter()
@@ -334,9 +348,7 @@ class HostPipeline:
                     except queue.Empty:
                         continue
                     pending[idx] = (payload, state)
-                dt = time.perf_counter() - t0
-                reg.timer(telemetry.REASSEMBLY_WAIT).record(dt)
-                _trace_stall(reg, telemetry.REASSEMBLY_WAIT, dt, t0)
+                reg.record_since(telemetry.REASSEMBLY_WAIT, t0)
                 payload, state = pending.pop(next_idx)
                 next_idx += 1
                 if isinstance(payload, _Failure):
@@ -350,9 +362,7 @@ class HostPipeline:
                 delivered = self._put_stop_aware(
                     self._buffer, (payload, state)
                 )
-                dt = time.perf_counter() - t0
-                reg.timer(telemetry.PRODUCER_WAIT).record(dt)
-                _trace_stall(reg, telemetry.PRODUCER_WAIT, dt, t0)
+                reg.record_since(telemetry.PRODUCER_WAIT, t0)
                 reg.gauge(telemetry.HOST_QUEUE_DEPTH).set(
                     self._buffer.qsize()
                 )
@@ -530,15 +540,19 @@ class DevicePrefetcher:
                 )
                 self._pending_error = e
                 return
-            dt = time.perf_counter() - t0
-            reg.timer(telemetry.PREFETCH_FILL).record(dt)
-            _trace_stall(reg, telemetry.PREFETCH_FILL, dt, t0)
+            reg.record_since(telemetry.PREFETCH_FILL, t0)
             state = (
                 self._source.get_state()
                 if hasattr(self._source, "get_state")
                 else None
             )
-            self._buf.append((self._shard(self._mesh, batch), state))
+            # The transfer: host batch to sharded device arrays.
+            nbytes = _batch_bytes(batch)
+            t0 = time.perf_counter()
+            placed = self._shard(self._mesh, batch)
+            reg.record_since(telemetry.SHARD, t0, {"bytes": nbytes})
+            reg.counter(telemetry.PIPELINE_BYTES).inc(nbytes)
+            self._buf.append((placed, state))
             reg.gauge(telemetry.PREFETCH_DEPTH).set(len(self._buf))
 
     def __iter__(self) -> Iterator[PyTree]:

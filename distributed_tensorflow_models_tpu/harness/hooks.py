@@ -334,6 +334,11 @@ class TelemetryHook(Hook):
 
     - ``step_time_s``    — mean full-iteration wall time over the interval
     - ``data_wait_s``    — mean per-step time blocked on the input pipeline
+    - ``assemble_s`` / ``shard_s`` — the input path's work, mean per
+      BATCH over the interval: the dataset producing one batch
+      (``pipeline/assemble``; with N pool workers it is host work per
+      batch, not wall) and its host-to-device placement
+      (``pipeline/shard``).  Always the two together.
     - ``dispatch_s``     — mean per-step host dispatch time
     - ``steps_per_sec``  — interval throughput
     - ``stall_fraction`` — data-wait share of interval wall time
@@ -419,6 +424,8 @@ class TelemetryHook(Hook):
         out = {
             "step_time_s": mean(telemetry.STEP_TIME),
             "data_wait_s": data_wait / max(d_steps, 1),
+            "assemble_s": mean(telemetry.ASSEMBLE),
+            "shard_s": mean(telemetry.SHARD),
             "dispatch_s": mean(telemetry.DISPATCH),
             "steps_per_sec": sps,
             "stall_fraction": stall_frac,
@@ -653,36 +660,6 @@ class FaultInjectionHook(Hook):
         if step == self._step and not self._fired:
             self._fired = True
             raise self._exc_factory()
-
-
-class ProfilerHook(Hook):
-    """Capture an XLA/TPU trace for steps [start, stop) into
-    ``<workdir>/profile`` — the Timeline/FULL_TRACE replacement (SURVEY.md
-    §5.1; TF client/timeline.py:410 → ``jax.profiler``)."""
-
-    def __init__(self, workdir: str, start_step: int, stop_step: int):
-        self._dir = os.path.join(workdir, "profile")
-        self._start = start_step
-        self._stop = stop_step
-        self._active = False
-
-    def wants_step(self, step):
-        return (not self._active and step == self._start) or (
-            self._active and step >= self._stop
-        )
-
-    def after_step(self, state, metrics, step):
-        if step == self._start and not self._active:
-            jax.profiler.start_trace(self._dir)
-            self._active = True
-        elif step >= self._stop and self._active:
-            jax.profiler.stop_trace()
-            self._active = False
-
-    def end(self, state):
-        if self._active:
-            jax.profiler.stop_trace()
-            self._active = False
 
 
 def run_hooks_after_step(hooks: Sequence[Hook], state, metrics, step) -> bool:

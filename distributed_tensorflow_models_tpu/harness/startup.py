@@ -24,8 +24,9 @@ without touching training semantics:
   jit call otherwise — a wrong guess costs a wasted background compile,
   never a wrong program.
 
-Telemetry: the thread stamps ``startup/aot_compile_s`` (full compile
-duration — mostly hidden behind the restore); only the *non-overlapped
+Telemetry: the thread stamps ``startup/aot_compile_s`` (full
+``lower().compile()`` duration — mostly hidden behind the restore) and
+``startup/aot_lower_s`` (its tracing-and-lowering part); only the *non-overlapped
 remainder* the first step actually blocked on lands in the
 ``train/compile`` timer (the first AOT use is accounted as the run's
 compile event, mirroring how a persistent-cache hit still records a
@@ -114,6 +115,23 @@ def apply_compile_cache(xla_cache_dir: Optional[str] = None) -> Optional[str]:
         "jax_persistent_cache_min_compile_time_secs", _MIN_COMPILE_TIME_S
     )
     jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    # jax leaves instruction metadata (op_name: module paths, named
+    # scopes) out of the cache key by default, so an executable cached
+    # before a scope was renamed would be served afterwards with the old
+    # names in its text — and the scope map (telemetry/scopes.py) would
+    # carry them into the trace's split.  With the metadata in the key a
+    # renamed scope is another program: it compiles once, like any
+    # change to the step.  Metadata also holds source locations, by
+    # default the whole Python traceback of every operation, callers
+    # included: the same step lowered from another call site (the AOT
+    # thread, the jit call, the FLOP count's and the scope map's
+    # lowerings) would then be another key and compile again.  One frame
+    # per location (the line that made the operation; the name stack
+    # that op_name is built from stays whole) keeps the key a function
+    # of the program's own code; an edit that moves traced lines
+    # compiles once.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     log.info("persistent XLA compilation cache at %s", path)
     return path
 
@@ -302,7 +320,17 @@ class AotTrainStep:
         t0 = time.perf_counter()
         entries_before = cache_entry_count(self._cache_dir)
         try:
-            self._exe = self._fn.lower(*self._args).compile()
+            lowered = self._fn.lower(*self._args)
+            # Tracing and lowering apart from the compile (or cache
+            # read) that follows: the part of a warm start no cache
+            # shortens.
+            dt_lower = time.perf_counter() - t0
+            self._registry.gauge(telemetry.STARTUP_AOT_LOWER).set(dt_lower)
+            self._registry.trace.complete(
+                "startup/aot_lower", dt_lower, ts_mono=t0,
+                args={"label": self._label},
+            )
+            self._exe = lowered.compile()
         except BaseException as e:  # noqa: BLE001 — re-raised by acquire()
             self._error = e
             return

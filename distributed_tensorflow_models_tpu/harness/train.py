@@ -41,6 +41,7 @@ from distributed_tensorflow_models_tpu.harness.config import (
     ExperimentConfig,
 )
 from distributed_tensorflow_models_tpu.models import get_model
+from distributed_tensorflow_models_tpu.telemetry import scopes as scopelib
 
 log = logging.getLogger("dtm")
 
@@ -1072,9 +1073,13 @@ def fit(
                     step = start + 1
                     steps_run += 1
                     registry.counter(telemetry.HOOK_WALKS).inc()
-                    ok = hooklib.run_hooks_after_step(
-                        all_hooks, state, metrics, step
-                    )
+                    t_hooks = time.perf_counter()
+                    try:
+                        ok = hooklib.run_hooks_after_step(
+                            all_hooks, state, metrics, step
+                        )
+                    finally:
+                        registry.record_since(telemetry.HOOKS, t_hooks)
                 else:
                     k_req = _chunk_len(start, cfg, all_hooks)
                     if pending_skips and pending_skips[0][0] > start:
@@ -1111,10 +1116,14 @@ def fit(
                     # object when the last row is walked (final_metrics
                     # parity with the unfused loop).
                     metrics = hooklib.LazyMetricRow(rows, k - 1, start + 1)
-                    ok = hooklib.run_hooks_after_chunk(
-                        all_hooks, state, rows, start, k,
-                        registry=registry, final_row=metrics,
-                    )
+                    t_hooks = time.perf_counter()
+                    try:
+                        ok = hooklib.run_hooks_after_chunk(
+                            all_hooks, state, rows, start, k,
+                            registry=registry, final_row=metrics,
+                        )
+                    finally:
+                        registry.record_since(telemetry.HOOKS, t_hooks)
             except FloatingPointError:
                 # The NaN guard's divergence signal.  Policy "abort"
                 # (default) keeps the reference behavior: propagate.
@@ -1144,7 +1153,8 @@ def fit(
                 _dump_flight("rollback")
                 continue
             if tracer.enabled:
-                # One complete event per chunk (dispatch + hook walk):
+                # One complete event per chunk (train/data_wait +
+                # train/dispatch + train/hooks + a remainder):
                 # the step-progress series fleet_report's skew/straggler
                 # attribution is computed from.
                 tracer.complete(
@@ -1194,7 +1204,7 @@ def fit(
         # abort hooks' checkpoint spans included) and the trace export /
         # trace gauges land before the goodput report snapshots them.
         _final_dump("crash")
-        _export_trace(workdir, registry, cfg)
+        _export_trace(workdir, registry, cfg, step_fn)
         _write_telemetry_report(workdir, registry, t_run0, steps_run)
         raise
     else:
@@ -1221,7 +1231,7 @@ def fit(
         tracer.instant(
             "fit/end", {"steps_run": steps_run, "preempted": preempted}
         )
-        _export_trace(workdir, registry, cfg)
+        _export_trace(workdir, registry, cfg, step_fn)
         _write_telemetry_report(workdir, registry, t_run0, steps_run)
         if chaos is not None and not preempted:
             # A drill whose fault never injected must not exit 0 looking
@@ -1272,13 +1282,17 @@ def _unwire_chaos_forensics(chaos) -> None:
 
 
 def _export_trace(
-    workdir: str, registry: telemetry.MetricsRegistry, cfg
+    workdir: str, registry: telemetry.MetricsRegistry, cfg, step_fn=None
 ) -> None:
     """Per-process, best-effort: stamp the ``trace/*`` gauges (so the
     goodput report's snapshot says how far the ring reached and how much
     it dropped) and — under ``cfg.trace_export`` — write the
-    Chrome-trace JSON ``scripts/fleet_report.py`` merges across hosts.
-    Runs on BOTH exit paths, before the telemetry report snapshots."""
+    Chrome-trace JSON ``scripts/fleet_report.py`` merges across hosts
+    and, beside it, the scope map of the step programs the loop ran
+    (``step_scopes_p<i>.json``, telemetry/scopes.py): what a reader of a
+    device trace needs to name the trace's instructions.  Runs on BOTH
+    exit paths, after the loop and before the telemetry report
+    snapshots."""
     tracer = registry.trace
     if not tracer.enabled:
         return
@@ -1287,6 +1301,13 @@ def _export_trace(
         registry.gauge(telemetry.TRACE_DROPPED).set(float(tracer.dropped))
         if cfg.trace_export:
             os.makedirs(workdir, exist_ok=True)
+            if step_fn is not None:
+                cost = scopelib.write_step_scopes(
+                    scopelib.step_scopes_path(workdir, tracer.process_index),
+                    step_fn.executables,
+                )
+                if cost is not None:
+                    tracer.instant("fit/step_scopes", cost)
             tracer.dump_chrome(
                 telemetry.chrome_trace_path(workdir, tracer.process_index)
             )

@@ -38,6 +38,11 @@ from jax import lax
 
 NEG_INF = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked rows
 
+# ``jax.named_scope`` of the attention core (:func:`attention`): a path
+# element of every instruction's ``op_name`` in the compiled step, which
+# ``step_scopes_p<i>.json`` carries to the device trace (PERF.md section 3).
+ATTENTION_CORE_SCOPE = "attention_core"
+
 
 def _scale(q, scale: Optional[float]) -> float:
     return scale if scale is not None else q.shape[-1] ** -0.5
@@ -1291,6 +1296,10 @@ def _flash_chunk_bwd(
 flash_attention_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 
 
+# Scope, not module: the core is a function, so flax names no part of it.
+# Every route (reference, blockwise, flash) sits under the one name; the
+# q/k/v/out projections stay outside.
+@jax.named_scope(ATTENTION_CORE_SCOPE)
 def attention(
     q: jax.Array,
     k: jax.Array,

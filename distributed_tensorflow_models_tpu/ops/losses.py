@@ -17,6 +17,13 @@ import jax.numpy as jnp
 
 PyTree = Any
 
+# ``jax.named_scope`` of the LM head's projection + cross entropy (fused:
+# :func:`chunked_unembed_xent`; plain: :func:`token_xent` over the model's
+# own logits): a path element of every instruction's ``op_name`` in the
+# compiled step, which ``step_scopes_p<i>.json`` carries to the device
+# trace (PERF.md section 3).
+UNEMBED_LOSS_SCOPE = "unembed_loss"
+
 
 def resolve_unembed_chunk(default: int = 2048) -> int:
     """Trace-time DTM_UNEMBED_CHUNK resolution (the DTM_CONV_IMPL
@@ -62,6 +69,14 @@ def softmax_cross_entropy(
     return -jnp.sum(onehot * log_probs, axis=-1)
 
 
+@jax.named_scope(UNEMBED_LOSS_SCOPE)
+def token_xent(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Per-token NLL of an LM's logits: :func:`softmax_cross_entropy`
+    under the ``unembed_loss`` scope, so the plain (two-stage) head's
+    loss is named in the compiled step like the fused one's."""
+    return softmax_cross_entropy(logits, targets)
+
+
 def mean_softmax_cross_entropy(
     logits: jax.Array,
     labels: jax.Array,
@@ -105,6 +120,7 @@ def l2_weight_decay(
     return scale * total
 
 
+@jax.named_scope(UNEMBED_LOSS_SCOPE)
 def chunked_unembed_xent(
     hidden: jax.Array,
     kernel: jax.Array,

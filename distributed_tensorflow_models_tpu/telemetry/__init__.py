@@ -38,6 +38,7 @@ durations, ``harness/hooks.py::TelemetryHook`` snapshots everything into
 """
 
 from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
+    ASSEMBLE,
     CHAOS_ARMED_UNFIRED,
     CKPT_FENCE,
     CKPT_RESIZE_RESTORES,
@@ -54,16 +55,20 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     FLEET_STEP_LAG,
     FLOPS_PER_STEP,
     FLOPS_TOTAL,
+    HOOKS,
     HOOK_WALKS,
     HOST_QUEUE_DEPTH,
+    PIPELINE_BYTES,
     PREFETCH_DEPTH,
     PREFETCH_FILL,
     PRODUCER_WAIT,
     REASSEMBLY_WAIT,
     RESTARTS,
     ROLLBACKS,
+    SHARD,
     SKIPPED_BATCHES,
     STARTUP_AOT_COMPILE,
+    STARTUP_AOT_LOWER,
     STARTUP_FIRST_STEP,
     STARTUP_RESTORE,
     STEP_TIME,

@@ -23,6 +23,7 @@ can never drift apart on spelling.
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from contextlib import contextmanager
@@ -40,6 +41,10 @@ STEP_TIME = "train/step_time"  # timer: full iteration wall time
 # walks/steps is the direct measure of the host overhead steps_per_loop
 # amortises (tier-1 micro-guard asserts the ≥K-fold drop).
 HOOK_WALKS = "train/hook_walks"
+# Timer: the hook walk of one loop iteration (run_hooks_after_step /
+# run_hooks_after_chunk), so that train/chunk = train/data_wait +
+# train/dispatch + train/hooks + a remainder, all on the loop's thread.
+HOOKS = "train/hooks"
 COMPILE = "train/compile"  # timer: one record per XLA compile event
 FLOPS_PER_STEP = "train/flops_per_step"  # gauge: XLA cost-analysis FLOPs
 FLOPS_TOTAL = "train/flops_total"  # counter: FLOPs retired across all steps
@@ -47,6 +52,15 @@ HOST_QUEUE_DEPTH = "pipeline/host_queue_depth"  # gauge
 PRODUCER_WAIT = "pipeline/producer_wait"  # timer: producer blocked on full buffer
 PREFETCH_FILL = "pipeline/prefetch_fill"  # timer: DevicePrefetcher upstream fetch
 PREFETCH_DEPTH = "pipeline/prefetch_depth"  # gauge
+# The input path's two pieces of WORK (the timers above are its waits):
+# ASSEMBLE is the dataset's own production of one batch — ``next()`` in
+# the serial producer, ``assemble(work)`` in each pool worker, so with N
+# workers it is host work per batch, not wall; SHARD is
+# DevicePrefetcher's host-to-device placement of one batch, whose size
+# PIPELINE_BYTES accumulates.  One record per batch each.
+ASSEMBLE = "pipeline/assemble"  # timer
+SHARD = "pipeline/shard"  # timer
+PIPELINE_BYTES = "pipeline/bytes"  # counter: bytes placed on the mesh
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling
@@ -85,6 +99,9 @@ CKPT_RESIZE_RESTORES = "checkpoint/resize_restores"  # counter
 # supervisor's relaunch-to-first-step MTTR is their fleet-side reading.
 STARTUP_RESTORE = "startup/restore_s"  # gauge
 STARTUP_AOT_COMPILE = "startup/aot_compile_s"  # gauge
+# The tracing-and-lowering part of STARTUP_AOT_COMPILE (the rest is the
+# compile, or the read of the persistent cache).
+STARTUP_AOT_LOWER = "startup/aot_lower_s"  # gauge
 STARTUP_FIRST_STEP = "startup/time_to_first_step_s"  # gauge
 # Resilience (harness/train.py + resilience/).  RESTARTS counts
 # recoverable_fit restore-retrain cycles (seeded into each attempt's fresh
@@ -278,6 +295,11 @@ SERVE_VERSION_TPOT = "serve/version/tpot_s"  # timer family: /<vid>
 SERVE_VERSION_ACCEPTANCE = "serve/version/acceptance_rate"  # timer: /<vid>
 
 
+# :meth:`MetricsRegistry.record_since` mirrors a record into the event
+# ring from this duration up.
+TRACE_MIN_S = 1e-3
+
+
 class Counter:
     """Monotonic accumulator (events, seconds-of-X)."""
 
@@ -313,25 +335,22 @@ class Timer:
 
     RESERVOIR = 512
 
-    __slots__ = ("count", "total", "max", "_samples", "_idx")
+    __slots__ = ("count", "total", "max", "_samples")
 
     def __init__(self):
         self.count = 0
         self.total = 0.0
         self.max = 0.0
-        self._samples: list[float] = []
-        self._idx = 0
+        self._samples: collections.deque = collections.deque(
+            maxlen=self.RESERVOIR
+        )
 
     def record(self, seconds: float) -> None:
         self.count += 1
         self.total += seconds
         if seconds > self.max:
             self.max = seconds
-        if len(self._samples) < self.RESERVOIR:
-            self._samples.append(seconds)
-        else:
-            self._samples[self._idx] = seconds
-            self._idx = (self._idx + 1) % self.RESERVOIR
+        self._samples.append(seconds)
 
     def percentiles(self, *qs: float) -> tuple[float, ...]:
         """Nearest-rank percentiles over the reservoir (0.0 when empty)."""
@@ -397,6 +416,21 @@ class MetricsRegistry:
             self.timer(name).record(dt)
             if self.trace.enabled:
                 self.trace.complete(name, dt, ts_mono=t0)
+
+    def record_since(
+        self, name: str, t0_mono: float, args: dict | None = None
+    ) -> None:
+        """Time a piece of work that began at ``t0_mono`` (a
+        ``perf_counter`` reading) into ``timer(name)``; from
+        :data:`TRACE_MIN_S` up it also lands in the event ring, with
+        ``args``.  For per-batch and per-step work where a ``with``
+        does not fit and where thousands of sub-millisecond records
+        would evict from the ring exactly the slow ones a post-mortem
+        (or the naming of a device idle gap) needs."""
+        dt = time.perf_counter() - t0_mono
+        (self._timers.get(name) or self.timer(name)).record(dt)
+        if dt >= TRACE_MIN_S and self.trace.enabled:
+            self.trace.complete(name, dt, ts_mono=t0_mono, args=args)
 
     def snapshot(self) -> dict[str, float]:
         """Flat ``{name: float}`` view of everything recorded so far.

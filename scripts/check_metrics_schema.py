@@ -38,6 +38,10 @@ Checks, per line:
   ``startup/time_to_first_step_s`` — README "Performance", restart
   MTTR): injected as a full set by TelemetryHook, each non-negative;
 
+- input-work keys (``assemble_s``, ``shard_s`` — the per-batch means
+  of ``pipeline/assemble`` and ``pipeline/shard``): injected together,
+  non-negative seconds;
+
 - tracer accounting (``trace/*`` — ``trace/events``, ``trace/dropped``
   in telemetry.json snapshots): any present value must be a
   non-negative number;
@@ -161,6 +165,13 @@ STARTUP_KEYS = (
 )
 
 
+# The input path's work per batch (``pipeline/assemble`` and
+# ``pipeline/shard`` interval means) TelemetryHook injects together
+# beside ``data_wait_s``: non-negative seconds, and a partial set on a
+# row is a writer bug.
+INPUT_WORK_KEYS = ("assemble_s", "shard_s")
+
+
 def _is_number(v) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
 
@@ -259,6 +270,19 @@ def check_lines(
             if _is_number(value) and value < 0:
                 errors.append(
                     f"line {i}: startup gauge {key!r} is negative: {value!r}"
+                )
+        work_present = [k for k in INPUT_WORK_KEYS if k in row]
+        if work_present and len(work_present) != len(INPUT_WORK_KEYS):
+            errors.append(
+                f"line {i}: partial input-work key set {work_present} "
+                f"(expected all of {list(INPUT_WORK_KEYS)} together)"
+            )
+        for key in work_present:
+            value = row[key]
+            if _is_number(value) and value < 0:
+                errors.append(
+                    f"line {i}: input-work timer {key!r} is negative: "
+                    f"{value!r}"
                 )
         for key, value in row.items():
             if not (_is_number(value) and value < 0):
