@@ -83,6 +83,17 @@ class ArrayDataset:
 
     ``transform(image, rng) -> image`` runs per sample with an rng derived
     from ``(seed, epoch, sample_position)`` — deterministic augmentation.
+    It is handed a row of the source array itself and must not write to it.
+
+    Batch arrays are recycled, not reallocated: :meth:`assemble` writes
+    into an array that :meth:`recycle` took back when one of the right
+    ``(key, shape, dtype)`` is free, and into a fresh one when none is
+    (a fresh 154 MB ImageNet batch is some 37,600 first-touch page
+    faults; the copy into an array that already exists is a twentieth of
+    that).  The free lists start empty and hold only what came back, so
+    a consumer that never calls :meth:`recycle` gets fresh arrays for
+    ever, none of them rewritten under it.  Only where the bytes land
+    changes: the stream is bit-identical either way.
 
     Multi-host (SURVEY.md §3.4 — each reference worker feeds its own input
     stream): ``batch_size`` stays the *global* batch; with
@@ -122,6 +133,8 @@ class ArrayDataset:
         self._local_lo = process_index * self._local_batch
         self._shuffle = shuffle
         self._seed = seed
+        if transform is not None and transform_key not in arrays:
+            raise KeyError(transform_key)
         self._transform = transform
         self._transform_key = transform_key
         if not drop_remainder and self._n % batch_size:
@@ -135,6 +148,13 @@ class ArrayDataset:
         # and old epochs are pruned to bound memory.
         self._perm_lock = threading.Lock()
         self._perm_cache: dict[int, np.ndarray] = {}
+        # Free batch arrays by leaf signature ``(key, shape, dtype)``.
+        # An entry exists once assemble() has asked for that signature;
+        # recycle() fills it, assemble() pops from it (pool workers call
+        # both sides concurrently, hence the lock; nothing ever waits).
+        self._free_lock = threading.Lock()
+        self._free: dict[tuple, list[np.ndarray]] = {}
+        self._last = threading.local()
 
     @property
     def batches_per_epoch(self) -> int:
@@ -171,26 +191,97 @@ class ArrayDataset:
         self._batch_idx += 1
         return work
 
+    def _take(self, sigs: list[tuple]) -> list[np.ndarray]:
+        """One array to write into per leaf signature: the recycled ones
+        if a whole set is free, else fresh ones where none is (noted for
+        :meth:`last_assemble_reused`).  One step under the lock, as
+        :meth:`recycle` is, so that a batch given back feeds one later
+        batch whole.  Never waits."""
+        with self._free_lock:
+            frees = [self._free.setdefault(sig, []) for sig in sigs]
+            bufs = [free.pop() if free else None for free in frees]
+        self._last.reused = all(buf is not None for buf in bufs)
+        return [
+            np.empty(shape, dtype) if buf is None else buf
+            for buf, (_, shape, dtype) in zip(bufs, sigs)
+        ]
+
     def assemble(self, work: tuple[int, int]) -> dict[str, np.ndarray]:
         """Pure position → batch (thread-safe; what a pool worker runs).
 
         Augmentation rngs are keyed by ``(seed, epoch, global sample
         position)`` exactly as the serial path always did, so the batch
         depends only on the work item — never on which worker assembles
-        it or in what order."""
+        it or in what order, nor on whether its arrays are recycled."""
         epoch, batch_idx = work
         perm = self._perm_for(epoch)
         lo = batch_idx * self._batch_size + self._local_lo
         idx = perm[lo : lo + self._local_batch]
-        batch = {k: v[idx] for k, v in self._arrays.items()}
+        sigs = {
+            k: (k, idx.shape + v.shape[1:], v.dtype)
+            for k, v in self._arrays.items()
+        }
+        transformed = {}
         if self._transform is not None:
+            # Transformed rows first: their shape and dtype are the
+            # transform's to choose, and the buffers are taken in one go.
             key = self._transform_key
-            out = []
-            for j, img in enumerate(batch[key]):
-                rng = np.random.default_rng((self._seed, epoch, lo + j))
-                out.append(self._transform(img, rng))
-            batch[key] = np.stack(out)
+            source = self._arrays[key]
+            rows = transformed[key] = [
+                self._transform(
+                    source[i],
+                    np.random.default_rng((self._seed, epoch, lo + j)),
+                )
+                for j, i in enumerate(idx)
+            ]
+            sigs[key] = (
+                key,
+                idx.shape + rows[0].shape,
+                np.result_type(*{r.dtype for r in rows}),
+            )
+        batch = dict(zip(sigs, self._take(list(sigs.values()))))
+        for k, buf in batch.items():
+            if k in transformed:
+                np.stack(transformed[k], out=buf)
+            else:
+                # mode="clip" writes straight into ``out`` (the default
+                # "raise" gathers into a temporary and copies); idx is a
+                # slice of a permutation, always in range.
+                np.take(self._arrays[k], idx, axis=0, out=buf, mode="clip")
         return batch
+
+    def last_assemble_reused(self) -> bool:
+        """Whether the calling thread's last :meth:`assemble` wrote every
+        leaf into a recycled array (for the pipeline's reuse counters)."""
+        return getattr(self._last, "reused", False)
+
+    def recycle(self, batch: dict[str, np.ndarray], limit: int) -> None:
+        """Take back the arrays of a batch :meth:`assemble` made, to be
+        overwritten by a later one.
+
+        The caller vouches that nothing reads them any more: not itself,
+        and no device array that was placed from them and may still be
+        copying out of, or aliasing, their memory.  At most ``limit``
+        free arrays are kept per signature; a leaf beyond that, or of a
+        signature assemble() never asked for, or one that is not a whole
+        writeable array of its own, is left to the garbage collector."""
+        whole = [
+            (k, arr)
+            for k, arr in batch.items()
+            if isinstance(arr, np.ndarray)
+            and arr.flags.owndata
+            and arr.flags.c_contiguous
+            and arr.flags.writeable
+        ]
+        with self._free_lock:
+            for k, arr in whole:
+                free = self._free.get((k, arr.shape, arr.dtype))
+                if (
+                    free is not None
+                    and len(free) < limit
+                    and not any(arr is a for a in free)
+                ):
+                    free.append(arr)
 
     def __iter__(self) -> Iterator[dict[str, np.ndarray]]:
         return iterate_via_work(self)

@@ -24,6 +24,21 @@ Unlike the reference's queues, the pipeline is *checkpointable*: each batch
 carries the producer state that follows it, so `state` after consuming
 batch k resumes at batch k+1 exactly (SURVEY.md §5.4 gap).
 
+Batch arrays are recycled, not reallocated (an ImageNet batch is 154 MB,
+and touching a fresh one costs twenty times the copy into one that
+already exists): a dataset with ``recycle`` (``ArrayDataset``) writes
+the next batch into arrays its consumer gave back through
+:meth:`HostPipeline.release`.  The invariant: **a buffer is rewritten
+only after (a) its consumer released it and (b) no device array can
+still read it.**  :class:`DevicePrefetcher` is the one caller in the
+program and observes (b) itself: it releases a host batch only once
+every array placed from it ``is_ready()`` (the transfer out of the numpy
+memory is asynchronous on an accelerator), and never on a mesh of CPU
+devices, whose arrays may alias the numpy memory for their whole life.
+Whoever iterates a ``HostPipeline`` and never calls ``release`` gets
+fresh arrays for ever, none rewritten under it.  Nothing waits for a
+buffer: no free one means allocate.
+
 Telemetry: all stages record into an injectable
 :class:`...telemetry.MetricsRegistry` (default: the process-global one) —
 ``pipeline/host_queue_depth`` + ``pipeline/producer_wait`` from the host
@@ -33,7 +48,9 @@ producer, ``pipeline/worker_busy/<i>`` per-worker utilization +
 *waits*; their two pieces of *work* are timed once per batch:
 ``pipeline/assemble`` (the dataset producing a batch, in the serial
 producer or in whichever pool worker ran it) and ``pipeline/shard`` (the
-host-to-device placement, with ``pipeline/bytes``).  High producer wait =
+host-to-device placement, with ``pipeline/bytes``);
+``pipeline/buffer_reused`` and ``pipeline/buffer_fresh`` count the
+batches assembled into recycled and into new arrays.  High producer wait =
 consumer-bound (healthy); high prefetch-fill p95 = the host stream is the
 bottleneck — then worker_busy vs reassembly_wait splits "pool too small /
 decode-bound" from "serial cursor-bound" (README "Performance").
@@ -66,6 +83,18 @@ def _batch_bytes(batch: PyTree) -> int:
 
     return sum(
         getattr(x, "nbytes", 0) for x in jax.tree_util.tree_leaves(batch)
+    )
+
+
+def _all_ready(placed: PyTree) -> bool:
+    """Whether every device array of a placed batch is there, i.e. no
+    transfer can still be reading the host arrays it came from.  (Of an
+    array its consumer donated nobody can tell any more: not ready.)"""
+    import jax
+
+    return all(
+        not x.is_deleted() and x.is_ready()
+        for x in jax.tree_util.tree_leaves(placed)
     )
 
 
@@ -103,9 +132,11 @@ class HostPipeline:
     releases results strictly in index order into the bounded consumer
     buffer.  Because release is ordered and state was captured at
     dispatch, the checkpointable state follows the last *released* batch
-    exactly as in the serial path, and in-flight work is naturally
-    bounded by the dispatch queue depth + pool width (the reassembly
-    hold-back set can never exceed it).
+    exactly as in the serial path.  In-flight work (dispatched, not yet
+    handed to the consumer buffer) is bounded by the consumer: the
+    dispatcher takes one of ``num_workers + prefetch`` permits per item
+    and reassembly gives it back on delivery, so a slow consumer stops
+    the workers instead of letting them fill the results queue.
     """
 
     def __init__(
@@ -133,6 +164,10 @@ class HostPipeline:
         # while the consumer is still draining buffered good batches —
         # the STOP sentinel (gated on _stop_event only) still goes out.
         self._pool_stop = threading.Event()
+        # Both present from the start, so that telemetry.json carries the
+        # pair (and their share) whether or not reuse ever engages.
+        self._registry.counter(telemetry.BUFFER_REUSED)
+        self._registry.counter(telemetry.BUFFER_FRESH)
         pooled = num_workers > 1
         if pooled and not (
             hasattr(dataset, "next_work") and hasattr(dataset, "assemble")
@@ -145,19 +180,29 @@ class HostPipeline:
                 type(dataset).__name__,
             )
             pooled = False
+        self._num_workers = num_workers if pooled else 1
+        # Free buffers worth keeping per leaf signature: what a fed
+        # pipeline holds at once (the consumer buffer, one per worker,
+        # the batch in the consumer's hands), plus what the releasing
+        # stage says it holds (release()'s ``downstream``).
+        self._retain = prefetch + self._num_workers + 1
         if pooled:
-            self._num_workers = num_workers
-            # Dispatch depth = pool width + prefetch: enough queued work
-            # to keep every worker fed while the consumer drains, small
-            # enough that dispatch (and so checkpoint state) never runs
-            # far ahead of release.
+            # In-flight permits = pool width + prefetch: enough work to
+            # keep every worker fed while the consumer drains, small
+            # enough that dispatch (and so checkpoint state, and the
+            # memory of assembled batches) never runs far ahead of
+            # release.  A permit is put per dispatched item (blocking
+            # when all are out) and taken back when reassembly hands the
+            # batch to the consumer buffer.
+            self._inflight: queue.Queue = queue.Queue(
+                maxsize=num_workers + prefetch
+            )
             self._work_q: queue.Queue = queue.Queue(
                 maxsize=num_workers + prefetch
             )
-            # Unbounded on purpose: in-flight items are bounded by
-            # work_q depth + num_workers, and a bounded results queue
-            # could deadlock reassembly waiting for an index a blocked
-            # worker holds.
+            # Unbounded on purpose: the permits bound what can be in
+            # it, and a bounded results queue could deadlock reassembly
+            # waiting for an index a blocked worker holds.
             self._results_q: queue.Queue = queue.Queue()
             self._dispatched = 0
             self._dispatch_done = False
@@ -218,6 +263,17 @@ class HostPipeline:
                 continue
         return False
 
+    def _count_buffers(self) -> None:
+        """One count per assembled batch, on the thread that assembled
+        it: into recycled arrays, or into new ones (any dataset that
+        cannot say allocates)."""
+        reused = getattr(self._dataset, "last_assemble_reused", None)
+        self._registry.counter(
+            telemetry.BUFFER_REUSED
+            if reused is not None and reused()
+            else telemetry.BUFFER_FRESH
+        ).inc()
+
     # -- serial producer (num_workers == 1 or no pool protocol) -----------
 
     def _run(self) -> None:
@@ -233,6 +289,7 @@ class HostPipeline:
                 except StopIteration:
                     break
                 reg.record_since(telemetry.ASSEMBLE, t0)
+                self._count_buffers()
                 state = (
                     self._dataset.get_state()
                     if hasattr(self._dataset, "get_state")
@@ -268,6 +325,10 @@ class HostPipeline:
         idx = 0
         try:
             while not self._pool_halted():
+                # A permit per item in flight: the consumer's pace, not
+                # the workers', decides how far the cursor runs ahead.
+                if not self._put_pool_aware(self._inflight, None):
+                    return
                 try:
                     work = self._dataset.next_work()
                 except StopIteration:
@@ -312,6 +373,7 @@ class HostPipeline:
             try:
                 payload = self._dataset.assemble(work)
                 reg.record_since(telemetry.ASSEMBLE, t0)
+                self._count_buffers()
             except BaseException as e:
                 payload = _Failure(e)
             now = time.perf_counter()
@@ -368,6 +430,7 @@ class HostPipeline:
                 )
                 if not delivered:
                     return
+                self._inflight.get_nowait()
         finally:
             # Wind the pool down on EVERY exit — on the error path the
             # dispatcher and workers would otherwise free-run an
@@ -401,6 +464,26 @@ class HostPipeline:
     def get_state(self) -> Optional[dict]:
         """Producer state as of the last *consumed* batch (resume-exact)."""
         return self._state
+
+    @property
+    def prefetch(self) -> int:
+        """How many finished batches the consumer buffer holds."""
+        return self._buffer.maxsize
+
+    def release(self, batch: PyTree, *, downstream: int = 0) -> None:
+        """The consumer has finished with ``batch`` (one this pipeline
+        emitted): its arrays may be overwritten by a later batch.
+
+        Call it only when nothing can read the arrays any more,
+        including a device array placed from them (see the module
+        docstring; ``DevicePrefetcher`` does it for ``fit``).  Never
+        calling it is always safe.  ``downstream`` is how many more
+        batches the calling stage holds, which are so many more buffers
+        worth keeping.  A dataset that does not recycle ignores the
+        call, and so does one handed arrays it did not make."""
+        recycle = getattr(self._dataset, "recycle", None)
+        if recycle is not None:
+            recycle(batch, self._retain + downstream)
 
     def stop(self, raise_pending: bool = True) -> None:
         """Cooperative stop — ``Coordinator.request_stop`` + ``join``
@@ -475,6 +558,13 @@ class DevicePrefetcher:
     *handed to the consumer* — so a checkpoint taken mid-training resumes
     at exactly the next unconsumed batch, never skipping the ``depth``
     batches sitting in this buffer.
+
+    It also keeps each host batch beside the placed one and gives it back
+    to the upstream (``release``, where the upstream has it) once the
+    placed batch has left for the loop and the transfer out of the host
+    arrays is over, which it looks at on every pull and never waits for.
+    On a mesh of CPU devices nothing is released: a CPU device array may
+    alias the numpy memory it was placed from for as long as it lives.
     """
 
     def __init__(self, iterator, mesh, *, depth: int = 2,
@@ -493,8 +583,20 @@ class DevicePrefetcher:
         self._shard = functools.partial(
             sharding.shard_batch, seq_dim=seq_dim
         )
-        self._buf: list[tuple[PyTree, Optional[dict]]] = []
+        # (placed batch, producer state, host batch to release or None)
+        self._buf: list[tuple[PyTree, Optional[dict], Optional[PyTree]]] = []
         self._depth = depth
+        self._release = (
+            None
+            if mesh.devices.flat[0].platform == "cpu"
+            else getattr(iterator, "release", None)
+        )
+        # (placed batch, host batch) handed to the loop whose transfer
+        # was still running then, oldest first.  The loop can pull faster
+        # than transfers end only for as long as batches were ready for
+        # it, here and in the upstream's buffer: so many may wait.
+        self._unreleased: list[tuple[PyTree, PyTree]] = []
+        self._max_unreleased = depth + getattr(iterator, "prefetch", 0)
         self._state: Optional[dict] = (
             iterator.get_state() if hasattr(iterator, "get_state") else None
         )
@@ -552,7 +654,9 @@ class DevicePrefetcher:
             placed = self._shard(self._mesh, batch)
             reg.record_since(telemetry.SHARD, t0, {"bytes": nbytes})
             reg.counter(telemetry.PIPELINE_BYTES).inc(nbytes)
-            self._buf.append((placed, state))
+            self._buf.append(
+                (placed, state, batch if self._release is not None else None)
+            )
             reg.gauge(telemetry.PREFETCH_DEPTH).set(len(self._buf))
 
     def __iter__(self) -> Iterator[PyTree]:
@@ -564,10 +668,24 @@ class DevicePrefetcher:
                 error, self._pending_error = self._pending_error, None
                 raise error
             raise StopIteration
-        out, state = self._buf.pop(0)
+        out, state, host = self._buf.pop(0)
         self._state = state
+        if host is not None:
+            self._unreleased.append((out, host))
+            self._release_ready()
         self._fill()
         return out
+
+    def _release_ready(self) -> None:
+        """Give back, oldest first, the host batches whose transfers are
+        over.  One whose transfer is not waits for a later pull (on the
+        chip a 154 MB batch is there 36-53 ms after the call, three
+        pulls of a loop that runs ahead of the device) and is dropped
+        unreleased once too many wait: nothing here ever blocks."""
+        waiting = self._unreleased
+        while waiting and _all_ready(waiting[0][0]):
+            self._release(waiting.pop(0)[1], downstream=self._depth)
+        del waiting[: max(0, len(waiting) - self._max_unreleased)]
 
     def get_state(self) -> Optional[dict]:
         """Producer state as of the last batch the consumer received."""

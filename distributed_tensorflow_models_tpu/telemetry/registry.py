@@ -61,6 +61,13 @@ PREFETCH_DEPTH = "pipeline/prefetch_depth"  # gauge
 ASSEMBLE = "pipeline/assemble"  # timer
 SHARD = "pipeline/shard"  # timer
 PIPELINE_BYTES = "pipeline/bytes"  # counter: bytes placed on the mesh
+# Batches whose arrays the dataset wrote into recycled buffers, and
+# batches it had to allocate (one or the other per batch, counted by
+# assemble's caller in HostPipeline): reused / (reused + fresh) is how
+# often buffer reuse engages.  Fresh for ever on a CPU mesh (device
+# arrays may alias host memory there, so nothing is ever released).
+BUFFER_REUSED = "pipeline/buffer_reused"  # counter
+BUFFER_FRESH = "pipeline/buffer_fresh"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -624,6 +624,351 @@ def test_device_prefetcher(mesh8):
 
 
 # --------------------------------------------------------------------------
+# Recycled batch buffers (HostPipeline.release -> ArrayDataset.recycle)
+# --------------------------------------------------------------------------
+
+
+def _recycling_dataset(transform, seed=11):
+    """48 rows in batches of 8: six batches an epoch."""
+    rng = np.random.RandomState(0)
+    x = rng.rand(48, 6, 6, 3).astype(np.float32)
+    y = np.arange(48, dtype=np.int32)
+    return datasets.ArrayDataset(
+        {"image": x, "label": y},
+        8,
+        seed=seed,
+        transform=augment.preprocess_cifar_train if transform else None,
+    )
+
+
+def _assert_batches_equal(got, want, msg=""):
+    assert got.keys() == want.keys()
+    for k in want:
+        assert got[k].dtype == want[k].dtype
+        np.testing.assert_array_equal(got[k], want[k], err_msg=f"{msg} {k}")
+
+
+def _address(batch):
+    return batch["image"].ctypes.data
+
+
+@pytest.mark.parametrize(
+    "transform", [False, True], ids=["gather", "transform"]
+)
+@pytest.mark.parametrize("workers", [1, 3])
+def test_released_stream_equals_unreleased_stream(workers, transform):
+    """Releasing every batch changes where the bytes land and nothing
+    else: across two epoch boundaries and a set_state resume the stream
+    is byte-identical to the dataset's plain iteration, which never
+    recycles — and the recycling did engage."""
+    from distributed_tensorflow_models_tpu import telemetry
+
+    ref_it = iter(_recycling_dataset(transform))
+    reg = telemetry.MetricsRegistry()
+    pipe = pipeline.HostPipeline(
+        _recycling_dataset(transform), prefetch=2, num_workers=workers,
+        registry=reg,
+    )
+    for i in range(14):
+        batch = next(pipe)
+        _assert_batches_equal(batch, next(ref_it), f"w={workers} batch {i}")
+        pipe.release(batch)
+    state = pipe.get_state()
+    pipe.stop()
+    assert state == {"epoch": 2, "batch_idx": 2}
+    assert reg.counter(telemetry.BUFFER_REUSED).value > 0
+
+    resumed = _recycling_dataset(transform)
+    resumed.set_state(state)
+    pipe = pipeline.HostPipeline(resumed, prefetch=2, num_workers=workers)
+    for i in range(14, 20):
+        batch = next(pipe)
+        _assert_batches_equal(batch, next(ref_it), f"resumed batch {i}")
+        pipe.release(batch)
+    pipe.stop()
+
+
+def test_released_buffer_is_reused_and_counted():
+    """One release feeds exactly one later batch: it lands at the same
+    address, and the two counters say one recycled, the rest allocated."""
+    from distributed_tensorflow_models_tpu import telemetry
+
+    reg = telemetry.MetricsRegistry()
+    pipe = pipeline.HostPipeline(
+        _recycling_dataset(False), prefetch=1, registry=reg
+    )
+    first = next(pipe)
+    address = _address(first)
+    pipe.release(first)
+    del first
+    later = [next(pipe) for _ in range(6)]
+    pipe.stop()
+    assert [_address(b) for b in later].count(address) == 1
+    snap = reg.snapshot()
+    assert snap[telemetry.BUFFER_REUSED] == 1
+    assert snap[telemetry.BUFFER_FRESH] >= 6
+    assert (
+        snap[telemetry.BUFFER_REUSED] + snap[telemetry.BUFFER_FRESH]
+        == snap[f"{telemetry.ASSEMBLE}/count"]
+    )
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_unreleased_batch_is_never_rewritten(workers):
+    """A consumer that keeps a batch and releases others: the kept
+    arrays stay what they were while later batches are produced, and no
+    later batch lands in their memory."""
+    ref_it = iter(_recycling_dataset(True))
+    pipe = pipeline.HostPipeline(
+        _recycling_dataset(True), prefetch=2, num_workers=workers
+    )
+    kept = []
+    later_addresses = set()
+    for i in range(24):
+        batch = next(pipe)
+        want = next(ref_it)
+        later_addresses.add(_address(batch))
+        if i % 3 == 0:
+            kept.append((batch, want))
+        else:
+            pipe.release(batch)
+        for held, expect in kept:
+            _assert_batches_equal(held, expect, f"kept, at batch {i}")
+    pipe.stop()
+    # 8 kept arrays, all distinct memory, none handed out twice.
+    assert len({_address(b) for b, _ in kept}) == len(kept) == 8
+    assert len(later_addresses) < 24  # and the released ones were reused
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_retention_is_bounded_by_the_pipeline_depths(workers):
+    """A consumer that hoards 30 batches and then releases them all
+    leaves at most prefetch + num_workers + downstream + 1 buffers to
+    reuse; the rest go to the garbage collector."""
+    prefetch, downstream = 2, 2
+    bound = prefetch + workers + downstream + 1
+    pipe = pipeline.HostPipeline(
+        _recycling_dataset(False), prefetch=prefetch, num_workers=workers
+    )
+    hoard = [next(pipe) for _ in range(30)]
+    for batch in hoard:
+        pipe.release(batch, downstream=downstream)
+        pipe.release(batch, downstream=downstream)  # twice: kept once
+    # ``hoard`` stays alive, so malloc cannot hand an address out again.
+    released = {_address(b) for b in hoard}
+    assert len(released) == 30
+    again = [next(pipe) for _ in range(30)]
+    pipe.stop()
+    reused = [_address(b) for b in again if _address(b) in released]
+    assert 1 <= len(reused) <= bound
+    assert len(set(reused)) == len(reused)
+
+
+def test_recycle_ignores_what_assemble_did_not_make():
+    ds = _recycling_dataset(False)
+    made = ds.assemble(ds.next_work())
+    foreign = {
+        "image": np.zeros((4, 6, 6, 3), np.float32),  # another shape
+        "label": made["label"][:],  # a view
+        "extra": np.zeros(8, np.int32),  # another key
+    }
+    ds.recycle(foreign, limit=4)
+    nxt = ds.assemble(ds.next_work())
+    assert not ds.last_assemble_reused()
+    assert not np.shares_memory(nxt["label"], made["label"])
+    ds.recycle(made, limit=4)
+    ds.assemble(ds.next_work())
+    assert ds.last_assemble_reused()
+
+
+def test_recycling_survives_a_thread_stress():
+    """Eight threads assemble, hold, check and give back batches of one
+    dataset at a short switch interval: every batch still holds its own
+    work item's bytes when its holder looks (two holders of one buffer
+    would not), and the free lists stay within their limit."""
+    import sys
+    import threading
+    import time
+
+    ds = _recycling_dataset(True)
+    ref = _recycling_dataset(True)
+    works = [ds.next_work() for _ in range(240)]
+    expected = {w: ref.assemble(w) for w in works}
+    wrong = []
+
+    def hold_and_check(mine):
+        for w in mine:
+            batch = ds.assemble(w)
+            time.sleep(0)  # let the others write
+            if not all(
+                np.array_equal(batch[k], expected[w][k]) for k in batch
+            ):
+                wrong.append(w)
+            ds.recycle(batch, limit=4)
+
+    threads = [
+        threading.Thread(target=hold_and_check, args=(works[i::8],))
+        for i in range(8)
+    ]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not wrong
+    assert ds._free and all(0 < len(v) <= 4 for v in ds._free.values())
+
+
+def test_device_prefetcher_never_releases_on_a_cpu_mesh(mesh8):
+    """The aliasing case: a CPU device array may be a zero-copy view of
+    the numpy batch, so on a CPU mesh the host batch is never given
+    back.  Device batches held alive across six later pulls still equal
+    the reference stream, and nothing was recycled."""
+    from distributed_tensorflow_models_tpu import telemetry
+
+    ref_it = iter(_recycling_dataset(False))
+    reg = telemetry.MetricsRegistry()
+    host = pipeline.HostPipeline(
+        _recycling_dataset(False), prefetch=2, registry=reg
+    )
+    pre = pipeline.DevicePrefetcher(host, mesh8, depth=2, registry=reg)
+    held = []
+    for i in range(14):
+        held.append((next(pre), next(ref_it)))
+        for placed, want in held[-7:]:
+            for k in want:
+                np.testing.assert_array_equal(
+                    np.asarray(placed[k]), want[k], err_msg=f"at pull {i}"
+                )
+    host.stop()
+    assert reg.counter(telemetry.BUFFER_REUSED).value == 0
+
+
+class _SlowTransfer:
+    """A placed leaf whose transfer out of the host array ends only when
+    the test says so: until then it aliases the host memory (what an
+    asynchronous DMA reads), from then on it holds its own copy."""
+
+    def __init__(self, host):
+        self._host = host
+        self._copy = None
+
+    def finish(self):
+        if self._copy is None:
+            self._copy = self._host.copy()
+            self._host = None
+
+    def is_ready(self):
+        return self._copy is not None
+
+    def is_deleted(self):
+        return False
+
+    def value(self):
+        return self._host if self._copy is None else self._copy
+
+
+def test_device_prefetcher_releases_only_after_the_transfer_is_over(
+    monkeypatch,
+):
+    """On a backend that copies out of the host batch asynchronously,
+    the prefetcher gives a host batch back only once every array placed
+    from it is ready: while transfers lag, nothing is recycled and every
+    pending transfer still reads its own batch; once they end, later
+    batches land in recycled buffers; and a transfer that never ends
+    costs its buffer, not the loop's time.  (The accelerator is stood in
+    for by a mesh that reports its platform and a placement whose
+    transfers the test ends by hand.)"""
+    import types
+
+    import jax
+
+    from distributed_tensorflow_models_tpu import telemetry
+    from distributed_tensorflow_models_tpu.core import sharding
+
+    monkeypatch.setattr(
+        sharding,
+        "shard_batch",
+        lambda mesh, batch, seq_dim=None: jax.tree.map(_SlowTransfer, batch),
+    )
+    accelerator = types.SimpleNamespace(
+        devices=np.array([types.SimpleNamespace(platform="tpu")])
+    )
+    ref_it = iter(_recycling_dataset(False))
+    reg = telemetry.MetricsRegistry()
+    host = pipeline.HostPipeline(
+        _recycling_dataset(False), prefetch=2, registry=reg
+    )
+    pre = pipeline.DevicePrefetcher(host, accelerator, depth=2, registry=reg)
+    held = []
+
+    def pull_and_check(n):
+        for _ in range(n):
+            held.append((next(pre), next(ref_it)))
+            for placed, want in held:
+                for k in want:
+                    np.testing.assert_array_equal(placed[k].value(), want[k])
+
+    # Ten pulls with every transfer lagging: four wait (depth + the
+    # upstream's prefetch), the older ones are dropped unreleased, and
+    # no batch is rewritten under its transfer.
+    pull_and_check(10)
+    assert reg.counter(telemetry.BUFFER_REUSED).value == 0
+    assert len(pre._unreleased) == 4
+    # The transfers end: the waiting batches go back at the next pulls
+    # and the producer writes into them.
+    for placed, _ in held:
+        jax.tree.map(_SlowTransfer.finish, placed)
+    pull_and_check(2)
+    for placed, _ in held[-2:]:
+        jax.tree.map(_SlowTransfer.finish, placed)
+    pull_and_check(8)
+    host.stop()
+    assert reg.counter(telemetry.BUFFER_REUSED).value >= 4
+
+
+def test_worker_pool_is_bounded_by_its_consumer():
+    """Three workers and a slow consumer: the items in flight
+    (dispatched, not yet handed to the consumer buffer) never exceed
+    num_workers + prefetch, so the workers stop when the consumer does
+    (unbounded before: 145,068 batches assembled in a 6-step drive)."""
+    import threading
+    import time
+
+    workers, prefetch = 3, 2
+    ds = _recycling_dataset(False)
+    assembled = 0
+    lock = threading.Lock()
+    inner = ds.assemble
+
+    def counting_assemble(work):
+        nonlocal assembled
+        batch = inner(work)
+        with lock:
+            assembled += 1
+        return batch
+
+    ds.assemble = counting_assemble
+    pipe = pipeline.HostPipeline(ds, prefetch=prefetch, num_workers=workers)
+    for consumed in range(1, 7):
+        next(pipe)
+        time.sleep(0.05)
+        # Read what was assembled first, what was delivered after: the
+        # difference can only under-read what is in flight.
+        n = assembled
+        in_flight = n - consumed - pipe._buffer.qsize()
+        assert in_flight <= workers + prefetch, (consumed, n)
+    time.sleep(0.3)
+    assert assembled <= 6 + prefetch + workers + prefetch
+    pipe.stop()
+
+
+# --------------------------------------------------------------------------
 # Multi-host sharding (SURVEY.md §3.4: per-worker input streams)
 # --------------------------------------------------------------------------
 
