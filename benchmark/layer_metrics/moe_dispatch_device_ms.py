@@ -1,0 +1,14 @@
+"""Models and ops (``parallel/moe.py::topk_moe_ffn``): device time per
+step under the ``moe_dispatch`` scope, forward and backward together:
+routing, the sort by expert, the gather of rows and the weighted sum back per token.
+
+Chip 0's self time per traced step: the profiler trace joined with the
+program's scope map by ``benchmark/lib/named_scopes.py``.  None without
+a trace or a map, or for a program without the scope.
+"""
+
+from benchmark.lib import named_scopes
+
+
+def read(ctx):
+    return named_scopes.ms_per_step(ctx, "moe_dispatch")

@@ -132,7 +132,10 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
     Models may ``sow`` scalar regularizers into the ``losses`` collection
     (the transformer's Switch-MoE load-balancing loss does); every leaf is
     summed into the objective but kept out of ``nll`` so perplexity stays
-    comparable across dense and MoE configs.
+    comparable across dense and MoE configs.  What a model sows into
+    ``moe_stats`` (the top-k expert layer: its unweighted ``aux_loss`` and
+    ``z_loss`` and ``load_max_over_mean``) is averaged over layers into
+    the metrics as ``moe_<name>`` and never touches the objective.
     """
 
     def loss_fn(params, state, batch, rngs):
@@ -147,7 +150,7 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
                 carry=state.carry,
                 train=True,
                 rngs=dict(rngs),
-                mutable=["losses"],
+                mutable=["losses", "moe_stats"],
                 return_hidden=True,
             )
             head = params["head"]
@@ -166,7 +169,7 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
                 carry=state.carry,
                 train=True,
                 rngs=dict(rngs),
-                mutable=["losses"],
+                mutable=["losses", "moe_stats"],
             )
             nll = jnp.mean(losslib.token_xent(logits, batch["targets"]))
         aux = sum(
@@ -177,6 +180,13 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
         metrics = {"loss": loss, "nll": nll}
         if updated.get("losses"):
             metrics["aux_loss"] = aux
+        by_name: dict = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            updated.get("moe_stats", {})
+        ):
+            by_name.setdefault(path[-1].key, []).append(leaf)
+        for name, leaves in by_name.items():
+            metrics[f"moe_{name}"] = sum(leaves) / len(leaves)
         return loss, {"metrics": metrics, "carry": new_carry}
 
     return loss_fn

@@ -434,6 +434,42 @@ _add(
     )
 )
 
+# OLMoE-1B-7B (Muennighoff et al. 2024, arXiv:2409.02060; config.json of
+# allenai/OLMoE-1B-7B-0125-Instruct): RMSNorm 1e-5, RMSNorm on the query
+# and key projections, RoPE, no bias anywhere, 64 gated experts of width
+# 1024 in every layer, softmax then top-8 without a capacity, router
+# z-loss 0.001 beside the 0.01 load-balancing loss, no dropout.  Every
+# width is the published one; the depth here is the published 16, which
+# no single chip holds in training (benchmark/configs/olmoe.json runs 1).
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="olmoe",
+        model_kwargs={
+            "vocab_size": 50304,
+            "num_layers": 16,
+            "num_heads": 16,
+            "d_model": 2048,
+            "d_ff": 1024,
+            "max_len": 4096,
+            "dropout_rate": 0.0,
+            "pos_encoding": "rope",
+            "rope_theta": 10000.0,
+            "norm": "rmsnorm",
+            "norm_eps": 1e-5,
+            "use_bias": False,
+            "qk_norm": True,
+            "num_experts": 64,
+            "moe_router": "topk",
+            "moe_top_k": 8,
+            "moe_layers": "all",
+            "moe_z_loss_weight": 0.001,
+        },
+        global_batch_size=8,
+        num_steps=4096,
+        vocab_size=50304,
+    )
+)
+
 
 def get_config(name: str, **overrides) -> ExperimentConfig:
     if name not in _CONFIGS:

@@ -323,6 +323,11 @@ class TensorBoardHook(Hook):
             self._writer.close()
 
 
+# The routing statistics a model with top-k experts puts into the step's
+# metrics (core/train_loop.py::lm_loss_fn), always the three together.
+MOE_KEYS = ("moe_load_max_over_mean", "moe_aux_loss", "moe_z_loss")
+
+
 class TelemetryHook(Hook):
     """Snapshot the telemetry registry every ``every_steps`` and inject the
     derived scalars into the per-step ``metrics`` dict, where the
@@ -357,6 +362,12 @@ class TelemetryHook(Hook):
     - ``restarts`` / ``rollbacks`` / ``skipped_batches`` — resilience
       counters (recoverable_fit restarts; nan_policy=rollback rewinds
       and the batches their skips discarded)
+    - ``moe_load_max_over_mean`` / ``moe_aux_loss`` / ``moe_z_loss`` —
+      only where the step reports them (a model with top-k routed
+      experts): the mean over the steps this hook walked since the
+      previous firing, replacing the firing step's own value.  The
+      steps' device scalars are kept and fetched at the cadence, where
+      the loss row is fetched anyway: no further device sync.
 
     Multi-host: steps/sec and stall fraction are allgathered
     (``multihost_utils.process_allgather`` — a collective, so the hook
@@ -386,6 +397,7 @@ class TelemetryHook(Hook):
         self._peak = peak and peak * len(jax.devices())
         self._last: Optional[tuple[float, int, dict]] = None
         self.last_emitted: Optional[dict] = None
+        self._moe: list[tuple] = []
 
     def begin(self, state):
         self._last = (
@@ -399,6 +411,8 @@ class TelemetryHook(Hook):
         return step % self._every == 0
 
     def after_step(self, state, metrics, step):
+        if MOE_KEYS[0] in metrics:
+            self._moe.append(tuple(metrics[k] for k in MOE_KEYS))
         if step % self._every:
             return
         now = time.perf_counter()
@@ -462,6 +476,10 @@ class TelemetryHook(Hook):
             "rollbacks": snap.get(telemetry.ROLLBACKS, 0.0),
             "skipped_batches": snap.get(telemetry.SKIPPED_BATCHES, 0.0),
         }
+        if self._moe:
+            means = np.mean(np.asarray(jax.device_get(self._moe)), axis=0)
+            out.update(zip(MOE_KEYS, map(float, means)))
+            self._moe = []
         if self._nproc > 1:
             from jax.experimental import multihost_utils
 

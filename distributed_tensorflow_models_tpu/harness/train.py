@@ -142,6 +142,12 @@ def _mesh_model_kwargs(cfg: ExperimentConfig, mesh) -> dict:
             "pipelined stacked layout does not use — TP would silently "
             "fall back to replication"
         )
+    if cfg.mesh_expert > 1 and cfg.model_kwargs.get("moe_router") == "topk":
+        raise ValueError(
+            "mesh_expert > 1 with moe_router='topk': exact top-k routing "
+            "over an expert axis needs an all-to-all of uneven size, "
+            "which is the cell olmoe_train_ep4's PR (PERF.md section 7)"
+        )
     kwargs: dict = {"attn_impl": cfg.attn_impl}
     if cfg.seq_impl:
         from distributed_tensorflow_models_tpu.parallel import ring as ringlib

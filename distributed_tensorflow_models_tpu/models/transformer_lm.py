@@ -14,6 +14,13 @@ Parameter naming is pinned to :func:`...parallel.tensor.transformer_tp_rules`
 (attn/query|key|value|out, mlp/up|down, embedding, head) so tensor
 parallelism is a placement rule set, not a model change.
 
+The block takes what an architecture states: the norm (LayerNorm, or
+RMSNorm with its epsilon), biases on or off, RMSNorm on the query and key
+projections, and the feed-forward kind: the GELU MLP, Switch experts in
+every other block, or softmax-then-top-k routing over gated experts in
+every block (:func:`...parallel.moe.topk_moe_ffn`; OLMoE, ROADMAP R1).
+These are a model's published settings, not tuning options.
+
 TPU notes: bf16 compute with fp32 LayerNorm and logits; attention and MLP
 matmuls are [B·T, d]-shaped for the MXU; causal masking is positional (no
 materialized [T, T] mask when the blockwise/flash paths run).
@@ -30,6 +37,18 @@ import jax.numpy as jnp
 from distributed_tensorflow_models_tpu.models import register
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops.embed import TokenEmbed
+
+
+def make_norm(kind: str, eps: Optional[float], name: str) -> nn.Module:
+    """The normalisation an architecture states, computed in float32.
+    ``eps`` None keeps flax's default (1e-6, what the GPT-2 block has
+    always used here)."""
+    kwargs = {} if eps is None else {"epsilon": eps}
+    if kind == "layernorm":
+        return nn.LayerNorm(dtype=jnp.float32, name=name, **kwargs)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(dtype=jnp.float32, name=name, **kwargs)
+    raise ValueError(f"unknown norm {kind!r} (want 'layernorm' or 'rmsnorm')")
 
 
 class SelfAttention(nn.Module):
@@ -61,6 +80,11 @@ class SelfAttention(nn.Module):
     # attention (ops/rotary.py); keys are cached post-rotation in decode.
     use_rope: bool = False
     rope_theta: float = 10000.0
+    use_bias: bool = True
+    # RMSNorm over the whole query and key projections, each with its own
+    # weight, before the head split and the rotation (OLMoE).
+    qk_norm: bool = False
+    norm_eps: Optional[float] = None
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -71,10 +95,17 @@ class SelfAttention(nn.Module):
         Hkv = self.num_kv_heads or H
         Dh = self.d_model // H
         dense = lambda name, feats: nn.Dense(
-            feats, dtype=self.dtype, name=name
+            feats, dtype=self.dtype, use_bias=self.use_bias, name=name
         )
-        q = dense("query", self.d_model)(x).reshape(B, T, H, Dh)
-        k = dense("key", Hkv * Dh)(x).reshape(B, T, Hkv, Dh)
+        q = dense("query", self.d_model)(x)
+        k = dense("key", Hkv * Dh)(x)
+        if self.qk_norm:
+            norm = lambda name, y: make_norm("rmsnorm", self.norm_eps, name)(
+                y
+            ).astype(self.dtype)
+            q, k = norm("q_norm", q), norm("k_norm", k)
+        q = q.reshape(B, T, H, Dh)
+        k = k.reshape(B, T, Hkv, Dh)
         v = dense("value", Hkv * Dh)(x).reshape(B, T, Hkv, Dh)
         if self.use_rope and not self.decode:
             pos = jnp.arange(T)
@@ -119,7 +150,7 @@ class SelfAttention(nn.Module):
                 window=self.attn_window,
             )
         out = out.reshape(B, T, self.d_model)
-        out = nn.Dense(self.d_model, dtype=self.dtype, name="out")(out)
+        out = dense("out", self.d_model)(out)
         if self.dropout_rate:
             out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
         return out
@@ -130,12 +161,15 @@ class MLP(nn.Module):
     d_ff: int
     dropout_rate: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        h = nn.Dense(self.d_ff, dtype=self.dtype, name="up")(x)
-        h = nn.gelu(h)
-        h = nn.Dense(self.d_model, dtype=self.dtype, name="down")(h)
+        dense = lambda name, feats: nn.Dense(
+            feats, dtype=self.dtype, use_bias=self.use_bias, name=name
+        )
+        h = nn.gelu(dense("up", self.d_ff)(x))
+        h = dense("down", self.d_model)(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
         return h
@@ -208,6 +242,61 @@ class MoEFFN(nn.Module):
         return res.out.reshape(B, T, d).astype(x.dtype)
 
 
+class TopKExpertsFFN(nn.Module):
+    """Softmax-then-top-k routing over gated (SiLU) experts without a
+    capacity: flax parameter declaration around
+    :func:`...parallel.moe.topk_moe_ffn`.  The weighted load-balancing
+    loss and router z-loss go into the ``losses`` collection (summed into
+    the objective by :func:`...core.train_loop.lm_loss_fn`); the
+    unweighted values and the load statistic into ``moe_stats``, which
+    the loss function averages over layers into the step's metrics."""
+
+    num_experts: int
+    top_k: int
+    d_model: int
+    d_ff: int  # one expert's width
+    mesh: Any = None
+    aux_loss_weight: float = 0.01
+    z_loss_weight: float = 0.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        from distributed_tensorflow_models_tpu.parallel import moe as moelib
+
+        E, d, f = self.num_experts, self.d_model, self.d_ff
+
+        def normal(name, shape, fan_in):
+            return self.param(
+                name,
+                lambda rng: jax.random.normal(rng, shape) * fan_in**-0.5,
+            )
+
+        params = {
+            "router": normal("router", (d, E), d),
+            "w_gate": normal("w_gate", (E, d, f), d),
+            "w_up": normal("w_up", (E, d, f), d),
+            "w_down": normal("w_down", (E, f, d), f),
+        }
+        res = moelib.topk_moe_ffn(
+            params, x, top_k=self.top_k, mesh=self.mesh, dtype=self.dtype
+        )
+        scalar = dict(
+            reduce_fn=lambda a, b: a + b,
+            init_fn=lambda: jnp.zeros((), jnp.float32),
+        )
+        self.sow("losses", "moe_aux", self.aux_loss_weight * res.aux_loss, **scalar)
+        if self.z_loss_weight:
+            self.sow("losses", "moe_z", self.z_loss_weight * res.z_loss, **scalar)
+        for name, value in (
+            ("aux_loss", res.aux_loss),
+            ("z_loss", res.z_loss),
+            ("load_max_over_mean", res.load_max_over_mean),
+        ):
+            self.sow("moe_stats", name, value, **scalar)
+        return res.out.astype(x.dtype)
+
+
 class Block(nn.Module):
     num_heads: int
     d_model: int
@@ -226,10 +315,22 @@ class Block(nn.Module):
     attn_window: Any = None
     use_rope: bool = False
     rope_theta: float = 10000.0
+    norm: str = "layernorm"
+    norm_eps: Optional[float] = None
+    use_bias: bool = True
+    qk_norm: bool = False
+    # Experts' routing where ``use_moe``: "switch" (top-1 with a capacity,
+    # ReLU experts) or "topk" (softmax then top-k, gated experts, exact).
+    moe_router: str = "switch"
+    moe_top_k: int = 1
+    moe_z_loss_weight: float = 0.0
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(self.dtype)
+        norm = lambda name, y: make_norm(self.norm, self.norm_eps, name)(
+            y
+        ).astype(self.dtype)
+        h = norm("ln1", x)
         x = x + SelfAttention(
             self.num_heads,
             self.d_model,
@@ -243,10 +344,24 @@ class Block(nn.Module):
             attn_window=self.attn_window,
             use_rope=self.use_rope,
             rope_theta=self.rope_theta,
+            use_bias=self.use_bias,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
             name="attn",
         )(h, train=train)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(self.dtype)
-        if self.use_moe:
+        h = norm("ln2", x)
+        if self.use_moe and self.moe_router == "topk":
+            ffn = TopKExpertsFFN(
+                self.num_experts,
+                self.moe_top_k,
+                self.d_model,
+                self.d_ff,
+                self.moe_mesh,
+                z_loss_weight=self.moe_z_loss_weight,
+                dtype=self.dtype,
+                name="moe",
+            )
+        elif self.use_moe:
             ffn = MoEFFN(
                 self.num_experts,
                 self.d_model,
@@ -262,6 +377,7 @@ class Block(nn.Module):
                 self.d_ff,
                 self.dropout_rate,
                 self.dtype,
+                use_bias=self.use_bias,
                 name="mlp",
             )
         return x + ffn(h, train=train)
@@ -485,6 +601,52 @@ class TransformerLM(nn.Module):
     # "rope" rotary relative positions applied inside attention.
     pos_encoding: str = "learned"
     rope_theta: float = 10000.0
+    # What the architecture states beyond the GPT-2 block (defaults: that
+    # block).  ``norm``: "layernorm" or "rmsnorm", ``norm_eps`` None =
+    # flax's 1e-6; ``use_bias`` on every projection and the head;
+    # ``qk_norm``: RMSNorm on the query and key projections.
+    norm: str = "layernorm"
+    norm_eps: Optional[float] = None
+    use_bias: bool = True
+    qk_norm: bool = False
+    # Experts (``num_experts`` > 0, each ``d_ff`` wide): "switch" routing
+    # (top-1, capacity, ReLU experts) or "topk" (softmax then
+    # ``moe_top_k``, gated SiLU experts, no token dropped); in every other
+    # block ("alternate", the Switch placement) or in "all".
+    moe_router: str = "switch"
+    moe_top_k: int = 1
+    moe_layers: str = "alternate"
+    moe_z_loss_weight: float = 0.0
+
+    def _check_settings(self):
+        """Refusals that depend on no input: raised when the model is
+        first called, before anything is traced."""
+        for name, value, known in (
+            ("pos_encoding", self.pos_encoding, ("learned", "rope")),
+            ("norm", self.norm, ("layernorm", "rmsnorm")),
+            ("moe_router", self.moe_router, ("switch", "topk")),
+            ("moe_layers", self.moe_layers, ("alternate", "all")),
+        ):
+            if value not in known:
+                raise ValueError(f"unknown {name} {value!r} (want one of {known})")
+        gpt2_block = (
+            self.norm == "layernorm"
+            and self.norm_eps is None
+            and self.use_bias
+            and not self.qk_norm
+        )
+        if (self.pipelined or self.pipe_mesh is not None) and not gpt2_block:
+            raise ValueError(
+                "the pipelined block stack is the GPT-2 block only "
+                "(LayerNorm, biases, GELU MLP): norm/norm_eps/use_bias/"
+                "qk_norm and experts are not plumbed into the stacked "
+                "layout (ROADMAP D3)"
+            )
+        if self.decode and self.num_experts and self.moe_router != "topk":
+            raise ValueError(
+                "decode mode does not run Switch experts (capacity is "
+                "counted per training batch); top-k experts decode"
+            )
 
     @nn.compact
     def __call__(
@@ -496,6 +658,7 @@ class TransformerLM(nn.Module):
         (:func:`...ops.losses.chunked_unembed_xent`) — the head parameters
         still exist (init uses the default path) and the loss consumes
         them directly from ``params``."""
+        self._check_settings()
         B, T = tokens.shape
         # TokenEmbed == nn.Embed (same param path/init/dtype promotion)
         # plus the selectable backward lowering: DTM_EMBED_GRAD=matmul
@@ -519,7 +682,7 @@ class TransformerLM(nn.Module):
                     "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
                 )
                 pi.value = pi.value + T
-        elif self.pos_encoding == "learned":
+        else:
             pos = self.param(
                 "pos_embedding",
                 nn.initializers.normal(0.02),
@@ -536,21 +699,15 @@ class TransformerLM(nn.Module):
                 pi.value = pi.value + T
             else:
                 x = x + pos[:T].astype(self.dtype)
-        else:
-            raise ValueError(
-                f"unknown pos_encoding {self.pos_encoding!r} "
-                "(want 'learned' or 'rope')"
-            )
         if self.dropout_rate:
             x = nn.Dropout(self.dropout_rate, deterministic=not train)(x)
         if self.decode and (
             self.pipelined
             or self.pipe_mesh is not None
-            or self.num_experts
             or self.attention_fn is not None
         ):
             raise ValueError(
-                "decode mode supports the dense non-pipelined stack "
+                "decode mode supports the non-pipelined stack "
                 "without a sequence-parallel attention_fn"
             )
         if self.attn_window is not None and self.attention_fn is not None:
@@ -604,7 +761,8 @@ class TransformerLM(nn.Module):
                     self.dtype,
                     self.attn_impl,
                     self.attention_fn,
-                    use_moe=self.num_experts > 0 and i % 2 == 1,
+                    use_moe=self.num_experts > 0
+                    and (self.moe_layers == "all" or i % 2 == 1),
                     num_experts=self.num_experts,
                     moe_mesh=self.moe_mesh,
                     moe_capacity_factor=self.moe_capacity_factor,
@@ -614,13 +772,23 @@ class TransformerLM(nn.Module):
                     attn_window=self.attn_window,
                     use_rope=self.pos_encoding == "rope",
                     rope_theta=self.rope_theta,
+                    norm=self.norm,
+                    norm_eps=self.norm_eps,
+                    use_bias=self.use_bias,
+                    qk_norm=self.qk_norm,
+                    moe_router=self.moe_router,
+                    moe_top_k=self.moe_top_k,
+                    moe_z_loss_weight=self.moe_z_loss_weight,
                     name=f"blocks_{i}",
                 )(x, train)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+        x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         if return_hidden:
             return x, carry
         logits = nn.Dense(
-            self.vocab_size, dtype=jnp.float32, name="head"
+            self.vocab_size,
+            dtype=jnp.float32,
+            use_bias=self.use_bias,
+            name="head",
         )(x)
         return logits, carry
 

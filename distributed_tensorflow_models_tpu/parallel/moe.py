@@ -18,15 +18,30 @@ E) is returned for the caller to add to the task loss.
 
 Everything is differentiable: ``all_to_all`` has a transpose rule, routing
 uses one-hot matmuls, and capacity masking is a multiply.
+
+Beside it, for the models that state it (OLMoE and its successors,
+ROADMAP R1-R4): :func:`topk_moe_ffn`, softmax-then-top-k routing over
+gated experts with **no capacity**: every one of a token's ``top_k``
+assignments is computed.  The assignments are sorted by expert, the rows
+gathered, each projection is one grouped matrix product over contiguous
+groups of uneven size (the Pallas grouped matmul that ships with jax,
+``megablox``: static shapes, the group sizes are data), and the weighted
+rows are summed back per token.  No tensor grows with tokens x experts x
+capacity.  ``jax.lax.ragged_dot`` was measured against it on a v5e at the
+cell's shapes and lost (100 against 135 TFLOP/s over forward and both
+backward products, PERF.md section 6, PR 25); XLA's rewrite of it also
+drops the instruction's ``op_name``, and with it the scopes below.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_tensorflow_models_tpu.core.mesh import AxisNames
@@ -214,3 +229,188 @@ def moe_ffn_reference(
         aux_loss=jnp.mean(jnp.stack(auxes)),
         dropped_fraction=jnp.mean(jnp.stack(drops)),
     )
+
+
+# --- Exact top-k routing over gated experts (no capacity) ----------------
+
+# ``jax.named_scope`` names of the expert layer, path elements of every
+# instruction's ``op_name`` in the compiled step (PERF.md section 3): the
+# whole layer; routing, sort, gather and the weighted sum back; the
+# grouped products and the gate.
+MOE_SCOPE = "moe"
+MOE_DISPATCH_SCOPE = "moe_dispatch"
+MOE_EXPERTS_SCOPE = "moe_experts"
+
+
+class TopKMoEOutput(NamedTuple):
+    out: jax.Array  # [tokens, d_model]
+    aux_loss: jax.Array  # E * sum_e f_e * P_e (unweighted)
+    z_loss: jax.Array  # mean(logsumexp(router logits)^2) (unweighted)
+    load_max_over_mean: jax.Array  # fullest expert's assignments / mean
+
+
+@jax.custom_vjp
+def _permute_rows(rows, perm, inverse):
+    """``rows[perm]`` for a permutation ``perm`` whose inverse is given:
+    the transpose of a gather by a permutation is the gather by its
+    inverse, which is what the backward pass runs in place of the
+    scatter-add XLA would derive."""
+    del inverse
+    return rows[perm]
+
+
+def _permute_rows_fwd(rows, perm, inverse):
+    return rows[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _tiling(dtype) -> tuple[int, int, int]:
+    """The grouped product's row, k and n tiles: for 2-byte operands the
+    best of four tilings tried on a v5e at [131072, 2048] x [64, 2048,
+    1024] (PERF.md section 6); 4-byte operands (the float32 comparison
+    with the reference) take tiles a quarter the size to stay inside the
+    kernel's fast memory."""
+    return (512, 1024, 1024) if jnp.dtype(dtype).itemsize <= 2 else (256, 512, 512)
+
+
+def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes):
+    """``rows`` [m, k], sorted by group, times each group's own matrix of
+    ``weights`` [groups, k, n]: row ``i`` of group ``g`` gives ``rows[i]
+    @ weights[g]``.  ``m`` has to be a multiple of the row tile
+    (:func:`_pad_rows`).  Differentiable in ``rows`` and ``weights``
+    (megablox's own backward products).  Off the TPU the kernel runs in
+    Pallas' interpret mode."""
+    return megablox.gmm(
+        rows,
+        weights,
+        group_sizes,
+        rows.dtype,
+        _tiling(rows.dtype),
+        None,
+        None,
+        False,
+        jax.default_backend() != "tpu",
+    )
+
+
+def _pad_rows(rows: jax.Array, group_sizes: jax.Array):
+    """Zero rows up to a multiple of the row tile, counted into the last
+    group: they cost a tile at most and change no result."""
+    pad = -rows.shape[0] % _tiling(rows.dtype)[0]
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        group_sizes = group_sizes.at[-1].add(pad)
+    return rows, group_sizes
+
+
+def route_topk(router: jax.Array, x: jax.Array, top_k: int):
+    """``(logits, probs, weight, expert)`` of tokens ``x`` [n, d]: the
+    router's product, its softmax and the choice, in float32 at full
+    precision: a bf16 product here moves near-ties across the top-k
+    boundary.  ``weight`` and ``expert`` are [n, top_k], largest first,
+    ties to the lower expert index."""
+    logits = jnp.dot(
+        x.astype(jnp.float32),
+        router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    weight, expert = lax.top_k(probs, top_k)
+    return logits, probs, weight, expert
+
+
+def _topk_local(params: dict, x: jax.Array, top_k: int, dtype):
+    """The layer on one rank's tokens ``x`` [n, d]; every expert is here."""
+    n, d = x.shape
+    num_experts = params["router"].shape[-1]
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        x = x.astype(dtype)
+        logits, probs, weight, expert = route_topk(params["router"], x, top_k)
+        flat = expert.reshape(n * top_k)
+        order = jnp.argsort(flat, stable=True)  # assignment ids by expert
+        inverse = jnp.argsort(order)
+        counts = jnp.sum(
+            jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0
+        )
+        # Assignment j belongs to token j // k: repeat, then permute.
+        rows = _permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+    with jax.named_scope(MOE_EXPERTS_SCOPE):
+        rows, sizes = _pad_rows(rows, counts)
+        grouped = functools.partial(grouped_matmul, group_sizes=sizes)
+        gate = grouped(rows, params["w_gate"].astype(dtype))
+        up = grouped(rows, params["w_up"].astype(dtype))
+        hidden = (
+            jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        ).astype(dtype)
+        down = grouped(hidden, params["w_down"].astype(dtype))[: n * top_k]
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        back = _permute_rows(down, inverse, order).reshape(n, top_k, d)
+        out = jnp.sum(
+            back.astype(jnp.float32) * weight[..., None], axis=1
+        ).astype(dtype)
+        fraction = counts.astype(jnp.float32) / (n * top_k)
+        aux = num_experts * jnp.sum(fraction * jnp.mean(probs, axis=0))
+        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        load = jnp.max(fraction) * num_experts
+    return out, aux, z, load
+
+
+@jax.named_scope(MOE_SCOPE)
+def topk_moe_ffn(
+    params: dict,
+    x: jax.Array,
+    *,
+    top_k: int,
+    mesh: Optional[Mesh] = None,
+    dtype=jnp.bfloat16,
+) -> TopKMoEOutput:
+    """Softmax-then-top-k routing over gated (SiLU) experts, exactly:
+    ``y = sum_{e in top_k} p_e * W_down_e (silu(W_gate_e h) * W_up_e h)``
+    with the ``p_e`` as they are (not renormalised).  ``x`` is
+    ``[batch, time, d_model]``; ``params`` holds ``router`` [d, E] and the expert
+    stacks ``w_gate``, ``w_up`` [E, d, f] and ``w_down`` [E, f, d].
+
+    On a mesh every rank routes its own tokens (``x`` sharded
+    ``[data, seq, ...]``, the experts replicated) and the three statistics
+    are means over ranks.  An ``expert`` axis larger than 1 needs an
+    exchange of uneven size and is not built.
+    """
+    d = x.shape[-1]
+    local = lambda p, xl: _topk_local(p, xl.reshape(-1, d), top_k, dtype)
+    if mesh is None:
+        out, aux, z, load = local(params, x)
+        return TopKMoEOutput(out.reshape(x.shape), aux, z, load)
+    if mesh.shape[AxisNames.EXPERT] > 1:
+        raise NotImplementedError(
+            "exact top-k routing over an expert axis larger than 1 needs "
+            "an all-to-all of uneven size: that is the cell "
+            "olmoe_train_ep4's PR (PERF.md section 7); here every expert "
+            "lives on every rank"
+        )
+    token_axes = (AxisNames.DATA, AxisNames.SEQ)
+
+    def per_device(p, xl):
+        out, aux, z, load = local(p, xl)
+        stats = lax.pmean(jnp.stack([aux, z, load]), token_axes)
+        return out.reshape(xl.shape), stats
+
+    # pallas_call outputs carry no varying-mesh-axes type, which the vma
+    # checker rejects (as in parallel/ring.py); a Mosaic kernel also wants
+    # every axis manual.  Unchecked, the transpose sums the experts'
+    # gradient over every axis and divides the output's by the axes it is
+    # replicated over, which is right for ranks that hold other tokens
+    # and for ranks that repeat the same ones (tests/test_olmoe_block.py).
+    out, stats = jax.shard_map(
+        per_device,
+        mesh=mesh,
+        in_specs=(P(), P(*token_axes)),
+        out_specs=(P(*token_axes), P()),
+        check_vma=False,
+    )(params, x)
+    return TopKMoEOutput(out, stats[0], stats[1], stats[2])

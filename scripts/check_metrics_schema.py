@@ -42,6 +42,11 @@ Checks, per line:
   of ``pipeline/assemble`` and ``pipeline/shard``): injected together,
   non-negative seconds;
 
+- expert-routing keys (``moe_load_max_over_mean``, ``moe_aux_loss``,
+  ``moe_z_loss`` — a top-k expert model's routing statistics, means
+  over layers and over the interval's steps): written together,
+  non-negative, and the load statistic at least 1;
+
 - tracer accounting (``trace/*`` — ``trace/events``, ``trace/dropped``
   in telemetry.json snapshots): any present value must be a
   non-negative number;
@@ -170,6 +175,11 @@ STARTUP_KEYS = (
 # beside ``data_wait_s``: non-negative seconds, and a partial set on a
 # row is a writer bug.
 INPUT_WORK_KEYS = ("assemble_s", "shard_s")
+# What a model with top-k routed experts reports on its log rows
+# (core/train_loop.py::lm_loss_fn from the ``moe_stats`` collection;
+# TelemetryHook averages them over the interval): always the three
+# together; the fullest expert holds at least the mean.
+MOE_KEYS = ("moe_load_max_over_mean", "moe_aux_loss", "moe_z_loss")
 
 
 def _is_number(v) -> bool:
@@ -283,6 +293,20 @@ def check_lines(
                 errors.append(
                     f"line {i}: input-work timer {key!r} is negative: "
                     f"{value!r}"
+                )
+        moe_present = [k for k in MOE_KEYS if k in row]
+        if moe_present and len(moe_present) != len(MOE_KEYS):
+            errors.append(
+                f"line {i}: partial expert-routing key set {moe_present} "
+                f"(expected all of {list(MOE_KEYS)} together)"
+            )
+        for key in moe_present:
+            value = row[key]
+            low = 1.0 if key == "moe_load_max_over_mean" else 0.0
+            if _is_number(value) and value < low:
+                errors.append(
+                    f"line {i}: expert-routing key {key!r} is below "
+                    f"{low}: {value!r}"
                 )
         for key, value in row.items():
             if not (_is_number(value) and value < 0):

@@ -16,6 +16,7 @@ cannot be read back without one, and the next run would warn.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -99,6 +100,45 @@ def test_pallas_kernel_compiles_for_v5e(v5e, fn, shapes, n_kernels):
     compiled = jax.jit(fn).lower(*args).compile()
     # Mosaic compiled the kernels (interpret mode leaves no custom call).
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+@pytest.mark.parametrize(
+    "dtype, n_kernels", [(jnp.bfloat16, 9), (jnp.float32, 9)], ids=["bf16", "f32"]
+)
+def test_topk_expert_layer_compiles_for_v5e(v5e, monkeypatch, dtype, n_kernels):
+    """The exact top-k expert layer at OLMoE's widths (64 experts of
+    2048 x 1024, 8 per token) on 4,096 tokens, forward and backward: the
+    grouped products are Mosaic kernels (three forward, six backward),
+    in bf16 as ``olmoe_train`` runs them and in float32 as the comparison
+    with the reference does, and every one keeps the ``moe_experts``
+    scope in its ``op_name`` (what the per-layer readers find it by)."""
+    from distributed_tensorflow_models_tpu.parallel import moe as moelib
+
+    # The layer asks the backend whether to interpret its kernels; here
+    # the CPU compiles for a described chip, so the test says "tpu".
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    params = {
+        "router": spec(2048, 64), "w_gate": spec(64, 2048, 1024),
+        "w_up": spec(64, 2048, 1024), "w_down": spec(64, 1024, 2048),
+    }
+
+    def loss(p, x):
+        out = moelib.topk_moe_ffn(p, x, top_k=8, dtype=dtype)
+        return jnp.sum(out.out.astype(jnp.float32)) + out.aux_loss + out.z_loss
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, jax.ShapeDtypeStruct((1, 4096, 2048), dtype, sharding=one_chip)
+    ).compile()
+    kernels = [
+        line for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == n_kernels
+    # A whole path element, bare or inside a transform's brackets.
+    assert all(re.search(r"[/(]moe_experts[/)]", line) for line in kernels)
+    assert all(re.search(r"[/(]moe[/)]", line) for line in kernels)
 
 
 def test_data_parallel_step_compiles_over_four_chips(v5e):
