@@ -84,7 +84,12 @@ SERVE_REQUESTS = (
 
 # (B, T, H, D) flash-attention shapes; one ResNet-50 3x3 conv class at
 # the published per-chip batch.
-FLASH_SHAPES = ((16, 512, 8, 64), (4, 2048, 8, 64))
+# The last two are the token cells' attention shapes (``gpt2m_train``;
+# ``olmoe_train`` at one sequence of its four, so that the materialized
+# f32 reference and its gradients fit the chip).
+FLASH_SHAPES = (
+    (16, 512, 8, 64), (4, 2048, 8, 64), (8, 1024, 16, 64), (1, 4096, 16, 128),
+)
 CONV_SHAPE = dict(batch=256, size=56, cin=64, cout=64)
 # bf16 inputs and outputs against an f32 reference: errors are compared
 # to the reference's largest magnitude.
@@ -565,6 +570,17 @@ def phase_kernels(
             ),
             lambda q, k, v: attnlib.reference_attention(q, k, v, causal=True),
             [bf16(*shape, scale=0.5) for _ in range(3)],
+            report,
+        )
+        # What ``attention(impl="auto")`` runs here: the fused kernels.
+        qkv = [bf16(*shape, scale=0.5) for _ in range(3)]
+        if attnlib.auto_route(*qkv) != "fused":
+            raise AssertionError(f"auto does not choose fused at {shape}")
+        _check_kernel(
+            "auto_attention_" + "x".join(map(str, shape)),
+            lambda q, k, v: attnlib.attention(q, k, v, causal=True),
+            lambda q, k, v: attnlib.reference_attention(q, k, v, causal=True),
+            qkv,
             report,
         )
     b, s = conv_shape["batch"], conv_shape["size"]

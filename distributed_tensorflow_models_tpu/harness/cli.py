@@ -77,7 +77,7 @@ def _add_common(p: argparse.ArgumentParser) -> None:
         "--attn-impl",
         choices=("auto", "reference", "blockwise", "flash"),
         default=None,
-        help="attention kernel (auto = Pallas flash on TPU)",
+        help="attention kernel (auto = fused kernels on a TPU, else blockwise)",
     )
     p.add_argument(
         "--fused-unembed", action=argparse.BooleanOptionalAction,

@@ -451,6 +451,9 @@ def fit(
     # declared-coverage check rightly treats absence as a failure.
     registry.counter(telemetry.ROLLBACKS)
     registry.counter(telemetry.SKIPPED_BATCHES)
+    # The attention op counts its route choices in the process-global
+    # registry, at trace time; the report gets what this run traced.
+    routes0 = _attention_routes()
     # Structured event tracing + flight recorder (telemetry/trace.py,
     # README "Observability"): the run's tracer rides the registry, so
     # every component the registry already reaches (pipeline, step,
@@ -1211,7 +1214,9 @@ def fit(
         # trace gauges land before the goodput report snapshots them.
         _final_dump("crash")
         _export_trace(workdir, registry, cfg, step_fn)
-        _write_telemetry_report(workdir, registry, t_run0, steps_run)
+        _write_telemetry_report(
+            workdir, registry, t_run0, steps_run, routes0
+        )
         raise
     else:
         # One hook's end() failing (e.g. a writer's close hitting ENOSPC)
@@ -1238,7 +1243,9 @@ def fit(
             "fit/end", {"steps_run": steps_run, "preempted": preempted}
         )
         _export_trace(workdir, registry, cfg, step_fn)
-        _write_telemetry_report(workdir, registry, t_run0, steps_run)
+        _write_telemetry_report(
+            workdir, registry, t_run0, steps_run, routes0
+        )
         if chaos is not None and not preempted:
             # A drill whose fault never injected must not exit 0 looking
             # like a passed drill (a preempted run legitimately leaves
@@ -1321,14 +1328,28 @@ def _export_trace(
         log.exception("trace export failed")
 
 
+def _attention_routes() -> dict[str, float]:
+    """``attention(impl="auto")``'s route counters, as the process-global
+    registry holds them now."""
+    shared = telemetry.get_registry()
+    return {
+        name: shared.counter(name).value
+        for name in (
+            telemetry.ATTN_ROUTE_FUSED, telemetry.ATTN_ROUTE_BLOCKWISE
+        )
+    }
+
+
 def _write_telemetry_report(
     workdir: str, registry: telemetry.MetricsRegistry,
-    t_run0: float, steps_run: int,
+    t_run0: float, steps_run: int, routes0: dict[str, float],
 ) -> None:
     """Chief-only, best-effort ``telemetry.json`` goodput report."""
     if jax.process_index() != 0:
         return
     try:
+        for name, count in _attention_routes().items():
+            registry.counter(name).inc(count - routes0[name])
         report = telemetry.goodput_report(
             registry, total_s=time.perf_counter() - t_run0, steps=steps_run
         )

@@ -1,8 +1,9 @@
-"""Attention ops: reference, blockwise (memory-efficient), and Pallas flash.
+"""Attention ops: reference, blockwise (memory-efficient), Pallas flash and
+the fused kernels that ``attention(impl="auto")`` runs on a TPU.
 
 The reference framework predates attention entirely (its only sequence model
 is the PTB LSTM, SURVEY.md §2.1 R8) — this module is part of the framework's
-long-context mandate: scaled-dot-product attention implemented three ways,
+long-context mandate: scaled-dot-product attention implemented four ways,
 all sharing one API so models and the sequence-parallel layer
 (:mod:`...parallel.ring`) can pick per backend:
 
@@ -11,7 +12,8 @@ all sharing one API so models and the sequence-parallel layer
 - :func:`blockwise_attention` — ``lax.scan`` over KV blocks with running
   (max, sum, acc) renormalization (Rabe & Staats / FlashAttention
   recurrence).  O(T·block) memory, differentiable end-to-end (scan is
-  reverse-AD-able), runs on any backend; the training default.
+  reverse-AD-able), runs on any backend; the training default off the
+  chip and for the calls the fused kernels do not take.
 - :func:`flash_attention` — the same recurrence as a Pallas TPU kernel:
   one grid step per (batch·head, q-block), KV loop innermost with the
   softmax state in VMEM scratch, causal blocks skipped.  Matmuls in the
@@ -20,7 +22,12 @@ all sharing one API so models and the sequence-parallel layer
   FlashAttention-2 backward as a Pallas kernel pair (dK/dV with the Q
   sweep innermost, dQ with the KV sweep innermost), rebuilding the
   probabilities from the forward's saved log-sum-exp — O(T·block) memory
-  in both passes.
+  in both passes.  Named by ``impl="flash"``; the ring path's chunk
+  kernels share its code.
+- :func:`fused_attention` — that recurrence as two kernels shaped by a
+  chip measurement (PERF.md, PR 26): lane-filling column blocks of
+  ``[B, T, H*D]``, only the block pairs a causal mask leaves, one
+  backward kernel.  What ``auto`` runs on a TPU.
 
 Layout convention everywhere: ``[batch, seq, heads, head_dim]`` (BTHD).
 """
@@ -34,7 +41,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from distributed_tensorflow_models_tpu.telemetry.registry import (
+    ATTN_ROUTE_BLOCKWISE,
+    ATTN_ROUTE_FUSED,
+    get_registry,
+)
 
 NEG_INF = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked rows
 
@@ -1296,6 +1310,459 @@ def _flash_chunk_bwd(
 flash_attention_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 
 
+# ------------------------------------------------------- fused (auto on TPU)
+#
+# What ``attention(impl="auto")`` runs on the chip: one forward kernel and
+# one backward kernel whose score tiles never leave VMEM.  They differ from
+# the flash pair above in what the chip measurement of PERF.md (PR 26)
+# asked for:
+#
+# - operands stay ``[B, T, H*D]`` (a free reshape of BTHD) and a grid step
+#   takes a 128-lane column block of it: one head at D=128, two at D=64, so
+#   every tile fills the lanes and no ``BTHD <-> (B*H, T, D)`` copy exists;
+#   at D=64 a head is picked out of the pair by zeroing the other head's
+#   lanes of one matmul operand (exact: the zeros add nothing to the f32
+#   sums, and a 64-deep contraction fills the MXU no better) and the
+#   products are joined by a lane select;
+# - the grid's last axis walks a static list of (q block, kv block) pairs,
+#   prefetched as scalars: the blocks a causal mask rules out are not grid
+#   steps at all, so nothing is fetched or skipped for them;
+# - the backward is ONE kernel in the transposed orientation
+#   (S^T = K Q^T, so LSE and delta are lane-dense rows and dV, dK are plain
+#   products): five matmuls a pair where the dKV/dQ pair needs seven, dQ
+#   accumulated in a VMEM-resident ``[T, 128]`` f32 buffer per head block;
+# - tiles of 512 (measured; the flash pair's backward runs at 128).
+#
+# Same mathematics as ``_block_update``/``_masked_scores``: scores from the
+# input dtype with f32 accumulation, the scale in f32 after the product,
+# (m, l, acc) in f32, P cast to the value dtype for P.V, exact exp and
+# division.  f32 inputs keep f32 products.
+
+_LANES = 128
+_FUSED_TILES = (512, 256, 128)  # largest the length divides, measured first
+_FUSED_VMEM_BYTES = 64 * 1024 * 1024  # of v5e's 128 MiB; tiles need ~10 MiB
+
+
+def _fused_tile(T: int) -> Optional[int]:
+    return next((t for t in _FUSED_TILES if T % t == 0), None)
+
+
+def fused_admissible(
+    q, k, v, *, window: Optional[int] = None,
+    q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
+) -> bool:
+    """Whether the fused kernels take this call: self-attention shapes
+    (no grouped KV heads), head size 64 (an even number of heads) or 128,
+    a length some tile divides, no sliding window, and offsets that are
+    the static zeros of self-attention.  Everything here is visible at
+    trace time; the backend is the caller's question."""
+    if window is not None:
+        return False
+    if not (isinstance(q_offset, int) and isinstance(kv_offset, int)):
+        return False
+    if q_offset != 0 or kv_offset != 0:
+        return False
+    if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
+        return False
+    B, T, H, D = q.shape
+    if D not in (64, _LANES) or (H * D) % _LANES:
+        return False
+    return _fused_tile(T) is not None
+
+
+def _fused_pairs(n_q, n_kv, block_q, block_kv, causal, kv_major):
+    """The (q block, kv block) pairs in which some query sees some key,
+    as two int32 vectors: q-major for the forward (a q block's kv blocks
+    are consecutive, ascending), kv-major for the backward."""
+    pairs = [
+        (i, j) for i in range(n_q) for j in range(n_kv)
+        if not causal or (i + 1) * block_q - 1 >= j * block_kv
+    ]
+    if kv_major:
+        pairs.sort(key=lambda ij: (ij[1], ij[0]))
+    return (
+        np.asarray([i for i, _ in pairs], np.int32),
+        np.asarray([j for _, j in pairs], np.int32),
+    )
+
+
+def _head_lanes(rows: int, D: int, a: int):
+    """Lanes of head ``a`` in a 128-lane block that holds 128/D heads."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    return (lane >= a * D) & (lane < (a + 1) * D)
+
+
+def _join_heads(parts, D):
+    """Per-head ``[rows, 128]`` results, each valid in its own head's
+    lanes, as one block."""
+    out = parts[0]
+    for a in range(1, len(parts)):
+        out = jnp.where(_head_lanes(out.shape[0], D, a), parts[a], out)
+    return out
+
+
+def _fused_fwd_kernel(
+    i_ref, j_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+    *, scale, causal, block_q, block_kv, head_dim, n_kv,
+):
+    """Grid (B, H*D/128, pairs).  ``m_scr``/``l_scr`` hold one
+    lane-replicated ``[block_q, 128]`` row statistic per head of the
+    block; LSE leaves as lane-dense rows ``[heads, block_q]``."""
+    import jax.experimental.pallas as pl
+
+    hp = _LANES // head_dim
+    p_idx = pl.program_id(2)
+    i, j = i_ref[p_idx], j_ref[p_idx]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _step(apply_mask):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        alphas, pvs = [], []
+        for a in range(hp):
+            qa = q
+            if hp > 1:
+                qa = jnp.where(_head_lanes(block_q, head_dim, a), q, 0)
+            s = _masked_scores(
+                qa, k, i, j, 0, 0, scale=scale, causal=causal,
+                block_q=block_q, block_kv=block_kv, apply_mask=apply_mask,
+            )  # [bq, bkv] f32
+            m_prev, l_prev = m_scr[a], l_scr[a]  # [bq, 128], replicated
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - jnp.tile(m_new, (1, block_kv // _LANES)))
+            l_scr[a] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[a] = m_new
+            alphas.append(alpha)
+            pvs.append(jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))  # [bq, 128]; head a's lanes are its P.V
+        acc_scr[...] = (
+            _join_heads(alphas, head_dim) * acc_scr[...]
+            + _join_heads(pvs, head_dim)
+        )
+
+    _dispatch_masked(
+        pl, _step, True, i, j, 0, 0,
+        causal=causal, block_q=block_q, block_kv=block_kv,
+    )
+    j_last = n_kv - 1
+    if causal:
+        j_last = jnp.minimum(j_last, ((i + 1) * block_q - 1) // block_kv)
+
+    @pl.when(j == j_last)
+    def _finish():
+        ls = [jnp.maximum(l_scr[a], 1e-30) for a in range(hp)]
+        o_ref[0] = (acc_scr[...] / _join_heads(ls, head_dim)).astype(
+            o_ref.dtype
+        )
+        for a in range(hp):
+            lse = m_scr[a] + jnp.log(ls[a])  # [bq, 128], replicated
+            lse_ref[0, 0, a:a + 1, :] = lse.T[:1, :]
+
+
+def _fused_bwd_kernel(
+    i_ref, j_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+    *, scale, causal, block_q, block_kv, head_dim, n_q, n_pairs,
+):
+    """Grid (B, H*D/128, pairs), kv-major.  Transposed orientation:
+      S^T = K Q^T * scale,  P^T = exp(S^T - LSE),  dP^T = V dO^T,
+      dS^T = P^T o (dP^T - delta),
+      dV_j += P^T dO,  dK_j += dS^T Q,  dQ_i += dS K   (scale at the end).
+    dK/dV accumulate over a kv block's q sweep; dQ over the whole pair
+    list, in ``dq_scr`` ``[T, 128]``."""
+    import jax.experimental.pallas as pl
+
+    hp = _LANES // head_dim
+    p_idx = pl.program_id(2)
+    i, j = i_ref[p_idx], j_ref[p_idx]
+    i_first = (j * block_kv) // block_q if causal else 0
+
+    @pl.when(p_idx == 0)
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(i == i_first)
+    def _init_dkv():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def _step(apply_mask):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        lse, delta = lse_ref[0, 0], delta_ref[0, 0]  # [hp, bq]
+        dvs, dks, dqs = [], [], []
+        for a in range(hp):
+            ka, va = k, v
+            if hp > 1:
+                mine = _head_lanes(block_kv, head_dim, a)
+                ka, va = jnp.where(mine, k, 0), jnp.where(mine, v, 0)
+            st = jax.lax.dot_general(
+                ka, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [bkv, bq] f32
+            if causal and apply_mask:
+                kj = j * block_kv + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 0
+                )
+                qi = i * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1
+                )
+                st = jnp.where(qi >= kj, st, NEG_INF)
+            pt = jnp.exp(st - lse[a:a + 1, :])
+            dpt = jax.lax.dot_general(
+                va, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dst = pt * (dpt - delta[a:a + 1, :])
+            dvs.append(jax.lax.dot_general(
+                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))  # [bkv, 128]
+            dks.append(jax.lax.dot_general(
+                dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))  # [bkv, 128]
+            dqs.append(jax.lax.dot_general(
+                dst.T.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))  # [bq, 128]
+        dv_scr[...] += _join_heads(dvs, head_dim)
+        dk_scr[...] += _join_heads(dks, head_dim)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_scr[rows, :] += _join_heads(dqs, head_dim)
+
+    _dispatch_masked(
+        pl, _step, True, i, j, 0, 0,
+        causal=causal, block_q=block_q, block_kv=block_kv,
+    )
+
+    @pl.when(i == n_q - 1)
+    def _finish_dkv():
+        dk_ref[0] = (scale * dk_scr[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(p_idx == n_pairs - 1)
+    def _finish_dq():
+        dq_ref[0] = (scale * dq_scr[...]).astype(dq_ref.dtype)
+
+
+def _fused_geometry(q, block_q, block_kv):
+    B, T, H, D = q.shape
+    tile = _fused_tile(T)
+    block_q = block_q if block_q is not None else tile
+    block_kv = block_kv if block_kv is not None else tile
+    if (
+        block_q is None or T % block_q or T % block_kv
+        or block_kv % _LANES or block_q % _LANES
+    ):
+        raise ValueError(
+            f"fused attention: length {T} against tiles "
+            f"({block_q}, {block_kv}); tiles are multiples of {_LANES} "
+            "that divide the length"
+        )
+    hp = _LANES // D
+    return B, T, H, D, hp, (H * D) // _LANES, block_q, block_kv
+
+
+def _vma(x):
+    """The varying mesh axes of ``x`` inside ``shard_map`` (empty outside):
+    a ``pallas_call``'s outputs take theirs from ``out_shape``."""
+    return getattr(jax.typeof(x), "vma", None) or frozenset()
+
+
+def _fused_specs(pl, block_q, block_kv, hp):
+    """Block specs over the grid (batch, head block, pair), the pair's
+    (q block, kv block) read from the two prefetched vectors: a
+    ``[block_q, 128]`` tile of a ``[B, T, H*D]`` operand, the same for
+    keys, and the ``[heads, block_q]`` rows of LSE or delta."""
+
+    def at(shape, index):
+        return pl.BlockSpec(
+            shape, lambda b, h, p, ii, jj: index(b, h, ii[p], jj[p])
+        )
+
+    return (
+        at((1, block_q, _LANES), lambda b, h, i, j: (b, i, h)),
+        at((1, block_kv, _LANES), lambda b, h, i, j: (b, j, h)),
+        at((1, 1, hp, block_q), lambda b, h, i, j: (b, h, 0, i)),
+    )
+
+
+def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
+    """Returns ``(out [B,T,H,D], lse [B, H*D/128, 128/D, T] f32)``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, D, hp, HB, block_q, block_kv = _fused_geometry(
+        q, block_q, block_kv
+    )
+    n_q, n_kv = T // block_q, T // block_kv
+    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, False)
+    flat = lambda x: x.reshape(B, T, H * D)
+    qspec, kvspec, rowspec = _fused_specs(pl, block_q, block_kv, hp)
+    vma = _vma(q)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fused_fwd_kernel, scale=_scale(q, scale), causal=causal,
+            block_q=block_q, block_kv=block_kv, head_dim=D, n_kv=n_kv,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, HB, len(i_idx)),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=[qspec, rowspec],
+            scratch_shapes=[
+                pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, T, H * D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, HB, hp, T), jnp.float32, vma=vma),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(i_idx, j_idx, flat(q), flat(k), flat(v))
+    return out.reshape(B, T, H, D), lse
+
+
+def _fused_backward(
+    q, k, v, out, lse, g, *, causal, scale, block_q, block_kv, interpret
+):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, D, hp, HB, block_q, block_kv = _fused_geometry(
+        q, block_q, block_kv
+    )
+    n_q, n_kv = T // block_q, T // block_kv
+    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, True)
+    flat = lambda x: x.reshape(B, T, H * D)
+    # delta_i = rowsum(dO o O), as lane-dense rows beside the LSE's.
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    )  # [B, T, H]
+    delta = jnp.swapaxes(delta, 1, 2).reshape(B, HB, hp, T)
+    qspec, kvspec, rowspec = _fused_specs(pl, block_q, block_kv, hp)
+    vma = _vma(q)
+    shape = jax.ShapeDtypeStruct((B, T, H * D), q.dtype, vma=vma)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _fused_bwd_kernel, scale=_scale(q, scale), causal=causal,
+            block_q=block_q, block_kv=block_kv, head_dim=D, n_q=n_q,
+            n_pairs=len(i_idx),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, HB, len(i_idx)),
+            in_specs=[qspec, kvspec, kvspec, qspec, rowspec, rowspec],
+            out_specs=[
+                # dQ of the whole head block stays resident; written once.
+                pl.BlockSpec(
+                    (1, T, _LANES), lambda b, h, p, ii, jj: (b, 0, h)
+                ),
+                kvspec,
+                kvspec,
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((T, _LANES), jnp.float32),
+                pltpu.VMEM((block_kv, _LANES), jnp.float32),
+                pltpu.VMEM((block_kv, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[shape, shape, shape],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(i_idx, j_idx, flat(q), flat(k), flat(v), flat(g), lse, delta)
+    unflat = lambda x: x.reshape(B, T, H, D)
+    return unflat(dq), unflat(dk), unflat(dv)
+
+
+def _mosaic_can_lower() -> bool:
+    """Whether a Mosaic kernel traced here will lower: such a kernel
+    cannot be partitioned automatically, so its program has to span one
+    device, or the call has to sit inside a ``shard_map`` that makes every
+    mesh axis manual (Ulysses, the pipeline stages).  Under plain ``jit``
+    the devices a program will span are its arguments' and not visible at
+    trace time; what is visible is a process that has only one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return set(mesh.manual_axes) == set(mesh.axis_names)
+
+
+def auto_route(
+    q, k, v, *, window: Optional[int] = None,
+    q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
+) -> str:
+    """What ``attention(impl="auto")`` runs for this call: ``"fused"`` on
+    a TPU for the calls the fused kernels admit, where a Mosaic kernel
+    can lower; else ``"blockwise"``."""
+    if (
+        jax.default_backend() == "tpu"
+        and fused_admissible(
+            q, k, v, window=window, q_offset=q_offset, kv_offset=kv_offset
+        )
+        and _mosaic_can_lower()
+    ):
+        return "fused"
+    return "blockwise"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def fused_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    causal: bool = False,
+    scale: Optional[float] = None,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """The fused self-attention kernels (see the section comment), BTHD
+    in and out; what ``attention(impl="auto")`` runs on a TPU for the
+    calls :func:`fused_admissible` admits.  ``None`` tiles resolve to the
+    largest of 512/256/128 the length divides; ``interpret=True`` runs
+    the same kernels on the CPU for tests."""
+    return _fused_forward(
+        q, k, v, causal=causal, scale=scale, block_q=block_q,
+        block_kv=block_kv, interpret=interpret,
+    )[0]
+
+
+def _fused_fwd(q, k, v, causal, scale, block_q, block_kv, interpret):
+    out, lse = _fused_forward(
+        q, k, v, causal=causal, scale=scale, block_q=block_q,
+        block_kv=block_kv, interpret=interpret,
+    )
+    return out, (q, k, v, out, lse)
+
+
+def _fused_bwd(causal, scale, block_q, block_kv, interpret, res, g):
+    q, k, v, out, lse = res
+    return _fused_backward(
+        q, k, v, out, lse, g, causal=causal, scale=scale, block_q=block_q,
+        block_kv=block_kv, interpret=interpret,
+    )
+
+
+fused_attention.defvjp(_fused_fwd, _fused_bwd)
+
+
 # Scope, not module: the core is a function, so flax names no part of it.
 # Every route (reference, blockwise, flash) sits under the one name; the
 # q/k/v/out projections stay outside.
@@ -1313,12 +1780,24 @@ def attention(
     """Dispatching entry point: ``impl`` in {auto, reference, blockwise,
     flash}.
 
-    ``auto`` routes to BLOCKWISE on every backend (builder reading from
-    an earlier round, not re-measured).  The Pallas kernels stay
-    first-class via ``impl="flash"`` (and the ring path's fused chunk
-    kernels)."""
+    ``auto`` chooses from what the call can observe, at trace time: on a
+    TPU, a call that :func:`fused_admissible` admits (self-attention
+    shapes, head size 64 or 128, a length 128 divides, no window) runs
+    the fused kernels (:func:`fused_attention`; measured on the chip,
+    PERF.md PR 26); every other call (the CPU, odd lengths, a sliding
+    window, grouped KV heads, and a ``jit`` over several devices outside
+    ``shard_map``, where a Mosaic kernel cannot be partitioned) runs
+    :func:`blockwise_attention` exactly as before.  The choice is counted once per traced call
+    (``attention/route_fused`` / ``attention/route_blockwise``).  A named
+    ``impl`` means what it says; the tree's older Pallas pair stays
+    ``impl="flash"`` (and the ring path's chunk kernels)."""
     if impl == "auto":
-        impl = "blockwise"
+        impl = auto_route(q, k, v, window=window)
+        get_registry().counter(
+            ATTN_ROUTE_FUSED if impl == "fused" else ATTN_ROUTE_BLOCKWISE
+        ).inc()
+        if impl == "fused":
+            return fused_attention(q, k, v, causal, scale)
     if impl == "reference":
         return reference_attention(
             q, k, v, causal=causal, scale=scale, window=window

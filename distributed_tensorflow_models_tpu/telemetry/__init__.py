@@ -39,6 +39,8 @@ durations, ``harness/hooks.py::TelemetryHook`` snapshots everything into
 
 from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     ASSEMBLE,
+    ATTN_ROUTE_BLOCKWISE,
+    ATTN_ROUTE_FUSED,
     BUFFER_FRESH,
     BUFFER_REUSED,
     CHAOS_ARMED_UNFIRED,

@@ -68,6 +68,14 @@ PIPELINE_BYTES = "pipeline/bytes"  # counter: bytes placed on the mesh
 # arrays may alias host memory there, so nothing is ever released).
 BUFFER_REUSED = "pipeline/buffer_reused"  # counter
 BUFFER_FRESH = "pipeline/buffer_fresh"  # counter
+# The route ``ops/attention.py::attention(impl="auto")`` chose, one
+# increment per traced call (the choice is made at trace time, so a step
+# program of 24 layers counts 24 once, not per step): the fused kernels
+# on a TPU for the calls they admit, blockwise everywhere else.  Counted
+# in the process-global registry (the op has no other); ``fit`` copies
+# what its own run traced into ``telemetry.json``.
+ATTN_ROUTE_FUSED = "attention/route_fused"  # counter
+ATTN_ROUTE_BLOCKWISE = "attention/route_blockwise"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

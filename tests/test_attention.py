@@ -7,10 +7,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
 from distributed_tensorflow_models_tpu.core import mesh as meshlib
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.parallel import ring
+from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
 
 def _qkv(B=2, T=128, H=4, D=32, seed=0):
@@ -833,12 +836,184 @@ class TestBlockwiseQChunked:
         np.testing.assert_array_equal(chunked, base)
 
 
-def test_auto_impl_is_blockwise():
-    """auto == blockwise bit-for-bit; flash stays opt-in."""
-    q, k, v = _qkv(T=256)
-    a = attnlib.attention(q, k, v, causal=True, impl="auto")
-    b = attnlib.attention(q, k, v, causal=True, impl="blockwise")
-    assert jnp.array_equal(a, b)
+def _route_counts():
+    reg = reglib.get_registry()
+    return (
+        reg.counter(reglib.ATTN_ROUTE_FUSED).value,
+        reg.counter(reglib.ATTN_ROUTE_BLOCKWISE).value,
+    )
+
+
+@pytest.mark.parametrize(
+    "backend, devices, shape, kv_heads, window, traced_offset, want",
+    [
+        # Off the chip every call keeps the scan.
+        ("cpu", 1, (2, 256, 4, 64), 4, None, False, "blockwise"),
+        ("cpu", 8, (1, 1024, 16, 64), 16, None, False, "blockwise"),
+        # Described as one TPU: the two cells' shapes and a small one.
+        ("tpu", 1, (1, 1024, 16, 64), 16, None, False, "fused"),
+        ("tpu", 1, (1, 4096, 16, 128), 16, None, False, "fused"),
+        ("tpu", 1, (2, 256, 4, 64), 4, None, False, "fused"),
+        # ... and what the kernels do not take.
+        ("tpu", 1, (2, 200, 4, 64), 4, None, False, "blockwise"),  # 128 does not divide
+        ("tpu", 1, (2, 256, 4, 64), 4, None, True, "blockwise"),  # traced offsets
+        ("tpu", 1, (2, 256, 4, 64), 4, 64, False, "blockwise"),  # sliding window
+        ("tpu", 1, (2, 256, 4, 64), 2, None, False, "blockwise"),  # grouped KV heads
+        ("tpu", 1, (2, 256, 3, 64), 3, None, False, "blockwise"),  # half a lane block
+        ("tpu", 1, (2, 256, 4, 32), 4, None, False, "blockwise"),  # head size
+        # A jit over several devices cannot partition a Mosaic kernel.
+        ("tpu", 4, (1, 1024, 16, 64), 16, None, False, "blockwise"),
+    ],
+)
+def test_auto_route(
+    monkeypatch, backend, devices, shape, kv_heads, window, traced_offset,
+    want,
+):
+    """``impl="auto"`` chooses from the backend and what the call shows at
+    trace time, counts the choice once per traced call, and off the chip
+    is blockwise bit for bit."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    B, T, H, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, T, kv_heads, D), jnp.bfloat16)
+    if traced_offset:
+        # attention() is self-attention (offsets are the static zeros);
+        # the rule itself refuses an offset it cannot read.
+        route = []
+        jax.eval_shape(
+            lambda off: route.append(
+                attnlib.auto_route(q, kv, kv, q_offset=off)
+            ),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        )
+        assert route == [want]
+        assert attnlib.auto_route(q, kv, kv, q_offset=128) == want
+        return
+    assert attnlib.auto_route(q, kv, kv, window=window) == want
+    fused0, blockwise0 = _route_counts()
+    # Traced, not run: a Mosaic kernel cannot run here.
+    out = jax.eval_shape(
+        lambda q, k, v: attnlib.attention(
+            q, k, v, causal=True, window=window
+        ),
+        q, kv, kv,
+    )
+    assert out.shape == shape and out.dtype == jnp.bfloat16
+    fused1, blockwise1 = _route_counts()
+    assert (fused1 - fused0, blockwise1 - blockwise0) == (
+        (1, 0) if want == "fused" else (0, 1)
+    )
+    if backend == "cpu" and T <= 256:
+        qa, ka, va = _qkv(B=B, T=T, H=H, D=D)
+        a = attnlib.attention(qa, ka, va, causal=True, impl="auto")
+        b = attnlib.attention(qa, ka, va, causal=True, impl="blockwise")
+        assert jnp.array_equal(a, b)
+
+
+@pytest.mark.parametrize(
+    "manual, want", [(("data", "seq"), "fused"), (("seq",), "blockwise")]
+)
+def test_auto_route_inside_shard_map(monkeypatch, manual, want):
+    """Several devices: a Mosaic kernel lowers only where every mesh axis
+    is manual (Ulysses, the pipeline stages), and there ``auto`` takes it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "seq"))
+    q = jnp.zeros((2, 256, 4, 64), jnp.bfloat16)
+    routes = []
+
+    def local(q):
+        routes.append(attnlib.auto_route(q, q, q))
+        return q
+
+    spec = P(*(a if a in manual else None for a in ("data", "seq")))
+    jax.eval_shape(
+        jax.shard_map(
+            local, mesh=mesh, in_specs=spec, out_specs=spec,
+            axis_names=set(manual),
+        ),
+        q,
+    )
+    assert routes == [want]
+
+
+def test_named_impl_is_not_counted():
+    """Only ``auto`` chooses, so only ``auto`` counts."""
+    q, k, v = _qkv(T=128)
+    before = _route_counts()
+    attnlib.attention(q, k, v, causal=True, impl="blockwise")
+    attnlib.attention(q, k, v, causal=True, impl="reference")
+    assert _route_counts() == before
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads, head_dim", [(4, 64), (2, 128)])
+def test_fused_matches_reference(heads, head_dim, dtype, causal):
+    """The kernels ``auto`` runs on the chip, in interpret mode: values
+    and all three gradients against the materialized reference, at both
+    lane packings (two heads of 64 a block, one of 128), over several
+    tiles so that interior, diagonal and skipped block pairs all occur.
+    f32 tight; bf16 at the tolerances the blockwise route is held to."""
+    q, k, v = _qkv(B=2, T=384, H=heads, D=head_dim, seed=3)
+    w = _qkv(B=2, T=384, H=heads, D=head_dim, seed=4)[0]
+    cast = lambda x: x.astype(dtype)
+
+    def grads(fn, *args):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
+            argnums=(0, 1, 2),
+        )(*args)
+
+    ref = attnlib.reference_attention(q, k, v, causal=causal)
+    _, g_ref = grads(
+        lambda q, k, v: attnlib.reference_attention(q, k, v, causal=causal),
+        q, k, v,
+    )
+    fused = lambda q, k, v: attnlib.fused_attention(
+        q, k, v, causal, None, 128, 128, True  # tiles, interpret
+    )
+    out = fused(cast(q), cast(k), cast(v))
+    assert out.dtype == jnp.dtype(dtype)
+    _, g = grads(fused, cast(q), cast(k), cast(v))
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == "float32" else dict(
+        rtol=3e-2, atol=3e-2
+    )
+    np.testing.assert_allclose(np.asarray(out, np.float32), ref, **tol)
+    gtol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" else dict(
+        rtol=5e-2, atol=5e-2
+    )
+    for got, want in zip(g, g_ref):
+        assert got.dtype == jnp.dtype(dtype)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want, **gtol)
+
+
+@pytest.mark.parametrize("block_q, block_kv", [(128, 256), (256, 128)])
+def test_fused_uneven_tiles(block_q, block_kv):
+    """Query and key tiles of different size: the pair list, the mask's
+    boundary test and the first/last-block tests are all in global
+    positions."""
+    q, k, v = _qkv(B=1, T=512, H=2, D=64, seed=5)
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+    ref = attnlib.reference_attention(q, k, v, causal=True)
+    fused = lambda q, k, v: attnlib.fused_attention(
+        q, k, v, True, None, block_q, block_kv, True
+    )
+    np.testing.assert_allclose(fused(q, k, v), ref, rtol=2e-5, atol=2e-5)
+    g = jax.grad(loss(fused), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(
+        loss(lambda q, k, v: attnlib.reference_attention(q, k, v, causal=True)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for got, want in zip(g, g_ref):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_fused_refuses_what_it_cannot_tile():
+    q, k, v = _qkv(T=200, H=4, D=64)
+    assert not attnlib.fused_admissible(q, k, v)
+    with pytest.raises(ValueError, match="fused attention"):
+        attnlib.fused_attention(q, k, v, True, None, None, None, True)
 
 
 def test_flash_tile_env_validated(monkeypatch):

@@ -141,6 +141,39 @@ def test_topk_expert_layer_compiles_for_v5e(v5e, monkeypatch, dtype, n_kernels):
     assert all(re.search(r"[/(]moe[/)]", line) for line in kernels)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize(
+    "shape", [(1, 1024, 16, 64), (1, 4096, 16, 128)], ids=["gpt2m", "olmoe"]
+)
+def test_auto_attention_compiles_fused_for_v5e(v5e, monkeypatch, shape, dtype):
+    """``attention(impl="auto")`` at the two token cells' shapes, forward
+    and backward, for the described chip: two Mosaic kernels (the forward
+    and the one backward kernel), both under the ``attention_core`` scope
+    the per-layer readers find them by, and no ``while`` left of the
+    blockwise scan; in bf16 as the cells run it and in float32 as the
+    comparison with the reference does."""
+    # ``auto`` asks the backend and the process's device count; here the
+    # CPU compiles for one described chip, so the test says so.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * 3
+
+    def loss(q, k, v):
+        out = attnlib.attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == 2
+    assert all(re.search(r"[/(]attention_core[/)]", line) for line in kernels)
+    assert sum("transpose(" in line for line in kernels) == 1
+    assert not re.search(r"\bwhile\(", text)
+
+
 def test_data_parallel_step_compiles_over_four_chips(v5e):
     """A small conv model's donated train step over a 4-device mesh of
     the described chips: the batch is split, the parameters replicated,
