@@ -61,7 +61,7 @@ TRAIN_STEPS = 60
 TRAIN_LOG_EVERY = 10
 TRAIN_CKPT_EVERY = 30
 
-# The widest LM the repo defines for serving (bench.py::run_serving).
+# The serve phase's LM: two layers at d_model 640, d_ff 8192.
 SERVE_MODEL = dict(
     vocab_size=256, num_layers=2, num_heads=4, d_model=640, d_ff=8192
 )
@@ -561,13 +561,14 @@ def phase_kernels(
 
     report: dict = {}
     for shape in flash_shapes:
+        # The ring's chunk kernels at offsets 0 (self-attention).
+        # Positional: (q, k, v, q_offset, kv_offset, causal, scale,
+        # block_q, block_kv, interpret) — interpret=False is the point.
         _check_kernel(
-            "flash_attention_" + "x".join(map(str, shape)),
-            # Positional: (q, k, v, causal, scale, block_q, block_kv,
-            # interpret) — interpret=False is the point.
-            lambda q, k, v: attnlib.flash_attention(
-                q, k, v, True, None, None, None, False
-            ),
+            "flash_attention_chunk_" + "x".join(map(str, shape)),
+            lambda q, k, v: attnlib.flash_attention_chunk(
+                q, k, v, 0, 0, True, None, None, None, False
+            )[0],
             lambda q, k, v: attnlib.reference_attention(q, k, v, causal=True),
             [bf16(*shape, scale=0.5) for _ in range(3)],
             report,

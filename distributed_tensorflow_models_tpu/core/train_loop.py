@@ -295,8 +295,7 @@ def _jit_multi_step(
 ) -> Callable:
     """Jit ``lax.scan`` of an already-built raw step (the
     :func:`make_train_step_fn` contract) over stacked batches — the
-    entry point for callers that hold a step fn rather than a loss fn
-    (bench.py's steps_per_loop sweep)."""
+    entry point for callers that hold a step fn rather than a loss fn."""
     if donate is None:
         donate = default_donate()
 
@@ -375,8 +374,8 @@ class InstrumentedStep:
       ``train/flops_total`` counter — the MFU numerator.  The counter,
       not ``gauge × steps``, is what MFU readers use, so a ragged final
       batch (smaller program, new signature) scales the accounting for
-      *its* steps only instead of silently re-pricing the whole run
-      (bench.py's single-step convention).  The program is lowered
+      *its* steps only instead of silently re-pricing the whole run.
+      The program is lowered
       *before* the call, while input buffers are still valid under
       donation, and costed *after* it, when the compiled program exists
       (in the AOT handle, or in the persistent cache).  A step whose
@@ -553,9 +552,9 @@ class InstrumentedMultiStep(InstrumentedStep):
     Telemetry stays comparable across ``steps_per_loop`` values:
 
     - **FLOPs per chunk = K × the per-step signature cost.**  XLA cost
-      analysis visits a scan/while body ONCE, ignoring the trip count
-      (bench.py's empirically verified trap), so analysing the chunk
-      program would under-count by exactly K.  Instead the per-step cost
+      analysis visits a scan/while body ONCE, ignoring the trip count (a
+      K-step scan is costed as its body), so analysing the chunk program
+      would under-count by exactly K.  Instead the per-step cost
       comes from the raw single step (``flops_step_fn``) lowered on one
       unstacked batch row (and, on the TPU, compiled — a second program
       beside the chunk's, paid once per signature), and the

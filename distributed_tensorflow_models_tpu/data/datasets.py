@@ -580,7 +580,7 @@ def synthetic_imagenet_dataset(
 ):
     """On-host synthetic ImageNet batches (shapes/classes exact) — the
     throughput-benchmark input, the role slim's fake dataset played for the
-    reference's own benchmarking (see bench.py)."""
+    reference's own benchmarking."""
     x, y = _synthetic_images(
         max(2 * batch_size, 256), image_size, image_size, 3, 1000, seed
     )

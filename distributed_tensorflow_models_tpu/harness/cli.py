@@ -75,7 +75,7 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     )
     p.add_argument(
         "--attn-impl",
-        choices=("auto", "reference", "blockwise", "flash"),
+        choices=("auto", "reference", "blockwise"),
         default=None,
         help="attention kernel (auto = fused kernels on a TPU, else blockwise)",
     )

@@ -219,9 +219,9 @@ class ExperimentConfig:
     mesh_pipe: int = 1
     mesh_expert: int = 1
     # Attention implementation for attention models: auto | reference |
-    # blockwise | flash ("auto" = the fused kernels on a TPU for the calls
-    # they admit, blockwise elsewhere: ops/attention.py::auto_route,
-    # measured on the chip, PERF.md PR 26; "flash" is the older pair).
+    # blockwise ("auto" = the fused kernels on a TPU for the calls they
+    # admit, blockwise elsewhere: ops/attention.py::auto_route, measured
+    # on the chip, PERF.md PR 26).
     attn_impl: str = "auto"
     # Sequence/context parallelism over the ``seq`` axis: None | "ring"
     # (ppermute KV rotation) | "ulysses" (all_to_all head scatter).

@@ -390,7 +390,7 @@ class TelemetryHook(Hook):
         )
         # Whole-mesh peak: the FLOPs numerator is the global SPMD
         # program's cost, so the denominator is per-chip peak x all
-        # participating devices (bench.py's global/per-chip split).
+        # participating devices.
         # None on the CPU; an unlisted accelerator raises here, at fit
         # start, rather than logging mfu 0.0 for the whole run.
         peak = telemetry.peak_flops(jax.devices()[0].device_kind)

@@ -85,8 +85,7 @@ def configured_cache_dir() -> Optional[str]:
 def apply_compile_cache(xla_cache_dir: Optional[str] = None) -> Optional[str]:
     """The one place the persistent compilation cache is placed; returns
     the active cache dir (None = disabled).  ``fit``, the serving worker,
-    ``chip_smoke.py``, ``bench.py``'s children and ``tests/conftest.py``
-    all come through here.
+    ``chip_smoke.py`` and ``tests/conftest.py`` all come through here.
 
     Resolution: ``""`` disables the cache.  Otherwise, when
     ``JAX_COMPILATION_CACHE_DIR`` is set the cache stays there — whoever

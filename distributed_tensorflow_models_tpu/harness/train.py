@@ -158,13 +158,10 @@ def _mesh_model_kwargs(cfg: ExperimentConfig, mesh) -> dict:
         # and the window isn't double-applied.
         window = cfg.model_kwargs.get("attn_window")
         if cfg.seq_impl == "ring":
-            # attn_impl maps onto the ring inner step: auto/flash pick the
+            # attn_impl maps onto the ring inner step: auto picks the
             # Pallas chunk kernel + LSE merge on TPU; reference/blockwise
-            # use the XLA streaming fold (parallel/ring.py).  Explicit
-            # "flash" goes through "auto" so the same config still runs on
-            # non-TPU backends (the Mosaic kernel only lowers on TPU) —
-            # harness configs are portable, the library call is strict.
-            ring_impl = "auto" if cfg.attn_impl in ("auto", "flash") else "fold"
+            # use the XLA streaming fold (parallel/ring.py).
+            ring_impl = "auto" if cfg.attn_impl == "auto" else "fold"
             kwargs["attention_fn"] = lambda q, k, v, causal=True: (
                 ringlib.ring_attention(
                     q, k, v, mesh, causal=causal, impl=ring_impl,

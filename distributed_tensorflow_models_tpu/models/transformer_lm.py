@@ -4,7 +4,7 @@ The reference's only sequence model is the PTB LSTM (SURVEY.md §2.1 R8);
 this model is the framework's beyond-parity flagship for the reserved
 ``seq``/``model``/``expert`` mesh axes (SURVEY.md §5.7, §7.5): a standard
 pre-LN causal transformer whose attention is routed through
-:mod:`...ops.attention` (reference / blockwise / Pallas flash) or, when the
+:mod:`...ops.attention` (reference / blockwise / fused Pallas) or, when the
 harness passes an ``attention_fn``, through the sequence-parallel layer
 (:func:`...parallel.ring.ring_attention` / :func:`ulysses_attention`), and
 whose FFN blocks can be Switch-MoE layers over the ``expert`` axis
@@ -23,7 +23,7 @@ These are a model's published settings, not tuning options.
 
 TPU notes: bf16 compute with fp32 LayerNorm and logits; attention and MLP
 matmuls are [B·T, d]-shaped for the MXU; causal masking is positional (no
-materialized [T, T] mask when the blockwise/flash paths run).
+materialized [T, T] mask when the blockwise/fused paths run).
 """
 
 from __future__ import annotations

@@ -1,33 +1,35 @@
-"""Attention ops: reference, blockwise (memory-efficient), Pallas flash and
-the fused kernels that ``attention(impl="auto")`` runs on a TPU.
+"""Attention ops: the reference, blockwise (memory-efficient), the fused
+kernels that ``attention(impl="auto")`` runs on a TPU, and the ring path's
+chunk kernels.
 
 The reference framework predates attention entirely (its only sequence model
 is the PTB LSTM, SURVEY.md §2.1 R8) — this module is part of the framework's
-long-context mandate: scaled-dot-product attention implemented four ways,
-all sharing one API so models and the sequence-parallel layer
-(:mod:`...parallel.ring`) can pick per backend:
+long-context mandate.  Scaled-dot-product attention has three
+implementations behind :func:`attention`, which chooses between the last
+two from what a call shows (:func:`auto_route`: backend, shapes, mesh):
 
 - :func:`reference_attention` — O(T²) materialized scores; the numerics
   oracle for everything else.
-- :func:`blockwise_attention` — ``lax.scan`` over KV blocks with running
+- :func:`blockwise_attention` — one ``lax.scan`` over KV blocks with running
   (max, sum, acc) renormalization (Rabe & Staats / FlashAttention
   recurrence).  O(T·block) memory, differentiable end-to-end (scan is
-  reverse-AD-able), runs on any backend; the training default off the
-  chip and for the calls the fused kernels do not take.
-- :func:`flash_attention` — the same recurrence as a Pallas TPU kernel:
-  one grid step per (batch·head, q-block), KV loop innermost with the
-  softmax state in VMEM scratch, causal blocks skipped.  Matmuls in the
-  input dtype (bf16 on the models' activation path) with fp32
-  accumulation.  Gradients via ``jax.custom_vjp`` running the
-  FlashAttention-2 backward as a Pallas kernel pair (dK/dV with the Q
-  sweep innermost, dQ with the KV sweep innermost), rebuilding the
-  probabilities from the forward's saved log-sum-exp — O(T·block) memory
-  in both passes.  Named by ``impl="flash"``; the ring path's chunk
-  kernels share its code.
+  reverse-AD-able), runs on any backend: the CPU, and on the chip every
+  call the fused kernels do not take (odd lengths, sliding windows,
+  grouped KV heads, a ``jit`` over several devices outside ``shard_map``).
 - :func:`fused_attention` — that recurrence as two kernels shaped by a
-  chip measurement (PERF.md, PR 26): lane-filling column blocks of
-  ``[B, T, H*D]``, only the block pairs a causal mask leaves, one
+  chip measurement (PERF.md section 6, PR 26): lane-filling column blocks
+  of ``[B, T, H*D]``, only the block pairs a causal mask leaves, one
   backward kernel.  What ``auto`` runs on a TPU.
+
+Plus one: :func:`flash_attention_chunk`, an older Pallas kernel pair (a
+forward that also emits the log-sum-exp, a FlashAttention-2 dK/dV + dQ
+backward) that takes global offsets and returns ``(out, lse)``.  It is kept
+ONLY as the chunk step of :func:`...parallel.ring.ring_attention`
+(``impl="flash"`` there), which the fused kernels cannot serve yet: they
+take no offsets and return no LSE.  As a whole-sequence route it won nowhere
+on the chip (PERF.md section 6, PR 26) and was deleted as one (PR 27);
+ROADMAP R5/D14 measures the ring on four chips and then either gives the
+fused kernels offsets and an LSE output and deletes the pair, or re-tiles it.
 
 Layout convention everywhere: ``[batch, seq, heads, head_dim]`` (BTHD).
 """
@@ -35,14 +37,11 @@ Layout convention everywhere: ``[batch, seq, heads, head_dim]`` (BTHD).
 from __future__ import annotations
 
 import functools
-import os
-import sys
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from distributed_tensorflow_models_tpu.telemetry.registry import (
     ATTN_ROUTE_BLOCKWISE,
@@ -168,55 +167,6 @@ def _block_update(carry, s_block, v_block):
     return m_new, l_new, acc_new
 
 
-def _resolve_qblock(block_q: Optional[int], Tq: int) -> Optional[int]:
-    """DTM_BLOCKWISE_QBLOCK / explicit ``block_q`` (trace-time,
-    fail-loudly naming the knob): opt-in static q-chunking for
-    :func:`blockwise_attention`.  None (and no env) keeps the single
-    full-Tq scan — the hardware-measured baseline; flip only with a
-    banked artifact.  Validation is shared by both entry paths: a chunk
-    size the length doesn't divide would SILENTLY bank a baseline
-    number labeled as chunked, and a tiny chunk python-unrolls
-    Tq/block_q scans — a multi-million-op HLO that takes the compiler
-    minutes."""
-    src = "block_q"
-    if block_q is None:
-        env = os.environ.get("DTM_BLOCKWISE_QBLOCK")
-        if not env:
-            return None
-        src = "DTM_BLOCKWISE_QBLOCK"
-        try:
-            block_q = int(env)
-        except ValueError:
-            raise ValueError(
-                f"DTM_BLOCKWISE_QBLOCK must be an integer, got {env!r}"
-            ) from None
-    if block_q < 1:
-        raise ValueError(f"{src} must be >= 1, got {block_q}")
-    v = min(block_q, Tq)
-    if v != block_q:
-        # The knob asked for a chunk longer than the query length:
-        # clamping to one full-length chunk is correct math but is the
-        # unchunked computation in all but name — say what was actually
-        # measured (same contract as the DTM_UNEMBED_CHUNK clamp notice
-        # in ops/losses.py).
-        print(
-            f"[attention] {src}={block_q} clamped to {v} "
-            f"(query length {Tq}) — one full-length chunk",
-            file=sys.stderr,
-        )
-    if Tq % v:
-        raise ValueError(
-            f"{src}={block_q} does not divide the query length {Tq} — "
-            "a silent fallback would mislabel an A/B artifact"
-        )
-    if Tq // v > 64:
-        raise ValueError(
-            f"{src}={block_q} would unroll {Tq // v} q chunks "
-            "(cap 64): the trace blow-up risks a wedged remote compile"
-        )
-    return v
-
-
 def blockwise_attention(
     q: jax.Array,
     k: jax.Array,
@@ -228,7 +178,6 @@ def blockwise_attention(
     q_offset: int | jax.Array = 0,
     kv_offset: int | jax.Array = 0,
     window: Optional[int] = None,
-    block_q: Optional[int] = None,
 ) -> jax.Array:
     """Memory-efficient attention: scan over KV blocks, BTHD in/out.
 
@@ -236,24 +185,9 @@ def blockwise_attention(
     passes (the scan body is remat-ed, so backward recomputes per-block
     scores instead of storing them); exact same math as
     :func:`reference_attention` (tested to fp32 tolerance).  KV lengths
-    that don't divide ``block_kv`` are padded and masked.
-
-    ``block_q`` (or DTM_BLOCKWISE_QBLOCK) opts into STATIC q-chunking
-    for causal/window masks with static offsets: the single scan
-    computes every (query, kv-block) pair — at T=4096/512 blocks, 44%
-    of the causal pairs are fully masked and still cost a full matmul +
-    mask field — whereas each q chunk statically needs only kv blocks
-    [window start .. causal diagonal], with the per-element mask applied
-    ONLY on its boundary blocks.  Computes the exact unchunked
-    masked-softmax math: skipped leading blocks contribute garbage the
-    renorm zeroes exactly (alpha = exp(NEG_INF - m) == 0), and skipped
-    trailing blocks are exact no-ops (p == 0) — differences vs the
-    unchunked scan are ulp-level backend matmul reassociation (pinned in
-    tests/test_attention.py).  Chunk sizes the length doesn't divide or
-    that would unroll >64 chunks fail loudly; traced offsets (the ring
-    path) and configs with fully-masked rows (whose documented-garbage
-    output depends on visit count — _check_window) fall back to the
-    unchunked scan unchanged.
+    that don't divide ``block_kv`` are padded and masked.  Every (query,
+    kv-block) pair is computed, the masked ones included; on the chip the
+    fused kernels' pair list is what skips them.
     """
     B, Tq, H, D = q.shape
     window = _check_window(window)
@@ -279,63 +213,6 @@ def blockwise_attention(
         vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = kf.reshape(B, H, nblocks, block_kv, D).transpose(2, 0, 1, 3, 4)
     vb = vf.reshape(B, H, nblocks, block_kv, D).transpose(2, 0, 1, 3, 4)
-
-    block_q = _resolve_qblock(block_q, Tq)
-    if block_q is not None and not (causal or window is not None):
-        # q-chunking only skips blocks a causal/window mask rules out;
-        # with neither mask there is nothing to skip and the unchunked
-        # scan runs.  Say so loudly: an A/B artifact labeled 'qchunk'
-        # on a non-masked config would actually measure the baseline —
-        # the exact mislabeling the knob's validation exists to prevent.
-        print(
-            f"[attention] block_q={block_q} ignored: neither causal nor "
-            "window is set, so the unchunked scan runs (a 'qchunk' A/B "
-            "label on this config would measure the baseline)",
-            file=sys.stderr,
-        )
-    # Gate includes a no-fully-masked-rows guarantee: causal needs
-    # q_offset >= kv_offset (every row reaches at least the first key)
-    # and a window must reach the KV tail from the last query.  Rows
-    # with zero valid positions produce DOCUMENTED garbage
-    # (_check_window) whose exact bits depend on how many masked blocks
-    # were visited — the chunked path visits fewer, so equivalence only
-    # holds when no such rows exist.
-    no_dead_rows = (
-        isinstance(q_offset, int)
-        and isinstance(kv_offset, int)
-        and (not causal or q_offset >= kv_offset)
-        and (
-            window is None
-            or (q_offset + Tq - 1) - (kv_offset + Tkv - 1) < window
-        )
-    )
-    if (
-        block_q is not None
-        and (causal or window is not None)
-        and not no_dead_rows
-    ):
-        # The documented fallbacks (traced offsets — the ring path — and
-        # dead-row configs) still deserve the same loud trace-time
-        # notice: an artifact labeled 'qchunk' on such a config measures
-        # the unchunked baseline.
-        print(
-            f"[attention] block_q={block_q} ignored: traced offsets or "
-            "possible fully-masked rows (q_offset/kv_offset/window gate) "
-            "— running the unchunked scan",
-            file=sys.stderr,
-        )
-    if (
-        block_q is not None
-        and (causal or window is not None)
-        and no_dead_rows
-    ):
-        return _blockwise_q_chunked(
-            qf, kb, vb, q.dtype,
-            causal=causal, scale=s, block_kv=block_kv,
-            block_q=block_q, q_offset=q_offset,
-            kv_offset=kv_offset, window=window, Tkv=Tkv,
-            nblocks=nblocks,
-        )
 
     qi = q_offset + jnp.arange(Tq)[:, None]  # [Tq, 1]
 
@@ -373,115 +250,14 @@ def blockwise_attention(
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
 
-def _blockwise_q_chunked(
-    qf, kb, vb, out_dtype, *, causal, scale, block_kv, block_q, q_offset,
-    kv_offset, window, Tkv, nblocks,
-):
-    """The static-triangle half of :func:`blockwise_attention` (see its
-    docstring): python-unrolled q chunks, each visiting only the kv
-    blocks its mask can reach, with the per-element mask applied only on
-    boundary blocks.  All trip counts and mask decisions are static —
-    offsets are python ints by the caller's gate."""
-    B, H, Tq, D = qf.shape
-
-    def mask_needed(b, q_min_g, q_max_g):
-        # Boundary iff the block contains KV padding, straddles the
-        # causal diagonal for some chunk row, or straddles the window
-        # start for some chunk row — the static complement of the
-        # per-element mask below.
-        if (b + 1) * block_kv > Tkv:
-            return True
-        k_min = kv_offset + b * block_kv
-        k_max = kv_offset + (b + 1) * block_kv - 1
-        if causal and q_min_g < k_max:
-            return True
-        if window is not None and q_max_g - k_min >= window:
-            return True
-        return False
-
-    outs = []
-    for c in range(Tq // block_q):
-        q0 = c * block_q
-        qc = lax.slice_in_dim(qf, q0, q0 + block_q, axis=2)
-
-        @jax.checkpoint
-        def interior_body(carry, inp, qc=qc):
-            k_j, v_j = inp
-            s_block = jnp.einsum(
-                "bhqd,bhkd->bhqk", qc, k_j,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            return _block_update(carry, s_block, v_j), None
-        q_min_g = q_offset + q0
-        q_max_g = q_offset + q0 + block_q - 1
-        if causal:
-            # Last kv block holding any key <= the chunk's max query.
-            end = min(nblocks, (q_max_g - kv_offset) // block_kv + 1)
-        else:
-            end = nblocks
-        if window is not None:
-            start = max(
-                0, (q_min_g - window + 1 - kv_offset) // block_kv
-            )
-        else:
-            start = 0
-        m = jnp.zeros_like(qc[..., :1], dtype=jnp.float32) + NEG_INF
-        l = jnp.zeros_like(qc[..., :1], dtype=jnp.float32)
-        a = jnp.zeros_like(qc, dtype=jnp.float32)
-        carry = (m, l, a)
-
-        def masked_step(carry, b):
-            k_j = kb[b]
-            v_j = vb[b]
-            s_block = jnp.einsum(
-                "bhqd,bhkd->bhqk", qc, k_j,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            qi_c = q_offset + q0 + jnp.arange(block_q)[:, None]
-            lk = b * block_kv + jnp.arange(block_kv)[None, :]
-            valid = lk < Tkv
-            if causal:
-                valid = valid & (qi_c >= kv_offset + lk)
-            if window is not None:
-                valid = valid & (qi_c - (kv_offset + lk) < window)
-            s_block = jnp.where(valid, s_block, NEG_INF)
-            return _block_update(carry, s_block, v_j)
-
-        # Ascending block order, exactly like the unchunked scan:
-        # leading boundary blocks (window start / pad), one interior
-        # scan over the contiguous fully-valid run, trailing boundary
-        # blocks (causal diagonal / pad).
-        b = start
-        while b < end and mask_needed(b, q_min_g, q_max_g):
-            carry = jax.checkpoint(masked_step)(carry, b)
-            b += 1
-        run_end = b
-        while run_end < end and not mask_needed(
-            run_end, q_min_g, q_max_g
-        ):
-            run_end += 1
-        if run_end > b:
-            kslab = lax.slice_in_dim(kb, b, run_end, axis=0)
-            vslab = lax.slice_in_dim(vb, b, run_end, axis=0)
-            carry, _ = jax.lax.scan(
-                interior_body, carry, (kslab, vslab)
-            )
-        for b2 in range(run_end, end):
-            carry = jax.checkpoint(masked_step)(carry, b2)
-        m, l, a = carry
-        outs.append(a / jnp.maximum(l, 1e-30))
-    out = jnp.concatenate(outs, axis=2)
-    return jnp.swapaxes(out, 1, 2).astype(out_dtype)
-
-
-# ------------------------------------------------------------ pallas flash
+# ----------------------------------------- pallas flash pair (ring chunks)
 
 
 def _masked_scores(
     qb, kb, i, j, q_base, kv_base, *, scale, causal, block_q, block_kv,
     window=None, apply_mask=True,
 ):
-    """Shared score block for all three Pallas kernels: S = (Q_i K_j^T) *
+    """Shared score block for the Pallas kernels: S = (Q_i K_j^T) *
     scale in the INPUT dtype with f32 accumulation (upcasting q/k to f32
     first would push the MXU to its f32 rate — measured ~4x slower on
     v5e), causal-masked in GLOBAL positions: ``q_base``/``kv_base`` are
@@ -492,11 +268,12 @@ def _masked_scores(
     ``apply_mask=False`` is the interior-block fast path: the caller has
     proven (via :func:`_block_fully_valid`, a scalar predicate) that every
     (q, k) pair in the block is valid, so the iota/compare/select field
-    ops are skipped.  These kernels are VPU-bound at model head dims (the
-    r3 sweep's 1.87 TFLOP/s at D=64 is ~1% of MXU peak while HBM and
-    per-step overheads account for <15% — the [bq, bkv] elementwise field
-    work is the roofline), so shaving ~6 of the ~14 field passes on the
-    majority interior blocks is the first-order lever."""
+    ops are skipped.  These kernels are VPU-bound at model head dims (a
+    round-3 sweep read 1.87 TFLOP/s at D=64, ~1% of MXU peak, with HBM and
+    per-step overheads under 15%; old access layer, not re-measured — the
+    [bq, bkv] elementwise field work is the roofline), so shaving ~6 of
+    the ~14 field passes on the majority interior blocks is the
+    first-order lever."""
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -519,11 +296,11 @@ def _dispatch_masked(
     pl, _step, should_run, i, j, q_base, kv_base,
     *, causal, block_q, block_kv, window=None,
 ):
-    """Shared interior/boundary dispatch for all three flash kernels:
+    """Shared interior/boundary dispatch for the flash and fused kernels:
     runs ``_step(apply_mask=False)`` on blocks proven fully valid by
     :func:`_block_fully_valid`, ``_step(apply_mask=True)`` on boundary
     blocks, in disjoint ``pl.when`` branches.  One definition so the
-    three kernels cannot desynchronize their masking."""
+    kernels cannot desynchronize their masking."""
     if causal or window is not None:
         full = _block_fully_valid(
             i, j, q_base, kv_base, causal=causal,
@@ -549,10 +326,9 @@ def _block_should_run(
 ):
     """Scalar predicate: True iff ANY (q, k) pair in block (i, j) passes
     the causal/window mask — the block-skip test shared by the forward
-    kernel, both pair backward kernels, and the staged dQ kernel.  ONE
-    definition: the staged dQ kernel reads dS blocks the dKV sweep
-    conditionally wrote, so a predicate drift between them would read
-    unwritten HBM garbage and silently corrupt gradients."""
+    kernel and both backward kernels.  ONE definition: a forward that
+    skips a block its backward visits (or the reverse) silently diverges
+    the gradients from the forward's math."""
     should = True
     if causal:
         # Q block i ends before KV block j starts -> block is all-masked.
@@ -603,7 +379,7 @@ def _flash_kernel(
     """Grid = (B*H, Tq/block_q, Tkv/block_kv); KV innermost, softmax state
     carried across KV steps in VMEM scratch, output written on the last.
     Also emits the per-row log-sum-exp (the FlashAttention-2 backward
-    residual — :func:`_flash_bwd` rebuilds P from it without a second
+    residual — :func:`_flash_backward` rebuilds P from it without a second
     softmax pass).  ``qoff_ref``/``kvoff_ref`` are SMEM scalars: global
     offsets of the local chunk (the ring-attention case)."""
     import jax.experimental.pallas as pl  # deferred: TPU-path only
@@ -661,21 +437,23 @@ def _flash_kernel(
 
 
 def _auto_block(T: int) -> int:
-    """Largest measured-good tile the length divides: the v5e forward
-    sweep put 256x256 first (experiments/tpu_r3_flash_check_detail.json);
-    128 is the Mosaic-aligned fallback for lengths 256 doesn't divide."""
+    """Forward default tile: 256 where the length divides it (a round-3
+    v5e sweep at B4 T2048 H8 D64 causal bf16 read 7.78 ms at 256x256
+    against 9.21 ms at 128x128; old access layer, not re-measured), 128,
+    the Mosaic-aligned floor, elsewhere.  PR 26's chip micro-benchmark read
+    512x512 three to four times faster than these defaults (PERF.md
+    section 6); re-tiling waits for the ring's own measurement (ROADMAP
+    D14)."""
     return 256 if T % 256 == 0 else 128
 
 
 def _auto_block_bwd(T: int) -> int:
     """Backward default tile, resolved INDEPENDENTLY of the forward's:
-    only the forward 256 tile has a banked hardware win
-    (tpu_r3_flash_check_detail.json); the FA2 kernel-pair grad sweep
-    (flash_check's grad_block_sweep_ms) has no artifact yet, so carrying
-    256 into the backward would be an untested assumption on the grad
-    path.  Constant 128 for every T the kernels accept (it is the
+    the round-3 sweep behind :func:`_auto_block` timed the forward only,
+    so carrying 256 into the backward would be an untested assumption on
+    the grad path.  Constant 128 for every T the kernels accept (the
     Mosaic-aligned floor both _check_blocks fallbacks share); the T
-    parameter stays so a banked grad sweep can make this
+    parameter stays so the ring's measurement (ROADMAP D14) can make this
     length-dependent like _auto_block without touching call sites."""
     return 128 if T >= 128 else T
 
@@ -712,8 +490,11 @@ def _smem_scalar_spec(pl, pltpu):
 
 def _flash_forward(
     q, k, v, *, causal, scale, block_q, block_kv, interpret,
-    return_lse=False, q_offset=0, kv_offset=0, window=None,
+    q_offset=0, kv_offset=0, window=None,
 ):
+    """Returns ``(out [B,T,H,D], lse [B,T,H] f32)``: the LSE layout
+    broadcasts against BTHD outputs with one trailing-axis expand (the
+    ring-merge shape)."""
     window = _check_window(window)
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -782,11 +563,7 @@ def _flash_forward(
         interpret=interpret,
     )(qoff, kvoff, qh, kh, vh)
     out = jnp.swapaxes(out.reshape(B, H, Tq, D), 1, 2)
-    if return_lse:
-        # Public LSE layout [B, T, H]: broadcasts against BTHD outputs
-        # with one trailing-axis expand (the ring-merge shape).
-        return out, jnp.swapaxes(lse.reshape(B, H, Tq), 1, 2)
-    return out
+    return out, jnp.swapaxes(lse.reshape(B, H, Tq), 1, 2)
 
 
 def _p_and_ds(
@@ -817,9 +594,9 @@ def _p_and_ds(
 
 def _flash_dkv_kernel(
     qoff_ref, kvoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, *rest,
-    scale: float, causal: bool, block_q: int, block_kv: int,
-    window=None, stage_ds: bool = False,
+    dk_ref, dv_ref, dk_scr, dv_scr,
+    *, scale: float, causal: bool, block_q: int, block_kv: int,
+    window=None,
 ):
     """dK/dV kernel: grid = (B*H, Tkv/block_kv, Tq/block_q), Q innermost;
     dK_j / dV_j accumulate in VMEM scratch across the Q sweep.
@@ -829,20 +606,8 @@ def _flash_dkv_kernel(
       dV_j += P_ij^T dO_i
       dS_ij = P_ij ∘ (dO_i V_j^T - delta_i)
       dK_j += scale * dS_ij^T Q_i
-
-    ``stage_ds=True`` additionally writes each computed dS block (in the
-    matmul dtype — bitwise what the dQ kernel would feed its MXU) to an
-    HBM-resident [B*H, Tq, Tkv] output, so the dQ sweep can skip the
-    second S/P rebuild entirely (:func:`_flash_dq_staged_kernel`).
-    Skipped blocks leave their dS garbage — the staged dQ kernel skips
-    the same blocks by the same predicate and never reads them.
     """
     import jax.experimental.pallas as pl
-
-    if stage_ds:
-        ds_ref, dk_scr, dv_scr = rest
-    else:
-        dk_scr, dv_scr = rest
 
     j = pl.program_id(1)
     i = pl.program_id(2)
@@ -876,11 +641,6 @@ def _flash_dkv_kernel(
             ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bkv, D]
-        if stage_ds:
-            # Staged in K's dtype: the pair dQ kernel feeds its MXU
-            # ds.astype(kb.dtype), so this keeps staged dQ bitwise equal
-            # even if q and k dtypes ever diverge.
-            ds_ref[0] = ds.astype(ds_ref.dtype)
 
     _dispatch_masked(
         pl, _step, should_run, i, j, q_base, kv_base,
@@ -891,45 +651,6 @@ def _flash_dkv_kernel(
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _flash_dq_staged_kernel(
-    qoff_ref, kvoff_ref, ds_ref, k_ref, dq_ref, dq_scr,
-    *, scale: float, causal: bool, block_q: int, block_kv: int,
-    window=None,
-):
-    """Staged dQ kernel: grid = (B*H, Tq/block_q, Tkv/block_kv), KV
-    innermost; consumes the dS blocks staged by the dKV sweep instead of
-    rebuilding S/P — one matmul and zero field passes per block:
-      dQ_i += scale * dS_ij K_j.
-    Must skip exactly the blocks the dKV sweep skipped (same predicate)
-    or it would read unwritten dS garbage."""
-    import jax.experimental.pallas as pl
-
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
-    q_base, kv_base = qoff_ref[0], kvoff_ref[0]
-
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    should_run = _block_should_run(
-        i, j, q_base, kv_base, causal=causal,
-        block_q=block_q, block_kv=block_kv, window=window,
-    )
-
-    @pl.when(should_run)
-    def _compute():
-        dq_scr[:] += scale * jax.lax.dot_general(
-            ds_ref[0], k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_j - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_dq_kernel(
@@ -982,23 +703,12 @@ def _flash_dq_kernel(
 
 
 def _flash_backward(
-    q, k, v, out, lse, g, *, causal, scale, block_q, block_kv, interpret,
-    q_offset=0, kv_offset=0, g_lse=None, window=None, staged=False,
+    q, k, v, out, lse, g, g_lse, *, causal, scale, block_q, block_kv,
+    interpret, q_offset=0, kv_offset=0, window=None,
 ):
     """``lse`` here is the kernel-internal [B*H, Tq, 1] layout.  ``g_lse``
-    (same layout, optional) is the LSE cotangent from callers that
-    consumed the (out, lse) pair — it folds into delta (see
-    :func:`_p_and_ds`).
-
-    ``staged=True`` selects the dS-staging variant: the dKV sweep writes
-    its dS blocks to an [B*H, Tq, Tkv] HBM buffer and the dQ sweep
-    consumes them instead of rebuilding S/P — removing 2 of the
-    backward's 7 matmuls and ~all of the dQ sweep's VPU field work, at
-    the cost of O(T²) transient HBM (which surrenders flash's O(T·block)
-    memory — hence opt-in, for shapes where HBM is plentiful; see
-    experiments/FLASH_BWD_r4.md).  dQ is bitwise identical either way:
-    the staged buffer holds exactly the ds.astype(matmul dtype) blocks
-    the pair kernel would feed its MXU."""
+    (same layout) is the LSE cotangent of the (out, lse) pair — it folds
+    into delta (see :func:`_p_and_ds`)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1012,15 +722,14 @@ def _flash_backward(
     doh = _heads_first(g)
     qoff, kvoff = _offset_scalars(q_offset, kv_offset)
     kv_row = _kv_row(H, Hkv, grp)
-    # delta_i = rowsum(dO ∘ O): elementwise, XLA fuses it fine outside.
+    # delta_i = rowsum(dO ∘ O) - dLSE_i: elementwise, XLA fuses it fine
+    # outside.
     delta = jnp.sum(
         doh.astype(jnp.float32)
         * _heads_first(out).astype(jnp.float32),
         axis=-1,
         keepdims=True,
-    )  # [B*H, Tq, 1] f32
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)
+    ) - g_lse.astype(jnp.float32)  # [B*H, Tq, 1] f32
 
     qspec = lambda im: pl.BlockSpec(
         (1, block_q, D), im, memory_space=pltpu.VMEM
@@ -1038,34 +747,13 @@ def _flash_backward(
     dkv_kernel = functools.partial(
         _flash_dkv_kernel,
         scale=s, causal=causal, block_q=block_q, block_kv=block_kv,
-        window=window, stage_ds=staged,
+        window=window,
     )
-    # dS stage buffer: blocked (1, block_q, block_kv) at index (b, i, j)
-    # — written by the dKV sweep (grid (b, j, i); index maps may permute
-    # grid axes freely), read back by the staged dQ sweep in its own
-    # (b, i, j) order.
-    dsspec = lambda im: pl.BlockSpec(
-        (1, block_q, block_kv), im, memory_space=pltpu.VMEM
-    )
-    dkv_out_specs = [
-        kvspec(lambda b, j, i: (b, j, 0)),
-        kvspec(lambda b, j, i: (b, j, 0)),
-    ]
-    dkv_out_shape = [
-        jax.ShapeDtypeStruct((B * H, Tkv, D), k.dtype),
-        jax.ShapeDtypeStruct((B * H, Tkv, D), v.dtype),
-    ]
-    if staged:
-        dkv_out_specs.append(dsspec(lambda b, j, i: (b, i, j)))
-        # K's dtype: what the pair dQ kernel would cast dS to at its MXU.
-        dkv_out_shape.append(
-            jax.ShapeDtypeStruct((B * H, Tq, Tkv), k.dtype)
-        )
     # GQA note: the kernel computes PER-QUERY-HEAD dK/dV ([B*H, Tkv, D])
     # — each query head reads its group's KV row but writes its own
     # gradient row, keeping grid dim 0 parallel (no cross-head output
     # revisiting); the group-sum down to H_kv heads happens outside.
-    dkv_out = pl.pallas_call(
+    dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B * H, Tkv // block_kv, Tq // block_q),
         in_specs=[
@@ -1078,8 +766,14 @@ def _flash_backward(
             rowspec(lambda b, j, i: (b, i, 0)),
             rowspec(lambda b, j, i: (b, i, 0)),
         ],
-        out_specs=dkv_out_specs,
-        out_shape=dkv_out_shape,
+        out_specs=[
+            kvspec(lambda b, j, i: (b, j, 0)),
+            kvspec(lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B * H, Tkv, D), k.dtype),
+            jax.ShapeDtypeStruct((B * H, Tkv, D), v.dtype),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, D), jnp.float32),
             pltpu.VMEM((block_kv, D), jnp.float32),
@@ -1089,58 +783,32 @@ def _flash_backward(
         ),
         interpret=interpret,
     )(qoff, kvoff, qh, kh, vh, doh, lse, delta)
-    if staged:
-        dk, dv, ds_buf = dkv_out
-        dq_kernel = functools.partial(
-            _flash_dq_staged_kernel,
-            scale=s, causal=causal, block_q=block_q, block_kv=block_kv,
-            window=window,
-        )
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid=(B * H, Tq // block_q, Tkv // block_kv),
-            in_specs=[
-                _smem_scalar_spec(pl, pltpu),
-                _smem_scalar_spec(pl, pltpu),
-                dsspec(lambda b, i, j: (b, i, j)),
-                kvspec(lambda b, i, j: (kv_row(b), j, 0)),
-            ],
-            out_specs=qspec(lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            ),
-            interpret=interpret,
-        )(qoff, kvoff, ds_buf, kh)
-    else:
-        dk, dv = dkv_out
-        dq_kernel = functools.partial(
-            _flash_dq_kernel,
-            scale=s, causal=causal, block_q=block_q, block_kv=block_kv,
-            window=window,
-        )
-        dq = pl.pallas_call(
-            dq_kernel,
-            grid=(B * H, Tq // block_q, Tkv // block_kv),
-            in_specs=[
-                _smem_scalar_spec(pl, pltpu),
-                _smem_scalar_spec(pl, pltpu),
-                qspec(lambda b, i, j: (b, i, 0)),
-                kvspec(lambda b, i, j: (kv_row(b), j, 0)),
-                kvspec(lambda b, i, j: (kv_row(b), j, 0)),
-                qspec(lambda b, i, j: (b, i, 0)),
-                rowspec(lambda b, i, j: (b, i, 0)),
-                rowspec(lambda b, i, j: (b, i, 0)),
-            ],
-            out_specs=qspec(lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            ),
-            interpret=interpret,
-        )(qoff, kvoff, qh, kh, vh, doh, lse, delta)
+    dq_kernel = functools.partial(
+        _flash_dq_kernel,
+        scale=s, causal=causal, block_q=block_q, block_kv=block_kv,
+        window=window,
+    )
+    dq = pl.pallas_call(
+        dq_kernel,
+        grid=(B * H, Tq // block_q, Tkv // block_kv),
+        in_specs=[
+            _smem_scalar_spec(pl, pltpu),
+            _smem_scalar_spec(pl, pltpu),
+            qspec(lambda b, i, j: (b, i, 0)),
+            kvspec(lambda b, i, j: (kv_row(b), j, 0)),
+            kvspec(lambda b, i, j: (kv_row(b), j, 0)),
+            qspec(lambda b, i, j: (b, i, 0)),
+            rowspec(lambda b, i, j: (b, i, 0)),
+            rowspec(lambda b, i, j: (b, i, 0)),
+        ],
+        out_specs=qspec(lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(qoff, kvoff, qh, kh, vh, doh, lse, delta)
 
     unflat = lambda x, nh, T: jnp.swapaxes(
         x.reshape(B, nh, T, D), 1, 2
@@ -1161,83 +829,10 @@ def _flash_backward(
     )
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
-)
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    causal: bool = False,
-    scale: Optional[float] = None,
-    block_q: Optional[int] = None,
-    block_kv: Optional[int] = None,
-    interpret: bool = False,
-    window: Optional[int] = None,
-    bwd_staged: bool = False,
-) -> jax.Array:
-    """Pallas TPU flash attention, BTHD in/out.
-
-    Default tiles (``None``) resolve per direction: the FORWARD via
-    :func:`_auto_block` (256 where the length divides it — the
-    on-hardware block sweep, bench.py --config flash_check, v5e, B4
-    T2048 H8 D64 causal bf16, measured 7.78 ms at 256x256 vs 9.21 ms at
-    the untuned 128x128; full grid in
-    experiments/tpu_r3_flash_check_detail.json), the BACKWARD via
-    :func:`_auto_block_bwd` (128 until a grad-sweep artifact lands).
-    Explicit tiles apply to both directions unchanged.
-
-    Forward is the fused kernel (which also emits per-row LSE); backward
-    is the FlashAttention-2 kernel pair (:func:`_flash_dkv_kernel` /
-    :func:`_flash_dq_kernel`) rebuilding P from the saved LSE — the O(T²)
-    score matrix is never materialized in either pass.  ``interpret=True``
-    runs the same kernels on CPU for tests.  ``bwd_staged=True`` opts the
-    backward into the dS-staging variant (O(T²) transient HBM for fewer
-    rebuild passes — see :func:`_flash_backward`); dQ/dK/dV values are
-    bitwise identical either way.
-    """
-    return _flash_forward(
-        q, k, v, causal=causal, scale=scale,
-        block_q=block_q, block_kv=block_kv, interpret=interpret,
-        window=window,
-    )
-
-
 def _lse_rows(lse):
     """[B, T, H] public LSE layout -> the kernels' [B*H, T, 1]."""
     B, T, H = lse.shape
     return jnp.swapaxes(lse, 1, 2).reshape(B * H, T, 1)
-
-
-def _flash_fwd(
-    q, k, v, causal, scale, block_q, block_kv, interpret, window,
-    bwd_staged,
-):
-    out, lse = _flash_forward(
-        q, k, v, causal=causal, scale=scale,
-        block_q=block_q, block_kv=block_kv, interpret=interpret,
-        return_lse=True, window=window,
-    )
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd(
-    causal, scale, block_q, block_kv, interpret, window, bwd_staged,
-    res, g,
-):
-    q, k, v, out, lse = res
-    bq = block_q if block_q is not None else _auto_block_bwd(q.shape[1])
-    bkv = (
-        block_kv if block_kv is not None else _auto_block_bwd(k.shape[1])
-    )
-    return _flash_backward(
-        q, k, v, out, _lse_rows(lse), g, causal=causal, scale=scale,
-        block_q=bq, block_kv=bkv, interpret=interpret,
-        window=window, staged=bwd_staged,
-    )
-
-
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
@@ -1259,7 +854,16 @@ def flash_attention_chunk(
     """Chunk-of-a-longer-sequence flash attention: returns ``(out, lse)``
     with lse ``[B, T, H]`` so a caller can exactly merge partial results
     from several KV chunks (the ring-attention inner step —
-    :func:`...parallel.ring.ring_attention` with ``impl='flash'``).
+    :func:`...parallel.ring.ring_attention` with ``impl='flash'``, this
+    function's one caller in the package).
+
+    The forward is :func:`_flash_kernel`; the backward is the
+    FlashAttention-2 pair (:func:`_flash_dkv_kernel` /
+    :func:`_flash_dq_kernel`) rebuilding P from the saved LSE — the O(T²)
+    score matrix is never materialized in either pass.  ``None`` tiles
+    resolve per direction (:func:`_auto_block`, :func:`_auto_block_bwd`);
+    explicit tiles apply to both.  ``interpret=True`` runs the same
+    kernels on the CPU for tests.
 
     ``q_offset``/``kv_offset`` are the *global* positions of the first
     local row — dynamic (traced) values; causal masking happens in global
@@ -1270,8 +874,7 @@ def flash_attention_chunk(
     return _flash_forward(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, interpret=interpret,
-        return_lse=True, q_offset=q_offset, kv_offset=kv_offset,
-        window=window,
+        q_offset=q_offset, kv_offset=kv_offset, window=window,
     )
 
 
@@ -1282,8 +885,7 @@ def _flash_chunk_fwd(
     out, lse = _flash_forward(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_kv=block_kv, interpret=interpret,
-        return_lse=True, q_offset=q_offset, kv_offset=kv_offset,
-        window=window,
+        q_offset=q_offset, kv_offset=kv_offset, window=window,
     )
     return (out, lse), (q, k, v, out, lse, q_offset, kv_offset)
 
@@ -1298,10 +900,10 @@ def _flash_chunk_bwd(
         block_kv if block_kv is not None else _auto_block_bwd(k.shape[1])
     )
     dq, dk, dv = _flash_backward(
-        q, k, v, out, _lse_rows(lse), g_out, causal=causal, scale=scale,
-        block_q=bq, block_kv=bkv, interpret=interpret,
-        q_offset=q_offset, kv_offset=kv_offset,
-        g_lse=_lse_rows(g_lse), window=window,
+        q, k, v, out, _lse_rows(lse), g_out, _lse_rows(g_lse),
+        causal=causal, scale=scale, block_q=bq, block_kv=bkv,
+        interpret=interpret, q_offset=q_offset, kv_offset=kv_offset,
+        window=window,
     )
     # Offsets are integer positions: no gradient.
     return dq, dk, dv, None, None
@@ -1764,7 +1366,7 @@ fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
 # Scope, not module: the core is a function, so flax names no part of it.
-# Every route (reference, blockwise, flash) sits under the one name; the
+# Every route (reference, blockwise, fused) sits under the one name; the
 # q/k/v/out projections stay outside.
 @jax.named_scope(ATTENTION_CORE_SCOPE)
 def attention(
@@ -1777,8 +1379,7 @@ def attention(
     impl: str = "auto",
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Dispatching entry point: ``impl`` in {auto, reference, blockwise,
-    flash}.
+    """Dispatching entry point: ``impl`` in {auto, reference, blockwise}.
 
     ``auto`` chooses from what the call can observe, at trace time: on a
     TPU, a call that :func:`fused_admissible` admits (self-attention
@@ -1787,10 +1388,9 @@ def attention(
     PERF.md PR 26); every other call (the CPU, odd lengths, a sliding
     window, grouped KV heads, and a ``jit`` over several devices outside
     ``shard_map``, where a Mosaic kernel cannot be partitioned) runs
-    :func:`blockwise_attention` exactly as before.  The choice is counted once per traced call
-    (``attention/route_fused`` / ``attention/route_blockwise``).  A named
-    ``impl`` means what it says; the tree's older Pallas pair stays
-    ``impl="flash"`` (and the ring path's chunk kernels)."""
+    :func:`blockwise_attention`.  The choice is counted once per traced
+    call (``attention/route_fused`` / ``attention/route_blockwise``).  A
+    named ``impl`` means what it says."""
     if impl == "auto":
         impl = auto_route(q, k, v, window=window)
         get_registry().counter(
@@ -1805,54 +1405,5 @@ def attention(
     if impl == "blockwise":
         return blockwise_attention(
             q, k, v, causal=causal, scale=scale, window=window
-        )
-    if impl == "flash":
-        # None blocks resolve per-length and per-direction: forward via
-        # _auto_block (256 where the sweep-measured winner divides, else
-        # 128), backward via _auto_block_bwd (128 until a grad-sweep
-        # artifact lands).  DTM_FLASH_TILE forces a square tile for
-        # end-to-end tile A/Bs in BOTH directions (read at trace time,
-        # same contract as DTM_CONV_IMPL in ops/conv.py).
-        # Positional: custom_vjp + nondiff_argnums is positional-indexed.
-        tile = os.environ.get("DTM_FLASH_TILE")
-        bq = bkv = None
-        if tile:
-            # Fail loudly naming the knob (the DTM_CONV_IMPL contract):
-            # a typo must not surface as a bare int()/ZeroDivisionError
-            # mid-trace.
-            try:
-                bq = bkv = int(tile)
-            except ValueError:
-                raise ValueError(
-                    f"DTM_FLASH_TILE must be an integer, got {tile!r}"
-                ) from None
-            if bq <= 0 or bq % 8:
-                raise ValueError(
-                    "DTM_FLASH_TILE must be a positive multiple of 8, "
-                    f"got {tile!r}"
-                )
-            # The knob exists for tile A/Bs: a tile the lengths don't
-            # divide would be silently clamped by _check_blocks (tile >
-            # T) or die mid-trace with an error that doesn't name the
-            # knob — either way the A/B artifacts would mislabel what
-            # they measured.
-            for which, L in (("query", q.shape[1]), ("key", k.shape[1])):
-                if L % bq:
-                    raise ValueError(
-                        f"DTM_FLASH_TILE={tile} does not divide the "
-                        f"{which} length {L}"
-                    )
-        # DTM_FLASH_BWD=staged opts the backward into the dS-staging
-        # variant; unset defaults to the O(T·block) kernel pair, and any
-        # other value is rejected loudly (trace-time knob, same
-        # fail-naming-the-knob contract as DTM_FLASH_TILE).
-        bwd = os.environ.get("DTM_FLASH_BWD", "pair")
-        if bwd not in ("pair", "staged"):
-            raise ValueError(
-                f"DTM_FLASH_BWD must be 'pair' or 'staged', got {bwd!r}"
-            )
-        return flash_attention(
-            q, k, v, causal, scale, bq, bkv, False, window,
-            bwd == "staged",
         )
     raise ValueError(f"unknown attention impl {impl!r}")

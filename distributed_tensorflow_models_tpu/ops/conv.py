@@ -48,8 +48,8 @@ _VALID_IMPLS = ("xla", "patches", "mxu")
 
 # Process-wide default used by impl="auto".  Read at *trace* time: two jits
 # traced under different defaults produce different programs, so callers that
-# flip it mid-process must not reuse previously-traced callables (bench.py
-# isolates per-config subprocesses; tests build fresh functions).
+# flip it mid-process must not reuse previously-traced callables (tests
+# build fresh functions).
 _default_impl = os.environ.get("DTM_CONV_IMPL", "xla")
 
 

@@ -4,8 +4,7 @@ Why a third lowering exists (beside ``impl="xla"`` and ``impl="patches"``,
 ops/conv.py): the conv models are the reference's headline benchmarks
 (SURVEY.md §2.1 R3-R7).  ``patches`` keeps the program matmul-shaped but
 materializes the im2col tensor — a kh*kw-fold HBM blow-up that capped
-ResNet-50 near 4% MFU (experiments/tpu_r3_resnet50_b*.json; builder
-reading from an earlier round, not re-measured).
+ResNet-50 near 4% MFU (round 3, old access layer, not re-measured).
 This module computes the same contraction *inside* a Pallas kernel: the
 input tile is DMA'd to VMEM once, the kh*kw shifted windows are read from
 VMEM (free), and the only HBM traffic is one read of x, one read of the

@@ -3,9 +3,7 @@
 The forward is a plain gather — XLA lowers it well on TPU.  The
 BACKWARD is the interesting half: the native vjp of ``take`` is a
 scatter-add over ``B*T`` token indices, and XLA's TPU scatter is the
-classic hidden cost of LM train steps (serialized row updates; the
-transformer_parts ablation in bench.py exists to measure exactly this —
-its ``frozen_embed`` variant removes this op from the step).  The MXU
+classic hidden cost of LM train steps (serialized row updates).  The MXU
 alternative every TPU embedding implementation reaches for is the
 one-hot matmul: ``dTable = one_hot(tokens)^T @ dOut`` — 2·N·V·d extra
 FLOPs (~84 GFLOP at the flagship transformer config, ~0.4 ms of MXU
@@ -24,8 +22,8 @@ never materialized in HBM.
 Both accumulate in f32 and produce the same values up to f32 summation
 order (pinned in tests/test_ops.py).  The trace-time env knob
 ``DTM_EMBED_GRAD`` selects the default for the model zoo's
-:class:`TokenEmbed` (same contract as DTM_CONV_IMPL / DTM_FLASH_TILE:
-invalid values fail loudly naming the knob).
+:class:`TokenEmbed` (same contract as DTM_CONV_IMPL: invalid values fail
+loudly naming the knob).
 """
 
 from __future__ import annotations

@@ -166,7 +166,7 @@ def chunked_unembed_xent(
     if chunk_rows == "auto":
         # Resolved AT THE OP so every caller honors DTM_UNEMBED_CHUNK
         # through one validation path (same placement as DTM_CONV_IMPL
-        # in ops/conv.py, DTM_FLASH_TILE in ops/attention.py).
+        # in ops/conv.py).
         chunk_rows = resolve_unembed_chunk()
     c = min(chunk_rows, n)
     if c != chunk_rows and os.environ.get("DTM_UNEMBED_CHUNK"):
@@ -200,9 +200,9 @@ def chunked_unembed_xent(
         return lse - picked
 
     # Static Python unroll, NOT lax.scan: XLA's cost analysis visits a
-    # scan body once regardless of trip count (see bench.py
-    # _flops_per_step_global), so a scanned head would silently vanish
-    # from FLOPs/MFU accounting.  The chunk count is small and static
+    # scan body once regardless of trip count (core/train_loop.py,
+    # InstrumentedMultiStep), so a scanned head would silently vanish from
+    # FLOPs/MFU accounting.  The chunk count is small and static
     # (B*T/chunk_rows); each body stays checkpointed, so backward
     # recomputes chunk logits either way.
     nll = jnp.concatenate(

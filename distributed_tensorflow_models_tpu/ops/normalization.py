@@ -8,9 +8,9 @@ purely about dtype discipline on TPU:
 - The elementwise normalize/scale/shift path runs in the *input* dtype
   (bfloat16 in the zoo's training configs).  flax's ``BatchNorm`` with
   ``dtype=float32`` promotes the activation tensor to float32, which doubles
-  HBM read+write traffic on what is a bandwidth-bound op; measured on this
-  repo's ResNet-50 bench that costs ~24% of end-to-end training throughput
-  (see bench.py).
+  HBM read+write traffic on what is a bandwidth-bound op; an early-round
+  ResNet-50 reading put that at ~24% of end-to-end training throughput
+  (old access layer, not re-measured).
 - Statistics are always *accumulated* in float32 regardless of input dtype
   (a bfloat16 ``E[x^2] - E[x]^2`` would be numerically catastrophic), and the
   per-channel affine constants are folded in float32 down to one fused

@@ -12,8 +12,8 @@ module's consumers rely on:
 - Sequence parallelism: rotation is position-elementwise, so each ring
   device rotates its local chunk by its global positions before the KV
   chunks start traveling — no cross-device coordination.
-- Kernels: rotation happens before the attention call; flash/blockwise
-  see ordinary q/k and need no RoPE awareness.
+- Kernels: rotation happens before the attention call; the kernels and
+  blockwise see ordinary q/k and need no RoPE awareness.
 
 Half-split ("rotate_half", GPT-NeoX/Llama) convention: features [0, D/2)
 pair with [D/2, D).

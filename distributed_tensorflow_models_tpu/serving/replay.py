@@ -1,13 +1,12 @@
 """Deterministic open-loop request replayer for serving drills and benches.
 
-Lifts the request mixes that ``bench.py`` previously built inline
-(mixed long-prefill/short-decode traffic, shared-prefix traffic with a
-common system prompt, and a uniform control mix) into one reusable
-module, and adds the piece the disaggregated drill needs: **open-loop
-arrivals**.  A closed-loop driver (write every request up front, let
-replicas drain the queue) hides interference — prefill of a long
-prompt stalls decode steps only when the two actually overlap, which
-requires requests to *arrive over time*.  The replayer assigns each
+Holds the request mixes (mixed long-prefill/short-decode traffic,
+shared-prefix traffic with a common system prompt, and a uniform control
+mix) in one reusable module, and adds the piece the disaggregated drill
+needs: **open-loop arrivals**.  A closed-loop driver (write every
+request up front, let replicas drain the queue) hides interference —
+prefill of a long prompt stalls decode steps only when the two actually
+overlap, which requires requests to *arrive over time*.  The replayer assigns each
 request a deterministic arrival offset (seeded exponential
 inter-arrival gaps) and paces emission against ``time.perf_counter``.
 
@@ -15,7 +14,7 @@ The overload tier (ISSUE 19) builds on the same machinery:
 
 - :data:`TRACE_PRESETS` / :func:`preset_trace` name the canonical
   request mixes (shared-prefix, long-context, interference, uniform)
-  with ONE parameterization shared by ``bench.py`` and the drills;
+  with ONE parameterization shared by every drill;
 - :func:`bursty_arrivals` (spike/lull phase switching) and
   :func:`diurnal_arrivals` (compressed day curve) generate the
   non-stationary arrival processes the admission/autoscale tier is
@@ -240,13 +239,11 @@ def shared_prefix_mix(n: int, *, seed: int, vocab: int = 64,
 
 # --------------------------------------------------------------------------
 # Named trace presets — the ONE parameterization of the canonical
-# request mixes.  bench.py's serving arms and the serve_drill/load arms
-# both read these (previously bench.py hardcoded the same numbers
-# inline), so a bench headline and a drill always describe the same
-# traffic.  Each preset carries its full-size shape plus a "smoke"
-# override (seconds-scale CPU validation); lengths are page-aligned
-# against ``page_tokens`` so warm shared-prefix admissions resume
-# exactly at a cached page boundary.
+# request mixes.  The serve_drill/load arms read these, so two drills
+# always describe the same traffic.  Each preset carries its full-size
+# shape plus a "smoke" override (seconds-scale CPU validation); lengths
+# are page-aligned against ``page_tokens`` so warm shared-prefix
+# admissions resume exactly at a cached page boundary.
 TRACE_PRESETS = {
     # Long common system prompt + short unique tails: the radix
     # prefix-cache / fleet-cache showcase.

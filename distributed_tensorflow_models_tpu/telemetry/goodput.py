@@ -21,7 +21,7 @@ exactly 1.0.
 
 MFU is wall-clock-inclusive (FLOPs retired per second of *total* time over
 peak), i.e. it already prices in every stall — the honest end-to-end
-number, matching ``bench.py``'s convention for the same configs.
+number.
 """
 
 from __future__ import annotations
@@ -33,7 +33,7 @@ from typing import Optional
 from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
 # Peak dense bf16 FLOPs/sec per chip, by jax ``device_kind`` prefix — the
-# one table (bench.py imports it).  Source: Google Cloud TPU documentation,
+# one table.  Source: Google Cloud TPU documentation,
 # per-chip specifications of each generation (v4 275, v5e 197, v5p 459,
 # v6e 918 TFLOP/s bf16).  A v5e chip reports ``device_kind`` "TPU v5 lite".
 PEAK_BF16_FLOPS = (
@@ -81,8 +81,7 @@ def device_kind() -> Optional[str]:
 def device_count() -> int:
     """Global participating-device count (1 when jax is unavailable).
     The MFU denominator must scale by this: cost analysis is of the
-    *global* SPMD program, so the peak must be the whole mesh's — the
-    same global-FLOPs/per-chip split bench.py applies explicitly."""
+    *global* SPMD program, so the peak must be the whole mesh's."""
     try:
         import jax
 

@@ -1,5 +1,6 @@
-"""Attention numerics: blockwise and Pallas-flash vs reference, and the
-sequence-parallel forms (ring, Ulysses) vs single-device reference."""
+"""Attention numerics: blockwise, the fused kernels and the ring's flash
+pair vs reference, and the sequence-parallel forms (ring, Ulysses) vs
+single-device reference."""
 
 import functools
 
@@ -24,6 +25,15 @@ def _qkv(B=2, T=128, H=4, D=32, seed=0):
     return mk(), mk(), mk()
 
 
+def _pair(q, k, v, causal, block_q=64, block_kv=64, window=None):
+    """The flash pair as the ring runs it, at offsets 0: the forward kernel
+    and the FlashAttention-2 backward through ``flash_attention_chunk``,
+    interpreted on the CPU.  Positional: custom_vjp + nondiff_argnums."""
+    return attnlib.flash_attention_chunk(
+        q, k, v, 0, 0, causal, None, block_q, block_kv, True, window
+    )[0]
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("block_kv", [32, 128])
 def test_blockwise_matches_reference(causal, block_kv):
@@ -39,9 +49,7 @@ def test_blockwise_matches_reference(causal, block_kv):
 def test_flash_kernel_matches_reference(causal):
     q, k, v = _qkv(T=256)
     ref = attnlib.reference_attention(q, k, v, causal=causal)
-    out = attnlib.flash_attention(
-        q, k, v, causal, None, 64, 64, True  # interpret=True on CPU
-    )
+    out = _pair(q, k, v, causal)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
@@ -58,10 +66,7 @@ def test_flash_grads_match_reference(causal):
         )
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            attnlib.flash_attention(q, k, v, causal, None, 64, 64, True)
-            ** 2
-        )
+        return jnp.sum(_pair(q, k, v, causal) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -81,10 +86,7 @@ def test_flash_grads_cross_attention_shapes():
         return jnp.sum(attnlib.reference_attention(q, k, v) ** 2)
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            attnlib.flash_attention(q, k, v, False, None, 64, 64, True)
-            ** 2
-        )
+        return jnp.sum(_pair(q, k, v, False) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -104,12 +106,7 @@ def test_flash_bf16_grads_close_to_reference():
         )
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            attnlib.flash_attention(
-                q, k, v, True, None, 64, 64, True
-            ).astype(jnp.float32)
-            ** 2
-        )
+        return jnp.sum(_pair(q, k, v, True).astype(jnp.float32) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(qb, kb, vb)
@@ -119,13 +116,99 @@ def test_flash_bf16_grads_close_to_reference():
         )
 
 
+@pytest.mark.parametrize("window", [None, 48])
 @pytest.mark.parametrize("causal", [False, True])
-def test_blockwise_pads_odd_lengths(causal):
-    """KV lengths that don't divide the block are padded+masked."""
+def test_blockwise_pads_odd_lengths(causal, window):
+    """KV lengths that don't divide the block are padded+masked, under a
+    sliding window too (on the chip blockwise is the route for both)."""
     q, k, v = _qkv(T=100)
-    ref = attnlib.reference_attention(q, k, v, causal=causal)
-    out = attnlib.blockwise_attention(q, k, v, causal=causal, block_kv=64)
+    ref = attnlib.reference_attention(q, k, v, causal=causal, window=window)
+    out = attnlib.blockwise_attention(
+        q, k, v, causal=causal, block_kv=64, window=window
+    )
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "Tq,Tkv,hkv,bkv,causal,window,qoff",
+    [
+        (512, 512, 2, 128, True, 96, 0),
+        (256, 384, 2, 100, True, None, 128),  # padding and an offset
+        (256, 256, 2, 128, False, 64, 0),  # window without causal
+        (256, 256, 1, 64, True, 80, 0),  # grouped KV heads under a window
+    ],
+    ids=["causal_window", "pad_offset", "window_only", "gqa_window"],
+)
+def test_blockwise_masked_geometries_match_reference(
+    Tq, Tkv, hkv, bkv, causal, window, qoff
+):
+    """What ``auto`` sends to blockwise on the chip (windows, grouped KV
+    heads, lengths no tile divides) and the ring's fold (offsets)."""
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(2, Tq, 2, 16), jnp.float32)
+    k = jnp.asarray(rng.randn(2, Tkv, hkv, 16), jnp.float32)
+    v = jnp.asarray(rng.randn(2, Tkv, hkv, 16), jnp.float32)
+    kw = dict(causal=causal, q_offset=qoff, kv_offset=0, window=window)
+    ref = attnlib.reference_attention(q, k, v, **kw)
+    out = attnlib.blockwise_attention(q, k, v, block_kv=bkv, **kw)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "window,hkv", [(None, 2), (80, 2), (None, 1)],
+    ids=["causal", "causal_window", "gqa"],
+)
+def test_blockwise_grads_match_reference(window, hkv):
+    """dQ, dK, dV through the remat-ed scan against autodiff through the
+    reference; with grouped KV heads the expansion's transpose sums the
+    group's gradients."""
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 256, hkv, 16), jnp.float32)
+    v = jnp.asarray(rng.randn(1, 256, hkv, 16), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, window=window) ** 2
+        )
+
+    g_ref = jax.grad(loss(attnlib.reference_attention), (0, 1, 2))(q, k, v)
+    g_bw = jax.grad(
+        loss(functools.partial(attnlib.blockwise_attention, block_kv=64)),
+        (0, 1, 2),
+    )(q, k, v)
+    for a, b in zip(g_bw, g_ref):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_blockwise_traced_offset_equals_static():
+    """The ring's fold passes traced offsets: under ``jit`` they mask as
+    the static ones do."""
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(1, 128, 2, 16), jnp.float32)
+
+    @jax.jit
+    def f(q, k, v, off):
+        return attnlib.blockwise_attention(
+            q, k, v, causal=True, block_kv=64, q_offset=off, kv_offset=0
+        )
+
+    base = attnlib.blockwise_attention(
+        q, q, q, causal=True, block_kv=64, q_offset=128, kv_offset=0
+    )
+    np.testing.assert_allclose(f(q, q, q, jnp.int32(128)), base, rtol=1e-6)
+
+
+def test_blockwise_live_rows_with_dead_rows_present():
+    """kv_offset > q_offset leaves the first rows with no visible key
+    (their output is documented garbage, _check_window); every row that
+    sees a key equals the reference's."""
+    rng = np.random.RandomState(8)
+    q = jnp.asarray(rng.randn(1, 128, 2, 16), jnp.float32)
+    kw = dict(causal=True, q_offset=0, kv_offset=64)
+    ref = attnlib.reference_attention(q, q, q, **kw)
+    out = attnlib.blockwise_attention(q, q, q, block_kv=64, **kw)
+    np.testing.assert_allclose(out[:, 64:], ref[:, 64:], rtol=2e-5, atol=2e-5)
 
 
 def test_blockwise_backward_is_remat():
@@ -171,24 +254,35 @@ def test_flash_chunk_merge_matches_full(causal):
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
-def test_flash_chunk_lse_grads():
+@pytest.mark.parametrize(
+    "window,hkv", [(None, 2), (48, 2), (None, 1)],
+    ids=["causal", "window", "gqa"],
+)
+def test_flash_chunk_lse_grads(window, hkv):
     """Gradients through BOTH chunk outputs (out and lse) — the lse
     cotangent folds into the backward delta; checked against autodiff of
-    an equivalent XLA computation."""
+    an equivalent XLA computation.  Under a window the block skip, and
+    under grouped KV heads the group sum, meet a non-zero LSE cotangent:
+    the backward the ring runs."""
     q, k, v = _qkv(B=1, T=128, H=2, D=32)
+    k, v = k[:, :, :hkv], v[:, :, :hkv]
 
     def loss_chunk(q, k, v):
         o, lse = attnlib.flash_attention_chunk(
             q, k, v, 0, 0, causal=True, block_q=64, block_kv=64,
-            interpret=True,
+            interpret=True, window=window,
         )
         return jnp.sum(o**2) + jnp.sum(jnp.sin(lse))
 
     def loss_ref(q, k, v):
+        k, v = (jnp.repeat(x, 2 // hkv, axis=2) for x in (k, v))
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (32**-0.5)
         qi = jnp.arange(128)[:, None]
         kj = jnp.arange(128)[None, :]
-        s = jnp.where(qi >= kj, s, attnlib.NEG_INF)
+        valid = qi >= kj
+        if window is not None:
+            valid = valid & (qi - kj < window)
+        s = jnp.where(valid, s, attnlib.NEG_INF)
         lse = jax.scipy.special.logsumexp(s, axis=-1)  # [B,H,Tq]
         o = jnp.einsum(
             "bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v
@@ -229,9 +323,7 @@ def test_window_blockwise_and_flash_match_reference(window):
     bw = attnlib.blockwise_attention(
         q, k, v, causal=True, block_kv=64, window=window
     )
-    fl = attnlib.flash_attention(
-        q, k, v, True, None, 64, 64, True, window
-    )
+    fl = _pair(q, k, v, True, window=window)
     np.testing.assert_allclose(bw, ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(fl, ref, rtol=2e-5, atol=2e-5)
 
@@ -245,7 +337,21 @@ def test_window_rejects_nonpositive():
             attnlib.blockwise_attention(q, k, v, causal=True, window=w)
 
 
-def test_window_flash_grads_match_reference():
+# f32 to autodiff tolerance; bf16 (the models' activation dtype, where the
+# dS and P casts round) to bf16 round-off of the f32 reference.
+_PAIR_GRAD_TOL = {"float32": (1e-4, 1e-4), "bfloat16": (0.1, 0.15)}
+
+
+def _assert_pair_grads_close(g_ref, g_pair, dtype):
+    rtol, atol = _PAIR_GRAD_TOL[dtype]
+    for a, b in zip(g_ref, g_pair):
+        np.testing.assert_allclose(
+            a, np.asarray(b, np.float32), rtol=rtol, atol=atol
+        )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_window_flash_grads_match_reference(dtype):
     q, k, v = _qkv(B=1, T=256, H=2, D=32)
 
     def loss_ref(q, k, v):
@@ -258,14 +364,14 @@ def test_window_flash_grads_match_reference():
 
     def loss_flash(q, k, v):
         return jnp.sum(
-            attnlib.flash_attention(q, k, v, True, None, 64, 64, True, 80)
-            ** 2
+            _pair(q, k, v, True, window=80).astype(jnp.float32) ** 2
         )
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_ref, g_fl):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(
+        *(x.astype(dtype) for x in (q, k, v))
+    )
+    _assert_pair_grads_close(g_ref, g_fl, dtype)
 
 
 # ----------------------------------------------------------------- GQA
@@ -285,12 +391,13 @@ def test_gqa_reference_equals_expanded_mha(hkv):
     for out in (
         attnlib.reference_attention(q, k, v, causal=True),
         attnlib.blockwise_attention(q, k, v, causal=True, block_kv=64),
-        attnlib.flash_attention(q, k, v, True, None, 64, 64, True),
+        _pair(q, k, v, True),
     ):
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
-def test_gqa_flash_grads_match_expanded_reference():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gqa_flash_grads_match_expanded_reference(dtype):
     """Flash GQA backward (group index maps + outside group-sum) vs
     autodiff through the expanded-KV reference."""
     rng = np.random.RandomState(6)
@@ -308,15 +415,13 @@ def test_gqa_flash_grads_match_expanded_reference():
         )
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            attnlib.flash_attention(q, k, v, True, None, 64, 64, True)
-            ** 2
-        )
+        return jnp.sum(_pair(q, k, v, True).astype(jnp.float32) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_ref, g_fl):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(
+        *(x.astype(dtype) for x in (q, k, v))
+    )
+    _assert_pair_grads_close(g_ref, g_fl, dtype)
 
 
 def test_gqa_rejects_indivisible_heads():
@@ -620,9 +725,9 @@ def test_ring_gqa_rejects_indivisible_heads(seq_mesh):
 
 
 def test_auto_block_resolution():
-    """None tiles resolve per-length: 256 where divisible (the v5e sweep
-    winner, experiments/tpu_r3_flash_check_detail.json), 128 fallback,
-    clamped to the sequence length."""
+    """None tiles resolve per-length: 256 where divisible (the winner of
+    a round-3 v5e forward sweep; old access layer, not re-measured), 128
+    fallback, clamped to the sequence length."""
     assert attnlib._check_blocks(512, 512, None, None) == (256, 256)
     assert attnlib._check_blocks(2048, 2048, None, None) == (256, 256)
     assert attnlib._check_blocks(384, 384, None, None) == (128, 128)
@@ -635,9 +740,9 @@ def test_auto_block_resolution():
 
 def test_auto_block_bwd_resolution():
     """Backward default tiles resolve INDEPENDENTLY of the forward's:
-    128 everywhere the kernels accept (only the FORWARD 256 tile has a
-    banked hardware win; the grad sweep has no artifact yet — ADVICE
-    r3), clamped for short sequences like the forward path."""
+    128 everywhere the kernels accept (the round-3 sweep behind the
+    forward's 256 timed no backward — ADVICE r3), clamped for short
+    sequences like the forward path."""
     assert attnlib._auto_block_bwd(512) == 128
     assert attnlib._auto_block_bwd(2048) == 128
     assert attnlib._auto_block_bwd(256) == 128
@@ -656,10 +761,7 @@ def test_flash_bwd_none_tiles_resolve_independently():
     unmeasured."""
     q, k, v = _qkv(T=512)
     f = lambda q, k, v: jnp.sum(
-        attnlib.flash_attention(
-            q, k, v, True, None, None, None, True
-        ).astype(jnp.float32)
-        ** 2
+        _pair(q, k, v, True, None, None).astype(jnp.float32) ** 2
     )
     r = lambda q, k, v: jnp.sum(
         attnlib.reference_attention(
@@ -676,164 +778,6 @@ def test_flash_bwd_none_tiles_resolve_independently():
     )
     for a, b in zip(gf, gr):
         assert jnp.max(jnp.abs(a.astype(jnp.float32) - b)) < 0.15
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize(
-    "causal,window,hkv",
-    [(True, None, None), (True, 64, None), (True, None, 2)],
-    ids=["causal", "window", "gqa"],
-)
-def test_flash_bwd_staged_matches_pair(causal, window, hkv, dtype):
-    """The dS-staging backward must produce BITWISE the pair backward's
-    gradients: the staged buffer holds exactly the ds.astype(matmul
-    dtype) blocks the pair's dQ kernel would rebuild, and dK/dV come
-    from the identical dKV sweep.  bf16 covers the production path where
-    the staging cast actually rounds."""
-    q, k, v = _qkv(T=256)
-    q, k, v = (x.astype(dtype) for x in (q, k, v))
-    if hkv is not None:
-        k, v = k[:, :, :hkv, :], v[:, :, :hkv, :]
-
-    def loss(staged):
-        return lambda q, k, v: jnp.sum(
-            attnlib.flash_attention(
-                q, k, v, causal, None, 128, 128, True, window, staged
-            ).astype(jnp.float32)
-            ** 2
-        )
-
-    gp = jax.grad(loss(False), (0, 1, 2))(q, k, v)
-    gs = jax.grad(loss(True), (0, 1, 2))(q, k, v)
-    for name, a, b in zip("q k v".split(), gs, gp):
-        assert jnp.array_equal(
-            jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
-        ), name
-
-
-class TestBlockwiseQChunked:
-    """Static q-chunking (block_q / DTM_BLOCKWISE_QBLOCK) computes the
-    exact unchunked masked-softmax math: skipped leading blocks are
-    zeroed exactly by the renorm (alpha = exp(NEG_INF - m) == 0) and
-    skipped trailing blocks are exact no-ops (p == 0).  Tolerances are
-    ulp-level: the backend may reassociate the score matmul's K-loop
-    differently for chunked vs full-Tq shapes."""
-
-    @pytest.mark.parametrize(
-        "T,Tkv,bkv,bq,causal,window,qoff,kvoff",
-        [
-            (512, 512, 128, 128, True, None, 0, 0),
-            (512, 512, 128, 256, True, 96, 0, 0),
-            (256, 384, 100, 64, True, None, 128, 0),  # pad + offset
-            (256, 256, 128, 64, False, 64, 0, 0),  # window only
-        ],
-        ids=["causal", "causal_window", "pad_offset", "window_only"],
-    )
-    def test_bitwise_matches_unchunked(
-        self, T, Tkv, bkv, bq, causal, window, qoff, kvoff
-    ):
-        rng = np.random.RandomState(3)
-        q = jnp.asarray(rng.randn(2, T, 2, 16), jnp.float32)
-        k = jnp.asarray(rng.randn(2, Tkv, 2, 16), jnp.float32)
-        v = jnp.asarray(rng.randn(2, Tkv, 2, 16), jnp.float32)
-        base = attnlib.blockwise_attention(
-            q, k, v, causal=causal, block_kv=bkv,
-            q_offset=qoff, kv_offset=kvoff, window=window,
-        )
-        chunked = attnlib.blockwise_attention(
-            q, k, v, causal=causal, block_kv=bkv,
-            q_offset=qoff, kv_offset=kvoff, window=window, block_q=bq,
-        )
-        np.testing.assert_allclose(chunked, base, rtol=3e-5, atol=1e-6)
-
-    def test_grads_match_unchunked(self):
-        rng = np.random.RandomState(4)
-        q = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
-        k = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
-        v = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
-
-        def loss(bq):
-            return lambda q, k, v: jnp.sum(
-                attnlib.blockwise_attention(
-                    q, k, v, causal=True, block_kv=64, block_q=bq
-                )
-                ** 2
-            )
-
-        g0 = jax.grad(loss(None), (0, 1, 2))(q, k, v)
-        g1 = jax.grad(loss(64), (0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g0):
-            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
-
-    def test_traced_offsets_fall_back(self):
-        """The ring path passes traced offsets; chunking must quietly
-        fall back to the unchunked scan rather than fail to unroll."""
-        rng = np.random.RandomState(5)
-        q = jnp.asarray(rng.randn(1, 128, 2, 16), jnp.float32)
-        k, v = q, q
-
-        @jax.jit
-        def f(q, k, v, off):
-            return attnlib.blockwise_attention(
-                q, k, v, causal=True, block_kv=64, block_q=64,
-                q_offset=off, kv_offset=0,
-            )
-
-        base = attnlib.blockwise_attention(
-            q, k, v, causal=True, block_kv=64, q_offset=128, kv_offset=0
-        )
-        np.testing.assert_allclose(
-            f(q, k, v, jnp.int32(128)), base, rtol=1e-6
-        )
-
-    def test_env_knob(self, monkeypatch):
-        rng = np.random.RandomState(6)
-        q = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
-        base = attnlib.blockwise_attention(
-            q, q, q, causal=True, block_kv=64
-        )
-        monkeypatch.setenv("DTM_BLOCKWISE_QBLOCK", "64")
-        chunked = attnlib.blockwise_attention(
-            q, q, q, causal=True, block_kv=64
-        )
-        np.testing.assert_allclose(chunked, base, rtol=3e-5, atol=1e-6)
-        monkeypatch.setenv("DTM_BLOCKWISE_QBLOCK", "soon")
-        with pytest.raises(ValueError, match="DTM_BLOCKWISE_QBLOCK"):
-            attnlib.blockwise_attention(q, q, q, causal=True)
-
-    def test_validation_fails_loudly(self):
-        rng = np.random.RandomState(7)
-        q = jnp.asarray(rng.randn(1, 96, 2, 16), jnp.float32)
-        # Non-dividing chunk: a silent fallback would mislabel an A/B.
-        with pytest.raises(ValueError, match="does not divide"):
-            attnlib.blockwise_attention(
-                q, q, q, causal=True, block_q=64
-            )
-        with pytest.raises(ValueError, match=">= 1"):
-            attnlib.blockwise_attention(
-                q, q, q, causal=True, block_q=0
-            )
-        # Unroll cap: tiny chunks blow up the trace (wedge class).
-        with pytest.raises(ValueError, match="cap 64"):
-            attnlib.blockwise_attention(
-                q, q, q, causal=True, block_q=1
-            )
-
-    def test_dead_rows_fall_back_to_unchunked(self):
-        """kv_offset > q_offset leaves fully-masked rows whose
-        documented-garbage output depends on visit count; the chunked
-        gate must decline so numerics stay identical."""
-        rng = np.random.RandomState(8)
-        q = jnp.asarray(rng.randn(1, 128, 2, 16), jnp.float32)
-        base = attnlib.blockwise_attention(
-            q, q, q, causal=True, block_kv=64,
-            q_offset=0, kv_offset=64,
-        )
-        chunked = attnlib.blockwise_attention(
-            q, q, q, causal=True, block_kv=64,
-            q_offset=0, kv_offset=64, block_q=32,
-        )
-        np.testing.assert_array_equal(chunked, base)
 
 
 def _route_counts():
@@ -1016,15 +960,23 @@ def test_fused_refuses_what_it_cannot_tile():
         attnlib.fused_attention(q, k, v, True, None, None, None, True)
 
 
-def test_flash_tile_env_validated(monkeypatch):
-    """DTM_FLASH_TILE typos must fail loudly naming the knob (the
-    DTM_CONV_IMPL contract), not as a bare int()/ZeroDivisionError
-    mid-trace."""
+def test_removed_flash_impl_fails_loudly():
+    """``impl="flash"`` was a route until PR 27: the name must raise, not
+    fall through to another implementation."""
     q, k, v = _qkv(T=128)
-    for bad in ("bogus", "0", "-128", "100"):
-        monkeypatch.setenv("DTM_FLASH_TILE", bad)
-        with pytest.raises(ValueError, match="DTM_FLASH_TILE"):
-            attnlib.attention(q, k, v, impl="flash")
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        attnlib.attention(q, k, v, impl="flash")
+
+
+def test_cli_refuses_removed_flash_impl(capsys):
+    from distributed_tensorflow_models_tpu.harness import cli
+
+    with pytest.raises(SystemExit) as e:
+        cli.main(
+            ["train", "--config", "transformer_lm", "--attn-impl", "flash"]
+        )
+    assert e.value.code == 2
+    assert "invalid choice: 'flash'" in capsys.readouterr().err
 
 
 def test_blockwise_bf16_matches_f32_reference():
@@ -1067,12 +1019,3 @@ def test_blockwise_f32_unchanged_by_dtype_scheme():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
-
-
-def test_flash_tile_env_must_divide_lengths(monkeypatch):
-    """A forced tile the lengths don't divide must fail naming the knob
-    — not silently clamp (tile > T) or die with a generic block error."""
-    q, k, v = _qkv(T=128)
-    monkeypatch.setenv("DTM_FLASH_TILE", "512")
-    with pytest.raises(ValueError, match="DTM_FLASH_TILE"):
-        attnlib.attention(q, k, v, impl="flash")

@@ -58,8 +58,11 @@ def v5e():
 
 def _flash_fwd_bwd(q, k, v):
     def loss(q, k, v):
-        # Positional: (causal, scale, block_q, block_kv, interpret).
-        out = attnlib.flash_attention(q, k, v, True, None, None, None, False)
+        # The ring's chunk step at offsets 0.  Positional: (q_offset,
+        # kv_offset, causal, scale, block_q, block_kv, interpret).
+        out = attnlib.flash_attention_chunk(
+            q, k, v, 0, 0, True, None, None, None, False
+        )[0]
         return jnp.sum(out.astype(jnp.float32))
 
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)

@@ -382,11 +382,10 @@ def test_mxu_under_sharded_mesh(mesh8):
     )
 
 
-def test_qchunk_blockwise_under_sharded_mesh(mesh8):
-    """q-chunked blockwise attention with static offsets under pjit
-    partitioning (VERDICT r4 Missing #3): batch-sharded inputs, the
-    chunked gate engages (causal + int offsets), result matches the
-    reference under SPMD."""
+def test_blockwise_under_sharded_mesh(mesh8):
+    """Blockwise attention under pjit partitioning (what ``auto`` runs for
+    a ``jit`` over several devices outside ``shard_map``): batch-sharded
+    inputs, result matches the reference under SPMD."""
     from distributed_tensorflow_models_tpu.core import mesh as meshlib
     from distributed_tensorflow_models_tpu.ops import attention as attnlib
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -400,7 +399,7 @@ def test_qchunk_blockwise_under_sharded_mesh(mesh8):
     q, k, v = mk(), mk(), mk()
     out = jax.jit(
         lambda q, k, v: attnlib.blockwise_attention(
-            q, k, v, causal=True, block_kv=16, block_q=16
+            q, k, v, causal=True, block_kv=16
         )
     )(q, k, v)
     ref = attnlib.reference_attention(q, k, v, causal=True)
@@ -427,9 +426,9 @@ def test_flash_under_sharded_mesh(mesh8):
     v = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
 
     def core(q, k, v):
-        out = attnlib.flash_attention(
-            q, k, v, True, None, 64, 64, True  # causal, interpret
-        )
+        out = attnlib.flash_attention_chunk(
+            q, k, v, 0, 0, True, None, 64, 64, True  # causal, interpret
+        )[0]
         return jnp.mean(out**2)
 
     def sharded_over(mesh):

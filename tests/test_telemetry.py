@@ -362,7 +362,7 @@ def test_goodput_report_clamps_overattribution():
 def test_mfu_scales_by_device_count():
     """The FLOPs numerator is the GLOBAL program's cost, so MFU must
     divide by per-chip peak x mesh size — not report >100% on any
-    multi-chip mesh (the bench.py global/per-chip convention)."""
+    multi-chip mesh."""
     reg = telemetry.MetricsRegistry()
     reg.counter(telemetry.FLOPS_TOTAL).inc(197e12)  # one chip-second of v5e
     rep1 = telemetry.goodput_report(
