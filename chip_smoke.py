@@ -25,7 +25,8 @@ Default phases (one chip):
   byte-identical to solo ``generate`` at matmul precision "highest"
   (see ``phase_serve`` for what the chip's default precision allows).
 - ``kernels`` — the Pallas kernels compiled by Mosaic (never interpret)
-  against their plain references at real shapes.
+  against their plain references at real shapes, and the fused LM head
+  at the token cells' shapes against f32 autodiff of the two-stage head.
 
 ``--chips 4`` runs only the data-parallel comparison: the same ``fit``
 on a one-device mesh and on all four, in this one process.
@@ -91,6 +92,12 @@ FLASH_SHAPES = (
     (16, 512, 8, 64), (4, 2048, 8, 64), (8, 1024, 16, 64), (1, 4096, 16, 128),
 )
 CONV_SHAPE = dict(batch=256, size=56, cin=64, cout=64)
+# (B, T, d, V, bias) of the fused LM head: ``gpt2m_train``'s and
+# ``olmoe_train``'s.
+HEAD_SHAPES = ((8, 1024, 1024, 50257, True), (4, 4096, 2048, 50304, False))
+# Vocabulary-sized products (2 n d V FLOPs each) the compiled head may
+# hold per row: logits, dlogits . W^T and x^T . dlogits, and no fourth.
+HEAD_PRODUCTS = (2.95, 3.10)
 # bf16 inputs and outputs against an f32 reference: errors are compared
 # to the reference's largest magnitude.
 KERNEL_TOL = 2e-2
@@ -541,8 +548,86 @@ def _check_kernel(name, fn, ref_fn, args, report):
         )
 
 
+def _check_head(shape, report):
+    """The fused LM head (``ops/losses.py::fused_unembed_mean_xent``, bf16
+    products) and the per-token op under ``jnp.mean`` that it replaced in
+    ``fit``, both against f32 autodiff of the two-stage head at highest
+    precision; and the products the new op's compiled program holds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_tensorflow_models_tpu.core import train_loop
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    B, T, d, V, with_bias = shape
+    rng = np.random.RandomState(0)
+    hidden = jnp.asarray(rng.randn(B, T, d).astype(np.float32), jnp.bfloat16)
+    kernel = jnp.asarray(rng.randn(d, V).astype(np.float32) * 0.02)
+    targets = jnp.asarray(rng.randint(0, V, (B, T)), jnp.int32)
+    args = [hidden, kernel]
+    names = ["dhidden", "dkernel"]
+    if with_bias:
+        args.append(jnp.asarray(rng.randn(V).astype(np.float32) * 0.02))
+        names.append("dbias")
+
+    def two_stage(h, k, b=None):
+        logits = h.reshape(-1, d) @ k
+        return losslib.mean_softmax_cross_entropy(
+            logits if b is None else logits + b, targets.reshape(-1)
+        )
+
+    heads = {
+        "autodiff_head": lambda h, k, b=None: jnp.mean(
+            losslib.chunked_unembed_xent(h, k, b, targets)
+        ),
+        "fused_head": lambda h, k, b=None: losslib.fused_unembed_mean_xent(
+            h, k, b, targets
+        ),
+    }
+
+    def with_grads(head):
+        return jax.jit(
+            jax.value_and_grad(head, argnums=tuple(range(len(args))))
+        )
+
+    with jax.default_matmul_precision("highest"):
+        want = with_grads(two_stage)(*[a.astype(jnp.float32) for a in args])
+    tag = f"_{B}x{T}x{d}_v{V}"
+    worst = {}
+    for which, head in heads.items():
+        lowered = with_grads(head).lower(*args)
+        compiled = lowered.compile()
+        loss, grads = compiled(*args)
+        errs = {"loss": _normalized_err(loss, want[0])}
+        for name, g, wg in zip(names, grads, want[1]):
+            errs[name] = _normalized_err(g, wg)
+        worst[which] = max(errs.values())
+        report[which + tag] = {k: round(v, 5) for k, v in errs.items()}
+        if not worst[which] <= KERNEL_TOL:
+            raise AssertionError(
+                f"{which}{tag}: normalized error {errs} exceeds {KERNEL_TOL}"
+            )
+    if worst["fused_head"] > 1.25 * worst["autodiff_head"]:
+        raise AssertionError(
+            f"fused_head{tag} is less exact than the op it replaced: {worst}"
+        )
+    # ``compiled`` is the fused head's: the loop's last.
+    products = train_loop.program_flops(lowered, compiled) / (
+        2.0 * B * T * d * V
+    )
+    report["fused_head" + tag]["vocab_products_per_row"] = round(products, 3)
+    if not HEAD_PRODUCTS[0] <= products <= HEAD_PRODUCTS[1]:
+        raise AssertionError(
+            f"fused_head{tag}: {products:.3f} vocabulary-sized products a "
+            f"row, not within {HEAD_PRODUCTS}"
+        )
+
+
 def phase_kernels(
-    flash_shapes: tuple = FLASH_SHAPES, conv_shape: dict = CONV_SHAPE
+    flash_shapes: tuple = FLASH_SHAPES,
+    conv_shape: dict = CONV_SHAPE,
+    head_shapes: tuple = HEAD_SHAPES,
 ) -> dict:
     import jax
     import jax.numpy as jnp
@@ -596,6 +681,8 @@ def phase_kernels(
         [bf16(b, s, s, cin), bf16(3, 3, cin, cout, scale=0.05)],
         report,
     )
+    for shape in head_shapes:
+        _check_head(shape, report)
     return {
         "compiled_by": "mosaic (interpret=False, tpu_custom_call present)",
         "tolerance": KERNEL_TOL,

@@ -112,7 +112,7 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
     """Forward + loss for the PTB LSTM (SURVEY.md §2.1 R8).
 
     ``fused_unembed=True`` routes the head projection + cross entropy
-    through :func:`...ops.losses.chunked_unembed_xent` (the model must
+    through :func:`...ops.losses.fused_unembed_mean_xent` (the model must
     accept ``return_hidden=True`` — the transformer does); bfloat16 MXU
     matmul, f32 accumulation, O(chunk, V) peak memory instead of
     O(B·T·V).
@@ -142,8 +142,9 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
         if fused_unembed:
             # Fused path: the model stops at the post-ln_f hidden states
             # and the head projection + xent run chunked in one op —
-            # never materializing [B*T, V] f32 logits
-            # (ops/losses.py::chunked_unembed_xent).
+            # never materializing [B*T, V] f32 logits, and finishing
+            # the head's gradient while each chunk's logits are live
+            # (ops/losses.py::fused_unembed_mean_xent).
             (hidden, new_carry), updated = apply_fn(
                 {"params": params},
                 batch["inputs"],
@@ -154,13 +155,11 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
                 return_hidden=True,
             )
             head = params["head"]
-            nll = jnp.mean(
-                losslib.chunked_unembed_xent(
-                    hidden,
-                    head["kernel"],
-                    head.get("bias"),
-                    batch["targets"],
-                )
+            nll = losslib.fused_unembed_mean_xent(
+                hidden,
+                head["kernel"],
+                head.get("bias"),
+                batch["targets"],
             )
         else:
             (logits, new_carry), updated = apply_fn(

@@ -448,9 +448,10 @@ def fit(
     # declared-coverage check rightly treats absence as a failure.
     registry.counter(telemetry.ROLLBACKS)
     registry.counter(telemetry.SKIPPED_BATCHES)
-    # The attention op counts its route choices in the process-global
-    # registry, at trace time; the report gets what this run traced.
-    routes0 = _attention_routes()
+    # The attention op counts its route choices, and the fused LM head
+    # its gradient-in-forward calls, in the process-global registry, at
+    # trace time; the report gets what this run traced.
+    traced0 = _trace_counts()
     # Structured event tracing + flight recorder (telemetry/trace.py,
     # README "Observability"): the run's tracer rides the registry, so
     # every component the registry already reaches (pipeline, step,
@@ -1212,7 +1213,7 @@ def fit(
         _final_dump("crash")
         _export_trace(workdir, registry, cfg, step_fn)
         _write_telemetry_report(
-            workdir, registry, t_run0, steps_run, routes0
+            workdir, registry, t_run0, steps_run, traced0
         )
         raise
     else:
@@ -1241,7 +1242,7 @@ def fit(
         )
         _export_trace(workdir, registry, cfg, step_fn)
         _write_telemetry_report(
-            workdir, registry, t_run0, steps_run, routes0
+            workdir, registry, t_run0, steps_run, traced0
         )
         if chaos is not None and not preempted:
             # A drill whose fault never injected must not exit 0 looking
@@ -1325,28 +1326,31 @@ def _export_trace(
         log.exception("trace export failed")
 
 
-def _attention_routes() -> dict[str, float]:
-    """``attention(impl="auto")``'s route counters, as the process-global
-    registry holds them now."""
+def _trace_counts() -> dict[str, float]:
+    """What the ops count at trace time (``attention(impl="auto")``'s
+    routes, the fused head's gradient-in-forward calls), as the
+    process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
         for name in (
-            telemetry.ATTN_ROUTE_FUSED, telemetry.ATTN_ROUTE_BLOCKWISE
+            telemetry.ATTN_ROUTE_FUSED,
+            telemetry.ATTN_ROUTE_BLOCKWISE,
+            telemetry.UNEMBED_GRAD_IN_FORWARD,
         )
     }
 
 
 def _write_telemetry_report(
     workdir: str, registry: telemetry.MetricsRegistry,
-    t_run0: float, steps_run: int, routes0: dict[str, float],
+    t_run0: float, steps_run: int, traced0: dict[str, float],
 ) -> None:
     """Chief-only, best-effort ``telemetry.json`` goodput report."""
     if jax.process_index() != 0:
         return
     try:
-        for name, count in _attention_routes().items():
-            registry.counter(name).inc(count - routes0[name])
+        for name, count in _trace_counts().items():
+            registry.counter(name).inc(count - traced0[name])
         report = telemetry.goodput_report(
             registry, total_s=time.perf_counter() - t_run0, steps=steps_run
         )

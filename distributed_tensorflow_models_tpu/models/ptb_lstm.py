@@ -138,7 +138,7 @@ class PTBLSTM(nn.Module):
                 )(x)
         if return_hidden:
             # Fused chunked unembed+xent path
-            # (ops/losses.py::chunked_unembed_xent): the head projection —
+            # (ops/losses.py::fused_unembed_mean_xent): the head projection —
             # HALF this model's per-token FLOPs (2·h·V vs ~2·8h² for the
             # LSTM stack at h=650, V=10k) — runs inside the loss instead.
             return x, tuple(new_carry)

@@ -655,7 +655,7 @@ class TransformerLM(nn.Module):
     ):
         """``return_hidden=True`` returns the post-``ln_f`` hidden states
         instead of logits, for the fused chunked unembed+xent loss
-        (:func:`...ops.losses.chunked_unembed_xent`) — the head parameters
+        (:func:`...ops.losses.fused_unembed_mean_xent`) — the head parameters
         still exist (init uses the default path) and the loss consumes
         them directly from ``params``."""
         self._check_settings()

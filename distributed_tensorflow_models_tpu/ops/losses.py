@@ -8,43 +8,31 @@ regularizer added to every conv/fc kernel.
 
 from __future__ import annotations
 
-import os
-import sys
-from typing import Any, Callable, Union
+import functools
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
+from distributed_tensorflow_models_tpu.telemetry.registry import (
+    UNEMBED_GRAD_IN_FORWARD,
+    get_registry,
+)
+
 PyTree = Any
 
 # ``jax.named_scope`` of the LM head's projection + cross entropy (fused:
-# :func:`chunked_unembed_xent`; plain: :func:`token_xent` over the model's
-# own logits): a path element of every instruction's ``op_name`` in the
-# compiled step, which ``step_scopes_p<i>.json`` carries to the device
-# trace (PERF.md section 3).
+# :func:`fused_unembed_mean_xent`; plain: :func:`token_xent` over the
+# model's own logits): a path element of every instruction's ``op_name``
+# in the compiled step, which ``step_scopes_p<i>.json`` carries to the
+# device trace (PERF.md section 3).
 UNEMBED_LOSS_SCOPE = "unembed_loss"
 
-
-def resolve_unembed_chunk(default: int = 2048) -> int:
-    """Trace-time DTM_UNEMBED_CHUNK resolution (the DTM_CONV_IMPL
-    contract: invalid values fail loudly naming the knob).  The knob
-    exists for the r3 TPU surprise — the two-stage head beat the fused
-    path ~3% at b16, and one hypothesis is per-chunk checkpoint
-    boundaries (4 segments at the 2048 default); chunk_rows >= B*T
-    collapses the fused head to a single remat'd segment, isolating
-    chunking cost from fusion benefit."""
-    env = os.environ.get("DTM_UNEMBED_CHUNK")
-    if not env:
-        return default
-    try:
-        v = int(env)
-    except ValueError:
-        raise ValueError(
-            f"DTM_UNEMBED_CHUNK must be an integer, got {env!r}"
-        ) from None
-    if v < 1:
-        raise ValueError(f"DTM_UNEMBED_CHUNK must be >= 1, got {env!r}")
-    return v
+# Rows of one chunk of the fused head, clamped to the rows there are.
+# Measured on a v5e at both token cells' head shapes (PERF.md PR 29):
+# 1,024 rows cost 15%, 2,048 cost 0.7% and 3.6%, and 8,192 buy 1.0% and
+# 3.4% for twice the ``[rows, V]`` f32 temporaries.
+UNEMBED_CHUNK_ROWS = 4096
 
 
 def softmax_cross_entropy(
@@ -120,6 +108,59 @@ def l2_weight_decay(
     return scale * total
 
 
+def _head_chunks(hidden, kernel, bias, targets, chunk_rows, compute_dtype):
+    """The fused head's operands, cut into row chunks: ``(chunks, kmat,
+    b32)`` with ``chunks`` a list of ``(x_i [c, d], t_i [c], real rows)``.
+    The tail chunk is zero-padded to ``c`` rows; only its first ``real``
+    rows count."""
+    d = hidden.shape[-1]
+    x = hidden.reshape(-1, d)
+    t = targets.reshape(-1)
+    n = x.shape[0]
+    c = min(chunk_rows, n)
+    pad = (-n) % c
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        t = jnp.pad(t, (0, pad))
+    xc = x.reshape(-1, c, d).astype(compute_dtype)
+    tc = t.reshape(-1, c)
+    # Static Python unroll, NOT lax.scan: XLA's cost analysis visits a
+    # scan body once regardless of trip count (core/train_loop.py,
+    # InstrumentedMultiStep), so a scanned head would silently vanish from
+    # FLOPs/MFU accounting.  The chunk count is small and static
+    # (B*T/chunk_rows).
+    chunks = [
+        (xc[i], tc[i], min(c, n - i * c)) for i in range(xc.shape[0])
+    ]
+    kmat = kernel.astype(compute_dtype)
+    b32 = None if bias is None else bias.astype(jnp.float32)
+    return chunks, kmat, b32
+
+
+def _chunk_logits(xi, kmat, b32):
+    """``[c, V]`` float32 logits of one chunk: the product in the
+    operands' dtype with float32 accumulation, the bias in float32."""
+    logits = jax.lax.dot_general(
+        xi, kmat, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return logits if b32 is None else logits + b32
+
+
+def _one_chunk_at_a_time(xi, carried):
+    """Tie a chunk's rows to what the chunk before it finished, so that
+    XLA cannot start (or merge) the chunks' independent logits products
+    together and hold every ``[c, V]`` block at once."""
+    return jax.lax.optimization_barrier((xi, carried))
+
+
+def _chunk_nll(logits, ti):
+    """``(nll [c], lse [c])`` of one chunk's logits, float32."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
+    return lse - picked, lse
+
+
 @jax.named_scope(UNEMBED_LOSS_SCOPE)
 def chunked_unembed_xent(
     hidden: jax.Array,
@@ -127,24 +168,21 @@ def chunked_unembed_xent(
     bias: jax.Array | None,
     targets: jax.Array,
     *,
-    chunk_rows: Union[int, str] = "auto",
+    chunk_rows: int = UNEMBED_CHUNK_ROWS,
     compute_dtype: jnp.dtype = jnp.bfloat16,
 ) -> jax.Array:
     """Per-token NLL of ``Dense(hidden) -> softmax xent`` WITHOUT ever
     materializing the full ``[B*T, V]`` float32 logits tensor.
 
-    The LM head is the single largest tensor in a small-vocab-model train
-    step (d512/V10k at B16/T512: 328 MB of f32 logits forward plus the
-    same again for the cotangent — more HBM traffic than all transformer
-    blocks combined) and the reference-style two-stage
-    ``logits = head(x); xent(logits)`` forces XLA to spill it.  This op
-    scans over row chunks: each chunk's ``[chunk, V]`` logits live only
-    inside one fused (projection -> logsumexp -> pick) body, the MXU
-    matmul runs in ``compute_dtype`` (bfloat16 — twice the f32 MXU issue
-    rate) with float32 accumulation, and ``jax.checkpoint`` makes the
-    backward recompute chunk logits instead of storing them — peak memory
-    drops from O(B*T*V) to O(chunk_rows*V) in both passes.  The kernel
-    cotangent accumulates across scan iterations automatically.
+    The plain-autodiff statement of the fused head: what wants per-token
+    values calls it, and :func:`fused_unembed_mean_xent` (the one ``fit``
+    runs, which needs only the mean) is tested against it.  Each chunk's
+    ``[chunk, V]`` logits live only inside one fused (projection ->
+    logsumexp -> pick) body, the MXU matmul runs in ``compute_dtype``
+    (bfloat16) with float32 accumulation, and ``jax.checkpoint`` makes the
+    backward recompute chunk logits instead of storing them: peak memory
+    O(chunk_rows*V) in both passes, at the price of a fourth
+    vocabulary-sized product per chunk.
 
     Equivalent math to ``softmax_cross_entropy(hidden @ kernel + bias,
     targets)`` (no label smoothing — LM targets are hard); with
@@ -159,53 +197,116 @@ def chunked_unembed_xent(
     Returns:
       ``[B, T]`` per-token negative log likelihood, float32.
     """
-    B, T, d = hidden.shape
-    n = B * T
-    x = hidden.reshape(n, d)
-    t = targets.reshape(n)
-    if chunk_rows == "auto":
-        # Resolved AT THE OP so every caller honors DTM_UNEMBED_CHUNK
-        # through one validation path (same placement as DTM_CONV_IMPL
-        # in ops/conv.py).
-        chunk_rows = resolve_unembed_chunk()
-    c = min(chunk_rows, n)
-    if c != chunk_rows and os.environ.get("DTM_UNEMBED_CHUNK"):
-        # The knob asked for a bigger chunk than this shape has rows:
-        # clamping is correct math but would silently mislabel an A/B
-        # artifact, so say what was actually measured (trace-time).
-        print(
-            f"[losses] DTM_UNEMBED_CHUNK={chunk_rows} clamped to {c} "
-            f"(B*T={n})",
-            file=sys.stderr,
-        )
-    pad = (-n) % c
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        t = jnp.pad(t, (0, pad))
-    xc = x.reshape(-1, c, d).astype(compute_dtype)
-    tc = t.reshape(-1, c)
-    kmat = kernel.astype(compute_dtype)
-    b32 = None if bias is None else bias.astype(jnp.float32)
+    chunks, kmat, b32 = _head_chunks(
+        hidden, kernel, bias, targets, chunk_rows, compute_dtype
+    )
 
     @jax.checkpoint
     def one_chunk(xi, ti):
-        logits = jax.lax.dot_general(
-            xi, kmat, (((1,), (0,)), ((), ())),
+        return _chunk_nll(_chunk_logits(xi, kmat, b32), ti)[0]
+
+    nll = jnp.concatenate([one_chunk(xi, ti) for xi, ti, _ in chunks])
+    return nll[: targets.size].reshape(targets.shape)
+
+
+def fused_unembed_mean_xent(
+    hidden: jax.Array,
+    kernel: jax.Array,
+    bias: jax.Array | None,
+    targets: jax.Array,
+    *,
+    chunk_rows: int = UNEMBED_CHUNK_ROWS,
+    compute_dtype: jnp.dtype = jnp.bfloat16,
+) -> jax.Array:
+    """Mean over tokens of :func:`chunked_unembed_xent`, with the gradient
+    finished while each chunk's logits are live.
+
+    A mean hands every token the same cotangent, so ``dlogits = (softmax
+    - onehot) / n`` is known the moment a chunk's logits exist: under
+    differentiation the forward pass makes both gradient products per
+    chunk (``dlogits . W^T`` and ``x^T . dlogits``, the latter summed into
+    ONE float32 ``[d, V]`` accumulator) and the backward pass only scales
+    them by the loss's cotangent.  Three vocabulary-sized products a
+    chunk where checkpointed autodiff runs four, nothing recomputed, no
+    ``[chunk, V]`` array alive outside a chunk.  Undifferentiated
+    (evaluation) it is one product a chunk.
+
+    Same arithmetic as autodiff of the per-token op: products in
+    ``compute_dtype`` with float32 accumulation (``dlogits`` stays float32
+    beside its ``compute_dtype`` operand, as autodiff hands it over),
+    softmax and log-sum-exp in float32; the weight gradient is summed
+    over chunks in float32.  Each traced differentiated call counts once
+    in ``unembed/grad_in_forward`` (the process-global registry).
+
+    Args as :func:`chunked_unembed_xent`.  Returns the float32 scalar.
+    """
+    return _mean_xent(
+        hidden, kernel, bias, targets, chunk_rows, jnp.dtype(compute_dtype)
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+@jax.named_scope(UNEMBED_LOSS_SCOPE)
+def _mean_xent(hidden, kernel, bias, targets, chunk_rows, compute_dtype):
+    chunks, kmat, b32 = _head_chunks(
+        hidden, kernel, bias, targets, chunk_rows, compute_dtype
+    )
+    total = jnp.zeros((), jnp.float32)
+    for xi, ti, real in chunks:
+        xi, total = _one_chunk_at_a_time(xi, total)
+        nll, _ = _chunk_nll(_chunk_logits(xi, kmat, b32), ti)
+        total = total + jnp.sum(nll[:real])
+    return total / targets.size
+
+
+@jax.named_scope(UNEMBED_LOSS_SCOPE)
+def _mean_xent_fwd(hidden, kernel, bias, targets, chunk_rows, compute_dtype):
+    get_registry().counter(UNEMBED_GRAD_IN_FORWARD).inc()
+    chunks, kmat, b32 = _head_chunks(
+        hidden, kernel, bias, targets, chunk_rows, compute_dtype
+    )
+    n = targets.size
+    d, V = kernel.shape
+    total = jnp.zeros((), jnp.float32)
+    dW = jnp.zeros((d, V), jnp.float32)
+    db = None if bias is None else jnp.zeros((V,), jnp.float32)
+    dxs = []
+    for xi, ti, real in chunks:
+        xi, dW = _one_chunk_at_a_time(xi, dW)
+        logits = _chunk_logits(xi, kmat, b32)
+        nll, lse = _chunk_nll(logits, ti)
+        dlogits = (
+            jnp.exp(logits - lse[:, None])
+            - jax.nn.one_hot(ti, V, dtype=jnp.float32)
+        ) / n
+        if real < xi.shape[0]:  # the zero-padded tail adds nothing
+            live = jnp.arange(xi.shape[0]) < real
+            dlogits = jnp.where(live[:, None], dlogits, 0.0)
+        total = total + jnp.sum(nll[:real])
+        dxs.append(
+            jax.lax.dot_general(
+                dlogits, kmat, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(hidden.dtype)
+        )
+        dW = dW + jax.lax.dot_general(
+            xi, dlogits, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        if b32 is not None:
-            logits = logits + b32
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
-        return lse - picked
+        if db is not None:
+            db = db + jnp.sum(dlogits, axis=0)
+    dx = jnp.concatenate(dxs)[:n].reshape(hidden.shape)
+    if db is not None:
+        db = db.astype(bias.dtype)
+    return total / n, (dx, dW.astype(kernel.dtype), db)
 
-    # Static Python unroll, NOT lax.scan: XLA's cost analysis visits a
-    # scan body once regardless of trip count (core/train_loop.py,
-    # InstrumentedMultiStep), so a scanned head would silently vanish from
-    # FLOPs/MFU accounting.  The chunk count is small and static
-    # (B*T/chunk_rows); each body stays checkpointed, so backward
-    # recomputes chunk logits either way.
-    nll = jnp.concatenate(
-        [one_chunk(xc[i], tc[i]) for i in range(xc.shape[0])]
-    )
-    return nll[:n].reshape(B, T)
+
+@jax.named_scope(UNEMBED_LOSS_SCOPE)
+def _mean_xent_bwd(chunk_rows, compute_dtype, residuals, g):
+    # The loss's cotangent scales what the forward pass finished; the
+    # integer targets have none.
+    scaled = [None if r is None else (g * r).astype(r.dtype) for r in residuals]
+    return (*scaled, None)
+
+
+_mean_xent.defvjp(_mean_xent_fwd, _mean_xent_bwd)

@@ -78,6 +78,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     STEP_TIME,
     TRACE_DROPPED,
     TRACE_EVENTS,
+    UNEMBED_GRAD_IN_FORWARD,
     WATCHDOG_LAST_PROGRESS,
     WORKER_BUSY,
     Counter,

@@ -76,6 +76,12 @@ BUFFER_FRESH = "pipeline/buffer_fresh"  # counter
 # what its own run traced into ``telemetry.json``.
 ATTN_ROUTE_FUSED = "attention/route_fused"  # counter
 ATTN_ROUTE_BLOCKWISE = "attention/route_blockwise"  # counter
+# ``ops/losses.py::fused_unembed_mean_xent`` traced under differentiation:
+# the fused LM head made its gradient in the forward pass (three
+# vocabulary-sized products a chunk).  One increment per traced call, in
+# the process-global registry like the attention routes; 0 for a model
+# with no fused head.
+UNEMBED_GRAD_IN_FORWARD = "unembed/grad_in_forward"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

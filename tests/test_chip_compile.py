@@ -177,6 +177,38 @@ def test_auto_attention_compiles_fused_for_v5e(v5e, monkeypatch, shape, dtype):
     assert not re.search(r"\bwhile\(", text)
 
 
+@pytest.mark.parametrize(
+    "shape", [(8, 1024, 1024, 50257, True), (4, 4096, 2048, 50304, False)],
+    ids=["gpt2m", "olmoe"],
+)
+def test_fused_head_compiles_chunk_by_chunk_for_v5e(v5e, shape):
+    """The fused LM head at the two token cells' shapes, value and
+    gradients, for the described chip: three products of 2 n d V in the
+    compiled program (no recomputed forward), and temporaries of about
+    one chunk's ``[4096, V]`` f32 logits, not every chunk's: the chunks'
+    independent logits products are neither merged nor started together
+    (``ops/losses.py::_one_chunk_at_a_time``)."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    B, T, d, V, with_bias = shape
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda dtype, *dims: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    bias = spec(jnp.float32, V) if with_bias else None
+    compiled = jax.jit(
+        jax.value_and_grad(
+            lambda h, k, b, t: losslib.fused_unembed_mean_xent(h, k, b, t),
+            argnums=(0, 1, 2) if with_bias else (0, 1),
+        )
+    ).lower(
+        spec(jnp.bfloat16, B, T, d), spec(jnp.float32, d, V), bias,
+        spec(jnp.int32, B, T),
+    ).compile()
+    flops = compiled.cost_analysis()["flops"]
+    assert 2.95 <= flops / (2.0 * B * T * d * V) <= 3.10
+    one_block = losslib.UNEMBED_CHUNK_ROWS * V * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * one_block
+
+
 def test_data_parallel_step_compiles_over_four_chips(v5e):
     """A small conv model's donated train step over a 4-device mesh of
     the described chips: the batch is split, the parameters replicated,

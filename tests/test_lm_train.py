@@ -111,55 +111,214 @@ def test_chunked_unembed_xent_exact_in_f32():
         np.testing.assert_allclose(a, b_, rtol=1e-5, atol=1e-6)
 
 
-def test_unembed_chunk_env_knob(monkeypatch):
-    """DTM_UNEMBED_CHUNK reroutes the fused head's chunk size at trace
-    time; the loss is chunk-size-invariant, and bad values fail loudly
-    naming the knob (the DTM_CONV_IMPL contract)."""
-    import optax
+def _head_case(with_bias, rows=(2, 7), d=16, V=33, seed=0):
+    rng = np.random.RandomState(seed)
+    Bc, Tc = rows
+    hidden = jnp.asarray(rng.randn(Bc, Tc, d).astype(np.float32))
+    kernel = jnp.asarray(rng.randn(d, V).astype(np.float32) * 0.1)
+    bias = (
+        jnp.asarray(rng.randn(V).astype(np.float32) * 0.1)
+        if with_bias else None
+    )
+    targets = jnp.asarray(rng.randint(0, V, (Bc, Tc)))
+    return hidden, kernel, bias, targets
 
-    from distributed_tensorflow_models_tpu.core import (
-        mesh as meshlib,
-        train_loop,
-    )
-    from distributed_tensorflow_models_tpu.core.train_state import (
-        TrainState,
-    )
-    from distributed_tensorflow_models_tpu.models import get_model
 
-    T = 16
-    model = get_model(
-        "transformer_lm", num_layers=1, num_heads=2, d_model=32,
-        d_ff=64, max_len=T, dropout_rate=0.0, vocab_size=50,
+def _value_and_grads(loss, hidden, kernel, bias):
+    """(value, dhidden, dkernel[, dbias]) of ``loss(hidden, kernel, bias)``."""
+    argnums = (0, 1) if bias is None else (0, 1, 2)
+    value, grads = jax.value_and_grad(loss, argnums=argnums)(
+        hidden, kernel, bias
     )
-    mesh = meshlib.data_parallel_mesh()
-    tx = optax.sgd(0.1)
-    state = TrainState.create(
-        model, tx, jax.random.key(0), jnp.zeros((2, T), jnp.int32)
-    )
-    state = train_loop.place_state(state, mesh)
-    tok = jnp.asarray(
-        np.random.RandomState(0).randint(0, 50, (8, T + 1)), jnp.int32
-    )
-    batch = {"inputs": tok[:, :-1], "targets": tok[:, 1:]}
-    loss_fn = train_loop.lm_loss_fn(model.apply, fused_unembed=True)
+    return (value, *grads)
 
-    def loss_at(chunk_env):
-        if chunk_env is None:
-            monkeypatch.delenv("DTM_UNEMBED_CHUNK", raising=False)
-        else:
-            monkeypatch.setenv("DTM_UNEMBED_CHUNK", chunk_env)
-        l, _ = loss_fn(
-            state.params, state, batch, {"dropout": jax.random.key(1)}
+
+def _normalized_err(got, want):
+    # chip_smoke.py's measure: the largest difference over the
+    # reference's largest magnitude.
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 0.37])
+@pytest.mark.parametrize("chunk_rows", [7, 4], ids=["divides", "padded"])
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+def test_fused_unembed_mean_xent_exact_in_f32(
+    with_bias, chunk_rows, cotangent
+):
+    """In f32 the gradient-in-forward head equals the mean of the
+    per-token op and the two-stage head to round-off: value, dhidden,
+    dkernel, dbias; B*T=14, so chunk 4 pads its tail."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    hidden, kernel, bias, targets = _head_case(with_bias)
+
+    def new(h, k, b):
+        return cotangent * losslib.fused_unembed_mean_xent(
+            h, k, b, targets, chunk_rows=chunk_rows,
+            compute_dtype=jnp.float32,
         )
-        return float(l)
 
-    base = loss_at(None)
-    np.testing.assert_allclose(loss_at("128"), base, rtol=1e-6)
-    np.testing.assert_allclose(loss_at("7"), base, rtol=1e-6)
-    with pytest.raises(ValueError, match="DTM_UNEMBED_CHUNK"):
-        loss_at("big")
-    with pytest.raises(ValueError, match="DTM_UNEMBED_CHUNK"):
-        loss_at("0")
+    def per_token(h, k, b):
+        return cotangent * jnp.mean(
+            losslib.chunked_unembed_xent(
+                h, k, b, targets, chunk_rows=chunk_rows,
+                compute_dtype=jnp.float32,
+            )
+        )
+
+    def two_stage(h, k, b):
+        logits = h.reshape(-1, h.shape[-1]) @ k
+        if b is not None:
+            logits = logits + b
+        return cotangent * jnp.mean(
+            losslib.softmax_cross_entropy(logits, targets.reshape(-1))
+        )
+
+    got = _value_and_grads(new, hidden, kernel, bias)
+    # The undifferentiated call (evaluation) is the same value.
+    np.testing.assert_allclose(
+        new(hidden, kernel, bias), got[0], rtol=1e-6, atol=1e-6
+    )
+    for ref in (per_token, two_stage):
+        want = _value_and_grads(ref, hidden, kernel, bias)
+        assert len(got) == len(want) == (4 if with_bias else 3)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+def _vocab_products(jaxpr, V):
+    """``dot_general``s with a vocabulary-sized operand or result, at any
+    depth of the jaxpr."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+            V in v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+        ):
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _vocab_products(sub, V)
+    return count
+
+
+@pytest.mark.parametrize("chunk_rows", [14, 7, 4])
+def test_fused_unembed_mean_xent_products_per_chunk(chunk_rows):
+    """Three vocabulary-sized products a chunk under differentiation
+    (logits, dlogits . W^T, x^T . dlogits: no recomputed forward), one
+    when only the value is wanted; the per-token op under autodiff
+    holds four."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    hidden, kernel, bias, targets = _head_case(True)
+    V = kernel.shape[1]
+    chunks = -(-targets.size // chunk_rows)
+
+    def new(h, k, b):
+        return losslib.fused_unembed_mean_xent(
+            h, k, b, targets, chunk_rows=chunk_rows
+        )
+
+    def per_token(h, k, b):
+        return jnp.mean(
+            losslib.chunked_unembed_xent(
+                h, k, b, targets, chunk_rows=chunk_rows
+            )
+        )
+
+    def products(fn):
+        return _vocab_products(
+            jax.make_jaxpr(fn)(hidden, kernel, bias).jaxpr, V
+        )
+
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2))
+    assert products(new) == chunks
+    assert products(grad(new)) == 3 * chunks
+    assert products(grad(per_token)) == 4 * chunks
+
+
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+def test_fused_unembed_mean_xent_bf16_products_near_f32(with_bias):
+    """bf16 products (f32 accumulation, f32 softmax) stay within
+    chip_smoke.py's KERNEL_TOL of the f32 op, value and gradients."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    hidden, kernel, bias, targets = _head_case(
+        with_bias, rows=(4, 32), d=64, V=257, seed=3
+    )
+
+    def at(dtype):
+        return _value_and_grads(
+            lambda h, k, b: losslib.fused_unembed_mean_xent(
+                h, k, b, targets, chunk_rows=48, compute_dtype=dtype
+            ),
+            hidden, kernel, bias,
+        )
+
+    got, want = at(jnp.bfloat16), at(jnp.float32)
+    assert all(g.dtype == jnp.float32 for g in got)
+    errs = [_normalized_err(g, w) for g, w in zip(got, want)]
+    assert 0.0 < max(errs) <= 2e-2, errs
+
+
+@pytest.mark.parametrize("chunk_rows", [128, 48, 7])
+def test_fused_unembed_independent_of_chunk_rows(chunk_rows):
+    """The chunk is the code's choice (4,096 rows, clamped to B*T), not
+    a knob: loss and gradients, bf16 products as ``fit`` runs them, do
+    not depend on it."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+
+    assert losslib.UNEMBED_CHUNK_ROWS == 4096
+    hidden, kernel, bias, targets = _head_case(
+        True, rows=(8, 16), d=32, V=50, seed=5
+    )
+
+    def at(**kw):
+        return _value_and_grads(
+            lambda h, k, b: losslib.fused_unembed_mean_xent(
+                h, k, b, targets, **kw
+            ),
+            hidden, kernel, bias,
+        )
+
+    want = at()  # the default: one chunk of the 128 rows there are
+    for g, w in zip(at(chunk_rows=chunk_rows), want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
+def test_unembed_grad_in_forward_counter():
+    """``unembed/grad_in_forward`` counts traced differentiated calls of
+    the fused head, and nothing else."""
+    from distributed_tensorflow_models_tpu.ops import losses as losslib
+    from distributed_tensorflow_models_tpu.telemetry import (
+        registry as reglib,
+    )
+
+    hidden, kernel, bias, targets = _head_case(True)
+    counter = reglib.get_registry().counter(reglib.UNEMBED_GRAD_IN_FORWARD)
+    new = lambda h, k, b: losslib.fused_unembed_mean_xent(
+        h, k, b, targets, chunk_rows=4
+    )
+    start = counter.value
+    new(hidden, kernel, bias)  # evaluation: no gradient work
+    jax.jit(new)(hidden, kernel, bias)
+    jax.grad(
+        lambda h: jnp.mean(
+            losslib.chunked_unembed_xent(h, kernel, bias, targets)
+        )
+    )(hidden)
+    logits = hidden.reshape(-1, hidden.shape[-1]) @ kernel + bias
+    jax.grad(
+        lambda lg: jnp.mean(losslib.token_xent(lg, targets.reshape(-1)))
+    )(logits)
+    assert counter.value == start
+    step = jax.jit(jax.value_and_grad(new, argnums=(0, 1, 2)))
+    step(hidden, kernel, bias)
+    assert counter.value == start + 1
+    step(hidden, kernel, bias)  # compiled: not traced again
+    assert counter.value == start + 1
+    jax.grad(new)(hidden, kernel, bias)
+    assert counter.value == start + 2
 
 
 def test_chunked_unembed_xent_no_bias():
