@@ -47,8 +47,18 @@ class OptimizerConfig:
     # takes precedence over the exponential fields.
     steps_per_epoch: Optional[int] = None
     hold_epochs: Optional[int] = None
+    # Linear warm-up over this many steps, on top of whatever follows
+    # (0: none).  Step ``t`` (from 0) runs at ``(t + 1) / warmup_steps``
+    # of the schedule's value.
+    warmup_steps: int = 0
 
     def schedule(self) -> float | optax.Schedule:
+        base = self._after_warmup()
+        if not self.warmup_steps:
+            return base
+        return optim.linear_warmup(base, self.warmup_steps)
+
+    def _after_warmup(self) -> float | optax.Schedule:
         if self.steps_per_epoch is not None and self.hold_epochs is not None:
             return optim.zaremba_decay(
                 self.learning_rate,
@@ -467,6 +477,72 @@ _add(
         global_batch_size=8,
         num_steps=4096,
         vocab_size=50304,
+    )
+)
+
+# Kimi-Linear-48B-A3B (Kimi Linear technical report, Moonshot AI 2025,
+# arXiv:2510.26692; config.json of moonshotai/Kimi-Linear-48B-A3B-Instruct):
+# 27 pre-norm RMSNorm (1e-5) layers without a position encoding, three
+# KDA mixers (32 heads of 128, short convolution 4) to one MLA mixer
+# (kv_lora_rank 512, 128 + 64 query/key and 128 value channels, no query
+# compression, nothing rotated); layer 1 a dense gated feed-forward of
+# 9216, the other 26 with 256 gated experts of 1024, sigmoid scores, top-8
+# renormalised times 2.446, one shared expert; vocabulary 163840, untied,
+# no bias.  The router's selection bias is a buffer outside the gradient,
+# held at zero, and there is no auxiliary loss.  Adam at 3e-4 like the
+# other language models, behind a linear warm-up of 2,000 steps: at the
+# full rate from the first step this router, which nothing balances, sends
+# every token to the same eight experts within twenty steps (PERF.md, PR
+# 30).  Every size is the published one; no single chip holds this in training
+# (benchmark/configs/kimi_linear.json runs five layers, 8 of the experts
+# and an eighth of the vocabulary: one chip's share of 32 x 8).
+_KIMI_FULL_ATTENTION = (4, 8, 12, 16, 20, 24, 27)  # 1-based, config.json
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="kimi_linear",
+        model_kwargs={
+            "vocab_size": 163840,
+            "num_layers": 27,
+            "num_heads": 32,
+            "d_model": 2304,
+            "d_ff": 1024,
+            "dense_d_ff": 9216,
+            "max_len": 8192,
+            "dropout_rate": 0.0,
+            "pos_encoding": "none",
+            "norm": "rmsnorm",
+            "norm_eps": 1e-5,
+            "use_bias": False,
+            "mlp": "gated_silu",
+            "layer_mixers": tuple(
+                "mla" if i in _KIMI_FULL_ATTENTION else "kda"
+                for i in range(1, 28)
+            ),
+            "kda_num_heads": 32,
+            "kda_head_dim": 128,
+            "kda_conv_size": 4,
+            "mla_kv_lora_rank": 512,
+            "mla_nope_dim": 128,
+            "mla_rope_dim": 64,
+            "mla_v_dim": 128,
+            "num_experts": 256,
+            "moe_router": "topk",
+            "moe_top_k": 8,
+            "moe_layers": "all",
+            "moe_first_dense": 1,
+            "moe_scoring": "sigmoid",
+            "moe_renormalize": True,
+            "moe_routed_scale": 2.446,
+            "moe_shared_experts": 1,
+            "moe_aux_loss_weight": 0.0,
+            "remat": True,
+        },
+        global_batch_size=2,
+        num_steps=8192,
+        vocab_size=163840,
+        optimizer=dataclasses.replace(
+            _CONFIGS["transformer_lm"].optimizer, warmup_steps=2000
+        ),
     )
 )
 

@@ -1,0 +1,162 @@
+"""Token mixers beside full attention, for stacks whose layers differ.
+
+Kimi Linear (Kimi Linear technical report, Moonshot AI 2025,
+arXiv:2510.26692; ``config.json`` and the published modelling code of
+moonshotai/Kimi-Linear-48B-A3B-Instruct) alternates two mixers, three to
+one:
+
+- :class:`KDAMixer`: Kimi Delta Attention, a gated delta-rule linear
+  attention with a decay per channel.  Per head of ``head_dim``: ``q, k =
+  l2norm(silu(conv(W x)))``, ``v = silu(conv(W_v x))`` (causal depthwise
+  convolutions of ``conv_size``, no bias); the log decay ``g_t =
+  -exp(A_log) * softplus(W_f2 W_f1 x + dt_bias)`` per channel (``A_log``
+  per head), ``b_t = sigmoid(W_b x)`` per head; the state and the read of
+  :mod:`...ops.linear_attention`; ``y = W_o (rmsnorm_head(o_t) *
+  sigmoid(W_g2 W_g1 x))``.  The two low-rank gates go through
+  ``head_dim`` channels.
+- :class:`LatentAttention`: multi-head latent attention without query
+  compression and **without positions** (``mla_use_nope``): ``q = W_q x``
+  (``nope_dim + rope_dim`` channels a head; the names are the config's,
+  nothing is rotated), ``c, k_r = split(W_kva x)``, ``c = rmsnorm(c)``,
+  ``k_n, v = split(W_kvb c)``, the key of a head ``[k_n, k_r]`` with
+  ``k_r`` shared by the heads, causal softmax at ``(nope_dim +
+  rope_dim)^-0.5``.  The core is :func:`...ops.attention.attention`,
+  with more query/key channels than value channels.
+
+Both compute in ``dtype`` over float32 parameters; the norms, the decay,
+``b_t``, the l2 norms and the output gate's norm are float32.  Neither
+decodes: the recurrent state and the latent cache have no place in
+``serving/kv_slots.py`` yet (ROADMAP Queue 2).
+"""
+
+from __future__ import annotations
+
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from distributed_tensorflow_models_tpu.ops import attention as attnlib
+from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
+
+# ``jax.named_scope`` (and flax module) name of the whole KDA mixer; the
+# chunk-wise core inside it is ``ops/linear_attention.py::KDA_CORE_SCOPE``.
+LINEAR_ATTN_SCOPE = "linear_attn"
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, dtype=dtype, use_bias=False, name=name)
+
+
+def causal_depthwise_conv(x, w):
+    """``y_t = sum_j w[j] * x_{t - (K-1) + j}`` per channel: ``x`` ``[B,
+    T, C]``, ``w`` ``[K, C]``; positions before the sequence are zeros."""
+    K, T = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(padded[:, j : j + T] * w[j].astype(x.dtype) for j in range(K))
+
+
+def l2norm(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
+
+
+class KDAMixer(nn.Module):
+    num_heads: int
+    head_dim: int
+    d_model: int
+    conv_size: int = 4
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, D = self.num_heads, self.head_dim
+        width = H * D
+
+        def conv_weight(name):
+            # torch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in K.
+            bound = self.conv_size**-0.5
+            return self.param(
+                name,
+                lambda rng: jax.random.uniform(
+                    rng, (self.conv_size, width), jnp.float32, -bound, bound
+                ),
+            )
+
+        def mixed(name):
+            y = _dense(width, self.dtype, name)(x)
+            y = causal_depthwise_conv(y, conv_weight(f"conv_{name}"))
+            return jax.nn.silu(y).reshape(B, T, H, D)
+
+        q = l2norm(mixed("query")).astype(self.dtype)
+        k = l2norm(mixed("key")).astype(self.dtype)
+        v = mixed("value")
+
+        # The decay: -exp(A_log) * softplus(low-rank(x) + dt_bias), float32.
+        a_log = self.param(
+            "A_log",
+            lambda rng: jnp.log(jax.random.uniform(rng, (H,), jnp.float32, 1.0, 16.0)),
+        )
+
+        def dt_bias_init(rng):
+            # The inverse softplus of a step drawn log-uniformly from
+            # [1e-3, 1e-1] (the published layer's initialisation).
+            dt = jnp.exp(
+                jax.random.uniform(
+                    rng, (width,), jnp.float32, math.log(1e-3), math.log(1e-1)
+                )
+            )
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        dt_bias = self.param("dt_bias", dt_bias_init)
+        f = _dense(width, self.dtype, "f_b")(_dense(D, self.dtype, "f_a")(x))
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            (f.astype(jnp.float32) + dt_bias).reshape(B, T, H, D)
+        )
+        beta = jax.nn.sigmoid(_dense(H, self.dtype, "beta")(x).astype(jnp.float32))
+
+        o = linattn.chunked_kda(q, k, v, g, beta)
+
+        gate = _dense(width, self.dtype, "g_b")(_dense(D, self.dtype, "g_a")(x))
+        o = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="o_norm")(o)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(B, T, H, D))
+        return _dense(self.d_model, self.dtype, "out")(
+            o.astype(self.dtype).reshape(B, T, width)
+        )
+
+
+class LatentAttention(nn.Module):
+    num_heads: int
+    d_model: int
+    kv_lora_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, qk = self.num_heads, self.nope_dim + self.rope_dim
+        q = _dense(H * qk, self.dtype, "query")(x).reshape(B, T, H, qk)
+        kv = _dense(self.kv_lora_rank + self.rope_dim, self.dtype, "kv_a")(x)
+        c, k_r = kv[..., : self.kv_lora_rank], kv[..., self.kv_lora_rank :]
+        c = nn.RMSNorm(
+            epsilon=self.norm_eps, dtype=jnp.float32, name="kv_a_norm"
+        )(c).astype(self.dtype)
+        kv = _dense(H * (self.nope_dim + self.v_dim), self.dtype, "kv_b")(c)
+        kv = kv.reshape(B, T, H, self.nope_dim + self.v_dim)
+        k_n, v = kv[..., : self.nope_dim], kv[..., self.nope_dim :]
+        k_r = jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, self.rope_dim))
+        k = jnp.concatenate([k_n, k_r], axis=-1)
+        out = attnlib.attention(
+            q, k, v, causal=True, scale=qk**-0.5, impl=self.attn_impl
+        )
+        return _dense(self.d_model, self.dtype, "out")(
+            out.reshape(B, T, H * self.v_dim)
+        )

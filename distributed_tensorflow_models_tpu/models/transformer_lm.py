@@ -21,6 +21,15 @@ every other block, or softmax-then-top-k routing over gated experts in
 every block (:func:`...parallel.moe.topk_moe_ffn`; OLMoE, ROADMAP R1).
 These are a model's published settings, not tuning options.
 
+The layers of a stack may differ (Kimi Linear: ``harness/config.py::
+kimi_linear``): ``layer_mixers`` names each layer's token mixer (full
+attention, the delta-rule linear attention or the latent attention of
+:mod:`.mixers`), ``moe_first_dense`` leading layers keep a dense
+feed-forward (gated SiLU, ``dense_d_ff`` wide) before the expert layers
+start, an expert layer may have shared experts beside the routed ones,
+sigmoid scores renormalised and scaled, and hold a range of the router's
+experts only (``moe_held``: one chip's share of an expert-parallel job).
+
 TPU notes: bf16 compute with fp32 LayerNorm and logits; attention and MLP
 matmuls are [B·T, d]-shaped for the MXU; causal masking is positional (no
 materialized [T, T] mask when the blockwise/fused paths run).
@@ -34,7 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_models_tpu.models import register
+from distributed_tensorflow_models_tpu.models import mixers, register
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops.embed import TokenEmbed
 
@@ -175,6 +184,29 @@ class MLP(nn.Module):
         return h
 
 
+class GatedMLP(nn.Module):
+    """``down(silu(gate(x)) * up(x))`` without biases: the dense
+    feed-forward, and the shared expert, of the models that state it."""
+
+    d_model: int
+    d_ff: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        dense = lambda name, feats: nn.Dense(
+            feats, dtype=self.dtype, use_bias=False, name=name
+        )
+        h = nn.silu(dense("gate", self.d_ff)(x)) * dense("up", self.d_ff)(x)
+        return dense("down", self.d_model)(h)
+
+
+# ``jax.named_scope`` of the shared experts of an expert layer (inside
+# the layer's ``moe``): what every token goes through beside its routed
+# experts.
+MOE_SHARED_SCOPE = "moe_shared"
+
+
 class MoEFFN(nn.Module):
     """Switch-MoE FFN block: flax param declaration around
     :func:`...parallel.moe.moe_ffn` (expert-parallel all_to_all exchange
@@ -243,13 +275,17 @@ class MoEFFN(nn.Module):
 
 
 class TopKExpertsFFN(nn.Module):
-    """Softmax-then-top-k routing over gated (SiLU) experts without a
-    capacity: flax parameter declaration around
-    :func:`...parallel.moe.topk_moe_ffn`.  The weighted load-balancing
-    loss and router z-loss go into the ``losses`` collection (summed into
-    the objective by :func:`...core.train_loop.lm_loss_fn`); the
-    unweighted values and the load statistic into ``moe_stats``, which
-    the loss function averages over layers into the step's metrics."""
+    """Top-k routing over gated (SiLU) experts without a capacity: flax
+    parameter declaration around :func:`...parallel.moe.topk_moe_ffn`.
+    The weighted load-balancing loss and router z-loss go into the
+    ``losses`` collection (summed into the objective by
+    :func:`...core.train_loop.lm_loss_fn`; a weight of 0 puts nothing
+    there); the unweighted values and the load statistic into
+    ``moe_stats``, which the loss function averages over layers into the
+    step's metrics.  ``held = (first, count)``: the expert stacks hold
+    that range of the router's ``num_experts`` (``held_share`` joins the
+    statistics); ``shared_experts``: a gated MLP that many experts wide
+    on every token, added to the routed result."""
 
     num_experts: int
     top_k: int
@@ -259,12 +295,16 @@ class TopKExpertsFFN(nn.Module):
     aux_loss_weight: float = 0.01
     z_loss_weight: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
+    routing: Any = None  # parallel.moe.Routing; None: softmax as it is
+    held: Any = None
+    shared_experts: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         from distributed_tensorflow_models_tpu.parallel import moe as moelib
 
         E, d, f = self.num_experts, self.d_model, self.d_ff
+        here = E if self.held is None else self.held[1]
 
         def normal(name, shape, fan_in):
             return self.param(
@@ -274,27 +314,38 @@ class TopKExpertsFFN(nn.Module):
 
         params = {
             "router": normal("router", (d, E), d),
-            "w_gate": normal("w_gate", (E, d, f), d),
-            "w_up": normal("w_up", (E, d, f), d),
-            "w_down": normal("w_down", (E, f, d), f),
+            "w_gate": normal("w_gate", (here, d, f), d),
+            "w_up": normal("w_up", (here, d, f), d),
+            "w_down": normal("w_down", (here, f, d), f),
         }
         res = moelib.topk_moe_ffn(
-            params, x, top_k=self.top_k, mesh=self.mesh, dtype=self.dtype
+            params, x, top_k=self.top_k, mesh=self.mesh, dtype=self.dtype,
+            routing=self.routing or moelib.Routing(), held=self.held,
         )
         scalar = dict(
             reduce_fn=lambda a, b: a + b,
             init_fn=lambda: jnp.zeros((), jnp.float32),
         )
-        self.sow("losses", "moe_aux", self.aux_loss_weight * res.aux_loss, **scalar)
+        if self.aux_loss_weight:
+            self.sow("losses", "moe_aux", self.aux_loss_weight * res.aux_loss, **scalar)
         if self.z_loss_weight:
             self.sow("losses", "moe_z", self.z_loss_weight * res.z_loss, **scalar)
-        for name, value in (
+        stats = [
             ("aux_loss", res.aux_loss),
             ("z_loss", res.z_loss),
             ("load_max_over_mean", res.load_max_over_mean),
-        ):
+        ]
+        if self.held is not None:
+            stats.append(("held_share", res.held_share))
+        for name, value in stats:
             self.sow("moe_stats", name, value, **scalar)
-        return res.out.astype(x.dtype)
+        out = res.out.astype(x.dtype)
+        if self.shared_experts:
+            with jax.named_scope(MOE_SHARED_SCOPE):
+                out = out + GatedMLP(
+                    d, f * self.shared_experts, self.dtype, name="shared"
+                )(x)
+        return out
 
 
 class Block(nn.Module):
@@ -324,14 +375,91 @@ class Block(nn.Module):
     moe_router: str = "switch"
     moe_top_k: int = 1
     moe_z_loss_weight: float = 0.0
+    moe_aux_loss_weight: float = 0.01
+    moe_routing: Any = None
+    moe_held: Any = None
+    moe_shared_experts: int = 0
+    # The token mixer: "attention" (SelfAttention), "kda" or "mla"
+    # (models/mixers.py; ``mixer_kwargs`` are that module's sizes).
+    mixer: str = "attention"
+    mixer_kwargs: Any = None
+    # The dense feed-forward: the "gelu" MLP or the "gated_silu" one.
+    mlp: str = "gelu"
+    # Recompute in the backward pass, the mixer's half of the block and
+    # the feed-forward's each on its own (each with its norm): while one
+    # half runs backward the other keeps nothing but its input.
+    remat: bool = False
+
+    def _mix(self, h, train):
+        if self.mixer == "attention":
+            return self._attention(h, train)
+        sizes = dict(self.mixer_kwargs or ())
+        if self.mixer == "kda":
+            out = mixers.KDAMixer(
+                d_model=self.d_model, norm_eps=self.norm_eps or 1e-6,
+                dtype=self.dtype, name=mixers.LINEAR_ATTN_SCOPE, **sizes,
+            )(h)
+        else:
+            out = mixers.LatentAttention(
+                num_heads=self.num_heads, d_model=self.d_model,
+                norm_eps=self.norm_eps or 1e-6, dtype=self.dtype,
+                attn_impl=self.attn_impl, name="attn", **sizes,
+            )(h)
+        if self.dropout_rate:
+            out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
+        return out
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         norm = lambda name, y: make_norm(self.norm, self.norm_eps, name)(
             y
         ).astype(self.dtype)
-        h = norm("ln1", x)
-        x = x + SelfAttention(
+        mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
+        feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
+        if self.remat:
+            mix, feed = nn.remat(mix), nn.remat(feed)
+        x = x + mix(self, x)
+        return x + feed(self, x)
+
+    def _ffn(self) -> nn.Module:
+        if self.use_moe and self.moe_router == "topk":
+            return TopKExpertsFFN(
+                self.num_experts,
+                self.moe_top_k,
+                self.d_model,
+                self.d_ff,
+                self.moe_mesh,
+                aux_loss_weight=self.moe_aux_loss_weight,
+                z_loss_weight=self.moe_z_loss_weight,
+                dtype=self.dtype,
+                routing=self.moe_routing,
+                held=self.moe_held,
+                shared_experts=self.moe_shared_experts,
+                name="moe",
+            )
+        if self.use_moe:
+            return MoEFFN(
+                self.num_experts,
+                self.d_model,
+                self.d_ff,
+                self.moe_mesh,
+                capacity_factor=self.moe_capacity_factor,
+                dtype=self.dtype,
+                name="moe",
+            )
+        if self.mlp == "gated_silu":
+            return GatedMLP(self.d_model, self.d_ff, self.dtype, name="mlp")
+        return MLP(
+            self.d_model,
+            self.d_ff,
+            self.dropout_rate,
+            self.dtype,
+            use_bias=self.use_bias,
+            name="mlp",
+        )
+
+    def _attention(self, h, train):
+        return SelfAttention(
             self.num_heads,
             self.d_model,
             self.dropout_rate,
@@ -349,38 +477,6 @@ class Block(nn.Module):
             norm_eps=self.norm_eps,
             name="attn",
         )(h, train=train)
-        h = norm("ln2", x)
-        if self.use_moe and self.moe_router == "topk":
-            ffn = TopKExpertsFFN(
-                self.num_experts,
-                self.moe_top_k,
-                self.d_model,
-                self.d_ff,
-                self.moe_mesh,
-                z_loss_weight=self.moe_z_loss_weight,
-                dtype=self.dtype,
-                name="moe",
-            )
-        elif self.use_moe:
-            ffn = MoEFFN(
-                self.num_experts,
-                self.d_model,
-                self.d_ff,
-                self.moe_mesh,
-                capacity_factor=self.moe_capacity_factor,
-                dtype=self.dtype,
-                name="moe",
-            )
-        else:
-            ffn = MLP(
-                self.d_model,
-                self.d_ff,
-                self.dropout_rate,
-                self.dtype,
-                use_bias=self.use_bias,
-                name="mlp",
-            )
-        return x + ffn(h, train=train)
 
 
 class PipelinedBlocks(nn.Module):
@@ -584,9 +680,10 @@ class TransformerLM(nn.Module):
     pipelined: bool = False
     pipe_mesh: Any = None
     pipeline_microbatches: int = 4
-    # Rematerialize each block in backward (jax.checkpoint): trades ~1/3
-    # more FLOPs for O(num_layers) less activation HBM — the standard TPU
-    # long-context memory lever (SURVEY.md TPU notes).
+    # Rematerialize each half of a block (mixer, feed-forward) in backward
+    # (jax.checkpoint): trades ~1/3 more FLOPs for O(num_layers) less
+    # activation HBM — the standard TPU long-context memory lever
+    # (SURVEY.md TPU notes).
     remat: bool = False
     # Autoregressive decode mode: KV caches in the ``cache`` variable
     # collection (see SelfAttention); drive with harness/generate.py.
@@ -597,8 +694,9 @@ class TransformerLM(nn.Module):
     # Sliding-window (local) attention span; None = full causal.  Applies
     # to the dense non-pipelined stack (and decode).
     attn_window: Any = None
-    # Position encoding: "learned" absolute table (the default) or
-    # "rope" rotary relative positions applied inside attention.
+    # Position encoding: "learned" absolute table (the default), "rope"
+    # rotary relative positions applied inside attention, or "none" (a
+    # stack whose mixers carry the order themselves: Kimi Linear).
     pos_encoding: str = "learned"
     rope_theta: float = 10000.0
     # What the architecture states beyond the GPT-2 block (defaults: that
@@ -617,23 +715,76 @@ class TransformerLM(nn.Module):
     moe_top_k: int = 1
     moe_layers: str = "alternate"
     moe_z_loss_weight: float = 0.0
+    moe_aux_loss_weight: float = 0.01
+    # Under ``moe_layers="all"``: this many leading layers keep a dense
+    # feed-forward.  The top-k router's scores ("softmax", or a "sigmoid"
+    # of each logit), renormalised over the chosen experts or not, times
+    # ``moe_routed_scale``; shared experts beside the routed ones; and the
+    # range ``(first, count)`` of the ``num_experts`` router outputs whose
+    # experts this program holds (None: all of them).
+    moe_first_dense: int = 0
+    moe_scoring: str = "softmax"
+    moe_renormalize: bool = False
+    moe_routed_scale: float = 1.0
+    moe_shared_experts: int = 0
+    moe_held: Any = None
+    # The dense feed-forward: the "gelu" MLP or the bias-free
+    # "gated_silu" one, ``dense_d_ff`` wide where that differs from an
+    # expert's ``d_ff`` (0: the same).
+    mlp: str = "gelu"
+    dense_d_ff: int = 0
+    # Each layer's token mixer, "attention" | "kda" | "mla" (None: full
+    # attention everywhere), and the sizes models/mixers.py takes.
+    layer_mixers: Any = None
+    kda_num_heads: int = 0  # 0: num_heads
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    mla_kv_lora_rank: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+
+    def _mixers(self) -> tuple:
+        return tuple(self.layer_mixers or ("attention",) * self.num_layers)
 
     def _check_settings(self):
         """Refusals that depend on no input: raised when the model is
         first called, before anything is traced."""
         for name, value, known in (
-            ("pos_encoding", self.pos_encoding, ("learned", "rope")),
+            ("pos_encoding", self.pos_encoding, ("learned", "rope", "none")),
             ("norm", self.norm, ("layernorm", "rmsnorm")),
             ("moe_router", self.moe_router, ("switch", "topk")),
             ("moe_layers", self.moe_layers, ("alternate", "all")),
+            ("moe_scoring", self.moe_scoring, ("softmax", "sigmoid")),
+            ("mlp", self.mlp, ("gelu", "gated_silu")),
+            *(
+                (f"layer_mixers[{i}]", m, ("attention", "kda", "mla"))
+                for i, m in enumerate(self._mixers())
+            ),
         ):
             if value not in known:
                 raise ValueError(f"unknown {name} {value!r} (want one of {known})")
+        if len(self._mixers()) != self.num_layers:
+            raise ValueError(
+                f"layer_mixers names {len(self._mixers())} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+        plain = set(self._mixers()) == {"attention"}
+        if not plain and (self.decode or self.attention_fn is not None):
+            raise ValueError(
+                "the kda and mla mixers neither decode nor take a "
+                "sequence-parallel attention_fn: the recurrent state and "
+                "the latent cache have no place in serving/kv_slots.py yet "
+                "(ROADMAP Queue 2)"
+            )
         gpt2_block = (
             self.norm == "layernorm"
             and self.norm_eps is None
             and self.use_bias
             and not self.qk_norm
+            and plain
+            and self.mlp == "gelu"
+            and self.pos_encoding != "none"
         )
         if (self.pipelined or self.pipe_mesh is not None) and not gpt2_block:
             raise ValueError(
@@ -671,9 +822,9 @@ class TransformerLM(nn.Module):
             dtype=self.dtype,
             name="embedding",
         )(tokens)
-        if self.pos_encoding == "rope":
-            # Relative positions enter inside attention (q/k rotation);
-            # no absolute table.  Decode still tracks pos_index: the
+        if self.pos_encoding in ("rope", "none"):
+            # Relative positions enter inside attention (q/k rotation), or
+            # nowhere; no absolute table.  Decode still tracks pos_index: the
             # attention blocks' cache_index carries the offset, but
             # keeping this counter preserves one cache layout invariant
             # across both encodings.
@@ -747,22 +898,43 @@ class TransformerLM(nn.Module):
                 name="pipeline",
             )(x, train=train)
         else:
-            block_cls = (
-                nn.remat(Block, static_argnums=(2,))
-                if self.remat
-                else Block
-            )
-            for i in range(self.num_layers):
-                x = block_cls(
+            routing = None
+            if (self.moe_scoring, self.moe_renormalize, self.moe_routed_scale) != (
+                "softmax", False, 1.0,
+            ):
+                from distributed_tensorflow_models_tpu.parallel import moe as moelib
+
+                routing = moelib.Routing(
+                    self.moe_scoring, self.moe_renormalize, self.moe_routed_scale
+                )
+            mixer_kwargs = {
+                "kda": (
+                    ("num_heads", self.kda_num_heads or self.num_heads),
+                    ("head_dim", self.kda_head_dim),
+                    ("conv_size", self.kda_conv_size),
+                ),
+                "mla": (
+                    ("kv_lora_rank", self.mla_kv_lora_rank),
+                    ("nope_dim", self.mla_nope_dim),
+                    ("rope_dim", self.mla_rope_dim),
+                    ("v_dim", self.mla_v_dim),
+                ),
+            }
+            for i, mixer in enumerate(self._mixers()):
+                use_moe = self.num_experts > 0 and (
+                    i >= self.moe_first_dense
+                    if self.moe_layers == "all"
+                    else i % 2 == 1
+                )
+                x = Block(
                     self.num_heads,
                     self.d_model,
-                    self.d_ff,
+                    self.d_ff if use_moe else self.dense_d_ff or self.d_ff,
                     self.dropout_rate,
                     self.dtype,
                     self.attn_impl,
                     self.attention_fn,
-                    use_moe=self.num_experts > 0
-                    and (self.moe_layers == "all" or i % 2 == 1),
+                    use_moe=use_moe,
                     num_experts=self.num_experts,
                     moe_mesh=self.moe_mesh,
                     moe_capacity_factor=self.moe_capacity_factor,
@@ -779,6 +951,14 @@ class TransformerLM(nn.Module):
                     moe_router=self.moe_router,
                     moe_top_k=self.moe_top_k,
                     moe_z_loss_weight=self.moe_z_loss_weight,
+                    moe_aux_loss_weight=self.moe_aux_loss_weight,
+                    moe_routing=routing,
+                    moe_held=self.moe_held and tuple(self.moe_held),
+                    moe_shared_experts=self.moe_shared_experts,
+                    mixer=mixer,
+                    mixer_kwargs=mixer_kwargs.get(mixer),
+                    mlp=self.mlp,
+                    remat=self.remat,
                     name=f"blocks_{i}",
                 )(x, train)
         x = make_norm(self.norm, self.norm_eps, "ln_f")(x)

@@ -190,6 +190,7 @@ def blockwise_attention(
     fused kernels' pair list is what skips them.
     """
     B, Tq, H, D = q.shape
+    Dv = v.shape[-1]  # MLA: 192 query/key channels, 128 value channels
     window = _check_window(window)
     k, v = _expand_kv(q, k, v)
     Tkv = k.shape[1]
@@ -212,7 +213,7 @@ def blockwise_attention(
         kf = jnp.pad(kf, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = kf.reshape(B, H, nblocks, block_kv, D).transpose(2, 0, 1, 3, 4)
-    vb = vf.reshape(B, H, nblocks, block_kv, D).transpose(2, 0, 1, 3, 4)
+    vb = vf.reshape(B, H, nblocks, block_kv, Dv).transpose(2, 0, 1, 3, 4)
 
     qi = q_offset + jnp.arange(Tq)[:, None]  # [Tq, 1]
 
@@ -242,7 +243,7 @@ def blockwise_attention(
     # state must not accumulate in bf16).
     m0 = jnp.zeros_like(qf[..., :1], dtype=jnp.float32) + NEG_INF
     l0 = jnp.zeros_like(qf[..., :1], dtype=jnp.float32)
-    a0 = jnp.zeros_like(qf, dtype=jnp.float32)
+    a0 = l0 + jnp.zeros((Dv,), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0), (jnp.arange(nblocks), kb, vb)
     )
@@ -933,7 +934,15 @@ flash_attention_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 #   (S^T = K Q^T, so LSE and delta are lane-dense rows and dV, dK are plain
 #   products): five matmuls a pair where the dKV/dQ pair needs seven, dQ
 #   accumulated in a VMEM-resident ``[T, 128]`` f32 buffer per head block;
-# - tiles of 512 (measured; the flash pair's backward runs at 128).
+# - tiles of 512 (measured; the flash pair's backward runs at 128);
+# - latent attention (MLA: 192 query/key channels a head, 128 value
+#   channels) runs the same two kernels: a head is one 128-lane value
+#   block, and its query/key block is as many whole lane blocks as the
+#   channels need (256 for 192; ``attention`` pads with zeros, which add
+#   nothing to a score).  The scores, dK and dQ products then run 256 deep
+#   where the mathematics needs 192: 1408 where 1152 multiply-adds a score
+#   would do, 22% more, none of it more than a 128-wide MXU spends on a
+#   192-deep contraction anyway.
 #
 # Same mathematics as ``_block_update``/``_masked_scores``: scores from the
 # input dtype with f32 accumulation, the scale in f32 after the product,
@@ -955,7 +964,9 @@ def fused_admissible(
 ) -> bool:
     """Whether the fused kernels take this call: self-attention shapes
     (no grouped KV heads), head size 64 (an even number of heads) or 128,
-    a length some tile divides, no sliding window, and offsets that are
+    or 128 value channels under query/key channels of another width
+    (latent attention; ``attention`` pads those to whole lane blocks), a
+    length some tile divides, no sliding window, and offsets that are
     the static zeros of self-attention.  Everything here is visible at
     trace time; the backend is the caller's question."""
     if window is not None:
@@ -964,10 +975,16 @@ def fused_admissible(
         return False
     if q_offset != 0 or kv_offset != 0:
         return False
-    if not (q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype):
+    if not (
+        q.shape == k.shape and q.shape[:3] == v.shape[:3]
+        and q.dtype == k.dtype == v.dtype
+    ):
         return False
     B, T, H, D = q.shape
-    if D not in (64, _LANES) or (H * D) % _LANES:
+    if D != v.shape[-1]:
+        if v.shape[-1] != _LANES:
+            return False
+    elif D not in (64, _LANES) or (H * D) % _LANES:
         return False
     return _fused_tile(T) is not None
 
@@ -1154,8 +1171,19 @@ def _fused_bwd_kernel(
         dq_ref[0] = (scale * dq_scr[...]).astype(dq_ref.dtype)
 
 
-def _fused_geometry(q, block_q, block_kv):
-    B, T, H, D = q.shape
+def _fused_geometry(q, v, block_q, block_kv):
+    """``(B, T, H, D, hp, HB, QL, block_q, block_kv)``: ``D`` the value
+    width of a head, ``hp`` heads in each of the ``HB`` 128-lane value
+    blocks, ``QL`` the lanes of a head block's queries and keys (128, or
+    the query/key width where it is another than the values')."""
+    B, T, H, D = v.shape
+    if q.shape[-1] != D and (D != _LANES or q.shape[-1] % _LANES):
+        raise ValueError(
+            f"fused attention: {q.shape[-1]} query/key channels against "
+            f"{D} value channels; another width than the values' has to "
+            f"be whole blocks of {_LANES} lanes over {_LANES} value "
+            "channels (attention() pads)"
+        )
     tile = _fused_tile(T)
     block_q = block_q if block_q is not None else tile
     block_kv = block_kv if block_kv is not None else tile
@@ -1169,7 +1197,7 @@ def _fused_geometry(q, block_q, block_kv):
             "that divide the length"
         )
     hp = _LANES // D
-    return B, T, H, D, hp, (H * D) // _LANES, block_q, block_kv
+    return B, T, H, D, hp, (H * D) // _LANES, q.shape[-1] * hp, block_q, block_kv
 
 
 def _vma(x):
@@ -1178,11 +1206,12 @@ def _vma(x):
     return getattr(jax.typeof(x), "vma", None) or frozenset()
 
 
-def _fused_specs(pl, block_q, block_kv, hp):
+def _fused_specs(pl, block_q, block_kv, hp, ql):
     """Block specs over the grid (batch, head block, pair), the pair's
     (q block, kv block) read from the two prefetched vectors: a
-    ``[block_q, 128]`` tile of a ``[B, T, H*D]`` operand, the same for
-    keys, and the ``[heads, block_q]`` rows of LSE or delta."""
+    ``[block_q, ql]`` tile of the ``[B, T, H*Dqk]`` queries, the same for
+    keys, ``[block_kv, 128]`` of the values, ``[block_q, 128]`` of the
+    output, and the ``[heads, block_q]`` rows of LSE or delta."""
 
     def at(shape, index):
         return pl.BlockSpec(
@@ -1190,8 +1219,10 @@ def _fused_specs(pl, block_q, block_kv, hp):
         )
 
     return (
-        at((1, block_q, _LANES), lambda b, h, i, j: (b, i, h)),
+        at((1, block_q, ql), lambda b, h, i, j: (b, i, h)),
+        at((1, block_kv, ql), lambda b, h, i, j: (b, j, h)),
         at((1, block_kv, _LANES), lambda b, h, i, j: (b, j, h)),
+        at((1, block_q, _LANES), lambda b, h, i, j: (b, i, h)),
         at((1, 1, hp, block_q), lambda b, h, i, j: (b, h, 0, i)),
     )
 
@@ -1201,13 +1232,15 @@ def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, T, H, D, hp, HB, block_q, block_kv = _fused_geometry(
-        q, block_q, block_kv
+    B, T, H, D, hp, HB, ql, block_q, block_kv = _fused_geometry(
+        q, v, block_q, block_kv
     )
     n_q, n_kv = T // block_q, T // block_kv
     i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, False)
-    flat = lambda x: x.reshape(B, T, H * D)
-    qspec, kvspec, rowspec = _fused_specs(pl, block_q, block_kv, hp)
+    flat = lambda x: x.reshape(B, T, -1)
+    qspec, kspec, vspec, ospec, rowspec = _fused_specs(
+        pl, block_q, block_kv, hp, ql
+    )
     vma = _vma(q)
     out, lse = pl.pallas_call(
         functools.partial(
@@ -1217,8 +1250,8 @@ def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, HB, len(i_idx)),
-            in_specs=[qspec, kvspec, kvspec],
-            out_specs=[qspec, rowspec],
+            in_specs=[qspec, kspec, vspec],
+            out_specs=[ospec, rowspec],
             scratch_shapes=[
                 pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
                 pltpu.VMEM((hp, block_q, _LANES), jnp.float32),
@@ -1244,20 +1277,23 @@ def _fused_backward(
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, T, H, D, hp, HB, block_q, block_kv = _fused_geometry(
-        q, block_q, block_kv
+    B, T, H, D, hp, HB, ql, block_q, block_kv = _fused_geometry(
+        q, v, block_q, block_kv
     )
     n_q, n_kv = T // block_q, T // block_kv
     i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, True)
-    flat = lambda x: x.reshape(B, T, H * D)
+    flat = lambda x: x.reshape(B, T, -1)
     # delta_i = rowsum(dO o O), as lane-dense rows beside the LSE's.
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )  # [B, T, H]
     delta = jnp.swapaxes(delta, 1, 2).reshape(B, HB, hp, T)
-    qspec, kvspec, rowspec = _fused_specs(pl, block_q, block_kv, hp)
+    qspec, kspec, vspec, ospec, rowspec = _fused_specs(
+        pl, block_q, block_kv, hp, ql
+    )
     vma = _vma(q)
-    shape = jax.ShapeDtypeStruct((B, T, H * D), q.dtype, vma=vma)
+    qk_shape = jax.ShapeDtypeStruct((B, T, HB * ql), q.dtype, vma=vma)
+    v_shape = jax.ShapeDtypeStruct((B, T, H * D), q.dtype, vma=vma)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _fused_bwd_kernel, scale=_scale(q, scale), causal=causal,
@@ -1267,29 +1303,29 @@ def _fused_backward(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, HB, len(i_idx)),
-            in_specs=[qspec, kvspec, kvspec, qspec, rowspec, rowspec],
+            in_specs=[qspec, kspec, vspec, ospec, rowspec, rowspec],
             out_specs=[
                 # dQ of the whole head block stays resident; written once.
                 pl.BlockSpec(
-                    (1, T, _LANES), lambda b, h, p, ii, jj: (b, 0, h)
+                    (1, T, ql), lambda b, h, p, ii, jj: (b, 0, h)
                 ),
-                kvspec,
-                kvspec,
+                kspec,
+                vspec,
             ],
             scratch_shapes=[
-                pltpu.VMEM((T, _LANES), jnp.float32),
-                pltpu.VMEM((block_kv, _LANES), jnp.float32),
+                pltpu.VMEM((T, ql), jnp.float32),
+                pltpu.VMEM((block_kv, ql), jnp.float32),
                 pltpu.VMEM((block_kv, _LANES), jnp.float32),
             ],
         ),
-        out_shape=[shape, shape, shape],
+        out_shape=[qk_shape, qk_shape, v_shape],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FUSED_VMEM_BYTES,
         ),
         interpret=interpret,
     )(i_idx, j_idx, flat(q), flat(k), flat(v), flat(g), lse, delta)
-    unflat = lambda x: x.reshape(B, T, H, D)
+    unflat = lambda x: x.reshape(B, T, H, -1)
     return unflat(dq), unflat(dk), unflat(dv)
 
 
@@ -1397,6 +1433,11 @@ def attention(
             ATTN_ROUTE_FUSED if impl == "fused" else ATTN_ROUTE_BLOCKWISE
         ).inc()
         if impl == "fused":
+            if q.shape[-1] != v.shape[-1]:
+                # Latent attention: zero channels up to whole lane blocks.
+                scale = _scale(q, scale)
+                widen = ((0, 0),) * 3 + ((0, -q.shape[-1] % _LANES),)
+                q, k = jnp.pad(q, widen), jnp.pad(k, widen)
             return fused_attention(q, k, v, causal, scale)
     if impl == "reference":
         return reference_attention(

@@ -161,6 +161,19 @@ def exponential_decay(
     )
 
 
+def linear_warmup(lr: ScalarOrSchedule, warmup_steps: int) -> optax.Schedule:
+    """``lr`` (a number or a schedule) behind a linear warm-up: step ``t``
+    (from 0) runs at ``min(1, (t + 1) / warmup_steps)`` of its value, so
+    the first step already moves and step ``warmup_steps - 1`` is the
+    first at the full rate."""
+    base = lr if callable(lr) else (lambda count: lr)
+
+    def schedule(count):
+        return base(count) * jnp.minimum(1.0, (count + 1) / warmup_steps)
+
+    return schedule
+
+
 def zaremba_decay(
     initial_lr: float,
     steps_per_epoch: int,

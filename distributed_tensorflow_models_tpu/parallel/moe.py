@@ -31,6 +31,18 @@ capacity.  ``jax.lax.ragged_dot`` was measured against it on a v5e at the
 cell's shapes and lost (100 against 135 TFLOP/s over forward and both
 backward products, PERF.md section 6, PR 25); XLA's rewrite of it also
 drops the instruction's ``op_name``, and with it the scopes below.
+
+The same layer is one chip's share of an expert-parallel deployment when
+it is told which experts it holds (``held = (first, count)``; Kimi
+Linear's 256 experts over 32 chips are 8 here): the router keeps every
+output and the top-k runs over all of them, the assignments are sorted
+with the held experts first, and the rows of that prefix are gathered,
+multiplied and added back slab by slab (:func:`_held_local`; rows of a
+slab that are no held expert's are not visited by the grouped product and
+come out zero).  What the absent experts would add is left out; no
+assignment to a held expert is dropped, whatever the imbalance.  The
+scores may be a sigmoid in place of the softmax, renormalised over the
+chosen experts and scaled, as the models that state it have them.
 """
 
 from __future__ import annotations
@@ -247,6 +259,7 @@ class TopKMoEOutput(NamedTuple):
     aux_loss: jax.Array  # E * sum_e f_e * P_e (unweighted)
     z_loss: jax.Array  # mean(logsumexp(router logits)^2) (unweighted)
     load_max_over_mean: jax.Array  # fullest expert's assignments / mean
+    held_share: jax.Array  # share of the assignments that fell on held experts
 
 
 @jax.custom_vjp
@@ -279,11 +292,16 @@ def _tiling(dtype) -> tuple[int, int, int]:
     return (512, 1024, 1024) if jnp.dtype(dtype).itemsize <= 2 else (256, 512, 512)
 
 
-def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes):
+def grouped_matmul(
+    rows: jax.Array, weights: jax.Array, group_sizes, leading: bool = False
+):
     """``rows`` [m, k], sorted by group, times each group's own matrix of
     ``weights`` [groups, k, n]: row ``i`` of group ``g`` gives ``rows[i]
     @ weights[g]``.  ``m`` has to be a multiple of the row tile
-    (:func:`_pad_rows`).  Differentiable in ``rows`` and ``weights``
+    (:func:`_pad_rows`).  With ``leading``, ``weights`` holds the first
+    ``len(weights)`` of the ``len(group_sizes)`` groups only (megablox's
+    ``group_offset`` 0): their rows are visited, the others come out
+    zero.  Differentiable in ``rows`` and ``weights``
     (megablox's own backward products).  Off the TPU the kernel runs in
     Pallas' interpret mode."""
     return megablox.gmm(
@@ -292,7 +310,7 @@ def grouped_matmul(rows: jax.Array, weights: jax.Array, group_sizes):
         group_sizes,
         rows.dtype,
         _tiling(rows.dtype),
-        None,
+        jnp.zeros((), jnp.int32) if leading else None,
         None,
         False,
         jax.default_backend() != "tpu",
@@ -309,29 +327,76 @@ def _pad_rows(rows: jax.Array, group_sizes: jax.Array):
     return rows, group_sizes
 
 
-def route_topk(router: jax.Array, x: jax.Array, top_k: int):
+class Routing(NamedTuple):
+    """How a model states its router: the scores (``"softmax"`` over the
+    experts, or a ``"sigmoid"`` of each logit), whether the chosen
+    experts' scores are renormalised to sum to 1, and the factor on the
+    result."""
+
+    scoring: str = "softmax"
+    renormalize: bool = False
+    scale: float = 1.0
+
+
+def route_topk(
+    router: jax.Array, x: jax.Array, top_k: int, routing: Routing = Routing()
+):
     """``(logits, probs, weight, expert)`` of tokens ``x`` [n, d]: the
-    router's product, its softmax and the choice, in float32 at full
-    precision: a bf16 product here moves near-ties across the top-k
-    boundary.  ``weight`` and ``expert`` are [n, top_k], largest first,
-    ties to the lower expert index."""
+    router's product, its scores (``routing.scoring``) and the choice, in
+    float32 at full precision: a bf16 product here moves near-ties across
+    the top-k boundary.  ``weight`` and ``expert`` are [n, top_k], largest
+    first, ties to the lower expert index; ``weight`` is the chosen
+    scores, renormalised and scaled as ``routing`` says."""
     logits = jnp.dot(
         x.astype(jnp.float32),
         router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
+    if routing.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif routing.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(
+            f"unknown router scoring {routing.scoring!r} "
+            "(want 'softmax' or 'sigmoid')"
+        )
     weight, expert = lax.top_k(probs, top_k)
+    if routing.renormalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if routing.scale != 1.0:
+        weight = weight * routing.scale
     return logits, probs, weight, expert
 
 
-def _topk_local(params: dict, x: jax.Array, top_k: int, dtype):
-    """The layer on one rank's tokens ``x`` [n, d]; every expert is here."""
+def _routing_statistics(counts, probs, logits, n: int, top_k: int):
+    """``(aux, z, load)`` of one rank's step: the load-balancing loss ``E
+    sum_e f_e P_e``, the router z-loss and the fullest expert's
+    assignments over the mean."""
+    num_experts = counts.shape[0]
+    fraction = counts.astype(jnp.float32) / (n * top_k)
+    aux = num_experts * jnp.sum(fraction * jnp.mean(probs, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    load = jnp.max(fraction) * num_experts
+    return aux, z, load
+
+
+def _topk_local(
+    params: dict, x: jax.Array, top_k: int, dtype,
+    routing: Routing = Routing(), held: Optional[tuple[int, int]] = None,
+):
+    """The layer on one rank's tokens ``x`` [n, d].  ``held`` None: every
+    expert is here; ``(first, count)``: the expert stacks hold those
+    ``count`` of the router's experts (:func:`_held_local`)."""
+    if held is not None:
+        return _held_local(params, x, top_k, dtype, routing, held)
     n, d = x.shape
     num_experts = params["router"].shape[-1]
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         x = x.astype(dtype)
-        logits, probs, weight, expert = route_topk(params["router"], x, top_k)
+        logits, probs, weight, expert = route_topk(
+            params["router"], x, top_k, routing
+        )
         flat = expert.reshape(n * top_k)
         order = jnp.argsort(flat, stable=True)  # assignment ids by expert
         inverse = jnp.argsort(order)
@@ -354,11 +419,157 @@ def _topk_local(params: dict, x: jax.Array, top_k: int, dtype):
         out = jnp.sum(
             back.astype(jnp.float32) * weight[..., None], axis=1
         ).astype(dtype)
-        fraction = counts.astype(jnp.float32) / (n * top_k)
-        aux = num_experts * jnp.sum(fraction * jnp.mean(probs, axis=0))
-        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-        load = jnp.max(fraction) * num_experts
-    return out, aux, z, load
+        aux, z, load = _routing_statistics(counts, probs, logits, n, top_k)
+    return out, aux, z, load, jnp.ones((), jnp.float32)
+
+
+# Of the sorted assignments, the held experts' come first.  The layer
+# works through them in slabs of this many times the rows an even routing
+# would give the held experts: one slab in an ordinary step (a Zipf
+# stream's routing is uneven: a layer's held share reads up to twice the
+# even one), as many as the step's routing needs otherwise.
+_HELD_SLAB_MARGIN = 4
+
+
+def _slab_rows(assignments: int, count: int, num_experts: int, tile: int) -> int:
+    rows = min(assignments, _HELD_SLAB_MARGIN * assignments * count // num_experts)
+    return max(tile, -(-rows // tile) * tile)
+
+
+def _slab(x, stacks, share, token, sizes, dtype):
+    """One slab of sorted assignments: ``token`` [R] names each row's
+    token, ``share`` [R] its routing weight, ``sizes`` [count + 1] the
+    rows of each held expert and, last, the rows that are no held
+    expert's (they come out of the grouped product as zeros).  Returns
+    the weighted rows ``[R, d]`` in float32."""
+    w_gate, w_up, w_down = stacks
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        rows = x[token]
+    with jax.named_scope(MOE_EXPERTS_SCOPE):
+        grouped = functools.partial(
+            grouped_matmul, group_sizes=sizes, leading=True
+        )
+        gate = grouped(rows, w_gate.astype(dtype))
+        up = grouped(rows, w_up.astype(dtype))
+        hidden = (
+            jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        ).astype(dtype)
+        down = grouped(hidden, w_down.astype(dtype))
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        return down.astype(jnp.float32) * share[:, None]
+
+
+def _slab_inputs(i, rows: int, share, token, offsets):
+    """Slab ``i``'s slices of the sorted ``share`` and ``token``, and its
+    group sizes from the held experts' ``offsets`` [count + 1] into the
+    sorted order."""
+    start = i * rows
+    lo = jnp.clip(offsets[:-1], start, start + rows)
+    hi = jnp.clip(offsets[1:], start, start + rows)
+    held = hi - lo
+    sizes = jnp.concatenate([held, (rows - jnp.sum(held))[None]])
+    cut = lambda a: lax.dynamic_slice_in_dim(a, start, rows)
+    return cut(share), cut(token), sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(x, stacks, share, token, offsets, rows: int, dtype):
+    """``out[t] = sum over t's held assignments of share * expert(x[t])``
+    for assignments sorted with the held experts first: ``share`` and
+    ``token`` [slabs * rows] in that order, ``offsets`` [count + 1] where
+    each held expert's rows start (the last: where they end).  Slab after
+    slab while there are held rows left (a ``while_loop``: its length is
+    the step's routing), each gathered, multiplied and added into the
+    tokens; nothing the size of all assignments exists.  The backward
+    pass walks the same slabs, recomputing each."""
+    return _held_experts_fwd(x, stacks, share, token, offsets, rows, dtype)[0]
+
+
+def _held_experts_fwd(x, stacks, share, token, offsets, rows, dtype):
+    def body(state):
+        i, out = state
+        share_i, token_i, sizes = _slab_inputs(i, rows, share, token, offsets)
+        weighted = _slab(x, stacks, share_i, token_i, sizes, dtype)
+        with jax.named_scope(MOE_DISPATCH_SCOPE):
+            return i + 1, out.at[token_i].add(weighted)
+
+    _, out = lax.while_loop(
+        lambda state: state[0] * rows < offsets[-1],
+        body,
+        (jnp.zeros((), jnp.int32), jnp.zeros(x.shape, jnp.float32)),
+    )
+    return out.astype(dtype), (x, stacks, share, token, offsets)
+
+
+def _held_experts_bwd(rows, dtype, residuals, g):
+    x, stacks, share, token, offsets = residuals
+    g = g.astype(jnp.float32)
+
+    def body(state):
+        i, dx, dstacks, dshare = state
+        share_i, token_i, sizes = _slab_inputs(i, rows, share, token, offsets)
+        _, pull = jax.vjp(
+            lambda x_, stacks_, share_: _slab(x_, stacks_, share_, token_i, sizes, dtype),
+            x, stacks, share_i,
+        )
+        with jax.named_scope(MOE_DISPATCH_SCOPE):
+            dx_i, dstacks_i, dshare_i = pull(g[token_i])
+            dshare = lax.dynamic_update_slice_in_dim(dshare, dshare_i, i * rows, 0)
+            dx = dx + dx_i.astype(jnp.float32)
+        return i + 1, dx, jax.tree.map(jnp.add, dstacks, dstacks_i), dshare
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    _, dx, dstacks, dshare = lax.while_loop(
+        lambda state: state[0] * rows < offsets[-1],
+        body,
+        (jnp.zeros((), jnp.int32), zeros(x), jax.tree.map(zeros, stacks), zeros(share)),
+    )
+    cast = lambda d, a: d.astype(a.dtype)
+    return cast(dx, x), jax.tree.map(cast, dstacks, stacks), dshare, None, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def _held_local(params, x, top_k, dtype, routing, held):
+    """One chip's share of the layer on tokens ``x`` [n, d]: the router
+    and the top-k run over every expert, the grouped products over the
+    ``count`` held ones, ``first`` onwards.  The assignments are sorted
+    with the held experts first, so that their rows are a prefix of the
+    sorted order, and :func:`_held_experts` works through that prefix and
+    no further: no assignment to a held expert is left out, whatever the
+    imbalance, and an ordinary step touches one slab of rows."""
+    n, d = x.shape
+    num_experts = params["router"].shape[-1]
+    first, count = held
+    assignments = n * top_k
+    rows = _slab_rows(assignments, count, num_experts, _tiling(dtype)[0])
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        x = x.astype(dtype)
+        logits, probs, weight, expert = route_topk(
+            params["router"], x, top_k, routing
+        )
+        flat = expert.reshape(assignments)
+        # Assignment ids by expert, the held experts first.
+        order = jnp.argsort((flat - first) % num_experts, stable=True)
+        counts = jnp.sum(
+            jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0
+        )
+        offsets = jnp.concatenate([
+            jnp.zeros((1,), jnp.int32),
+            jnp.cumsum(lax.dynamic_slice_in_dim(counts, first, count)),
+        ])
+        pad = (0, -assignments % rows)
+        share = jnp.pad(weight.reshape(assignments)[order], pad)
+        token = jnp.pad(order // top_k, pad)
+    out = _held_experts(
+        x, (params["w_gate"], params["w_up"], params["w_down"]),
+        share, token, offsets, rows, dtype,
+    )
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        aux, z, load = _routing_statistics(counts, probs, logits, n, top_k)
+        held_share = offsets[-1].astype(jnp.float32) / assignments
+    return out, aux, z, load, held_share
 
 
 @jax.named_scope(MOE_SCOPE)
@@ -369,23 +580,40 @@ def topk_moe_ffn(
     top_k: int,
     mesh: Optional[Mesh] = None,
     dtype=jnp.bfloat16,
+    routing: Routing = Routing(),
+    held: Optional[tuple[int, int]] = None,
 ) -> TopKMoEOutput:
-    """Softmax-then-top-k routing over gated (SiLU) experts, exactly:
+    """Top-k routing over gated (SiLU) experts, exactly:
     ``y = sum_{e in top_k} p_e * W_down_e (silu(W_gate_e h) * W_up_e h)``
-    with the ``p_e`` as they are (not renormalised).  ``x`` is
-    ``[batch, time, d_model]``; ``params`` holds ``router`` [d, E] and the expert
-    stacks ``w_gate``, ``w_up`` [E, d, f] and ``w_down`` [E, f, d].
+    with the ``p_e`` the softmax's as they are (not renormalised), or what
+    ``routing`` states.  ``x`` is ``[batch, time, d_model]``; ``params``
+    holds ``router`` [d, E] and the expert stacks ``w_gate``, ``w_up``
+    [E, d, f] and ``w_down`` [E, f, d].  With ``held = (first, count)``
+    the stacks hold ``count`` experts, ``first`` onwards, of the router's
+    ``E``, and the sum runs over the chosen experts that are held (module
+    docstring); ``held_share`` says how many of the assignments that was.
 
     On a mesh every rank routes its own tokens (``x`` sharded
-    ``[data, seq, ...]``, the experts replicated) and the three statistics
+    ``[data, seq, ...]``, the experts replicated) and the statistics
     are means over ranks.  An ``expert`` axis larger than 1 needs an
     exchange of uneven size and is not built.
     """
     d = x.shape[-1]
-    local = lambda p, xl: _topk_local(p, xl.reshape(-1, d), top_k, dtype)
+    num_experts = params["router"].shape[-1]
+    if held is not None and not (
+        0 <= held[0] and held[1] == params["w_gate"].shape[0]
+        and held[0] + held[1] <= num_experts
+    ):
+        raise ValueError(
+            f"held {held} against {params['w_gate'].shape[0]} expert "
+            f"matrices and {num_experts} router outputs"
+        )
+    local = lambda p, xl: _topk_local(
+        p, xl.reshape(-1, d), top_k, dtype, routing, held
+    )
     if mesh is None:
-        out, aux, z, load = local(params, x)
-        return TopKMoEOutput(out.reshape(x.shape), aux, z, load)
+        out, *stats = local(params, x)
+        return TopKMoEOutput(out.reshape(x.shape), *stats)
     if mesh.shape[AxisNames.EXPERT] > 1:
         raise NotImplementedError(
             "exact top-k routing over an expert axis larger than 1 needs "
@@ -396,8 +624,8 @@ def topk_moe_ffn(
     token_axes = (AxisNames.DATA, AxisNames.SEQ)
 
     def per_device(p, xl):
-        out, aux, z, load = local(p, xl)
-        stats = lax.pmean(jnp.stack([aux, z, load]), token_axes)
+        out, *stats = local(p, xl)
+        stats = lax.pmean(jnp.stack(stats), token_axes)
         return out.reshape(xl.shape), stats
 
     # pallas_call outputs carry no varying-mesh-axes type, which the vma
@@ -413,4 +641,4 @@ def topk_moe_ffn(
         out_specs=(P(*token_axes), P()),
         check_vma=False,
     )(params, x)
-    return TopKMoEOutput(out, stats[0], stats[1], stats[2])
+    return TopKMoEOutput(out, *stats)

@@ -180,6 +180,10 @@ INPUT_WORK_KEYS = ("assemble_s", "shard_s")
 # TelemetryHook averages them over the interval): always the three
 # together; the fullest expert holds at least the mean.
 MOE_KEYS = ("moe_load_max_over_mean", "moe_aux_loss", "moe_z_loss")
+# Beside them where the expert layers hold a share of the router's
+# experts: the share of the assignments that fell on it, in [0, 1], and
+# never without the three.
+MOE_HELD_KEY = "moe_held_share"
 
 
 def _is_number(v) -> bool:
@@ -300,6 +304,17 @@ def check_lines(
                 f"line {i}: partial expert-routing key set {moe_present} "
                 f"(expected all of {list(MOE_KEYS)} together)"
             )
+        if MOE_HELD_KEY in row:
+            value = row[MOE_HELD_KEY]
+            if not moe_present:
+                errors.append(
+                    f"line {i}: {MOE_HELD_KEY!r} without the expert-routing "
+                    f"keys {list(MOE_KEYS)}"
+                )
+            if _is_number(value) and not 0.0 <= value <= 1.0:
+                errors.append(
+                    f"line {i}: {MOE_HELD_KEY!r} is outside [0, 1]: {value!r}"
+                )
         for key in moe_present:
             value = row[key]
             low = 1.0 if key == "moe_load_max_over_mean" else 0.0

@@ -177,6 +177,78 @@ def test_auto_attention_compiles_fused_for_v5e(v5e, monkeypatch, shape, dtype):
     assert not re.search(r"\bwhile\(", text)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_latent_attention_compiles_fused_for_v5e(v5e, monkeypatch, dtype):
+    """``attention(impl="auto")`` at ``kimi_linear_train``'s MLA shapes
+    (192 query/key channels a head, 128 value channels, 8,192 positions),
+    forward and backward: the same two Mosaic kernels as the other token
+    cells, the queries and keys padded to 256 lanes outside them, no
+    ``while`` of the blockwise scan; in bf16 as the cell runs it and in
+    float32 as the comparison with the reference does."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda d: jax.ShapeDtypeStruct((1, 8192, 32, d), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = attnlib.attention(q, k, v, causal=True, scale=192**-0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    assert attnlib.auto_route(spec(192), spec(192), spec(128)) == "fused"
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(192), spec(192), spec(128)
+    ).compile().as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == 2
+    assert all(re.search(r"[/(]attention_core[/)]", line) for line in kernels)
+    assert sum("transpose(" in line for line in kernels) == 1
+    assert not re.search(r"\bwhile\(", text)
+
+
+def test_held_expert_layer_compiles_for_v5e(v5e, monkeypatch):
+    """One chip's share of Kimi Linear's expert layer (8 of 256 experts
+    of 2304 x 1024 held, sigmoid top-8) on 16,384 tokens, forward and
+    backward: the grouped products are Mosaic kernels inside the
+    ``while`` loop over slabs of held rows (the backward loop holds the
+    three products again and their six gradients; this loss needs no
+    value of the forward loop, so the compiler drops it), all under
+    ``moe_experts``, and nothing the size of all 131,072 assignments by
+    the model width exists (the step would not fit the chip otherwise)."""
+    from distributed_tensorflow_models_tpu.parallel import moe as moelib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    params = {
+        "router": spec(2304, 256), "w_gate": spec(8, 2304, 1024),
+        "w_up": spec(8, 2304, 1024), "w_down": spec(8, 1024, 2304),
+    }
+
+    def loss(p, x):
+        out = moelib.topk_moe_ffn(
+            p, x, top_k=8, routing=moelib.Routing("sigmoid", True, 2.446), held=(0, 8)
+        )
+        return jnp.sum(out.out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, jax.ShapeDtypeStruct((2, 8192, 2304), jnp.bfloat16, sharding=one_chip)
+    ).compile()
+    text = compiled.as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == 9
+    assert all(re.search(r"[/(]moe_experts[/)]", line) for line in kernels)
+    assert re.search(r"\bwhile\(", text)
+    assert "[131072,2304]" not in text
+    # Under a gigabyte of scratch for the whole layer's gradient.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 @pytest.mark.parametrize(
     "shape", [(8, 1024, 1024, 50257, True), (4, 4096, 2048, 50304, False)],
     ids=["gpt2m", "olmoe"],

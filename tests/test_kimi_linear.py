@@ -1,0 +1,612 @@
+"""What Kimi Linear brought to the program, at a small size on the CPU in
+float32: the chunk-wise delta rule against its recurrence, the latent
+attention's shapes through every attention route, the router's sigmoid
+scores, an expert layer that holds a share of the experts (and the
+shares adding up), and the stack whose layers differ.
+
+The plain reference's side of it (logits, loss, every gradient) is
+``tests/benchmark/test_bench_reference_kimi_linear.py``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_models_tpu.harness.config import get_config
+from distributed_tensorflow_models_tpu.models import get_model
+from distributed_tensorflow_models_tpu.ops import attention as attnlib
+from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
+from distributed_tensorflow_models_tpu.parallel import moe as moelib
+
+SMALL = {
+    **get_config("kimi_linear").model_kwargs,
+    "vocab_size": 97, "num_layers": 5,
+    "layer_mixers": ("kda", "kda", "kda", "mla", "kda"),
+    "num_heads": 4, "d_model": 64, "d_ff": 32, "dense_d_ff": 96,
+    "kda_num_heads": 4, "kda_head_dim": 16, "mla_kv_lora_rank": 24,
+    "mla_nope_dim": 16, "mla_rope_dim": 8, "mla_v_dim": 16,
+    "num_experts": 16, "moe_top_k": 4, "moe_held": (4, 4),
+}
+
+
+# --- the chunk-wise delta rule ------------------------------------------
+
+def _kda_inputs(seed, T, decay, B=2, H=3, dk=16, dv=8):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, T, H, dk)))
+    k = unit(jax.random.normal(ks[1], (B, T, H, dk)))
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    noise = jax.random.normal(ks[3], (B, T, H, dk))
+    g = {
+        # a_t about 0.9 .. 0.999
+        "mild": -jnp.exp(noise - 4.0),
+        # a_t within 1e-6 of 1: the state hardly forgets
+        "near_one": -jnp.exp(noise - 14.0),
+        # many a_t below e^-20, some below e^-100: e^{-G} leaves float32
+        # inside one chunk, the quotients do not
+        "near_zero": -jnp.exp(1.5 * noise + 2.0),
+        # both in one sequence, channel by channel
+        "mixed": jnp.where(noise > 0, -jnp.exp(noise + 3.0), -jnp.exp(noise - 12.0)),
+    }[decay]
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(ks[4], (B, T, H)))
+    return q, k, v, g, beta
+
+
+KDA_CASES = [
+    # chunk, sub, length, decay
+    (64, 16, 128, "mild"),
+    (64, 16, 70, "near_zero"),   # a length the chunk does not divide
+    (64, 16, 150, "mixed"),
+    (32, 8, 100, "near_one"),
+    (32, 16, 33, "near_zero"),
+    (16, 16, 64, "mixed"),       # one block a chunk: no product between blocks
+    (16, 4, 50, "mild"),
+    (128, 16, 130, "near_zero"),
+    (8, 8, 5, "near_one"),       # shorter than a chunk
+]
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("chunk,sub,T,decay", KDA_CASES)
+def test_chunked_delta_rule_is_the_recurrence(chunk, sub, T, decay, what):
+    x = _kda_inputs(chunk + T, T, decay)
+    chunked = functools.partial(linattn.chunked_kda, chunk=chunk, sub=sub)
+    with jax.default_matmul_precision("highest"):
+        if what == "forward":
+            got, want = chunked(*x), linattn.recurrent_kda(*x)
+            scale = float(jnp.abs(want).max())
+            assert scale > 1e-3
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4 * scale, rtol=1e-4)
+            return
+        probe = jax.random.normal(jax.random.key(9), x[2].shape)
+        grad = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * probe), argnums=(0, 1, 2, 3, 4))(*x)
+        for name, g, w in zip("q k v g beta".split(), grad(chunked), grad(linattn.recurrent_kda)):
+            assert bool(jnp.isfinite(g).all()), name
+            scale = float(jnp.abs(w).max())
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), atol=1e-3 * scale + 1e-6, rtol=1e-3, err_msg=name
+            )
+
+
+def test_a_split_exponential_would_overflow_where_the_chunked_form_does_not():
+    """The case the ``near_zero`` decays are there for: ``e^{-G_s}``
+    inside one chunk is beyond float32, the chunk-wise form is finite."""
+    q, k, v, g, beta = _kda_inputs(3, 64, "near_zero")
+    G = jnp.cumsum(g, axis=1)
+    assert not bool(jnp.isfinite(jnp.exp(-G)).all())
+    out = linattn.chunked_kda(q, k, v, g, beta)
+    assert bool(jnp.isfinite(out).all())
+
+
+@pytest.mark.parametrize("size,sub", [(16, 16), (32, 8), (64, 16), (64, 4)])
+def test_unit_lower_inverse_and_its_cotangent(size, sub):
+    # Entries as the layer has them: b_t k_t . k_s of unit keys, below 1.
+    a = jnp.tril(0.3 * jax.random.normal(jax.random.key(size + sub), (3, 2, size, size)), -1)
+    eye = jnp.eye(size)
+    with jax.default_matmul_precision("highest"):
+        got = linattn.unit_lower_inverse(a, sub)
+        want = jax.scipy.linalg.solve_triangular(
+            eye + a, jnp.broadcast_to(eye, a.shape), lower=True, unit_diagonal=True
+        )
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3 * float(jnp.abs(want).max()))
+        probe = jax.random.normal(jax.random.key(1), a.shape)
+        g = jax.grad(lambda a: jnp.sum(linattn.unit_lower_inverse(a, sub) * probe))(a)
+        w = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(eye + jnp.tril(a, -1)) * probe))(a)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-3 * float(jnp.abs(w).max()))
+    assert float(jnp.abs(jnp.triu(g)).max()) == 0.0
+
+
+def test_a_chunk_that_is_no_power_of_two_of_blocks_is_refused():
+    x = _kda_inputs(0, 48, "mild")
+    with pytest.raises(ValueError, match="power-of-two"):
+        linattn.chunked_kda(*x, chunk=48, sub=16)
+
+
+# --- latent attention's shapes through the attention routes --------------
+
+def _mla_qkv(T=256, H=2, dqk=192, dv=128, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(0), 4)
+    shape = lambda d: (1, T, H, d)
+    q, k = jax.random.normal(ks[0], shape(dqk), dtype), jax.random.normal(ks[1], shape(dqk), dtype)
+    return q, k, jax.random.normal(ks[2], shape(dv), dtype), jax.random.normal(ks[3], shape(dv))
+
+
+def _fused_interpreted(q, k, v):
+    """What ``attention(impl="auto")`` runs on the chip for these shapes,
+    with the kernels interpreted."""
+    widen = ((0, 0),) * 3 + ((0, -q.shape[-1] % 128),)
+    return attnlib.fused_attention(
+        jnp.pad(q, widen), jnp.pad(k, widen), v, True, q.shape[-1] ** -0.5, 128, 128, True
+    )
+
+
+@pytest.mark.parametrize("route", ["blockwise", "auto_on_the_cpu", "fused_interpreted"])
+def test_192_key_and_128_value_channels_against_a_full_score_matrix(route):
+    q, k, v, probe = _mla_qkv()
+    fn = {
+        "blockwise": functools.partial(attnlib.blockwise_attention, causal=True, block_kv=64),
+        "auto_on_the_cpu": functools.partial(attnlib.attention, causal=True, scale=192**-0.5),
+        "fused_interpreted": _fused_interpreted,
+    }[route]
+    want_fn = functools.partial(attnlib.reference_attention, causal=True)
+    with jax.default_matmul_precision("highest"):
+        got, want = fn(q, k, v), want_fn(q, k, v)
+        assert got.shape == v.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+        grad = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * probe), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(grad(fn), grad(want_fn)):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
+
+
+@pytest.mark.parametrize(
+    "shape_qk,shape_v,want",
+    [
+        ((1, 1024, 16, 64), (1, 1024, 16, 64), True),     # gpt2m_train
+        ((1, 4096, 16, 128), (1, 4096, 16, 128), True),   # olmoe_train
+        ((2, 8192, 32, 192), (2, 8192, 32, 128), True),   # kimi_linear_train's MLA
+        ((1, 1024, 32, 192), (1, 1024, 32, 64), False),   # narrower values: no kernel
+        ((1, 1024, 4, 96), (1, 1024, 4, 96), False),
+        ((1, 1000, 32, 192), (1, 1000, 32, 128), False),  # no tile divides the length
+        ((1, 1024, 32, 192), (1, 1024, 8, 128), False),   # grouped values
+    ],
+)
+def test_which_shapes_the_fused_kernels_admit(shape_qk, shape_v, want):
+    q = jax.ShapeDtypeStruct(shape_qk, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape_v, jnp.bfloat16)
+    assert attnlib.fused_admissible(q, q, v) is want
+    assert attnlib.fused_admissible(q, q, v, window=128) is False
+
+
+def test_fused_attention_itself_refuses_channels_that_are_not_whole_lane_blocks():
+    q, k, v, _ = _mla_qkv()
+    with pytest.raises(ValueError, match="whole blocks"):
+        attnlib.fused_attention(q, k, v, True, None, 128, 128, True)
+
+
+# --- the router -----------------------------------------------------------
+
+def test_sigmoid_scores_renormalised_and_scaled_with_ties_to_the_lower_index():
+    # Four tokens whose router logits are given outright (x = identity).
+    logits = jnp.array([
+        [2.0, -1.0, 0.5, 0.5, 0.5, -3.0],   # a three-way tie for two places
+        [0.0, 0.0, 0.0, 0.0, 0.0, 0.0],     # all tied
+        [-1.0, 3.0, -2.0, 1.0, 0.0, 2.0],
+        [5.0, 4.0, 3.0, 2.0, 1.0, 0.0],
+    ])
+    x, router = jnp.eye(4), logits
+    routing = moelib.Routing("sigmoid", True, 2.446)
+    got_logits, scores, weight, expert = moelib.route_topk(router, x, 3, routing)
+    np.testing.assert_array_equal(np.asarray(expert), [[0, 2, 3], [0, 1, 2], [1, 5, 3], [0, 1, 2]])
+    s = np.asarray(jax.nn.sigmoid(logits))
+    np.testing.assert_allclose(np.asarray(scores), s, rtol=1e-6)
+    chosen = np.take_along_axis(s, np.asarray(expert), axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(weight), 2.446 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6
+    )
+    np.testing.assert_allclose(np.asarray(weight).sum(-1), 2.446, rtol=1e-6)
+    # The softmax router, untouched: its probabilities as they are.
+    _, probs, plain, same = moelib.route_topk(router, x, 3)
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(expert))
+    np.testing.assert_allclose(
+        np.asarray(plain), np.take_along_axis(np.asarray(jax.nn.softmax(logits)), np.asarray(expert), -1),
+        rtol=1e-6,
+    )
+    with pytest.raises(ValueError, match="scoring"):
+        moelib.route_topk(router, x, 3, moelib.Routing("tanh"))
+
+
+# --- an expert layer that holds a share of the experts -------------------
+
+E, K, D, F, N = 16, 4, 64, 32, 96
+ROUTING = moelib.Routing("sigmoid", True, 2.446)
+
+
+def _layer_params(seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return {
+        "router": jax.random.normal(keys[0], (D, E)) * D**-0.5,
+        "w_gate": jax.random.normal(keys[1], (E, D, F)) * D**-0.5,
+        "w_up": jax.random.normal(keys[2], (E, D, F)) * D**-0.5,
+        "w_down": jax.random.normal(keys[3], (E, F, D)) * F**-0.5,
+    }
+
+
+def _share(params, first, count):
+    take = lambda w: w[first : first + count]
+    return {"router": params["router"], **{k: take(params[k]) for k in ("w_gate", "w_up", "w_down")}}
+
+
+def _dense_masked(params, x, held=(0, E)):
+    """Every expert of the range on every token, masked by the top-k over
+    all experts: what a share has to equal."""
+    with jax.default_matmul_precision("highest"):
+        h = x.reshape(-1, D)
+        s = jax.nn.sigmoid(h @ params["router"])
+        kth = jnp.sort(s, axis=-1)[:, -K][:, None]
+        chosen = s >= kth  # no ties on random inputs
+        w = jnp.where(chosen, s, 0.0)
+        w = 2.446 * w / w.sum(-1, keepdims=True)
+        ys = jnp.einsum(
+            "enf,efd->end",
+            jax.nn.silu(jnp.einsum("nd,edf->enf", h, params["w_gate"]))
+            * jnp.einsum("nd,edf->enf", h, params["w_up"]),
+            params["w_down"],
+        )
+        mine = (jnp.arange(E) >= held[0]) & (jnp.arange(E) < held[0] + held[1])
+        return jnp.einsum("ne,end->nd", w * mine, ys).reshape(x.shape), chosen
+
+
+def _held_layer(params, x, held):
+    with jax.default_matmul_precision("highest"):
+        return moelib.topk_moe_ffn(
+            _share(params, *held), x, top_k=K, dtype=jnp.float32, routing=ROUTING, held=held
+        )
+
+
+@pytest.mark.parametrize("skew", ["random", "everything_on_one_share", "nothing_on_this_share"])
+@pytest.mark.parametrize("held", [(0, 4), (4, 4), (12, 4), (2, 8)])
+def test_a_share_computes_its_own_experts_part_and_drops_nothing(held, skew):
+    params = _layer_params()
+    x = jnp.abs(jax.random.normal(jax.random.key(7), (2, N // 2, D))) + 0.1
+    if skew != "random":
+        # Positive inputs: a router column of one sign decides an expert.
+        sign = 1.0 if skew == "everything_on_one_share" else -1.0
+        cols = jnp.arange(held[0], held[0] + held[1])
+        params["router"] = params["router"].at[:, cols].set(
+            sign * (0.1 + 0.01 * jnp.arange(held[1]))  # apart, and short of saturation: no ties
+        )
+    want, chosen = _dense_masked(params, x, held)
+    got = _held_layer(params, x, held)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want), atol=3e-5, rtol=1e-5)
+    on_share = float(chosen[:, held[0] : held[0] + held[1]].sum()) / (K * N)
+    assert float(got.held_share) == pytest.approx(on_share, abs=1e-6)
+    if skew == "everything_on_one_share":
+        assert on_share == 1.0  # every assignment of every token, all computed
+    if skew == "nothing_on_this_share":
+        assert on_share == 0.0 and float(jnp.abs(got.out).max()) == 0.0
+    probe = jax.random.normal(jax.random.key(3), x.shape)
+    share = _share(params, *held)
+
+    def dense(p, y):
+        full = {**params, **{k: params[k].at[held[0] : held[0] + held[1]].set(p[k]) for k in ("w_gate", "w_up", "w_down")}}
+        return jnp.sum(_dense_masked({**full, "router": p["router"]}, y, held)[0] * probe)
+
+    def grouped(p, y):
+        with jax.default_matmul_precision("highest"):
+            out = moelib.topk_moe_ffn(p, y, top_k=K, dtype=jnp.float32, routing=ROUTING, held=held)
+        return jnp.sum(out.out * probe)
+
+    for g, w in zip(
+        jax.tree.leaves(jax.grad(grouped, argnums=(0, 1))(share, x)),
+        jax.tree.leaves(jax.grad(dense, argnums=(0, 1))(share, x)),
+    ):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-3)
+
+
+def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
+    """The model-configs guide's test of the cut: 16 experts as 4 shares
+    of 4.  The routed parts the four shares give, plus the shared expert
+    (which every chip computes alike) counted once, are the uncut layer."""
+    from distributed_tensorflow_models_tpu.models import transformer_lm as tlm
+
+    x = jax.random.normal(jax.random.key(5), (2, N // 2, D))
+    whole = tlm.TopKExpertsFFN(
+        E, K, D, F, dtype=jnp.float32, routing=ROUTING, shared_experts=1, aux_loss_weight=0.0
+    )
+    variables = whole.init(jax.random.key(0), x)
+    params = variables["params"]
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = whole.apply(variables, x, mutable=["moe_stats"])
+        shared = tlm.GatedMLP(D, F, jnp.float32).apply({"params": params["shared"]}, x)
+        parts, shares = [], []
+        for first in range(0, E, 4):
+            layer = tlm.TopKExpertsFFN(
+                E, K, D, F, dtype=jnp.float32, routing=ROUTING, shared_experts=1,
+                aux_loss_weight=0.0, held=(first, 4),
+            )
+            mine = {**params, **{k: params[k][first : first + 4] for k in ("w_gate", "w_up", "w_down")}}
+            out, stats = layer.apply({"params": mine}, x, mutable=["moe_stats"])
+            parts.append(out - shared)  # this chip's routed part
+            shares.append(float(stats["moe_stats"]["held_share"]))
+        # The uncut layer against the dense masked formulation too.
+        routed, _ = _dense_masked({k: params[k] for k in ("router", "w_gate", "w_up", "w_down")}, x)
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared), np.asarray(uncut), atol=3e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(uncut - shared), np.asarray(routed), atol=3e-5, rtol=1e-5)
+    assert sum(shares) == pytest.approx(1.0, abs=1e-6)
+
+
+@pytest.mark.parametrize("skew", [0.0, 0.08, 0.2], ids=["one_slab", "several_slabs", "every_assignment"])
+def test_held_rows_in_one_slab_or_in_many(skew):
+    """64 experts of which 4 are held, 2,048 assignments: the layer works
+    through the held experts' sorted rows in slabs of 512 (four times an even
+    routing's 128): one slab, several, or all four.  Each against the
+    dense masked formulation, forward and gradient."""
+    from distributed_tensorflow_models_tpu.parallel.moe import _slab_rows
+
+    E64, held, n = 64, (8, 4), 512
+    keys = jax.random.split(jax.random.key(11), 5)
+    params = {
+        "router": jax.random.normal(keys[0], (D, E64)) * D**-0.5,
+        "w_gate": jax.random.normal(keys[1], (4, D, F)) * D**-0.5,
+        "w_up": jax.random.normal(keys[2], (4, D, F)) * D**-0.5,
+        "w_down": jax.random.normal(keys[3], (4, F, D)) * F**-0.5,
+    }
+    x = jnp.abs(jax.random.normal(keys[4], (1, n, D))) + 0.1
+    params["router"] = params["router"].at[:, 8:12].add(skew * (1.0 + 0.1 * jnp.arange(4)))
+    probe = jax.random.normal(jax.random.key(3), x.shape)
+
+    def dense(p, y):
+        with jax.default_matmul_precision("highest"):
+            h = y.reshape(-1, D)
+            s = jax.nn.sigmoid(h @ p["router"])
+            chosen = s >= jnp.sort(s, axis=-1)[:, -K][:, None]
+            w = jnp.where(chosen, s, 0.0)
+            w = 2.446 * w / w.sum(-1, keepdims=True)
+            ys = jnp.einsum(
+                "enf,efd->end",
+                jax.nn.silu(jnp.einsum("nd,edf->enf", h, p["w_gate"])) * jnp.einsum("nd,edf->enf", h, p["w_up"]),
+                p["w_down"],
+            )
+            return jnp.einsum("ne,end->nd", w[:, 8:12], ys).reshape(y.shape), chosen[:, 8:12].sum()
+
+    def layer(p, y):
+        with jax.default_matmul_precision("highest"):
+            return moelib.topk_moe_ffn(p, y, top_k=K, dtype=jnp.float32, routing=ROUTING, held=held)
+
+    want, on_share = dense(params, x)
+    prefix = _slab_rows(n * K, held[1], E64, 256)
+    assert prefix == 512
+    assert (int(on_share) > prefix) == (skew > 0), int(on_share)
+    if skew == 0.2:
+        assert int(on_share) == n * K
+    got = layer(params, x)
+    np.testing.assert_allclose(np.asarray(got.out), np.asarray(want), atol=3e-5, rtol=1e-5)
+    assert float(got.held_share) == pytest.approx(int(on_share) / (n * K))
+    g = jax.grad(lambda p, y: jnp.sum(layer(p, y).out * probe), argnums=(0, 1))(params, x)
+    w = jax.grad(lambda p, y: jnp.sum(dense(p, y)[0] * probe), argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-3)
+
+
+def test_held_has_to_match_the_expert_stacks():
+    params, x = _layer_params(), jnp.ones((1, 8, D))
+    for held in ((0, 4), (14, 16), (-1, 16)):
+        with pytest.raises(ValueError, match="held"):
+            moelib.topk_moe_ffn(params, x, top_k=K, dtype=jnp.float32, held=held)
+
+
+def _parent_topk_local(params, x, top_k, dtype):
+    """``parallel/moe.py::_topk_local`` of the parent commit (e15f5a8),
+    verbatim but for the scopes: what ``olmoe``'s path has to stay."""
+    n, d = x.shape
+    num_experts = params["router"].shape[-1]
+    x = x.astype(dtype)
+    logits = jnp.dot(
+        x.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    weight, expert = jax.lax.top_k(probs, top_k)
+    flat = expert.reshape(n * top_k)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.argsort(order)
+    counts = jnp.sum(jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0)
+    rows = moelib._permute_rows(jnp.repeat(x, top_k, axis=0), order, inverse)
+    rows, sizes = moelib._pad_rows(rows, counts)
+    grouped = functools.partial(moelib.grouped_matmul, group_sizes=sizes)
+    gate = grouped(rows, params["w_gate"].astype(dtype))
+    up = grouped(rows, params["w_up"].astype(dtype))
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(dtype)
+    down = grouped(hidden, params["w_down"].astype(dtype))[: n * top_k]
+    back = moelib._permute_rows(down, inverse, order).reshape(n, top_k, d)
+    out = jnp.sum(back.astype(jnp.float32) * weight[..., None], axis=1).astype(dtype)
+    fraction = counts.astype(jnp.float32) / (n * top_k)
+    aux = num_experts * jnp.sum(fraction * jnp.mean(probs, axis=0))
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return out, aux, z, jnp.max(fraction) * num_experts
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_with_everything_held_the_layer_is_bit_for_bit_the_parent_s(dtype):
+    params = _layer_params(1)
+    x = jax.random.normal(jax.random.key(2), (2, 40, D))
+    probe = jax.random.normal(jax.random.key(3), x.shape)
+
+    def ours(p, y):
+        res = moelib.topk_moe_ffn(p, y, top_k=K, dtype=dtype)
+        return jnp.sum(res.out.astype(jnp.float32) * probe) + res.aux_loss + res.z_loss, res
+
+    def parents(p, y):
+        out, aux, z, load = _parent_topk_local(p, y.reshape(-1, D), K, dtype)
+        return jnp.sum(out.reshape(y.shape).astype(jnp.float32) * probe) + aux + z, (out, aux, z, load)
+
+    (_, res), got = jax.value_and_grad(ours, argnums=(0, 1), has_aux=True)(params, x)
+    (_, (out, aux, z, load)), want = jax.value_and_grad(parents, argnums=(0, 1), has_aux=True)(params, x)
+    np.testing.assert_array_equal(np.asarray(res.out.reshape(-1, D), np.float32), np.asarray(out, np.float32))
+    for a, b in ((res.aux_loss, aux), (res.z_loss, z), (res.load_max_over_mean, load)):
+        assert float(a) == float(b)
+    assert float(res.held_share) == 1.0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# --- the stack whose layers differ ----------------------------------------
+
+def _tree(params):
+    return {
+        "/".join(k.key for k in path): tuple(leaf.shape)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+    }
+
+
+def test_kimi_linear_parameter_tree():
+    model = get_model("transformer_lm", **SMALL)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    got = _tree(jax.eval_shape(lambda: model.init(jax.random.key(0), tokens))["params"])
+    d, w, h = 64, 64, 16  # hidden, heads x head, head
+    kda = {
+        "query/kernel": (d, w), "key/kernel": (d, w), "value/kernel": (d, w), "out/kernel": (w, d),
+        "conv_query": (4, w), "conv_key": (4, w), "conv_value": (4, w),
+        "f_a/kernel": (d, h), "f_b/kernel": (h, w), "g_a/kernel": (d, h), "g_b/kernel": (h, w),
+        "beta/kernel": (d, 4), "A_log": (4,), "dt_bias": (w,), "o_norm/scale": (h,),
+    }
+    mla = {
+        "query/kernel": (d, 4 * 24), "kv_a/kernel": (d, 24 + 8), "kv_a_norm/scale": (24,),
+        "kv_b/kernel": (24, 4 * 32), "out/kernel": (4 * 16, d),
+    }
+    moe = {
+        "router": (d, 16), "w_gate": (4, d, 32), "w_up": (4, d, 32), "w_down": (4, 32, d),
+        "shared/gate/kernel": (d, 32), "shared/up/kernel": (d, 32), "shared/down/kernel": (32, d),
+    }
+    dense = {"gate/kernel": (d, 96), "up/kernel": (d, 96), "down/kernel": (96, d)}
+    want = {"embedding/embedding": (97, d), "ln_f/scale": (d,), "head/kernel": (d, 97)}
+    for i, mixer in enumerate(SMALL["layer_mixers"]):
+        name, sizes = ("attn", mla) if mixer == "mla" else ("linear_attn", kda)
+        ffn_name, ffn = ("mlp", dense) if i == 0 else ("moe", moe)
+        for group, leaves in ((name, sizes), (ffn_name, ffn)):
+            want.update({f"blocks_{i}/{group}/{k}": v for k, v in leaves.items()})
+        want.update({f"blocks_{i}/ln1/scale": (d,), f"blocks_{i}/ln2/scale": (d,)})
+    # No bias, no position table, an untied head, a dense first layer.
+    assert got == want
+
+
+def test_jitted_init_draws_the_parameters_without_the_forward_pass():
+    """``TrainState.create``'s jitted init returns ``params`` and
+    ``batch_stats`` alone: the ``moe_stats`` an expert layer sows hang on
+    the whole forward pass, and the cell's init program compiled it for
+    nothing.  Same parameters, to the bit, as the program that returned
+    every collection; the program that draws them holds nothing of
+    the chunk-wise delta rule or of the expert layers."""
+    from distributed_tensorflow_models_tpu.core.train_state import TrainState
+    from distributed_tensorflow_models_tpu.ops import optim
+
+    model = get_model("transformer_lm", **SMALL)
+    key, tokens = jax.random.key(5), jnp.zeros((2, 16), jnp.int32)
+    whole = jax.jit(lambda r, s: model.init(r, s))
+    assert "moe_stats" in whole(key, tokens)
+    state = TrainState.create(model, optim.sgd(0.1), key, tokens, jit_init=True)
+    for a, b in zip(jax.tree.leaves(whole(key, tokens)["params"]), jax.tree.leaves(state.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    drawn = jax.jit(lambda r, s: model.init(r, s)["params"])
+    text = whole.lower(key, tokens).compile().as_text()
+    assert "kda_core" in text and "moe_dispatch" in text
+    text = drawn.lower(key, tokens).compile().as_text()
+    assert "kda_core" not in text and "moe_dispatch" not in text
+
+
+def test_the_published_configuration_counts_what_the_issue_reckoned():
+    """At the published widths, cut as the benchmark cuts it: 602.4 M."""
+    cut = {
+        **get_config("kimi_linear").model_kwargs, "vocab_size": 20480, "num_layers": 5,
+        "layer_mixers": ("kda", "kda", "kda", "mla", "kda"), "moe_held": (0, 8),
+    }
+    model = get_model("transformer_lm", **cut)
+    shapes = jax.eval_shape(lambda: model.init(jax.random.key(0), jnp.zeros((1, 64), jnp.int32)))["params"]
+    tree = _tree(shapes)
+    count = lambda prefix: sum(int(np.prod(s)) for k, s in tree.items() if k.startswith(prefix))
+    assert count("blocks_1/linear_attn/") == 39_514_272
+    assert count("blocks_3/attn/") == 29_114_880
+    assert count("blocks_0/mlp/") == 63_700_992
+    assert count("blocks_1/moe/") == 8 * 7_077_888 + 7_077_888 + 589_824
+    assert count("") == 602_433_408
+    full = get_config("kimi_linear").model_kwargs
+    assert full["layer_mixers"].count("mla") == 7 and full["layer_mixers"].count("kda") == 20
+    assert [i + 1 for i, m in enumerate(full["layer_mixers"]) if m == "mla"] == [4, 8, 12, 16, 20, 24, 27]
+
+
+@pytest.mark.parametrize(
+    "kwargs,match",
+    [
+        ({"decode": True}, "neither decode"),
+        ({"layer_mixers": ("kda", "mla")}, "names 2 layers"),
+        ({"layer_mixers": ("kda", "kda", "gla", "mla", "kda")}, "unknown layer_mixers"),
+        ({"moe_scoring": "tanh"}, "unknown moe_scoring"),
+        ({"mlp": "relu"}, "unknown mlp"),
+        ({"pipelined": True}, "GPT-2 block only"),
+    ],
+)
+def test_settings_the_stack_does_not_have_are_refused(kwargs, match):
+    model = get_model("transformer_lm", **{**SMALL, **kwargs})
+    with pytest.raises(ValueError, match=match):
+        jax.eval_shape(lambda: model.init(jax.random.key(0), jnp.zeros((2, 16), jnp.int32)))
+
+
+def test_fit_trains_the_kimi_linear_program_config_and_reports_the_held_share(tmp_path):
+    from distributed_tensorflow_models_tpu.core import mesh as meshlib
+    from distributed_tensorflow_models_tpu.harness import train as trainlib
+
+    cfg = get_config(
+        "kimi_linear", model_kwargs={**SMALL, "max_len": 40}, vocab_size=97, num_steps=40,
+        global_batch_size=2, train_steps=8, log_every_steps=2, trace_export=True,
+    )
+    mesh = meshlib.data_parallel_mesh(jax.devices()[:1])
+    result = trainlib.fit(cfg, str(tmp_path), mesh=mesh)
+    assert int(result.state.step) == 8
+    import json
+    import subprocess
+    import sys
+
+    rows = [json.loads(l) for l in open(tmp_path / "metrics.jsonl")]
+    final = [r for r in rows if "loss" in r][-1]
+    assert np.isfinite(final["loss"])
+    # 4 of 16 experts held: about a quarter of the assignments, and the
+    # three routing statistics beside it; no auxiliary loss in the objective.
+    assert 0.05 < final["moe_held_share"] < 0.6
+    assert final["moe_load_max_over_mean"] >= 1.0 and "moe_aux_loss" in final
+    assert "aux_loss" not in final and final["loss"] == pytest.approx(final["nll"])
+    check = subprocess.run(
+        [sys.executable, "scripts/check_metrics_schema.py", str(tmp_path / "metrics.jsonl")],
+        capture_output=True, text=True,
+    )
+    assert check.returncode == 0, check.stdout + check.stderr
+    # The scopes the per-layer readers find the new layers by.
+    scopes = json.load(open(tmp_path / "step_scopes_p0.json"))["modules"]
+    names = " ".join(n for module in scopes.values() for n in module.values())
+    for scope in ("linear_attn", "kda_core", "attention_core", "moe_shared", "moe_dispatch", "moe_experts"):
+        assert f"/{scope}/" in names or f"({scope})" in names, scope
+    telemetry = json.load(open(tmp_path / "telemetry.json"))["metrics"]
+    # One MLA layer's call, counted once per traced program (blockwise on the CPU).
+    assert telemetry["attention/route_blockwise"] >= 1 and telemetry.get("attention/route_fused", 0) == 0
+
+
+def test_kimi_linear_warms_up_and_the_other_language_models_do_not():
+    from distributed_tensorflow_models_tpu.harness.config import OptimizerConfig
+
+    schedule = get_config("kimi_linear").optimizer.schedule()
+    got = [float(schedule(t)) for t in (0, 1, 39, 1998, 1999, 2000, 10_000)]
+    want = [3e-4 * f for f in (1 / 2000, 2 / 2000, 40 / 2000, 1999 / 2000, 1.0, 1.0, 1.0)]
+    assert got == pytest.approx(want, rel=1e-6)
+    for name in ("transformer_lm", "olmoe"):
+        assert get_config(name).optimizer.schedule() == 3e-4
+    # On top of a schedule: the reference's staircase decay behind four steps of warm-up.
+    both = OptimizerConfig(
+        name="sgd", learning_rate=1.0, decay_steps=10, decay_rate=0.5, warmup_steps=4
+    ).schedule()
+    assert [float(both(t)) for t in (0, 3, 4, 9, 10, 25)] == pytest.approx(
+        [0.25, 1.0, 1.0, 1.0, 0.5, 0.25]
+    )
