@@ -1328,14 +1328,16 @@ def _export_trace(
 
 def _trace_counts() -> dict[str, float]:
     """What the ops count at trace time (``attention(impl="auto")``'s
-    routes, the fused head's gradient-in-forward calls), as the
-    process-global registry holds it now."""
+    routes, ``chunked_kda``'s, the fused head's gradient-in-forward
+    calls), as the process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
         for name in (
             telemetry.ATTN_ROUTE_FUSED,
             telemetry.ATTN_ROUTE_BLOCKWISE,
+            telemetry.KDA_ROUTE_KERNEL,
+            telemetry.KDA_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
         )
     }

@@ -1329,7 +1329,7 @@ def _fused_backward(
     return unflat(dq), unflat(dk), unflat(dv)
 
 
-def _mosaic_can_lower() -> bool:
+def mosaic_can_lower() -> bool:
     """Whether a Mosaic kernel traced here will lower: such a kernel
     cannot be partitioned automatically, so its program has to span one
     device, or the call has to sit inside a ``shard_map`` that makes every
@@ -1354,7 +1354,7 @@ def auto_route(
         and fused_admissible(
             q, k, v, window=window, q_offset=q_offset, kv_offset=kv_offset
         )
-        and _mosaic_can_lower()
+        and mosaic_can_lower()
     ):
         return "fused"
     return "blockwise"

@@ -43,20 +43,45 @@ accumulate in float32.  ``T`` is made in float32: exact forward
 substitution inside ``sub`` x ``sub`` blocks, then ``T_21 = -T_22 A_21
 T_11`` block by block at full precision.
 
-The backward pass is autodiff through all of it except ``T``, whose
-cotangent is ``-T^T dT T^T`` (no pass through the substitution).  What
-it keeps is per chunk (the state at each chunk's start, ``U``, ``T``),
-never a state per token.
+Two routes, one entry.  :func:`chunked_kda` runs :func:`kernel_kda`, the
+chunk-wise form as two Pallas (Mosaic) kernels under one ``custom_vjp``,
+on a TPU where such a kernel can lower and the shapes are whole tiles
+(key and value widths multiples of 128, chunks of 64 in blocks of 16),
+and :func:`plain_kda`, the same form in ``jax.numpy``, everywhere else
+(the CPU, other head sizes, a ``jit`` over several devices outside
+``shard_map``); the choice is counted once per traced call
+(``kda/route_kernel``, ``kda/route_plain``).  The two paragraphs above
+hold for both, forward and backward.
+
+The backward pass of the plain route is autodiff through all of it
+except ``T``, whose cotangent is ``-T^T dT T^T`` (no pass through the
+substitution); the kernels' is by hand (the section comment below).
+What either keeps is per chunk (the state at each chunk's start and
+``T``; the plain route also ``U``), never a state per token.
 """
 
 from __future__ import annotations
 
 import functools
+import types
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributed_tensorflow_models_tpu.ops.attention import (
+    _LANES,
+    _vma,
+    mosaic_can_lower,
+)
+from distributed_tensorflow_models_tpu.telemetry.registry import (
+    KDA_ROUTE_KERNEL,
+    KDA_ROUTE_PLAIN,
+    get_registry,
+)
 
 # ``jax.named_scope`` of the chunk-wise core, forward and backward: a path
 # element of every instruction's ``op_name`` in the compiled step (PERF.md
@@ -182,19 +207,18 @@ def _decayed_products(xs, k, G, sub: int, dtype):
     return [jnp.concatenate(row, axis=-2) for row in rows]
 
 
-@jax.named_scope(KDA_CORE_SCOPE)
-def chunked_kda(
+def plain_kda(
     q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
     sub: int = 16,
 ):
-    """:func:`recurrent_kda` computed chunk-wise (module docstring); same
-    arguments, the result in the dtype of ``v``.  Chunks of 64 in blocks
-    of 16 run 37 ms forward and 104 with the backward pass at ``[2, 8192,
-    32, 128]`` on a v5e; chunks of 32 read 32 and 95 there, and
-    ``kimi_linear_train``'s whole step then no longer fits the chip (the
-    ``[.., 16, 16]`` blocks pad eightfold; PERF.md, PR 30).  A length the chunk does
-    not divide is padded with tokens that leave the state alone (``g`` 0,
-    ``beta`` 0)."""
+    """The chunk-wise form in plain ``jax.numpy`` (module docstring): the
+    route of :func:`chunked_kda` wherever the kernels do not run, and
+    their oracle.  Same arguments as :func:`recurrent_kda`, the result in
+    the dtype of ``v``.  Chunks of 64 in blocks of 16 run 37-41 ms forward
+    and 104-107 with the backward pass at ``[2, 8192, 32, 128]`` on a v5e
+    (every intermediate goes through HBM; PERF.md, PRs 30 and 31).  A
+    length the chunk does not divide is padded with tokens that leave the
+    state alone (``g`` 0, ``beta`` 0)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     dtype = v.dtype
@@ -250,3 +274,564 @@ def chunked_kda(
     # [n, B, H, chunk, dv] -> [B, T, H, dv]
     out = jnp.moveaxis(jnp.moveaxis(out, 2, 3), 0, 1).reshape(B, n * chunk, H, dv)
     return out[:, :T]
+
+
+# --- The same chunk-wise form as Pallas (Mosaic) kernels --------------------
+#
+# One grid step is a group of heads' ``block`` tokens (whole chunks, worked
+# through in order by a ``fori_loop``); the grid is (batch, token block,
+# head group) with the token blocks in sequence, so every head's state
+# ``S^T`` ``[dv, dk]`` float32 stays in VMEM scratch from block to block.
+# Heads are lane blocks of the free ``[B, T, H * d]`` views: nothing is
+# transposed in HBM.  Per chunk nothing leaves VMEM but the output and,
+# where a backward pass will follow, the state at the chunk's start and
+# ``T``.  The backward kernel sweeps the token blocks in reverse with the
+# state's cotangent in scratch and makes every other intermediate of a
+# chunk again from the inputs and what was kept.
+#
+# A chunk and head is a chain of some dozen small dependent products, so
+# the kernels are bound by that chain's latency and by the passes of its
+# float32 products, not by operations or bytes (PERF.md, PR 31): the pair
+# loop is unrolled (its sixteen steps are independent but for a select and
+# the substitution's rank-one update; not unrolled it ran seven times
+# slower), a grid step works through two heads whose chains the scheduler
+# interleaves, and the backward is given ``T`` and not the chain that
+# makes it.
+#
+# Same mathematics, same precision (module docstring): ``G`` is the sum of
+# three bfloat16 products of a triangle of ones with the three pieces of
+# ``g``, float32 sums, exact as a float32 product at full precision
+# (:func:`_sum_rows`); the pairs inside a
+# ``sub`` x ``sub`` block are float32 on the vector unit, column ``j`` of
+# every block per loop step, and the same step is one step of the exact
+# forward substitution inside the blocks (``(I + D)^-1 = (I - d_15 e_15^T)
+# ... (I - d_0 e_0^T)``, ``d_j`` column ``j`` of ``D``: a rank-one update
+# of all four blocks at once, not a row at a time); pairs of different
+# blocks go through the first row of the later block; the blocks'
+# inverses combine as ``T <- T - T A_off T`` level by level at full
+# precision (that is ``T_21 = -T_22 A_21 T_11``); every other product
+# takes its operands in the dtype of ``v`` with float32 accumulation.  The
+# backward is by hand: ``dA = -T^T dT T^T``; for ``M_ts = sum_c x_tc k_sc
+# e^{G_tc - G_sc}`` with ``dx``, ``dk`` the cotangents through ``M`` alone,
+# ``dG = x dx - k dk`` (the same for the factors ``e^G``, ``e^{G_end -
+# G}``, ``e^{G_end}``), and ``dg`` is the reverse running sum of ``dG``
+# inside the chunk.  No exponent taken is positive there either.
+
+_KERNEL_CHUNK, _KERNEL_SUB = 64, 16
+_KERNEL_BLOCK_CHUNKS = (8, 4, 2, 1)  # chunks a grid step, the most that divides
+_KERNEL_HEADS = (2, 1)  # heads a grid step, likewise
+_KERNEL_VMEM_BYTES = 64 * 1024 * 1024  # of v5e's 128 MiB
+
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+
+
+def _mm(a, b, dims=_NN):
+    """A matrix product with float32 accumulation; float32 operands at
+    full precision, others as they are (said outright: an ambient
+    ``jax.default_matmul_precision`` is not for bfloat16 operands)."""
+    return lax.dot_general(
+        a, b, dims, preferred_element_type=_F32,
+        precision=_HI if a.dtype == _F32 else lax.Precision.DEFAULT,
+    )
+
+
+def _sum_rows(ones, x):
+    """``ones @ x`` for a matrix of zeros and ones and float32 ``x``, as
+    exact as a float32 product at full precision at half its passes: the
+    three bfloat16 pieces of ``x`` add up to it exactly, the ones have no
+    second piece, and the sums are float32."""
+    ones = ones.astype(jnp.bfloat16)
+    total = None
+    for _ in range(3):
+        piece = x.astype(jnp.bfloat16)
+        x = x - piece.astype(_F32)
+        part = _mm(ones, piece)
+        total = part if total is None else total + part
+    return total
+
+
+def kernel_admissible(q, k, v, g, beta, *, chunk: int, sub: int) -> bool:
+    """Whether the kernels take this call: whole tiles (key and value
+    widths multiples of 128 lanes, chunks of 64 in blocks of 16), one
+    dtype for ``q``, ``k``, ``v``.  Visible at trace time; the backend is
+    the caller's question."""
+    return (
+        q.shape == k.shape == g.shape
+        and q.shape[:3] == v.shape[:3] == beta.shape
+        and q.dtype == k.dtype == v.dtype
+        and q.shape[-1] % _LANES == 0
+        and v.shape[-1] % _LANES == 0
+        and (chunk, sub) == (_KERNEL_CHUNK, _KERNEL_SUB)
+    )
+
+
+def _same_group(row, col, size):
+    """Whether row and column index fall into the same group of ``size``
+    (a power of two)."""
+    shift = size.bit_length() - 1
+    return lax.shift_right_logical(row, shift) == lax.shift_right_logical(col, shift)
+
+
+def _block_rows(ref, a, j, n, sub, width):
+    """Row ``j`` of each of the ``n`` blocks of ``ref[a]`` ``[n * sub,
+    width]``, each spread over its block's rows."""
+    return jnp.concatenate(
+        [
+            jnp.broadcast_to(ref[a, pl.ds(i * sub + j, 1), :], (sub, width))
+            for i in range(n)
+        ],
+        axis=0,
+    )
+
+
+def _chunk_terms(q, k, v, g, b, St, G_scr, kf_scr, X_scr, a, *, sub, T=None):
+    """What a chunk's forward pass makes from its inputs ``[C, d]``, ``b``
+    ``[C, 1]`` and the state ``S^T`` at its start, all in VMEM; the
+    backward kernel makes the same again, but for ``T``, which it is
+    given.  Slice ``a`` of ``G_scr``, ``kf_scr`` ``[heads, C, dk]`` and
+    ``X_scr`` ``[heads, C, C]`` is this head's float32 scratch for the row
+    reads of the pair loop."""
+    C, dk = k.shape
+    dtype = v.dtype
+    n = C // sub
+    qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
+    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    same_block = _same_group(row, col, sub)
+    lower = col <= row
+    G = _sum_rows(lower, g.astype(_F32))  # the running sum
+    G_scr[a] = G
+    kf_scr[a] = kf
+    E = jnp.exp(G)
+    G_end = G[C - 1:, :]
+    trow = lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    in_block = trow & (sub - 1)
+
+    # Pairs of different blocks, through the first row of the later one.
+    scale_t = jnp.exp(G - _block_rows(G_scr, a, 0, n, sub, dk))
+    kt, qt = (kf * scale_t).astype(dtype), (qf * scale_t).astype(dtype)
+    es, ks = [None], [None]
+    kk_rows = [jnp.zeros((sub, C), _F32)]
+    qk_rows = [jnp.zeros((sub, C), _F32)]
+    for i in range(1, n):
+        ref = G[i * sub:i * sub + 1, :]
+        es.append(jnp.exp(jnp.where(trow < i * sub, ref - G, -jnp.inf)))
+        ks.append((kf * es[i]).astype(dtype))
+        lhs = jnp.concatenate(
+            [kt[i * sub:(i + 1) * sub], qt[i * sub:(i + 1) * sub]], axis=0
+        )
+        prod = _mm(lhs, ks[i], _NT)  # [2 sub, C]
+        kk_rows.append(prod[:sub])
+        qk_rows.append(prod[sub:])
+
+    # Pairs inside one block, one by one: column j of every block a step,
+    # and with it a step of the forward substitution inside the blocks:
+    # (I + D)^-1 = (I - d_15 e_15^T) ... (I - d_0 e_0^T), d_j column j of D.
+    below = in_block[:, :1]
+    eye = (row == col).astype(_F32)
+
+    def pairs(j, carry):
+        kk_in, qk_in, X = carry
+        Gs = _block_rows(G_scr, a, j, n, sub, dk)
+        ksj = _block_rows(kf_scr, a, j, n, sub, dk)
+        kd = ksj * jnp.exp(jnp.where(in_block >= j, G - Gs, -jnp.inf))
+        at_j = (col & (sub - 1)) == j
+        kk_j = jnp.sum(kf * kd, axis=1, keepdims=True)
+        qk_j = jnp.sum(qf * kd, axis=1, keepdims=True)
+        if T is None:
+            X_scr[a] = X
+            X = X - jnp.where(below > j, b * kk_j, 0.0) * _block_rows(
+                X_scr, a, j, n, sub, C
+            )
+        return jnp.where(at_j, kk_j, kk_in), jnp.where(at_j, qk_j, qk_in), X
+
+    zero = jnp.zeros((C, C), _F32)
+    kk_in, qk_in, X = lax.fori_loop(0, sub, pairs, (zero, zero, eye), unroll=True)
+    inside = same_block & lower
+    kk = jnp.where(inside, kk_in, jnp.concatenate(kk_rows, axis=0))
+    qk = jnp.where(inside, qk_in, jnp.concatenate(qk_rows, axis=0))
+
+    # T = (I + A)^-1, float32: the blocks' inverses combine level by level
+    # at full precision, T <- T - T A_off T (that is T_21 = -T_22 A_21 T_11).
+    kk_strict = jnp.where(col < row, kk, 0.0)
+    A = b * kk_strict
+    size = sub
+    while T is None and size < C:
+        off = _same_group(row, col, 2 * size) & ~_same_group(row, col, size)
+        X = X - _mm(_mm(X, jnp.where(off, A, 0.0)), X)
+        size *= 2
+    T = X if T is None else T
+
+    Tb = T.astype(dtype)
+    rv = (b * vf).astype(dtype)
+    rk32 = b * kf * E
+    rk = rk32.astype(dtype)
+    u_own = _mm(Tb, rv)
+    w = _mm(Tb, rk).astype(dtype)
+    Sd = St.astype(dtype)
+    u = (u_own - _mm(w, Sd, _NT)).astype(dtype)
+    e_end = jnp.exp(G_end - G)
+    ke32 = kf * e_end
+    qi32 = qf * E
+    return types.SimpleNamespace(
+        qf=qf, kf=kf, vf=vf, G=G, E=E, e_end=e_end, decay=jnp.exp(G_end),
+        scale_t=scale_t, kt=kt, qt=qt, es=es, ks=ks, kk_strict=kk_strict,
+        qk=qk, T=T, Tb=Tb, rv=rv, rk32=rk32, rk=rk, w=w, Sd=Sd, u=u,
+        ke32=ke32, ke=ke32.astype(dtype), qi32=qi32, qi=qi32.astype(dtype),
+        row=row, col=col, same_block=same_block, in_block=in_block,
+    )
+
+
+def _beta_column(b_ref, rows, h):
+    """Head ``h``'s column of the ``[C, H]`` tile, as ``[C, 1]``."""
+    tile = b_ref[0, rows, :].astype(_F32)
+    lane = lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.sum(jnp.where(lane == h, tile, 0.0), axis=1, keepdims=True)
+
+
+def _kda_fwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest,
+    scale, chunk, sub, chunks, heads, keep_states,
+):
+    """Grid (B, token blocks, H / heads).  ``S_scr`` ``[H, dv, dk]`` holds
+    every head's ``S^T``; ``s_ref`` and ``t_ref`` (kept for a backward
+    pass) take the state at each chunk's start and its ``T``.  The
+    ``heads`` of a step are independent chains of small dependent
+    products: written one after the other in one block of code, the
+    compiler's scheduler runs them side by side."""
+    s_ref, t_ref = rest[:2] if keep_states else (None, None)
+    S_scr, G_scr, kf_scr, X_scr = rest[-4:]
+    dk, dv = G_scr.shape[-1], S_scr.shape[-2]
+    first = pl.program_id(2) * heads
+
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        S_scr[pl.ds(first, heads)] = jnp.zeros((heads,) + S_scr.shape[1:], _F32)
+
+    def one_chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        for a in range(heads):
+            kl, vl = slice(a * dk, (a + 1) * dk), slice(a * dv, (a + 1) * dv)
+            St = S_scr[first + a]
+            if keep_states:
+                s_ref[0, c, a] = St
+            x = _chunk_terms(
+                q_ref[0, rows, kl], k_ref[0, rows, kl], v_ref[0, rows, vl],
+                g_ref[0, rows, kl], _beta_column(b_ref, rows, first + a), St,
+                G_scr, kf_scr, X_scr, a, sub=sub,
+            )
+            if keep_states:
+                t_ref[0, c, a] = x.T
+            out = _mm(x.qi, x.Sd, _NT) + _mm(x.qk.astype(x.u.dtype), x.u)
+            o_ref[0, rows, vl] = (scale * out).astype(o_ref.dtype)
+            S_scr[first + a] = St * x.decay + _mm(x.u, x.ke, _TN)
+        return 0
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+
+def _kernel_geometry(q, v, chunk):
+    B, T, H, dk = q.shape
+    n = T // chunk
+    chunks = next(m for m in _KERNEL_BLOCK_CHUNKS if n % m == 0)
+    heads = next(m for m in _KERNEL_HEADS if H % m == 0)
+    return B, T, H, dk, v.shape[-1], n, chunks, heads
+
+
+def _kernel_specs(chunk, H, dk, dv, chunks, heads, order):
+    """Block specs over the grid (batch, token block, head group): ``[block,
+    heads * d]`` tiles of the ``[B, T, H * d]`` views, the ``[block, H]``
+    tile of ``beta``, the ``chunks`` states of a block and their ``T``;
+    ``order`` maps the grid's token block to the array's (the backward
+    sweeps in reverse)."""
+    block = chunks * chunk
+    tile = lambda d: pl.BlockSpec(
+        (1, block, heads * d), lambda b, t, h: (b, order(t), h)
+    )
+    return (
+        tile(dk), tile(dv),
+        pl.BlockSpec((1, block, H), lambda b, t, h: (b, order(t), 0)),
+        pl.BlockSpec(
+            (1, chunks, heads, dv, dk), lambda b, t, h: (b, order(t), h, 0, 0)
+        ),
+        pl.BlockSpec(
+            (1, chunks, heads, chunk, chunk),
+            lambda b, t, h: (b, order(t), h, 0, 0),
+        ),
+    )
+
+
+def _kernel_scratch(H, dk, dv, chunk, heads):
+    """The state (or its cotangent) of every head, and a head group's
+    scratch for the pair loop's row reads."""
+    return [
+        pltpu.VMEM((H, dv, dk), _F32),
+        pltpu.VMEM((heads, chunk, dk), _F32),
+        pltpu.VMEM((heads, chunk, dk), _F32),
+        pltpu.VMEM((heads, chunk, chunk), _F32),
+    ]
+
+
+def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpret):
+    """``(out [B, T, H, dv], kept)``, ``kept`` the states ``[B, T/chunk,
+    H, dv, dk]`` and every chunk's ``T`` ``[B, T/chunk, H, chunk, chunk]``
+    (float32) or ``()``; ``T`` a multiple of ``chunk``."""
+    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, chunk)
+    kspec, vspec, bspec, sspec, tspec = _kernel_specs(
+        chunk, H, dk, dv, chunks, heads, lambda t: t
+    )
+    flat = lambda x: x.reshape(B, T, -1)
+    vma = _vma(q)
+    out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), v.dtype, vma=vma)]
+    out_specs = [vspec]
+    if keep_states:
+        out_shape.append(jax.ShapeDtypeStruct((B, n, H, dv, dk), _F32, vma=vma))
+        out_shape.append(jax.ShapeDtypeStruct((B, n, H, chunk, chunk), _F32, vma=vma))
+        out_specs += [sspec, tspec]
+    res = pl.pallas_call(
+        functools.partial(
+            _kda_fwd_kernel, scale=scale, chunk=chunk, sub=sub, chunks=chunks,
+            heads=heads, keep_states=keep_states,
+        ),
+        grid=(B, n // chunks, H // heads),
+        in_specs=[kspec, kspec, vspec, kspec, bspec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=_kernel_scratch(H, dk, dv, chunk, heads),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(flat(q), flat(k), flat(v), flat(g), beta)
+    return res[0].reshape(B, T, H, dv), tuple(res[1:])
+
+
+def _kda_bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, t_ref, do_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+    dS_scr, G_scr, kf_scr, X_scr, dkc_scr,
+    *, scale, chunk, sub, chunks, heads,
+):
+    """Grid (B, token blocks in reverse, H / heads).  ``dS_scr`` ``[H, dv, dk]``
+    holds the cotangent of every head's ``S^T`` at the end of the chunk
+    being worked on; ``dkc_scr`` takes the rows of the pair loop's key
+    cotangent.  ``db_ref`` is the ``[block, H]`` tile every head of the
+    block writes its column of."""
+    first = pl.program_id(2) * heads
+    n = chunk // sub
+    dk, dv = G_scr.shape[-1], dS_scr.shape[-2]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        dS_scr[pl.ds(first, heads)] = jnp.zeros((heads,) + dS_scr.shape[1:], _F32)
+
+    def one_head(a, c, rows):
+        h = first + a
+        kl, vl = slice(a * dk, (a + 1) * dk), slice(a * dv, (a + 1) * dv)
+        St = s_ref[0, c, a]
+        b = _beta_column(b_ref, rows, h)
+        v = v_ref[0, rows, vl]
+        dtype = v.dtype
+        x = _chunk_terms(
+            q_ref[0, rows, kl], k_ref[0, rows, kl], v, g_ref[0, rows, kl], b,
+            St, G_scr, kf_scr, X_scr, a, sub=sub, T=t_ref[0, c, a],
+        )
+        qf, kf, G, E = x.qf, x.kf, x.G, x.E
+        row, col = x.row, x.col
+        C = chunk
+        dSt = dS_scr[h]
+        dSb = dSt.astype(dtype)
+        dOb = (scale * do_ref[0, rows, vl].astype(_F32)).astype(dtype)
+
+        # The output and the state's update.
+        dqi = _mm(dOb, x.Sd)
+        dqk = jnp.where(col <= row, _mm(dOb, x.u, _NT), 0.0)
+        du = _mm(x.qk.astype(dtype), dOb, _TN) + _mm(x.ke, dSb, _NT)
+        dke = _mm(x.u, dSb)
+        dub = du.astype(dtype)
+        # u = T (b v) - (T (b k e^G)) S.
+        dwb = (-_mm(dub, x.Sd)).astype(dtype)
+        dT = _mm(dub, x.rv, _NT) + _mm(dwb, x.rk, _NT)
+        drv = _mm(x.Tb, dub, _TN)
+        drk = _mm(x.Tb, dwb, _TN)
+        dS_scr[h] = (
+            dSt * x.decay + _mm(dOb, x.qi, _TN) - _mm(dub, x.w, _TN)
+        )
+        # T = (I + A)^-1: dA = -T^T dT T^T, strictly lower.
+        dA = jnp.where(col < row, -_mm(_mm(x.T, dT, _TN), x.T, _NT), 0.0)
+        dkk = b * dA
+        db = (
+            jnp.sum(dA * x.kk_strict, axis=1, keepdims=True)
+            + jnp.sum(drv * x.vf, axis=1, keepdims=True)
+            + jnp.sum(drk * kf * E, axis=1, keepdims=True)
+        )
+
+        # The decayed products: pairs of different blocks ...
+        dkx_rows = [jnp.zeros((sub, dk), _F32)]
+        dqx_rows = [jnp.zeros((sub, dk), _F32)]
+        dkc = jnp.zeros((C, dk), _F32)
+        for i_blk in range(1, n):
+            blk = slice(i_blk * sub, (i_blk + 1) * sub)
+            dm = jnp.concatenate([dkk[blk], dqk[blk]], axis=0).astype(dtype)
+            dxt = _mm(dm, x.ks[i_blk])  # [2 sub, dk]
+            dkx_rows.append(dxt[:sub] * x.scale_t[blk])
+            dqx_rows.append(dxt[sub:] * x.scale_t[blk])
+            xt = jnp.concatenate([x.kt[blk], x.qt[blk]], axis=0)
+            dkc = dkc + _mm(dm, xt, _TN) * x.es[i_blk]
+        dkx = jnp.concatenate(dkx_rows, axis=0)
+        dqx = jnp.concatenate(dqx_rows, axis=0)
+
+        # ... and the pairs inside one block, column j of every block a step.
+        def pairs(j, carry):
+            dkx, dqx = carry
+            Gs = _block_rows(G_scr, a, j, n, sub, dk)
+            ksj = _block_rows(kf_scr, a, j, n, sub, dk)
+            e = jnp.exp(jnp.where(x.in_block >= j, G - Gs, -jnp.inf))
+            kd = ksj * e
+            at_j = x.same_block & ((col & (sub - 1)) == j)
+            mk = jnp.sum(jnp.where(at_j, dkk, 0.0), axis=1, keepdims=True)
+            mq = jnp.sum(jnp.where(at_j, dqk, 0.0), axis=1, keepdims=True)
+            to_key = (mk * kf + mq * qf) * e
+            for i_blk in range(n):
+                dkc_scr[a, pl.ds(i_blk * sub + j, 1), :] = jnp.sum(
+                    to_key[i_blk * sub:(i_blk + 1) * sub], axis=0, keepdims=True
+                )
+            return dkx + mk * kd, dqx + mq * kd
+
+        dkx, dqx = lax.fori_loop(0, sub, pairs, (dkx, dqx), unroll=True)
+        dkc = dkc + dkc_scr[a]
+
+        # dG = x dx - k dk for every factor; dg its reverse running sum.
+        to_end = dke * x.ke32
+        dG = (
+            kf * (dkx - dkc) + qf * dqx + dqi * x.qi32 + drk * x.rk32
+            - to_end
+        )
+        dG_end = jnp.sum(to_end, axis=0, keepdims=True) + x.decay * jnp.sum(
+            dSt * St, axis=0, keepdims=True
+        )
+        trow = lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+        dG = jnp.where(trow == C - 1, dG + dG_end, dG)
+        dg_ref[0, rows, kl] = _sum_rows(col >= row, dG).astype(dg_ref.dtype)
+        dq_ref[0, rows, kl] = (dqx + dqi * E).astype(dq_ref.dtype)
+        dk_ref[0, rows, kl] = (
+            dkx + dkc + drk * (b * E) + dke * x.e_end
+        ).astype(dk_ref.dtype)
+        dv_ref[0, rows, vl] = (b * drv).astype(dv_ref.dtype)
+        lane = lax.broadcasted_iota(jnp.int32, (C, db_ref.shape[2]), 1)
+        db_ref[0, rows, :] = jnp.where(
+            lane == h, db, db_ref[0, rows, :].astype(_F32)
+        ).astype(db_ref.dtype)
+
+    def one_chunk(i, _):
+        c = chunks - 1 - i
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        for a in range(heads):
+            one_head(a, c, rows)
+        return 0
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+
+def _kernel_backward(q, k, v, g, beta, states, ts, do, *, scale, chunk, sub, interpret):
+    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, chunk)
+    blocks = n // chunks
+    kspec, vspec, bspec, sspec, tspec = _kernel_specs(
+        chunk, H, dk, dv, chunks, heads, lambda t: blocks - 1 - t
+    )
+    flat = lambda x: x.reshape(B, T, -1)
+    vma = _vma(q)
+    like = lambda x: jax.ShapeDtypeStruct(flat(x).shape, x.dtype, vma=vma)
+    dq, dk_, dv_, dg, db = pl.pallas_call(
+        functools.partial(
+            _kda_bwd_kernel, scale=scale, chunk=chunk, sub=sub, chunks=chunks,
+            heads=heads,
+        ),
+        grid=(B, blocks, H // heads),
+        in_specs=[kspec, kspec, vspec, kspec, bspec, sspec, tspec, vspec],
+        out_specs=[kspec, kspec, vspec, kspec, bspec],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta)],
+        scratch_shapes=_kernel_scratch(H, dk, dv, chunk, heads)
+        + [pltpu.VMEM((heads, chunk, dk), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )(flat(q), flat(k), flat(v), flat(g), beta, states, ts, flat(do))
+    return dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape), dg.reshape(g.shape), db
+
+
+def _padded(x, pad):
+    return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def kernel_kda(q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False):
+    """The chunk-wise delta rule as Pallas kernels (section comment above),
+    forward and backward; what :func:`chunked_kda` runs on a TPU for the
+    calls :func:`kernel_admissible` admits.  ``interpret=True`` runs the
+    same kernels on the CPU for tests."""
+    return _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, False)[0]
+
+
+def _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, keep_states=True):
+    T = q.shape[1]
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    pad = -T % chunk
+    padded = tuple(_padded(x, pad) for x in (q, k, v, g, beta))
+    out, kept = _kernel_forward(
+        *padded, scale=scale, chunk=chunk, sub=_KERNEL_SUB,
+        keep_states=keep_states, interpret=interpret,
+    )
+    return out[:, :T], padded + kept
+
+
+def _kernel_bwd(scale, chunk, interpret, res, do):
+    T = do.shape[1]
+    scale = res[0].shape[-1] ** -0.5 if scale is None else scale
+    grads = _kernel_backward(
+        *res, _padded(do, -T % chunk), scale=scale, chunk=chunk,
+        sub=_KERNEL_SUB, interpret=interpret,
+    )
+    return tuple(dx[:, :T] for dx in grads)
+
+
+kernel_kda.defvjp(_kernel_fwd, _kernel_bwd)
+
+
+def kda_route(q, k, v, g, beta, *, chunk: int, sub: int) -> str:
+    """What :func:`chunked_kda` runs for this call: ``"kernel"`` on a TPU
+    for the calls the kernels admit, where a Mosaic kernel can lower;
+    else ``"plain"``."""
+    if (
+        jax.default_backend() == "tpu"
+        and kernel_admissible(q, k, v, g, beta, chunk=chunk, sub=sub)
+        and mosaic_can_lower()
+    ):
+        return "kernel"
+    return "plain"
+
+
+@jax.named_scope(KDA_CORE_SCOPE)
+def chunked_kda(
+    q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
+    sub: int = 16,
+):
+    """:func:`recurrent_kda` computed chunk-wise (module docstring); same
+    arguments, the result in the dtype of ``v``.  On a TPU, for whole
+    tiles, the Pallas kernels (:func:`kernel_kda`: 13.9 ms forward and
+    31.2 with the backward pass at ``[2, 8192, 32, 128]`` on a v5e, chunks
+    of 64; PERF.md, PR 31), else :func:`plain_kda`; the choice is counted
+    once per traced call.  A length the chunk does not divide is padded
+    with tokens that leave the state alone (``g`` 0, ``beta`` 0)."""
+    route = kda_route(q, k, v, g, beta, chunk=chunk, sub=sub)
+    get_registry().counter(
+        KDA_ROUTE_KERNEL if route == "kernel" else KDA_ROUTE_PLAIN
+    ).inc()
+    if route == "kernel":
+        return kernel_kda(q, k, v, g, beta, scale, chunk)
+    return plain_kda(q, k, v, g, beta, scale=scale, chunk=chunk, sub=sub)

@@ -62,6 +62,8 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     HOOKS,
     HOOK_WALKS,
     HOST_QUEUE_DEPTH,
+    KDA_ROUTE_KERNEL,
+    KDA_ROUTE_PLAIN,
     PIPELINE_BYTES,
     PREFETCH_DEPTH,
     PREFETCH_FILL,

@@ -82,6 +82,11 @@ ATTN_ROUTE_BLOCKWISE = "attention/route_blockwise"  # counter
 # the process-global registry like the attention routes; 0 for a model
 # with no fused head.
 UNEMBED_GRAD_IN_FORWARD = "unembed/grad_in_forward"  # counter
+# The route ``ops/linear_attention.py::chunked_kda`` chose, one increment
+# per traced call like attention's: the Pallas kernels on a TPU for whole
+# tiles, the plain ``jax.numpy`` form everywhere else.
+KDA_ROUTE_KERNEL = "kda/route_kernel"  # counter
+KDA_ROUTE_PLAIN = "kda/route_plain"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -32,6 +32,7 @@ from distributed_tensorflow_models_tpu.core import train_loop
 from distributed_tensorflow_models_tpu.core.train_state import TrainState
 from distributed_tensorflow_models_tpu.models import get_model
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
+from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.ops import optim
 from distributed_tensorflow_models_tpu.ops.conv_mxu import conv2d_mxu
 
@@ -72,6 +73,28 @@ def _conv_fwd(x, kernel):
     return conv2d_mxu(x, kernel, (1, 1), "SAME", interpret=False)
 
 
+def _kda(q, k, v, g, beta):
+    # The decay and ``beta`` are float32 in the mixer.
+    return linattn.kernel_kda(
+        q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32), None, 64, False
+    )
+
+
+def _kda_fwd_bwd(*x):
+    loss = lambda *x: jnp.sum(_kda(*x).astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
+
+
+def _kda_fwd_bwd_f32(*x):
+    # As the comparison with the reference runs the float32 program.
+    with jax.default_matmul_precision("highest"):
+        return _kda_fwd_bwd(*(a.astype(jnp.float32) for a in x))
+
+
+# ``kimi_linear_train``'s call of the chunk-wise delta rule.
+_KDA_SHAPES = [(2, 8192, 32, 128)] * 4 + [(2, 8192, 32)]
+
+
 @pytest.mark.parametrize(
     "fn,shapes,n_kernels",
     [
@@ -91,6 +114,12 @@ def _conv_fwd(x, kernel):
         pytest.param(
             _conv_fwd, [(32, 28, 28, 128), (3, 3, 128, 128)], 1,
             id="conv2d_mxu_28x28x128",
+        ),
+        pytest.param(_kda, _KDA_SHAPES, 1, id="kda_fwd_b2_t8192"),
+        pytest.param(_kda_fwd_bwd, _KDA_SHAPES, 2, id="kda_fwd_bwd_b2_t8192"),
+        pytest.param(
+            _kda_fwd_bwd_f32, [(1,) + s[1:] for s in _KDA_SHAPES], 2,
+            id="kda_fwd_bwd_f32_highest_b1_t8192",
         ),
     ],
 )
@@ -204,6 +233,40 @@ def test_latent_attention_compiles_fused_for_v5e(v5e, monkeypatch, dtype):
     ]
     assert len(kernels) == 2
     assert all(re.search(r"[/(]attention_core[/)]", line) for line in kernels)
+    assert sum("transpose(" in line for line in kernels) == 1
+    assert not re.search(r"\bwhile\(", text)
+
+
+def test_kda_mixer_takes_the_kernel_route_for_v5e(v5e, monkeypatch):
+    """A ``KDAMixer`` layer at ``kimi_linear_train``'s widths (32 heads of
+    128 over a width of 2304), ``value_and_grad`` under ``jit`` for the
+    described chip: the chunk-wise delta rule is two Mosaic kernels (the
+    forward that keeps the states, and the one backward kernel), both
+    under the ``linear_attn`` and ``kda_core`` scopes the per-layer readers
+    find them by, and nothing of the plain route's scan is left."""
+    from distributed_tensorflow_models_tpu.models.mixers import KDAMixer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    mixer = KDAMixer(num_heads=32, head_dim=128, d_model=2304, name="linear_attn")
+    x = jax.ShapeDtypeStruct((1, 2048, 2304), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.key(0), x),
+    )
+
+    def loss(params, x):
+        return jnp.sum(mixer.apply(params, x).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, x).compile().as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == 2
+    for scope in ("linear_attn", "kda_core"):
+        assert all(re.search(rf"[/(]{scope}[/)]", line) for line in kernels)
     assert sum("transpose(" in line for line in kernels) == 1
     assert not re.search(r"\bwhile\(", text)
 

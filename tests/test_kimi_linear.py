@@ -20,6 +20,7 @@ from distributed_tensorflow_models_tpu.models import get_model
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.parallel import moe as moelib
+from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
 SMALL = {
     **get_config("kimi_linear").model_kwargs,
@@ -124,6 +125,160 @@ def test_a_chunk_that_is_no_power_of_two_of_blocks_is_refused():
     x = _kda_inputs(0, 48, "mild")
     with pytest.raises(ValueError, match="power-of-two"):
         linattn.chunked_kda(*x, chunk=48, sub=16)
+
+
+# --- the same as Pallas kernels (interpret mode on the CPU) ----------------
+
+KERNEL_CASES = [
+    # length, decay; whole tiles (128 key and value channels, chunks of 64)
+    (128, "mild"),        # two chunks in one grid step
+    (70, "near_zero"),    # a length the chunk does not divide
+    (150, "mixed"),       # three grid steps of one chunk: the carried state
+    (192, "near_one"),
+    (384, "mixed"),       # three grid steps of two chunks
+]
+
+
+def _kernel_route(*x):
+    return linattn.kernel_kda(*x, None, 64, True)
+
+
+_KDA_ROUTES = {
+    "kernel": _kernel_route,
+    "plain": linattn.plain_kda,
+    "recurrence": linattn.recurrent_kda,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kda_result(route, T, decay, what, dtype="float32"):
+    """The output, or the five gradients of a probed sum, of one route."""
+    x = _kda_inputs(T, T, decay, B=1, H=2, dk=128, dv=128)
+    x = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
+    f = _KDA_ROUTES[route]
+    with jax.default_matmul_precision("highest"):
+        if what == "forward":
+            return (f(*x),)
+        probe = jax.random.normal(jax.random.key(9), x[2].shape)
+        loss = lambda *a: jnp.sum(f(*a).astype(jnp.float32) * probe)
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
+
+
+@pytest.mark.parametrize("oracle", ["recurrence", "plain"])
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("T,decay", KERNEL_CASES)
+def test_the_kernels_are_the_recurrence_and_the_plain_route(T, decay, what, oracle):
+    got, want = _kda_result("kernel", T, decay, what), _kda_result(oracle, T, decay, what)
+    tol = 2e-4 if what == "forward" else 1e-3
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-3
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=tol * scale + 1e-6, rtol=1e-3,
+            err_msg=name if what == "gradient" else "output",
+        )
+
+
+def test_the_kernels_take_keys_of_two_lane_blocks_and_an_odd_number_of_heads():
+    """256 key channels over 128 value channels, three heads (one head a
+    grid step): the output and the five gradients of the recurrence."""
+    x = _kda_inputs(7, 100, "mixed", B=1, H=3, dk=256, dv=128)
+    probe = jax.random.normal(jax.random.key(9), x[2].shape)
+    both = lambda f: jax.value_and_grad(
+        lambda *a: jnp.sum(f(*a) * probe), argnums=(0, 1, 2, 3, 4)
+    )(*x)
+    with jax.default_matmul_precision("highest"):
+        (got_sum, got), (want_sum, want) = both(_kernel_route), both(linattn.recurrent_kda)
+    assert float(got_sum) == pytest.approx(float(want_sum), rel=1e-4, abs=1e-4)
+    for name, g, w in zip("q k v g beta".split(), got, want):
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=1e-3 * scale + 1e-6, rtol=1e-3, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("T,decay", [(192, "mild"), (150, "mixed")])
+def test_the_kernels_in_bf16_are_the_plain_route_in_bf16(T, decay, what):
+    """Both routes round the same operands to bfloat16 and accumulate in
+    float32; they differ in the order of float32 sums and in which
+    cotangents the backward rounds, so they agree to a few bfloat16
+    roundings of the largest entry (0.03), and each is as near the float32
+    result as the other (within a factor of two)."""
+    got = _kda_result("kernel", T, decay, what, "bfloat16")
+    plain = _kda_result("plain", T, decay, what, "bfloat16")
+    exact = _kda_result("plain", T, decay, what)
+    for name, g, p, e in zip("q k v g beta".split(), got, plain, exact):
+        assert g.dtype == p.dtype, name
+        g, p = g.astype(jnp.float32), p.astype(jnp.float32)
+        scale = float(jnp.abs(e).max())
+        assert float(jnp.abs(g - p).max()) <= 0.03 * scale, name
+        assert float(jnp.abs(g - e).max()) <= 2 * float(jnp.abs(p - e).max()) + 4e-3 * scale, name
+
+
+def _kda_route_counts():
+    reg = reglib.get_registry()
+    return (
+        reg.counter(reglib.KDA_ROUTE_KERNEL).value,
+        reg.counter(reglib.KDA_ROUTE_PLAIN).value,
+    )
+
+
+@pytest.mark.parametrize(
+    "backend, devices, dk, dv, chunk, sub, want",
+    [
+        # Off the chip every call is the plain form.
+        ("cpu", 1, 128, 128, 64, 16, "plain"),
+        # Described as one TPU: the cell's widths.
+        ("tpu", 1, 128, 128, 64, 16, "kernel"),
+        ("tpu", 1, 256, 128, 64, 16, "kernel"),
+        # ... and what the kernels do not take.
+        ("tpu", 1, 16, 8, 64, 16, "plain"),      # no whole lane block
+        ("tpu", 1, 128, 64, 64, 16, "plain"),    # half a lane block of values
+        ("tpu", 1, 128, 128, 32, 16, "plain"),   # another chunk
+        ("tpu", 1, 128, 128, 64, 8, "plain"),    # other blocks
+        # A jit over several devices cannot partition a Mosaic kernel.
+        ("tpu", 4, 128, 128, 64, 16, "plain"),
+    ],
+)
+def test_which_route_the_delta_rule_takes(monkeypatch, backend, devices, dk, dv, chunk, sub, want):
+    """``chunked_kda`` chooses from the backend and what the call shows at
+    trace time, and counts the choice once per traced call."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    x = (spec(2, 200, 4, dk), spec(2, 200, 4, dk), spec(2, 200, 4, dv),
+         jax.ShapeDtypeStruct((2, 200, 4, dk), jnp.float32),
+         jax.ShapeDtypeStruct((2, 200, 4), jnp.float32))
+    assert linattn.kda_route(*x, chunk=chunk, sub=sub) == want
+    kernel0, plain0 = _kda_route_counts()
+    # Traced, not run: a Mosaic kernel cannot run here.
+    out = jax.eval_shape(functools.partial(linattn.chunked_kda, chunk=chunk, sub=sub), *x)
+    assert out.shape == (2, 200, 4, dv) and out.dtype == jnp.bfloat16
+    kernel1, plain1 = _kda_route_counts()
+    assert (kernel1 - kernel0, plain1 - plain0) == ((1, 0) if want == "kernel" else (0, 1))
+
+
+def test_the_entry_runs_the_kernels_where_it_would_on_the_chip(monkeypatch):
+    """``chunked_kda`` itself, taken down the kernel route (the backend
+    described as one TPU, the kernels interpreted): the recurrence, and
+    one count of ``kda/route_kernel``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    kernels = linattn.kernel_kda
+    monkeypatch.setattr(
+        linattn, "kernel_kda", lambda *x: kernels(*x[:5], None, 64, True)
+    )
+    x = _kda_inputs(5, 100, "mild", B=1, H=2, dk=128, dv=128)
+    kernel0, plain0 = _kda_route_counts()
+    got = linattn.chunked_kda(*x)
+    assert _kda_route_counts() == (kernel0 + 1, plain0)
+    with jax.default_matmul_precision("highest"):
+        want = linattn.recurrent_kda(*x)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-4 * float(jnp.abs(want).max())
+    )
 
 
 # --- latent attention's shapes through the attention routes --------------
@@ -592,6 +747,8 @@ def test_fit_trains_the_kimi_linear_program_config_and_reports_the_held_share(tm
     telemetry = json.load(open(tmp_path / "telemetry.json"))["metrics"]
     # One MLA layer's call, counted once per traced program (blockwise on the CPU).
     assert telemetry["attention/route_blockwise"] >= 1 and telemetry.get("attention/route_fused", 0) == 0
+    # Four KDA layers' calls likewise (the plain route on the CPU).
+    assert telemetry["kda/route_plain"] >= 4 and telemetry["kda/route_kernel"] == 0
 
 
 def test_kimi_linear_warms_up_and_the_other_language_models_do_not():
