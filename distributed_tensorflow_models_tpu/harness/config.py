@@ -547,6 +547,61 @@ _add(
 )
 
 
+# Olmo-Hybrid-7B (config.json of allenai/Olmo-Hybrid-7B, 2026-03): 32
+# layers of width 3840, three gated delta-rule linear attentions (Yang,
+# Kautz, Hatamizadeh 2024, arXiv:2412.06464: 30 heads of 96 key and 192
+# value channels, short convolution 4, one decay a head and step, b in
+# (0, 2): linear_allow_neg_eigval) to one full attention (30 heads of
+# 128, rotary), a gated SiLU feed-forward of 11008, RMSNorm 1e-6, no bias,
+# vocabulary 100352, untied.  What config.json does not say is the OLMo
+# family's (OLMo 2, arXiv:2501.00656, kept by OLMo 3): each norm on its
+# sub-layer's output inside the residual branch, RMSNorm over the whole
+# query and key projections, RoPE (theta 500000) in the full-attention
+# layers; the delta-rule layers take no positions
+# (benchmark/configs/olmo_hybrid.json lists every such choice under
+# ``assumed``).  Adam 3e-4 with clip 1.0 behind the 2,000-step warm-up of
+# the other large language models, per-half recomputation, sequences of
+# 8,192.  Every size is the published one; no single chip holds this in
+# training (benchmark/configs/olmo_hybrid.json runs layers 1-4, 15 of each
+# layer's 30 heads and an eighth of the vocabulary: one chip's share of 2
+# x 8).
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="olmo_hybrid",
+        model_kwargs={
+            "vocab_size": 100352,
+            "num_layers": 32,
+            "num_heads": 30,
+            "head_dim": 128,
+            "d_model": 3840,
+            "d_ff": 11008,
+            "max_len": 65536,
+            "dropout_rate": 0.0,
+            "pos_encoding": "rope",
+            "rope_theta": 500000.0,
+            "norm": "rmsnorm",
+            "norm_eps": 1e-6,
+            "norm_placement": "post",
+            "use_bias": False,
+            "qk_norm": True,
+            "mlp": "gated_silu",
+            "layer_mixers": ("gdn", "gdn", "gdn", "attention") * 8,
+            "gdn_num_heads": 30,
+            "gdn_key_dim": 96,
+            "gdn_value_dim": 192,
+            "gdn_conv_size": 4,
+            "remat": True,
+        },
+        global_batch_size=1,
+        num_steps=8192,
+        vocab_size=100352,
+        optimizer=dataclasses.replace(
+            _CONFIGS["transformer_lm"].optimizer, warmup_steps=2000
+        ),
+    )
+)
+
+
 def get_config(name: str, **overrides) -> ExperimentConfig:
     if name not in _CONFIGS:
         raise KeyError(f"unknown config {name!r}; have {sorted(_CONFIGS)}")

@@ -1,4 +1,5 @@
-"""Token mixers beside full attention, for stacks whose layers differ.
+"""Token mixers beside full attention, for stacks whose layers differ:
+three mixers, two kinds of decay.
 
 Kimi Linear (Kimi Linear technical report, Moonshot AI 2025,
 arXiv:2510.26692; ``config.json`` and the published modelling code of
@@ -23,9 +24,29 @@ one:
   rope_dim)^-0.5``.  The core is :func:`...ops.attention.attention`,
   with more query/key channels than value channels.
 
-Both compute in ``dtype`` over float32 parameters; the norms, the decay,
-``b_t``, the l2 norms and the output gate's norm are float32.  Neither
-decodes: the recurrent state and the latent cache have no place in
+Olmo-Hybrid (allenai/Olmo-Hybrid-7B ``config.json``: ``layer_types``,
+the ``linear_*`` keys) alternates, three to one with rotary full
+attention (``transformer_lm.SelfAttention``), a third:
+
+- :class:`GatedDeltaNetMixer`: the gated delta rule (Yang, Kautz,
+  Hatamizadeh 2024, "Gated Delta Networks", arXiv:2412.06464) with **one
+  decay a head and step** and key and value widths that differ (96 and
+  192).  Per head: ``q, k = l2norm(silu(conv(W x)))`` over ``key_dim``
+  channels, ``v = silu(conv(W_v x))`` over ``value_dim``; ``g_t =
+  -exp(A_log) * softplus(W_a x + dt_bias)`` and ``b_t = 2 sigmoid(W_b
+  x)``, one number each a head (``b`` up to 2: ``linear_allow_neg_eigval``,
+  the transition ``I - b k k^T`` then takes eigenvalues down to -1); the
+  state ``[key_dim, value_dim]`` and the read of
+  :func:`...ops.linear_attention.chunked_gdn` at ``key_dim^-0.5``; ``y =
+  W_o (rmsnorm_head(o_t) * silu(W_g x))``, the gate full rank.
+  ``num_heads`` is how many heads this program holds: every piece but
+  ``W_o``'s sum is per head, so a chip that shares a layer's heads with
+  others runs this module at its count and its output is its partial sum.
+
+All compute in ``dtype`` over float32 parameters; the norms, the decay,
+``b_t``, the l2 norms and the output gate's norm are float32.  None
+decodes: the recurrent states (a decay per channel at 128 x 128, a
+scalar decay at 96 x 192 a head) and the latent cache have no place in
 ``serving/kv_slots.py`` yet (ROADMAP Queue 2).
 """
 
@@ -40,8 +61,9 @@ import jax.numpy as jnp
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 
-# ``jax.named_scope`` (and flax module) name of the whole KDA mixer; the
-# chunk-wise core inside it is ``ops/linear_attention.py::KDA_CORE_SCOPE``.
+# ``jax.named_scope`` (and flax module) name of a whole delta-rule mixer,
+# either kind; the chunk-wise core inside it is
+# ``ops/linear_attention.py::KDA_CORE_SCOPE`` or ``GDN_CORE_SCOPE``.
 LINEAR_ATTN_SCOPE = "linear_attn"
 
 
@@ -62,6 +84,43 @@ def l2norm(x, eps: float = 1e-6):
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
 
+def _short_conv_silu(mdl: nn.Module, x, name: str, heads: int, dim: int):
+    """``silu(conv(W x))`` as ``[B, T, heads, dim]``: a bias-free
+    projection ``name``, its causal depthwise convolution ``conv_<name>``
+    of ``mdl.conv_size`` taps, SiLU."""
+    width, taps = heads * dim, mdl.conv_size
+    # torch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in K.
+    weight = mdl.param(
+        f"conv_{name}",
+        lambda rng: jax.random.uniform(
+            rng, (taps, width), jnp.float32, -(taps**-0.5), taps**-0.5
+        ),
+    )
+    y = causal_depthwise_conv(_dense(width, mdl.dtype, name)(x), weight)
+    return jax.nn.silu(y).reshape(*x.shape[:2], heads, dim)
+
+
+def _a_log_init(heads: int):
+    return lambda rng: jnp.log(
+        jax.random.uniform(rng, (heads,), jnp.float32, 1.0, 16.0)
+    )
+
+
+def _dt_bias_init(count: int):
+    """The inverse softplus of a step drawn log-uniformly from [1e-3,
+    1e-1] (the published layers' initialisation)."""
+
+    def init(rng):
+        dt = jnp.exp(
+            jax.random.uniform(
+                rng, (count,), jnp.float32, math.log(1e-3), math.log(1e-1)
+            )
+        )
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return init
+
+
 class KDAMixer(nn.Module):
     num_heads: int
     head_dim: int
@@ -75,43 +134,14 @@ class KDAMixer(nn.Module):
         B, T, _ = x.shape
         H, D = self.num_heads, self.head_dim
         width = H * D
-
-        def conv_weight(name):
-            # torch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in K.
-            bound = self.conv_size**-0.5
-            return self.param(
-                name,
-                lambda rng: jax.random.uniform(
-                    rng, (self.conv_size, width), jnp.float32, -bound, bound
-                ),
-            )
-
-        def mixed(name):
-            y = _dense(width, self.dtype, name)(x)
-            y = causal_depthwise_conv(y, conv_weight(f"conv_{name}"))
-            return jax.nn.silu(y).reshape(B, T, H, D)
-
+        mixed = lambda name: _short_conv_silu(self, x, name, H, D)
         q = l2norm(mixed("query")).astype(self.dtype)
         k = l2norm(mixed("key")).astype(self.dtype)
         v = mixed("value")
 
         # The decay: -exp(A_log) * softplus(low-rank(x) + dt_bias), float32.
-        a_log = self.param(
-            "A_log",
-            lambda rng: jnp.log(jax.random.uniform(rng, (H,), jnp.float32, 1.0, 16.0)),
-        )
-
-        def dt_bias_init(rng):
-            # The inverse softplus of a step drawn log-uniformly from
-            # [1e-3, 1e-1] (the published layer's initialisation).
-            dt = jnp.exp(
-                jax.random.uniform(
-                    rng, (width,), jnp.float32, math.log(1e-3), math.log(1e-1)
-                )
-            )
-            return dt + jnp.log(-jnp.expm1(-dt))
-
-        dt_bias = self.param("dt_bias", dt_bias_init)
+        a_log = self.param("A_log", _a_log_init(H))
+        dt_bias = self.param("dt_bias", _dt_bias_init(width))
         f = _dense(width, self.dtype, "f_b")(_dense(D, self.dtype, "f_a")(x))
         g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
             (f.astype(jnp.float32) + dt_bias).reshape(B, T, H, D)
@@ -125,6 +155,40 @@ class KDAMixer(nn.Module):
         o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(B, T, H, D))
         return _dense(self.d_model, self.dtype, "out")(
             o.astype(self.dtype).reshape(B, T, width)
+        )
+
+
+class GatedDeltaNetMixer(nn.Module):
+    num_heads: int  # the heads held here
+    key_dim: int
+    value_dim: int
+    d_model: int
+    conv_size: int = 4
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        q = l2norm(_short_conv_silu(self, x, "query", H, dk)).astype(self.dtype)
+        k = l2norm(_short_conv_silu(self, x, "key", H, dk)).astype(self.dtype)
+        v = _short_conv_silu(self, x, "value", H, dv)
+
+        # One decay and one b a head and token, float32.
+        a_log = self.param("A_log", _a_log_init(H))
+        dt_bias = self.param("dt_bias", _dt_bias_init(H))
+        per_head = lambda name: _dense(H, self.dtype, name)(x).astype(jnp.float32)
+        g = -jnp.exp(a_log) * jax.nn.softplus(per_head("a") + dt_bias)
+        beta = 2.0 * jax.nn.sigmoid(per_head("beta"))
+
+        o = linattn.chunked_gdn(q, k, v, g, beta)
+
+        gate = _dense(H * dv, self.dtype, "gate")(x)
+        o = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="o_norm")(o)
+        o = o * jax.nn.silu(gate.astype(jnp.float32).reshape(B, T, H, dv))
+        return _dense(self.d_model, self.dtype, "out")(
+            o.astype(self.dtype).reshape(B, T, H * dv)
         )
 
 

@@ -21,10 +21,16 @@ every other block, or softmax-then-top-k routing over gated experts in
 every block (:func:`...parallel.moe.topk_moe_ffn`; OLMoE, ROADMAP R1).
 These are a model's published settings, not tuning options.
 
-The layers of a stack may differ (Kimi Linear: ``harness/config.py::
-kimi_linear``): ``layer_mixers`` names each layer's token mixer (full
-attention, the delta-rule linear attention or the latent attention of
-:mod:`.mixers`), ``moe_first_dense`` leading layers keep a dense
+The layers of a stack may differ (Kimi Linear and Olmo-Hybrid:
+``harness/config.py::kimi_linear``, ``olmo_hybrid``): ``layer_mixers``
+names each layer's token mixer (full attention, or one of the two
+delta-rule linear attentions or the latent attention of :mod:`.mixers`;
+under ``pos_encoding="rope"`` only the full-attention layers rotate, the
+others take no positions), ``norm_placement`` puts each norm before its
+sub-layer (pre-norm) or on its output inside the residual branch (the
+OLMo family's), ``head_dim`` frees the attention's head size from
+``d_model / num_heads`` (a chip that holds a share of a layer's heads),
+``moe_first_dense`` leading layers keep a dense
 feed-forward (gated SiLU, ``dense_d_ff`` wide) before the expert layers
 start, an expert layer may have shared experts beside the routed ones,
 sigmoid scores renormalised and scaled, and hold a range of the router's
@@ -94,6 +100,10 @@ class SelfAttention(nn.Module):
     # weight, before the head split and the rotation (OLMoE).
     qk_norm: bool = False
     norm_eps: Optional[float] = None
+    # A head's channels where that is not ``d_model / num_heads`` (0): a
+    # program that holds ``num_heads`` of a layer's heads keeps the
+    # published head size, and its ``out`` projection gives a partial sum.
+    head_dim: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -102,11 +112,11 @@ class SelfAttention(nn.Module):
         B, T, _ = x.shape
         H = self.num_heads
         Hkv = self.num_kv_heads or H
-        Dh = self.d_model // H
+        Dh = self.head_dim or self.d_model // H
         dense = lambda name, feats: nn.Dense(
             feats, dtype=self.dtype, use_bias=self.use_bias, name=name
         )
-        q = dense("query", self.d_model)(x)
+        q = dense("query", H * Dh)(x)
         k = dense("key", Hkv * Dh)(x)
         if self.qk_norm:
             norm = lambda name, y: make_norm("rmsnorm", self.norm_eps, name)(
@@ -158,7 +168,7 @@ class SelfAttention(nn.Module):
                 q, k, v, causal=True, impl=self.attn_impl,
                 window=self.attn_window,
             )
-        out = out.reshape(B, T, self.d_model)
+        out = out.reshape(B, T, H * Dh)
         out = dense("out", self.d_model)(out)
         if self.dropout_rate:
             out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
@@ -379,10 +389,15 @@ class Block(nn.Module):
     moe_routing: Any = None
     moe_held: Any = None
     moe_shared_experts: int = 0
-    # The token mixer: "attention" (SelfAttention), "kda" or "mla"
+    # The token mixer: "attention" (SelfAttention), "kda", "gdn" or "mla"
     # (models/mixers.py; ``mixer_kwargs`` are that module's sizes).
     mixer: str = "attention"
     mixer_kwargs: Any = None
+    head_dim: int = 0
+    # "pre": ``x + f(norm(x))``; "post": ``x + norm(f(x))`` (OLMo 2's
+    # block, arXiv:2501.00656: the norm on the sub-layer's output, inside
+    # the residual branch).
+    norm_placement: str = "pre"
     # The dense feed-forward: the "gelu" MLP or the "gated_silu" one.
     mlp: str = "gelu"
     # Recompute in the backward pass, the mixer's half of the block and
@@ -394,8 +409,9 @@ class Block(nn.Module):
         if self.mixer == "attention":
             return self._attention(h, train)
         sizes = dict(self.mixer_kwargs or ())
-        if self.mixer == "kda":
-            out = mixers.KDAMixer(
+        if self.mixer in ("kda", "gdn"):
+            kind = mixers.KDAMixer if self.mixer == "kda" else mixers.GatedDeltaNetMixer
+            out = kind(
                 d_model=self.d_model, norm_eps=self.norm_eps or 1e-6,
                 dtype=self.dtype, name=mixers.LINEAR_ATTN_SCOPE, **sizes,
             )(h)
@@ -414,8 +430,12 @@ class Block(nn.Module):
         norm = lambda name, y: make_norm(self.norm, self.norm_eps, name)(
             y
         ).astype(self.dtype)
-        mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
-        feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
+        if self.norm_placement == "post":
+            mix = lambda mdl, y: norm("ln1", mdl._mix(y, train))
+            feed = lambda mdl, y: norm("ln2", mdl._ffn()(y, train=train))
+        else:
+            mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
+            feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
         if self.remat:
             mix, feed = nn.remat(mix), nn.remat(feed)
         x = x + mix(self, x)
@@ -475,6 +495,7 @@ class Block(nn.Module):
             use_bias=self.use_bias,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
+            head_dim=self.head_dim,
             name="attn",
         )(h, train=train)
 
@@ -695,8 +716,10 @@ class TransformerLM(nn.Module):
     # to the dense non-pipelined stack (and decode).
     attn_window: Any = None
     # Position encoding: "learned" absolute table (the default), "rope"
-    # rotary relative positions applied inside attention, or "none" (a
-    # stack whose mixers carry the order themselves: Kimi Linear).
+    # rotary relative positions applied inside full attention (the other
+    # mixers of ``layer_mixers`` take no positions: Olmo-Hybrid), or
+    # "none" (a stack whose mixers carry the order themselves: Kimi
+    # Linear).
     pos_encoding: str = "learned"
     rope_theta: float = 10000.0
     # What the architecture states beyond the GPT-2 block (defaults: that
@@ -707,6 +730,11 @@ class TransformerLM(nn.Module):
     norm_eps: Optional[float] = None
     use_bias: bool = True
     qk_norm: bool = False
+    # Each norm before its sub-layer ("pre") or on its output inside the
+    # residual branch ("post", the OLMo family's); full attention's head
+    # size where it is not ``d_model / num_heads`` (0).
+    norm_placement: str = "pre"
+    head_dim: int = 0
     # Experts (``num_experts`` > 0, each ``d_ff`` wide): "switch" routing
     # (top-1, capacity, ReLU experts) or "topk" (softmax then
     # ``moe_top_k``, gated SiLU experts, no token dropped); in every other
@@ -733,12 +761,16 @@ class TransformerLM(nn.Module):
     # expert's ``d_ff`` (0: the same).
     mlp: str = "gelu"
     dense_d_ff: int = 0
-    # Each layer's token mixer, "attention" | "kda" | "mla" (None: full
-    # attention everywhere), and the sizes models/mixers.py takes.
+    # Each layer's token mixer, "attention" | "kda" | "gdn" | "mla" (None:
+    # full attention everywhere), and the sizes models/mixers.py takes.
     layer_mixers: Any = None
     kda_num_heads: int = 0  # 0: num_heads
     kda_head_dim: int = 128
     kda_conv_size: int = 4
+    gdn_num_heads: int = 0  # 0: num_heads; the heads held here
+    gdn_key_dim: int = 96
+    gdn_value_dim: int = 192
+    gdn_conv_size: int = 4
     mla_kv_lora_rank: int = 512
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
@@ -753,12 +785,13 @@ class TransformerLM(nn.Module):
         for name, value, known in (
             ("pos_encoding", self.pos_encoding, ("learned", "rope", "none")),
             ("norm", self.norm, ("layernorm", "rmsnorm")),
+            ("norm_placement", self.norm_placement, ("pre", "post")),
             ("moe_router", self.moe_router, ("switch", "topk")),
             ("moe_layers", self.moe_layers, ("alternate", "all")),
             ("moe_scoring", self.moe_scoring, ("softmax", "sigmoid")),
             ("mlp", self.mlp, ("gelu", "gated_silu")),
             *(
-                (f"layer_mixers[{i}]", m, ("attention", "kda", "mla"))
+                (f"layer_mixers[{i}]", m, ("attention", "kda", "gdn", "mla"))
                 for i, m in enumerate(self._mixers())
             ),
         ):
@@ -772,10 +805,17 @@ class TransformerLM(nn.Module):
         plain = set(self._mixers()) == {"attention"}
         if not plain and (self.decode or self.attention_fn is not None):
             raise ValueError(
-                "the kda and mla mixers neither decode nor take a "
-                "sequence-parallel attention_fn: the recurrent state and "
+                "the kda, gdn and mla mixers neither decode nor take a "
+                "sequence-parallel attention_fn: the recurrent states and "
                 "the latent cache have no place in serving/kv_slots.py yet "
                 "(ROADMAP Queue 2)"
+            )
+        if self.decode and self.norm_placement != "pre":
+            raise ValueError(
+                "norm_placement='post' does not decode: the serving "
+                "programs and harness/generate.py have run pre-norm blocks "
+                "only, and the one model that states it (olmo_hybrid) has "
+                "mixers without a decode path (ROADMAP Queue 2)"
             )
         gpt2_block = (
             self.norm == "layernorm"
@@ -785,13 +825,15 @@ class TransformerLM(nn.Module):
             and plain
             and self.mlp == "gelu"
             and self.pos_encoding != "none"
+            and self.norm_placement == "pre"
+            and not self.head_dim
         )
         if (self.pipelined or self.pipe_mesh is not None) and not gpt2_block:
             raise ValueError(
                 "the pipelined block stack is the GPT-2 block only "
-                "(LayerNorm, biases, GELU MLP): norm/norm_eps/use_bias/"
-                "qk_norm and experts are not plumbed into the stacked "
-                "layout (ROADMAP D3)"
+                "(pre-LayerNorm, biases, GELU MLP): norm/norm_eps/"
+                "norm_placement/use_bias/qk_norm/head_dim and experts are "
+                "not plumbed into the stacked layout (ROADMAP D3)"
             )
         if self.decode and self.num_experts and self.moe_router != "topk":
             raise ValueError(
@@ -913,6 +955,12 @@ class TransformerLM(nn.Module):
                     ("head_dim", self.kda_head_dim),
                     ("conv_size", self.kda_conv_size),
                 ),
+                "gdn": (
+                    ("num_heads", self.gdn_num_heads or self.num_heads),
+                    ("key_dim", self.gdn_key_dim),
+                    ("value_dim", self.gdn_value_dim),
+                    ("conv_size", self.gdn_conv_size),
+                ),
                 "mla": (
                     ("kv_lora_rank", self.mla_kv_lora_rank),
                     ("nope_dim", self.mla_nope_dim),
@@ -957,6 +1005,8 @@ class TransformerLM(nn.Module):
                     moe_shared_experts=self.moe_shared_experts,
                     mixer=mixer,
                     mixer_kwargs=mixer_kwargs.get(mixer),
+                    head_dim=self.head_dim,
+                    norm_placement=self.norm_placement,
                     mlp=self.mlp,
                     remat=self.remat,
                     name=f"blocks_{i}",

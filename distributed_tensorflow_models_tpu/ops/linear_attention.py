@@ -1,4 +1,5 @@
-"""Delta-rule linear attention with a decay per channel (KDA), chunk-wise.
+"""Delta-rule linear attention, chunk-wise, for two kinds of decay: one
+per channel (KDA) and one per head (the gated delta rule).
 
 Kimi Delta Attention (Kimi Linear technical report, Moonshot AI 2025,
 arXiv:2510.26692) keeps, per head, a state ``S`` ``[dk, dv]`` that every
@@ -8,9 +9,15 @@ token decays channel by channel, corrects by the delta rule and reads::
     o_t = scale * S_t^T q_t
 
 with ``a_t`` in (0, 1)^dk (given here as its logarithm ``g_t <= 0``) and
-``b_t`` in (0, 1).  :func:`recurrent_kda` is that recurrence token by
-token (a ``lax.scan`` over time; the oracle of the tests).
-:func:`chunked_kda` is what the model runs: matrix products inside
+``b_t`` in (0, 1).  The gated delta rule (Yang, Kautz, Hatamizadeh 2024,
+"Gated Delta Networks", arXiv:2412.06464; Olmo-Hybrid's linear layers) is
+the same recurrence with ``a_t`` one number a head, ``g`` ``[B, T, H]``,
+and ``b_t`` in (0, 2) where the model allows the transition ``I - b k
+k^T`` eigenvalues down to -1; its key and value widths need not agree
+(96 and 192).  :func:`recurrent_kda` is the recurrence token by token (a
+``lax.scan`` over time; the oracle of the tests, of both: a scalar decay
+is ``g`` spread over the key channels).  :func:`chunked_kda` and
+:func:`chunked_gdn` are what the models run: matrix products inside
 chunks of ``chunk`` tokens and the state carried from chunk to chunk.
 
 The chunk-wise form.  With ``G_t = sum_{r<=t} g_r`` inside a chunk that
@@ -21,20 +28,25 @@ diag(e^{G_t - G_s}) k_s u_s^T``, and the ``u`` of a chunk solve
     (I + A) U = diag(b) (V - (K * e^G) S_0),
     A_ts = b_t sum_c k_tc k_sc e^{G_tc - G_sc}   (s < t),
 
-a unit lower-triangular system per chunk and head.  So per chunk:
-``T = (I + A)^-1``; ``U = T diag(b) V - (T diag(b) (K * e^G)) S_0``;
-``O = scale * ((Q * e^G) S_0 + tril(QK) U)`` with ``QK_ts = sum_c q_tc
-k_sc e^{G_tc - G_sc}`` (``s <= t``); ``S_C = diag(e^{G_C}) S_0 + (K *
-e^{G_C - G})^T U``.  Only ``U`` and the state need the chunks in order.
+a unit lower-triangular system per chunk and head (for any ``b``: nothing
+here needs ``b < 1``).  So per chunk: ``T = (I + A)^-1``; ``U = T diag(b)
+V - (T diag(b) (K * e^G)) S_0``; ``O = scale * ((Q * e^G) S_0 + tril(QK)
+U)`` with ``QK_ts = sum_c q_tc k_sc e^{G_tc - G_sc}`` (``s <= t``); ``S_C
+= diag(e^{G_C}) S_0 + (K * e^{G_C - G})^T U``.  Only ``U`` and the state
+need the chunks in order.
 
 Decays near 0.  ``e^{G_t - G_s}`` cannot be split as ``e^{G_t} e^{-G_s}``:
 sixty-four steps of a strong decay put ``e^{-G_s}`` beyond float32 while
 the quotient is an ordinary number.  Every exponent taken here is of a
-difference ``G_later - G_earlier <= 0``: the pairs of one ``sub``-token
-block are computed one by one (``[sub, sub, dk]`` exponentials), a pair
-of different blocks through a point between them, the first row of the
-later block (``e^{G_t - G_ref} e^{G_ref - G_s}``, both at most 1), so
-those are matrix products.
+difference ``G_later - G_earlier <= 0``.  With a decay per channel the
+pairs of one ``sub``-token block are computed one by one (``[sub, sub,
+dk]`` exponentials), a pair of different blocks through a point between
+them, the first row of the later block (``e^{G_t - G_ref} e^{G_ref -
+G_s}``, both at most 1), so those are matrix products.  With one decay a
+head ``e^{G_t - G_s}`` leaves the sum over channels: ``K K^T`` and ``Q
+K^T`` are plain matrix products under one ``[C, C]`` mask of such
+quotients and there is no pair loop (:func:`plain_gdn`); everything after
+the two products is one function for both decays (:func:`_chunkwise`).
 
 Precision.  ``g``, ``G``, every exponential, ``A``, ``T``, the state and
 all accumulations are float32.  The matrix products take their operands
@@ -51,13 +63,15 @@ and :func:`plain_kda`, the same form in ``jax.numpy``, everywhere else
 (the CPU, other head sizes, a ``jit`` over several devices outside
 ``shard_map``); the choice is counted once per traced call
 (``kda/route_kernel``, ``kda/route_plain``).  The two paragraphs above
-hold for both, forward and backward.
+hold for both, forward and backward.  :func:`chunked_gdn` has the one
+route, :func:`plain_gdn`, and counts its traced calls as
+``gdn/route_plain``.
 
-The backward pass of the plain route is autodiff through all of it
+The backward pass of the plain routes is autodiff through all of it
 except ``T``, whose cotangent is ``-T^T dT T^T`` (no pass through the
 substitution); the kernels' is by hand (the section comment below).
 What either keeps is per chunk (the state at each chunk's start and
-``T``; the plain route also ``U``), never a state per token.
+``T``; the plain routes also ``U``), never a state per token.
 """
 
 from __future__ import annotations
@@ -78,6 +92,7 @@ from distributed_tensorflow_models_tpu.ops.attention import (
     mosaic_can_lower,
 )
 from distributed_tensorflow_models_tpu.telemetry.registry import (
+    GDN_ROUTE_PLAIN,
     KDA_ROUTE_KERNEL,
     KDA_ROUTE_PLAIN,
     get_registry,
@@ -88,6 +103,9 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (
 # section 3).  The projections, the convolutions, the gates and the output
 # norm of the mixer stay outside it (``linear_attn`` holds them all).
 KDA_CORE_SCOPE = "kda_core"
+# The same of :func:`chunked_gdn` (one decay a head): a scope of its own,
+# because its need per step is another count (``benchmark/flops/``).
+GDN_CORE_SCOPE = "gdn_core"
 
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -207,42 +225,39 @@ def _decayed_products(xs, k, G, sub: int, dtype):
     return [jnp.concatenate(row, axis=-2) for row in rows]
 
 
-def plain_kda(
-    q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
-    sub: int = 16,
-):
-    """The chunk-wise form in plain ``jax.numpy`` (module docstring): the
-    route of :func:`chunked_kda` wherever the kernels do not run, and
-    their oracle.  Same arguments as :func:`recurrent_kda`, the result in
-    the dtype of ``v``.  Chunks of 64 in blocks of 16 run 37-41 ms forward
-    and 104-107 with the backward pass at ``[2, 8192, 32, 128]`` on a v5e
-    (every intermediate goes through HBM; PERF.md, PRs 30 and 31).  A
-    length the chunk does not divide is padded with tokens that leave the
-    state alone (``g`` 0, ``beta`` 0)."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    dtype = v.dtype
-    scale = dk**-0.5 if scale is None else scale
+def _check_chunk(chunk: int, sub: int) -> int:
+    """``sub`` clamped to the chunk; a chunk is a power-of-two multiple of
+    it (:func:`unit_lower_inverse` doubles the blocks)."""
     sub = min(sub, chunk)
     if chunk % sub or (chunk // sub) & (chunk // sub - 1):
         raise ValueError(
             f"chunk {chunk} has to be a power-of-two multiple of sub {sub}"
         )
+    return sub
+
+
+def _in_chunks(x, chunk: int):
+    """``[B, T, H, ...]`` -> ``[n, B, H, chunk, ...]`` (a scan runs over
+    axis 0), the length padded to whole chunks with zeros: tokens that
+    leave the state alone (``g`` 0, ``beta`` 0)."""
+    B, T, H = x.shape[:3]
     pad = -T % chunk
-    n = (T + pad) // chunk
+    x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape(B, (T + pad) // chunk, chunk, H, *x.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
 
-    def chunks(x):
-        # [B, T, H, ...] -> [n, B, H, chunk, ...]: the scan runs over axis 0.
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape(B, n, chunk, H, *x.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
 
-    q, k, v = chunks(q), chunks(k), chunks(v)
-    b = chunks(beta.astype(_F32))[..., None]  # [n, B, H, chunk, 1]
-    G = jnp.cumsum(chunks(g.astype(_F32)), axis=-2)  # [n, B, H, chunk, dk]
+def _chunkwise(q, k, v, b, G, kk, qk, *, scale: float, sub: int, length: int):
+    """The chunk-wise form from the decayed products on (module
+    docstring), for either decay: ``q``, ``k``, ``v`` in chunks ``[n, B,
+    H, C, d]``, ``b`` ``[n, B, H, C, 1]``, the running sum ``G`` of the
+    log decay ``[n, B, H, C, dk]`` (a decay per channel) or ``[n, B, H, C,
+    1]`` (one a head), ``kk`` and ``qk`` ``[n, B, H, C, C]`` float32.
+    Returns ``[B, length, H, dv]`` in the dtype of ``v``."""
+    n, B, H, chunk, dk = k.shape
+    dv = v.shape[-1]
+    dtype = v.dtype
     G_end = G[..., -1:, :]
-
-    kk, qk = _decayed_products((k, q), k, G, sub, dtype)
     A = b * jnp.tril(kk, -1)
     t = unit_lower_inverse(A, sub).astype(dtype)
     decayed = jnp.exp(G)
@@ -273,7 +288,57 @@ def plain_kda(
     out = (scale * out).astype(dtype)
     # [n, B, H, chunk, dv] -> [B, T, H, dv]
     out = jnp.moveaxis(jnp.moveaxis(out, 2, 3), 0, 1).reshape(B, n * chunk, H, dv)
-    return out[:, :T]
+    return out[:, :length]
+
+
+def plain_kda(
+    q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
+    sub: int = 16,
+):
+    """The chunk-wise form in plain ``jax.numpy`` (module docstring): the
+    route of :func:`chunked_kda` wherever the kernels do not run, and
+    their oracle.  Same arguments as :func:`recurrent_kda`, the result in
+    the dtype of ``v``.  Chunks of 64 in blocks of 16 run 37-41 ms forward
+    and 104-107 with the backward pass at ``[2, 8192, 32, 128]`` on a v5e
+    (every intermediate goes through HBM; PERF.md, PRs 30 and 31).  A
+    length the chunk does not divide is padded with tokens that leave the
+    state alone (``g`` 0, ``beta`` 0)."""
+    T, dk = q.shape[1], q.shape[-1]
+    scale = dk**-0.5 if scale is None else scale
+    sub = _check_chunk(chunk, sub)
+    q, k, v = (_in_chunks(x, chunk) for x in (q, k, v))
+    b = _in_chunks(beta.astype(_F32), chunk)[..., None]  # [n, B, H, chunk, 1]
+    G = jnp.cumsum(_in_chunks(g.astype(_F32), chunk), axis=-2)  # [.., chunk, dk]
+    kk, qk = _decayed_products((k, q), k, G, sub, v.dtype)
+    return _chunkwise(q, k, v, b, G, kk, qk, scale=scale, sub=sub, length=T)
+
+
+def plain_gdn(
+    q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
+    sub: int = 16,
+):
+    """The chunk-wise form for **one decay a head and step** (the gated
+    delta rule; module docstring): ``g`` ``[B, T, H]``, the key and value
+    widths free (96 and 192 in Olmo-Hybrid).  A scalar decay leaves the
+    key-key and query-key products ordinary matrix products under one
+    ``[C, C]`` mask ``e^{G_t - G_s}`` (``s <= t``: no exponent is
+    positive), so there is no pair loop; everything after them is
+    :func:`plain_kda`'s own code."""
+    T, dk = q.shape[1], q.shape[-1]
+    scale = dk**-0.5 if scale is None else scale
+    sub = _check_chunk(chunk, sub)
+    q, k, v = (_in_chunks(x, chunk) for x in (q, k, v))
+    b = _in_chunks(beta.astype(_F32), chunk)[..., None]  # [n, B, H, chunk, 1]
+    G = jnp.cumsum(_in_chunks(g.astype(_F32), chunk), axis=-1)  # [n, B, H, chunk]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    mask = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
+    masked = lambda x: mask * jnp.einsum(
+        "...tc,...sc->...ts", x, k, preferred_element_type=_F32
+    )
+    return _chunkwise(
+        q, k, v, b, G[..., None], masked(k), masked(q), scale=scale, sub=sub,
+        length=T,
+    )
 
 
 # --- The same chunk-wise form as Pallas (Mosaic) kernels --------------------
@@ -835,3 +900,28 @@ def chunked_kda(
     if route == "kernel":
         return kernel_kda(q, k, v, g, beta, scale, chunk)
     return plain_kda(q, k, v, g, beta, scale=scale, chunk=chunk, sub=sub)
+
+
+# --- One decay a head and step: the gated delta rule -----------------------
+
+
+@jax.named_scope(GDN_CORE_SCOPE)
+def chunked_gdn(
+    q, k, v, g, beta, *, scale: Optional[float] = None, chunk: int = 64,
+    sub: int = 16,
+):
+    """The gated delta rule (Yang, Kautz, Hatamizadeh 2024,
+    arXiv:2412.06464) chunk-wise: :func:`recurrent_kda` with ``g`` ``[B,
+    T, H]`` spread over the key channels; ``q``, ``k`` ``[B, T, H, dk]``,
+    ``v`` ``[B, T, H, dv]`` (any widths), ``beta`` ``[B, T, H]`` in (0, 2)
+    (at ``b > 1`` the transition ``I - b k k^T`` has a negative
+    eigenvalue); the result in the dtype of ``v``.  One route,
+    :func:`plain_gdn`, counted once per traced call
+    (``gdn/route_plain``).  No kernel for a scalar decay is written: the
+    per-channel kernels fed padded inputs (keys to 128 lanes, values to
+    256, ``g`` spread over the key channels) run 4.9 ms forward and 9.9
+    with the backward pass at Olmo-Hybrid's ``[1, 8192, 15, 96 | 192]`` on
+    a v5e against :func:`plain_gdn`'s 6.3 and 15.4 (PERF.md, PR 32), and
+    still walk a pair loop this decay does not need."""
+    get_registry().counter(GDN_ROUTE_PLAIN).inc()
+    return plain_gdn(q, k, v, g, beta, scale=scale, chunk=chunk, sub=sub)

@@ -61,6 +61,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     FLOPS_TOTAL,
     HOOKS,
     HOOK_WALKS,
+    GDN_ROUTE_PLAIN,
     HOST_QUEUE_DEPTH,
     KDA_ROUTE_KERNEL,
     KDA_ROUTE_PLAIN,

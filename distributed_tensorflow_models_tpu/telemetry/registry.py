@@ -87,6 +87,9 @@ UNEMBED_GRAD_IN_FORWARD = "unembed/grad_in_forward"  # counter
 # tiles, the plain ``jax.numpy`` form everywhere else.
 KDA_ROUTE_KERNEL = "kda/route_kernel"  # counter
 KDA_ROUTE_PLAIN = "kda/route_plain"  # counter
+# Traced calls of ``ops/linear_attention.py::chunked_gdn`` (one decay a
+# head), which has the plain route alone.
+GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -10,7 +10,9 @@ data-parallel step over four devices compiling on every later PR.
 Nothing runs and nothing here is a measurement.  Only the fast compiles
 are kept (about a second or two each); the whole ResNet-50 b256 step
 (~40 s) and the ``conv2d_mxu`` gradient at 56x56x64 (~18 s) stay in the
-builder's rehearsal.  The persistent cache is switched off around the
+builder's rehearsal.  One whole step is here all the same, ISSUE 32's:
+``olmo_hybrid_train``'s (45 s), because that cell's batch was chosen by
+what the compiler places, and a later PR's temporary could undo it.  The persistent cache is switched off around the
 cases: an executable compiled for a described chip is written to it but
 cannot be read back without one, and the next run would warn.
 """
@@ -377,3 +379,94 @@ def test_data_parallel_step_compiles_over_four_chips(v5e):
     assert "all-reduce" in compiled.as_text()
     # Donation reached the compiler: the state's bytes alias the output.
     assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+# ``olmo_hybrid_train``'s call of the gated delta rule: one sequence of
+# 8,192 tokens, 15 heads of 96 key and 192 value channels, ``g`` and
+# ``beta`` one number a head.
+_GDN_SHAPES = [(1, 8192, 15, 96)] * 2 + [(1, 8192, 15, 192)] + [(1, 8192, 15)] * 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32_highest"])
+def test_chunked_gdn_compiles_for_v5e(v5e, monkeypatch, dtype):
+    """``chunked_gdn`` forward and backward at the cell's shape, as the
+    cell runs it (bf16) and as the comparison with the reference runs the
+    float32 program (under ``default_matmul_precision("highest")``, which
+    PR 31's kernels first met on the chip): whatever route it takes has
+    to lower, with the ``gdn_core`` scope on its instructions."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    args = [
+        jax.ShapeDtypeStruct(s, dtype if len(s) == 4 else jnp.float32, sharding=one_chip)
+        for s in _GDN_SHAPES
+    ]
+
+    def fwd_bwd(*x):
+        loss = lambda *x: jnp.sum(linattn.chunked_gdn(*x).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
+
+    if dtype == jnp.float32:
+        with jax.default_matmul_precision("highest"):
+            compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    else:
+        compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"[/(]gdn_core[/)]", text) and "kda_core" not in text
+    assert "tpu_custom_call" not in text  # the plain route alone, today
+    # Nothing the size of a state per token (9 GB): a state per chunk (0.14
+    # GB in float32) a few times over, 1.8 GiB in all in float32.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
+    """The whole ``olmo_hybrid_train`` step (the cell's configuration
+    through ``benchmark/lib/cells.py``, Adam with the clip, the fused
+    head, per-half recomputation, one sequence of 8,192) for one
+    described v5e: it fits the chip's 15.75 GiB with room (13.15 GiB when
+    the cell was added: 8.56 of state, 4.41 of temporaries; PERF.md, PR
+    32), the compiler rematerializes nothing of its own, the attention
+    layer runs the fused kernels and the three scopes are on the step."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark.lib import cells
+
+    from distributed_tensorflow_models_tpu.harness import train as trainlib
+    from distributed_tensorflow_models_tpu.harness.config import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cell = cells.load_cell("olmo_hybrid_train")
+    per_chip = cell.traffic["fit"]["per_chip_batch"]
+    cfg = get_config(
+        cell.config["program_config"], **cell.config["overrides"], global_batch_size=per_chip
+    )
+    assert (per_chip, cfg.num_steps, cfg.fused_unembed) == (1, 8192, True)
+    model = get_model(cfg.model, **cfg.model_kwargs)
+    state = jax.eval_shape(
+        lambda: TrainState.create(
+            model, cfg.optimizer.make(), jax.random.key(0),
+            jnp.zeros((2, 128), jnp.int32), jit_init=False,
+        )
+    )
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    step = train_loop.make_train_step(trainlib.build_loss(cfg, state), donate=True)
+    tokens = jax.ShapeDtypeStruct((per_chip, cfg.num_steps), jnp.int32, sharding=one_chip)
+    compiled = step.lower(
+        jax.tree.map(spec, state), {"inputs": tokens, "targets": tokens},
+        spec(jax.eval_shape(lambda: jax.random.key(0))),
+    ).compile()
+    m = compiled.memory_analysis()
+    held = (
+        m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+        - m.alias_size_in_bytes + m.generated_code_size_in_bytes
+    )
+    assert 12.0 * 2**30 < held < 14.5 * 2**30, held / 2**30
+    text = compiled.as_text()
+    # The attention layer: forward, the recomputed forward, the backward.
+    assert text.count("tpu_custom_call") >= 3
+    assert not re.search(r"\.remat\d*", text)
+    for scope in ("linear_attn", "gdn_core", "attention_core", "unembed_loss", "optimizer"):
+        assert re.search(rf"[/(]{scope}[/)]", text), scope
