@@ -1328,9 +1328,9 @@ def _export_trace(
 
 def _trace_counts() -> dict[str, float]:
     """What the ops count at trace time (``attention(impl="auto")``'s
-    routes, ``chunked_kda``'s, ``chunked_gdn``'s traced calls, the fused
-    head's gradient-in-forward calls), as the process-global registry
-    holds it now."""
+    routes, ``chunked_kda``'s, ``KDAMixer``'s two placements,
+    ``chunked_gdn``'s traced calls, the fused head's gradient-in-forward
+    calls), as the process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
@@ -1339,6 +1339,8 @@ def _trace_counts() -> dict[str, float]:
             telemetry.ATTN_ROUTE_BLOCKWISE,
             telemetry.KDA_ROUTE_KERNEL,
             telemetry.KDA_ROUTE_PLAIN,
+            telemetry.KDA_MIXER_FUSED,
+            telemetry.KDA_MIXER_PLAIN,
             telemetry.GDN_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
         )

@@ -14,7 +14,14 @@ one:
   per head), ``b_t = sigmoid(W_b x)`` per head; the state and the read of
   :mod:`...ops.linear_attention`; ``y = W_o (rmsnorm_head(o_t) *
   sigmoid(W_g2 W_g1 x))``.  The two low-rank gates go through
-  ``head_dim`` channels.
+  ``head_dim`` channels.  On a TPU, for heads of whole lane blocks
+  (``head_dim`` a multiple of 128), everything between the projections
+  and the core and between the core and ``W_o`` runs as fused Pallas
+  passes over the flat ``[B, T, H * head_dim]`` views the projections
+  write and the core's kernels read
+  (:func:`...ops.linear_attention.kda_prologue`, ``kda_epilogue``), so no
+  ``[B, T, H, head_dim]`` array exists in HBM; elsewhere plain
+  ``jax.numpy`` on that view.  Same parameters, same mathematics.
 - :class:`LatentAttention`: multi-head latent attention without query
   compression and **without positions** (``mla_use_nope``): ``q = W_q x``
   (``nope_dim + rope_dim`` channels a head; the names are the config's,
@@ -44,10 +51,13 @@ attention (``transformer_lm.SelfAttention``), a third:
   others runs this module at its count and its output is its partial sum.
 
 All compute in ``dtype`` over float32 parameters; the norms, the decay,
-``b_t``, the l2 norms and the output gate's norm are float32.  None
-decodes: the recurrent states (a decay per channel at 128 x 128, a
-scalar decay at 96 x 192 a head) and the latent cache have no place in
-``serving/kv_slots.py`` yet (ROADMAP Queue 2).
+``b_t``, the l2 norms and the output gate's norm are float32 (the fused
+passes of :class:`KDAMixer` hold float32 from the projections' outputs to
+``q``, ``k``, ``v``, where the plain code rounds the convolution and the
+SiLU to ``dtype`` on the way).  None decodes: the recurrent states (a
+decay per channel at 128 x 128, a scalar decay at 96 x 192 a head) and
+the latent cache have no place in ``serving/kv_slots.py`` yet (ROADMAP
+Queue 2).
 """
 
 from __future__ import annotations
@@ -60,6 +70,11 @@ import jax.numpy as jnp
 
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
+from distributed_tensorflow_models_tpu.telemetry.registry import (
+    KDA_MIXER_FUSED,
+    KDA_MIXER_PLAIN,
+    get_registry,
+)
 
 # ``jax.named_scope`` (and flax module) name of a whole delta-rule mixer,
 # either kind; the chunk-wise core inside it is
@@ -84,11 +99,11 @@ def l2norm(x, eps: float = 1e-6):
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + eps)
 
 
-def _short_conv_silu(mdl: nn.Module, x, name: str, heads: int, dim: int):
-    """``silu(conv(W x))`` as ``[B, T, heads, dim]``: a bias-free
-    projection ``name``, its causal depthwise convolution ``conv_<name>``
-    of ``mdl.conv_size`` taps, SiLU."""
-    width, taps = heads * dim, mdl.conv_size
+def _projection_and_taps(mdl: nn.Module, x, name: str, width: int):
+    """A bias-free projection ``name`` of ``x`` to ``width`` channels and
+    the taps ``conv_<name>`` ``[mdl.conv_size, width]`` of its causal
+    depthwise convolution."""
+    taps = mdl.conv_size
     # torch's Conv1d default: uniform(+-1/sqrt(fan_in)), fan_in K.
     weight = mdl.param(
         f"conv_{name}",
@@ -96,8 +111,27 @@ def _short_conv_silu(mdl: nn.Module, x, name: str, heads: int, dim: int):
             rng, (taps, width), jnp.float32, -(taps**-0.5), taps**-0.5
         ),
     )
-    y = causal_depthwise_conv(_dense(width, mdl.dtype, name)(x), weight)
+    return _dense(width, mdl.dtype, name)(x), weight
+
+
+def _short_conv_silu(mdl: nn.Module, x, name: str, heads: int, dim: int):
+    """``silu(conv(W x))`` as ``[B, T, heads, dim]``: a bias-free
+    projection ``name``, its causal depthwise convolution ``conv_<name>``
+    of ``mdl.conv_size`` taps, SiLU."""
+    y = causal_depthwise_conv(*_projection_and_taps(mdl, x, name, heads * dim))
     return jax.nn.silu(y).reshape(*x.shape[:2], heads, dim)
+
+
+class _HeadScale(nn.Module):
+    """The ``scale`` ``[dim]`` of a per-head ``nn.RMSNorm``, under the
+    norm's own name and initialisation, for a caller that applies it
+    itself (the fused route of :class:`KDAMixer`)."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.dim,), jnp.float32)
 
 
 def _a_log_init(heads: int):
@@ -122,6 +156,18 @@ def _dt_bias_init(count: int):
 
 
 class KDAMixer(nn.Module):
+    """Kimi Delta Attention (module docstring).  Two ways to place one
+    mathematics, chosen from what the call shows
+    (:func:`...ops.linear_attention.kda_mixer_route`) and counted once per
+    traced call (``kda/mixer_fused``, ``kda/mixer_plain``): on a TPU, for
+    heads of whole lane blocks, everything between the projections and
+    the chunk-wise core and between the core and the output projection
+    runs as fused passes over the flat ``[B, T, H * head_dim]`` views the
+    projections write and the core's kernels read, and no ``[B, T, H,
+    head_dim]`` array exists in HBM; everywhere else plain ``jax.numpy``
+    on the ``[B, T, H, head_dim]`` view.  The parameters are the same,
+    name for name."""
+
     num_heads: int
     head_dim: int
     d_model: int
@@ -134,23 +180,43 @@ class KDAMixer(nn.Module):
         B, T, _ = x.shape
         H, D = self.num_heads, self.head_dim
         width = H * D
-        mixed = lambda name: _short_conv_silu(self, x, name, H, D)
-        q = l2norm(mixed("query")).astype(self.dtype)
-        k = l2norm(mixed("key")).astype(self.dtype)
-        v = mixed("value")
-
-        # The decay: -exp(A_log) * softplus(low-rank(x) + dt_bias), float32.
+        (xq, wq), (xk, wk), (xv, wv) = (
+            _projection_and_taps(self, x, name, width)
+            for name in ("query", "key", "value")
+        )
         a_log = self.param("A_log", _a_log_init(H))
         dt_bias = self.param("dt_bias", _dt_bias_init(width))
         f = _dense(width, self.dtype, "f_b")(_dense(D, self.dtype, "f_a")(x))
+        beta = jax.nn.sigmoid(_dense(H, self.dtype, "beta")(x).astype(jnp.float32))
+        gate = _dense(width, self.dtype, "g_b")(_dense(D, self.dtype, "g_a")(x))
+
+        fused = (
+            linattn.kda_mixer_route(xq, xk, xv, heads=H, taps=self.conv_size)
+            == "fused"
+        )
+        get_registry().counter(KDA_MIXER_FUSED if fused else KDA_MIXER_PLAIN).inc()
+        if fused:
+            q, k, v, g = linattn.kda_prologue(
+                xq, xk, xv, f, wq, wk, wv, dt_bias, a_log
+            )
+            o = linattn.chunked_kda_flat(q, k, v, g, beta)
+            scale = _HeadScale(D, name="o_norm")()
+            o = linattn.kda_epilogue(o, gate, scale, eps=self.norm_eps)
+            return _dense(self.d_model, self.dtype, "out")(o)
+
+        mixed = lambda y, w: jax.nn.silu(causal_depthwise_conv(y, w)).reshape(
+            B, T, H, D
+        )
+        q = l2norm(mixed(xq, wq)).astype(self.dtype)
+        k = l2norm(mixed(xk, wk)).astype(self.dtype)
+        v = mixed(xv, wv)
+        # The decay: -exp(A_log) * softplus(low-rank(x) + dt_bias), float32.
         g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
             (f.astype(jnp.float32) + dt_bias).reshape(B, T, H, D)
         )
-        beta = jax.nn.sigmoid(_dense(H, self.dtype, "beta")(x).astype(jnp.float32))
 
         o = linattn.chunked_kda(q, k, v, g, beta)
 
-        gate = _dense(width, self.dtype, "g_b")(_dense(D, self.dtype, "g_a")(x))
         o = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="o_norm")(o)
         o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(B, T, H, D))
         return _dense(self.d_model, self.dtype, "out")(

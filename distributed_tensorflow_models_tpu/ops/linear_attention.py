@@ -67,6 +67,16 @@ hold for both, forward and backward.  :func:`chunked_gdn` has the one
 route, :func:`plain_gdn`, and counts its traced calls as
 ``gdn/route_plain``.
 
+The kernels read and write the flat ``[B, T, H * d]`` views
+(:func:`kernel_kda_flat`); :func:`kernel_kda` folds ``[B, T, H, d]``
+arguments into them.  A caller that holds flat views already, the KDA
+mixer on its fused route (:func:`kda_mixer_route`), enters through
+:func:`chunked_kda_flat` and does its own element-wise work between the
+projections and the core and after it as fused passes over the same views
+(:func:`kda_prologue`, :func:`kda_epilogue`; the section comment above
+them): outside the ``kda_core`` scope, so that scope's time and its
+yardstick keep meaning the core.
+
 The backward pass of the plain routes is autodiff through all of it
 except ``T``, whose cotangent is ``-T^T dT T^T`` (no pass through the
 substitution); the kernels' is by hand (the section comment below).
@@ -106,6 +116,16 @@ KDA_CORE_SCOPE = "kda_core"
 # The same of :func:`chunked_gdn` (one decay a head): a scope of its own,
 # because its need per step is another count (``benchmark/flops/``).
 GDN_CORE_SCOPE = "gdn_core"
+
+# The innermost scope of the mixer's fused element-wise passes' kernels
+# (inside ``linear_attn``, outside ``kda_core``).  The kernels'
+# ``custom_vjp`` rules are jitted (one trace and one lowering for a step's
+# many identical calls: traced call by call they were 58 of a warm start's
+# 131 s, PERF.md, PR 33), which makes the rule's name the innermost element
+# of a kernel's ``op_name`` and, with XLA, the name of its instruction; so
+# each ``pallas_call`` is bound under its scope again, and a trace's rows
+# read ``kda_core`` and ``kda_pass`` whatever the rules are called.
+KDA_PASS_SCOPE = "kda_pass"
 
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -347,8 +367,13 @@ def plain_gdn(
 # through in order by a ``fori_loop``); the grid is (batch, token block,
 # head group) with the token blocks in sequence, so every head's state
 # ``S^T`` ``[dv, dk]`` float32 stays in VMEM scratch from block to block.
-# Heads are lane blocks of the free ``[B, T, H * d]`` views: nothing is
-# transposed in HBM.  Per chunk nothing leaves VMEM but the output and,
+# Heads are lane blocks of the flat ``[B, T, H * d]`` views, which is how
+# the kernels' entry takes and returns them (:func:`kernel_kda_flat`):
+# nothing is transposed in HBM inside it, and a caller that holds such
+# views (the mixer's projections write them) has nothing relaid out on
+# either side; :func:`kernel_kda` folds ``[B, T, H, d]`` arguments, which
+# on the chip's ``(8, 128)`` tiles is a relayout and not a bitcast (PERF.md,
+# PR 33).  Per chunk nothing leaves VMEM but the output and,
 # where a backward pass will follow, the state at the chunk's start and
 # ``T``.  The backward kernel sweeps the token blocks in reverse with the
 # state's cotangent in scratch and makes every other intermediate of a
@@ -597,12 +622,13 @@ def _kda_fwd_kernel(
     lax.fori_loop(0, chunks, one_chunk, 0)
 
 
-def _kernel_geometry(q, v, chunk):
-    B, T, H, dk = q.shape
+def _kernel_geometry(q, v, beta, chunk):
+    """From the flat views ``[B, T, H * d]`` and ``beta`` ``[B, T, H]``."""
+    B, T, H = beta.shape
     n = T // chunk
     chunks = next(m for m in _KERNEL_BLOCK_CHUNKS if n % m == 0)
     heads = next(m for m in _KERNEL_HEADS if H % m == 0)
-    return B, T, H, dk, v.shape[-1], n, chunks, heads
+    return B, T, H, q.shape[-1] // H, v.shape[-1] // H, n, chunks, heads
 
 
 def _kernel_specs(chunk, H, dk, dv, chunks, heads, order):
@@ -640,14 +666,14 @@ def _kernel_scratch(H, dk, dv, chunk, heads):
 
 
 def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpret):
-    """``(out [B, T, H, dv], kept)``, ``kept`` the states ``[B, T/chunk,
-    H, dv, dk]`` and every chunk's ``T`` ``[B, T/chunk, H, chunk, chunk]``
-    (float32) or ``()``; ``T`` a multiple of ``chunk``."""
-    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, chunk)
+    """``(out [B, T, H * dv], kept)`` from the flat views, ``kept`` the
+    states ``[B, T/chunk, H, dv, dk]`` and every chunk's ``T`` ``[B,
+    T/chunk, H, chunk, chunk]`` (float32) or ``()``; ``T`` a multiple of
+    ``chunk``."""
+    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, beta, chunk)
     kspec, vspec, bspec, sspec, tspec = _kernel_specs(
         chunk, H, dk, dv, chunks, heads, lambda t: t
     )
-    flat = lambda x: x.reshape(B, T, -1)
     vma = _vma(q)
     out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), v.dtype, vma=vma)]
     out_specs = [vspec]
@@ -655,7 +681,7 @@ def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpr
         out_shape.append(jax.ShapeDtypeStruct((B, n, H, dv, dk), _F32, vma=vma))
         out_shape.append(jax.ShapeDtypeStruct((B, n, H, chunk, chunk), _F32, vma=vma))
         out_specs += [sspec, tspec]
-    res = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(
             _kda_fwd_kernel, scale=scale, chunk=chunk, sub=sub, chunks=chunks,
             heads=heads, keep_states=keep_states,
@@ -670,8 +696,10 @@ def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpr
             vmem_limit_bytes=_KERNEL_VMEM_BYTES,
         ),
         interpret=interpret,
-    )(flat(q), flat(k), flat(v), flat(g), beta)
-    return res[0].reshape(B, T, H, dv), tuple(res[1:])
+    )
+    with jax.named_scope(KDA_CORE_SCOPE):
+        res = call(q, k, v, g, beta)
+    return res[0], tuple(res[1:])
 
 
 def _kda_bwd_kernel(
@@ -802,15 +830,14 @@ def _kda_bwd_kernel(
 
 
 def _kernel_backward(q, k, v, g, beta, states, ts, do, *, scale, chunk, sub, interpret):
-    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, chunk)
+    B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, beta, chunk)
     blocks = n // chunks
     kspec, vspec, bspec, sspec, tspec = _kernel_specs(
         chunk, H, dk, dv, chunks, heads, lambda t: blocks - 1 - t
     )
-    flat = lambda x: x.reshape(B, T, -1)
     vma = _vma(q)
-    like = lambda x: jax.ShapeDtypeStruct(flat(x).shape, x.dtype, vma=vma)
-    dq, dk_, dv_, dg, db = pl.pallas_call(
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+    call = pl.pallas_call(
         functools.partial(
             _kda_bwd_kernel, scale=scale, chunk=chunk, sub=sub, chunks=chunks,
             heads=heads,
@@ -826,8 +853,9 @@ def _kernel_backward(q, k, v, g, beta, states, ts, do, *, scale, chunk, sub, int
             vmem_limit_bytes=_KERNEL_VMEM_BYTES,
         ),
         interpret=interpret,
-    )(flat(q), flat(k), flat(v), flat(g), beta, states, ts, flat(do))
-    return dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape), dg.reshape(g.shape), db
+    )
+    with jax.named_scope(KDA_CORE_SCOPE):
+        return call(q, k, v, g, beta, states, ts, do)
 
 
 def _padded(x, pad):
@@ -835,17 +863,21 @@ def _padded(x, pad):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def kernel_kda(q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False):
+def kernel_kda_flat(q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False):
     """The chunk-wise delta rule as Pallas kernels (section comment above),
-    forward and backward; what :func:`chunked_kda` runs on a TPU for the
-    calls :func:`kernel_admissible` admits.  ``interpret=True`` runs the
-    same kernels on the CPU for tests."""
+    forward and backward, on the flat views the kernels read: ``q``, ``k``,
+    ``g`` ``[B, T, H * dk]``, ``v`` ``[B, T, H * dv]``, ``beta`` ``[B, T,
+    H]``; returns ``[B, T, H * dv]``.  For a caller that holds such views
+    (the fused route of ``models/mixers.py::KDAMixer``): no ``[B, T, H,
+    d]`` array exists on either side.  ``interpret=True`` runs the same
+    kernels on the CPU for tests."""
     return _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, False)[0]
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, keep_states=True):
-    T = q.shape[1]
-    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    T, H = beta.shape[1:]
+    scale = (q.shape[-1] // H) ** -0.5 if scale is None else scale
     pad = -T % chunk
     padded = tuple(_padded(x, pad) for x in (q, k, v, g, beta))
     out, kept = _kernel_forward(
@@ -855,9 +887,10 @@ def _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, keep_states=True):
     return out[:, :T], padded + kept
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _kernel_bwd(scale, chunk, interpret, res, do):
-    T = do.shape[1]
-    scale = res[0].shape[-1] ** -0.5 if scale is None else scale
+    T, H = do.shape[1], res[4].shape[-1]
+    scale = (res[0].shape[-1] // H) ** -0.5 if scale is None else scale
     grads = _kernel_backward(
         *res, _padded(do, -T % chunk), scale=scale, chunk=chunk,
         sub=_KERNEL_SUB, interpret=interpret,
@@ -865,7 +898,18 @@ def _kernel_bwd(scale, chunk, interpret, res, do):
     return tuple(dx[:, :T] for dx in grads)
 
 
-kernel_kda.defvjp(_kernel_fwd, _kernel_bwd)
+kernel_kda_flat.defvjp(_kernel_fwd, _kernel_bwd)
+
+
+def kernel_kda(q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False):
+    """:func:`kernel_kda_flat` for ``[B, T, H, d]`` arguments, what
+    :func:`chunked_kda` runs on a TPU for the calls
+    :func:`kernel_admissible` admits: the heads' channels are folded into
+    the lanes on the way in and out again on the way back."""
+    B, T, H, dv = v.shape
+    flat = lambda x: x.reshape(B, T, -1)
+    out = kernel_kda_flat(flat(q), flat(k), flat(v), flat(g), beta, scale, chunk, interpret)
+    return out.reshape(B, T, H, dv)
 
 
 def kda_route(q, k, v, g, beta, *, chunk: int, sub: int) -> str:
@@ -900,6 +944,533 @@ def chunked_kda(
     if route == "kernel":
         return kernel_kda(q, k, v, g, beta, scale, chunk)
     return plain_kda(q, k, v, g, beta, scale=scale, chunk=chunk, sub=sub)
+
+
+# --- The KDA mixer's element-wise work as fused passes ----------------------
+#
+# Everything between the mixer's projections and the chunk-wise core, and
+# between the core and the output projection, on the flat ``[B, T, H * D]``
+# views the projections write and the kernels above read: a head is a
+# block of ``D`` lanes, a per-head reduction is a lane reduction, and no
+# ``[B, T, H, D]`` array exists in HBM (on the chip's ``(8, 128)`` tiles
+# the reshape between the two views is a relayout, not a bitcast, and XLA
+# moved 5.5 times the bytes the work needs: PERF.md, PR 33).  Three
+# passes, each forward and backward under a ``custom_vjp`` whose residuals
+# are the pass's inputs (the backward makes the activations again in
+# VMEM):
+#
+# - :func:`short_conv_silu`: ``silu(conv(x))``, the causal depthwise
+#   convolution of a few taps, and for queries and keys the l2 norm over
+#   each head's channels;
+# - :func:`kda_decay`: ``g = -exp(A_log) * softplus(f + dt_bias)``;
+# - :func:`kda_epilogue`: ``rmsnorm_head(o) * sigmoid(gate)``.
+#
+# One grid step is ``block`` tokens of a group of heads; the grid is
+# (batch, head group, token block), the token blocks innermost so that the
+# per-lane sums a backward pass owes the small parameters (the taps,
+# ``dt_bias``, ``A_log``, the norm's scale) stay in one resident output
+# block ``[k, 8, lanes]`` per batch row, eight sublanes of partial sums
+# that are added up outside.  Inside a step a ``fori_loop`` walks the
+# rows ``rows`` at a time and, in its body, the group's heads one after
+# the other: a head's chain of some dozen element-wise operations lives
+# in registers and not in VMEM, and the heads' chains are independent, so
+# the scheduler fills one's latencies (the lane reduction, the
+# exponential, the root) with the others (one head a loop step ran 1.7
+# times slower: PERF.md, PR 33).  The
+# convolution's rows of history are the last rows of the block before (a
+# second, 16-row view of the same array: a bfloat16 tile; zeros before the
+# sequence) and then of the chunk before; its transpose takes the
+# cotangent of the rows after, so the backward walks a block's chunks in
+# reverse and makes the first rows of the block after again from a third
+# view.  A shift by ``s`` rows is a sublane rotation of the chunk with
+# its neighbour's eight rows.  Same mathematics, same precision as the
+# plain path of ``models/mixers.py`` or higher: everything is float32 in
+# VMEM (the plain path rounds the convolution and the SiLU to the
+# projections' dtype), the results leave in the dtype the plain path
+# gives them (``q``, ``k``, ``v`` and the gated output in the
+# projections', ``g`` in float32).  A length the block does not divide:
+# the last block overhangs, what a step reads beyond the sequence is
+# masked to zero where it could reach a sum or an earlier row (the
+# backward), and what it writes there is dropped.
+
+_PASS_BLOCK = 512  # tokens a grid step
+_PASS_LANES = 1024  # lanes a grid step (whole heads)
+_PASS_ROWS = 64  # rows of an inner step, a head at a time
+_HALO = 16  # rows of a neighbouring block a step is handed: a bfloat16 tile
+_SUB = 8  # rows of a float32 tile: what a chunk keeps of its neighbour
+
+
+def _pass_geometry(T, W, D, block):
+    """``(block, lanes, rows, token blocks)``: the token block clamped to
+    the length in whole halos, the widest group of whole heads within
+    ``_PASS_LANES`` that divides the width, the rows of an inner step
+    (eight registers an array and head at 128 lanes a head)."""
+    block = min(block, -(-T // _HALO) * _HALO)
+    heads = max(1, min(_PASS_LANES, W) // D)
+    while (W // D) % heads:
+        heads -= 1
+    rows = _PASS_ROWS if block % _PASS_ROWS == 0 and D <= _LANES else _HALO
+    return block, heads * D, rows, pl.cdiv(T, block)
+
+
+def _pass_call(
+    kernel, tiles, per_lane, outs, *, head_dim, block, before=(), after=(),
+    sums=0, interpret=False, **static,
+):
+    """One pass over ``[B, T, W]`` arrays.  The kernel gets, in order: a
+    ``[1, block, lanes]`` tile of each of ``tiles``; the ``_HALO`` rows
+    before the tile of each of ``before`` and after it of each of
+    ``after`` (clamped at the sequence's ends: the kernel knows where it
+    is); the ``[k, lanes]`` columns of each ``[k, W]`` of ``per_lane``;
+    a tile of each output (dtypes ``outs``); and, with ``sums``, the
+    resident ``[1, sums, 8, lanes]`` block of per-lane partial sums
+    ``[B, sums, 8, W]`` float32, the last output."""
+    B, T, W = tiles[0].shape
+    block, lanes, rows, n = _pass_geometry(T, W, head_dim, block)
+    per, last = block // _HALO, pl.cdiv(T, _HALO) - 1
+    tile = pl.BlockSpec((1, block, lanes), lambda b, h, t: (b, t, h))
+    halo = lambda at: pl.BlockSpec((1, _HALO, lanes), lambda b, h, t: (b, at(t), h))
+    lead = halo(lambda t: jnp.maximum(t * per - 1, 0))
+    trail = halo(lambda t: jnp.minimum((t + 1) * per, last))
+    vma = _vma(tiles[0])
+    out_shape = [jax.ShapeDtypeStruct((B, T, W), dt, vma=vma) for dt in outs]
+    out_specs = [tile for _ in outs]
+    if sums:
+        out_shape.append(jax.ShapeDtypeStruct((B, sums, _SUB, W), _F32, vma=vma))
+        out_specs.append(
+            pl.BlockSpec((1, sums, _SUB, lanes), lambda b, h, t: (b, 0, 0, h))
+        )
+    call = pl.pallas_call(
+        functools.partial(kernel, length=T, head_dim=head_dim, rows=rows, **static),
+        grid=(B, W // lanes, n),
+        in_specs=[tile for _ in tiles] + [lead for _ in before] + [trail for _ in after]
+        + [pl.BlockSpec((p.shape[0], lanes), lambda b, h, t: (0, h)) for p in per_lane],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_VMEM_BYTES,
+        ),
+        interpret=interpret,
+    )
+    with jax.named_scope(KDA_PASS_SCOPE):
+        return call(*tiles, *before, *after, *per_lane)
+
+
+def _heads_of(ref, D):
+    """The lane slices of the heads in a tile."""
+    return [slice(a * D, (a + 1) * D) for a in range(ref.shape[-1] // D)]
+
+
+def _per_lane(ref, j, lanes, rows):
+    """Row ``j`` of a ``[k, lanes]`` tile of per-lane numbers, spread over
+    ``rows`` rows."""
+    return jnp.broadcast_to(ref[j:j + 1, lanes].astype(_F32), (rows, lanes.stop - lanes.start))
+
+
+def _live(x, first, length):
+    """``x`` ``[n, D]`` with the rows at positions ``first + row >=
+    length`` (beyond the sequence: whatever the overhanging block read)
+    set to zero."""
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(first + row < length, x, 0.0)
+
+
+def _rows_to_tile(x):
+    """``[n, D]`` -> ``[8, D]``: the sum of its 8-row slabs (register
+    adds; the last eight rows are added up outside)."""
+    return sum(x[i:i + _SUB] for i in range(0, x.shape[0], _SUB))
+
+
+def _rows_at(both, start, n):
+    """Rows ``start .. start + n`` of ``both`` (``n`` whole tiles, ``start``
+    any row): where that is not a tile's edge, a sublane rotation."""
+    if start % _SUB == 0:
+        return both[start:start + n]
+    return pltpu.roll(both, both.shape[0] - start, 0)[:n]
+
+
+def _lead_rows(lead_ref, ln):
+    """The eight rows before a token block, float32: the end of the
+    ``_HALO`` rows it is handed, zeros before the sequence."""
+    rows = lead_ref[0, :, ln].astype(_F32)[_HALO - _SUB:]
+    return jnp.where(pl.program_id(2) > 0, rows, 0.0)
+
+
+def _add_sums(ref, heads, sums):
+    """Adds each head's tiles of partial sums ``[8, D]`` to the resident
+    block ``[1, k, 8, lanes]``, which a batch row's first token block
+    starts from zero."""
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        ref[...] = jnp.zeros(ref.shape, _F32)
+
+    for ln, tiles in zip(heads, sums):
+        for j, tile in enumerate(tiles):
+            ref[0, j, :, ln] += tile
+
+
+def _softplus_and_sigmoid(z):
+    """Both from one exponential (the passes are bound by the
+    transcendental unit before they are by HBM): ``e = exp(-|z|)``,
+    ``softplus = max(z, 0) + log1p(e)``, ``sigmoid = (z >= 0 ? 1 : e) /
+    (1 + e)``."""
+    e = jnp.exp(-jnp.abs(z))
+    return jnp.maximum(z, 0.0) + jnp.log1p(e), jnp.where(z >= 0.0, 1.0, e) / (1.0 + e)
+
+
+def _conv_silu(prev, cur, taps, normalize, eps):
+    """A chunk's forward pass: ``c_t = sum_j w_j x_{t-(K-1)+j}``, ``s =
+    silu(c)`` and, normalized, ``y = s (sum_head s^2 + eps)^-1/2``."""
+    K, n = len(taps), cur.shape[0]
+    both = jnp.concatenate([prev, cur], axis=0)
+    xs = [_rows_at(both, _SUB - (K - 1 - j), n) for j in range(K)]
+    c = sum(w * x for w, x in zip(taps, xs))
+    sig = jax.nn.sigmoid(c)
+    y = c * sig
+    r = None
+    if normalize:
+        r = lax.rsqrt(jnp.sum(y * y, axis=1, keepdims=True) + eps)
+        y = y * r
+    return types.SimpleNamespace(xs=xs, c=c, sig=sig, r=r, y=y)
+
+
+def _conv_silu_cotangent(x, dy):
+    """``dc`` of a chunk from its forward terms ``x`` and ``dy``."""
+    if x.r is not None:
+        dy = x.r * (dy - x.y * jnp.sum(dy * x.y, axis=1, keepdims=True))
+    return dy * (x.sig * (1.0 + x.c * (1.0 - x.sig)))
+
+
+def _conv_fwd_kernel(x_ref, lead_ref, w_ref, y_ref, *, length, head_dim, rows, normalize, eps):
+    del length  # what overhangs is written nowhere and reaches no row before it
+    block, K = x_ref.shape[1], w_ref.shape[0]
+    heads = _heads_of(x_ref, head_dim)
+    taps = [[_per_lane(w_ref, j, ln, rows) for j in range(K)] for ln in heads]
+
+    def chunk(i, prev):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        cur = [x_ref[0, at, ln].astype(_F32) for ln in heads]
+        for a, ln in enumerate(heads):
+            y = _conv_silu(prev[a], cur[a], taps[a], normalize, eps).y
+            y_ref[0, at, ln] = y.astype(y_ref.dtype)
+        return [x[rows - _SUB:] for x in cur]
+
+    lax.fori_loop(0, block // rows, chunk, [_lead_rows(lead_ref, ln) for ln in heads])
+
+
+def _conv_bwd_kernel(
+    x_ref, dy_ref, lead_ref, x_trail_ref, dy_trail_ref, w_ref, dx_ref, dw_ref,
+    *, length, head_dim, rows, normalize, eps,
+):
+    block, K = x_ref.shape[1], w_ref.shape[0]
+    first = pl.program_id(2) * block
+    ragged = length % block != 0
+    live = (lambda x, at: _live(x, first + at, length)) if ragged else (lambda x, at: x)
+    heads = _heads_of(x_ref, head_dim)
+    taps = [[_per_lane(w_ref, j, ln, rows) for j in range(K)] for ln in heads]
+
+    lead, dc_after = [_lead_rows(lead_ref, ln) for ln in heads], []
+    for ln, w in zip(heads, taps):
+        # The cotangent of the first rows of the block after this one
+        # (zero beyond the sequence), made again from its inputs.
+        tail = live(x_ref[0, block - _HALO:, ln].astype(_F32), block - _HALO)[_HALO - _SUB:]
+        x_after = _live(x_trail_ref[0, :, ln].astype(_F32)[:_SUB], first + block, length)
+        dy_after = _live(dy_trail_ref[0, :, ln].astype(_F32)[:_SUB], first + block, length)
+        after = _conv_silu(tail, x_after, [wj[:_SUB] for wj in w], normalize, eps)
+        dc_after.append(_conv_silu_cotangent(after, dy_after))
+
+    def chunk(n, carry):
+        dc_after, sums = carry
+        i = block // rows - 1 - n
+        start = pl.multiple_of(i * rows, rows)
+        at = pl.ds(start, rows)
+        before = pl.ds(pl.multiple_of(jnp.maximum(start - _HALO, 0), _HALO), _HALO)
+        dc_first, new_sums = [], []
+        for a, ln in enumerate(heads):
+            prev = live(x_ref[0, before, ln].astype(_F32), start - _HALO)[_HALO - _SUB:]
+            prev = jnp.where(i > 0, prev, lead[a])
+            x = _conv_silu(
+                prev, live(x_ref[0, at, ln].astype(_F32), start), taps[a], normalize, eps
+            )
+            dc = _conv_silu_cotangent(x, live(dy_ref[0, at, ln].astype(_F32), start))
+            ahead = jnp.concatenate([dc, dc_after[a]], axis=0)
+            dx = sum(
+                w * _rows_at(ahead, K - 1 - j, rows) for j, w in enumerate(taps[a])
+            )
+            dx_ref[0, at, ln] = dx.astype(dx_ref.dtype)
+            new_sums.append([sums[a][j] + _rows_to_tile(dc * x.xs[j]) for j in range(K)])
+            dc_first.append(dc[:_SUB])
+        return dc_first, new_sums
+
+    zero = jnp.zeros((_SUB, head_dim), _F32)
+    _, sums = lax.fori_loop(
+        0, block // rows, chunk, (dc_after, [[zero] * K for _ in heads])
+    )
+    _add_sums(dw_ref, heads, sums)
+
+
+def _decay_fwd_kernel(f_ref, p_ref, g_ref, *, length, head_dim, rows):
+    del length
+    heads = _heads_of(f_ref, head_dim)
+    params = [[_per_lane(p_ref, j, ln, rows) for j in range(2)] for ln in heads]
+
+    def chunk(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for ln, (bias, a) in zip(heads, params):
+            z = f_ref[0, at, ln].astype(_F32) + bias
+            g_ref[0, at, ln] = a * _softplus_and_sigmoid(z)[0]
+        return 0
+
+    lax.fori_loop(0, f_ref.shape[1] // rows, chunk, 0)
+
+
+def _decay_bwd_kernel(f_ref, dg_ref, p_ref, df_ref, dp_ref, *, length, head_dim, rows):
+    t, block = pl.program_id(2), f_ref.shape[1]
+    ragged = length % block != 0
+    heads = _heads_of(f_ref, head_dim)
+    params = [[_per_lane(p_ref, j, ln, rows) for j in range(2)] for ln in heads]
+
+    def chunk(i, sums):
+        start = pl.multiple_of(i * rows, rows)
+        at = pl.ds(start, rows)
+        out = []
+        for h, ln in enumerate(heads):
+            (bias, a), (s_bias, s_a) = params[h], sums[h]
+            z = f_ref[0, at, ln].astype(_F32) + bias
+            dg = dg_ref[0, at, ln]
+            softplus, sigmoid = _softplus_and_sigmoid(z)
+            dz = dg * a * sigmoid
+            df_ref[0, at, ln] = dz.astype(df_ref.dtype)
+            da = dg * softplus
+            if ragged:
+                dz, da = (_live(x, t * block + start, length) for x in (dz, da))
+            out.append((s_bias + _rows_to_tile(dz), s_a + _rows_to_tile(da)))
+        return out
+
+    zero = jnp.zeros((_SUB, head_dim), _F32)
+    sums = lax.fori_loop(0, block // rows, chunk, [(zero, zero)] * len(heads))
+    _add_sums(dp_ref, heads, sums)
+
+
+def _gate_terms(o, gate, scale, eps):
+    """``n = o (mean_head o^2 + eps)^-1/2 scale`` and ``sigmoid(gate)``."""
+    r = lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+    return r, o * (r * scale), jax.nn.sigmoid(gate)
+
+
+def _gate_fwd_kernel(o_ref, gate_ref, s_ref, z_ref, *, length, head_dim, rows, eps):
+    del length
+    heads = _heads_of(o_ref, head_dim)
+    scales = [_per_lane(s_ref, 0, ln, rows) for ln in heads]
+
+    def chunk(i, _):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        for ln, scale in zip(heads, scales):
+            _, n, sg = _gate_terms(
+                o_ref[0, at, ln].astype(_F32), gate_ref[0, at, ln].astype(_F32), scale, eps
+            )
+            z_ref[0, at, ln] = (n * sg).astype(z_ref.dtype)
+        return 0
+
+    lax.fori_loop(0, o_ref.shape[1] // rows, chunk, 0)
+
+
+def _gate_bwd_kernel(
+    o_ref, gate_ref, dz_ref, s_ref, do_ref, dgate_ref, ds_ref,
+    *, length, head_dim, rows, eps,
+):
+    t, block = pl.program_id(2), o_ref.shape[1]
+    ragged = length % block != 0
+    heads = _heads_of(o_ref, head_dim)
+    scales = [_per_lane(s_ref, 0, ln, rows) for ln in heads]
+
+    def chunk(i, sums):
+        start = pl.multiple_of(i * rows, rows)
+        at = pl.ds(start, rows)
+        out = []
+        for h, ln in enumerate(heads):
+            o = o_ref[0, at, ln].astype(_F32)
+            r, n, sg = _gate_terms(o, gate_ref[0, at, ln].astype(_F32), scales[h], eps)
+            dz = dz_ref[0, at, ln].astype(_F32)
+            dn = dz * sg
+            dgate_ref[0, at, ln] = (dn * n * (1.0 - sg)).astype(dgate_ref.dtype)
+            m = dn * scales[h]
+            do = r * (m - o * (r * r) * jnp.mean(m * o, axis=1, keepdims=True))
+            do_ref[0, at, ln] = do.astype(do_ref.dtype)
+            ds = dn * o * r
+            if ragged:
+                ds = _live(ds, t * block + start, length)
+            out.append(sums[h] + _rows_to_tile(ds))
+        return out
+
+    zero = jnp.zeros((_SUB, head_dim), _F32)
+    sums = lax.fori_loop(0, block // rows, chunk, [zero] * len(heads))
+    _add_sums(ds_ref, heads, [[total] for total in sums])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def short_conv_silu(
+    x, w, head_dim, normalize=False, eps=1e-6, block=_PASS_BLOCK, interpret=False
+):
+    """``silu(conv(x))`` in one pass: ``x`` ``[B, T, H * head_dim]``, the
+    causal depthwise convolution's taps ``w`` ``[K, H * head_dim]`` (``c_t
+    = sum_j w_j x_{t-(K-1)+j}``, zeros before the sequence; ``2 <= K <=
+    9``), and with ``normalize`` each head's channels divided by their
+    l2 norm (``eps`` inside the root).  Float32 inside, the result in the
+    dtype of ``x``; the backward is one pass too and makes ``dx`` and
+    ``dw``."""
+    return _conv_fwd(x, w, head_dim, normalize, eps, block, interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _conv_fwd(x, w, head_dim, normalize, eps, block, interpret):
+    (y,) = _pass_call(
+        _conv_fwd_kernel, [x], [w], [x.dtype], head_dim=head_dim, block=block,
+        before=[x], interpret=interpret, normalize=normalize, eps=eps,
+    )
+    return y, (x, w)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _conv_bwd(head_dim, normalize, eps, block, interpret, res, dy):
+    x, w = res
+    dx, dw = _pass_call(
+        _conv_bwd_kernel, [x, dy], [w], [x.dtype], head_dim=head_dim,
+        block=block, before=[x], after=[x, dy], sums=w.shape[0],
+        interpret=interpret, normalize=normalize, eps=eps,
+    )
+    return dx, jnp.sum(dw, axis=(0, 2)).astype(w.dtype)
+
+
+short_conv_silu.defvjp(_conv_fwd, _conv_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _decay(f, p, head_dim, block, interpret):
+    """``p[1] * softplus(f + p[0])`` float32, ``p`` ``[2, W]`` per lane."""
+    return _decay_fwd(f, p, head_dim, block, interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _decay_fwd(f, p, head_dim, block, interpret):
+    (g,) = _pass_call(
+        _decay_fwd_kernel, [f], [p], [_F32], head_dim=head_dim, block=block,
+        interpret=interpret,
+    )
+    return g, (f, p)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _decay_bwd(head_dim, block, interpret, res, dg):
+    f, p = res
+    df, dp = _pass_call(
+        _decay_bwd_kernel, [f, dg], [p], [f.dtype], head_dim=head_dim,
+        block=block, sums=2, interpret=interpret,
+    )
+    return df, jnp.sum(dp, axis=(0, 2))
+
+
+_decay.defvjp(_decay_fwd, _decay_bwd)
+
+
+def kda_decay(f, dt_bias, a_log, *, block=_PASS_BLOCK, interpret=False):
+    """KDA's log decay in one pass: ``g = -exp(A_log) * softplus(f +
+    dt_bias)`` float32 ``[B, T, H * D]`` from ``f`` of that shape (any
+    float dtype), ``dt_bias`` ``[H * D]`` and ``A_log`` ``[H]``."""
+    D = f.shape[-1] // a_log.shape[0]
+    p = jnp.stack([dt_bias.astype(_F32), jnp.repeat(-jnp.exp(a_log.astype(_F32)), D)])
+    return _decay(f, p, D, block, interpret)
+
+
+def kda_prologue(
+    xq, xk, xv, f, conv_q, conv_k, conv_v, dt_bias, a_log, *,
+    eps: float = 1e-6, block: int = _PASS_BLOCK, interpret: bool = False,
+):
+    """What the KDA mixer does between its projections and the chunk-wise
+    core, one pass an array (section comment above): ``q, k =
+    l2norm(silu(conv(.)))`` and ``v = silu(conv(.))`` in the projections'
+    dtype, ``g`` float32, all on the flat ``[B, T, H * D]`` views."""
+    D = xq.shape[-1] // a_log.shape[0]
+    q = short_conv_silu(xq, conv_q, D, True, eps, block, interpret)
+    k = short_conv_silu(xk, conv_k, D, True, eps, block, interpret)
+    v = short_conv_silu(xv, conv_v, D, False, eps, block, interpret)
+    return q, k, v, kda_decay(f, dt_bias, a_log, block=block, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gated_norm(o, gate, scale, head_dim, eps, block, interpret):
+    return _gated_norm_fwd(o, gate, scale, head_dim, eps, block, interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _gated_norm_fwd(o, gate, scale, head_dim, eps, block, interpret):
+    (z,) = _pass_call(
+        _gate_fwd_kernel, [o, gate], [scale], [o.dtype], head_dim=head_dim,
+        block=block, interpret=interpret, eps=eps,
+    )
+    return z, (o, gate, scale)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _gated_norm_bwd(head_dim, eps, block, interpret, res, dz):
+    o, gate, scale = res
+    do, dgate, ds = _pass_call(
+        _gate_bwd_kernel, [o, gate, dz], [scale], [o.dtype, gate.dtype],
+        head_dim=head_dim, block=block, sums=1, interpret=interpret, eps=eps,
+    )
+    return do, dgate, jnp.sum(ds, axis=(0, 2))
+
+
+_gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+def kda_epilogue(
+    o, gate, scale, *, eps: float, block: int = _PASS_BLOCK,
+    interpret: bool = False,
+):
+    """What the KDA mixer does between the core and its output
+    projection, in one pass: ``rmsnorm_head(o) * sigmoid(gate)`` on the
+    flat views, ``scale`` ``[D]`` the norm's (float32 inside, the result
+    in the dtype of ``o``)."""
+    D = scale.shape[0]
+    lanes = jnp.tile(scale.astype(_F32), o.shape[-1] // D)[None]
+    return _gated_norm(o, gate, lanes, D, eps, block, interpret)
+
+
+def kda_mixer_route(xq, xk, xv, *, heads: int, taps: int) -> str:
+    """What ``models/mixers.py::KDAMixer`` runs around its core for these
+    projections ``[B, T, heads * D]``: ``"fused"`` (the passes above and
+    the core's kernels on the flat views) where the core itself takes its
+    kernel route (a TPU, a Mosaic kernel can lower, heads of whole lane
+    blocks, one dtype) and the taps fit the halo; else ``"plain"``."""
+    B, T, W = xq.shape
+    like = lambda d, dtype: jax.ShapeDtypeStruct((B, T, heads, d), dtype)
+    D = W // heads
+    if (
+        xq.shape == xk.shape == xv.shape
+        and xq.dtype == xk.dtype == xv.dtype
+        and W == heads * D
+        and 2 <= taps <= _SUB + 1
+        and kda_route(
+            like(D, xq.dtype), like(D, xk.dtype), like(D, xv.dtype),
+            like(D, _F32), jax.ShapeDtypeStruct((B, T, heads), _F32),
+            chunk=_KERNEL_CHUNK, sub=_KERNEL_SUB,
+        ) == "kernel"
+    ):
+        return "fused"
+    return "plain"
+
+
+@jax.named_scope(KDA_CORE_SCOPE)
+def chunked_kda_flat(q, k, v, g, beta, *, scale: Optional[float] = None, interpret: bool = False):
+    """:func:`chunked_kda` for a caller on the fused route
+    (:func:`kda_mixer_route` said so: the kernels take the call), on the
+    flat views ``[B, T, H * d]`` and ``beta`` ``[B, T, H]``, under the
+    same scope and counted as the same route."""
+    get_registry().counter(KDA_ROUTE_KERNEL).inc()
+    return kernel_kda_flat(q, k, v, g, beta, scale, _KERNEL_CHUNK, interpret)
 
 
 # --- One decay a head and step: the gated delta rule -----------------------

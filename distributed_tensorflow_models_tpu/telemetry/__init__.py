@@ -63,6 +63,8 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     HOOK_WALKS,
     GDN_ROUTE_PLAIN,
     HOST_QUEUE_DEPTH,
+    KDA_MIXER_FUSED,
+    KDA_MIXER_PLAIN,
     KDA_ROUTE_KERNEL,
     KDA_ROUTE_PLAIN,
     PIPELINE_BYTES,

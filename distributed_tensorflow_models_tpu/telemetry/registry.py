@@ -87,6 +87,12 @@ UNEMBED_GRAD_IN_FORWARD = "unembed/grad_in_forward"  # counter
 # tiles, the plain ``jax.numpy`` form everywhere else.
 KDA_ROUTE_KERNEL = "kda/route_kernel"  # counter
 KDA_ROUTE_PLAIN = "kda/route_plain"  # counter
+# How ``models/mixers.py::KDAMixer`` placed its element-wise work, one or
+# the other per traced call: as fused passes over the flat ``[B, T, H *
+# D]`` views around the core's kernels (a TPU, heads of whole lane
+# blocks), or in plain ``jax.numpy`` on the ``[B, T, H, D]`` view.
+KDA_MIXER_FUSED = "kda/mixer_fused"  # counter
+KDA_MIXER_PLAIN = "kda/mixer_plain"  # counter
 # Traced calls of ``ops/linear_attention.py::chunked_gdn`` (one decay a
 # head), which has the plain route alone.
 GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter

@@ -239,20 +239,19 @@ def test_latent_attention_compiles_fused_for_v5e(v5e, monkeypatch, dtype):
     assert not re.search(r"\bwhile\(", text)
 
 
-def test_kda_mixer_takes_the_kernel_route_for_v5e(v5e, monkeypatch):
-    """A ``KDAMixer`` layer at ``kimi_linear_train``'s widths (32 heads of
-    128 over a width of 2304), ``value_and_grad`` under ``jit`` for the
-    described chip: the chunk-wise delta rule is two Mosaic kernels (the
-    forward that keeps the states, and the one backward kernel), both
-    under the ``linear_attn`` and ``kda_core`` scopes the per-layer readers
-    find them by, and nothing of the plain route's scan is left."""
+def _kda_mixer_text(v5e, monkeypatch, dtype=jnp.bfloat16):
+    """The compiled text of a ``KDAMixer`` layer at ``kimi_linear_train``'s
+    widths (32 heads of 128 over a width of 2304), ``value_and_grad``
+    under ``jit`` for the described chip, and its Mosaic kernels' lines."""
     from distributed_tensorflow_models_tpu.models.mixers import KDAMixer
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     one_chip = SingleDeviceSharding(v5e.devices[0])
-    mixer = KDAMixer(num_heads=32, head_dim=128, d_model=2304, name="linear_attn")
-    x = jax.ShapeDtypeStruct((1, 2048, 2304), jnp.bfloat16, sharding=one_chip)
+    mixer = KDAMixer(
+        num_heads=32, head_dim=128, d_model=2304, dtype=dtype, name="linear_attn"
+    )
+    x = jax.ShapeDtypeStruct((1, 2048, 2304), dtype, sharding=one_chip)
     params = jax.tree.map(
         lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
         jax.eval_shape(mixer.init, jax.random.key(0), x),
@@ -266,11 +265,47 @@ def test_kda_mixer_takes_the_kernel_route_for_v5e(v5e, monkeypatch):
         line for line in text.splitlines()
         if "tpu_custom_call" in line and "pallas_call" in line
     ]
-    assert len(kernels) == 2
-    for scope in ("linear_attn", "kda_core"):
-        assert all(re.search(rf"[/(]{scope}[/)]", line) for line in kernels)
-    assert sum("transpose(" in line for line in kernels) == 1
+    return text, kernels
+
+
+def test_kda_mixer_takes_the_kernel_route_for_v5e(v5e, monkeypatch):
+    """The chunk-wise delta rule of a ``KDAMixer`` layer is exactly two
+    Mosaic kernels under ``kda_core`` (the forward that keeps the states,
+    and the one backward kernel, under ``transpose(``), both under the
+    ``linear_attn`` scope too, as the per-layer readers find them, and
+    nothing of the plain route's scan is left."""
+    text, kernels = _kda_mixer_text(v5e, monkeypatch)
+    core = [line for line in kernels if re.search(r"[/(]kda_core[/)]", line)]
+    assert len(core) == 2
+    assert all(re.search(r"[/(]linear_attn[/)]", line) for line in core)
+    assert sum("transpose(" in line for line in core) == 1
     assert not re.search(r"\bwhile\(", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32_highest"])
+def test_kda_mixer_runs_its_elementwise_work_as_fused_passes_for_v5e(v5e, monkeypatch, dtype):
+    """Everything else of the layer between its matrix products (PR 33):
+    five Mosaic kernels forward (a pass each for ``q``, ``k``, ``v`` and
+    ``g``, one for the gated output norm) and five backward, under
+    ``linear_attn`` and **not** under ``kda_core`` (whose reader and
+    yardstick keep meaning the core); and no ``[B, T, 32, 128]`` array
+    anywhere in the compiled layer, so no reshape, copy or broadcast
+    between that view and the flat one the products write.  As the cell
+    runs it (bf16) and as the comparison with the reference runs the
+    float32 program, under ``default_matmul_precision("highest")`` (which
+    PR 31's kernels first met on the chip)."""
+    if dtype == jnp.float32:
+        with jax.default_matmul_precision("highest"):
+            text, kernels = _kda_mixer_text(v5e, monkeypatch, dtype)
+    else:
+        text, kernels = _kda_mixer_text(v5e, monkeypatch, dtype)
+    passes = [line for line in kernels if not re.search(r"[/(]kda_core[/)]", line)]
+    assert len(kernels) == 12 and len(passes) == 10
+    assert all(re.search(r"[/(]linear_attn[/)]", line) for line in passes)
+    assert sum("transpose(" in line for line in passes) == 5
+    assert not re.search(r"\[1,2048,32,128\]", text)
+    for op in ("reshape", "copy", "broadcast"):
+        assert not re.search(rf"f32\[(\d+,)+32,128\]\S* {op}\(", text), op
 
 
 def test_held_expert_layer_compiles_for_v5e(v5e, monkeypatch):

@@ -10,17 +10,36 @@ The plain reference's side of it (logits, loss, every gradient) is
 
 import functools
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_tensorflow_models_tpu.harness.config import get_config
-from distributed_tensorflow_models_tpu.models import get_model
+from distributed_tensorflow_models_tpu.models import get_model, mixers
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.parallel import moe as moelib
 from distributed_tensorflow_models_tpu.telemetry import registry as reglib
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs():
+    """This file compiles some hundreds of programs for the CPU, the
+    interpreted kernels among them, each of many small mapped objects, and
+    jitted functions keep theirs for the life of the process: one worker
+    running the whole file came to the kernel's limit of memory mappings
+    (``vm.max_map_count``, 65,530) and the next compile died of a
+    segmentation fault.  Past half of that, drop what JAX has cached."""
+    yield
+    try:
+        with open("/proc/self/maps") as maps:
+            mapped = sum(1 for _ in maps)
+    except OSError:  # no procfs: nothing to count
+        return
+    if mapped > 30_000:
+        jax.clear_caches()
+
 
 SMALL = {
     **get_config("kimi_linear").model_kwargs,
@@ -279,6 +298,248 @@ def test_the_entry_runs_the_kernels_where_it_would_on_the_chip(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-4 * float(jnp.abs(want).max())
     )
+
+
+# --- the mixer's element-wise work as fused passes (interpreted) -----------
+
+PASS_H, PASS_D, PASS_BLOCK = 2, 128, 64
+PASS_LENGTHS = [
+    64,    # one token block
+    192,   # three: rows of history cross a block's edge, forward and back
+    150,   # a length the block does not divide: the last block overhangs
+    40,    # shorter than a block, and no whole halo
+]
+
+
+def _pass_inputs(T, dtype, B=2):
+    ks = jax.random.split(jax.random.key(T), 13)
+    W = PASS_H * PASS_D
+    wide = lambda k: jax.random.normal(k, (B, T, W), jnp.float32).astype(dtype)
+    taps = lambda k: jax.random.uniform(k, (4, W), jnp.float32, -0.5, 0.5)
+    return {
+        "prologue": (
+            wide(ks[0]), wide(ks[1]), wide(ks[2]), wide(ks[3]), taps(ks[4]), taps(ks[5]),
+            taps(ks[6]), jax.random.normal(ks[7], (W,)),
+            jnp.log(jax.random.uniform(ks[8], (PASS_H,), jnp.float32, 1.0, 16.0)),
+        ),
+        "epilogue": (
+            wide(ks[9]), wide(ks[10]), 1.0 + 0.1 * jax.random.normal(ks[11], (PASS_D,)),
+        ),
+    }
+
+
+def _plain_prologue(xq, xk, xv, f, wq, wk, wv, dt_bias, a_log):
+    """What ``KDAMixer`` runs between its projections and the core on the
+    plain route, with the results folded back to the flat views."""
+    B, T, W = xq.shape
+    heads = lambda x: x.reshape(B, T, PASS_H, PASS_D)
+    mixed = lambda y, w: heads(jax.nn.silu(mixers.causal_depthwise_conv(y, w)))
+    q = mixers.l2norm(mixed(xq, wq)).astype(xq.dtype)
+    k = mixers.l2norm(mixed(xk, wk)).astype(xk.dtype)
+    g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(heads(f.astype(jnp.float32) + dt_bias))
+    return tuple(x.reshape(B, T, W) for x in (q, k, mixed(xv, wv), g))
+
+
+def _plain_epilogue(o, gate, scale):
+    B, T, W = o.shape
+    heads = lambda x: x.reshape(B, T, PASS_H, PASS_D)
+    n = nn.RMSNorm(epsilon=1e-5, dtype=jnp.float32).apply({"params": {"scale": scale}}, heads(o))
+    return (n * jax.nn.sigmoid(heads(gate.astype(jnp.float32)))).astype(o.dtype).reshape(B, T, W)
+
+
+_PASSES = {
+    ("prologue", "fused"): functools.partial(linattn.kda_prologue, block=PASS_BLOCK, interpret=True),
+    ("prologue", "plain"): _plain_prologue,
+    ("epilogue", "fused"): functools.partial(
+        linattn.kda_epilogue, eps=1e-5, block=PASS_BLOCK, interpret=True
+    ),
+    ("epilogue", "plain"): _plain_epilogue,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _pass_result(which, route, T, dtype):
+    """The outputs of a pass and the gradients of a probed sum of them by
+    every argument, as float32."""
+    args = _pass_inputs(T, jnp.float32)[which]
+    # The same numbers in both precisions: draws rounded to bfloat16.
+    rounded = lambda x: x.astype(jnp.bfloat16).astype(dtype) if x.ndim == 3 else x
+    args = tuple(rounded(x) for x in args)
+    fn = _PASSES[which, route]
+
+    def loss(*a):
+        outs = fn(*a)
+        outs = outs if isinstance(outs, tuple) else (outs,)
+        total = sum(
+            jnp.sum(o.astype(jnp.float32) * jax.random.normal(jax.random.key(i), o.shape))
+            for i, o in enumerate(outs)
+        )
+        return total, outs
+
+    (_, outs), grads = jax.value_and_grad(loss, tuple(range(len(args))), has_aux=True)(*args)
+    return tuple(x.astype(jnp.float32) for x in outs + grads)
+
+
+_PASS_NAMES = {
+    "prologue": "q k v g d_xq d_xk d_xv d_f d_conv_query d_conv_key d_conv_value d_dt_bias d_A_log".split(),
+    "epilogue": "out d_o d_gate d_o_norm_scale".split(),
+}
+
+
+@pytest.mark.parametrize("which", ["prologue", "epilogue"])
+@pytest.mark.parametrize("T", PASS_LENGTHS)
+def test_the_fused_passes_in_float32_are_the_plain_functions_to_rounding(T, which):
+    got = _pass_result(which, "fused", T, jnp.float32)
+    want = _pass_result(which, "plain", T, jnp.float32)
+    for name, g, w in zip(_PASS_NAMES[which], got, want, strict=True):
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all()), name
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-3, name
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=1e-5 * scale, rtol=1e-4, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("which", ["prologue", "epilogue"])
+@pytest.mark.parametrize("T", [192, 150])
+def test_the_fused_passes_in_bf16_are_within_the_plain_path_s_own_rounding(T, which):
+    """The passes hold float32 between the bfloat16 they read and the
+    bfloat16 they write, where the plain path rounds the convolution and
+    the SiLU on the way: each result is as near the float32 one as the
+    plain path's, to a rounding of the largest entry, and in the dtype
+    the plain path gives it."""
+    got = _pass_result(which, "fused", T, jnp.bfloat16)
+    plain = _pass_result(which, "plain", T, jnp.bfloat16)
+    exact = _pass_result(which, "plain", T, jnp.float32)
+    for name, g, p, e in zip(_PASS_NAMES[which], got, plain, exact, strict=True):
+        scale = float(jnp.abs(e).max())
+        err, plain_err = float(jnp.abs(g - e).max()), float(jnp.abs(p - e).max())
+        assert err <= plain_err + 2.0**-8 * scale, (name, err, plain_err, scale)
+    fused = _PASSES[which, "fused"](*_pass_inputs(T, jnp.bfloat16)[which])
+    want = _PASSES[which, "plain"](*_pass_inputs(T, jnp.bfloat16)[which])
+    assert jax.tree.map(lambda x: x.dtype, fused) == jax.tree.map(lambda x: x.dtype, want)
+
+
+def test_the_first_positions_of_every_sequence_see_zeros_before_them():
+    """The convolution's history before position 0 is zeros, in every
+    batch row (the rows before a block are the block before it, never the
+    sequence before it) and at a token block's first rows alike; the
+    cotangent after the last position is zero too."""
+    T, W = 150, PASS_H * PASS_D
+    xq, w = _pass_inputs(T, jnp.float32)["prologue"][::4][:2]
+    conv = lambda x: linattn.short_conv_silu(x, w, PASS_D, True, 1e-6, PASS_BLOCK, True)
+    both = conv(xq)
+    for b in range(xq.shape[0]):
+        np.testing.assert_array_equal(np.asarray(both[b]), np.asarray(conv(xq[b:b + 1])[0]))
+    # Position 0 by hand: the last tap alone.
+    s = jax.nn.silu(w[3] * xq[:, 0]).reshape(-1, PASS_H, PASS_D)
+    want = (s * jax.lax.rsqrt(jnp.sum(s * s, -1, keepdims=True) + 1e-6)).reshape(-1, W)
+    np.testing.assert_allclose(np.asarray(both[:, 0]), np.asarray(want), atol=1e-6)
+    # A sequence is the start of a longer one, value and gradient: nothing
+    # after a position reaches it, nothing beyond the end comes back.
+    probe = jax.random.normal(jax.random.key(3), (xq.shape[0], 100, W))
+    grad = lambda x: jax.grad(lambda x: jnp.sum(conv(x)[:, :100] * probe))(x)
+    np.testing.assert_allclose(np.asarray(conv(xq[:, :100])), np.asarray(both[:, :100]), atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(grad(xq[:, :100])), np.asarray(grad(xq)[:, :100]), atol=1e-6
+    )
+    assert not np.asarray(grad(xq)[:, 100:]).any()
+
+
+def _on_the_fused_route(monkeypatch, block=PASS_BLOCK):
+    """``KDAMixer`` as it runs on the chip (the backend described as one
+    TPU), with every kernel interpreted and small token blocks."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    for name, kwargs in (
+        ("kda_prologue", {"block": block, "interpret": True}),
+        ("kda_epilogue", {"block": block, "interpret": True}),
+        ("chunked_kda_flat", {"interpret": True}),
+    ):
+        monkeypatch.setattr(linattn, name, functools.partial(getattr(linattn, name), **kwargs))
+
+
+def _mixer_counts():
+    reg = reglib.get_registry()
+    return tuple(
+        reg.counter(name).value
+        for name in (reglib.KDA_MIXER_FUSED, reglib.KDA_MIXER_PLAIN, reglib.KDA_ROUTE_KERNEL)
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_whole_mixer_on_the_fused_route_is_the_mixer_on_the_plain_route(monkeypatch, dtype):
+    """One ``KDAMixer`` (two heads of 128, a length no block divides),
+    output and the gradient of every parameter and of the input: the
+    fused route with its kernels interpreted against the plain route, on
+    the same parameter tree."""
+    mixer = mixers.KDAMixer(num_heads=2, head_dim=128, d_model=64, dtype=dtype)
+    x = jax.random.normal(jax.random.key(1), (2, 150, 64), dtype)
+    params = mixer.init(jax.random.key(0), x)
+    params = jax.tree.map(
+        lambda p: p + 0.05 * jax.random.normal(jax.random.key(p.size), p.shape), params
+    )
+    probe = jax.random.normal(jax.random.key(2), (2, 150, 64))
+
+    def loss(p, x):
+        out = mixer.apply(p, x)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    both = lambda: jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+    with jax.default_matmul_precision("highest"):
+        counts = _mixer_counts()
+        (_, want_out), want = both()
+        assert _mixer_counts() == (counts[0], counts[1] + 1, counts[2])
+        _on_the_fused_route(monkeypatch)
+        (_, got_out), got = both()
+        assert _mixer_counts() == (counts[0] + 1, counts[1] + 1, counts[2] + 1)
+        assert jax.tree.structure(mixer.init(jax.random.key(0), x)) == jax.tree.structure(params)
+    # bf16: the routes round at different places (a rounding of the
+    # largest entry, 2^-8, a few times over); f32: the order of sums.
+    tol = 1e-4 if dtype == jnp.float32 else 0.04
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, g), (_, w) in zip(flat((got_out, got)), flat((want_out, want)), strict=True):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all())
+        assert float(jnp.abs(g - w).max()) <= tol * float(jnp.abs(w).max()), jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize(
+    "backend, devices, head_dim, taps, want",
+    [
+        ("cpu", 1, 128, 4, "plain"),
+        ("tpu", 1, 128, 4, "fused"),
+        ("tpu", 1, 256, 2, "fused"),
+        ("tpu", 1, 16, 4, "plain"),    # no whole lane block a head
+        ("tpu", 1, 128, 12, "plain"),  # more history than a chunk keeps
+        ("tpu", 4, 128, 4, "plain"),   # the core would not take its kernels
+    ],
+)
+def test_which_placement_the_mixer_takes_and_that_it_counts_it_once(
+    monkeypatch, backend, devices, head_dim, taps, want
+):
+    """``KDAMixer`` chooses from the backend and what the call shows at
+    trace time, and counts ``kda/mixer_fused`` or ``kda/mixer_plain`` once
+    per traced call (traced, not run: a Mosaic kernel cannot run here);
+    the core's own route is counted beside it as before."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    mixer = mixers.KDAMixer(num_heads=2, head_dim=head_dim, d_model=64, conv_size=taps)
+    x = jax.ShapeDtypeStruct((2, 200, 64), jnp.bfloat16)
+    wide = jax.ShapeDtypeStruct((2, 200, 2 * head_dim), jnp.bfloat16)
+    assert linattn.kda_mixer_route(wide, wide, wide, heads=2, taps=taps) == want
+    narrow = jax.ShapeDtypeStruct(wide.shape, jnp.float32)
+    assert linattn.kda_mixer_route(wide, wide, narrow, heads=2, taps=taps) == "plain"
+    fused0, plain0, kernel0 = _mixer_counts()
+    params = jax.eval_shape(mixer.init, jax.random.key(0), x)
+    out = jax.eval_shape(mixer.apply, params, x)
+    assert out.shape == (2, 200, 64) and out.dtype == jnp.bfloat16
+    fused1, plain1, kernel1 = _mixer_counts()
+    calls = (2, 0) if want == "fused" else (0, 2)
+    assert (fused1 - fused0, plain1 - plain0) == calls
+    # Too many taps leave the core on its kernels and the mixer plain.
+    core_on_kernels = (backend, devices, head_dim % 128) == ("tpu", 1, 0)
+    assert kernel1 - kernel0 == (2 if core_on_kernels else 0)
 
 
 # --- latent attention's shapes through the attention routes --------------
@@ -747,8 +1008,11 @@ def test_fit_trains_the_kimi_linear_program_config_and_reports_the_held_share(tm
     telemetry = json.load(open(tmp_path / "telemetry.json"))["metrics"]
     # One MLA layer's call, counted once per traced program (blockwise on the CPU).
     assert telemetry["attention/route_blockwise"] >= 1 and telemetry.get("attention/route_fused", 0) == 0
-    # Four KDA layers' calls likewise (the plain route on the CPU).
+    # Four KDA layers' calls likewise (the plain route on the CPU), each
+    # mixer's placement beside its core's route.
     assert telemetry["kda/route_plain"] >= 4 and telemetry["kda/route_kernel"] == 0
+    assert telemetry["kda/mixer_plain"] == telemetry["kda/route_plain"]
+    assert telemetry["kda/mixer_fused"] == 0
 
 
 def test_kimi_linear_warms_up_and_the_other_language_models_do_not():

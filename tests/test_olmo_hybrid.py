@@ -210,6 +210,94 @@ def test_two_halves_of_the_delta_rule_mixer_add_up_to_the_uncut_layer():
     )
 
 
+class _ParentGatedDeltaNetMixer(mixers.nn.Module):
+    """``GatedDeltaNetMixer`` as PR 32 committed it (``f685f19``), with the
+    helper ``_short_conv_silu`` it shared with ``KDAMixer`` written out:
+    PR 33 split that helper for ``KDAMixer``'s fused route, and this
+    mixer has to run what it ran."""
+
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    d_model: int
+    conv_size: int = 4
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+
+    def _short_conv_silu(self, x, name, heads, dim):
+        nn = mixers.nn
+        width, taps = heads * dim, self.conv_size
+        weight = self.param(
+            f"conv_{name}",
+            lambda rng: jax.random.uniform(
+                rng, (taps, width), jnp.float32, -(taps**-0.5), taps**-0.5
+            ),
+        )
+        y = nn.Dense(width, dtype=self.dtype, use_bias=False, name=name)(x)
+        y = mixers.causal_depthwise_conv(y, weight)
+        return jax.nn.silu(y).reshape(*x.shape[:2], heads, dim)
+
+    @mixers.nn.compact
+    def __call__(self, x):
+        nn = mixers.nn
+        B, T, _ = x.shape
+        H, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        dense = lambda features, name: nn.Dense(
+            features, dtype=self.dtype, use_bias=False, name=name
+        )
+        q = mixers.l2norm(self._short_conv_silu(x, "query", H, dk)).astype(self.dtype)
+        k = mixers.l2norm(self._short_conv_silu(x, "key", H, dk)).astype(self.dtype)
+        v = self._short_conv_silu(x, "value", H, dv)
+        a_log = self.param("A_log", mixers._a_log_init(H))
+        dt_bias = self.param("dt_bias", mixers._dt_bias_init(H))
+        per_head = lambda name: dense(H, name)(x).astype(jnp.float32)
+        g = -jnp.exp(a_log) * jax.nn.softplus(per_head("a") + dt_bias)
+        beta = 2.0 * jax.nn.sigmoid(per_head("beta"))
+        o = linattn.chunked_gdn(q, k, v, g, beta)
+        gate = dense(H * dv, "gate")(x)
+        o = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="o_norm")(o)
+        o = o * jax.nn.silu(gate.astype(jnp.float32).reshape(B, T, H, dv))
+        return dense(self.d_model, "out")(o.astype(self.dtype).reshape(B, T, H * dv))
+
+
+def test_the_delta_rule_mixer_runs_what_it_ran_before_kimi_s_mixer_was_fused(monkeypatch):
+    """PR 33 gave ``KDAMixer`` a fused route and split the helper the two
+    mixers share.  ``GatedDeltaNetMixer``'s heads are 96 and 192 channels,
+    no lane blocks, and it stays on the plain code: at Olmo-Hybrid's
+    widths, described as on the chip, its jaxpr (forward and gradient) is
+    the parent's to the letter, and at a small size its output and every
+    gradient are the parent's bit for bit."""
+    # The cell's: 15 of the published 30 heads held.
+    widths = {"num_heads": 15, "key_dim": 96, "value_dim": 192, "d_model": 3840}
+    assert (FULL["gdn_num_heads"], FULL["gdn_key_dim"], FULL["gdn_value_dim"], FULL["d_model"]) == (30, 96, 192, 3840)
+
+    def traced(cls, x, **kwargs):
+        mixer = cls(**kwargs)
+        params = jax.eval_shape(mixer.init, jax.random.key(0), x)
+        loss = lambda p, x: jnp.sum(mixer.apply(p, x).astype(jnp.float32))
+        return params, str(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(params, x))
+
+    x = jax.ShapeDtypeStruct((1, 256, 3840), jnp.bfloat16)
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        on_the_chip.setattr(jax, "device_count", lambda: 1)
+        params, now = traced(mixers.GatedDeltaNetMixer, x, **widths)
+        parent_params, parent = traced(_ParentGatedDeltaNetMixer, x, **widths)
+    assert jax.tree.structure(params) == jax.tree.structure(parent_params)
+    assert now == parent and "pallas_call" not in now
+
+    small = {"num_heads": 3, "key_dim": DK, "value_dim": DV, "d_model": D, "dtype": jnp.float32}
+    x = jax.random.normal(jax.random.key(1), (2, 70, D))
+    params = mixers.GatedDeltaNetMixer(**small).init(jax.random.key(0), x)
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+    both = lambda cls: jax.value_and_grad(
+        lambda p, x: jnp.sum(cls(**small).apply(p, x) * probe), (0, 1)
+    )(params, x)
+    got, want = both(mixers.GatedDeltaNetMixer), both(_ParentGatedDeltaNetMixer)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 DH = 16
 
 
