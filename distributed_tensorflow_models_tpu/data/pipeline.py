@@ -43,8 +43,8 @@ Telemetry: all stages record into an injectable
 :class:`...telemetry.MetricsRegistry` (default: the process-global one) —
 ``pipeline/host_queue_depth`` + ``pipeline/producer_wait`` from the host
 producer, ``pipeline/worker_busy/<i>`` per-worker utilization +
-``pipeline/reassembly_wait`` from the pool, ``pipeline/prefetch_fill`` +
-``pipeline/prefetch_depth`` from the device stage.  Those are the stages'
+``pipeline/reassembly_wait`` from the pool, ``pipeline/prefetch_fill``
+from the device stage.  Those are the stages'
 *waits*; their two pieces of *work* are timed once per batch:
 ``pipeline/assemble`` (the dataset producing a batch, in the serial
 producer or in whichever pool worker ran it) and ``pipeline/shard`` (the
@@ -657,7 +657,6 @@ class DevicePrefetcher:
             self._buf.append(
                 (placed, state, batch if self._release is not None else None)
             )
-            reg.gauge(telemetry.PREFETCH_DEPTH).set(len(self._buf))
 
     def __iter__(self) -> Iterator[PyTree]:
         return self

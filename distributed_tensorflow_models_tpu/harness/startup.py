@@ -24,16 +24,24 @@ without touching training semantics:
   jit call otherwise — a wrong guess costs a wasted background compile,
   never a wrong program.
 
-Telemetry: the thread stamps ``startup/aot_compile_s`` (full
-``lower().compile()`` duration — mostly hidden behind the restore) and
-``startup/aot_lower_s`` (its tracing-and-lowering part); only the *non-overlapped
-remainder* the first step actually blocked on lands in the
-``train/compile`` timer (the first AOT use is accounted as the run's
-compile event, mirroring how a persistent-cache hit still records a
-compile event today).  ``fit`` stamps ``startup/restore_s`` and
-``startup/time_to_first_step_s`` around this module; the goodput report
-surfaces all three as its ``startup`` section and ``launch.py`` reads
-the fleet-side equivalent off the heartbeat files.
+Telemetry: the start-up timeline (:class:`Timeline`, README
+"Observability").  ``fit`` cuts its main thread's time from the
+process's start to the end of the first loop iteration into exclusive
+phases on ``perf_counter``, each a ``startup/<phase>_s`` gauge and a
+``startup/<phase>`` span in the run's event ring, through the one
+helper :func:`stamp`; the AOT thread stamps ``startup/aot_compile_s``
+(the full ``lower().compile()`` duration, mostly hidden behind the
+restore) and ``startup/aot_lower_s`` (its tracing-and-lowering part)
+the same way, from its own thread, so the export shows them
+overlapping the phases.  ``startup/aot_join_s`` is the *non-overlapped
+remainder* the first step actually blocked on; the same wait, plus the
+first dispatch, is the first record of the ``train/compile`` timer (the
+first AOT use is accounted as the run's compile event, mirroring how a
+persistent-cache hit still records a compile event today).
+``startup/compile_requests`` and ``startup/cache_hits`` say whether the
+start was warm.  The goodput report surfaces all of it as its
+``startup`` section and ``launch.py`` reads the fleet-side equivalent
+off the heartbeat files.
 """
 
 from __future__ import annotations
@@ -98,6 +106,7 @@ def apply_compile_cache(xla_cache_dir: Optional[str] = None) -> Optional[str]:
     """
     import jax
 
+    _count_cache_events()
     if xla_cache_dir == "":
         jax.config.update("jax_compilation_cache_dir", None)
         log.info("persistent XLA compilation cache disabled")
@@ -135,6 +144,46 @@ def apply_compile_cache(xla_cache_dir: Optional[str] = None) -> Optional[str]:
     return path
 
 
+# jax's monitoring events for the persistent cache: a request is every
+# compile that consulted it, a hit every one it answered.
+_COMPILE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_cache_events_lock = threading.Lock()
+_cache_events_counted = False
+
+
+def _count_cache_events() -> None:
+    """Install, once a process, the listener that counts the persistent
+    cache's requests and hits into the process-global registry
+    (``startup/compile_requests``, ``startup/cache_hits``).  A
+    :class:`Timeline` copies what its own start-up raised, as ``fit``
+    does for the ops' trace-time counters, so a second ``fit`` in the
+    process neither listens twice nor counts the first one's."""
+    global _cache_events_counted
+    with _cache_events_lock:
+        if _cache_events_counted:
+            return
+        _cache_events_counted = True
+    import jax.monitoring
+
+    shared = telemetry.get_registry()
+    by_event = {
+        _COMPILE_REQUEST_EVENT: shared.counter(
+            telemetry.STARTUP_COMPILE_REQUESTS
+        ),
+        _CACHE_HIT_EVENT: shared.counter(telemetry.STARTUP_CACHE_HITS),
+    }
+
+    def on_event(event: str, **_) -> None:
+        counter = by_event.get(event)
+        if counter is not None:
+            # The main thread and the AOT thread both compile.
+            with _cache_events_lock:
+                counter.inc()
+
+    jax.monitoring.register_event_listener(on_event)
+
+
 def cache_entry_count(cache_dir: Optional[str]) -> int:
     """Number of files under the cache dir (0 when unset/missing) — the
     before/after delta is the cache-hit signal for the first compile."""
@@ -144,6 +193,133 @@ def cache_entry_count(cache_dir: Optional[str]) -> int:
     for _, _, files in os.walk(cache_dir):
         total += len(files)
     return total
+
+
+# --------------------------------------------------------------------------
+# The start-up timeline
+# --------------------------------------------------------------------------
+
+_T_IMPORT = time.perf_counter()
+
+
+def seconds_since_process_start() -> float:
+    """From the kernel's record of when this process started (Linux:
+    ``/proc/self/stat`` field 22 against ``/proc/uptime``, to the
+    kernel's clock tick); from this module's import where ``/proc`` is
+    not there."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return time.perf_counter() - _T_IMPORT
+
+
+def stamp(
+    registry: telemetry.MetricsRegistry,
+    key: str,
+    t0: float,
+    t1: Optional[float] = None,
+    *,
+    args: Optional[dict] = None,
+    ts_wall: Optional[float] = None,
+) -> float:
+    """The one way a start-up duration is recorded: the ``perf_counter``
+    interval ``t0`` to ``t1`` (default: now) becomes the gauge ``key``
+    (``startup/<name>_s``) and the complete event ``startup/<name>`` in
+    the registry's event ring, from the calling thread.  Returns
+    ``t1``, where the next phase starts."""
+    if t1 is None:
+        t1 = time.perf_counter()
+    registry.gauge(key).set(t1 - t0)
+    registry.trace.complete(
+        key[: -len("_s")], t1 - t0, ts_mono=t0, ts_wall=ts_wall, args=args
+    )
+    return t1
+
+
+class Timeline:
+    """``fit``'s main thread from the process's start to its first loss
+    row, as the ``startup/*`` gauges and spans (``telemetry/registry.py``
+    says what each holds).
+
+    Built at ``fit`` entry: creates every start-up gauge and counter, so
+    that each is in ``telemetry.json`` with an explicit zero where
+    nothing happened, and stamps ``startup/process_to_fit_s``.  Each
+    :meth:`mark` then closes the phase that began where the last one
+    ended, so the phases are exclusive and contiguous by construction
+    and a new one cannot overlap its neighbours; they are cut at
+    statement boundaries because they span ``fit``'s three guarded
+    blocks, which no ``with`` could.  No call waits for the device.
+    """
+
+    def __init__(self, registry: telemetry.MetricsRegistry, t_fit: float):
+        self._registry = registry
+        self.t_fit = t_fit
+        self._t = t_fit
+        # One wall-clock origin for the main thread's spans, so that
+        # they tile in the export as they do on perf_counter.
+        self._wall0 = time.time() - time.perf_counter()
+        for key in telemetry.STARTUP_GAUGES:
+            registry.gauge(key)
+        for key in telemetry.STARTUP_COUNTERS:
+            registry.counter(key)
+        self._cache0 = self._cache_counts()
+        before_fit = seconds_since_process_start() - (
+            time.perf_counter() - t_fit
+        )
+        self._stamp(
+            telemetry.STARTUP_PROCESS_TO_FIT, t_fit - before_fit, t_fit
+        )
+
+    @staticmethod
+    def _cache_counts() -> tuple[float, ...]:
+        shared = telemetry.get_registry()
+        return tuple(
+            shared.counter(key).value for key in telemetry.STARTUP_COUNTERS
+        )
+
+    def _stamp(self, key: str, t0: float, t1: Optional[float] = None):
+        return stamp(
+            self._registry, key, t0, t1, ts_wall=self._wall0 + t0
+        )
+
+    def mark(self, key: str) -> None:
+        """End the phase ``key`` here; the next begins."""
+        self._t = self._stamp(key, self._t)
+
+    def first_chunk_done(self) -> None:
+        """The end of the first loop iteration: closes
+        ``startup/first_chunk_s``, stamps ``startup/time_to_first_step_s``
+        (``fit`` entry to here), what the phases leave of it, the
+        iteration's wait for its first batch and what the persistent
+        cache was asked and answered since ``fit`` entry."""
+        reg = self._registry
+        self.mark(telemetry.STARTUP_FIRST_CHUNK)
+        reg.gauge(telemetry.STARTUP_FIRST_STEP).set(
+            time.perf_counter() - self.t_fit
+        )
+        reg.gauge(telemetry.STARTUP_FIRST_DATA_WAIT).set(
+            reg.timer(telemetry.DATA_WAIT).total
+        )
+        reg.gauge(telemetry.STARTUP_UNATTRIBUTED).set(
+            reg.gauge(telemetry.STARTUP_FIRST_STEP).value
+            - sum(reg.gauge(k).value for k in telemetry.STARTUP_PHASES)
+        )
+        for key, now, then in zip(
+            telemetry.STARTUP_COUNTERS, self._cache_counts(), self._cache0
+        ):
+            reg.counter(key).inc(now - then)
+
+    def first_loss_row(self) -> None:
+        """The end of the first hook walk that fetched a loss row: a
+        gauge and an instant (as a span it would lie over every phase)."""
+        self._registry.gauge(telemetry.STARTUP_FIRST_LOSS_ROW).set(
+            time.perf_counter() - self.t_fit
+        )
+        self._registry.trace.instant("startup/first_loss_row")
 
 
 # --------------------------------------------------------------------------
@@ -323,10 +499,8 @@ class AotTrainStep:
             # Tracing and lowering apart from the compile (or cache
             # read) that follows: the part of a warm start no cache
             # shortens.
-            dt_lower = time.perf_counter() - t0
-            self._registry.gauge(telemetry.STARTUP_AOT_LOWER).set(dt_lower)
-            self._registry.trace.complete(
-                "startup/aot_lower", dt_lower, ts_mono=t0,
+            stamp(
+                self._registry, telemetry.STARTUP_AOT_LOWER, t0,
                 args={"label": self._label},
             )
             self._exe = lowered.compile()
@@ -342,9 +516,8 @@ class AotTrainStep:
             # compile overlapping the main thread's restore span — the
             # overlap is the whole point of the design, and the trace is
             # where it's visible.
-            self._registry.gauge(telemetry.STARTUP_AOT_COMPILE).set(dt)
-            self._registry.trace.complete(
-                "startup/aot_compile", dt, ts_mono=t0,
+            stamp(
+                self._registry, telemetry.STARTUP_AOT_COMPILE, t0, t0 + dt,
                 args={"label": self._label, "ok": self._error is None},
             )
         new_entries = cache_entry_count(self._cache_dir) - entries_before
@@ -373,8 +546,8 @@ class AotTrainStep:
             # span so the timeline shows hidden vs. paid cold-start cost.
             t0 = time.perf_counter()
             self._thread.join()
-            self._registry.trace.complete(
-                "startup/aot_join", time.perf_counter() - t0, ts_mono=t0,
+            stamp(
+                self._registry, telemetry.STARTUP_AOT_JOIN, t0,
                 args={"label": self._label},
             )
         if self._error is not None:

@@ -462,6 +462,10 @@ def fit(
         enabled=int(cfg.trace_ring_events or 0) > 0,
     )
     registry.trace = tracer
+    # The start-up timeline (harness/startup.py, README "Observability"):
+    # from here to the first loss row every ``startup.mark`` closes one
+    # exclusive phase of this thread's time.
+    startup = startuplib.Timeline(registry, t_run0)
     # Read by the flight-dump closure below at CALL time (a closure over
     # fit's local): dumps fired before the loop report the sentinel.
     step = -1
@@ -499,6 +503,7 @@ def fit(
     if mesh is None:
         mesh = mesh_from_config(cfg)
     state = build_state(cfg, mesh)
+    startup.mark(telemetry.STARTUP_BUILD_STATE)
     manager = ckptlib.CheckpointManager(
         workdir,
         keep=cfg.keep_checkpoints,
@@ -554,7 +559,7 @@ def fit(
         )
 
         resilience.heartbeat.set_phase("restore")
-        t_restore0 = time.perf_counter()
+        startup.mark(telemetry.STARTUP_BUILD_STEP)
         state, data_state, restored = ckptlib.restore_or_init(manager, state)
         if restored:
             state = _place(state)
@@ -565,12 +570,10 @@ def fit(
             # resize facts on this host's timeline.
             tracer.instant("fit/resize_restore", dict(manager.last_resize))
             _dump_flight("resize_restore")
-        # Startup restore wall (incl. the re-placement): one of the two
+        # Startup restore wall (incl. the re-placement): one of the
         # restart-MTTR terms the goodput report's "startup" section
         # carries.
-        registry.gauge(telemetry.STARTUP_RESTORE).set(
-            time.perf_counter() - t_restore0
-        )
+        startup.mark(telemetry.STARTUP_RESTORE)
         tracer.instant(
             "fit/restore_done",
             {"restored": restored, "step": int(state.step)},
@@ -587,6 +590,7 @@ def fit(
             dataset.set_state(data_state["dataset"])
         if chaos is not None:
             dataset = chaos.wrap_dataset(dataset)
+        startup.mark(telemetry.STARTUP_DATASET)
     except BaseException:
         _close_quietly(None, manager, aot)
         _dump_flight("setup_failure")
@@ -1012,6 +1016,7 @@ def fit(
         # supervisor and peers see "looping, at step N" before the first
         # chunk — which may take a full XLA compile — completes.
         resilience.heartbeat.beat(step)
+        startup.mark(telemetry.STARTUP_PIPELINE_OPEN)
         while step < cfg.train_steps:
             if _preempt_due(step):
                 log.warning(
@@ -1171,15 +1176,21 @@ def fit(
                     args={"start": start, "k": k},
                 )
             if steps_run and registry.gauge(
-                telemetry.STARTUP_FIRST_STEP
+                telemetry.STARTUP_FIRST_LOSS_ROW
             ).value == 0.0:
-                # Relaunch-to-first-step MTTR, the number the cold-start
-                # work (compile cache + AOT-overlapped restore) exists
-                # to shrink: fit entry → first completed chunk.
-                registry.gauge(telemetry.STARTUP_FIRST_STEP).set(
-                    time.perf_counter() - t_run0
-                )
-                resilience.heartbeat.set_phase("train")
+                # Start-up lasts until the first loss row; this test is
+                # the loop's one check for it.
+                if registry.gauge(telemetry.STARTUP_FIRST_STEP).value == 0.0:
+                    # Relaunch-to-first-step MTTR, the number the
+                    # cold-start work (compile cache + AOT-overlapped
+                    # restore) exists to shrink: fit entry → first
+                    # completed chunk.
+                    startup.first_chunk_done()
+                    resilience.heartbeat.set_phase("train")
+                if cfg.log_every_steps and step % cfg.log_every_steps == 0:
+                    # The walk above ran the log-cadence hooks (chunks
+                    # end at their steps), which fetched the loss.
+                    startup.first_loss_row()
             if watchdog is not None:
                 watchdog.beat(step)
             resilience.heartbeat.beat(step)

@@ -68,7 +68,6 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     KDA_ROUTE_KERNEL,
     KDA_ROUTE_PLAIN,
     PIPELINE_BYTES,
-    PREFETCH_DEPTH,
     PREFETCH_FILL,
     PRODUCER_WAIT,
     REASSEMBLY_WAIT,
@@ -77,9 +76,24 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     SHARD,
     SKIPPED_BATCHES,
     STARTUP_AOT_COMPILE,
+    STARTUP_AOT_JOIN,
     STARTUP_AOT_LOWER,
+    STARTUP_BUILD_STATE,
+    STARTUP_BUILD_STEP,
+    STARTUP_CACHE_HITS,
+    STARTUP_COMPILE_REQUESTS,
+    STARTUP_COUNTERS,
+    STARTUP_DATASET,
+    STARTUP_FIRST_CHUNK,
+    STARTUP_FIRST_DATA_WAIT,
+    STARTUP_FIRST_LOSS_ROW,
     STARTUP_FIRST_STEP,
+    STARTUP_GAUGES,
+    STARTUP_PHASES,
+    STARTUP_PIPELINE_OPEN,
+    STARTUP_PROCESS_TO_FIT,
     STARTUP_RESTORE,
+    STARTUP_UNATTRIBUTED,
     STEP_TIME,
     TRACE_DROPPED,
     TRACE_EVENTS,

@@ -11,13 +11,15 @@ construction:
     checkpoint  = checkpoint/{save,restore,wait,fence}
     compile     = train/compile          (explicit XLA compile events)
 
-The report also carries a ``startup`` section — the restart-MTTR
-numbers (``startup/restore_s``, ``startup/aot_compile_s``,
-``startup/time_to_first_step_s`` gauges from ``harness/startup.py`` and
-``fit``).  They are *overlapped* wall readings (the AOT compile runs
-concurrently with the restore), so they are reported alongside — never
-added into — the four exclusive fractions above, which still sum to
-exactly 1.0.
+The report also carries a ``startup`` section: the start-up timeline
+(``registry.STARTUP_*``; ``harness/startup.py::Timeline``, stamped by
+``fit``).  ``process_to_fit_s`` and then the exclusive phases, which
+add up to ``time_to_first_step_s`` but for ``unattributed_s``; the AOT
+thread's ``aot_lower_s`` and ``aot_compile_s``, which overlap them;
+``first_loss_row_s``; and the persistent cache's ``compile_requests``
+and ``cache_hits`` up to the first chunk.  All of it is reported
+alongside — never added into — the four exclusive fractions above,
+which still sum to exactly 1.0.
 
 MFU is wall-clock-inclusive (FLOPs retired per second of *total* time over
 peak), i.e. it already prices in every stall — the honest end-to-end
@@ -155,14 +157,12 @@ def goodput_report(
             "compile": compile_s / total_s,
         },
         "compile_events": int(snap.get(f"{reglib.COMPILE}/count", 0.0)),
-        # Restart-MTTR section (overlapped wall readings — reported
-        # beside the exclusive four-way split, never summed into it).
+        # The start-up timeline, reported beside the exclusive four-way
+        # split, never summed into it: every start-up gauge and counter
+        # under its name less the prefix.
         "startup": {
-            "restore_s": snap.get(reglib.STARTUP_RESTORE, 0.0),
-            "aot_compile_s": snap.get(reglib.STARTUP_AOT_COMPILE, 0.0),
-            "time_to_first_step_s": snap.get(
-                reglib.STARTUP_FIRST_STEP, 0.0
-            ),
+            key.split("/", 1)[1]: snap.get(key, 0.0)
+            for key in (*reglib.STARTUP_GAUGES, *reglib.STARTUP_COUNTERS)
         },
         "flops_per_step": flops_per_step,
         "flops_total": flops_total,

@@ -51,7 +51,6 @@ FLOPS_TOTAL = "train/flops_total"  # counter: FLOPs retired across all steps
 HOST_QUEUE_DEPTH = "pipeline/host_queue_depth"  # gauge
 PRODUCER_WAIT = "pipeline/producer_wait"  # timer: producer blocked on full buffer
 PREFETCH_FILL = "pipeline/prefetch_fill"  # timer: DevicePrefetcher upstream fetch
-PREFETCH_DEPTH = "pipeline/prefetch_depth"  # gauge
 # The input path's two pieces of WORK (the timers above are its waits):
 # ASSEMBLE is the dataset's own production of one batch — ``next()`` in
 # the serial producer, ``assemble(work)`` in each pool worker, so with N
@@ -126,18 +125,88 @@ CKPT_FENCE = "checkpoint/fence"  # timer
 # fleet is a red flag.
 CKPT_SIDECAR_FALLBACKS = "checkpoint/sidecar_fallbacks"  # counter
 CKPT_RESIZE_RESTORES = "checkpoint/resize_restores"  # counter
-# Cold-start / restart-MTTR gauges (harness/startup.py + fit): wall time
-# of the startup restore walk, the background AOT train-step compile
-# (overlapped with the restore — only the non-overlapped remainder lands
-# in train/compile), and process-entry→first-completed-step.  The
-# goodput report surfaces them as its "startup" section and the
-# supervisor's relaunch-to-first-step MTTR is their fleet-side reading.
+# The start-up timeline (harness/startup.py::Timeline, stamped by fit;
+# README "Observability").  One clock (``perf_counter``), one origin
+# (the kernel's record of when the process started), and every gauge
+# is in telemetry.json with an explicit zero where nothing happened.
+# The goodput report carries them as its "startup" section, beside and
+# never inside the four exclusive fractions.
+#
+# Exclusive and in order, on the loop's thread.  PROCESS_TO_FIT runs
+# from the process's start to fit's entry (interpreter, imports, the
+# device client, whatever the caller did first); the six of
+# STARTUP_PHASES then tile fit entry → the end of the first loop
+# iteration, so that they add up to STARTUP_FIRST_STEP (fit entry →
+# first completed chunk: dispatched and its hooks walked, which is not
+# the step's end on the device) but for STARTUP_UNATTRIBUTED.  Each is
+# host time as it was spent: nothing waits for the device that did not
+# wait before, so device work still in flight when a phase ends (the
+# parameter draw) shows in the first phase that blocks on it.
+STARTUP_PROCESS_TO_FIT = "startup/process_to_fit_s"  # gauge
+# apply_compile_cache, the mesh, build_state (model.init's trace, its
+# compile or cache read, dispatch of the draw, placement).
+STARTUP_BUILD_STATE = "startup/build_state_s"  # gauge
+# The checkpoint manager, build_step / build_multi_step, starting the
+# AOT thread.
+STARTUP_BUILD_STEP = "startup/build_step_s"  # gauge
+# The restore walk and the re-placement (0.0 on a fresh run).
 STARTUP_RESTORE = "startup/restore_s"  # gauge
-STARTUP_AOT_COMPILE = "startup/aot_compile_s"  # gauge
-# The tracing-and-lowering part of STARTUP_AOT_COMPILE (the rest is the
-# compile, or the read of the persistent cache).
-STARTUP_AOT_LOWER = "startup/aot_lower_s"  # gauge
+# build_dataset, set_state, the chaos wrap.
+STARTUP_DATASET = "startup/dataset_s"  # gauge
+# The input stack, the step wrapper, listener, watchdog, hooks and
+# their begin(), up to the loop.
+STARTUP_PIPELINE_OPEN = "startup/pipeline_open_s"  # gauge
+# The first loop iteration; inside it (not added in) AOT_JOIN, the part
+# of the background compile the loop waited for (0.0 when the thread
+# had finished; the first train/compile record is that wait plus the
+# first dispatch), and FIRST_DATA_WAIT, its wait for the first batch.
+STARTUP_FIRST_CHUNK = "startup/first_chunk_s"  # gauge
+STARTUP_AOT_JOIN = "startup/aot_join_s"  # gauge
+STARTUP_FIRST_DATA_WAIT = "startup/first_data_wait_s"  # gauge
+STARTUP_UNATTRIBUTED = "startup/unattributed_s"  # gauge
 STARTUP_FIRST_STEP = "startup/time_to_first_step_s"  # gauge
+STARTUP_PHASES = (
+    STARTUP_BUILD_STATE,
+    STARTUP_BUILD_STEP,
+    STARTUP_RESTORE,
+    STARTUP_DATASET,
+    STARTUP_PIPELINE_OPEN,
+    STARTUP_FIRST_CHUNK,
+)
+# Overlapped with the phases above, from the AOT thread: the whole
+# background lower().compile() of the train step, and its
+# tracing-and-lowering part (the rest is the compile, or the read of
+# the persistent cache).
+STARTUP_AOT_COMPILE = "startup/aot_compile_s"  # gauge
+STARTUP_AOT_LOWER = "startup/aot_lower_s"  # gauge
+# Fit entry → the end of the first hook walk in which the log-cadence
+# hooks turned a device scalar into a host float: the first loss line,
+# and the first instant at which a step is known to have finished on
+# the device.  No sync of its own.
+STARTUP_FIRST_LOSS_ROW = "startup/first_loss_row_s"  # gauge
+# Warm or cold: jax's compile requests that went to the persistent
+# cache, and how many it answered, between fit entry and the end of
+# the first chunk.  A program that compiles in under 0.5 s is never
+# written, so a warm start reads hits < requests.  Counted by one
+# process-global listener (harness/startup.py) into the process-global
+# registry; fit copies what its own start-up raised.
+STARTUP_COMPILE_REQUESTS = "startup/compile_requests"  # counter
+STARTUP_CACHE_HITS = "startup/cache_hits"  # counter
+# The whole set, in reading order: what the Timeline creates at fit
+# entry, the goodput report's "startup" section, and what
+# check_metrics_schema.py wants together.
+STARTUP_GAUGES = (
+    STARTUP_PROCESS_TO_FIT,
+    *STARTUP_PHASES,
+    STARTUP_AOT_JOIN,
+    STARTUP_FIRST_DATA_WAIT,
+    STARTUP_UNATTRIBUTED,
+    STARTUP_FIRST_STEP,
+    STARTUP_FIRST_LOSS_ROW,
+    STARTUP_AOT_LOWER,
+    STARTUP_AOT_COMPILE,
+)
+STARTUP_COUNTERS = (STARTUP_COMPILE_REQUESTS, STARTUP_CACHE_HITS)
 # Resilience (harness/train.py + resilience/).  RESTARTS counts
 # recoverable_fit restore-retrain cycles (seeded into each attempt's fresh
 # registry so the final telemetry.json carries the cumulative count);

@@ -62,7 +62,12 @@ carries the full telemetry key set (``data_wait_s``, ``step_time_s``,
 any row is always an error.
 
 With ``--declared-coverage REGISTRY_PY`` the path is validated as a
-``telemetry.json`` goodput report instead: every metric key constant
+``telemetry.json`` goodput report instead: its ``startup`` section (the
+start-up timeline, README "Observability") has to carry the whole of
+``STARTUP_REPORT_KEYS``, each an explicit number even where nothing
+happened, none negative, with the exclusive phases adding up to
+``time_to_first_step_s`` but for ``unattributed_s`` and no more cache
+hits than requests; and every metric key constant
 declared in the registry module (the same UPPERCASE-constant extraction
 ``analysis/dtmlint``'s metric-key-registry rule uses) must appear in the
 report's ``metrics`` snapshot, exactly or as a ``key/...`` timer/family
@@ -168,6 +173,38 @@ STARTUP_KEYS = (
     "startup/aot_compile_s",
     "startup/time_to_first_step_s",
 )
+
+
+# The start-up timeline in a telemetry.json report's "startup" section
+# (telemetry/registry.py STARTUP_*; harness/startup.py::Timeline): fit
+# creates every one at entry, so the section is this set or the writer
+# is broken.  The first is outside fit; the next six are the exclusive
+# phases that add up to time_to_first_step_s but for unattributed_s.
+STARTUP_PHASE_KEYS = (
+    "build_state_s",
+    "build_step_s",
+    "restore_s",
+    "dataset_s",
+    "pipeline_open_s",
+    "first_chunk_s",
+)
+STARTUP_REPORT_KEYS = (
+    "process_to_fit_s",
+    *STARTUP_PHASE_KEYS,
+    "aot_join_s",
+    "first_data_wait_s",
+    "unattributed_s",
+    "time_to_first_step_s",
+    "first_loss_row_s",
+    "aot_lower_s",
+    "aot_compile_s",
+    "compile_requests",
+    "cache_hits",
+)
+# What the phases' sum may miss of time_to_first_step_s beyond the
+# reported remainder, and how far below zero the remainder may read:
+# float rounding of a dozen perf_counter differences.
+STARTUP_ROUNDING_S = 1e-3
 
 
 # The input path's work per batch (``pipeline/assemble`` and
@@ -878,6 +915,44 @@ def declared_metric_keys(registry_path: str) -> dict[str, str]:
         return declared_keys_from_source(f.read())
 
 
+def check_startup_section(report: dict) -> list[str]:
+    """The ``startup`` section of a telemetry.json report against
+    ``STARTUP_REPORT_KEYS``: the whole set, numbers, none negative (the
+    remainder down to rounding), phases + remainder = time to the first
+    step once there was one, hits <= requests."""
+    section = report.get("startup") if isinstance(report, dict) else None
+    if not isinstance(section, dict):
+        return ["report carries no 'startup' section object"]
+    errors = [
+        f"startup section lacks {key!r}"
+        for key in STARTUP_REPORT_KEYS
+        if key not in section
+    ]
+    for key, value in section.items():
+        if not _is_number(value):
+            errors.append(f"startup {key!r} is not a number: {value!r}")
+        elif value < (-STARTUP_ROUNDING_S if key == "unattributed_s" else 0):
+            errors.append(f"startup {key!r} is negative: {value!r}")
+    if errors:
+        return errors
+    first_step = section["time_to_first_step_s"]
+    covered = sum(section[k] for k in STARTUP_PHASE_KEYS)
+    if first_step and abs(
+        first_step - covered - section["unattributed_s"]
+    ) > STARTUP_ROUNDING_S:
+        errors.append(
+            f"startup phases ({covered!r}) + unattributed_s "
+            f"({section['unattributed_s']!r}) do not add up to "
+            f"time_to_first_step_s ({first_step!r})"
+        )
+    if section["cache_hits"] > section["compile_requests"]:
+        errors.append(
+            f"startup cache_hits ({section['cache_hits']!r}) exceed "
+            f"compile_requests ({section['compile_requests']!r})"
+        )
+    return errors
+
+
 def check_declared_coverage(
     report: dict,
     declared: dict[str, str],
@@ -1031,6 +1106,10 @@ def main(argv=None) -> int:
             report, declared, allow_missing=args.allow_missing,
             only_prefix=args.only_prefix,
         )
+        if not args.only_prefix:
+            # A report scoped to one subsystem's keys (a serving stats
+            # file) is not a fit's and has no start-up timeline.
+            errors += check_startup_section(report)
         if errors:
             for e in errors:
                 print(f"{args.path}: {e}", file=sys.stderr)

@@ -449,13 +449,221 @@ def test_goodput_report_counts_fence_and_carries_startup():
     reg.gauge(telemetry.STARTUP_RESTORE).set(1.5)
     reg.gauge(telemetry.STARTUP_AOT_COMPILE).set(0.7)
     reg.gauge(telemetry.STARTUP_FIRST_STEP).set(2.5)
+    reg.counter(telemetry.STARTUP_CACHE_HITS).inc(3)
     rep = telemetry.goodput_report(reg, total_s=1.0, steps=4, kind="CPU")
     assert rep["fractions"]["checkpoint"] == pytest.approx(0.2)
     assert sum(rep["fractions"].values()) == pytest.approx(1.0)
-    assert rep["startup"] == {
+    # The whole timeline, each key under its name less the prefix, and an
+    # explicit zero for what this registry never saw.
+    assert list(rep["startup"]) == [
+        key.split("/", 1)[1]
+        for key in (*telemetry.STARTUP_GAUGES, *telemetry.STARTUP_COUNTERS)
+    ]
+    assert {k: v for k, v in rep["startup"].items() if v} == {
         "restore_s": 1.5, "aot_compile_s": 0.7,
-        "time_to_first_step_s": 2.5,
+        "time_to_first_step_s": 2.5, "cache_hits": 3.0,
     }
+
+
+# --------------------------------------------------------------------------
+# The start-up timeline (ISSUE 34)
+# --------------------------------------------------------------------------
+
+_TIMELINE_CFG = dict(
+    train_steps=6, global_batch_size=32, log_every_steps=3,
+    checkpoint_every_secs=10_000.0, trace_export=True,
+)
+
+
+def _startup_events(workdir):
+    """The ``startup/*`` complete events of a fit's span export, by
+    thread: ``{tid: [(name, start_s, end_s), ...]}`` in start order."""
+    trace = json.load(open(os.path.join(workdir, "trace_p0.json")))
+    by_tid = {}
+    for e in trace["traceEvents"]:
+        if e["name"].startswith("startup/") and e["ph"] == "X":
+            by_tid.setdefault(e["tid"], []).append(
+                (e["name"], e["ts"] * 1e-6, (e["ts"] + e["dur"]) * 1e-6)
+            )
+    return {tid: sorted(evs, key=lambda e: e[1]) for tid, evs in by_tid.items()}
+
+
+@pytest.mark.parametrize("steps_per_loop", [1, 3])
+def test_fit_stamps_the_startup_timeline(mesh8, tmp_path, steps_per_loop):
+    """Every start-up gauge and counter is in telemetry.json, the
+    exclusive phases tile fit entry to the first chunk, the first loss
+    row comes no earlier than the first chunk, and the span export shows
+    the main thread's phases in order beside the AOT thread's."""
+    cfg = configlib.get_config(
+        "lenet_mnist", steps_per_loop=steps_per_loop, **_TIMELINE_CFG
+    )
+    trainlib.fit(cfg, str(tmp_path), mesh=mesh8)
+    rep = json.load(open(tmp_path / "telemetry.json"))
+    snap, startup = rep["metrics"], rep["startup"]
+    for key in (*telemetry.STARTUP_GAUGES, *telemetry.STARTUP_COUNTERS):
+        assert key in snap, key
+        assert startup[key.split("/", 1)[1]] == snap[key]
+    assert _load_script("check_metrics_schema").check_startup_section(rep) == []
+
+    phases = [snap[k] for k in telemetry.STARTUP_PHASES]
+    assert all(v > 0 for v in phases)
+    first_step = snap[telemetry.STARTUP_FIRST_STEP]
+    unattributed = snap[telemetry.STARTUP_UNATTRIBUTED]
+    assert first_step - sum(phases) == pytest.approx(unattributed, abs=1e-9)
+    assert -1e-3 <= unattributed < 0.05
+    # A fresh run walks no checkpoint: the restore phase is there and empty.
+    assert snap[telemetry.STARTUP_RESTORE] < 0.1
+    assert snap[telemetry.STARTUP_FIRST_LOSS_ROW] >= first_step
+    if steps_per_loop == 1:
+        # Steps 1 and 2 run between the first chunk and the first row.
+        assert snap[telemetry.STARTUP_FIRST_LOSS_ROW] > first_step
+    assert 0 <= snap[telemetry.STARTUP_FIRST_DATA_WAIT] <= snap[
+        telemetry.STARTUP_FIRST_CHUNK
+    ]
+    assert snap[telemetry.STARTUP_AOT_JOIN] <= snap[telemetry.STARTUP_FIRST_CHUNK]
+    assert snap[telemetry.STARTUP_AOT_COMPILE] >= snap[
+        telemetry.STARTUP_AOT_LOWER
+    ] > 0
+    assert snap[telemetry.STARTUP_COMPILE_REQUESTS] >= 1
+    assert snap[telemetry.STARTUP_CACHE_HITS] <= snap[
+        telemetry.STARTUP_COMPILE_REQUESTS
+    ]
+
+    by_tid = _startup_events(str(tmp_path))
+    main = next(
+        evs for evs in by_tid.values()
+        if any(name == "startup/build_state" for name, _, _ in evs)
+    )
+    # The wait for the background compile, where there was one, is the
+    # loop's own and lies inside its first iteration.
+    joins = [e for e in main if e[0] == "startup/aot_join"]
+    main = [e for e in main if e[0] != "startup/aot_join"]
+    assert [name for name, _, _ in main] == [
+        "startup/process_to_fit",
+        *(key[: -len("_s")] for key in telemetry.STARTUP_PHASES),
+    ]
+    for (_, _, end), (name, start, _) in zip(main, main[1:]):
+        # One clock: each phase starts where the one before ended (to
+        # the microsecond the export's float64 wall stamps keep).
+        assert abs(start - end) < 5e-6, name
+    for _, start, end in joins:
+        assert main[-1][1] <= start and end <= main[-1][2]
+    assert len(joins) == (snap[telemetry.STARTUP_AOT_JOIN] > 0)
+    (aot,) = [evs for tid, evs in by_tid.items() if evs[0][0] != main[0][0]]
+    assert [name for name, _, _ in aot] == [
+        "startup/aot_lower", "startup/aot_compile",
+    ]
+
+
+def test_aot_off_leaves_explicit_zeros(mesh8, tmp_path):
+    cfg = configlib.get_config(
+        "lenet_mnist", aot_compile=False, **_TIMELINE_CFG
+    )
+    trainlib.fit(cfg, str(tmp_path), mesh=mesh8)
+    snap = json.load(open(tmp_path / "telemetry.json"))["metrics"]
+    for key in (
+        telemetry.STARTUP_AOT_JOIN,
+        telemetry.STARTUP_AOT_LOWER,
+        telemetry.STARTUP_AOT_COMPILE,
+    ):
+        assert snap[key] == 0.0, key
+    assert len(_startup_events(str(tmp_path))) == 1  # the main thread alone
+
+
+def test_aot_join_gauge_is_the_wait_acquire_measured(mesh8):
+    """``startup/aot_join_s`` is set from the one measurement ``acquire``
+    makes for its span: the wait while the thread still compiles, and
+    untouched (the timeline's explicit 0.0) once it had finished."""
+    state, loss, batch = _tiny_setup(mesh8)
+    step = train_loop.make_train_step(loss)
+    rng = jax.random.key(7)
+    b = batch(0)
+
+    class Slow:
+        def lower(self, *args):
+            time.sleep(0.3)
+            return step.lower(*args)
+
+    def run(fn, wait_first):
+        reg = telemetry.MetricsRegistry()
+        reg.trace = telemetry.Tracer(capacity=64)
+        aot = startuplib.AotTrainStep(
+            fn, (state, _spec_of(b), rng), registry=reg
+        ).start()
+        if wait_first:
+            aot.join()
+        exe, first = aot.acquire(startuplib.AotTrainStep.signature(b))
+        assert exe is not None and first
+        joins = [
+            e for e in reg.trace.events() if e["name"] == "startup/aot_join"
+        ]
+        return reg.gauge(telemetry.STARTUP_AOT_JOIN).value, joins
+
+    waited, joins = run(Slow(), wait_first=False)
+    assert waited > 0.1 and len(joins) == 1
+    assert joins[0]["dur_s"] == waited
+    done, joins = run(step, wait_first=True)
+    assert done == 0.0 and joins == []
+
+
+def test_process_to_fit_agrees_with_the_benchmarks_clock(mesh8, tmp_path):
+    """``startup/process_to_fit_s`` starts where the benchmark's
+    ``setup_s`` starts: the kernel's record of the process's start."""
+    from importlib import util as importutil
+
+    spec = importutil.spec_from_file_location(
+        "benchmark_run_for_test",
+        os.path.join(os.path.dirname(_SCRIPTS), "benchmark", "run.py"),
+    )
+    bench_run = importutil.module_from_spec(spec)
+    spec.loader.exec_module(bench_run)
+
+    cfg = configlib.get_config("lenet_mnist", **_TIMELINE_CFG)
+    t_before = time.perf_counter()
+    trainlib.fit(cfg, str(tmp_path), mesh=mesh8)
+    since_start = bench_run.seconds_since_process_start()
+    age = time.perf_counter() - t_before
+    snap = json.load(open(tmp_path / "telemetry.json"))["metrics"]
+    assert snap[telemetry.STARTUP_PROCESS_TO_FIT] == pytest.approx(
+        since_start - age, abs=0.5
+    )
+    assert startuplib.seconds_since_process_start() == pytest.approx(
+        bench_run.seconds_since_process_start(), abs=0.05
+    )
+
+
+def test_two_fits_count_their_own_compile_requests(mesh8, tmp_path):
+    """One listener a process, however often the cache is placed, and
+    each fit's report holds what its own start-up asked of the cache."""
+    import jax.monitoring
+
+    seen = []
+
+    def on_event(event, **_):
+        if event == startuplib._COMPILE_REQUEST_EVENT:
+            seen.append(event)
+
+    shared = telemetry.get_registry().counter(
+        telemetry.STARTUP_COMPILE_REQUESTS
+    )
+    startuplib.apply_compile_cache()
+    startuplib.apply_compile_cache()
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        cfg = configlib.get_config("lenet_mnist", **_TIMELINE_CFG)
+        for name in ("a", "b"):
+            seen_before, shared_before = len(seen), shared.value
+            trainlib.fit(cfg, str(tmp_path / name), mesh=mesh8)
+            in_fit = len(seen) - seen_before
+            # Counted once, not once per apply_compile_cache call.
+            assert shared.value - shared_before == in_fit
+            rep = json.load(open(tmp_path / name / "telemetry.json"))
+            requests = rep["metrics"][telemetry.STARTUP_COMPILE_REQUESTS]
+            # Its own start-up's, up to the first chunk: at least the
+            # step program, at most what the whole fit asked for.
+            assert 1 <= requests <= in_fit, (name, requests, in_fit)
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
 
 
 def test_metrics_schema_startup_and_checkpoint_keys():
@@ -480,3 +688,37 @@ def test_metrics_schema_startup_and_checkpoint_keys():
     assert any("startup gauge" in e and "negative" in e for e in errors)
     errors, _, _ = check_lines([row(**{"checkpoint/fence_s": -0.1})])
     assert any("checkpoint key" in e and "negative" in e for e in errors)
+
+
+def test_metrics_schema_startup_section():
+    """telemetry.json's start-up timeline is a set: every key, numbers,
+    none negative, phases + remainder = time to the first step."""
+    schema = _load_script("check_metrics_schema")
+    check = schema.check_startup_section
+    good = dict.fromkeys(schema.STARTUP_REPORT_KEYS, 0.0)
+    assert set(good) == {
+        key.split("/", 1)[1]
+        for key in (*telemetry.STARTUP_GAUGES, *telemetry.STARTUP_COUNTERS)
+    }
+    good.update(
+        process_to_fit_s=20.0, build_state_s=4.0, first_chunk_s=5.5,
+        unattributed_s=0.5, time_to_first_step_s=10.0,
+        first_loss_row_s=12.0, compile_requests=4.0, cache_hits=3.0,
+    )
+    assert check({"startup": good}) == []
+    assert check({"startup": dict.fromkeys(good, 0.0)}) == []  # no step yet
+    assert check({}) == ["report carries no 'startup' section object"]
+    lacking = {k: v for k, v in good.items() if k != "aot_join_s"}
+    assert any("lacks 'aot_join_s'" in e for e in check({"startup": lacking}))
+    for change, message in (
+        ({"dataset_s": -0.1}, "is negative"),
+        ({"unattributed_s": -0.5}, "is negative"),
+        ({"first_chunk_s": 7.0}, "do not add up"),
+        ({"cache_hits": 5.0}, "exceed"),
+        ({"restore_s": "0"}, "not a number"),
+    ):
+        errors = check({"startup": {**good, **change}})
+        assert any(message in e for e in errors), (change, errors)
+    # The remainder may read a rounding below zero.
+    assert check({"startup": {**good, "unattributed_s": -1e-6,
+                              "first_chunk_s": 6.000001}}) == []

@@ -250,7 +250,6 @@ def test_pipeline_records_waits_and_depths(mesh8):
     snap = reg.snapshot()
     # Prefetcher pulled >= depth + consumed batches from upstream.
     assert snap[f"{telemetry.PREFETCH_FILL}/count"] >= 3
-    assert snap[telemetry.PREFETCH_DEPTH] >= 1
     # The producer thread recorded put waits and the queue depth gauge.
     assert snap[f"{telemetry.PRODUCER_WAIT}/count"] >= 1
     assert telemetry.HOST_QUEUE_DEPTH in snap
@@ -420,6 +419,15 @@ def test_smoke_train_produces_telemetry_artifacts(mesh8, tmp_path):
     assert report["seconds"]["checkpoint"] > 0  # CheckpointHook.end saved
     assert report["flops_per_step"] > 0  # XLA cost analysis on CPU
     assert math.isfinite(report["steps_per_sec"])
+    # The start-up timeline's schema, pinned: the whole set, in order
+    # (the coverage lint below also checks that its phases add up).
+    assert list(report["startup"]) == [
+        "process_to_fit_s", "build_state_s", "build_step_s", "restore_s",
+        "dataset_s", "pipeline_open_s", "first_chunk_s", "aot_join_s",
+        "first_data_wait_s", "unattributed_s", "time_to_first_step_s",
+        "first_loss_row_s", "aot_lower_s", "aot_compile_s",
+        "compile_requests", "cache_hits",
+    ]
 
     rows = [
         json.loads(line)
