@@ -58,13 +58,15 @@ import shutil
 from typing import Any, Callable, Optional, Sequence
 
 import jax
-import orbax.checkpoint as ocp
 
 from distributed_tensorflow_models_tpu import telemetry
 from distributed_tensorflow_models_tpu.core.train_state import TrainState
 from distributed_tensorflow_models_tpu.data import resplit as resplitlib
+from distributed_tensorflow_models_tpu.harness.startup import import_orbax
 from distributed_tensorflow_models_tpu.resilience import consensus as conslib
 from distributed_tensorflow_models_tpu.resilience import fsck as fscklib
+
+ocp = import_orbax()
 
 log = logging.getLogger("dtm")
 

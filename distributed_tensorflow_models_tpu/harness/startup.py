@@ -39,15 +39,18 @@ first dispatch, is the first record of the ``train/compile`` timer (the
 first AOT use is accounted as the run's compile event, mirroring how a
 persistent-cache hit still records a compile event today).
 ``startup/compile_requests`` and ``startup/cache_hits`` say whether the
-start was warm.  The goodput report surfaces all of it as its
-``startup`` section and ``launch.py`` reads the fleet-side equivalent
-off the heartbeat files.
+start was warm, ``startup/modules_at_fit`` what the imports before
+``fit`` brought into the process (:func:`import_orbax` keeps orbax's
+unused cloud-logging stack out of it).  The goodput report surfaces all
+of it as its ``startup`` section and ``launch.py`` reads the fleet-side
+equivalent off the heartbeat files.
 """
 
 from __future__ import annotations
 
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -196,6 +199,57 @@ def cache_entry_count(cache_dir: Optional[str]) -> int:
 
 
 # --------------------------------------------------------------------------
+# orbax without its cloud-logging stack
+# --------------------------------------------------------------------------
+
+# What orbax's optional cloud logger pulls in: the Google Cloud Logging
+# client with its gRPC, protobuf and OpenTelemetry stack, some 600
+# modules and most of the time ``import orbax.checkpoint`` takes.
+_REFUSED_IMPORT = "google.cloud.logging"
+
+
+def import_orbax():
+    """``orbax.checkpoint``, imported without the cloud-logging stack:
+    the one place this program imports it.
+
+    ``orbax/checkpoint/logging/__init__.py`` imports its ``CloudLogger``
+    inside ``try: ... except ImportError: pass``: orbax treats
+    ``google.cloud.logging`` as optional, nothing else in orbax names the
+    class, and this program never logs to the cloud.  So for the length
+    of the import, and no longer, ``sys.modules["google.cloud.logging"]``
+    is ``None``, which makes ``import google.cloud.logging`` raise
+    ``ModuleNotFoundError``; orbax takes that as "not installed" and
+    ``ocp.logging.CloudLogger`` (with ``CloudLoggerOptions``) is then
+    absent, everything else as ever.  ``sys.modules`` is put back in a
+    ``finally``, so a later ``import google.cloud.logging`` by anyone
+    works.  An orbax that imports the stack unconditionally fails the
+    refused import; it is then imported plainly, stack and all
+    (``startup/cloud_logging_imported`` reads 1).  Nothing is refused
+    where either module is already in the process (a caller imported it
+    first): the import is then a lookup.
+
+    The refusal is process-wide while it lasts: another thread's own
+    ``import google.cloud.logging`` in that window is refused too.
+    ``fit``'s import runs at ``harness/checkpoint.py``'s module import,
+    before it starts a thread; a process whose threads import Google's
+    client libraries themselves calls this before it starts them.
+    """
+    if not (
+        "orbax.checkpoint" in sys.modules or _REFUSED_IMPORT in sys.modules
+    ):
+        sys.modules[_REFUSED_IMPORT] = None
+        try:
+            import orbax.checkpoint  # noqa: F401
+        except ImportError:
+            pass  # needed after all, or no orbax: the plain import says
+        finally:
+            del sys.modules[_REFUSED_IMPORT]
+    import orbax.checkpoint as ocp
+
+    return ocp
+
+
+# --------------------------------------------------------------------------
 # The start-up timeline
 # --------------------------------------------------------------------------
 
@@ -266,6 +320,10 @@ class Timeline:
             registry.gauge(key)
         for key in telemetry.STARTUP_COUNTERS:
             registry.counter(key)
+        registry.gauge(telemetry.STARTUP_MODULES_AT_FIT).set(len(sys.modules))
+        registry.gauge(telemetry.STARTUP_CLOUD_LOGGING_IMPORTED).set(
+            _REFUSED_IMPORT in sys.modules
+        )
         self._cache0 = self._cache_counts()
         before_fit = seconds_since_process_start() - (
             time.perf_counter() - t_fit

@@ -208,8 +208,9 @@ def load_candidate_params(step_dir: str):
     Function-level orbax import: the journal/controller half of this
     module must stay importable on jax-free supervisor hosts.
     """
-    import orbax.checkpoint as ocp  # noqa: lazy heavy dep
+    from distributed_tensorflow_models_tpu.harness.startup import import_orbax
 
+    ocp = import_orbax()
     restored = ocp.StandardCheckpointer().restore(
         os.path.join(step_dir, "state")
     )

@@ -81,6 +81,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     STARTUP_BUILD_STATE,
     STARTUP_BUILD_STEP,
     STARTUP_CACHE_HITS,
+    STARTUP_CLOUD_LOGGING_IMPORTED,
     STARTUP_COMPILE_REQUESTS,
     STARTUP_COUNTERS,
     STARTUP_DATASET,
@@ -89,6 +90,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     STARTUP_FIRST_LOSS_ROW,
     STARTUP_FIRST_STEP,
     STARTUP_GAUGES,
+    STARTUP_MODULES_AT_FIT,
     STARTUP_PHASES,
     STARTUP_PIPELINE_OPEN,
     STARTUP_PROCESS_TO_FIT,

@@ -16,10 +16,11 @@ The report also carries a ``startup`` section: the start-up timeline
 ``fit``).  ``process_to_fit_s`` and then the exclusive phases, which
 add up to ``time_to_first_step_s`` but for ``unattributed_s``; the AOT
 thread's ``aot_lower_s`` and ``aot_compile_s``, which overlap them;
-``first_loss_row_s``; and the persistent cache's ``compile_requests``
-and ``cache_hits`` up to the first chunk.  All of it is reported
-alongside — never added into — the four exclusive fractions above,
-which still sum to exactly 1.0.
+``first_loss_row_s``; the persistent cache's ``compile_requests`` and
+``cache_hits`` up to the first chunk; ``modules_at_fit`` with
+``cloud_logging_imported``.  All of it is reported alongside — never
+added into — the four exclusive fractions above, which still sum to
+exactly 1.0.
 
 MFU is wall-clock-inclusive (FLOPs retired per second of *total* time over
 peak), i.e. it already prices in every stall — the honest end-to-end

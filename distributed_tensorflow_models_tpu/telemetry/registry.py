@@ -192,6 +192,14 @@ STARTUP_FIRST_LOSS_ROW = "startup/first_loss_row_s"  # gauge
 # registry; fit copies what its own start-up raised.
 STARTUP_COMPILE_REQUESTS = "startup/compile_requests"  # counter
 STARTUP_CACHE_HITS = "startup/cache_hits"  # counter
+# len(sys.modules) at fit entry: what the imports before fit brought
+# into the process.  harness/startup.py::import_orbax keeps some 600 of
+# orbax's optional cloud-logging stack out; CLOUD_LOGGING_IMPORTED is 1
+# where the process holds ``google.cloud.logging`` all the same (a
+# caller imported it first, or orbax stopped treating it as optional and
+# the helper imported it plainly), else 0.
+STARTUP_MODULES_AT_FIT = "startup/modules_at_fit"  # gauge
+STARTUP_CLOUD_LOGGING_IMPORTED = "startup/cloud_logging_imported"  # gauge
 # The whole set, in reading order: what the Timeline creates at fit
 # entry, the goodput report's "startup" section, and what
 # check_metrics_schema.py wants together.
@@ -205,6 +213,8 @@ STARTUP_GAUGES = (
     STARTUP_FIRST_LOSS_ROW,
     STARTUP_AOT_LOWER,
     STARTUP_AOT_COMPILE,
+    STARTUP_MODULES_AT_FIT,
+    STARTUP_CLOUD_LOGGING_IMPORTED,
 )
 STARTUP_COUNTERS = (STARTUP_COMPILE_REQUESTS, STARTUP_CACHE_HITS)
 # Resilience (harness/train.py + resilience/).  RESTARTS counts

@@ -200,6 +200,8 @@ STARTUP_REPORT_KEYS = (
     "aot_compile_s",
     "compile_requests",
     "cache_hits",
+    "modules_at_fit",
+    "cloud_logging_imported",
 )
 # What the phases' sum may miss of time_to_first_step_s beyond the
 # reported remainder, and how far below zero the remainder may read:

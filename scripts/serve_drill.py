@@ -1393,9 +1393,10 @@ def _deploy_helper_main(mode: str, spec_path: str) -> int:
     if mode == "build-staging":
         import jax
         import numpy as np
-        import orbax.checkpoint as ocp
 
-        ckptr = ocp.StandardCheckpointer()
+        from distributed_tensorflow_models_tpu.harness.startup import import_orbax
+
+        ckptr = import_orbax().StandardCheckpointer()
         for entry in spec["steps"]:
             step = int(entry["step"])
             params = init(step)
@@ -1435,9 +1436,11 @@ def _deploy_helper_main(mode: str, spec_path: str) -> int:
             if vid == 0:
                 params = init(0)
             else:
-                import orbax.checkpoint as ocp
+                from distributed_tensorflow_models_tpu.harness.startup import (
+                    import_orbax,
+                )
 
-                params = ocp.StandardCheckpointer().restore(
+                params = import_orbax().StandardCheckpointer().restore(
                     os.path.join(spec["ckpt_dir"], str(vid), "state")
                 )["params"]
             eng = InferenceEngine(

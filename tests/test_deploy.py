@@ -245,11 +245,11 @@ def test_gate_candidate_rejects_incomplete_fleet_sidecars(tmp_path):
 
 def _save_candidate(ckpt_dir, step, tree):
     """Write a real orbax save in the trainer's step layout."""
-    import orbax.checkpoint as ocp
+    from distributed_tensorflow_models_tpu.harness.startup import import_orbax
 
     step_dir = os.path.join(ckpt_dir, str(step))
     os.makedirs(step_dir, exist_ok=True)
-    ckptr = ocp.StandardCheckpointer()
+    ckptr = import_orbax().StandardCheckpointer()
     ckptr.save(os.path.join(step_dir, "state"), {"params": tree})
     ckptr.wait_until_finished()  # StandardCheckpointer saves async
     with open(os.path.join(step_dir, "_CHECKPOINT_METADATA"), "w") as f:

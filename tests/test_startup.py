@@ -14,6 +14,8 @@ lint.
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -343,6 +345,96 @@ def test_fit_aot_on_off_bit_identical(mesh8, tmp_path):
 
 
 # --------------------------------------------------------------------------
+# orbax comes without its cloud-logging stack (harness/startup.py::import_orbax)
+# --------------------------------------------------------------------------
+
+_NAME = "google.cloud.logging"
+_HELPER = (
+    "from distributed_tensorflow_models_tpu.harness.startup import "
+    "import_orbax\n"
+)
+# Each script runs in a process of its own, so that what the test
+# worker has imported does not decide it, and prints "ok" at its end.
+_IMPORT_SCRIPTS = {
+    "fit_imports_orbax_without_it": f"""
+import sys
+import distributed_tensorflow_models_tpu.harness.train
+from distributed_tensorflow_models_tpu.harness import checkpoint
+assert {_NAME!r} not in sys.modules, "the cloud-logging stack was imported"
+ocp = checkpoint.ocp
+assert ocp is sys.modules["orbax.checkpoint"]
+ocp.CheckpointManager, ocp.StandardCheckpointer, ocp.args.StandardSave
+ocp.logging.StandardLogger, ocp.logging.CompositeLogger
+assert not hasattr(ocp.logging, "CloudLogger")
+""",
+    "imported_first_is_left_alone": f"""
+import sys
+import google.cloud.logging as first
+{_HELPER}
+ocp = import_orbax()
+assert sys.modules[{_NAME!r}] is first
+ocp.CheckpointManager, ocp.logging.CloudLogger
+""",
+    "importable_afterwards": f"""
+import sys
+{_HELPER}
+ocp = import_orbax()
+assert {_NAME!r} not in sys.modules  # neither the module nor a None
+import google.cloud.logging
+assert sys.modules[{_NAME!r}] is google.cloud.logging
+assert import_orbax() is ocp
+assert not hasattr(ocp.logging, "CloudLogger")
+""",
+    "restored_when_orbax_fails": f"""
+import sys
+{_HELPER}
+sys.modules["orbax"] = None  # makes `import orbax.checkpoint` raise
+try:
+    import_orbax()
+except ModuleNotFoundError as e:
+    assert "orbax" in str(e), e
+else:
+    raise AssertionError("the import did not raise")
+assert {_NAME!r} not in sys.modules
+del sys.modules["orbax"]
+import_orbax().CheckpointManager
+assert {_NAME!r} not in sys.modules
+""",
+    # An orbax (a stub of one, ahead of the real on the path) that
+    # imports the stack outside any try: refused, it fails, and the
+    # helper imports it again with nothing refused.
+    "an_orbax_that_needs_it_gets_it": f"""
+import os, sys, tempfile
+{_HELPER}
+stub = os.path.join(tempfile.mkdtemp(), "orbax", "checkpoint")
+os.makedirs(stub)
+open(os.path.join(stub, "..", "__init__.py"), "w").close()
+with open(os.path.join(stub, "__init__.py"), "w") as f:
+    f.write("import google.cloud.logging as needed\\n")
+sys.path.insert(0, os.path.dirname(os.path.dirname(stub)))
+ocp = import_orbax()
+assert ocp.__file__.startswith(stub), ocp.__file__
+assert ocp.needed is sys.modules[{_NAME!r}]
+""",
+}
+
+
+@pytest.mark.parametrize("case", list(_IMPORT_SCRIPTS))
+def test_orbax_is_imported_without_the_cloud_logging_stack(case):
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.abspath(root), env.get("PYTHONPATH", "")]
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", _IMPORT_SCRIPTS[case] + 'print("ok")'],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert done.stdout.strip().endswith("ok")
+
+
+# --------------------------------------------------------------------------
 # Heartbeat liveness through a slow cold start
 # --------------------------------------------------------------------------
 
@@ -528,6 +620,10 @@ def test_fit_stamps_the_startup_timeline(mesh8, tmp_path, steps_per_loop):
     assert snap[telemetry.STARTUP_CACHE_HITS] <= snap[
         telemetry.STARTUP_COMPILE_REQUESTS
     ]
+    assert 500 < snap[telemetry.STARTUP_MODULES_AT_FIT] <= len(sys.modules)
+    assert snap[telemetry.STARTUP_CLOUD_LOGGING_IMPORTED] == (
+        "google.cloud.logging" in sys.modules
+    )
 
     by_tid = _startup_events(str(tmp_path))
     main = next(
@@ -704,6 +800,7 @@ def test_metrics_schema_startup_section():
         process_to_fit_s=20.0, build_state_s=4.0, first_chunk_s=5.5,
         unattributed_s=0.5, time_to_first_step_s=10.0,
         first_loss_row_s=12.0, compile_requests=4.0, cache_hits=3.0,
+        modules_at_fit=1400.0,
     )
     assert check({"startup": good}) == []
     assert check({"startup": dict.fromkeys(good, 0.0)}) == []  # no step yet

@@ -426,6 +426,7 @@ def test_smoke_train_produces_telemetry_artifacts(mesh8, tmp_path):
         "dataset_s", "pipeline_open_s", "first_chunk_s", "aot_join_s",
         "first_data_wait_s", "unattributed_s", "time_to_first_step_s",
         "first_loss_row_s", "aot_lower_s", "aot_compile_s",
+        "modules_at_fit", "cloud_logging_imported",
         "compile_requests", "cache_hits",
     ]
 
