@@ -154,12 +154,15 @@ def lm_loss_fn(apply_fn: Callable, fused_unembed: bool = False) -> LossFn:
                 mutable=["losses", "moe_stats"],
                 return_hidden=True,
             )
-            head = params["head"]
+            if "head" in params:
+                head = params["head"]
+                kernel, bias = head["kernel"], head.get("bias")
+            else:
+                # A tied head: the embedding matrix ``[V, d]`` the other
+                # way round; its gradient joins the gather's.
+                kernel, bias = params["embedding"]["embedding"].T, None
             nll = losslib.fused_unembed_mean_xent(
-                hidden,
-                head["kernel"],
-                head.get("bias"),
-                batch["targets"],
+                hidden, kernel, bias, batch["targets"]
             )
         else:
             (logits, new_carry), updated = apply_fn(

@@ -602,6 +602,69 @@ _add(
 )
 
 
+# granite-4.0-h-micro (config.json of ibm-granite/granite-4.0-h-micro,
+# 2025-10, model_type granitemoehybrid): 40 pre-norm RMSNorm (1e-5) layers
+# of width 2048 without any position encoding; 36 Mamba-2 state-space
+# layers (Dao and Gu 2024, arXiv:2405.21060: d_inner 4096 = 64 heads of
+# 64 over a state of 128, one group, so one B and one C for all the
+# heads; one causal depth-wise convolution of 4 taps with a bias over x,
+# B and C) and, as layers 6, 16, 26 and 36, full attention over 32 query
+# and 8 key/value heads of 64; no experts (num_local_experts 0): every
+# layer's feed-forward is the gated SiLU one of 8192; Granite's four
+# scalars (the embedding times 12, each sub-layer's output times 0.22
+# before it joins the residual, attention scores times 1/64, logits over
+# 8); vocabulary 100352, the head tied to the embedding, no bias.  What
+# config.json does not say is listed under ``assumed`` in
+# benchmark/configs/granite_h_micro.json.  Adam 3e-4 with clip 1.0 behind
+# the 2,000-step warm-up of the other large language models, per-half
+# recomputation, sequences of 8,192.  Every size is the published one; no
+# single chip holds the 3.19 B parameters in training
+# (benchmark/configs/granite_h_micro.json runs layers 1-10, one whole
+# period, and an eighth of the vocabulary: the first of four pipeline
+# stages, the vocabulary shared eight ways).
+_GRANITE_H_ATTENTION = (6, 16, 26, 36)  # 1-based, config.json layer_types
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="granite_h_micro",
+        model_kwargs={
+            "vocab_size": 100352,
+            "num_layers": 40,
+            "num_heads": 32,
+            "num_kv_heads": 8,
+            "d_model": 2048,
+            "d_ff": 8192,
+            "max_len": 131072,
+            "dropout_rate": 0.0,
+            "pos_encoding": "none",
+            "norm": "rmsnorm",
+            "norm_eps": 1e-5,
+            "use_bias": False,
+            "mlp": "gated_silu",
+            "layer_mixers": tuple(
+                "attention" if i in _GRANITE_H_ATTENTION else "ssm"
+                for i in range(1, 41)
+            ),
+            "ssm_num_heads": 64,
+            "ssm_head_dim": 64,
+            "ssm_state_dim": 128,
+            "ssm_conv_size": 4,
+            "embedding_multiplier": 12.0,
+            "residual_multiplier": 0.22,
+            "attention_multiplier": 0.015625,
+            "logits_scaling": 8.0,
+            "tie_embeddings": True,
+            "remat": True,
+        },
+        global_batch_size=1,
+        num_steps=8192,
+        vocab_size=100352,
+        optimizer=dataclasses.replace(
+            _CONFIGS["transformer_lm"].optimizer, warmup_steps=2000
+        ),
+    )
+)
+
+
 def get_config(name: str, **overrides) -> ExperimentConfig:
     if name not in _CONFIGS:
         raise KeyError(f"unknown config {name!r}; have {sorted(_CONFIGS)}")

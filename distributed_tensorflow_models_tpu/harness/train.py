@@ -1340,8 +1340,8 @@ def _export_trace(
 def _trace_counts() -> dict[str, float]:
     """What the ops count at trace time (``attention(impl="auto")``'s
     routes, ``chunked_kda``'s, ``KDAMixer``'s two placements,
-    ``chunked_gdn``'s traced calls, the fused head's gradient-in-forward
-    calls), as the process-global registry holds it now."""
+    ``chunked_gdn``'s and ``chunked_ssd``'s traced calls, the fused head's
+    gradient-in-forward calls), as the process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
@@ -1353,6 +1353,7 @@ def _trace_counts() -> dict[str, float]:
             telemetry.KDA_MIXER_FUSED,
             telemetry.KDA_MIXER_PLAIN,
             telemetry.GDN_ROUTE_PLAIN,
+            telemetry.SSD_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
         )
     }

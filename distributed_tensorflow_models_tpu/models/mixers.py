@@ -1,5 +1,5 @@
 """Token mixers beside full attention, for stacks whose layers differ:
-three mixers, two kinds of decay.
+four mixers: two delta rules, a latent attention, a state-space layer.
 
 Kimi Linear (Kimi Linear technical report, Moonshot AI 2025,
 arXiv:2510.26692; ``config.json`` and the published modelling code of
@@ -50,14 +50,34 @@ attention (``transformer_lm.SelfAttention``), a third:
   ``W_o``'s sum is per head, so a chip that shares a layer's heads with
   others runs this module at its count and its output is its partial sum.
 
+Granite 4.0-H (ibm-granite/granite-4.0-h-micro ``config.json``:
+``layer_types``, the ``mamba_*`` keys; the ``granitemoehybrid`` Mamba
+layer of transformers, which follows ``mamba_ssm``'s Mamba-2 block)
+alternates, nine to one with full attention over grouped key/value heads,
+a fourth:
+
+- :class:`Mamba2Mixer`: the Mamba-2 state-space layer (Dao and Gu 2024,
+  arXiv:2405.21060).  ``[z, xBC, dt] = W_in x`` (``d_inner``, ``d_inner +
+  2 N`` and ``H`` channels, in that order, ``d_inner = H P``); ``xBC =
+  silu(conv(xBC) + b_conv)``, **one** causal depthwise convolution **with
+  a bias** over ``x``, ``B`` and ``C`` together; ``dt = softplus(dt +
+  dt_bias)`` and the decay ``exp(-exp(A_log) dt)``, one number a head
+  and token; ``B`` and ``C`` ``[N]`` **one vector for all the heads**
+  (one group); the state ``[N, P]`` a head and the read of
+  :func:`...ops.ssm.chunked_ssd` with its ``D`` skip; ``y = W_out (w *
+  rmsnorm(y * silu(z)))``, the gate **before** the norm and the mean
+  square over all ``d_inner`` channels (the delta-rule mixers normalise
+  per head and gate after).
+
 All compute in ``dtype`` over float32 parameters; the norms, the decay,
-``b_t``, the l2 norms and the output gate's norm are float32 (the fused
-passes of :class:`KDAMixer` hold float32 from the projections' outputs to
-``q``, ``k``, ``v``, where the plain code rounds the convolution and the
-SiLU to ``dtype`` on the way).  None decodes: the recurrent states (a
-decay per channel at 128 x 128, a scalar decay at 96 x 192 a head) and
-the latent cache have no place in ``serving/kv_slots.py`` yet (ROADMAP
-Queue 2).
+``b_t``, ``dt``, the l2 norms and the output gate's norm are float32 (the
+fused passes of :class:`KDAMixer` hold float32 from the projections'
+outputs to ``q``, ``k``, ``v``, where the plain code rounds the
+convolution and the SiLU to ``dtype`` on the way).  None decodes: the
+recurrent states (a decay per channel at 128 x 128, a scalar decay at 96
+x 192, a state-space state at 128 x 64 a head with its convolution's
+tail of 3 tokens) and the latent cache have no place in
+``serving/kv_slots.py`` yet (ROADMAP Queue 2).
 """
 
 from __future__ import annotations
@@ -70,6 +90,7 @@ import jax.numpy as jnp
 
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
+from distributed_tensorflow_models_tpu.ops import ssm as ssmlib
 from distributed_tensorflow_models_tpu.telemetry.registry import (
     KDA_MIXER_FUSED,
     KDA_MIXER_PLAIN,
@@ -80,6 +101,9 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (
 # either kind; the chunk-wise core inside it is
 # ``ops/linear_attention.py::KDA_CORE_SCOPE`` or ``GDN_CORE_SCOPE``.
 LINEAR_ATTN_SCOPE = "linear_attn"
+# The same of a whole state-space mixer (:class:`Mamba2Mixer`); the scan
+# inside it is ``ops/ssm.py::SSD_CORE_SCOPE``.
+SSM_SCOPE = "ssm"
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -256,6 +280,52 @@ class GatedDeltaNetMixer(nn.Module):
         return _dense(self.d_model, self.dtype, "out")(
             o.astype(self.dtype).reshape(B, T, H * dv)
         )
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 state-space layer (module docstring), one group:
+    ``num_heads`` heads of ``head_dim`` channels over a state of
+    ``state_dim``."""
+
+    num_heads: int
+    head_dim: int
+    state_dim: int
+    d_model: int
+    conv_size: int = 4
+    norm_eps: float = 1e-5
+    chunk: int = 256  # the scan's, not the model's (ops/ssm.py)
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, P, N, K = self.num_heads, self.head_dim, self.state_dim, self.conv_size
+        inner, mixed = H * P, H * P + 2 * N
+        zxbcdt = _dense(inner + mixed + H, self.dtype, "in_proj")(x)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + mixed], axis=-1)
+        # torch's Conv1d default, weight and bias: uniform(+-1/sqrt(fan_in)),
+        # fan_in K for a depth-wise convolution.
+        uniform = lambda shape: lambda rng: jax.random.uniform(
+            rng, shape, jnp.float32, -(K**-0.5), K**-0.5
+        )
+        taps = self.param("conv", uniform((K, mixed)))
+        bias = self.param("conv_bias", uniform((mixed,)))
+        xbc = jax.nn.silu(causal_depthwise_conv(xbc, taps) + bias.astype(self.dtype))
+        xs, b, c = jnp.split(xbc, [inner, inner + N], axis=-1)
+
+        a_log = self.param("A_log", _a_log_init(H))
+        dt_bias = self.param("dt_bias", _dt_bias_init(H))
+        d_skip = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+
+        y = ssmlib.chunked_ssd(
+            xs.reshape(B, T, H, P), dt, a_log, b, c, d_skip, chunk=self.chunk
+        ).reshape(B, T, inner)
+
+        # The gate first, then one norm over all the channels.
+        y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(y)
+        return _dense(self.d_model, self.dtype, "out_proj")(y.astype(self.dtype))
 
 
 class LatentAttention(nn.Module):

@@ -21,10 +21,11 @@ every other block, or softmax-then-top-k routing over gated experts in
 every block (:func:`...parallel.moe.topk_moe_ffn`; OLMoE, ROADMAP R1).
 These are a model's published settings, not tuning options.
 
-The layers of a stack may differ (Kimi Linear and Olmo-Hybrid:
-``harness/config.py::kimi_linear``, ``olmo_hybrid``): ``layer_mixers``
-names each layer's token mixer (full attention, or one of the two
-delta-rule linear attentions or the latent attention of :mod:`.mixers`;
+The layers of a stack may differ (Kimi Linear, Olmo-Hybrid and Granite
+4.0-H: ``harness/config.py::kimi_linear``, ``olmo_hybrid``,
+``granite_h_micro``): ``layer_mixers`` names each layer's token mixer
+(full attention, or one of the two delta-rule linear attentions, the
+latent attention or the Mamba-2 state-space layer of :mod:`.mixers`;
 under ``pos_encoding="rope"`` only the full-attention layers rotate, the
 others take no positions), ``norm_placement`` puts each norm before its
 sub-layer (pre-norm) or on its output inside the residual branch (the
@@ -35,6 +36,12 @@ feed-forward (gated SiLU, ``dense_d_ff`` wide) before the expert layers
 start, an expert layer may have shared experts beside the routed ones,
 sigmoid scores renormalised and scaled, and hold a range of the router's
 experts only (``moe_held``: one chip's share of an expert-parallel job).
+Granite's four scalars (``embedding_multiplier`` on the embedding,
+``residual_multiplier`` on each sub-layer's output before it joins the
+residual, ``attention_multiplier`` for the scores' scale,
+``logits_scaling`` dividing the logits) and ``tie_embeddings`` (the head
+is the embedding matrix: no ``head`` parameter) default to a model
+without them, whose traced program they leave as it was.
 
 TPU notes: bf16 compute with fp32 LayerNorm and logits; attention and MLP
 matmuls are [B·T, d]-shaped for the MXU; causal masking is positional (no
@@ -104,6 +111,8 @@ class SelfAttention(nn.Module):
     # program that holds ``num_heads`` of a layer's heads keeps the
     # published head size, and its ``out`` projection gives a partial sum.
     head_dim: int = 0
+    # The scores' scale where it is not ``head size ** -0.5`` (None).
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -159,14 +168,14 @@ class SelfAttention(nn.Module):
             # are all at positions > the last query row.
             out = attnlib.reference_attention(
                 q, ck.value, cv.value, causal=True, q_offset=idx,
-                window=self.attn_window,
+                window=self.attn_window, scale=self.scale,
             )
         elif self.attention_fn is not None:
             out = self.attention_fn(q, k, v, causal=True)
         else:
             out = attnlib.attention(
                 q, k, v, causal=True, impl=self.attn_impl,
-                window=self.attn_window,
+                window=self.attn_window, scale=self.scale,
             )
         out = out.reshape(B, T, H * Dh)
         out = dense("out", self.d_model)(out)
@@ -389,11 +398,15 @@ class Block(nn.Module):
     moe_routing: Any = None
     moe_held: Any = None
     moe_shared_experts: int = 0
-    # The token mixer: "attention" (SelfAttention), "kda", "gdn" or "mla"
-    # (models/mixers.py; ``mixer_kwargs`` are that module's sizes).
+    # The token mixer: "attention" (SelfAttention), "kda", "gdn", "mla" or
+    # "ssm" (models/mixers.py; ``mixer_kwargs`` are that module's sizes).
     mixer: str = "attention"
     mixer_kwargs: Any = None
     head_dim: int = 0
+    # The attention scores' scale (None: head size ** -0.5) and what each
+    # sub-layer's output is multiplied by before it joins the residual.
+    attn_scale: Optional[float] = None
+    residual_multiplier: float = 1.0
     # "pre": ``x + f(norm(x))``; "post": ``x + norm(f(x))`` (OLMo 2's
     # block, arXiv:2501.00656: the norm on the sub-layer's output, inside
     # the residual branch).
@@ -414,6 +427,11 @@ class Block(nn.Module):
             out = kind(
                 d_model=self.d_model, norm_eps=self.norm_eps or 1e-6,
                 dtype=self.dtype, name=mixers.LINEAR_ATTN_SCOPE, **sizes,
+            )(h)
+        elif self.mixer == "ssm":
+            out = mixers.Mamba2Mixer(
+                d_model=self.d_model, norm_eps=self.norm_eps or 1e-6,
+                dtype=self.dtype, name=mixers.SSM_SCOPE, **sizes,
             )(h)
         else:
             out = mixers.LatentAttention(
@@ -436,6 +454,11 @@ class Block(nn.Module):
         else:
             mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
             feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
+        if self.residual_multiplier != 1.0:
+            branch = lambda half: lambda mdl, y: (
+                self.residual_multiplier * half(mdl, y)
+            )
+            mix, feed = branch(mix), branch(feed)
         if self.remat:
             mix, feed = nn.remat(mix), nn.remat(feed)
         x = x + mix(self, x)
@@ -496,6 +519,7 @@ class Block(nn.Module):
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
             head_dim=self.head_dim,
+            scale=self.attn_scale,
             name="attn",
         )(h, train=train)
 
@@ -761,8 +785,9 @@ class TransformerLM(nn.Module):
     # expert's ``d_ff`` (0: the same).
     mlp: str = "gelu"
     dense_d_ff: int = 0
-    # Each layer's token mixer, "attention" | "kda" | "gdn" | "mla" (None:
-    # full attention everywhere), and the sizes models/mixers.py takes.
+    # Each layer's token mixer, "attention" | "kda" | "gdn" | "mla" | "ssm"
+    # (None: full attention everywhere), and the sizes models/mixers.py
+    # takes.
     layer_mixers: Any = None
     kda_num_heads: int = 0  # 0: num_heads
     kda_head_dim: int = 128
@@ -775,6 +800,22 @@ class TransformerLM(nn.Module):
     mla_nope_dim: int = 128
     mla_rope_dim: int = 64
     mla_v_dim: int = 128
+    ssm_num_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state_dim: int = 128
+    ssm_conv_size: int = 4
+    ssm_chunk: int = 256  # the scan's chunk: the program's, not the model's
+    # Granite's four scalars: the embedding times ``embedding_multiplier``,
+    # each sub-layer's output times ``residual_multiplier`` before it
+    # joins the residual, attention scores times ``attention_multiplier``
+    # (None: head size ** -0.5), logits over ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    # The head is the embedding matrix (no ``head`` parameter, no bias):
+    # ``logits = E norm(h)``.
+    tie_embeddings: bool = False
 
     def _mixers(self) -> tuple:
         return tuple(self.layer_mixers or ("attention",) * self.num_layers)
@@ -791,7 +832,7 @@ class TransformerLM(nn.Module):
             ("moe_scoring", self.moe_scoring, ("softmax", "sigmoid")),
             ("mlp", self.mlp, ("gelu", "gated_silu")),
             *(
-                (f"layer_mixers[{i}]", m, ("attention", "kda", "gdn", "mla"))
+                (f"layer_mixers[{i}]", m, ("attention", "kda", "gdn", "mla", "ssm"))
                 for i, m in enumerate(self._mixers())
             ),
         ):
@@ -805,10 +846,12 @@ class TransformerLM(nn.Module):
         plain = set(self._mixers()) == {"attention"}
         if not plain and (self.decode or self.attention_fn is not None):
             raise ValueError(
-                "the kda, gdn and mla mixers neither decode nor take a "
-                "sequence-parallel attention_fn: the recurrent states and "
-                "the latent cache have no place in serving/kv_slots.py yet "
-                "(ROADMAP Queue 2)"
+                "the kda, gdn, mla and ssm mixers neither decode nor take a "
+                "sequence-parallel attention_fn: the recurrent states (a "
+                "state-space layer's 128 x 64 a head with its convolution's "
+                "3-token tail of 4352 channels among them) and the latent "
+                "cache have no place in serving/kv_slots.py yet (ROADMAP "
+                "Queue 2)"
             )
         if self.decode and self.norm_placement != "pre":
             raise ValueError(
@@ -827,18 +870,29 @@ class TransformerLM(nn.Module):
             and self.pos_encoding != "none"
             and self.norm_placement == "pre"
             and not self.head_dim
+            and not self.tie_embeddings
+            and (
+                self.embedding_multiplier, self.residual_multiplier,
+                self.attention_multiplier, self.logits_scaling,
+            ) == (1.0, 1.0, None, 1.0)
         )
         if (self.pipelined or self.pipe_mesh is not None) and not gpt2_block:
             raise ValueError(
                 "the pipelined block stack is the GPT-2 block only "
                 "(pre-LayerNorm, biases, GELU MLP): norm/norm_eps/"
-                "norm_placement/use_bias/qk_norm/head_dim and experts are "
+                "norm_placement/use_bias/qk_norm/head_dim, the four "
+                "multipliers, a tied head and experts are "
                 "not plumbed into the stacked layout (ROADMAP D3)"
             )
         if self.decode and self.num_experts and self.moe_router != "topk":
             raise ValueError(
                 "decode mode does not run Switch experts (capacity is "
                 "counted per training batch); top-k experts decode"
+            )
+        if self.tie_embeddings and self.use_bias:
+            raise ValueError(
+                "tie_embeddings shares the embedding matrix with the head, "
+                "which then has no bias: set use_bias=False"
             )
 
     @nn.compact
@@ -850,7 +904,9 @@ class TransformerLM(nn.Module):
         instead of logits, for the fused chunked unembed+xent loss
         (:func:`...ops.losses.fused_unembed_mean_xent`) — the head parameters
         still exist (init uses the default path) and the loss consumes
-        them directly from ``params``."""
+        them directly from ``params`` (under ``tie_embeddings`` the
+        embedding matrix, there is no ``head``); ``logits_scaling`` is
+        already in the hidden states."""
         self._check_settings()
         B, T = tokens.shape
         # TokenEmbed == nn.Embed (same param path/init/dtype promotion)
@@ -858,12 +914,15 @@ class TransformerLM(nn.Module):
         # swaps the gather's scatter-add gradient for the chunked
         # one-hot matmul (ops/embed.py) — the A/B the transformer_parts
         # frozen_embed ablation motivates.
-        x = TokenEmbed(
+        embed = TokenEmbed(
             self.vocab_size,
             self.d_model,
             dtype=self.dtype,
             name="embedding",
-        )(tokens)
+        )
+        x = embed(tokens)
+        if self.embedding_multiplier != 1.0:
+            x = self.embedding_multiplier * x
         if self.pos_encoding in ("rope", "none"):
             # Relative positions enter inside attention (q/k rotation), or
             # nowhere; no absolute table.  Decode still tracks pos_index: the
@@ -967,6 +1026,13 @@ class TransformerLM(nn.Module):
                     ("rope_dim", self.mla_rope_dim),
                     ("v_dim", self.mla_v_dim),
                 ),
+                "ssm": (
+                    ("num_heads", self.ssm_num_heads),
+                    ("head_dim", self.ssm_head_dim),
+                    ("state_dim", self.ssm_state_dim),
+                    ("conv_size", self.ssm_conv_size),
+                    ("chunk", self.ssm_chunk),
+                ),
             }
             for i, mixer in enumerate(self._mixers()):
                 use_moe = self.num_experts > 0 and (
@@ -1006,14 +1072,22 @@ class TransformerLM(nn.Module):
                     mixer=mixer,
                     mixer_kwargs=mixer_kwargs.get(mixer),
                     head_dim=self.head_dim,
+                    attn_scale=self.attention_multiplier,
+                    residual_multiplier=self.residual_multiplier,
                     norm_placement=self.norm_placement,
                     mlp=self.mlp,
                     remat=self.remat,
                     name=f"blocks_{i}",
                 )(x, train)
         x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
+        if self.logits_scaling != 1.0:
+            # Folded into the hidden states, so that the fused head sees
+            # it too (a power of two, Granite's 8, is exact in any dtype).
+            x = x / self.logits_scaling
         if return_hidden:
             return x, carry
+        if self.tie_embeddings:
+            return embed.attend(x), carry
         logits = nn.Dense(
             self.vocab_size,
             dtype=jnp.float32,

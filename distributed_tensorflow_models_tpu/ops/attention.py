@@ -14,12 +14,12 @@ two from what a call shows (:func:`auto_route`: backend, shapes, mesh):
   (max, sum, acc) renormalization (Rabe & Staats / FlashAttention
   recurrence).  O(T·block) memory, differentiable end-to-end (scan is
   reverse-AD-able), runs on any backend: the CPU, and on the chip every
-  call the fused kernels do not take (odd lengths, sliding windows,
-  grouped KV heads, a ``jit`` over several devices outside ``shard_map``).
+  call the fused kernels do not take (odd lengths, sliding windows, a
+  ``jit`` over several devices outside ``shard_map``).
 - :func:`fused_attention` — that recurrence as two kernels shaped by a
   chip measurement (PERF.md section 6, PR 26): lane-filling column blocks
   of ``[B, T, H*D]``, only the block pairs a causal mask leaves, one
-  backward kernel.  What ``auto`` runs on a TPU.
+  backward kernel.  What ``auto`` runs on a TPU (grouped KV heads repeated).
 
 Plus one: :func:`flash_attention_chunk`, an older Pallas kernel pair (a
 forward that also emits the log-sum-exp, a FlashAttention-2 dK/dV + dQ
@@ -962,25 +962,25 @@ def fused_admissible(
     q, k, v, *, window: Optional[int] = None,
     q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
 ) -> bool:
-    """Whether the fused kernels take this call: self-attention shapes
-    (no grouped KV heads), head size 64 (an even number of heads) or 128,
-    or 128 value channels under query/key channels of another width
-    (latent attention; ``attention`` pads those to whole lane blocks), a
-    length some tile divides, no sliding window, and offsets that are
-    the static zeros of self-attention.  Everything here is visible at
-    trace time; the backend is the caller's question."""
+    """Whether the fused route takes this call: self-attention shapes
+    (grouped KV heads too: ``attention`` repeats them over their groups
+    before the kernels, which want equal head counts), head size 64 (an
+    even number of heads) or 128, or 128 value channels under another
+    query/key width (latent attention; ``attention`` pads those), a length
+    some tile divides, no sliding window, static zero offsets.  All of it
+    is visible at trace time; the backend is the caller's question."""
     if window is not None:
         return False
     if not (isinstance(q_offset, int) and isinstance(kv_offset, int)):
         return False
     if q_offset != 0 or kv_offset != 0:
         return False
+    B, T, H, D = q.shape
     if not (
-        q.shape == k.shape and q.shape[:3] == v.shape[:3]
-        and q.dtype == k.dtype == v.dtype
+        k.shape == (B, T, k.shape[2], D) and H % k.shape[2] == 0
+        and k.shape[:3] == v.shape[:3] and q.dtype == k.dtype == v.dtype
     ):
         return False
-    B, T, H, D = q.shape
     if D != v.shape[-1]:
         if v.shape[-1] != _LANES:
             return False
@@ -1421,10 +1421,11 @@ def attention(
     TPU, a call that :func:`fused_admissible` admits (self-attention
     shapes, head size 64 or 128, a length 128 divides, no window) runs
     the fused kernels (:func:`fused_attention`; measured on the chip,
-    PERF.md PR 26); every other call (the CPU, odd lengths, a sliding
-    window, grouped KV heads, and a ``jit`` over several devices outside
-    ``shard_map``, where a Mosaic kernel cannot be partitioned) runs
-    :func:`blockwise_attention`.  The choice is counted once per traced
+    PERF.md PR 26), grouped key/value heads repeated over their groups
+    first; every other call (the CPU, odd lengths, a sliding window, and
+    a ``jit`` over several devices outside ``shard_map``, where a Mosaic
+    kernel cannot be partitioned) runs :func:`blockwise_attention`.  The
+    choice is counted once per traced
     call (``attention/route_fused`` / ``attention/route_blockwise``).  A
     named ``impl`` means what it says."""
     if impl == "auto":
@@ -1433,6 +1434,7 @@ def attention(
             ATTN_ROUTE_FUSED if impl == "fused" else ATTN_ROUTE_BLOCKWISE
         ).inc()
         if impl == "fused":
+            k, v = _expand_kv(q, k, v)
             if q.shape[-1] != v.shape[-1]:
                 # Latent attention: zero channels up to whole lane blocks.
                 scale = _scale(q, scale)

@@ -145,3 +145,13 @@ class TokenEmbed(nn.Module):
         return embed_lookup(
             table.astype(self.dtype), tokens, impl
         )
+
+    def attend(self, hidden: jax.Array) -> jax.Array:
+        """``hidden @ table^T`` in float32: the logits of a head tied to
+        this embedding (``nn.Embed.attend``, at the precision the
+        untied ``nn.Dense(dtype=float32)`` head has)."""
+        table = self.get_variable("params", "embedding")
+        return jnp.einsum(
+            "...d,vd->...v", hidden.astype(jnp.float32),
+            table.astype(jnp.float32),
+        )

@@ -95,6 +95,9 @@ KDA_MIXER_PLAIN = "kda/mixer_plain"  # counter
 # Traced calls of ``ops/linear_attention.py::chunked_gdn`` (one decay a
 # head), which has the plain route alone.
 GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter
+# Traced calls of ``ops/ssm.py::chunked_ssd`` (Mamba-2's state-space dual
+# scan), which has the plain route alone.
+SSD_ROUTE_PLAIN = "ssd/route_plain"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -802,7 +802,8 @@ def _route_counts():
         ("tpu", 1, (2, 200, 4, 64), 4, None, False, "blockwise"),  # 128 does not divide
         ("tpu", 1, (2, 256, 4, 64), 4, None, True, "blockwise"),  # traced offsets
         ("tpu", 1, (2, 256, 4, 64), 4, 64, False, "blockwise"),  # sliding window
-        ("tpu", 1, (2, 256, 4, 64), 2, None, False, "blockwise"),  # grouped KV heads
+        # Grouped KV heads: repeated over their groups into the kernels (PR 38).
+        ("tpu", 1, (2, 256, 4, 64), 2, None, False, "fused"),
         ("tpu", 1, (2, 256, 3, 64), 3, None, False, "blockwise"),  # half a lane block
         ("tpu", 1, (2, 256, 4, 32), 4, None, False, "blockwise"),  # head size
         # A jit over several devices cannot partition a Mosaic kernel.

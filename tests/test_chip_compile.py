@@ -10,8 +10,9 @@ data-parallel step over four devices compiling on every later PR.
 Nothing runs and nothing here is a measurement.  Only the fast compiles
 are kept (about a second or two each); the whole ResNet-50 b256 step
 (~40 s) and the ``conv2d_mxu`` gradient at 56x56x64 (~18 s) stay in the
-builder's rehearsal.  One whole step is here all the same, ISSUE 32's:
-``olmo_hybrid_train``'s (45 s), because that cell's batch was chosen by
+builder's rehearsal.  Two whole steps are here all the same, ISSUE 32's
+and ISSUE 38's: ``olmo_hybrid_train``'s (45 s) and ``granite_h_train``'s
+(50 s), because those cells' batch and the scan's chunk were chosen by
 what the compiler places, and a later PR's temporary could undo it.  The persistent cache is switched off around the
 cases: an executable compiled for a described chip is written to it but
 cannot be read back without one, and the next run would warn.
@@ -36,6 +37,7 @@ from distributed_tensorflow_models_tpu.models import get_model
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.ops import optim
+from distributed_tensorflow_models_tpu.ops import ssm as ssmlib
 from distributed_tensorflow_models_tpu.ops.conv_mxu import conv2d_mxu
 
 
@@ -504,4 +506,130 @@ def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
     assert text.count("tpu_custom_call") >= 3
     assert not re.search(r"\.remat\d*", text)
     for scope in ("linear_attn", "gdn_core", "attention_core", "unembed_loss", "optimizer"):
+        assert re.search(rf"[/(]{scope}[/)]", text), scope
+
+
+# ``granite_h_train``'s call of the state-space scan: one sequence of 8,192
+# tokens, 64 heads of 64 over a state of 128, ``B`` and ``C`` one vector
+# for all the heads, ``dt`` one number a head.
+_SSD_SHAPES = [(1, 8192, 64, 64), (1, 8192, 64), (64,), (1, 8192, 128), (1, 8192, 128), (64,)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32_highest"])
+def test_chunked_ssd_compiles_for_v5e(v5e, monkeypatch, dtype):
+    """``chunked_ssd`` forward and backward at the cell's shape and chunk,
+    as the cell runs it (bf16) and as the comparison with the reference
+    runs the float32 program (under ``default_matmul_precision("highest")``):
+    whatever route it takes has to lower, with the ``ssd_core`` scope on
+    its instructions, and hold a state per chunk and never one per token."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    wide = (0, 3, 4)  # x, B, C in the model's dtype; dt, A_log, D in float32
+    args = [
+        jax.ShapeDtypeStruct(s, dtype if i in wide else jnp.float32, sharding=one_chip)
+        for i, s in enumerate(_SSD_SHAPES)
+    ]
+
+    def fwd_bwd(*x):
+        loss = lambda *x: jnp.sum(ssmlib.chunked_ssd(*x, chunk=256).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5))(*x)
+
+    if dtype == jnp.float32:
+        with jax.default_matmul_precision("highest"):
+            compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    else:
+        compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"[/(]ssd_core[/)]", text) and "gdn_core" not in text
+    assert "tpu_custom_call" not in text  # the plain route alone, today
+    # A state per token would be 17 GB (8192 x 64 x 128 x 64 float32); a
+    # state per chunk of 256 is 67 MB a copy: 0.63 GiB in all in bf16.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0 * 2**30
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_grouped_attention_compiles_fused_for_v5e(v5e, monkeypatch, dtype):
+    """``attention(impl="auto")`` at ``granite_h_train``'s shape, 32 query
+    heads of 64 over 8 key/value heads at 8,192 positions and Granite's
+    scale: the two fused kernels (keys and values repeated over their
+    groups outside them; their gradients come back at 8 heads), under the
+    ``attention_core`` scope, and no ``while`` left of the blockwise scan."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda heads: jax.ShapeDtypeStruct((1, 8192, heads, 64), dtype, sharding=one_chip)
+    assert attnlib.auto_route(spec(32), spec(8), spec(8)) == "fused"
+
+    def loss(q, k, v):
+        out = attnlib.attention(q, k, v, causal=True, scale=0.015625)
+        return jnp.sum(out.astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.lower(spec(32), spec(8), spec(8)).compile().as_text()
+    kernels = [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and "pallas_call" in line
+    ]
+    assert len(kernels) == 2
+    assert all(re.search(r"[/(]attention_core[/)]", line) for line in kernels)
+    assert sum("transpose(" in line for line in kernels) == 1
+    assert not re.search(r"\bwhile\(", text)
+    dq, dk, dv = jax.eval_shape(grad, spec(32), spec(8), spec(8))
+    assert dq.shape == (1, 8192, 32, 64) and dk.shape == dv.shape == (1, 8192, 8, 64)
+
+
+def test_granite_h_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
+    """The whole ``granite_h_train`` step (the cell's configuration through
+    ``benchmark/lib/cells.py``, Adam with the clip, the fused head fed from
+    the tied embedding, per-half recomputation, one sequence of 8,192, the
+    scan's chunk of 256) for one described v5e: it fits the chip's 15.75
+    GiB with room (12.13 GiB when the cell was added: 8.63 of state, 3.25
+    of temporaries; at a chunk of 64 it did not fit without 254
+    rematerialized clones; PERF.md, PR 38), the compiler rematerializes
+    nothing of its own, the attention layer runs the fused kernels over
+    its grouped heads and the scopes are on the step."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark.lib import cells
+
+    from distributed_tensorflow_models_tpu.harness import train as trainlib
+    from distributed_tensorflow_models_tpu.harness.config import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cell = cells.load_cell("granite_h_train")
+    per_chip = cell.traffic["fit"]["per_chip_batch"]
+    cfg = get_config(
+        cell.config["program_config"], **cell.config["overrides"], global_batch_size=per_chip
+    )
+    assert (per_chip, cfg.num_steps, cfg.fused_unembed) == (1, 8192, True)
+    model = get_model(cfg.model, **cfg.model_kwargs)
+    state = jax.eval_shape(
+        lambda: TrainState.create(
+            model, cfg.optimizer.make(), jax.random.key(0),
+            jnp.zeros((2, 128), jnp.int32), jit_init=False,
+        )
+    )
+    assert "head" not in state.params  # tied
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    step = train_loop.make_train_step(trainlib.build_loss(cfg, state), donate=True)
+    tokens = jax.ShapeDtypeStruct((per_chip, cfg.num_steps), jnp.int32, sharding=one_chip)
+    compiled = step.lower(
+        jax.tree.map(spec, state), {"inputs": tokens, "targets": tokens},
+        spec(jax.eval_shape(lambda: jax.random.key(0))),
+    ).compile()
+    m = compiled.memory_analysis()
+    held = (
+        m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
+        - m.alias_size_in_bytes + m.generated_code_size_in_bytes
+    )
+    assert 11.0 * 2**30 < held < 13.5 * 2**30, held / 2**30
+    text = compiled.as_text()
+    # The attention layer: forward, the recomputed forward, the backward.
+    assert text.count("tpu_custom_call") >= 3
+    assert not re.search(r"\.remat\d*", text)
+    for scope in ("ssm", "ssd_core", "attention_core", "unembed_loss", "optimizer"):
         assert re.search(rf"[/(]{scope}[/)]", text), scope

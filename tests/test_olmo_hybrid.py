@@ -431,7 +431,7 @@ def test_only_the_attention_layers_rotate():
 @pytest.mark.parametrize(
     "kwargs,match",
     [
-        ({"decode": True}, "kda, gdn and mla mixers neither decode"),
+        ({"decode": True}, "kda, gdn, mla and ssm mixers neither decode"),
         ({"decode": True, "layer_mixers": None}, "norm_placement='post' does not decode"),
         ({"norm_placement": "sandwich"}, "unknown norm_placement"),
         ({"layer_mixers": ("gdn", "gdn", "gla", "attention")}, "unknown layer_mixers"),
