@@ -1353,7 +1353,7 @@ def _trace_counts() -> dict[str, float]:
             telemetry.KDA_MIXER_FUSED,
             telemetry.KDA_MIXER_PLAIN,
             telemetry.GDN_ROUTE_PLAIN,
-            telemetry.SSD_ROUTE_PLAIN,
+            telemetry.SSD_ROUTE_KERNEL, telemetry.SSD_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
         )
     }

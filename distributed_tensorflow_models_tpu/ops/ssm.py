@@ -43,29 +43,49 @@ accumulations are float32.  The matrix products take their operands in
 the dtype of ``x`` (bfloat16 in a bf16 model, float32 otherwise) and
 accumulate in float32.
 
-One route, :func:`plain_ssd`, in ``jax.numpy`` on every backend, counted
-once per traced call (``ssd/route_plain``); whoever writes the kernel
-adds the route function and ``ssd/route_kernel``, as ``kda_route`` has.
-The chunk-wise body is bound under ``jax.jit``, so a stack's identical
-layers trace and lower it once.  The backward pass is autodiff through
-all of it; what it keeps is per chunk (the state at each chunk's start),
-never a state per token.
+Two routes, chosen by :func:`ssd_route` from what a call shows (backend,
+shapes, dtype) and counted once per traced call (``ssd/route_kernel``,
+``ssd/route_plain``), as ``kda_route`` does it.  On a TPU, for heads that
+fill whole lane tiles, :func:`kernel_ssd`: Pallas (Mosaic) kernels, forward
+and backward under one ``custom_vjp``, that keep ``C B^T``, every head's
+mask and the carried state in VMEM (the section comment above them).
+Everywhere else :func:`plain_ssd`, ``jax.numpy``, whose backward pass is
+autodiff through all of it.  Both are bound under ``jax.jit``, so a
+stack's identical layers trace and lower one body, and both keep for the
+backward pass what is per chunk (the state at each chunk's start), never
+a state per token.
 
 A module of its own beside :mod:`.linear_attention`: it shares that
-module's chunking (``_in_chunks``) and the scalar decay's mask, and
-nothing of the delta rule (``T``, ``U``, the pair loop, the kernels).
+module's chunking (``_in_chunks``), the scalar decay's mask and the
+kernels' product helper (``_mm``), and nothing of the delta rule (``T``,
+``U``, the pair loop, its kernels).
 """
 
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from distributed_tensorflow_models_tpu.ops.linear_attention import _in_chunks
+from distributed_tensorflow_models_tpu.ops.attention import (
+    _LANES,
+    _vma,
+    mosaic_can_lower,
+)
+from distributed_tensorflow_models_tpu.ops.linear_attention import (
+    _NT,
+    _TN,
+    _in_chunks,
+    _mm,
+    _padded,
+)
 from distributed_tensorflow_models_tpu.telemetry.registry import (
+    SSD_ROUTE_KERNEL,
     SSD_ROUTE_PLAIN,
     get_registry,
 )
@@ -150,16 +170,525 @@ def plain_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
     return y.astype(dtype)
 
 
+# --- The chunk-wise form as Pallas (Mosaic) kernels -------------------------
+#
+# What :func:`plain_ssd` computes, with nothing a token or a head wide ever
+# in HBM but the inputs, the output and the state at each chunk's start:
+# ``C B^T``, every head's mask, the masked scores and the carried state
+# live in VMEM.  Both kernels read the flat ``[B, T, H * P]`` view that the
+# mixer's projection writes (a head is ``P`` lanes; ``[B, T, H, P]`` on the
+# chip's ``(8, 128)`` tiles would be a relayout on either side: PERF.md, PR
+# 33) and walk a grid (batch, chunk, group of heads), the groups innermost:
+# a chunk's ``B`` and ``C`` tiles stay resident while its groups go by, and
+# the first group's step makes ``C B^T`` once for all of them.  A step
+# takes the group's decays ``[L, heads]`` (``dt`` is handed over group by
+# group, so a head's column is a static lane), makes ``G`` as a column and
+# as a row a head by two small products with a matrix of ones (float32
+# exactly: the three bfloat16 pieces of ``g``), and then, 128 lanes of
+# ``x`` at a time: the read of the carried state for those lanes, each
+# head's mask and scores and their product with the head's lanes of ``v``
+# (128 rows of the ``[L, L]`` mask at a time, up to their diagonal: what
+# lies above it is never made; the other heads' lanes of ``v`` zeroed: the
+# 128-wide output costs the MXU what a 64-wide one would), the ``D`` skip,
+# and the lanes' part of the state's update.  The state of every head is scratch ``[groups, N,
+# lanes]`` float32 (2 MB at Granite's sizes), carried from chunk to chunk.
+#
+# The backward walks the chunks in reverse with the state's cotangent in
+# that scratch and makes ``C B^T``, ``G`` and the masks again.  With ``W =
+# C B^T . M`` a head's scores, ``dW = dy v^T``: ``dv = W^T dy``, the
+# cotangent of ``C B^T`` is the sum over heads of ``dW . M`` (a value of
+# the step, then scratch across the groups; ``dB`` and ``dC`` take it
+# through two products when the last group is done, and the state's part
+# of them sums over the lanes inside the products), and ``dG_t`` is the
+# row sum less the column sum of ``dW . W`` plus what the three factors
+# ``e^{G_t}``, ``e^{G_L - G_s}`` and ``e^{G_L}`` owe; ``dg`` is the reverse
+# running sum of ``dG`` inside the chunk, again by products with ones (the
+# column sums arrive as rows and go through the transposed product), and
+# ``ddt = -e^{A_log} dg + x . dv``.  The sums a step owes ``A_log`` and
+# ``D`` leave as one small row a step and are added up outside.  No
+# exponent taken is positive here either; precision is the plain route's
+# (the cotangents ``dy`` and ``dS`` enter a product in the dtype of ``x``,
+# as XLA's default precision has them on the plain route).
+
+_KERNEL_CHUNKS = (256, 512)
+_KERNEL_HEAD_DIMS = (64, 128)
+_KERNEL_HEADS = (16, 8, 4, 2)  # heads a grid step, the most that divides
+_KERNEL_VMEM_BYTES = 64 * 1024 * 1024  # of v5e's 128 MiB
+
+
+def _kernel_heads(H: int, P: int):
+    """Heads a grid step: whole 128-lane tiles of ``x``, or None."""
+    return next(
+        (m for m in _KERNEL_HEADS if H % m == 0 and (m * P) % _LANES == 0), None
+    )
+
+
+def kernel_admissible(x, b, c, *, chunk: int) -> bool:
+    """Whether the kernels take this call: heads of 64 or 128 channels
+    that fill whole 128-lane tiles side by side, a state of whole lane
+    tiles, a chunk of 256 or 512, one dtype for ``x``, ``B`` and ``C``.
+    Visible at trace time; the backend is the caller's question."""
+    H, P = x.shape[2:]
+    return (
+        x.dtype == b.dtype == c.dtype
+        and P in _KERNEL_HEAD_DIMS
+        and _kernel_heads(H, P) is not None
+        and b.shape[-1] % _LANES == 0
+        and chunk in _KERNEL_CHUNKS
+    )
+
+
+def _pieces(x):
+    """The three bfloat16 pieces of float32 ``x``: they add up to it
+    exactly, so a product of theirs with a matrix of zeros and ones,
+    summed in float32, is the float32 product at half the passes."""
+    out = []
+    for _ in range(3):
+        out.append(x.astype(jnp.bfloat16))
+        x = x - out[-1].astype(_F32)
+    return out
+
+
+def _step_terms(dt_ref, a_ref):
+    """What the heads of a grid step share: ``dt`` and ``g`` ``[L,
+    heads]``, the running sum ``G`` as columns ``[L, heads]`` and as rows
+    ``[heads, L]``, the two triangles of ones."""
+    dt = dt_ref[0, 0].astype(_F32)
+    L = dt.shape[0]
+    rate = jnp.exp(a_ref[0].astype(_F32))  # [1, heads]
+    g = -rate * dt
+    row = lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    lower, upper = col <= row, row <= col
+    ones_l, ones_u = lower.astype(jnp.bfloat16), upper.astype(jnp.bfloat16)
+    parts = _pieces(g)
+    G = sum(_mm(ones_l, p) for p in parts)
+    Gt = sum(_mm(p, ones_u, _TN) for p in parts)
+    return types.SimpleNamespace(
+        dt=dt, g=g, rate=rate, G=G, Gt=Gt, lower=lower, ones_u=ones_u
+    )
+
+
+def _spread(cols, first: int, P: int):
+    """Columns ``first`` on of ``cols`` ``[L, heads]``, each over its
+    head's ``P`` lanes of a ``[L, 128]`` tile."""
+    L = cols.shape[0]
+    k = _LANES // P
+    out = jnp.broadcast_to(cols[:, first + k - 1:first + k], (L, _LANES))
+    lane = lax.broadcasted_iota(jnp.int32, (L, _LANES), 1)
+    for i in range(k - 2, -1, -1):
+        out = jnp.where(lane < (i + 1) * P, cols[:, first + i:first + i + 1], out)
+    return out
+
+
+def _own_lanes(y, i: int, P: int):
+    """``y`` ``[L, 128]`` with every lane but head ``i``'s of the tile
+    zeroed."""
+    if P == _LANES:
+        return y
+    lane = lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    return jnp.where((lane >= i * P) & (lane < (i + 1) * P), y, jnp.zeros_like(y))
+
+
+def _tile_terms(x_ref, s, j: int, P: int):
+    """Tile ``j`` (128 lanes) of a step's ``x``: ``v = dt x`` rounded, and
+    the three decays a token of each head's lanes."""
+    dtype = x_ref.dtype
+    lanes = slice(j * _LANES, (j + 1) * _LANES)
+    first = j * (_LANES // P)
+    x32 = x_ref[0, :, lanes].astype(_F32)
+    dtx = _spread(s.dt, first, P)
+    Gx = _spread(s.G, first, P)
+    v = (dtx * x32).astype(dtype)
+    G_end = Gx[Gx.shape[0] - 1:, :]
+    to_end = jnp.exp(G_end - Gx)
+    v_end32 = to_end * v.astype(_F32)
+    return types.SimpleNamespace(
+        lanes=lanes, first=first, x32=x32, dtx=dtx, v=v, from_start=jnp.exp(Gx),
+        decay=jnp.exp(G_end), to_end=to_end, v_end32=v_end32,
+        v_end=v_end32.astype(dtype),
+    )
+
+
+def _row_blocks(L: int):
+    """``(rows, cols)`` of the mask's blocks of 128 rows: what is not
+    above the diagonal, so that a quarter of a chunk of 256 (three eighths
+    at 512) is never made."""
+    return [
+        (slice(r, r + _LANES), slice(0, r + _LANES)) for r in range(0, L, _LANES)
+    ]
+
+
+def _head_mask(s, a: int, rows, cols):
+    """``e^{G_t - G_s}`` for ``s <= t``, 0 above: rows ``rows`` of head
+    ``a`` of the step, over the columns ``cols`` up to their diagonal."""
+    return jnp.exp(
+        jnp.where(
+            s.lower[rows, cols], s.G[rows, a:a + 1] - s.Gt[a:a + 1, cols], -jnp.inf
+        )
+    )
+
+
+def _ssd_fwd_kernel(
+    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, *rest, head_dim, keep_states,
+):
+    """Grid (B, chunks, groups of heads).  ``S_scr`` ``[groups, N, lanes]``
+    holds every head's state, ``cb_scr`` the chunk's ``C B^T``; ``s_ref``
+    (kept for a backward pass) takes the state at the chunk's start."""
+    s_ref = rest[0] if keep_states else None
+    S_scr, cb_scr = rest[-2:]
+    h = pl.program_id(2)
+    P, dtype = head_dim, x_ref.dtype
+    b, c = b_ref[0], c_ref[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        S_scr[h] = jnp.zeros(S_scr.shape[1:], _F32)
+
+    @pl.when(h == 0)
+    def _scores():
+        cb_scr[...] = _mm(c, b, _NT)
+
+    s = _step_terms(dt_ref, a_ref)
+    cb = cb_scr[...]
+    blocks = _row_blocks(cb.shape[0])
+    if keep_states:
+        s_ref[0, 0] = S_scr[h]
+    for j in range(x_ref.shape[2] // _LANES):
+        u = _tile_terms(x_ref, s, j, P)
+        S = S_scr[h, :, u.lanes]
+        y = u.from_start * _mm(c, S.astype(dtype))
+        for i in range(_LANES // P):
+            v_own = _own_lanes(u.v, i, P)
+            y = y + jnp.concatenate(
+                [
+                    _mm(
+                        (cb[rows, cols] * _head_mask(s, u.first + i, rows, cols)).astype(dtype),
+                        v_own[cols],
+                    )
+                    for rows, cols in blocks
+                ],
+                axis=0,
+            )
+        y = y + d_ref[:, u.lanes].astype(_F32) * u.x32
+        o_ref[0, :, u.lanes] = y.astype(dtype)
+        S_scr[h, :, u.lanes] = u.decay * S + _mm(b, u.v_end, _TN)
+
+
+def _ssd_bwd_kernel(
+    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, s_ref, dy_ref,
+    dx_ref, ddt_ref, da_ref, db_ref, dc_ref, dd_ref,
+    dS_scr, cb_scr, dcb_scr, db_scr, dc_scr, dGt_scr, *, head_dim,
+):
+    """Grid (B, chunks in reverse, groups of heads).  ``dS_scr`` holds the
+    cotangent of every head's state at the end of the chunk being worked
+    on; ``dcb_scr``, ``db_scr`` and ``dc_scr`` add up over a chunk's
+    groups what the heads owe ``C B^T``, ``B`` and ``C``; ``dGt_scr``
+    ``[heads, L]`` takes the column sums a head owes ``G``, as rows."""
+    h = pl.program_id(2)
+    P, dtype = head_dim, x_ref.dtype
+    L, width = x_ref.shape[1:]
+    heads = width // P
+    b, c = b_ref[0], c_ref[0]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        dS_scr[h] = jnp.zeros(dS_scr.shape[1:], _F32)
+
+    @pl.when(h == 0)
+    def _scores():
+        cb_scr[...] = _mm(c, b, _NT)
+        dcb_scr[...] = jnp.zeros(dcb_scr.shape, _F32)
+        db_scr[...] = jnp.zeros(db_scr.shape, _F32)
+        dc_scr[...] = jnp.zeros(dc_scr.shape, _F32)
+
+    s = _step_terms(dt_ref, a_ref)
+    cb = cb_scr[...]
+    blocks = _row_blocks(L)
+    dcb = [jnp.zeros((_LANES, cols.stop), _F32) for _, cols in blocks]
+    db_acc = jnp.zeros(b.shape, _F32)
+    dc_acc = jnp.zeros(c.shape, _F32)
+    dG = jnp.zeros((L, heads), _F32)  # what G owes, a column a head
+    ddt = jnp.zeros((L, heads), _F32)  # dt's share through v = dt x
+    head_lane = lax.broadcasted_iota(jnp.int32, (L, heads), 1)
+    last_row = lax.broadcasted_iota(jnp.int32, (L, _LANES), 0) == L - 1
+    for j in range(width // _LANES):
+        u = _tile_terms(x_ref, s, j, P)
+        dy = dy_ref[0, :, u.lanes]
+        dy32 = dy.astype(_F32)
+        S0 = s_ref[0, 0, :, u.lanes]
+        S0b = S0.astype(dtype)
+        dS = dS_scr[h, :, u.lanes]
+        dSb = dS.astype(dtype)
+        # The read of the carried state, y += e^{G_t} C S0.
+        dread32 = u.from_start * dy32
+        dread = dread32.astype(dtype)
+        owed = dread32 * _mm(c, S0b)  # to G_t, lane by lane
+        dc_acc = dc_acc + _mm(dread, S0b, _NT)
+        # The chunk's write, S_L = e^{G_L} S0 + B^T (e^{G_L - G_s} v).
+        dv_end = _mm(b, dSb)
+        db_acc = db_acc + _mm(u.v_end, dSb, _NT)
+        to_end = dv_end * u.v_end32  # to G_L - G_s
+        at_end = jnp.sum(to_end, axis=0, keepdims=True) + u.decay * jnp.sum(
+            dS * S0, axis=0, keepdims=True
+        )
+        owed = owed - to_end + jnp.where(last_row, at_end, 0.0)
+        dv = u.to_end * dv_end
+        dS_scr[h, :, u.lanes] = u.decay * dS + _mm(c, dread, _TN)
+        # The heads' scores.
+        row_sums = []
+        for i in range(_LANES // P):
+            a = u.first + i
+            dy_own = _own_lanes(dy, i, P)
+            rows_owed, cols_owed = [], jnp.zeros((1, L), _F32)
+            for r, (rows, cols) in enumerate(blocks):
+                mask = _head_mask(s, a, rows, cols)
+                scores32 = cb[rows, cols] * mask
+                dscores = _mm(dy_own[rows], u.v[cols], _NT)
+                dcb[r] = dcb[r] + dscores * mask
+                both = dscores * scores32
+                rows_owed.append(jnp.sum(both, axis=1, keepdims=True))
+                cols_owed = cols_owed + jnp.pad(
+                    jnp.sum(both, axis=0, keepdims=True), ((0, 0), (0, L - cols.stop))
+                )
+                dv = dv + jnp.pad(
+                    _mm(scores32.astype(dtype), dy_own[rows], _TN),
+                    ((0, L - cols.stop), (0, 0)),
+                )
+            row_sums.append(jnp.concatenate(rows_owed, axis=0))
+            dGt_scr[a:a + 1, :] = -cols_owed
+        dx_ref[0, :, u.lanes] = (
+            u.dtx * dv + d_ref[:, u.lanes].astype(_F32) * dy32
+        ).astype(dtype)
+        dd_ref[0, 0, :, u.lanes] = jnp.sum(dy32 * u.x32, axis=0, keepdims=True)
+        through_v = dv * u.x32
+        for i in range(_LANES // P):
+            a = u.first + i
+            lane_sum = lambda y: jnp.sum(_own_lanes(y, i, P), axis=1, keepdims=True)
+            dG = jnp.where(head_lane == a, row_sums[i] + lane_sum(owed), dG)
+            ddt = jnp.where(head_lane == a, lane_sum(through_v), ddt)
+    # dg: the reverse running sum of dG inside the chunk.
+    dg = sum(_mm(s.ones_u, p) for p in _pieces(dG)) + sum(
+        _mm(s.ones_u, p, _NT) for p in _pieces(dGt_scr[...])
+    )
+    ddt_ref[0, 0] = (ddt - s.rate * dg).astype(ddt_ref.dtype)
+    da_ref[0, 0, 0] = jnp.sum(dg * s.g, axis=0, keepdims=True)
+    for (rows, cols), part in zip(blocks, dcb):
+        dcb_scr[rows, cols] += part
+    db_scr[...] += db_acc
+    dc_scr[...] += dc_acc
+
+    @pl.when(h == pl.num_programs(2) - 1)
+    def _shared():
+        dcbb = dcb_scr[...].astype(dtype)
+        dc_ref[0] = (dc_scr[...] + _mm(dcbb, b)).astype(dc_ref.dtype)
+        db_ref[0] = (db_scr[...] + _mm(dcbb, c, _TN)).astype(db_ref.dtype)
+
+
+def _kernel_specs(chunk, heads, width, N, order):
+    """Block specs over the grid (batch, chunk, group of heads): the
+    ``[chunk, lanes]`` tile of ``x`` (and of whatever has its shape), the
+    group's ``dt`` ``[chunk, heads]``, ``A_log`` and ``D`` for the group,
+    the chunk's ``B`` or ``C``, the group's state at the chunk's start,
+    and the rows a backward step owes ``D`` and ``A_log``; ``order`` maps
+    the grid's chunk to the array's (the backward sweeps in reverse)."""
+    return types.SimpleNamespace(
+        x=pl.BlockSpec((1, chunk, width), lambda i, t, h: (i, order(t), h)),
+        dt=pl.BlockSpec((1, 1, chunk, heads), lambda i, t, h: (i, h, order(t), 0)),
+        a=pl.BlockSpec((1, 1, heads), lambda i, t, h: (h, 0, 0)),
+        b=pl.BlockSpec((1, chunk, N), lambda i, t, h: (i, order(t), 0)),
+        d=pl.BlockSpec((1, width), lambda i, t, h: (0, h)),
+        state=pl.BlockSpec((1, 1, N, width), lambda i, t, h: (i, order(t), 0, h)),
+        d_row=pl.BlockSpec((1, 1, 1, width), lambda i, t, h: (i, order(t), 0, h)),
+        a_row=pl.BlockSpec((1, 1, 1, 1, heads), lambda i, t, h: (i, order(t), h, 0, 0)),
+    )
+
+
+def _kernel_geometry(x, dt, b, chunk):
+    """From ``x`` ``[B, T, H * P]``, ``dt`` by groups ``[B, groups, T,
+    heads]`` and ``b`` ``[B, T, N]``."""
+    B, T, lanes = x.shape
+    groups, heads = dt.shape[1], dt.shape[3]
+    return B, T // chunk, groups, heads, lanes // groups, b.shape[-1]
+
+
+def _kernel_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_KERNEL_VMEM_BYTES,
+    )
+
+
+def _kernel_forward(x, dt, a_log, b, c, d, *, chunk, keep_states, interpret):
+    """``(y [B, T, H * P], kept)``, ``kept`` the states at the chunks'
+    starts ``[B, T / chunk, N, H * P]`` (float32) or ``()``; ``T`` a
+    multiple of ``chunk``."""
+    B, n, groups, heads, width, N = _kernel_geometry(x, dt, b, chunk)
+    spec = _kernel_specs(chunk, heads, width, N, lambda t: t)
+    vma = _vma(x)
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)]
+    out_specs = [spec.x]
+    if keep_states:
+        out_shape.append(jax.ShapeDtypeStruct((B, n, N, x.shape[2]), _F32, vma=vma))
+        out_specs.append(spec.state)
+    call = pl.pallas_call(
+        functools.partial(
+            _ssd_fwd_kernel, head_dim=width // heads, keep_states=keep_states
+        ),
+        grid=(B, n, groups),
+        in_specs=[spec.x, spec.dt, spec.a, spec.b, spec.b, spec.d],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((groups, N, width), _F32),
+            pltpu.VMEM((chunk, chunk), _F32),
+        ],
+        compiler_params=_kernel_params(),
+        interpret=interpret,
+    )
+    with jax.named_scope(SSD_CORE_SCOPE):
+        res = call(x, dt, a_log, b, c, d)
+    return res[0], tuple(res[1:])
+
+
+def _kernel_backward(x, dt, a_log, b, c, d, states, dy, *, chunk, interpret):
+    """The cotangents of ``x``, ``dt`` (by groups, like ``dt``), ``b`` and
+    ``c``, and a row a chunk and group of what ``A_log`` ``[B, T / chunk,
+    groups, 1, heads]`` and ``D`` ``[B, T / chunk, 1, H * P]`` are owed."""
+    B, n, groups, heads, width, N = _kernel_geometry(x, dt, b, chunk)
+    spec = _kernel_specs(chunk, heads, width, N, lambda t: n - 1 - t)
+    vma = _vma(x)
+    like = lambda y: jax.ShapeDtypeStruct(y.shape, y.dtype, vma=vma)
+    rows = lambda *shape: jax.ShapeDtypeStruct((B, n) + shape, _F32, vma=vma)
+    call = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, head_dim=width // heads),
+        grid=(B, n, groups),
+        in_specs=[
+            spec.x, spec.dt, spec.a, spec.b, spec.b, spec.d, spec.state, spec.x,
+        ],
+        out_specs=[spec.x, spec.dt, spec.a_row, spec.b, spec.b, spec.d_row],
+        out_shape=[
+            like(x), like(dt), rows(groups, 1, heads), like(b), like(c),
+            rows(1, x.shape[2]),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((groups, N, width), _F32),
+            pltpu.VMEM((chunk, chunk), _F32),
+            pltpu.VMEM((chunk, chunk), _F32),
+            pltpu.VMEM((chunk, N), _F32),
+            pltpu.VMEM((chunk, N), _F32),
+            pltpu.VMEM((heads, chunk), _F32),
+        ],
+        compiler_params=_kernel_params(),
+        interpret=interpret,
+    )
+    with jax.named_scope(SSD_CORE_SCOPE):
+        return call(x, dt, a_log, b, c, d, states, dy)
+
+
+def _by_groups(dt, heads):
+    """``[B, T, H]`` -> ``[B, H / heads, T, heads]``: a grid step's heads
+    as the lanes of its tile."""
+    B, T, H = dt.shape
+    return jnp.swapaxes(dt.reshape(B, T, H // heads, heads), 1, 2)
+
+
+def _kernel_operands(x, dt, a_log, b, c, d, chunk):
+    """The arguments as the kernels read them, the length padded to whole
+    chunks with tokens that leave the state alone (``dt`` 0)."""
+    H = dt.shape[-1]
+    P = x.shape[-1] // H
+    heads = _kernel_heads(H, P)
+    pad = -x.shape[1] % chunk
+    x, dt, b, c = (_padded(y, pad) for y in (x, dt, b, c))
+    return (
+        x, _by_groups(dt, heads), a_log.reshape(H // heads, 1, heads), b, c,
+        jnp.repeat(d, P)[None],
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def kernel_ssd_flat(x, dt, a_log, b, c, d, chunk=256, interpret=False):
+    """The chunk-wise scan as Pallas kernels (section comment above),
+    forward and backward, on the flat view the kernels read: ``x`` ``[B,
+    T, H * P]``, ``dt`` ``[B, T, H]``, ``a_log`` and ``d`` ``[H]``, ``b``,
+    ``c`` ``[B, T, N]``; returns ``[B, T, H * P]``.  ``interpret=True``
+    runs the same kernels on the CPU for tests."""
+    return _kernel_fwd(x, dt, a_log, b, c, d, chunk, interpret, False)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+@jax.named_scope(SSD_CORE_SCOPE)
+def _kernel_fwd(x, dt, a_log, b, c, d, chunk, interpret, keep_states=True):
+    operands = _kernel_operands(x, dt, a_log, b, c, d, chunk)
+    y, kept = _kernel_forward(
+        *operands, chunk=chunk, keep_states=keep_states, interpret=interpret
+    )
+    return y[:, :x.shape[1]], operands + kept
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+@jax.named_scope(SSD_CORE_SCOPE)
+def _kernel_bwd(chunk, interpret, res, dy):
+    a_log, d = res[2], res[5]
+    T, H = dy.shape[1], a_log.size
+    dx, ddt, da, db, dc, dd = _kernel_backward(
+        *res, _padded(dy, -T % chunk), chunk=chunk, interpret=interpret
+    )
+    ddt = jnp.swapaxes(ddt, 1, 2).reshape(ddt.shape[0], -1, H)
+    return (
+        dx[:, :T], ddt[:, :T],
+        jnp.sum(da, axis=(0, 1)).reshape(H).astype(a_log.dtype),
+        db[:, :T], dc[:, :T],
+        jnp.sum(dd, axis=(0, 1, 2)).reshape(H, -1).sum(axis=1).astype(d.dtype),
+    )
+
+
+kernel_ssd_flat.defvjp(_kernel_fwd, _kernel_bwd)
+
+
+def kernel_ssd(x, dt, a_log, b, c, d_skip=None, chunk=256, interpret=False):
+    """:func:`kernel_ssd_flat` for the arguments of :func:`chunked_ssd`,
+    what it runs on a TPU for the calls :func:`kernel_admissible` admits:
+    the heads' channels are folded into the lanes on the way in and out
+    again on the way back (the mixer's own reshapes beside them, so XLA
+    drops both)."""
+    B, T, H, P = x.shape
+    d = jnp.zeros((H,), _F32) if d_skip is None else d_skip
+    y = kernel_ssd_flat(x.reshape(B, T, H * P), dt, a_log, b, c, d, chunk, interpret)
+    return y.reshape(B, T, H, P)
+
+
+def ssd_route(x, dt, a_log, b, c, *, chunk: int) -> str:
+    """What :func:`chunked_ssd` runs for this call: ``"kernel"`` on a TPU
+    for the calls the kernels admit, where a Mosaic kernel can lower;
+    else ``"plain"``."""
+    if (
+        jax.default_backend() == "tpu"
+        and kernel_admissible(x, b, c, chunk=chunk)
+        and mosaic_can_lower()
+    ):
+        return "kernel"
+    return "plain"
+
+
 def chunked_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
     """:func:`recurrent_ssd` computed chunk-wise (module docstring); same
-    arguments, the result in the dtype of ``x``.  One route,
-    :func:`plain_ssd`, counted once per traced call (``ssd/route_plain``).
-    ``chunk`` is the program's way to compute the recurrence and no part
-    of the model (``mamba_chunk_size`` 256 is the published kernel's
-    block).  What it trades is memory: a float32 copy of the per-chunk
-    states is ``T / chunk x H x N x P`` (268 MB at 8,192 tokens of
-    Granite's 64 x 128 x 64 in chunks of 64, 67 MB at 256), a head's masks
-    ``T x chunk`` (PERF.md, PR 38)."""
+    arguments, the result in the dtype of ``x``.  On a TPU, for whole
+    tiles, the Pallas kernels (:func:`kernel_ssd`: 1.37 ms forward and
+    4.45 with the backward pass at ``[1, 8192, 64, 64]`` over a 128-wide
+    state on a v5e, chunks of 256, against :func:`plain_ssd`'s 2.87 and
+    10.73 beside them; in ``granite_h_train``'s step, where the mixer's
+    reshapes cancel the 4-D view's relayout, two forward passes and the
+    backward of a layer are 2.55 ms, 10.25 plain; PERF.md, PR 39), else
+    :func:`plain_ssd`; the choice is counted once per traced
+    call.  ``chunk`` is the program's way to compute the recurrence and no
+    part of the model (``mamba_chunk_size`` 256 is the published kernel's
+    block).  What it trades on the plain route is memory: a float32 copy
+    of the per-chunk states is ``T / chunk x H x N x P`` (268 MB at 8,192
+    tokens of Granite's 64 x 128 x 64 in chunks of 64, 67 MB at 256), a
+    head's masks ``T x chunk`` (PERF.md, PR 38); the kernels keep the
+    states alone."""
     if not (
         x.shape[:3] == dt.shape and b.shape == c.shape
         and b.shape[:2] == x.shape[:2] and a_log.shape == x.shape[2:3]
@@ -169,5 +698,10 @@ def chunked_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
             f"b, c [B, T, N] (one group); got {x.shape}, {dt.shape}, "
             f"{a_log.shape}, {b.shape}, {c.shape}"
         )
-    get_registry().counter(SSD_ROUTE_PLAIN).inc()
+    route = ssd_route(x, dt, a_log, b, c, chunk=chunk)
+    get_registry().counter(
+        SSD_ROUTE_KERNEL if route == "kernel" else SSD_ROUTE_PLAIN
+    ).inc()
+    if route == "kernel":
+        return kernel_ssd(x, dt, a_log, b, c, d_skip, chunk)
     return plain_ssd(x, dt, a_log, b, c, d_skip, chunk=chunk)

@@ -75,6 +75,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     ROLLBACKS,
     SHARD,
     SKIPPED_BATCHES,
+    SSD_ROUTE_KERNEL,
     SSD_ROUTE_PLAIN,
     STARTUP_AOT_COMPILE,
     STARTUP_AOT_JOIN,

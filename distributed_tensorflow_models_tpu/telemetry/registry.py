@@ -95,8 +95,10 @@ KDA_MIXER_PLAIN = "kda/mixer_plain"  # counter
 # Traced calls of ``ops/linear_attention.py::chunked_gdn`` (one decay a
 # head), which has the plain route alone.
 GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter
-# Traced calls of ``ops/ssm.py::chunked_ssd`` (Mamba-2's state-space dual
-# scan), which has the plain route alone.
+# Which route ``ops/ssm.py::chunked_ssd`` (Mamba-2's state-space dual
+# scan) took, one or the other per traced call: the Pallas kernels on a
+# TPU for whole tiles, the plain ``jax.numpy`` form everywhere else.
+SSD_ROUTE_KERNEL = "ssd/route_kernel"  # counter
 SSD_ROUTE_PLAIN = "ssd/route_plain"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at

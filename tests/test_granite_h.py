@@ -500,7 +500,7 @@ def test_fit_trains_the_granite_h_program_config(tmp_path):
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     with open(os.path.join(workdir, "telemetry.json")) as f:
         telemetry = json.load(f)["metrics"]
-    assert telemetry["ssd/route_plain"] == 6 and "ssd/route_kernel" not in telemetry
+    assert telemetry["ssd/route_plain"] == 6 and telemetry["ssd/route_kernel"] == 0
     assert telemetry["attention/route_blockwise"] == 2 and telemetry["gdn/route_plain"] == 0
     assert telemetry["unembed/grad_in_forward"] == 1
     assert _ssd_traced_calls() - before == 6
