@@ -665,6 +665,78 @@ _add(
 )
 
 
+# NVIDIA-Nemotron-3-Nano-30B-A3B (config.json of
+# nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16, 2025-12, model_type
+# nemotron_h; the block is Nemotron-H's, NVIDIA 2025, arXiv:2504.03624):
+# 52 pre-norm RMSNorm (1e-5) layers of width 2688 without any position
+# encoding, **each one sub-layer alone** by hybrid_override_pattern: 23
+# Mamba-2 mixers (M: d_inner 4096 = 64 heads of 64 over a state of 128,
+# B and C one vector for each of eight groups of eight heads, the gated
+# norm over each group's 512 channels; expand 2 would give 5376 and is
+# used by no layer), 23 expert feed-forwards (E: 128 experts of 1856,
+# two matrices around a squared ReLU without a gate; sigmoid scores, the
+# top 6 renormalised times 2.5, n_group 1 so no group limit; one shared
+# expert of 3712) and 6 attentions (*: 32 query heads of 128 over 2
+# key/value heads, 4096 channels into a width of 2688); vocabulary
+# 131072, untied, no bias.  The router's selection bias is a buffer
+# outside the gradient, held at zero, and there is no auxiliary loss.
+# Adam 3e-4 with clip 1.0 behind the 2,000-step warm-up of the other large
+# language models (this router too is balanced by nothing: PERF.md, PR
+# 30), per-half recomputation (a layer is one half), sequences of 8,192.
+# Every size is the published one; no single chip holds one expert layer
+# in training (benchmark/configs/nemotron3_nano.json runs layers 1-9, 8 of
+# the experts and an eighth of the vocabulary: one chip's share of 16).
+_NEMOTRON_LAYER = {"M": "ssm_only", "E": "ffn_only", "*": "attention_only"}
+NEMOTRON3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="nemotron3_nano",
+        model_kwargs={
+            "vocab_size": 131072,
+            "num_layers": 52,
+            "num_heads": 32,
+            "num_kv_heads": 2,
+            "head_dim": 128,
+            "d_model": 2688,
+            "d_ff": 1856,
+            "max_len": 262144,
+            "dropout_rate": 0.0,
+            "pos_encoding": "none",
+            "norm": "rmsnorm",
+            "norm_eps": 1e-5,
+            "use_bias": False,
+            "mlp": "relu2",
+            "layer_mixers": tuple(
+                _NEMOTRON_LAYER[kind] for kind in NEMOTRON3_NANO_PATTERN
+            ),
+            "ssm_num_heads": 64,
+            "ssm_head_dim": 64,
+            "ssm_state_dim": 128,
+            "ssm_num_groups": 8,
+            "ssm_conv_size": 4,
+            "num_experts": 128,
+            "moe_router": "topk",
+            "moe_top_k": 6,
+            "moe_layers": "all",
+            "moe_scoring": "sigmoid",
+            "moe_renormalize": True,
+            "moe_routed_scale": 2.5,
+            "moe_shared_experts": 1,
+            "moe_shared_d_ff": 3712,
+            "moe_expert": "relu2",
+            "moe_aux_loss_weight": 0.0,
+            "remat": True,
+        },
+        global_batch_size=1,
+        num_steps=8192,
+        vocab_size=131072,
+        optimizer=dataclasses.replace(
+            _CONFIGS["transformer_lm"].optimizer, warmup_steps=2000
+        ),
+    )
+)
+
+
 def get_config(name: str, **overrides) -> ExperimentConfig:
     if name not in _CONFIGS:
         raise KeyError(f"unknown config {name!r}; have {sorted(_CONFIGS)}")

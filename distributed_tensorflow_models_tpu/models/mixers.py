@@ -58,16 +58,24 @@ a fourth:
 
 - :class:`Mamba2Mixer`: the Mamba-2 state-space layer (Dao and Gu 2024,
   arXiv:2405.21060).  ``[z, xBC, dt] = W_in x`` (``d_inner``, ``d_inner +
-  2 N`` and ``H`` channels, in that order, ``d_inner = H P``); ``xBC =
+  2 G N`` and ``H`` channels, in that order, ``d_inner = H P``); ``xBC =
   silu(conv(xBC) + b_conv)``, **one** causal depthwise convolution **with
   a bias** over ``x``, ``B`` and ``C`` together; ``dt = softplus(dt +
   dt_bias)`` and the decay ``exp(-exp(A_log) dt)``, one number a head
-  and token; ``B`` and ``C`` ``[N]`` **one vector for all the heads**
-  (one group); the state ``[N, P]`` a head and the read of
-  :func:`...ops.ssm.chunked_ssd` with its ``D`` skip; ``y = W_out (w *
-  rmsnorm(y * silu(z)))``, the gate **before** the norm and the mean
-  square over all ``d_inner`` channels (the delta-rule mixers normalise
-  per head and gate after).
+  and token; ``B`` and ``C`` ``[N]`` **one vector a group of heads**
+  (``num_groups``: Granite's one group for all 64 heads, so ``xBC`` is
+  ``d_inner + 2 N`` wide; Nemotron 3 Nano's eight, ``d_inner + 2 G N``,
+  head ``h`` reading group ``h // (H / G)``); the state ``[N, P]`` a head
+  and the read of :func:`...ops.ssm.chunked_ssd` with its ``D`` skip; ``y
+  = W_out (w * rmsnorm(y * silu(z)))``, the gate **before** the norm and
+  the mean square over all ``d_inner`` channels with one group, over each
+  group's ``d_inner / G`` with more (the delta-rule mixers normalise per
+  head and gate after).
+
+Nemotron 3 Nano (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+``config.json``, ``model_type`` ``nemotron_h``: ``hybrid_override_pattern``,
+``n_groups``) runs the same mixer with eight groups, in layers that are
+this mixer alone (``transformer_lm``'s ``layer_mixers``).
 
 All compute in ``dtype`` over float32 parameters; the norms, the decay,
 ``b_t``, ``dt``, the l2 norms and the output gate's norm are float32 (the
@@ -283,14 +291,15 @@ class GatedDeltaNetMixer(nn.Module):
 
 
 class Mamba2Mixer(nn.Module):
-    """The Mamba-2 state-space layer (module docstring), one group:
-    ``num_heads`` heads of ``head_dim`` channels over a state of
-    ``state_dim``."""
+    """The Mamba-2 state-space layer (module docstring): ``num_heads``
+    heads of ``head_dim`` channels over a state of ``state_dim``, ``B``
+    and ``C`` one vector for each of ``num_groups`` groups of heads."""
 
     num_heads: int
     head_dim: int
     state_dim: int
     d_model: int
+    num_groups: int = 1
     conv_size: int = 4
     norm_eps: float = 1e-5
     chunk: int = 256  # the scan's, not the model's (ops/ssm.py)
@@ -300,7 +309,8 @@ class Mamba2Mixer(nn.Module):
     def __call__(self, x):
         B, T, _ = x.shape
         H, P, N, K = self.num_heads, self.head_dim, self.state_dim, self.conv_size
-        inner, mixed = H * P, H * P + 2 * N
+        groups = self.num_groups
+        inner, mixed = H * P, H * P + 2 * groups * N
         zxbcdt = _dense(inner + mixed + H, self.dtype, "in_proj")(x)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + mixed], axis=-1)
         # torch's Conv1d default, weight and bias: uniform(+-1/sqrt(fan_in)),
@@ -311,7 +321,9 @@ class Mamba2Mixer(nn.Module):
         taps = self.param("conv", uniform((K, mixed)))
         bias = self.param("conv_bias", uniform((mixed,)))
         xbc = jax.nn.silu(causal_depthwise_conv(xbc, taps) + bias.astype(self.dtype))
-        xs, b, c = jnp.split(xbc, [inner, inner + N], axis=-1)
+        xs, b, c = jnp.split(xbc, [inner, inner + groups * N], axis=-1)
+        if groups > 1:
+            b, c = (y.reshape(B, T, groups, N) for y in (b, c))
 
         a_log = self.param("A_log", _a_log_init(H))
         dt_bias = self.param("dt_bias", _dt_bias_init(H))
@@ -322,9 +334,23 @@ class Mamba2Mixer(nn.Module):
             xs.reshape(B, T, H, P), dt, a_log, b, c, d_skip, chunk=self.chunk
         ).reshape(B, T, inner)
 
-        # The gate first, then one norm over all the channels.
+        # The gate first, then the norm: over all the channels, or with
+        # groups over each group's own (``mamba_ssm``'s ``RMSNormGated``
+        # with ``group_size = d_inner / n_groups``).
         y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        y = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(y)
+        if groups > 1:
+            # Each channel's group as a 0/1 matrix: the groups' sums and
+            # their way back over the channels are two thin products on
+            # the flat ``[B, T, d_inner]`` view (exact in float32 at
+            # ``highest``); a ``[B, T, G, d_inner / G]`` view would put the
+            # groups in the sublanes, a relayout of the whole activation.
+            scale = _HeadScale(inner, name="norm")()
+            member = jnp.repeat(jnp.eye(groups, dtype=jnp.float32), inner // groups, axis=0)
+            thin = lambda a, m: jnp.dot(a, m, precision=jax.lax.Precision.HIGHEST)
+            mean_square = thin(jnp.square(y), member) / (inner // groups)
+            y = y * thin(jax.lax.rsqrt(mean_square + self.norm_eps), member.T) * scale
+        else:
+            y = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(y)
         return _dense(self.d_model, self.dtype, "out_proj")(y.astype(self.dtype))
 
 

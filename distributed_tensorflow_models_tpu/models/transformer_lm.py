@@ -21,9 +21,10 @@ every other block, or softmax-then-top-k routing over gated experts in
 every block (:func:`...parallel.moe.topk_moe_ffn`; OLMoE, ROADMAP R1).
 These are a model's published settings, not tuning options.
 
-The layers of a stack may differ (Kimi Linear, Olmo-Hybrid and Granite
-4.0-H: ``harness/config.py::kimi_linear``, ``olmo_hybrid``,
-``granite_h_micro``): ``layer_mixers`` names each layer's token mixer
+The layers of a stack may differ (Kimi Linear, Olmo-Hybrid, Granite
+4.0-H and Nemotron 3 Nano: ``harness/config.py::kimi_linear``,
+``olmo_hybrid``, ``granite_h_micro``, ``nemotron3_nano``):
+``layer_mixers`` names each layer's token mixer
 (full attention, or one of the two delta-rule linear attentions, the
 latent attention or the Mamba-2 state-space layer of :mod:`.mixers`;
 under ``pos_encoding="rope"`` only the full-attention layers rotate, the
@@ -36,6 +37,14 @@ feed-forward (gated SiLU, ``dense_d_ff`` wide) before the expert layers
 start, an expert layer may have shared experts beside the routed ones,
 sigmoid scores renormalised and scaled, and hold a range of the router's
 experts only (``moe_held``: one chip's share of an expert-parallel job).
+A layer may also be **one sub-layer alone** behind its one norm, a mixer
+without a feed-forward or a feed-forward without a mixer (``layer_mixers``
+entries ``"<mixer>_only"`` and ``"ffn_only"``: the Nemotron-H family,
+whose 52 layers are a Mamba-2 mixer, an expert feed-forward or attention
+each), and its feed-forwards, dense, shared and routed, may be **two
+matrices around a squared ReLU without a gate** (``mlp="relu2"``,
+``moe_expert="relu2"``; the experts of the other models are three with a
+SiLU gate).
 Granite's four scalars (``embedding_multiplier`` on the embedding,
 ``residual_multiplier`` on each sub-layer's output before it joins the
 residual, ``attention_multiplier`` for the scores' scale,
@@ -185,18 +194,29 @@ class SelfAttention(nn.Module):
 
 
 class MLP(nn.Module):
+    """``down(act(up(x)))``: the GELU feed-forward of the GPT-2 block, or
+    with ``activation="relu2"`` the squared-ReLU one of the Nemotron-H
+    family (its dense layers and its shared expert)."""
+
     d_model: int
     d_ff: int
     dropout_rate: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
     use_bias: bool = True
+    activation: str = "gelu"
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         dense = lambda name, feats: nn.Dense(
             feats, dtype=self.dtype, use_bias=self.use_bias, name=name
         )
-        h = nn.gelu(dense("up", self.d_ff)(x))
+        h = dense("up", self.d_ff)(x)
+        if self.activation == "relu2":
+            from distributed_tensorflow_models_tpu.parallel.moe import squared_relu
+
+            h = squared_relu(h)
+        else:
+            h = nn.gelu(h)
         h = dense("down", self.d_model)(h)
         if self.dropout_rate:
             h = nn.Dropout(self.dropout_rate, deterministic=not train)(h)
@@ -219,6 +239,10 @@ class GatedMLP(nn.Module):
         h = nn.silu(dense("gate", self.d_ff)(x)) * dense("up", self.d_ff)(x)
         return dense("down", self.d_model)(h)
 
+
+_MIXERS = ("attention", "kda", "gdn", "mla", "ssm")
+# What an entry of ``TransformerLM.layer_mixers`` may say.
+_LAYER_KINDS = _MIXERS + tuple(f"{m}_only" for m in _MIXERS) + ("ffn_only",)
 
 # ``jax.named_scope`` of the shared experts of an expert layer (inside
 # the layer's ``moe``): what every token goes through beside its routed
@@ -294,8 +318,11 @@ class MoEFFN(nn.Module):
 
 
 class TopKExpertsFFN(nn.Module):
-    """Top-k routing over gated (SiLU) experts without a capacity: flax
-    parameter declaration around :func:`...parallel.moe.topk_moe_ffn`.
+    """Top-k routing over experts without a capacity: flax parameter
+    declaration around :func:`...parallel.moe.topk_moe_ffn`.  ``expert``:
+    ``"gated_silu"`` (three matrices an expert, ``W_down (silu(W_gate h) *
+    W_up h)``) or ``"relu2"`` (two, ``W_down relu(W_up h)^2``: no
+    ``w_gate`` leaf); the shared experts are of the same kind.
     The weighted load-balancing loss and router z-loss go into the
     ``losses`` collection (summed into the objective by
     :func:`...core.train_loop.lm_loss_fn`; a weight of 0 puts nothing
@@ -303,8 +330,9 @@ class TopKExpertsFFN(nn.Module):
     ``moe_stats``, which the loss function averages over layers into the
     step's metrics.  ``held = (first, count)``: the expert stacks hold
     that range of the router's ``num_experts`` (``held_share`` joins the
-    statistics); ``shared_experts``: a gated MLP that many experts wide
-    on every token, added to the routed result."""
+    statistics); ``shared_experts``: a feed-forward that many experts
+    wide (each ``shared_d_ff`` where that is not an expert's ``d_ff``) on
+    every token, added to the routed result."""
 
     num_experts: int
     top_k: int
@@ -317,6 +345,8 @@ class TopKExpertsFFN(nn.Module):
     routing: Any = None  # parallel.moe.Routing; None: softmax as it is
     held: Any = None
     shared_experts: int = 0
+    expert: str = "gated_silu"
+    shared_d_ff: int = 0
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -324,6 +354,7 @@ class TopKExpertsFFN(nn.Module):
 
         E, d, f = self.num_experts, self.d_model, self.d_ff
         here = E if self.held is None else self.held[1]
+        gated = self.expert == "gated_silu"
 
         def normal(name, shape, fan_in):
             return self.param(
@@ -331,12 +362,11 @@ class TopKExpertsFFN(nn.Module):
                 lambda rng: jax.random.normal(rng, shape) * fan_in**-0.5,
             )
 
-        params = {
-            "router": normal("router", (d, E), d),
-            "w_gate": normal("w_gate", (here, d, f), d),
-            "w_up": normal("w_up", (here, d, f), d),
-            "w_down": normal("w_down", (here, f, d), f),
-        }
+        params = {"router": normal("router", (d, E), d)}
+        if gated:
+            params["w_gate"] = normal("w_gate", (here, d, f), d)
+        params["w_up"] = normal("w_up", (here, d, f), d)
+        params["w_down"] = normal("w_down", (here, f, d), f)
         res = moelib.topk_moe_ffn(
             params, x, top_k=self.top_k, mesh=self.mesh, dtype=self.dtype,
             routing=self.routing or moelib.Routing(), held=self.held,
@@ -360,10 +390,16 @@ class TopKExpertsFFN(nn.Module):
             self.sow("moe_stats", name, value, **scalar)
         out = res.out.astype(x.dtype)
         if self.shared_experts:
+            width = (self.shared_d_ff or f) * self.shared_experts
             with jax.named_scope(MOE_SHARED_SCOPE):
-                out = out + GatedMLP(
-                    d, f * self.shared_experts, self.dtype, name="shared"
-                )(x)
+                if gated:
+                    shared = GatedMLP(d, width, self.dtype, name="shared")
+                else:
+                    shared = MLP(
+                        d, width, dtype=self.dtype, use_bias=False,
+                        activation="relu2", name="shared",
+                    )
+                out = out + shared(x)
         return out
 
 
@@ -390,7 +426,8 @@ class Block(nn.Module):
     use_bias: bool = True
     qk_norm: bool = False
     # Experts' routing where ``use_moe``: "switch" (top-1 with a capacity,
-    # ReLU experts) or "topk" (softmax then top-k, gated experts, exact).
+    # ReLU experts) or "topk" (softmax then top-k, experts of the kind
+    # ``moe_expert`` says, exact).
     moe_router: str = "switch"
     moe_top_k: int = 1
     moe_z_loss_weight: float = 0.0
@@ -398,10 +435,16 @@ class Block(nn.Module):
     moe_routing: Any = None
     moe_held: Any = None
     moe_shared_experts: int = 0
+    moe_expert: str = "gated_silu"
+    moe_shared_d_ff: int = 0
     # The token mixer: "attention" (SelfAttention), "kda", "gdn", "mla" or
-    # "ssm" (models/mixers.py; ``mixer_kwargs`` are that module's sizes).
+    # "ssm" (models/mixers.py; ``mixer_kwargs`` are that module's sizes),
+    # or "none": a layer that is its feed-forward alone (no ``ln1``, no
+    # mixer leaf).  ``feed`` False: a layer that is its mixer alone (no
+    # ``ln2``, no feed-forward leaf).
     mixer: str = "attention"
     mixer_kwargs: Any = None
+    feed: bool = True
     head_dim: int = 0
     # The attention scores' scale (None: head size ** -0.5) and what each
     # sub-layer's output is multiplied by before it joins the residual.
@@ -411,11 +454,13 @@ class Block(nn.Module):
     # block, arXiv:2501.00656: the norm on the sub-layer's output, inside
     # the residual branch).
     norm_placement: str = "pre"
-    # The dense feed-forward: the "gelu" MLP or the "gated_silu" one.
+    # The dense feed-forward: the "gelu" MLP, the "gated_silu" one or the
+    # "relu2" one (the MLP with a squared ReLU).
     mlp: str = "gelu"
     # Recompute in the backward pass, the mixer's half of the block and
     # the feed-forward's each on its own (each with its norm): while one
-    # half runs backward the other keeps nothing but its input.
+    # half runs backward the other keeps nothing but its input.  A layer
+    # of one sub-layer has one half.
     remat: bool = False
 
     def _mix(self, h, train):
@@ -461,8 +506,11 @@ class Block(nn.Module):
             mix, feed = branch(mix), branch(feed)
         if self.remat:
             mix, feed = nn.remat(mix), nn.remat(feed)
-        x = x + mix(self, x)
-        return x + feed(self, x)
+        if self.mixer != "none":
+            x = x + mix(self, x)
+        if self.feed:
+            x = x + feed(self, x)
+        return x
 
     def _ffn(self) -> nn.Module:
         if self.use_moe and self.moe_router == "topk":
@@ -478,6 +526,8 @@ class Block(nn.Module):
                 routing=self.moe_routing,
                 held=self.moe_held,
                 shared_experts=self.moe_shared_experts,
+                expert=self.moe_expert,
+                shared_d_ff=self.moe_shared_d_ff,
                 name="moe",
             )
         if self.use_moe:
@@ -498,6 +548,7 @@ class Block(nn.Module):
             self.dropout_rate,
             self.dtype,
             use_bias=self.use_bias,
+            activation="relu2" if self.mlp == "relu2" else "gelu",
             name="mlp",
         )
 
@@ -761,7 +812,7 @@ class TransformerLM(nn.Module):
     head_dim: int = 0
     # Experts (``num_experts`` > 0, each ``d_ff`` wide): "switch" routing
     # (top-1, capacity, ReLU experts) or "topk" (softmax then
-    # ``moe_top_k``, gated SiLU experts, no token dropped); in every other
+    # ``moe_top_k``, experts of ``moe_expert``'s kind, no token dropped); in every other
     # block ("alternate", the Switch placement) or in "all".
     moe_router: str = "switch"
     moe_top_k: int = 1
@@ -780,14 +831,23 @@ class TransformerLM(nn.Module):
     moe_routed_scale: float = 1.0
     moe_shared_experts: int = 0
     moe_held: Any = None
-    # The dense feed-forward: the "gelu" MLP or the bias-free
-    # "gated_silu" one, ``dense_d_ff`` wide where that differs from an
-    # expert's ``d_ff`` (0: the same).
+    # An expert (routed or shared): "gated_silu", three matrices, or
+    # "relu2", two around a squared ReLU; a shared expert's width where it
+    # is not a routed one's ``d_ff`` (0).
+    moe_expert: str = "gated_silu"
+    moe_shared_d_ff: int = 0
+    # The dense feed-forward: the "gelu" MLP, the bias-free "gated_silu"
+    # one or the "relu2" MLP, ``dense_d_ff`` wide where that differs from
+    # an expert's ``d_ff`` (0: the same).
     mlp: str = "gelu"
     dense_d_ff: int = 0
     # Each layer's token mixer, "attention" | "kda" | "gdn" | "mla" | "ssm"
     # (None: full attention everywhere), and the sizes models/mixers.py
-    # takes.
+    # takes.  A layer named so is the mixer and then the feed-forward;
+    # "<mixer>_only" is a layer that is the mixer alone behind its one
+    # norm, "ffn_only" one that is the feed-forward alone (dense or
+    # experts, by ``moe_layers`` as everywhere): the Nemotron-H family's
+    # layers are one sub-layer each.
     layer_mixers: Any = None
     kda_num_heads: int = 0  # 0: num_heads
     kda_head_dim: int = 128
@@ -803,6 +863,7 @@ class TransformerLM(nn.Module):
     ssm_num_heads: int = 64
     ssm_head_dim: int = 64
     ssm_state_dim: int = 128
+    ssm_num_groups: int = 1  # of heads that share a B and a C
     ssm_conv_size: int = 4
     ssm_chunk: int = 256  # the scan's chunk: the program's, not the model's
     # Granite's four scalars: the embedding times ``embedding_multiplier``,
@@ -820,6 +881,15 @@ class TransformerLM(nn.Module):
     def _mixers(self) -> tuple:
         return tuple(self.layer_mixers or ("attention",) * self.num_layers)
 
+    @staticmethod
+    def _halves(entry: str) -> tuple:
+        """``(mixer, feed)`` of one entry of ``layer_mixers``."""
+        if entry == "ffn_only":
+            return "none", True
+        if entry.endswith("_only"):
+            return entry[: -len("_only")], False
+        return entry, True
+
     def _check_settings(self):
         """Refusals that depend on no input: raised when the model is
         first called, before anything is traced."""
@@ -830,9 +900,10 @@ class TransformerLM(nn.Module):
             ("moe_router", self.moe_router, ("switch", "topk")),
             ("moe_layers", self.moe_layers, ("alternate", "all")),
             ("moe_scoring", self.moe_scoring, ("softmax", "sigmoid")),
-            ("mlp", self.mlp, ("gelu", "gated_silu")),
+            ("mlp", self.mlp, ("gelu", "gated_silu", "relu2")),
+            ("moe_expert", self.moe_expert, ("gated_silu", "relu2")),
             *(
-                (f"layer_mixers[{i}]", m, ("attention", "kda", "gdn", "mla", "ssm"))
+                (f"layer_mixers[{i}]", m, _LAYER_KINDS)
                 for i, m in enumerate(self._mixers())
             ),
         ):
@@ -1030,11 +1101,13 @@ class TransformerLM(nn.Module):
                     ("num_heads", self.ssm_num_heads),
                     ("head_dim", self.ssm_head_dim),
                     ("state_dim", self.ssm_state_dim),
+                    ("num_groups", self.ssm_num_groups),
                     ("conv_size", self.ssm_conv_size),
                     ("chunk", self.ssm_chunk),
                 ),
             }
-            for i, mixer in enumerate(self._mixers()):
+            for i, entry in enumerate(self._mixers()):
+                mixer, feed = self._halves(entry)
                 use_moe = self.num_experts > 0 and (
                     i >= self.moe_first_dense
                     if self.moe_layers == "all"
@@ -1069,8 +1142,11 @@ class TransformerLM(nn.Module):
                     moe_routing=routing,
                     moe_held=self.moe_held and tuple(self.moe_held),
                     moe_shared_experts=self.moe_shared_experts,
+                    moe_expert=self.moe_expert,
+                    moe_shared_d_ff=self.moe_shared_d_ff,
                     mixer=mixer,
                     mixer_kwargs=mixer_kwargs.get(mixer),
+                    feed=feed,
                     head_dim=self.head_dim,
                     attn_scale=self.attention_multiplier,
                     residual_multiplier=self.residual_multiplier,

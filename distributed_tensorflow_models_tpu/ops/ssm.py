@@ -9,7 +9,9 @@ P]`` that every token decays by one number, writes and reads::
 
 with ``a_t = exp(-exp(A_log) dt_t)`` in (0, 1), ``dt_t > 0`` one number a
 head and token, ``D`` one number a head, and ``B_t``, ``C_t`` ``[N]``
-**one vector for all the heads** (one group).  In the words of
+**one vector a group of heads**: with ``G`` groups over ``H`` heads, head
+``h`` reads group ``h // (H / G)`` (Granite 4.0-H has one group for its 64
+heads, Nemotron 3 Nano eight).  In the words of
 :mod:`.linear_attention` it is linear attention with queries ``C``, keys
 ``B``, values ``dt x``, one log decay ``g = -exp(A_log) dt`` a head and
 step, scale 1 and **no delta rule**: nothing is read back before a write,
@@ -24,11 +26,11 @@ The chunk-wise form.  With ``G_t = sum_{r<=t} g_r`` inside a chunk of
     y_t = e^{G_t} S_0^T C_t + sum_{s<=t} (C_t . B_s) e^{G_t - G_s} v_s + D x_t
     S_L = e^{G_L} S_0 + sum_s e^{G_L - G_s} B_s v_s^T
 
-``C B^T`` is **one** ``[L, L]`` product a chunk, whatever the number of
-heads (the delta rules make one a head); a head's own part is the mask of
-``e^{G_t - G_s}`` (``s <= t``) laid over it.  The two products with the
-state take all the heads at once, ``[L, N] x [N, H P]`` and ``[N, L] x [L,
-H P]``.  Only the states need the chunks in order: ``S_{c+1} = e^{G_L} S_c
+``C B^T`` is **one** ``[L, L]`` product a chunk and group, whatever the
+number of heads in the group (the delta rules make one a head); a head's
+own part is the mask of ``e^{G_t - G_s}`` (``s <= t``) laid over its
+group's.  The two products with the state take a group's heads at once,
+``[L, N] x [N, (H / G) P]`` and ``[N, L] x [L, (H / G) P]``.  Only the states need the chunks in order: ``S_{c+1} = e^{G_L} S_c
 + U_c`` is a ``lax.scan`` over the chunks of one multiply-add on ``[H, N,
 P]``.
 
@@ -100,20 +102,31 @@ _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
 
 
+def _by_group(b):
+    """``B`` or ``C`` as ``[B, T, G, N]``: ``[B, T, N]`` is one group (a
+    reshape, whose transpose is one too)."""
+    return b.reshape(*b.shape[:2], 1, b.shape[2]) if b.ndim == 3 else b
+
+
+def _groups(b) -> int:
+    return 1 if b.ndim == 3 else b.shape[2]
+
+
 def recurrent_ssd(x, dt, a_log, b, c, d_skip=None):
     """The recurrence token by token, float32.  ``x`` ``[B, T, H, P]``,
     ``dt`` ``[B, T, H]`` (> 0, after the softplus), ``a_log`` ``[H]``,
-    ``b``, ``c`` ``[B, T, N]`` (one group), ``d_skip`` ``[H]`` or None;
-    returns ``[B, T, H, P]``."""
+    ``b``, ``c`` ``[B, T, G, N]`` (``[B, T, N]``: one group), ``d_skip``
+    ``[H]`` or None; returns ``[B, T, H, P]``."""
     B, T, H, P = x.shape
-    x, dt, b, c = (y.astype(_F32) for y in (x, dt, b, c))
+    x, dt, b, c = (y.astype(_F32) for y in (x, dt, _by_group(b), _by_group(c)))
+    b, c = (jnp.repeat(y, H // y.shape[2], axis=2) for y in (b, c))  # a head its group's
     decay = jnp.exp(-jnp.exp(a_log.astype(_F32)) * dt)
 
     def step(S, at):
         x_t, dt_t, a_t, b_t, c_t = at
-        write = jnp.einsum("bn,bhp->bhnp", b_t, dt_t[..., None] * x_t, precision=_HI)
+        write = jnp.einsum("bhn,bhp->bhnp", b_t, dt_t[..., None] * x_t, precision=_HI)
         S = a_t[..., None, None] * S + write
-        return S, jnp.einsum("bn,bhnp->bhp", c_t, S, precision=_HI)
+        return S, jnp.einsum("bhn,bhnp->bhp", c_t, S, precision=_HI)
 
     time_first = lambda y: jnp.moveaxis(y, 1, 0)
     S0 = jnp.zeros((B, H, b.shape[-1], P), _F32)
@@ -139,29 +152,37 @@ def plain_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
     g = -jnp.exp(a_log.astype(_F32)) * dt  # log decay, <= 0
     v = (dt[..., None] * x.astype(_F32)).astype(dtype)
     v, g = _in_chunks(v, chunk), _in_chunks(g, chunk)  # [n, B, H, L, P], [n, B, H, L]
-    # One group: B and C as one "head", [n, B, L, N].
-    bc, cc = (_in_chunks(y[:, :, None].astype(dtype), chunk)[:, :, 0] for y in (b, c))
+    # A group's B and C as one "head", [n, B, G, L, N].
+    bc, cc = (_in_chunks(_by_group(y).astype(dtype), chunk) for y in (b, c))
+    groups, N = bc.shape[2], bc.shape[-1]
+    # [n, B, H, ...] <-> [n, B, G, H / G, ...]: a group's heads side by side.
+    grouped = lambda y: y.reshape(y.shape[:2] + (groups, H // groups) + y.shape[3:])
+    per_head = lambda y: y.reshape(y.shape[:2] + (H,) + y.shape[4:])
     G = jnp.cumsum(g, axis=-1)
     G_end = G[..., -1:]
 
-    # Inside a chunk: one C B^T for all the heads under each head's mask.
-    cb = jnp.einsum("nbtk,nbsk->nbts", cc, bc, preferred_element_type=_F32)
+    # Inside a chunk: one C B^T a group under each of its heads' masks.
+    cb = jnp.einsum("nbgtk,nbgsk->nbgts", cc, bc, preferred_element_type=_F32)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     mask = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
-    scores = (cb[:, :, None] * mask).astype(dtype)  # [n, B, H, L, L]
+    scores = per_head(cb[:, :, :, None] * grouped(mask)).astype(dtype)  # [n, B, H, L, L]
     y = jnp.einsum("nbhts,nbhsp->nbhtp", scores, v, preferred_element_type=_F32)
 
-    # What a chunk adds to the state, all the heads at once.
+    # What a chunk adds to the state, a group's heads at once.
     v_end = (jnp.exp(G_end - G)[..., None] * v.astype(_F32)).astype(dtype)
-    wrote = jnp.einsum("nbsk,nbhsp->nbhkp", bc, v_end, preferred_element_type=_F32)
+    wrote = per_head(
+        jnp.einsum("nbgsk,nbgjsp->nbgjkp", bc, grouped(v_end), preferred_element_type=_F32)
+    )
 
     def step(S, at):
         decay_c, wrote_c = at
         return decay_c * S + wrote_c, S.astype(dtype)
 
-    S0 = jnp.zeros((B, H, b.shape[-1], P), _F32)
+    S0 = jnp.zeros((B, H, N, P), _F32)
     _, S_at = lax.scan(step, S0, (jnp.exp(G_end)[..., None], wrote))
-    carried = jnp.einsum("nbtk,nbhkp->nbhtp", cc, S_at, preferred_element_type=_F32)
+    carried = per_head(
+        jnp.einsum("nbgtk,nbgjkp->nbgjtp", cc, grouped(S_at), preferred_element_type=_F32)
+    )
     y = y + jnp.exp(G)[..., None] * carried
     # [n, B, H, L, P] -> [B, T, H, P]
     y = jnp.moveaxis(jnp.moveaxis(y, 2, 3), 0, 1).reshape(B, -1, H, P)[:, :T]
@@ -178,11 +199,14 @@ def plain_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
 # live in VMEM.  Both kernels read the flat ``[B, T, H * P]`` view that the
 # mixer's projection writes (a head is ``P`` lanes; ``[B, T, H, P]`` on the
 # chip's ``(8, 128)`` tiles would be a relayout on either side: PERF.md, PR
-# 33) and walk a grid (batch, chunk, group of heads), the groups innermost:
-# a chunk's ``B`` and ``C`` tiles stay resident while its groups go by, and
-# the first group's step makes ``C B^T`` once for all of them.  A step
-# takes the group's decays ``[L, heads]`` (``dt`` is handed over group by
-# group, so a head's column is a static lane), makes ``G`` as a column and
+# 33) and walk a grid (batch, chunk, set of heads), the sets innermost.  A
+# set is the heads of one grid step: sixteen at most, and never of two of
+# ``B`` and ``C``'s groups (Granite's one group is four sets of sixteen
+# heads, each of Nemotron's eight groups one set of eight).  A group's
+# ``B`` and ``C`` tiles stay resident while its sets go by, and its first
+# set's step makes ``C B^T`` once for all of them.  A step
+# takes the set's decays ``[L, heads]`` (``dt`` is handed over set by
+# set, so a head's column is a static lane), makes ``G`` as a column and
 # as a row a head by two small products with a matrix of ones (float32
 # exactly: the three bfloat16 pieces of ``g``), and then, 128 lanes of
 # ``x`` at a time: the read of the carried state for those lanes, each
@@ -190,16 +214,17 @@ def plain_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
 # (128 rows of the ``[L, L]`` mask at a time, up to their diagonal: what
 # lies above it is never made; the other heads' lanes of ``v`` zeroed: the
 # 128-wide output costs the MXU what a 64-wide one would), the ``D`` skip,
-# and the lanes' part of the state's update.  The state of every head is scratch ``[groups, N,
-# lanes]`` float32 (2 MB at Granite's sizes), carried from chunk to chunk.
+# and the lanes' part of the state's update.  The state of every head is scratch ``[sets, N,
+# lanes]`` float32 (2 MB at 64 heads of 64 over a state of 128), carried from chunk to chunk.
 #
 # The backward walks the chunks in reverse with the state's cotangent in
 # that scratch and makes ``C B^T``, ``G`` and the masks again.  With ``W =
 # C B^T . M`` a head's scores, ``dW = dy v^T``: ``dv = W^T dy``, the
-# cotangent of ``C B^T`` is the sum over heads of ``dW . M`` (a value of
-# the step, then scratch across the groups; ``dB`` and ``dC`` take it
-# through two products when the last group is done, and the state's part
-# of them sums over the lanes inside the products), and ``dG_t`` is the
+# cotangent of a group's ``C B^T`` is the sum over its heads of ``dW . M``
+# (a value of the step, then scratch across the group's sets; ``dB`` and
+# ``dC`` take it through two products when the group's last set is done,
+# and the state's part of them sums over the lanes inside the products:
+# both sum over a group's heads and no further), and ``dG_t`` is the
 # row sum less the column sum of ``dW . W`` plus what the three factors
 # ``e^{G_t}``, ``e^{G_L - G_s}`` and ``e^{G_L}`` owe; ``dg`` is the reverse
 # running sum of ``dG`` inside the chunk, again by products with ones (the
@@ -216,23 +241,28 @@ _KERNEL_HEADS = (16, 8, 4, 2)  # heads a grid step, the most that divides
 _KERNEL_VMEM_BYTES = 64 * 1024 * 1024  # of v5e's 128 MiB
 
 
-def _kernel_heads(H: int, P: int):
-    """Heads a grid step: whole 128-lane tiles of ``x``, or None."""
+def _kernel_heads(per_group: int, P: int):
+    """Heads a grid step (a set) for groups of ``per_group`` heads: whole
+    128-lane tiles of ``x`` inside one group, or None."""
     return next(
-        (m for m in _KERNEL_HEADS if H % m == 0 and (m * P) % _LANES == 0), None
+        (m for m in _KERNEL_HEADS if per_group % m == 0 and (m * P) % _LANES == 0),
+        None,
     )
 
 
 def kernel_admissible(x, b, c, *, chunk: int) -> bool:
     """Whether the kernels take this call: heads of 64 or 128 channels
-    that fill whole 128-lane tiles side by side, a state of whole lane
-    tiles, a chunk of 256 or 512, one dtype for ``x``, ``B`` and ``C``.
-    Visible at trace time; the backend is the caller's question."""
+    that fill whole 128-lane tiles side by side within a group of ``B``
+    and ``C``, a state of whole lane tiles, a chunk of 256 or 512, one
+    dtype for ``x``, ``B`` and ``C``.  Visible at trace time; the backend
+    is the caller's question."""
     H, P = x.shape[2:]
+    groups = _groups(b)
     return (
         x.dtype == b.dtype == c.dtype
         and P in _KERNEL_HEAD_DIMS
-        and _kernel_heads(H, P) is not None
+        and H % groups == 0
+        and _kernel_heads(H // groups, P) is not None
         and b.shape[-1] % _LANES == 0
         and chunk in _KERNEL_CHUNKS
     )
@@ -250,7 +280,7 @@ def _pieces(x):
 
 
 def _step_terms(dt_ref, a_ref):
-    """What the heads of a grid step share: ``dt`` and ``g`` ``[L,
+    """What the heads of a grid step (a set) share: ``dt`` and ``g`` ``[L,
     heads]``, the running sum ``G`` as columns ``[L, heads]`` and as rows
     ``[heads, L]``, the two triangles of ones."""
     dt = dt_ref[0, 0].astype(_F32)
@@ -330,11 +360,14 @@ def _head_mask(s, a: int, rows, cols):
 
 
 def _ssd_fwd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, *rest, head_dim, keep_states,
+    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, *rest, head_dim, group_sets,
+    keep_states,
 ):
-    """Grid (B, chunks, groups of heads).  ``S_scr`` ``[groups, N, lanes]``
-    holds every head's state, ``cb_scr`` the chunk's ``C B^T``; ``s_ref``
-    (kept for a backward pass) takes the state at the chunk's start."""
+    """Grid (B, chunks, sets of heads), ``group_sets`` sets a group of
+    ``B`` and ``C``.  ``S_scr`` ``[sets, N, lanes]`` holds every head's
+    state, ``cb_scr`` the chunk's ``C B^T`` of the group being worked on;
+    ``s_ref`` (kept for a backward pass) takes the state at the chunk's
+    start."""
     s_ref = rest[0] if keep_states else None
     S_scr, cb_scr = rest[-2:]
     h = pl.program_id(2)
@@ -345,7 +378,7 @@ def _ssd_fwd_kernel(
     def _start():
         S_scr[h] = jnp.zeros(S_scr.shape[1:], _F32)
 
-    @pl.when(h == 0)
+    @pl.when(lax.rem(h, group_sets) == 0)
     def _scores():
         cb_scr[...] = _mm(c, b, _NT)
 
@@ -378,13 +411,14 @@ def _ssd_fwd_kernel(
 def _ssd_bwd_kernel(
     x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, s_ref, dy_ref,
     dx_ref, ddt_ref, da_ref, db_ref, dc_ref, dd_ref,
-    dS_scr, cb_scr, dcb_scr, db_scr, dc_scr, dGt_scr, *, head_dim,
+    dS_scr, cb_scr, dcb_scr, db_scr, dc_scr, dGt_scr, *, head_dim, group_sets,
 ):
-    """Grid (B, chunks in reverse, groups of heads).  ``dS_scr`` holds the
-    cotangent of every head's state at the end of the chunk being worked
-    on; ``dcb_scr``, ``db_scr`` and ``dc_scr`` add up over a chunk's
-    groups what the heads owe ``C B^T``, ``B`` and ``C``; ``dGt_scr``
-    ``[heads, L]`` takes the column sums a head owes ``G``, as rows."""
+    """Grid (B, chunks in reverse, sets of heads), ``group_sets`` sets a
+    group of ``B`` and ``C``.  ``dS_scr`` holds the cotangent of every
+    head's state at the end of the chunk being worked on; ``dcb_scr``,
+    ``db_scr`` and ``dc_scr`` add up over a group's sets what its heads
+    owe ``C B^T``, ``B`` and ``C``; ``dGt_scr`` ``[heads, L]`` takes the
+    column sums a head owes ``G``, as rows."""
     h = pl.program_id(2)
     P, dtype = head_dim, x_ref.dtype
     L, width = x_ref.shape[1:]
@@ -395,7 +429,7 @@ def _ssd_bwd_kernel(
     def _start():
         dS_scr[h] = jnp.zeros(dS_scr.shape[1:], _F32)
 
-    @pl.when(h == 0)
+    @pl.when(lax.rem(h, group_sets) == 0)
     def _scores():
         cb_scr[...] = _mm(c, b, _NT)
         dcb_scr[...] = jnp.zeros(dcb_scr.shape, _F32)
@@ -478,25 +512,28 @@ def _ssd_bwd_kernel(
     db_scr[...] += db_acc
     dc_scr[...] += dc_acc
 
-    @pl.when(h == pl.num_programs(2) - 1)
+    @pl.when(lax.rem(h, group_sets) == group_sets - 1)
     def _shared():
         dcbb = dcb_scr[...].astype(dtype)
         dc_ref[0] = (dc_scr[...] + _mm(dcbb, b)).astype(dc_ref.dtype)
         db_ref[0] = (db_scr[...] + _mm(dcbb, c, _TN)).astype(db_ref.dtype)
 
 
-def _kernel_specs(chunk, heads, width, N, order):
-    """Block specs over the grid (batch, chunk, group of heads): the
+def _kernel_specs(chunk, heads, width, N, group_sets, order):
+    """Block specs over the grid (batch, chunk, set of heads): the
     ``[chunk, lanes]`` tile of ``x`` (and of whatever has its shape), the
-    group's ``dt`` ``[chunk, heads]``, ``A_log`` and ``D`` for the group,
-    the chunk's ``B`` or ``C``, the group's state at the chunk's start,
+    set's ``dt`` ``[chunk, heads]``, ``A_log`` and ``D`` for the set,
+    the chunk's ``B`` or ``C`` of the set's group (``N`` lanes of the
+    flat ``[B, T, G * N]``), the set's state at the chunk's start,
     and the rows a backward step owes ``D`` and ``A_log``; ``order`` maps
     the grid's chunk to the array's (the backward sweeps in reverse)."""
     return types.SimpleNamespace(
         x=pl.BlockSpec((1, chunk, width), lambda i, t, h: (i, order(t), h)),
         dt=pl.BlockSpec((1, 1, chunk, heads), lambda i, t, h: (i, h, order(t), 0)),
         a=pl.BlockSpec((1, 1, heads), lambda i, t, h: (h, 0, 0)),
-        b=pl.BlockSpec((1, chunk, N), lambda i, t, h: (i, order(t), 0)),
+        b=pl.BlockSpec(
+            (1, chunk, N), lambda i, t, h: (i, order(t), lax.div(h, group_sets))
+        ),
         d=pl.BlockSpec((1, width), lambda i, t, h: (0, h)),
         state=pl.BlockSpec((1, 1, N, width), lambda i, t, h: (i, order(t), 0, h)),
         d_row=pl.BlockSpec((1, 1, 1, width), lambda i, t, h: (i, order(t), 0, h)),
@@ -505,11 +542,20 @@ def _kernel_specs(chunk, heads, width, N, order):
 
 
 def _kernel_geometry(x, dt, b, chunk):
-    """From ``x`` ``[B, T, H * P]``, ``dt`` by groups ``[B, groups, T,
-    heads]`` and ``b`` ``[B, T, N]``."""
+    """From ``x`` ``[B, T, H * P]``, ``dt`` by sets ``[B, sets, T,
+    heads]`` and ``b`` ``[B, T, G, N]``."""
     B, T, lanes = x.shape
-    groups, heads = dt.shape[1], dt.shape[3]
-    return B, T // chunk, groups, heads, lanes // groups, b.shape[-1]
+    sets, heads = dt.shape[1], dt.shape[3]
+    return types.SimpleNamespace(
+        B=B, n=T // chunk, sets=sets, heads=heads, width=lanes // sets,
+        N=b.shape[-1], group_sets=sets // b.shape[2],
+    )
+
+
+def _group_lanes(b):
+    """``B`` or ``C`` ``[B, T, G, N]`` as the kernels read it: a group is
+    ``N`` lanes of ``[B, T, G * N]``."""
+    return b.reshape(*b.shape[:2], -1)
 
 
 def _kernel_params():
@@ -523,61 +569,70 @@ def _kernel_forward(x, dt, a_log, b, c, d, *, chunk, keep_states, interpret):
     """``(y [B, T, H * P], kept)``, ``kept`` the states at the chunks'
     starts ``[B, T / chunk, N, H * P]`` (float32) or ``()``; ``T`` a
     multiple of ``chunk``."""
-    B, n, groups, heads, width, N = _kernel_geometry(x, dt, b, chunk)
-    spec = _kernel_specs(chunk, heads, width, N, lambda t: t)
+    k = _kernel_geometry(x, dt, b, chunk)
+    spec = _kernel_specs(chunk, k.heads, k.width, k.N, k.group_sets, lambda t: t)
     vma = _vma(x)
     out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)]
     out_specs = [spec.x]
     if keep_states:
-        out_shape.append(jax.ShapeDtypeStruct((B, n, N, x.shape[2]), _F32, vma=vma))
+        out_shape.append(
+            jax.ShapeDtypeStruct((k.B, k.n, k.N, x.shape[2]), _F32, vma=vma)
+        )
         out_specs.append(spec.state)
     call = pl.pallas_call(
         functools.partial(
-            _ssd_fwd_kernel, head_dim=width // heads, keep_states=keep_states
+            _ssd_fwd_kernel, head_dim=k.width // k.heads,
+            group_sets=k.group_sets, keep_states=keep_states,
         ),
-        grid=(B, n, groups),
+        grid=(k.B, k.n, k.sets),
         in_specs=[spec.x, spec.dt, spec.a, spec.b, spec.b, spec.d],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((groups, N, width), _F32),
+            pltpu.VMEM((k.sets, k.N, k.width), _F32),
             pltpu.VMEM((chunk, chunk), _F32),
         ],
         compiler_params=_kernel_params(),
         interpret=interpret,
     )
     with jax.named_scope(SSD_CORE_SCOPE):
-        res = call(x, dt, a_log, b, c, d)
+        res = call(x, dt, a_log, _group_lanes(b), _group_lanes(c), d)
     return res[0], tuple(res[1:])
 
 
 def _kernel_backward(x, dt, a_log, b, c, d, states, dy, *, chunk, interpret):
-    """The cotangents of ``x``, ``dt`` (by groups, like ``dt``), ``b`` and
-    ``c``, and a row a chunk and group of what ``A_log`` ``[B, T / chunk,
-    groups, 1, heads]`` and ``D`` ``[B, T / chunk, 1, H * P]`` are owed."""
-    B, n, groups, heads, width, N = _kernel_geometry(x, dt, b, chunk)
-    spec = _kernel_specs(chunk, heads, width, N, lambda t: n - 1 - t)
+    """The cotangents of ``x``, ``dt`` (by sets, like ``dt``), ``b`` and
+    ``c`` (as lanes, ``[B, T, G * N]``), and a row a chunk and set of what
+    ``A_log`` ``[B, T / chunk, sets, 1, heads]`` and ``D`` ``[B, T /
+    chunk, 1, H * P]`` are owed."""
+    k = _kernel_geometry(x, dt, b, chunk)
+    spec = _kernel_specs(
+        chunk, k.heads, k.width, k.N, k.group_sets, lambda t: k.n - 1 - t
+    )
+    b, c = _group_lanes(b), _group_lanes(c)
     vma = _vma(x)
     like = lambda y: jax.ShapeDtypeStruct(y.shape, y.dtype, vma=vma)
-    rows = lambda *shape: jax.ShapeDtypeStruct((B, n) + shape, _F32, vma=vma)
+    rows = lambda *shape: jax.ShapeDtypeStruct((k.B, k.n) + shape, _F32, vma=vma)
     call = pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, head_dim=width // heads),
-        grid=(B, n, groups),
+        functools.partial(
+            _ssd_bwd_kernel, head_dim=k.width // k.heads, group_sets=k.group_sets
+        ),
+        grid=(k.B, k.n, k.sets),
         in_specs=[
             spec.x, spec.dt, spec.a, spec.b, spec.b, spec.d, spec.state, spec.x,
         ],
         out_specs=[spec.x, spec.dt, spec.a_row, spec.b, spec.b, spec.d_row],
         out_shape=[
-            like(x), like(dt), rows(groups, 1, heads), like(b), like(c),
+            like(x), like(dt), rows(k.sets, 1, k.heads), like(b), like(c),
             rows(1, x.shape[2]),
         ],
         scratch_shapes=[
-            pltpu.VMEM((groups, N, width), _F32),
+            pltpu.VMEM((k.sets, k.N, k.width), _F32),
             pltpu.VMEM((chunk, chunk), _F32),
             pltpu.VMEM((chunk, chunk), _F32),
-            pltpu.VMEM((chunk, N), _F32),
-            pltpu.VMEM((chunk, N), _F32),
-            pltpu.VMEM((heads, chunk), _F32),
+            pltpu.VMEM((chunk, k.N), _F32),
+            pltpu.VMEM((chunk, k.N), _F32),
+            pltpu.VMEM((k.heads, chunk), _F32),
         ],
         compiler_params=_kernel_params(),
         interpret=interpret,
@@ -586,7 +641,7 @@ def _kernel_backward(x, dt, a_log, b, c, d, states, dy, *, chunk, interpret):
         return call(x, dt, a_log, b, c, d, states, dy)
 
 
-def _by_groups(dt, heads):
+def _by_sets(dt, heads):
     """``[B, T, H]`` -> ``[B, H / heads, T, heads]``: a grid step's heads
     as the lanes of its tile."""
     B, T, H = dt.shape
@@ -598,11 +653,11 @@ def _kernel_operands(x, dt, a_log, b, c, d, chunk):
     chunks with tokens that leave the state alone (``dt`` 0)."""
     H = dt.shape[-1]
     P = x.shape[-1] // H
-    heads = _kernel_heads(H, P)
+    heads = _kernel_heads(H // b.shape[2], P)
     pad = -x.shape[1] % chunk
     x, dt, b, c = (_padded(y, pad) for y in (x, dt, b, c))
     return (
-        x, _by_groups(dt, heads), a_log.reshape(H // heads, 1, heads), b, c,
+        x, _by_sets(dt, heads), a_log.reshape(H // heads, 1, heads), b, c,
         jnp.repeat(d, P)[None],
     )
 
@@ -612,7 +667,7 @@ def kernel_ssd_flat(x, dt, a_log, b, c, d, chunk=256, interpret=False):
     """The chunk-wise scan as Pallas kernels (section comment above),
     forward and backward, on the flat view the kernels read: ``x`` ``[B,
     T, H * P]``, ``dt`` ``[B, T, H]``, ``a_log`` and ``d`` ``[H]``, ``b``,
-    ``c`` ``[B, T, N]``; returns ``[B, T, H * P]``.  ``interpret=True``
+    ``c`` ``[B, T, G, N]``; returns ``[B, T, H * P]``.  ``interpret=True``
     runs the same kernels on the CPU for tests."""
     return _kernel_fwd(x, dt, a_log, b, c, d, chunk, interpret, False)[0]
 
@@ -636,10 +691,11 @@ def _kernel_bwd(chunk, interpret, res, dy):
         *res, _padded(dy, -T % chunk), chunk=chunk, interpret=interpret
     )
     ddt = jnp.swapaxes(ddt, 1, 2).reshape(ddt.shape[0], -1, H)
+    by_group = lambda y: y.reshape(res[3].shape)[:, :T]
     return (
         dx[:, :T], ddt[:, :T],
         jnp.sum(da, axis=(0, 1)).reshape(H).astype(a_log.dtype),
-        db[:, :T], dc[:, :T],
+        by_group(db), by_group(dc),
         jnp.sum(dd, axis=(0, 1, 2)).reshape(H, -1).sum(axis=1).astype(d.dtype),
     )
 
@@ -655,7 +711,10 @@ def kernel_ssd(x, dt, a_log, b, c, d_skip=None, chunk=256, interpret=False):
     drops both)."""
     B, T, H, P = x.shape
     d = jnp.zeros((H,), _F32) if d_skip is None else d_skip
-    y = kernel_ssd_flat(x.reshape(B, T, H * P), dt, a_log, b, c, d, chunk, interpret)
+    y = kernel_ssd_flat(
+        x.reshape(B, T, H * P), dt, a_log, _by_group(b), _by_group(c), d, chunk,
+        interpret,
+    )
     return y.reshape(B, T, H, P)
 
 
@@ -674,13 +733,15 @@ def ssd_route(x, dt, a_log, b, c, *, chunk: int) -> str:
 
 def chunked_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
     """:func:`recurrent_ssd` computed chunk-wise (module docstring); same
-    arguments, the result in the dtype of ``x``.  On a TPU, for whole
-    tiles, the Pallas kernels (:func:`kernel_ssd`: 1.37 ms forward and
+    arguments (``b``, ``c`` ``[B, T, G, N]`` with ``G`` dividing ``H``, or
+    ``[B, T, N]`` for one group), the result in the dtype of ``x``.  On a
+    TPU, for whole tiles, the Pallas kernels (:func:`kernel_ssd`: 1.37 ms forward and
     4.45 with the backward pass at ``[1, 8192, 64, 64]`` over a 128-wide
     state on a v5e, chunks of 256, against :func:`plain_ssd`'s 2.87 and
     10.73 beside them; in ``granite_h_train``'s step, where the mixer's
     reshapes cancel the 4-D view's relayout, two forward passes and the
-    backward of a layer are 2.55 ms, 10.25 plain; PERF.md, PR 39), else
+    backward of a layer are 2.55 ms, 10.25 plain; PERF.md, PR 39; with
+    eight groups of eight heads in ``nemotron_h_train`` 2.98 ms; PR 40), else
     :func:`plain_ssd`; the choice is counted once per traced
     call.  ``chunk`` is the program's way to compute the recurrence and no
     part of the model (``mamba_chunk_size`` 256 is the published kernel's
@@ -690,13 +751,14 @@ def chunked_ssd(x, dt, a_log, b, c, d_skip=None, *, chunk: int = 256):
     head's masks ``T x chunk`` (PERF.md, PR 38); the kernels keep the
     states alone."""
     if not (
-        x.shape[:3] == dt.shape and b.shape == c.shape
+        x.shape[:3] == dt.shape and b.shape == c.shape and b.ndim in (3, 4)
         and b.shape[:2] == x.shape[:2] and a_log.shape == x.shape[2:3]
+        and x.shape[2] % _groups(b) == 0
     ):
         raise ValueError(
             f"chunked_ssd wants x [B, T, H, P], dt [B, T, H], a_log [H] and "
-            f"b, c [B, T, N] (one group); got {x.shape}, {dt.shape}, "
-            f"{a_log.shape}, {b.shape}, {c.shape}"
+            f"b, c [B, T, G, N] with G dividing H ([B, T, N]: one group); "
+            f"got {x.shape}, {dt.shape}, {a_log.shape}, {b.shape}, {c.shape}"
         )
     route = ssd_route(x, dt, a_log, b, c, chunk=chunk)
     get_registry().counter(

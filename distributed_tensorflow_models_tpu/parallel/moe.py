@@ -21,8 +21,11 @@ uses one-hot matmuls, and capacity masking is a multiply.
 
 Beside it, for the models that state it (OLMoE and its successors,
 ROADMAP R1-R4): :func:`topk_moe_ffn`, softmax-then-top-k routing over
-gated experts with **no capacity**: every one of a token's ``top_k``
-assignments is computed.  The assignments are sorted by expert, the rows
+experts with **no capacity**: every one of a token's ``top_k``
+assignments is computed.  An expert is three matrices with a SiLU gate
+(OLMoE, Kimi Linear) or two around a squared ReLU without a gate (the
+Nemotron-H family), by whether ``params`` holds ``w_gate``; both go
+through one function (:func:`_expert_rows`).  The assignments are sorted by expert, the rows
 gathered, each projection is one grouped matrix product over contiguous
 groups of uneven size (the Pallas grouped matmul that ships with jax,
 ``megablox``: static shapes, the group sizes are data), and the weighted
@@ -34,7 +37,8 @@ drops the instruction's ``op_name``, and with it the scopes below.
 
 The same layer is one chip's share of an expert-parallel deployment when
 it is told which experts it holds (``held = (first, count)``; Kimi
-Linear's 256 experts over 32 chips are 8 here): the router keeps every
+Linear's 256 experts over 32 chips are 8 here, Nemotron 3 Nano's 128 over
+16 chips too): the router keeps every
 output and the top-k runs over all of them, the assignments are sorted
 with the held experts first, and the rows of that prefix are gathered,
 multiplied and added back slab by slab (:func:`_held_local`; rows of a
@@ -243,12 +247,12 @@ def moe_ffn_reference(
     )
 
 
-# --- Exact top-k routing over gated experts (no capacity) ----------------
+# --- Exact top-k routing over experts (no capacity) ----------------------
 
 # ``jax.named_scope`` names of the expert layer, path elements of every
 # instruction's ``op_name`` in the compiled step (PERF.md section 3): the
 # whole layer; routing, sort, gather and the weighted sum back; the
-# grouped products and the gate.
+# grouped products and the activation.
 MOE_SCOPE = "moe"
 MOE_DISPATCH_SCOPE = "moe_dispatch"
 MOE_EXPERTS_SCOPE = "moe_experts"
@@ -369,6 +373,35 @@ def route_topk(
     return logits, probs, weight, expert
 
 
+def squared_relu(x):
+    """``relu(x)^2``: the activation of the Nemotron-H family's
+    feed-forwards, dense, shared and routed alike."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _expert_stacks(params: dict) -> tuple:
+    """The expert matrices in the order :func:`_expert_rows` takes them:
+    ``(w_gate, w_up, w_down)`` of gated SiLU experts, or ``(w_up,
+    w_down)`` of experts without a gate (squared ReLU)."""
+    names = ("w_gate", "w_up", "w_down") if "w_gate" in params else ("w_up", "w_down")
+    return tuple(params[name] for name in names)
+
+
+def _expert_rows(rows, stacks, grouped, dtype):
+    """Sorted ``rows`` through their experts, ``grouped`` the product
+    with each group's own matrix: ``W_down (silu(W_gate h) * W_up h)`` for
+    three stacks, ``W_down relu(W_up h)^2`` for two; the activation in
+    float32."""
+    *inner, w_down = stacks
+    into = lambda w: grouped(rows, w.astype(dtype))
+    if len(inner) == 2:
+        gate, up = into(inner[0]), into(inner[1])
+        hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    else:
+        hidden = squared_relu(into(inner[0]).astype(jnp.float32))
+    return grouped(hidden.astype(dtype), w_down.astype(dtype))
+
+
 def _routing_statistics(counts, probs, logits, n: int, top_k: int):
     """``(aux, z, load)`` of one rank's step: the load-balancing loss ``E
     sum_e f_e P_e``, the router z-loss and the fullest expert's
@@ -408,12 +441,7 @@ def _topk_local(
     with jax.named_scope(MOE_EXPERTS_SCOPE):
         rows, sizes = _pad_rows(rows, counts)
         grouped = functools.partial(grouped_matmul, group_sizes=sizes)
-        gate = grouped(rows, params["w_gate"].astype(dtype))
-        up = grouped(rows, params["w_up"].astype(dtype))
-        hidden = (
-            jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        ).astype(dtype)
-        down = grouped(hidden, params["w_down"].astype(dtype))[: n * top_k]
+        down = _expert_rows(rows, _expert_stacks(params), grouped, dtype)[: n * top_k]
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         back = _permute_rows(down, inverse, order).reshape(n, top_k, d)
         out = jnp.sum(
@@ -442,19 +470,13 @@ def _slab(x, stacks, share, token, sizes, dtype):
     rows of each held expert and, last, the rows that are no held
     expert's (they come out of the grouped product as zeros).  Returns
     the weighted rows ``[R, d]`` in float32."""
-    w_gate, w_up, w_down = stacks
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         rows = x[token]
     with jax.named_scope(MOE_EXPERTS_SCOPE):
         grouped = functools.partial(
             grouped_matmul, group_sizes=sizes, leading=True
         )
-        gate = grouped(rows, w_gate.astype(dtype))
-        up = grouped(rows, w_up.astype(dtype))
-        hidden = (
-            jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        ).astype(dtype)
-        down = grouped(hidden, w_down.astype(dtype))
+        down = _expert_rows(rows, stacks, grouped, dtype)
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         return down.astype(jnp.float32) * share[:, None]
 
@@ -563,8 +585,7 @@ def _held_local(params, x, top_k, dtype, routing, held):
         share = jnp.pad(weight.reshape(assignments)[order], pad)
         token = jnp.pad(order // top_k, pad)
     out = _held_experts(
-        x, (params["w_gate"], params["w_up"], params["w_down"]),
-        share, token, offsets, rows, dtype,
+        x, _expert_stacks(params), share, token, offsets, rows, dtype
     )
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         aux, z, load = _routing_statistics(counts, probs, logits, n, top_k)
@@ -583,12 +604,13 @@ def topk_moe_ffn(
     routing: Routing = Routing(),
     held: Optional[tuple[int, int]] = None,
 ) -> TopKMoEOutput:
-    """Top-k routing over gated (SiLU) experts, exactly:
-    ``y = sum_{e in top_k} p_e * W_down_e (silu(W_gate_e h) * W_up_e h)``
-    with the ``p_e`` the softmax's as they are (not renormalised), or what
-    ``routing`` states.  ``x`` is ``[batch, time, d_model]``; ``params``
-    holds ``router`` [d, E] and the expert stacks ``w_gate``, ``w_up``
-    [E, d, f] and ``w_down`` [E, f, d].  With ``held = (first, count)``
+    """Top-k routing over experts, exactly: ``y = sum_{e in top_k} p_e *
+    W_down_e (silu(W_gate_e h) * W_up_e h)`` with the ``p_e`` the
+    softmax's as they are (not renormalised), or what ``routing`` states.
+    ``x`` is ``[batch, time, d_model]``; ``params`` holds ``router`` [d,
+    E] and the expert stacks ``w_gate``, ``w_up`` [E, d, f] and ``w_down``
+    [E, f, d]; without ``w_gate`` an expert is ``W_down_e relu(W_up_e
+    h)^2`` (two matrices, no gate).  With ``held = (first, count)``
     the stacks hold ``count`` experts, ``first`` onwards, of the router's
     ``E``, and the sum runs over the chosen experts that are held (module
     docstring); ``held_share`` says how many of the assignments that was.
@@ -601,11 +623,11 @@ def topk_moe_ffn(
     d = x.shape[-1]
     num_experts = params["router"].shape[-1]
     if held is not None and not (
-        0 <= held[0] and held[1] == params["w_gate"].shape[0]
+        0 <= held[0] and held[1] == params["w_down"].shape[0]
         and held[0] + held[1] <= num_experts
     ):
         raise ValueError(
-            f"held {held} against {params['w_gate'].shape[0]} expert "
+            f"held {held} against {params['w_down'].shape[0]} expert "
             f"matrices and {num_experts} router outputs"
         )
     local = lambda p, xl: _topk_local(

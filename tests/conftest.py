@@ -40,6 +40,55 @@ startuplib.apply_compile_cache()
 
 import pytest  # noqa: E402
 
+# The files whose cases take the longest (compiles of whole models and
+# interpreted kernels), in the order they run: after everything else, the
+# ones with the longest single cases first.  Some 1,100 of the suite's
+# cases take a fifth of its time; with them out of the way first, a
+# machine too slow for the run's time limit loses a few long cases at the
+# cut and not hundreds of quick ones (PR 40: the driver's run was cut at
+# 1,491 s with 1,074 of 1,424 cases counted, the long files in the middle).
+_LONG_FILES_LAST = (
+    "tests/test_chip_compile.py",
+    "tests/benchmark/test_bench_run_files.py",
+    "tests/benchmark/test_bench_rehearse.py",
+    "tests/benchmark/test_bench_startup.py",
+    "tests/test_kimi_linear.py",
+    "tests/test_olmo_hybrid.py",
+    "tests/test_olmoe_block.py",
+    "tests/test_conv_impl.py",
+    "tests/test_transformer.py",
+    "tests/test_granite_h.py",
+    "tests/test_nemotron_h.py",
+    "tests/test_ssm_kernel.py",
+)
+
+
+def _long_place(nodeid: str) -> int:
+    """0 for a case of any other file, else 1 + the file's place above."""
+    file = nodeid.split("::", 1)[0]
+    return next((1 + i for i, name in enumerate(_LONG_FILES_LAST) if file.endswith(name)), 0)
+
+
+def pytest_collection_modifyitems(session, config, items):
+    items.sort(key=lambda item: _long_place(item.nodeid))  # stable: collection order within a place
+
+
+def pytest_xdist_make_scheduler(config, log):
+    """Under xdist a file's cases stay together on one worker, in their
+    order (several files hold one expensive module-scoped fixture, a model
+    and its plain reference with every gradient, which ``--dist load``
+    made two or three workers pay for, up to 350 s each; some cases lean
+    on what an earlier case of their file left in the process); the cases
+    of the long files above are handed out one by one as workers run dry,
+    which is what balances the run's second half."""
+    from xdist.scheduler import LoadScopeScheduling
+
+    class _FilesThenLongCases(LoadScopeScheduling):
+        def _split_scope(self, nodeid):
+            return nodeid if _long_place(nodeid) else nodeid.split("::", 1)[0]
+
+    return _FilesThenLongCases(config, log)
+
 
 @pytest.fixture(scope="session")
 def mesh8():

@@ -10,9 +10,10 @@ data-parallel step over four devices compiling on every later PR.
 Nothing runs and nothing here is a measurement.  Only the fast compiles
 are kept (about a second or two each); the whole ResNet-50 b256 step
 (~40 s) and the ``conv2d_mxu`` gradient at 56x56x64 (~18 s) stay in the
-builder's rehearsal.  Two whole steps are here all the same, ISSUE 32's
-and ISSUE 38's: ``olmo_hybrid_train``'s (45 s) and ``granite_h_train``'s
-(50 s), because those cells' batch and the scan's chunk were chosen by
+builder's rehearsal.  Three whole steps are here all the same, ISSUE
+32's, ISSUE 38's and ISSUE 40's: ``olmo_hybrid_train``'s (45 s),
+``granite_h_train``'s (50 s) and ``nemotron_h_train``'s (about a minute),
+because those cells' batch and the scan's chunk were chosen by
 what the compiler places, and a later PR's temporary could undo it.  The persistent cache is switched off around the
 cases: an executable compiled for a described chip is written to it but
 cannot be read back without one, and the next run would warn.
@@ -456,14 +457,11 @@ def test_chunked_gdn_compiles_for_v5e(v5e, monkeypatch, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
 
 
-def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
-    """The whole ``olmo_hybrid_train`` step (the cell's configuration
-    through ``benchmark/lib/cells.py``, Adam with the clip, the fused
-    head, per-half recomputation, one sequence of 8,192) for one
-    described v5e: it fits the chip's 15.75 GiB with room (13.15 GiB when
-    the cell was added: 8.56 of state, 4.41 of temporaries; PERF.md, PR
-    32), the compiler rematerializes nothing of its own, the attention
-    layer runs the fused kernels and the three scopes are on the step."""
+def _cell_step_compiled(v5e, monkeypatch, cell_name):
+    """``(compiled step, GiB it holds, abstract state, ssd/route_kernel
+    counted while tracing)`` of a cell's configuration (through
+    ``benchmark/lib/cells.py``: Adam with the clip, the fused head, the
+    cell's recomputation and batch) for one described v5e."""
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -471,16 +469,19 @@ def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
 
     from distributed_tensorflow_models_tpu.harness import train as trainlib
     from distributed_tensorflow_models_tpu.harness.config import get_config
+    from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    cell = cells.load_cell("olmo_hybrid_train")
+    cell = cells.load_cell(cell_name)
     per_chip = cell.traffic["fit"]["per_chip_batch"]
     cfg = get_config(
         cell.config["program_config"], **cell.config["overrides"], global_batch_size=per_chip
     )
     assert (per_chip, cfg.num_steps, cfg.fused_unembed) == (1, 8192, True)
     model = get_model(cfg.model, **cfg.model_kwargs)
+    kernel_route = reglib.get_registry().counter(reglib.SSD_ROUTE_KERNEL)
+    before = kernel_route.value
     state = jax.eval_shape(
         lambda: TrainState.create(
             model, cfg.optimizer.make(), jax.random.key(0),
@@ -500,7 +501,19 @@ def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
         m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
         - m.alias_size_in_bytes + m.generated_code_size_in_bytes
     )
-    assert 12.0 * 2**30 < held < 14.5 * 2**30, held / 2**30
+    return compiled, held / 2**30, state, kernel_route.value - before
+
+
+def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
+    """The whole ``olmo_hybrid_train`` step (the cell's configuration
+    through ``benchmark/lib/cells.py``, Adam with the clip, the fused
+    head, per-half recomputation, one sequence of 8,192) for one
+    described v5e: it fits the chip's 15.75 GiB with room (13.15 GiB when
+    the cell was added: 8.56 of state, 4.41 of temporaries; PERF.md, PR
+    32), the compiler rematerializes nothing of its own, the attention
+    layer runs the fused kernels and the three scopes are on the step."""
+    compiled, held, _, _ = _cell_step_compiled(v5e, monkeypatch, "olmo_hybrid_train")
+    assert 12.0 < held < 14.5, held
     text = compiled.as_text()
     # The attention layer: forward, the recomputed forward, the backward.
     assert text.count("tpu_custom_call") >= 3
@@ -515,22 +528,26 @@ def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
 _SSD_SHAPES = [(1, 8192, 64, 64), (1, 8192, 64), (64,), (1, 8192, 128), (1, 8192, 128), (64,)]
 
 
+@pytest.mark.parametrize("groups", [0, 8], ids=["one_group", "eight_groups"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32_highest"])
-def test_chunked_ssd_compiles_for_v5e(v5e, monkeypatch, dtype):
-    """``chunked_ssd`` forward and backward at the cell's shape and chunk,
-    as the cell runs it (bf16) and as the comparison with the reference
-    runs the float32 program (under ``default_matmul_precision("highest")``):
-    both take the Pallas kernels since PR 39, one forward and one backward
-    under the ``ssd_core`` scope, with no ``while`` left of the plain
-    route's scan over the chunks, and hold a state per chunk and never one
-    per token."""
+def test_chunked_ssd_compiles_for_v5e(v5e, monkeypatch, dtype, groups):
+    """``chunked_ssd`` forward and backward at the cells' shape and chunk
+    (``granite_h_train``'s one ``B`` and ``C`` for all 64 heads, the call
+    without the group axis; ``nemotron_h_train``'s eight groups of eight
+    heads), as the cells run it (bf16) and as the comparison with the
+    reference runs the float32 program (under
+    ``default_matmul_precision("highest")``): all take the Pallas kernels,
+    one forward and one backward Mosaic body under the ``ssd_core`` scope,
+    with no ``while`` left of the plain route's scan over the chunks, and
+    hold a state per chunk and never one per token."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     one_chip = SingleDeviceSharding(v5e.devices[0])
     wide = (0, 3, 4)  # x, B, C in the model's dtype; dt, A_log, D in float32
+    shapes = [(1, 8192, groups, 128) if groups and i in (3, 4) else s for i, s in enumerate(_SSD_SHAPES)]
     args = [
         jax.ShapeDtypeStruct(s, dtype if i in wide else jnp.float32, sharding=one_chip)
-        for i, s in enumerate(_SSD_SHAPES)
+        for i, s in enumerate(shapes)
     ]
     assert ssmlib.ssd_route(*args[:5], chunk=256) == "kernel"
 
@@ -599,47 +616,42 @@ def test_granite_h_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
     rematerialized clones; PERF.md, PR 38), the compiler rematerializes
     nothing of its own, the attention layer runs the fused kernels over
     its grouped heads and the scopes are on the step."""
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from benchmark.lib import cells
-
-    from distributed_tensorflow_models_tpu.harness import train as trainlib
-    from distributed_tensorflow_models_tpu.harness.config import get_config
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
-    cell = cells.load_cell("granite_h_train")
-    per_chip = cell.traffic["fit"]["per_chip_batch"]
-    cfg = get_config(
-        cell.config["program_config"], **cell.config["overrides"], global_batch_size=per_chip
-    )
-    assert (per_chip, cfg.num_steps, cfg.fused_unembed) == (1, 8192, True)
-    model = get_model(cfg.model, **cfg.model_kwargs)
-    state = jax.eval_shape(
-        lambda: TrainState.create(
-            model, cfg.optimizer.make(), jax.random.key(0),
-            jnp.zeros((2, 128), jnp.int32), jit_init=False,
-        )
-    )
+    compiled, held, state, kernel_route = _cell_step_compiled(v5e, monkeypatch, "granite_h_train")
     assert "head" not in state.params  # tied
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-    step = train_loop.make_train_step(trainlib.build_loss(cfg, state), donate=True)
-    tokens = jax.ShapeDtypeStruct((per_chip, cfg.num_steps), jnp.int32, sharding=one_chip)
-    compiled = step.lower(
-        jax.tree.map(spec, state), {"inputs": tokens, "targets": tokens},
-        spec(jax.eval_shape(lambda: jax.random.key(0))),
-    ).compile()
-    m = compiled.memory_analysis()
-    held = (
-        m.temp_size_in_bytes + m.argument_size_in_bytes + m.output_size_in_bytes
-        - m.alias_size_in_bytes + m.generated_code_size_in_bytes
-    )
-    assert 11.0 * 2**30 < held < 13.5 * 2**30, held / 2**30
+    # Nine state-space layers, ``model.init`` and the step: the generalised
+    # scan (groups of heads, PR 40) still takes its kernels at one group.
+    assert kernel_route == 18
+    assert 11.0 < held < 13.5, held
     text = compiled.as_text()
     # The attention layer: forward, the recomputed forward, the backward.
     assert text.count("tpu_custom_call") >= 3
     assert not re.search(r"\.remat\d*", text)
     for scope in ("ssm", "ssd_core", "attention_core", "unembed_loss", "optimizer"):
+        assert re.search(rf"[/(]{scope}[/)]", text), scope
+
+
+def test_nemotron_h_step_compiles_for_v5e_under_its_memory(v5e, monkeypatch):
+    """The whole ``nemotron_h_train`` step (nine one-sub-layer layers
+    ``MEMEM*EME`` at the published widths, 8 of 128 experts held, an eighth
+    of the vocabulary, Adam with the clip, the fused head, every layer
+    recomputed, one sequence of 8,192) for one described v5e: it fits the
+    chip's 15.75 GiB with room (10.97 GiB when the cell was added: 7.45 of
+    state, 3.34 of temporaries; PERF.md, PR 40), the compiler
+    rematerializes nothing of its own, the four state-space layers take
+    the grouped scan's kernels (``model.init`` and the step: 8), the
+    attention layer the fused kernels over sixteen-fold groups, and the
+    scopes of every piece are on the step."""
+    compiled, held, state, kernel_route = _cell_step_compiled(v5e, monkeypatch, "nemotron_h_train")
+    assert sorted(state.params["blocks_0"]) == ["ln1", "ssm"] and sorted(state.params["blocks_1"]) == ["ln2", "moe"]
+    assert "w_gate" not in state.params["blocks_1"]["moe"] and "head" in state.params
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 666_962_944
+    assert kernel_route == 8
+    assert 10.0 < held < 12.5, held
+    text = compiled.as_text()
+    # Four scans and the attention layer, each forward, recomputed and
+    # backward; the experts' grouped products.
+    assert text.count("tpu_custom_call") >= 15
+    assert not re.search(r"\.remat\d*", text)
+    for scope in ("ssm", "ssd_core", "moe", "moe_dispatch", "moe_experts", "moe_shared", "attention_core",
+                  "unembed_loss", "optimizer"):
         assert re.search(rf"[/(]{scope}[/)]", text), scope

@@ -42,7 +42,9 @@ SMALL = {
 
 # --- the chunk-wise state-space scan ----------------------------------------
 
-def _ssd_inputs(seed, T, decay, B=2, H=3, P=8, N=16):
+def _ssd_inputs(seed, T, decay, B=2, H=3, P=8, N=16, G=0):
+    """``G`` 0: ``B`` and ``C`` ``[B, T, N]``, Granite's call; else ``[B,
+    T, G, N]``, a vector a group of ``H / G`` heads."""
     ks = jax.random.split(jax.random.key(seed), 6)
     x = jax.random.normal(ks[0], (B, T, H, P))
     raw = jax.random.normal(ks[1], (B, T, H))
@@ -55,39 +57,45 @@ def _ssd_inputs(seed, T, decay, B=2, H=3, P=8, N=16):
         "near_1": 1e-6 * jax.nn.sigmoid(raw),
     }[decay]
     a_log = jnp.log(jax.random.uniform(ks[2], (H,), minval=1.0, maxval=16.0))
-    b, c = (jax.random.normal(k, (B, T, N)) for k in ks[3:5])
+    b, c = (jax.random.normal(k, (B, T, G, N) if G else (B, T, N)) for k in ks[3:5])
     d_skip = 1.0 + 0.1 * jax.random.normal(ks[5], (H,))
     return x, dt, a_log, b, c, d_skip
 
 
+# chunk, length, decay, groups of heads (0: one, B and C without the axis;
+# three heads, or with two groups four).
 SSD_CASES = [
-    (64, 128, "model"), (64, 150, "model"), (16, 150, "model"), (256, 300, "model"),
-    (64, 150, "near_0"), (16, 40, "near_0"), (64, 150, "near_1"), (64, 10, "model"),
+    (64, 128, "model", 0), (64, 150, "model", 0), (16, 150, "model", 0), (256, 300, "model", 0),
+    (64, 150, "near_0", 0), (16, 40, "near_0", 0), (64, 150, "near_1", 0), (64, 10, "model", 0),
+    (16, 40, "model", 2), (16, 40, "near_0", 2),
 ]
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
-@pytest.mark.parametrize("chunk,T,decay", SSD_CASES)
-def test_chunked_ssd_is_the_recurrence(chunk, T, decay, what):
+@pytest.mark.parametrize("chunk,T,decay,groups", SSD_CASES)
+def test_chunked_ssd_is_the_recurrence(chunk, T, decay, groups, what):
     """``chunked_ssd`` against the recurrence token by token, float32 at
     ``highest``: whole chunks and a length the chunk does not divide, a
     sequence shorter than a chunk, decays near 0 (every exponent taken is
-    of a difference <= 0, so nothing overflows) and near 1."""
-    args = _ssd_inputs(1, T, decay)
+    of a difference <= 0, so nothing overflows) and near 1, and ``B`` and
+    ``C`` a group of heads (Nemotron 3 Nano's; Granite's one group is the
+    call without the axis)."""
+    args = _ssd_inputs(1, T, decay, H=4 if groups else 3, G=groups)
     if decay == "near_0":
         G = np.cumsum(np.asarray(-jnp.exp(args[2]) * args[1], np.float64), axis=1)
         assert np.exp(-G[:, min(chunk, T) - 1]).max() > 1e38  # exp(-G) alone is beyond float32
     with jax.default_matmul_precision("highest"):
+        # Jitted here and below: one compile a side, where op by op is some hundred.
         if what == "forward":
-            got = ssm.chunked_ssd(*args, chunk=chunk)
-            want = ssm.recurrent_ssd(*args)
+            got = jax.jit(lambda *a: ssm.chunked_ssd(*a, chunk=chunk))(*args)
+            want = jax.jit(ssm.recurrent_ssd)(*args)
             assert got.shape == want.shape and bool(jnp.all(jnp.isfinite(got)))
             scale = float(jnp.abs(want).max())
             assert float(jnp.abs(got - want).max()) <= 2e-5 * scale
             return
         loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
-        got = jax.grad(loss(lambda *a: ssm.chunked_ssd(*a, chunk=chunk)), argnums=range(6))(*args)
-        want = jax.grad(loss(ssm.recurrent_ssd), argnums=range(6))(*args)
+        got = jax.jit(jax.grad(loss(lambda *a: ssm.chunked_ssd(*a, chunk=chunk)), argnums=range(6)))(*args)
+        want = jax.jit(jax.grad(loss(ssm.recurrent_ssd), argnums=range(6)))(*args)
     for name, g, w in zip(("x", "dt", "a_log", "b", "c", "d_skip"), got, want):
         assert bool(jnp.all(jnp.isfinite(g))), name
         g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
@@ -115,12 +123,38 @@ def test_ssd_is_linear_attention_without_the_delta_rule():
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-4)
 
 
-def test_ssd_refuses_b_and_c_a_head_by_shape():
+def test_ssd_refuses_groups_that_do_not_divide_the_heads_by_shape():
     x, dt, a_log, b, c, d = _ssd_inputs(3, 32, "model")
-    with pytest.raises(ValueError, match="one group"):
-        ssm.chunked_ssd(x, dt, a_log, jnp.broadcast_to(b[:, :, None], (2, 32, 3, 16)), c, d)
+    per_group = lambda y, groups: jnp.broadcast_to(y[:, :, None], (2, 32, groups, 16))
+    with pytest.raises(ValueError, match="G dividing H"):
+        ssm.chunked_ssd(x, dt, a_log, per_group(b, 2), per_group(c, 2), d)  # three heads
+    with pytest.raises(ValueError, match="G dividing H"):
+        ssm.chunked_ssd(x, dt, a_log, per_group(b, 3), c, d)  # B with groups, C without
     with pytest.raises(ValueError, match="one group"):
         ssm.chunked_ssd(x, dt[..., None], a_log, b, c, d)
+
+
+@pytest.mark.parametrize("route", [ssm.recurrent_ssd, ssm.plain_ssd], ids=["recurrence", "chunk_wise"])
+def test_a_scan_with_groups_is_one_scan_a_group_over_that_group_s_heads(route):
+    """Head ``h`` reads group ``h // (H / G)`` and nothing of another
+    group: the scan over six heads in three groups is three one-group
+    scans, each over its group's two heads with its group's ``B`` and
+    ``C`` (the call without the group axis), side by side."""
+    x, dt, a_log, b, c, d = _ssd_inputs(6, 40, "model", H=6, G=3)
+    f = route if route is ssm.recurrent_ssd else lambda *a: route(*a, chunk=16)
+    with jax.default_matmul_precision("highest"):
+        got = f(x, dt, a_log, b, c, d)
+        heads = lambda g: slice(2 * g, 2 * g + 2)
+        want = jnp.concatenate(
+            [
+                f(x[:, :, heads(g)], dt[:, :, heads(g)], a_log[heads(g)], b[:, :, g], c[:, :, g], d[heads(g)])
+                for g in range(3)
+            ],
+            axis=2,
+        )
+        other = f(x, dt, a_log, b[:, :, ::-1], c, d)  # another group's B: another function
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(jnp.abs(want).max())
+    assert float(jnp.abs(got - other).max()) > 1e-2 * float(jnp.abs(want).max())
 
 
 def _ssd_traced_calls():
@@ -152,44 +186,50 @@ def _rms(x, scale, eps=1e-5):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _hand_mamba(p, h, H, P, N, eps=1e-5):
-    """The layer as ISSUE 38 writes it, the recurrence token by token."""
+def _hand_mamba(p, h, H, P, N, eps=1e-5, G=1):
+    """The layer as ISSUE 38 writes it (``G`` 1) and as ISSUE 40 does
+    (``B`` and ``C`` a group of ``H / G`` heads, the gated norm over each
+    group's channels), the recurrence token by token."""
     B, T, _ = h.shape
     inner = H * P
     zxbcdt = h @ p["in_proj"]["kernel"]
-    z, xbc, dt = zxbcdt[..., :inner], zxbcdt[..., inner : 2 * inner + 2 * N], zxbcdt[..., -H:]
+    z, xbc, dt = zxbcdt[..., :inner], zxbcdt[..., inner : 2 * inner + 2 * G * N], zxbcdt[..., -H:]
     padded = jnp.pad(xbc, ((0, 0), (3, 0), (0, 0)))
     conv = sum(padded[:, j : j + T] * p["conv"][j] for j in range(4)) + p["conv_bias"]
     xbc = jax.nn.silu(conv)
-    x, b, c = xbc[..., :inner], xbc[..., inner : inner + N], xbc[..., inner + N :]
+    x, b, c = xbc[..., :inner], xbc[..., inner : inner + G * N], xbc[..., inner + G * N :]
     x = x.reshape(B, T, H, P)
+    # A head reads its group's vector.
+    b, c = (jnp.repeat(y.reshape(B, T, G, N), H // G, axis=2) for y in (b, c))
     dt = jax.nn.softplus(dt + p["dt_bias"])
     a = jnp.exp(-jnp.exp(p["A_log"]) * dt)
     S = jnp.zeros((B, H, N, P))
     ys = []
     for t in range(T):
-        S = a[:, t, :, None, None] * S + jnp.einsum("bn,bhp->bhnp", b[:, t], dt[:, t, :, None] * x[:, t])
-        ys.append(jnp.einsum("bn,bhnp->bhp", c[:, t], S) + p["D"][:, None] * x[:, t])
+        S = a[:, t, :, None, None] * S + jnp.einsum("bhn,bhp->bhnp", b[:, t], dt[:, t, :, None] * x[:, t])
+        ys.append(jnp.einsum("bhn,bhnp->bhp", c[:, t], S) + p["D"][:, None] * x[:, t])
     y = jnp.stack(ys, axis=1).reshape(B, T, inner) * jax.nn.silu(z)
-    return _rms(y, p["norm"]["scale"], eps) @ p["out_proj"]["kernel"]
+    y = _rms(y.reshape(B, T, G, inner // G), 1.0, eps).reshape(B, T, inner) * p["norm"]["scale"]
+    return y @ p["out_proj"]["kernel"]
 
 
-def test_the_mamba2_mixer_against_a_hand_written_layer():
+@pytest.mark.parametrize("G", [1, 2], ids=["one_group", "two_groups"])
+def test_the_mamba2_mixer_against_a_hand_written_layer(G):
     H, P, N, D = 4, 8, 16, 24
     mixer = mixers.Mamba2Mixer(
-        num_heads=H, head_dim=P, state_dim=N, d_model=D, chunk=16, dtype=jnp.float32
+        num_heads=H, head_dim=P, state_dim=N, num_groups=G, d_model=D, chunk=16, dtype=jnp.float32
     )
     h = jax.random.normal(jax.random.key(3), (2, 40, D))
     params = _moved(mixer.init(jax.random.key(0), h)["params"])
     assert sorted(params) == ["A_log", "D", "conv", "conv_bias", "dt_bias", "in_proj", "norm", "out_proj"]
-    assert params["in_proj"]["kernel"].shape == (D, 2 * H * P + 2 * N + H)
-    assert params["conv"].shape == (4, H * P + 2 * N) and params["conv_bias"].shape == (H * P + 2 * N,)
-    assert params["norm"]["scale"].shape == (H * P,)  # one norm over all the channels
+    assert params["in_proj"]["kernel"].shape == (D, 2 * H * P + 2 * G * N + H)
+    assert params["conv"].shape == (4, H * P + 2 * G * N) and params["conv_bias"].shape == (H * P + 2 * G * N,)
+    assert params["norm"]["scale"].shape == (H * P,)  # one weight, whatever the groups of the mean square
     assert params["A_log"].shape == params["dt_bias"].shape == params["D"].shape == (H,)
     with jax.default_matmul_precision("highest"):
-        got = mixer.apply({"params": params}, h)
-        want = _hand_mamba(params, h, H, P, N)
-        grads = jax.grad(lambda p: jnp.sum(jnp.sin(mixer.apply({"params": p}, h))))(params)
+        got = jax.jit(lambda p: mixer.apply({"params": p}, h))(params)
+        want = jax.jit(lambda p: _hand_mamba(p, h, H, P, N, G=G))(params)
+        grads = jax.jit(jax.grad(lambda p: jnp.sum(jnp.sin(mixer.apply({"params": p}, h)))))(params)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
     # The gate comes before the norm: gating after it is another function.
@@ -275,9 +315,10 @@ def _losses(model, params, tokens, targets, fused):
 
     state = types.SimpleNamespace(apply_fn=model.apply, carry=None)
     fn = train_loop.lm_loss_fn(model.apply, fused_unembed=fused)
-    (loss, _), grads = jax.value_and_grad(fn, has_aux=True)(
-        params, state, {"inputs": tokens, "targets": targets}, {}
-    )
+    # Jitted: one compile, where op by op is some hundred.
+    (loss, _), grads = jax.jit(
+        lambda p: jax.value_and_grad(fn, has_aux=True)(p, state, {"inputs": tokens, "targets": targets}, {})
+    )(params)
     return loss, grads
 
 
@@ -440,7 +481,8 @@ def test_recomputing_each_half_changes_no_value_and_no_leaf():
     assert jax.tree.structure(params) == jax.tree.structure(off.init(jax.random.key(0), tokens)["params"])
     loss = lambda m: lambda p: jnp.sum(jnp.sin(m.apply({"params": p}, tokens)[0]))
     with jax.default_matmul_precision("highest"):
-        (a, ga), (b, gb) = (jax.value_and_grad(loss(m))(params) for m in (on, off))
+        # Jitted: one compile a side, where op by op is some hundred.
+        (a, ga), (b, gb) = (jax.jit(jax.value_and_grad(loss(m)))(params) for m in (on, off))
     assert float(a) == pytest.approx(float(b), rel=1e-6)
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         assert float(jnp.abs(x - y).max()) <= 1e-5 * float(jnp.abs(y).max()) + 1e-7

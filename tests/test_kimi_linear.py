@@ -96,14 +96,15 @@ def test_chunked_delta_rule_is_the_recurrence(chunk, sub, T, decay, what):
     x = _kda_inputs(chunk + T, T, decay)
     chunked = functools.partial(linattn.chunked_kda, chunk=chunk, sub=sub)
     with jax.default_matmul_precision("highest"):
+        # Jitted here and below: one compile a side, where op by op is some hundred.
         if what == "forward":
-            got, want = chunked(*x), linattn.recurrent_kda(*x)
+            got, want = jax.jit(chunked)(*x), jax.jit(linattn.recurrent_kda)(*x)
             scale = float(jnp.abs(want).max())
             assert scale > 1e-3
             np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4 * scale, rtol=1e-4)
             return
         probe = jax.random.normal(jax.random.key(9), x[2].shape)
-        grad = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * probe), argnums=(0, 1, 2, 3, 4))(*x)
+        grad = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * probe), argnums=(0, 1, 2, 3, 4)))(*x)
         for name, g, w in zip("q k v g beta".split(), grad(chunked), grad(linattn.recurrent_kda)):
             assert bool(jnp.isfinite(g).all()), name
             scale = float(jnp.abs(w).max())
@@ -175,12 +176,13 @@ def _kda_result(route, T, decay, what, dtype="float32"):
     x = _kda_inputs(T, T, decay, B=1, H=2, dk=128, dv=128)
     x = tuple(a.astype(dtype) for a in x[:3]) + x[3:]
     f = _KDA_ROUTES[route]
+    # Jitted: one compile a result, where op by op is some hundred.
     with jax.default_matmul_precision("highest"):
         if what == "forward":
-            return (f(*x),)
+            return (jax.jit(f)(*x),)
         probe = jax.random.normal(jax.random.key(9), x[2].shape)
         loss = lambda *a: jnp.sum(f(*a).astype(jnp.float32) * probe)
-        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*x)
 
 
 @pytest.mark.parametrize("oracle", ["recurrence", "plain"])
@@ -376,7 +378,7 @@ def _pass_result(which, route, T, dtype):
         )
         return total, outs
 
-    (_, outs), grads = jax.value_and_grad(loss, tuple(range(len(args))), has_aux=True)(*args)
+    (_, outs), grads = jax.jit(jax.value_and_grad(loss, tuple(range(len(args))), has_aux=True))(*args)
     return tuple(x.astype(jnp.float32) for x in outs + grads)
 
 
@@ -485,7 +487,8 @@ def test_a_whole_mixer_on_the_fused_route_is_the_mixer_on_the_plain_route(monkey
         out = mixer.apply(p, x)
         return jnp.sum(out.astype(jnp.float32) * probe), out
 
-    both = lambda: jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+    # A fresh jit each time: the second is traced after the routes are patched.
+    both = lambda: jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(params, x)
     with jax.default_matmul_precision("highest"):
         counts = _mixer_counts()
         (_, want_out), want = both()
@@ -642,24 +645,35 @@ E, K, D, F, N = 16, 4, 64, 32, 96
 ROUTING = moelib.Routing("sigmoid", True, 2.446)
 
 
-def _layer_params(seed=0):
+def _layer_params(seed=0, kind="gated_silu"):
+    """The expert stacks of either kind: ``"gated_silu"``, three matrices
+    an expert (Kimi Linear's, OLMoE's), or ``"relu2"``, two and no gate
+    (Nemotron 3 Nano's)."""
     keys = jax.random.split(jax.random.key(seed), 4)
-    return {
+    params = {
         "router": jax.random.normal(keys[0], (D, E)) * D**-0.5,
         "w_gate": jax.random.normal(keys[1], (E, D, F)) * D**-0.5,
         "w_up": jax.random.normal(keys[2], (E, D, F)) * D**-0.5,
         "w_down": jax.random.normal(keys[3], (E, F, D)) * F**-0.5,
     }
+    if kind == "relu2":
+        del params["w_gate"]
+    return params
+
+
+def _stacks(params):
+    return [k for k in ("w_gate", "w_up", "w_down") if k in params]
 
 
 def _share(params, first, count):
     take = lambda w: w[first : first + count]
-    return {"router": params["router"], **{k: take(params[k]) for k in ("w_gate", "w_up", "w_down")}}
+    return {"router": params["router"], **{k: take(params[k]) for k in _stacks(params)}}
 
 
 def _dense_masked(params, x, held=(0, E)):
     """Every expert of the range on every token, masked by the top-k over
-    all experts: what a share has to equal."""
+    all experts: what a share has to equal.  The plain form of either
+    kind of expert, by whether there is a gate matrix."""
     with jax.default_matmul_precision("highest"):
         h = x.reshape(-1, D)
         s = jax.nn.sigmoid(h @ params["router"])
@@ -667,12 +681,12 @@ def _dense_masked(params, x, held=(0, E)):
         chosen = s >= kth  # no ties on random inputs
         w = jnp.where(chosen, s, 0.0)
         w = 2.446 * w / w.sum(-1, keepdims=True)
-        ys = jnp.einsum(
-            "enf,efd->end",
-            jax.nn.silu(jnp.einsum("nd,edf->enf", h, params["w_gate"]))
-            * jnp.einsum("nd,edf->enf", h, params["w_up"]),
-            params["w_down"],
-        )
+        up = jnp.einsum("nd,edf->enf", h, params["w_up"])
+        if "w_gate" in params:
+            hidden = jax.nn.silu(jnp.einsum("nd,edf->enf", h, params["w_gate"])) * up
+        else:
+            hidden = jnp.square(jnp.maximum(up, 0.0))
+        ys = jnp.einsum("enf,efd->end", hidden, params["w_down"])
         mine = (jnp.arange(E) >= held[0]) & (jnp.arange(E) < held[0] + held[1])
         return jnp.einsum("ne,end->nd", w * mine, ys).reshape(x.shape), chosen
 
@@ -684,10 +698,23 @@ def _held_layer(params, x, held):
         )
 
 
-@pytest.mark.parametrize("skew", ["random", "everything_on_one_share", "nothing_on_this_share"])
-@pytest.mark.parametrize("held", [(0, 4), (4, 4), (12, 4), (2, 8)])
-def test_a_share_computes_its_own_experts_part_and_drops_nothing(held, skew):
-    params = _layer_params()
+_SKEWS = ["random", "everything_on_one_share", "nothing_on_this_share"]
+SHARE_CASES = [
+    *((held, skew, "gated_silu") for skew in _SKEWS for held in [(0, 4), (4, 4), (12, 4), (2, 8)]),
+    # Experts of two matrices around a squared ReLU: the same dispatch, the
+    # slab's backward (a vjp of the slab) with the other activation.
+    ((2, 8), "random", "relu2"), ((4, 4), "everything_on_one_share", "relu2"),
+]
+
+
+@pytest.mark.parametrize(
+    "held,skew,kind", SHARE_CASES, ids=[f"held{h[0]}_{h[1]}-{s}-{k}" for h, s, k in SHARE_CASES]
+)
+def test_a_share_computes_its_own_experts_part_and_drops_nothing(held, skew, kind):
+    """Forward, and the hand-written backward of the held range (a
+    ``custom_vjp`` that walks the slabs again) against autodiff of the
+    plain dense masked form, for both kinds of expert."""
+    params = _layer_params(kind=kind)
     x = jnp.abs(jax.random.normal(jax.random.key(7), (2, N // 2, D))) + 0.1
     if skew != "random":
         # Positive inputs: a router column of one sign decides an expert.
@@ -709,7 +736,7 @@ def test_a_share_computes_its_own_experts_part_and_drops_nothing(held, skew):
     share = _share(params, *held)
 
     def dense(p, y):
-        full = {**params, **{k: params[k].at[held[0] : held[0] + held[1]].set(p[k]) for k in ("w_gate", "w_up", "w_down")}}
+        full = {**params, **{k: params[k].at[held[0] : held[0] + held[1]].set(p[k]) for k in _stacks(params)}}
         return jnp.sum(_dense_masked({**full, "router": p["router"]}, y, held)[0] * probe)
 
     def grouped(p, y):
@@ -724,36 +751,53 @@ def test_a_share_computes_its_own_experts_part_and_drops_nothing(held, skew):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-3)
 
 
-def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("kind", ["gated_silu", "relu2"])
+def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_whole_layer(kind):
     """The model-configs guide's test of the cut: 16 experts as 4 shares
     of 4.  The routed parts the four shares give, plus the shared expert
-    (which every chip computes alike) counted once, are the uncut layer."""
+    (which every chip computes alike) counted once, are the uncut layer;
+    with squared-ReLU experts and a shared expert of another width
+    (Nemotron 3 Nano's layer) the uncut layer is also what the plain
+    reference gives with every expert held."""
     from distributed_tensorflow_models_tpu.models import transformer_lm as tlm
 
     x = jax.random.normal(jax.random.key(5), (2, N // 2, D))
-    whole = tlm.TopKExpertsFFN(
-        E, K, D, F, dtype=jnp.float32, routing=ROUTING, shared_experts=1, aux_loss_weight=0.0
-    )
+    sizes = dict(dtype=jnp.float32, routing=ROUTING, shared_experts=1, aux_loss_weight=0.0)
+    if kind == "relu2":
+        sizes.update(expert="relu2", shared_d_ff=F + 8)
+        alone = tlm.MLP(D, F + 8, dtype=jnp.float32, use_bias=False, activation="relu2")
+    else:
+        alone = tlm.GatedMLP(D, F, jnp.float32)
+    whole = tlm.TopKExpertsFFN(E, K, D, F, **sizes)
     variables = whole.init(jax.random.key(0), x)
     params = variables["params"]
+    assert ("w_gate" in params) == (kind == "gated_silu")
     with jax.default_matmul_precision("highest"):
         uncut, _ = whole.apply(variables, x, mutable=["moe_stats"])
-        shared = tlm.GatedMLP(D, F, jnp.float32).apply({"params": params["shared"]}, x)
+        shared = alone.apply({"params": params["shared"]}, x)
         parts, shares = [], []
         for first in range(0, E, 4):
-            layer = tlm.TopKExpertsFFN(
-                E, K, D, F, dtype=jnp.float32, routing=ROUTING, shared_experts=1,
-                aux_loss_weight=0.0, held=(first, 4),
-            )
-            mine = {**params, **{k: params[k][first : first + 4] for k in ("w_gate", "w_up", "w_down")}}
+            layer = tlm.TopKExpertsFFN(E, K, D, F, held=(first, 4), **sizes)
+            mine = {**params, **{k: params[k][first : first + 4] for k in _stacks(params)}}
             out, stats = layer.apply({"params": mine}, x, mutable=["moe_stats"])
             parts.append(out - shared)  # this chip's routed part
             shares.append(float(stats["moe_stats"]["held_share"]))
         # The uncut layer against the dense masked formulation too.
-        routed, _ = _dense_masked({k: params[k] for k in ("router", "w_gate", "w_up", "w_down")}, x)
+        routed, _ = _dense_masked({k: params[k] for k in ("router", *_stacks(params))}, x)
     np.testing.assert_allclose(np.asarray(sum(parts) + shared), np.asarray(uncut), atol=3e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(uncut - shared), np.asarray(routed), atol=3e-5, rtol=1e-5)
     assert sum(shares) == pytest.approx(1.0, abs=1e-6)
+    if kind == "relu2":
+        import os
+        import sys
+
+        sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        from benchmark.lib import cells
+
+        ref = cells.load_module("references", "nemotron_h")
+        want, _, share = ref.experts(x.reshape(-1, D), params, K, 2.446, 0)
+        np.testing.assert_allclose(np.asarray(uncut), np.asarray(want).reshape(x.shape), atol=3e-5, rtol=1e-5)
+        assert float(share) == 1.0
 
 
 @pytest.mark.parametrize("skew", [0.0, 0.08, 0.2], ids=["one_slab", "several_slabs", "every_assignment"])

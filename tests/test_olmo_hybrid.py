@@ -93,14 +93,15 @@ def test_chunked_gated_delta_rule_is_the_recurrence(chunk, sub, T, decay, b_max,
     assert float(x[4].max()) > 0.99 * b_max and float(x[4].min()) < 0.01 * b_max
     chunked = lambda *a: linattn.chunked_gdn(*a, chunk=chunk, sub=sub)
     with jax.default_matmul_precision("highest"):
+        # Jitted here and below: one compile a side, where op by op is some hundred.
         if what == "forward":
-            got, want = chunked(*x), _recurrence(*x)
+            got, want = jax.jit(chunked)(*x), jax.jit(_recurrence)(*x)
             assert got.shape == want.shape == x[2].shape and bool(jnp.isfinite(got).all())
             pairs = [(got, want)]
         else:
             # A loss that weighs every output entry differently.
             w = jax.random.normal(jax.random.key(7), x[2].shape)
-            grad = lambda f: jax.grad(lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2, 3, 4))(*x)
+            grad = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2, 3, 4)))(*x)
             pairs = list(zip(grad(chunked), grad(_recurrence)))
     for got, want in pairs:
         assert bool(jnp.isfinite(got).all())
@@ -456,7 +457,8 @@ def test_recomputing_each_half_changes_no_value_and_no_leaf():
     assert jax.tree.structure(params) == jax.tree.structure(off.init(jax.random.key(0), tokens)["params"])
     loss = lambda m: lambda p: jnp.sum(jnp.sin(m.apply({"params": p}, tokens)[0]))
     with jax.default_matmul_precision("highest"):
-        (a, ga), (b, gb) = (jax.value_and_grad(loss(m))(params) for m in (on, off))
+        # Jitted: one compile a side, where op by op is some hundred.
+        (a, ga), (b, gb) = (jax.jit(jax.value_and_grad(loss(m)))(params) for m in (on, off))
     assert float(a) == pytest.approx(float(b), rel=1e-6)
     for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         assert float(jnp.abs(x - y).max()) <= 1e-5 * float(jnp.abs(y).max()) + 1e-7

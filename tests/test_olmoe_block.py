@@ -36,11 +36,14 @@ def _layer_params(seed=0):
     }
 
 
-def _routing_case(case):
+def _routing_case(case, kind="gated_silu"):
     """``(params, x)`` whose routing is uneven in the way ``case`` says.
     The inputs are positive, so a router column of one sign decides an
-    expert's fate for every token."""
+    expert's fate for every token.  ``kind`` ``"relu2"``: experts of two
+    matrices around a squared ReLU (no ``w_gate``)."""
     params = _layer_params()
+    if kind == "relu2":
+        del params["w_gate"]
     x = jnp.abs(jax.random.normal(jax.random.key(7), (1, N, D))) + 0.1
     router = params["router"]
     if case in ("one_expert_gets_nothing", "nothing_and_everything"):
@@ -61,12 +64,12 @@ def _dense_masked(params, x, top_k):
         kth = jnp.sort(probs, axis=-1)[:, -top_k][:, None]
         chosen = probs >= kth  # no ties on random inputs
         weight = jnp.where(chosen, probs, 0.0)
-        ys = jnp.einsum(
-            "enf,efd->end",
-            jax.nn.silu(jnp.einsum("nd,edf->enf", h, params["w_gate"]))
-            * jnp.einsum("nd,edf->enf", h, params["w_up"]),
-            params["w_down"],
-        )
+        up = jnp.einsum("nd,edf->enf", h, params["w_up"])
+        if "w_gate" in params:
+            hidden = jax.nn.silu(jnp.einsum("nd,edf->enf", h, params["w_gate"])) * up
+        else:
+            hidden = jnp.square(jnp.maximum(up, 0.0))
+        ys = jnp.einsum("enf,efd->end", hidden, params["w_down"])
         out = jnp.einsum("ne,end->nd", weight, ys)
         counts = jnp.sum(chosen, axis=0)
     return out.reshape(x.shape), counts
@@ -78,14 +81,19 @@ def _grouped(params, x, top_k=K):
 
 
 CASES = [
-    "random", "zipf", "one_expert_gets_nothing", "one_expert_gets_everything",
-    "nothing_and_everything",
+    *((case, "gated_silu") for case in (
+        "random", "zipf", "one_expert_gets_nothing", "one_expert_gets_everything",
+        "nothing_and_everything",
+    )),
+    # Two grouped products a pass in place of three, the other activation.
+    ("zipf", "relu2"), ("nothing_and_everything", "relu2"),
 ]
+_CASE_IDS = [f"{case}-{kind}" for case, kind in CASES]
 
 
-@pytest.mark.parametrize("case", CASES)
-def test_grouped_layer_equals_the_dense_masked_formulation(case):
-    params, x = _routing_case(case)
+@pytest.mark.parametrize("case,kind", CASES, ids=_CASE_IDS)
+def test_grouped_layer_equals_the_dense_masked_formulation(case, kind):
+    params, x = _routing_case(case, kind)
     want, counts = _dense_masked(params, x, K)
     got = _grouped(params, x)
     if "nothing" in case:
@@ -99,9 +107,9 @@ def test_grouped_layer_equals_the_dense_masked_formulation(case):
     assert float(got.load_max_over_mean) == pytest.approx(float(counts.max()) * E / (K * N))
 
 
-@pytest.mark.parametrize("case", CASES)
-def test_grouped_layer_gradients_equal_the_dense_masked_ones(case):
-    params, x = _routing_case(case)
+@pytest.mark.parametrize("case,kind", CASES, ids=_CASE_IDS)
+def test_grouped_layer_gradients_equal_the_dense_masked_ones(case, kind):
+    params, x = _routing_case(case, kind)
     probe = jax.random.normal(jax.random.key(3), x.shape)
     want = jax.grad(lambda p, y: jnp.sum(_dense_masked(p, y, K)[0] * probe), argnums=(0, 1))(params, x)
     got = jax.grad(lambda p, y: jnp.sum(_grouped(p, y).out * probe), argnums=(0, 1))(params, x)

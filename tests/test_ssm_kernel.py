@@ -21,7 +21,9 @@ from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 NAMES = ("x", "dt", "a_log", "b", "c", "d_skip")
 
 
-def _inputs(seed, T, decay, B=2, H=2, P=64, N=128):
+def _inputs(seed, T, decay, B=2, H=2, P=64, N=128, G=0):
+    """``G`` 0: ``B`` and ``C`` ``[B, T, N]`` (one group, the call without
+    the axis); else ``[B, T, G, N]``."""
     ks = jax.random.split(jax.random.key(seed), 6)
     x = jax.random.normal(ks[0], (B, T, H, P))
     raw = jax.random.normal(ks[1], (B, T, H))
@@ -34,22 +36,31 @@ def _inputs(seed, T, decay, B=2, H=2, P=64, N=128):
         "near_1": 1e-6 * jax.nn.sigmoid(raw),
     }[decay]
     a_log = jnp.log(jax.random.uniform(ks[2], (H,), minval=1.0, maxval=16.0))
-    b = jax.random.normal(ks[3], (B, T, N)) * N**-0.5
-    c = jax.random.normal(ks[4], (B, T, N))
+    shape = (B, T, G, N) if G else (B, T, N)
+    b = jax.random.normal(ks[3], shape) * N**-0.5
+    c = jax.random.normal(ks[4], shape)
     d_skip = 1.0 + 0.1 * jax.random.normal(ks[5], (H,))
     return x, dt, a_log, b, c, d_skip
 
 
-# length, decay, head width, heads, chunk; batch 2, a state of 128.
+# length, decay, head width, heads, chunk, groups of heads (0: one, the
+# call without the axis); batch 2, a state of 128.
 KERNEL_CASES = [
-    (512, "model", 64, 2, 256),   # two chunks: the carried state
-    (600, "model", 64, 4, 256),   # three chunks, a length the chunk does not divide
-    (300, "near_0", 64, 2, 256),
-    (300, "near_1", 64, 2, 256),
-    (300, "model", 128, 2, 256),  # a head a lane tile
-    (600, "model", 64, 2, 512),   # the other chunk the kernels take
+    (512, "model", 64, 2, 256, 0),   # two chunks: the carried state
+    (600, "model", 64, 4, 256, 0),   # three chunks, a length the chunk does not divide
+    (300, "near_0", 64, 2, 256, 0),
+    (300, "near_1", 64, 2, 256, 0),
+    (300, "model", 128, 2, 256, 0),  # a head a lane tile
+    (600, "model", 64, 2, 512, 0),   # the other chunk the kernels take
+    # Two groups of six heads: a grid step takes two heads (a lane tile) and
+    # never of two groups, so a group is three steps that share its C B^T
+    # and add up its dB and dC, and the next group starts both afresh.
+    (300, "model", 64, 12, 256, 2),
 ]
-_IDS = [f"{T}-{decay}-p{P}-h{H}-c{chunk}" for T, decay, P, H, chunk in KERNEL_CASES]
+_IDS = [
+    f"{T}-{decay}-p{P}-h{H}-c{chunk}" + (f"-g{G}" if G else "")
+    for T, decay, P, H, chunk, G in KERNEL_CASES
+]
 
 _ROUTES = {
     "kernel": lambda chunk: lambda *a: ssm.kernel_ssd(*a, chunk, True),
@@ -61,16 +72,17 @@ _ROUTES = {
 @functools.lru_cache(maxsize=None)
 def _result(route, case, what, dtype="float32"):
     """The output, or the six gradients of a probed sum, of one route."""
-    T, decay, P, H, chunk = case
-    args = _inputs(1, T, decay, H=H, P=P)
+    T, decay, P, H, chunk, G = case
+    args = _inputs(1, T, decay, H=H, P=P, G=G)
     args = tuple(a.astype(dtype) if i in (0, 3, 4) else a for i, a in enumerate(args))
     f = _ROUTES[route](chunk)
+    # Jitted: one compile a result, where op by op is some hundred.
     with jax.default_matmul_precision("highest"):
         if what == "forward":
-            return (f(*args),)
+            return (jax.jit(f)(*args),)
         probe = jax.random.normal(jax.random.key(9), args[0].shape)
         loss = lambda *a: jnp.sum(f(*a).astype(jnp.float32) * probe)
-        return jax.grad(loss, argnums=tuple(range(6)))(*args)
+        return jax.jit(jax.grad(loss, argnums=tuple(range(6))))(*args)
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
@@ -82,7 +94,7 @@ def test_the_kernels_are_the_recurrence(case, what):
     chunk) and near 1, batch 2.  ``A_log``'s gradient to 1e-4: chunk-wise
     it is a difference of a mask's row and column sums added up over every
     token, and the plain route reads 1e-5 to 6e-5 on these cases itself."""
-    T, decay, P, H, chunk = case
+    T, decay, P, H, chunk, _ = case
     if decay == "near_0":
         _, dt, a_log = _inputs(1, T, decay, H=H, P=P)[:3]
         G = np.cumsum(np.asarray(-jnp.exp(a_log) * dt, np.float64), axis=1)
@@ -147,35 +159,37 @@ def _route_counts():
 
 
 @pytest.mark.parametrize(
-    "backend, devices, H, P, N, chunk, dtype, want",
+    "backend, devices, H, P, N, chunk, dtype, want, G",
     [
         # Off the chip every call is the plain form.
-        ("cpu", 1, 64, 64, 128, 256, "bfloat16", "plain"),
-        # Described as one TPU: the cell's call, and what else the kernels take.
-        ("tpu", 1, 64, 64, 128, 256, "bfloat16", "kernel"),
-        ("tpu", 1, 64, 64, 128, 256, "float32", "kernel"),
-        ("tpu", 1, 6, 128, 256, 512, "bfloat16", "kernel"),
+        ("cpu", 1, 64, 64, 128, 256, "bfloat16", "plain", 0),
+        # Described as one TPU: the cells' calls (Granite's one group,
+        # Nemotron's eight), and what else the kernels take.
+        ("tpu", 1, 64, 64, 128, 256, "bfloat16", "kernel", 0),
+        ("tpu", 1, 64, 64, 128, 256, "bfloat16", "kernel", 8),
+        ("tpu", 1, 64, 64, 128, 256, "float32", "kernel", 0),
+        ("tpu", 1, 6, 128, 256, 512, "bfloat16", "kernel", 0),
+        ("tpu", 1, 6, 128, 256, 512, "bfloat16", "kernel", 3),
         # ... and what they do not.
-        ("tpu", 1, 4, 8, 16, 16, "bfloat16", "plain"),       # the rehearsal's sizes
-        ("tpu", 1, 64, 96, 128, 256, "bfloat16", "plain"),   # heads that fill no lane tile
-        ("tpu", 1, 3, 64, 128, 256, "bfloat16", "plain"),    # half a tile left over
-        ("tpu", 1, 64, 64, 64, 256, "bfloat16", "plain"),    # half a tile of state
-        ("tpu", 1, 64, 64, 128, 128, "bfloat16", "plain"),   # a smaller chunk
-        ("tpu", 1, 64, 64, 128, 384, "bfloat16", "plain"),   # one they were not built for
+        ("tpu", 1, 4, 8, 16, 16, "bfloat16", "plain", 0),       # the rehearsal's sizes
+        ("tpu", 1, 64, 96, 128, 256, "bfloat16", "plain", 0),   # heads that fill no lane tile
+        ("tpu", 1, 3, 64, 128, 256, "bfloat16", "plain", 0),    # half a tile left over
+        ("tpu", 1, 64, 64, 128, 256, "bfloat16", "plain", 64),  # a group of one head: half a tile
+        ("tpu", 1, 64, 64, 64, 256, "bfloat16", "plain", 0),    # half a tile of state
+        ("tpu", 1, 64, 64, 128, 128, "bfloat16", "plain", 0),   # a smaller chunk
+        ("tpu", 1, 64, 64, 128, 384, "bfloat16", "plain", 0),   # one they were not built for
         # A jit over several devices cannot partition a Mosaic kernel.
-        ("tpu", 4, 64, 64, 128, 256, "bfloat16", "plain"),
+        ("tpu", 4, 64, 64, 128, 256, "bfloat16", "plain", 0),
     ],
 )
-def test_which_route_the_scan_takes(monkeypatch, backend, devices, H, P, N, chunk, dtype, want):
+def test_which_route_the_scan_takes(monkeypatch, backend, devices, H, P, N, chunk, dtype, want, G):
     """``chunked_ssd`` chooses from the backend and what the call shows at
     trace time, and counts exactly one of the two routes a traced call."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(jax, "device_count", lambda: devices)
     spec = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype)
-    args = (
-        spec((1, 700, H, P), dtype), spec((1, 700, H)), spec((H,)),
-        spec((1, 700, N), dtype), spec((1, 700, N), dtype), spec((H,)),
-    )
+    bc = spec((1, 700, G, N) if G else (1, 700, N), dtype)
+    args = (spec((1, 700, H, P), dtype), spec((1, 700, H)), spec((H,)), bc, bc, spec((H,)))
     assert ssm.ssd_route(*args[:5], chunk=chunk) == want
     before = _route_counts()
     # Traced, not run: a Mosaic kernel cannot run here.
