@@ -550,7 +550,7 @@ class AotTrainStep:
         return self
 
     def _compile(self) -> None:
-        t0 = time.perf_counter()
+        t0, wall = time.perf_counter(), time.time()  # both events' start
         entries_before = cache_entry_count(self._cache_dir)
         try:
             lowered = self._fn.lower(*self._args)
@@ -559,7 +559,7 @@ class AotTrainStep:
             # shortens.
             stamp(
                 self._registry, telemetry.STARTUP_AOT_LOWER, t0,
-                args={"label": self._label},
+                args={"label": self._label}, ts_wall=wall,
             )
             self._exe = lowered.compile()
         except BaseException as e:  # noqa: BLE001 — re-raised by acquire()
@@ -577,6 +577,7 @@ class AotTrainStep:
             stamp(
                 self._registry, telemetry.STARTUP_AOT_COMPILE, t0, t0 + dt,
                 args={"label": self._label, "ok": self._error is None},
+                ts_wall=wall,  # the export orders a thread's events by it
             )
         new_entries = cache_entry_count(self._cache_dir) - entries_before
         if self._cache_dir is None:

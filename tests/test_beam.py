@@ -27,7 +27,7 @@ def tiny_lm():
         dtype=jnp.float32,
         attn_impl="reference",
     )
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.zeros((1, 2), jnp.int32)
     )["params"]
     return model, params
@@ -38,12 +38,14 @@ def _brute_force_best(model, params, prompt, steps):
     import itertools
 
     V = model.vocab_size
+    # Jitted: one compile a length, where the bare call compiles every operation of each by itself.
+    forward = jax.jit(lambda p, t: model.apply({"params": p}, t, train=False))
     best_lp, best_seq = -np.inf, None
     for cont in itertools.product(range(V), repeat=steps):
         toks = prompt
         lp = 0.0
         for t in cont:
-            logits, _ = model.apply({"params": params}, toks, train=False)
+            logits, _ = forward(params, toks)
             logp = jax.nn.log_softmax(
                 logits[0, -1].astype(jnp.float32)
             )

@@ -133,9 +133,10 @@ def test_model_forward_same_under_both_impls(name, kwargs, shape):
     x = jax.random.normal(jax.random.PRNGKey(5), shape, jnp.float32)
     m_xla = get_model(name, conv_impl="xla", **kwargs)
     m_pat = get_model(name, conv_impl="patches", **kwargs)
-    variables = m_xla.init(jax.random.PRNGKey(6), x)
-    out_xla = m_xla.apply(variables, x)
-    out_pat = m_pat.apply(variables, x)
+    # Jitted: one compile a model, where op by op is one a layer's every op.
+    variables = jax.jit(m_xla.init)(jax.random.PRNGKey(6), x)
+    out_xla = jax.jit(m_xla.apply)(variables, x)
+    out_pat = jax.jit(m_pat.apply)(variables, x)
     np.testing.assert_allclose(out_xla, out_pat, rtol=2e-4, atol=2e-4)
 
 
@@ -145,7 +146,7 @@ def test_model_grads_same_under_both_impls():
     m_pat = get_model(
         "resnet32_cifar", blocks_per_stage=1, conv_impl="patches"
     )
-    variables = m_xla.init(jax.random.PRNGKey(8), x)
+    variables = jax.jit(m_xla.init)(jax.random.PRNGKey(8), x)
     params, rest = variables["params"], variables["batch_stats"]
 
     def loss(model):
@@ -158,8 +159,8 @@ def test_model_grads_same_under_both_impls():
 
         return f
 
-    g_xla = jax.grad(loss(m_xla))(params)
-    g_pat = jax.grad(loss(m_pat))(params)
+    g_xla = jax.jit(jax.grad(loss(m_xla)))(params)
+    g_pat = jax.jit(jax.grad(loss(m_pat)))(params)
     flat_x, _ = jax.flatten_util.ravel_pytree(g_xla)
     flat_p, _ = jax.flatten_util.ravel_pytree(g_pat)
     np.testing.assert_allclose(flat_p, flat_x, rtol=5e-4, atol=5e-4)
@@ -204,16 +205,17 @@ def test_resnet50_patches_train_step_lowers_without_conv_hlo():
     reduce-window HLO."""
     model = get_model("resnet50", conv_impl="patches")
     x = jnp.ones((1, 64, 64, 3), jnp.bfloat16)
-    variables = model.init(jax.random.PRNGKey(0), x)
+    # Traced, not run: the shapes of the variables are enough to lower.
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)
     params, stats = variables["params"], variables["batch_stats"]
 
-    def step(p):
+    def step(p, stats):
         out, _ = model.apply(
             {"params": p, "batch_stats": stats}, x, train=True,
             mutable=["batch_stats"],
         )
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    text = jax.jit(jax.grad(step)).lower(params).as_text()
+    text = jax.jit(jax.grad(step)).lower(params, stats).as_text()
     assert "convolution" not in text
     assert "reduce-window" not in text

@@ -2,6 +2,8 @@
 full-sequence forward, and `generate` must reproduce a naive
 recompute-everything greedy loop."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,13 @@ import pytest
 
 from distributed_tensorflow_models_tpu.harness.generate import generate
 from distributed_tensorflow_models_tpu.models import get_model
+
+
+@functools.cache
+def _jitted(model, mutable=False):
+    """``model.apply`` under ``jit``: one compile a shape, where the bare
+    call compiles every operation of every new length by itself."""
+    return jax.jit(lambda variables, *args: model.apply(variables, *args, train=False, mutable=mutable))
 
 
 @pytest.fixture(scope="module")
@@ -25,7 +34,7 @@ def small_lm():
         dtype=jnp.float32,
         attn_impl="reference",
     )
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
     )["params"]
     return model, params
@@ -39,7 +48,7 @@ def test_decode_logits_match_full_forward(small_lm):
     rng = np.random.RandomState(0)
     tokens = jnp.asarray(rng.randint(0, 50, (2, 10)), jnp.int32)
 
-    full_logits, _ = model.apply({"params": params}, tokens, train=False)
+    full_logits, _ = _jitted(model)({"params": params}, tokens)
 
     decode_model = model.clone(decode=True)
     cache = {}
@@ -48,8 +57,8 @@ def test_decode_logits_match_full_forward(small_lm):
         variables = {"params": params}
         if cache:
             variables["cache"] = cache
-        (lg, _), mut = decode_model.apply(
-            variables, tokens[:, t : t + 1], train=False, mutable=["cache"]
+        (lg, _), mut = _jitted(decode_model, mutable=("cache",))(
+            variables, tokens[:, t : t + 1]
         )
         cache = mut["cache"]
         step_logits.append(lg[:, 0])
@@ -67,17 +76,15 @@ def test_decode_prompt_chunk_then_steps(small_lm):
     tokens = jnp.asarray(rng.randint(0, 50, (1, 8)), jnp.int32)
     decode_model = model.clone(decode=True)
 
-    (lg_prompt, _), mut = decode_model.apply(
-        {"params": params}, tokens[:, :5], train=False, mutable=["cache"]
+    (lg_prompt, _), mut = _jitted(decode_model, mutable=("cache",))(
+        {"params": params}, tokens[:, :5]
     )
-    (lg6, _), _ = decode_model.apply(
+    (lg6, _), _ = _jitted(decode_model, mutable=("cache",))(
         {"params": params, "cache": mut["cache"]},
         tokens[:, 5:6],
-        train=False,
-        mutable=["cache"],
     )
-    full_logits, _ = model.apply(
-        {"params": params}, tokens[:, :6], train=False
+    full_logits, _ = _jitted(model)(
+        {"params": params}, tokens[:, :6]
     )
     np.testing.assert_allclose(
         lg_prompt, full_logits[:, :5], rtol=1e-4, atol=1e-4
@@ -99,7 +106,7 @@ def test_generate_matches_naive_greedy(small_lm):
 
     toks = prompt
     for _ in range(max_new):
-        logits, _ = model.apply({"params": params}, toks, train=False)
+        logits, _ = _jitted(model)({"params": params}, toks)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(toks))
@@ -111,7 +118,7 @@ def test_generate_eos_freeze(small_lm):
     greedy token so the freeze path deterministically triggers."""
     model, params = small_lm
     prompt = jnp.zeros((1, 2), jnp.int32)
-    logits, _ = model.apply({"params": params}, prompt, train=False)
+    logits, _ = _jitted(model)({"params": params}, prompt)
     eos = int(jnp.argmax(logits[0, -1]))
     out = generate(model, params, prompt, 8, eos_id=eos)
     gen = np.asarray(out)[0, 2:]
@@ -299,16 +306,16 @@ def test_gqa_decode_matches_full_forward():
         dtype=jnp.float32,
         attn_impl="reference",
     )
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
     )["params"]
     rng = np.random.RandomState(7)
     tokens = jnp.asarray(rng.randint(0, 50, (2, 8)), jnp.int32)
-    full_logits, _ = model.apply({"params": params}, tokens, train=False)
+    full_logits, _ = _jitted(model)({"params": params}, tokens)
 
     decode_model = model.clone(decode=True)
-    (lg, _), mut = decode_model.apply(
-        {"params": params}, tokens, train=False, mutable=["cache"]
+    (lg, _), mut = _jitted(decode_model, mutable=("cache",))(
+        {"params": params}, tokens
     )
     np.testing.assert_allclose(lg, full_logits, rtol=1e-4, atol=1e-4)
     ck = mut["cache"]["blocks_0"]["attn"]["cached_key"]
@@ -326,7 +333,7 @@ def test_generate_rnn_matches_naive_greedy():
     )
     rng = np.random.RandomState(11)
     prompt = jnp.asarray(rng.randint(0, 40, (2, 5)), jnp.int32)
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), prompt, model.initial_carry(2)
     )["params"]
 
@@ -335,8 +342,8 @@ def test_generate_rnn_matches_naive_greedy():
 
     toks = prompt
     for _ in range(6):
-        logits, _ = model.apply(
-            {"params": params}, toks, model.initial_carry(2), train=False
+        logits, _ = _jitted(model)(
+            {"params": params}, toks, model.initial_carry(2)
         )
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)

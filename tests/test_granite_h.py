@@ -5,13 +5,13 @@ the chunk does not divide), the Mamba-2 mixer against a hand-written one,
 Granite's four scalars against a hand-written block, the tied head (no
 ``head`` leaf, the fused and the plain loss agree, the embedding's
 gradient is the sum of both uses), grouped key/value heads on the fused
-attention route, and the refusals.
+attention route, and the refusals.  ``fit`` through the program config is
+a case of ``tests/test_lm_fit_smoke.py``; the command line's way in is here.
 
 The plain reference's side of it (logits, loss, every gradient) is
 ``tests/benchmark/test_bench_reference_granite_h.py``.
 """
 
-import dataclasses
 import json
 import os
 import types
@@ -220,7 +220,7 @@ def test_the_mamba2_mixer_against_a_hand_written_layer(G):
         num_heads=H, head_dim=P, state_dim=N, num_groups=G, d_model=D, chunk=16, dtype=jnp.float32
     )
     h = jax.random.normal(jax.random.key(3), (2, 40, D))
-    params = _moved(mixer.init(jax.random.key(0), h)["params"])
+    params = _moved(jax.jit(mixer.init)(jax.random.key(0), h)["params"])
     assert sorted(params) == ["A_log", "D", "conv", "conv_bias", "dt_bias", "in_proj", "norm", "out_proj"]
     assert params["in_proj"]["kernel"].shape == (D, 2 * H * P + 2 * G * N + H)
     assert params["conv"].shape == (4, H * P + 2 * G * N) and params["conv_bias"].shape == (H * P + 2 * G * N,)
@@ -233,7 +233,7 @@ def test_the_mamba2_mixer_against_a_hand_written_layer(G):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
     # The gate comes before the norm: gating after it is another function.
-    fresh = mixer.init(jax.random.key(0), h)["params"]
+    fresh = jax.jit(mixer.init)(jax.random.key(0), h)["params"]
     assert bool(jnp.all(fresh["D"] == 1.0)) and float(jnp.abs(fresh["conv_bias"]).max()) <= 0.5
 
 
@@ -253,7 +253,7 @@ def test_granite_block_against_a_hand_written_block(mixer, remat):
         mixer_kwargs=(("num_heads", 4), ("head_dim", 8), ("state_dim", 16), ("chunk", 16)) if mixer == "ssm" else None,
     )
     x = jax.random.normal(jax.random.key(3), (2, 24, D))
-    params = _moved(block.init(jax.random.key(0), x)["params"])
+    params = _moved(jax.jit(block.init)(jax.random.key(0), x)["params"])
     assert sorted(params) == sorted(["attn" if mixer == "attention" else "ssm", "ln1", "ln2", "mlp"])
     with jax.default_matmul_precision("highest"):
         got = block.apply({"params": params}, x)
@@ -283,12 +283,13 @@ def test_the_four_scalars_against_a_hand_written_stack():
     kw = {**SMALL, "num_layers": 2, "layer_mixers": ("ssm", "attention"), "remat": False}
     model = get_model("transformer_lm", **kw)
     tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 97)
-    params = _moved(model.init(jax.random.key(0), tokens)["params"], seed=6)
+    params = _moved(jax.jit(model.init)(jax.random.key(0), tokens)["params"], seed=6)
     assert sorted(params) == ["blocks_0", "blocks_1", "embedding", "ln_f"]
     table = params["embedding"]["embedding"]
-    with jax.default_matmul_precision("highest"):
-        got, _ = model.apply({"params": params}, tokens)
-        hidden, _ = model.apply({"params": params}, tokens, return_hidden=True)
+    logits = lambda m: jax.jit(lambda p: m.apply({"params": p}, tokens)[0])(params)
+
+    @jax.jit
+    def by_hand(params):
         x = 12.0 * table[tokens]
         for i, mixer in enumerate(kw["layer_mixers"]):
             block = tlm.Block(
@@ -300,13 +301,18 @@ def test_the_four_scalars_against_a_hand_written_stack():
             )
             x = block.apply({"params": params[f"blocks_{i}"]}, x)
         normed = _rms(x, params["ln_f"]["scale"])
-        want = (normed @ table.T) / 8.0
+        return normed, (normed @ table.T) / 8.0
+
+    with jax.default_matmul_precision("highest"):
+        got = logits(model)
+        hidden, _ = jax.jit(lambda p: model.apply({"params": p}, tokens, return_hidden=True))(params)
+        normed, want = by_hand(params)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
     # The fused head's hidden states carry the logit scale already.
     np.testing.assert_allclose(np.asarray(hidden), np.asarray(normed / 8.0), atol=1e-6, rtol=1e-6)
     for name, value in (("embedding_multiplier", 1.0), ("residual_multiplier", 1.0),
                         ("attention_multiplier", None), ("logits_scaling", 1.0)):
-        other, _ = get_model("transformer_lm", **{**kw, name: value}).apply({"params": params}, tokens)
+        other = logits(get_model("transformer_lm", **{**kw, name: value}))
         assert float(jnp.abs(other - got).max()) > 1e-4, name
 
 
@@ -328,7 +334,7 @@ def test_the_tied_head_has_no_leaf_and_both_losses_agree():
     model = get_model("transformer_lm", **SMALL)
     tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 97)
     targets = jnp.roll(tokens, -1, axis=1)
-    params = _moved(model.init(jax.random.key(0), tokens)["params"], seed=7)
+    params = _moved(jax.jit(model.init)(jax.random.key(0), tokens)["params"], seed=7)
     assert "head" not in params and params["embedding"]["embedding"].shape == (97, 64)
     untied = get_model("transformer_lm", **{**SMALL, "tie_embeddings": False})
     assert "head" in jax.eval_shape(lambda: untied.init(jax.random.key(0), tokens))["params"]
@@ -336,9 +342,11 @@ def test_the_tied_head_has_no_leaf_and_both_losses_agree():
         plain, g_plain = _losses(model, params, tokens, targets, fused=False)
         # The fused head multiplies in bfloat16 by default; in float32 it is
         # the same loss to rounding.
-        hidden, _ = model.apply({"params": params}, tokens, return_hidden=True)
+        hidden, _ = jax.jit(lambda p: model.apply({"params": p}, tokens, return_hidden=True))(params)
         table = params["embedding"]["embedding"]
-        f32 = losslib.fused_unembed_mean_xent(hidden, table.T, None, targets, compute_dtype=jnp.float32)
+        f32 = jax.jit(
+            lambda h, k: losslib.fused_unembed_mean_xent(h, k, None, targets, compute_dtype=jnp.float32)
+        )(hidden, table.T)
         fused, g_fused = _losses(model, params, tokens, targets, fused=True)
     assert float(f32) == pytest.approx(float(plain), rel=1e-6)
     assert float(fused) == pytest.approx(float(plain), rel=2e-3)  # bf16 products in the head alone
@@ -356,7 +364,7 @@ def test_the_embedding_s_gradient_is_the_sum_of_the_gather_s_and_the_head_s():
     targets = jnp.roll(tokens, -1, axis=1)
     tied = get_model("transformer_lm", **SMALL)
     untied = get_model("transformer_lm", **{**SMALL, "tie_embeddings": False})
-    params = _moved(tied.init(jax.random.key(0), tokens)["params"], seed=8)
+    params = _moved(jax.jit(tied.init)(jax.random.key(0), tokens)["params"], seed=8)
     table = params["embedding"]["embedding"]
     twice = {**params, "head": {"kernel": table.T}}
     for fused in (False, True):
@@ -477,8 +485,10 @@ def test_recomputing_each_half_changes_no_value_and_no_leaf():
     on = get_model("transformer_lm", **SMALL)
     off = get_model("transformer_lm", **{**SMALL, "remat": False})
     assert on.remat and not off.remat
-    params = on.init(jax.random.key(0), tokens)["params"]
-    assert jax.tree.structure(params) == jax.tree.structure(off.init(jax.random.key(0), tokens)["params"])
+    params = jax.jit(on.init)(jax.random.key(0), tokens)["params"]
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(off.init, jax.random.key(0), tokens)["params"]
+    )
     loss = lambda m: lambda p: jnp.sum(jnp.sin(m.apply({"params": p}, tokens)[0]))
     with jax.default_matmul_precision("highest"):
         # Jitted: one compile a side, where op by op is some hundred.
@@ -512,45 +522,6 @@ def test_the_published_configuration_and_the_cut_count_what_the_issue_reckoned()
     tree, total = _count({**FULL, **kw})
     assert sorted(k for k in tree if "ssm" in tree[k]) == sorted(f"blocks_{i}" for i in (0, 1, 2, 3, 4, 6, 7, 8, 9))
     assert total == cut["parameters"]["count"] == 772_160_448  # x 16 B = 12.35 GB
-
-
-def test_fit_trains_the_granite_h_program_config(tmp_path):
-    """The normal path: ``get_config("granite_h_micro")`` through ``fit`` at
-    a small size, with the fused head fed from the tied embedding; the
-    routes are counted (three state-space layers and one attention,
-    ``model.init`` and the step), the scopes are in the step's map, the
-    loss falls."""
-    from distributed_tensorflow_models_tpu.core import mesh as meshlib
-    from distributed_tensorflow_models_tpu.harness import train as trainlib
-
-    kw = {k: v for k, v in SMALL.items() if k != "dtype"}
-    cfg = get_config(
-        "granite_h_micro", model_kwargs=kw, vocab_size=97, num_steps=40, global_batch_size=2,
-        train_steps=12, log_every_steps=2, fused_unembed=True, trace_export=True,
-    )
-    assert cfg.optimizer.warmup_steps == 2000 and cfg.optimizer.clip_global_norm == 1.0
-    # Twelve steps of a 2,000-step warm-up move nothing a loss row can
-    # show over the batches' own noise: the test's run warms up in three.
-    cfg = cfg.replace(optimizer=dataclasses.replace(cfg.optimizer, warmup_steps=3, learning_rate=3e-3))
-    workdir = str(tmp_path / "fit")
-    before = _ssd_traced_calls()
-    result = trainlib.fit(cfg, workdir, mesh=meshlib.data_parallel_mesh(jax.devices()[:1]))
-    assert int(result.state.step) == 12 and "head" not in result.state.params
-    with open(os.path.join(workdir, "metrics.jsonl")) as f:
-        rows = [json.loads(line) for line in f if line.strip()]
-    losses = [r["loss"] for r in rows if "loss" in r]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    with open(os.path.join(workdir, "telemetry.json")) as f:
-        telemetry = json.load(f)["metrics"]
-    assert telemetry["ssd/route_plain"] == 6 and telemetry["ssd/route_kernel"] == 0
-    assert telemetry["attention/route_blockwise"] == 2 and telemetry["gdn/route_plain"] == 0
-    assert telemetry["unembed/grad_in_forward"] == 1
-    assert _ssd_traced_calls() - before == 6
-    with open(os.path.join(workdir, "step_scopes_p0.json")) as f:
-        scopes = f.read()
-    for name in ("ssm", "ssd_core", "attention_core", "unembed_loss", "optimizer"):
-        assert name in scopes
-    assert "gdn_core" not in scopes and "linear_attn" not in scopes
 
 
 def test_cli_train_runs_the_granite_h_program_config(tmp_path, monkeypatch, capsys):

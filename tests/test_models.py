@@ -69,8 +69,8 @@ def test_resnet50_shapes():
 def test_resnet50_tiny_forward():
     # Real forward at 32x32 to exercise the graph cheaply.
     model = get_model("resnet50", num_classes=7, dtype=jnp.float32)
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 32, 32, 3)))
-    out = model.apply(variables, jnp.zeros((2, 32, 32, 3)))
+    variables = jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 32, 32, 3)))
+    out = jax.jit(model.apply)(variables, jnp.zeros((2, 32, 32, 3)))
     assert out.shape == (2, 7)
 
 

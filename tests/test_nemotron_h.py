@@ -4,22 +4,21 @@ without a feed-forward, a feed-forward without a mixer) against
 hand-written ones, with per-half recomputation; the squared-ReLU
 feed-forward; sixteen query heads a key/value head and a projection wider
 than the model on the route the chip takes; the parameter tree and the
-counts; the refusals; ``fit`` through the program config.
+counts; the refusals.  ``fit`` through the program config is a case of
+``tests/test_lm_fit_smoke.py``.
 
 What it shares with Granite 4.0-H and Kimi Linear is tested beside
 theirs, as cases of their parametrised tests: the state-space scan with
 groups of heads (``tests/test_granite_h.py``, ``tests/test_ssm_kernel.py``),
 the Mamba-2 mixer with groups and its grouped norm
 (``tests/test_granite_h.py``), experts without a gate and the shares that
-add up (``tests/test_kimi_linear.py``, ``tests/test_olmoe_block.py``).
+add up (``tests/test_moe.py``, ``tests/test_olmoe_block.py``).
 The plain reference's side of it (logits, loss, every gradient) is
 ``tests/benchmark/test_bench_reference_nemotron_h.py``.
 """
 
-import dataclasses
 import json
 import os
-import re
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +76,7 @@ def test_a_layer_of_one_sub_layer_against_a_hand_written_one(kind, remat):
     mixer, feed = tlm.TransformerLM._halves(kind)
     block = _block(mixer, feed, remat)
     x = jax.random.normal(jax.random.key(3), (2, 24, 32))
-    params = _moved(block.init(jax.random.key(0), x)["params"])
+    params = _moved(jax.jit(block.init)(jax.random.key(0), x)["params"])
     assert sorted(params) == (["attn", "ln1"] if kind == "attention_only" else ["ln2", "mlp"])
     with jax.default_matmul_precision("highest"):
         got = block.apply({"params": params}, x)
@@ -104,7 +103,7 @@ def test_a_block_of_both_halves_is_its_two_one_sub_layer_blocks_in_turn():
     the same leaves: the new layer kinds add no mathematics of their own."""
     x = jax.random.normal(jax.random.key(3), (2, 24, 32))
     both = _block("attention", True, False)
-    params = _moved(both.init(jax.random.key(0), x)["params"])
+    params = _moved(jax.jit(both.init)(jax.random.key(0), x)["params"])
     assert sorted(params) == ["attn", "ln1", "ln2", "mlp"]
     first = _block("attention", False, False).apply(
         {"params": {k: params[k] for k in ("attn", "ln1")}}, x
@@ -116,15 +115,18 @@ def test_a_block_of_both_halves_is_its_two_one_sub_layer_blocks_in_turn():
 def test_recomputing_a_one_sub_layer_stack_changes_no_value_and_no_leaf():
     """Each layer is one recomputed half.  The family's dense stack (``-``
     for ``E``: no experts), so that the case costs seconds; experts under
-    recomputation are the fit below and the reference's test."""
+    recomputation are the fit (``tests/test_lm_fit_smoke.py``) and the
+    reference's test."""
     tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 97)
     dense = {**SMALL, "num_experts": 0, "moe_held": None, "num_layers": 3,
              "layer_mixers": ("ssm_only", "ffn_only", "attention_only")}
     on = get_model("transformer_lm", **dense)
     off = get_model("transformer_lm", **{**dense, "remat": False})
     assert on.remat and not off.remat
-    params = on.init(jax.random.key(0), tokens)["params"]
-    assert jax.tree.structure(params) == jax.tree.structure(off.init(jax.random.key(0), tokens)["params"])
+    params = jax.jit(on.init)(jax.random.key(0), tokens)["params"]
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(off.init, jax.random.key(0), tokens)["params"]
+    )
     loss = lambda m: lambda p: jnp.sum(jnp.sin(m.apply({"params": p}, tokens)[0]))
     with jax.default_matmul_precision("highest"):
         # Jitted: one compile a side, where op by op is some hundred.
@@ -230,45 +232,3 @@ def test_a_model_without_the_new_fields_traces_what_it_traced_before():
     assert base == text(get_model("transformer_lm", **kw, layer_mixers=("attention", "attention"),
                                   ssm_num_groups=1, moe_expert="gated_silu", moe_shared_d_ff=0))
     assert base != text(get_model("transformer_lm", **kw, mlp="relu2"))
-
-
-# --- fit ---------------------------------------------------------------------
-
-def test_fit_trains_the_nemotron3_nano_program_config(tmp_path):
-    """The normal path: ``get_config("nemotron3_nano")`` through ``fit`` at
-    a small size (two state-space layers, two expert layers of which one
-    holds 4 of 8 experts' share, one attention): the routes are counted,
-    the scopes of every new piece are in the step's map, the held share
-    and the load statistics are on the loss rows, the loss falls."""
-    from distributed_tensorflow_models_tpu.core import mesh as meshlib
-    from distributed_tensorflow_models_tpu.harness import train as trainlib
-
-    kw = {k: v for k, v in SMALL.items() if k != "dtype"}
-    cfg = get_config(
-        "nemotron3_nano", model_kwargs=kw, vocab_size=97, num_steps=40, global_batch_size=2,
-        train_steps=12, log_every_steps=2, fused_unembed=True, trace_export=True,
-    )
-    assert cfg.optimizer.warmup_steps == 2000 and cfg.optimizer.clip_global_norm == 1.0
-    # Twelve steps of a 2,000-step warm-up move nothing a loss row can
-    # show over the batches' own noise: the test's run warms up in three.
-    cfg = cfg.replace(optimizer=dataclasses.replace(cfg.optimizer, warmup_steps=3, learning_rate=3e-3))
-    workdir = str(tmp_path / "fit")
-    result = trainlib.fit(cfg, workdir, mesh=meshlib.data_parallel_mesh(jax.devices()[:1]))
-    assert int(result.state.step) == 12 and "head" in result.state.params
-    with open(os.path.join(workdir, "metrics.jsonl")) as f:
-        rows = [json.loads(line) for line in f if line.strip()]
-    losses = [r["loss"] for r in rows if "loss" in r]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    held = [r["moe_held_share"] for r in rows if "moe_held_share" in r]
-    assert held and all(0.0 < h < 1.0 for h in held)
-    assert all("moe_load_max_over_mean" in r for r in rows if "moe_held_share" in r)
-    with open(os.path.join(workdir, "telemetry.json")) as f:
-        telemetry = json.load(f)["metrics"]
-    assert telemetry["ssd/route_plain"] == 4 and telemetry["ssd/route_kernel"] == 0
-    assert telemetry["attention/route_blockwise"] == 2 and telemetry["unembed/grad_in_forward"] == 1
-    with open(os.path.join(workdir, "step_scopes_p0.json")) as f:
-        scopes = f.read()
-    for name in ("ssm", "ssd_core", "moe", "moe_dispatch", "moe_experts", "moe_shared", "attention_core",
-                 "unembed_loss", "optimizer"):
-        assert re.search(rf"[/(]{name}[/)]", scopes), name
-    assert "gdn_core" not in scopes and "linear_attn" not in scopes

@@ -6,9 +6,11 @@ adding up to the uncut layer), the norm on each sub-layer's output, RoPE
 in the attention layers of a stack whose other layers take no positions.
 
 The plain reference's side of it (logits, loss, every gradient) is
-``tests/benchmark/test_bench_reference_olmo_hybrid.py``.
+``tests/benchmark/test_bench_reference_olmo_hybrid.py``; ``fit`` through
+the program config is a case of ``tests/test_lm_fit_smoke.py``.
 """
 
+import functools
 import json
 import os
 import sys
@@ -86,23 +88,33 @@ GDN_CASES = [
 ]
 
 
+def _probed(f, *x):
+    """``(f(*x), its gradients by every argument)`` of a loss that weighs
+    every output entry differently, as one jitted program: one compile
+    where op by op is some hundred, the forward pass once for both."""
+    w = jax.random.normal(jax.random.key(7), jax.eval_shape(f, *x).shape)
+    loss = lambda *a: (lambda out: (jnp.sum(w * out), out))(f(*a))
+    with jax.default_matmul_precision("highest"):
+        (_, out), grads = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(len(x))), has_aux=True))(*x)
+    return out, grads
+
+
+@functools.cache
+def _chunked_and_recurrence(chunk, sub, T, decay, b_max):
+    x = _gdn_inputs(chunk + T, T, decay, b_max)
+    assert float(x[4].max()) > 0.99 * b_max and float(x[4].min()) < 0.01 * b_max
+    return _probed(lambda *a: linattn.chunked_gdn(*a, chunk=chunk, sub=sub), *x), _probed(_recurrence, *x)
+
+
 @pytest.mark.parametrize("what", ["forward", "gradient"])
 @pytest.mark.parametrize("chunk,sub,T,decay,b_max", GDN_CASES)
 def test_chunked_gated_delta_rule_is_the_recurrence(chunk, sub, T, decay, b_max, what):
-    x = _gdn_inputs(chunk + T, T, decay, b_max)
-    assert float(x[4].max()) > 0.99 * b_max and float(x[4].min()) < 0.01 * b_max
-    chunked = lambda *a: linattn.chunked_gdn(*a, chunk=chunk, sub=sub)
-    with jax.default_matmul_precision("highest"):
-        # Jitted here and below: one compile a side, where op by op is some hundred.
-        if what == "forward":
-            got, want = jax.jit(chunked)(*x), jax.jit(_recurrence)(*x)
-            assert got.shape == want.shape == x[2].shape and bool(jnp.isfinite(got).all())
-            pairs = [(got, want)]
-        else:
-            # A loss that weighs every output entry differently.
-            w = jax.random.normal(jax.random.key(7), x[2].shape)
-            grad = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2, 3, 4)))(*x)
-            pairs = list(zip(grad(chunked), grad(_recurrence)))
+    (got, got_grads), (want, want_grads) = _chunked_and_recurrence(chunk, sub, T, decay, b_max)
+    if what == "forward":
+        assert got.shape == want.shape == (2, T, 3, 24) and bool(jnp.isfinite(got).all())
+        pairs = [(got, want)]
+    else:
+        pairs = list(zip(got_grads, want_grads, strict=True))
     for got, want in pairs:
         assert bool(jnp.isfinite(got).all())
         # float32 on both sides, sums in another order: 1e-4 of the largest
@@ -117,17 +129,18 @@ def test_the_scalar_decay_form_is_the_per_channel_form_with_the_decay_spread():
     x = _gdn_inputs(3, 150, "mixed", 2.0)
     q, k, v, g, beta = x
     with jax.default_matmul_precision("highest"):
-        got = linattn.plain_gdn(*x)
-        want = linattn.plain_kda(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
+        got = jax.jit(linattn.plain_gdn)(*x)
+        want = jax.jit(linattn.plain_kda)(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
 def test_a_decay_per_channel_is_refused_by_shape():
     q, k, v, g, beta = _gdn_inputs(1, 64, "model", 2.0)
+    # Traced, not run: both are refused by what the call shows.
     with pytest.raises((ValueError, TypeError)):
-        linattn.chunked_gdn(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
+        jax.eval_shape(linattn.chunked_gdn, q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
     with pytest.raises(ValueError, match="power-of-two"):
-        linattn.chunked_gdn(q, k, v, g, beta, chunk=48, sub=16)
+        jax.eval_shape(functools.partial(linattn.chunked_gdn, chunk=48, sub=16), q, k, v, g, beta)
 
 
 def _gdn_traced_calls():
@@ -193,20 +206,25 @@ def test_two_halves_of_the_delta_rule_mixer_add_up_to_the_uncut_layer():
         num_heads=heads, key_dim=DK, value_dim=DV, d_model=D, dtype=jnp.float32
     )
     x = jax.random.normal(jax.random.key(1), (2, 70, D))
-    params = _moved(mixer(H_ALL).init(jax.random.key(0), x)["params"])
-    with jax.default_matmul_precision("highest"):
-        whole = mixer(H_ALL).apply({"params": params}, x)
-        halves = [
-            mixer(3).apply({"params": _gdn_half(params, first, 3)}, x) for first in (0, 3)
-        ]
-    want = _reference().linear_attention(x, params, 1e-6)
+    params = _moved(jax.jit(mixer(H_ALL).init)(jax.random.key(0), x)["params"])
+    reference = jax.jit(lambda x, p: _reference().linear_attention(x, p, 1e-6))
+
+    @jax.jit
+    def whole_and_halves(params, x):
+        with jax.default_matmul_precision("highest"):
+            return mixer(H_ALL).apply({"params": params}, x), [
+                mixer(3).apply({"params": _gdn_half(params, first, 3)}, x) for first in (0, 3)
+            ]
+
+    whole, halves = whole_and_halves(params, x)
+    want = reference(x, params)
     assert float(jnp.abs(halves[0]).max()) > 1e-2 and float(jnp.abs(halves[1]).max()) > 1e-2
     np.testing.assert_allclose(np.asarray(halves[0] + halves[1]), np.asarray(whole), atol=2e-6, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(whole), np.asarray(want), atol=2e-5, rtol=1e-4)
     # And a half is the reference's half.
     np.testing.assert_allclose(
         np.asarray(halves[1]),
-        np.asarray(_reference().linear_attention(x, _gdn_half(params, 3, 3), 1e-6)),
+        np.asarray(reference(x, _gdn_half(params, 3, 3))),
         atol=2e-5, rtol=1e-4,
     )
 
@@ -289,11 +307,11 @@ def test_the_delta_rule_mixer_runs_what_it_ran_before_kimi_s_mixer_was_fused(mon
 
     small = {"num_heads": 3, "key_dim": DK, "value_dim": DV, "d_model": D, "dtype": jnp.float32}
     x = jax.random.normal(jax.random.key(1), (2, 70, D))
-    params = mixers.GatedDeltaNetMixer(**small).init(jax.random.key(0), x)
+    params = jax.jit(mixers.GatedDeltaNetMixer(**small).init)(jax.random.key(0), x)
     probe = jax.random.normal(jax.random.key(2), x.shape)
-    both = lambda cls: jax.value_and_grad(
+    both = lambda cls: jax.jit(jax.value_and_grad(
         lambda p, x: jnp.sum(cls(**small).apply(p, x) * probe), (0, 1)
-    )(params, x)
+    ))(params, x)
     got, want = both(mixers.GatedDeltaNetMixer), both(_ParentGatedDeltaNetMixer)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
@@ -333,22 +351,33 @@ def test_two_halves_of_full_attention_add_up_given_the_uncut_norm_statistic():
     own statistic, and the uncut program the uncut reference."""
     ref = _reference()
     x = jax.random.normal(jax.random.key(2), (2, 40, D))
-    params = _moved(_attention(H_ALL).init(jax.random.key(0), x)["params"])
-    whole = ref.full_attention(x, params, H_ALL, 1e-6, 500000.0)
-    mean_square = lambda name: jnp.mean(
-        jnp.square(jnp.matmul(x, params[name]["kernel"], precision="highest")), axis=-1, keepdims=True
-    )
-    stats = (mean_square("query"), mean_square("key"))
-    halves = [
-        ref.full_attention(x, _attention_half(params, first, 3), 3, 1e-6, 500000.0, qk_mean_squares=stats)
-        for first in (0, 3)
-    ]
+    params = _moved(jax.jit(_attention(H_ALL).init)(jax.random.key(0), x)["params"])
+
+    @jax.jit
+    def the_reference_s(params, x):
+        whole = ref.full_attention(x, params, H_ALL, 1e-6, 500000.0)
+        mean_square = lambda name: jnp.mean(
+            jnp.square(jnp.matmul(x, params[name]["kernel"], precision="highest")), axis=-1, keepdims=True
+        )
+        stats = (mean_square("query"), mean_square("key"))
+        halves = [
+            ref.full_attention(x, _attention_half(params, first, 3), 3, 1e-6, 500000.0, qk_mean_squares=stats)
+            for first in (0, 3)
+        ]
+        return whole, halves, ref.full_attention(x, _attention_half(params, 0, 3), 3, 1e-6, 500000.0)
+
+    @jax.jit
+    def the_program_s(params, x):
+        with jax.default_matmul_precision("highest"):
+            return (
+                _attention(3).apply({"params": _attention_half(params, 0, 3)}, x),
+                _attention(H_ALL).apply({"params": params}, x),
+            )
+
+    whole, halves, own = the_reference_s(params, x)
     np.testing.assert_allclose(np.asarray(halves[0] + halves[1]), np.asarray(whole), atol=2e-6, rtol=1e-5)
-    own = ref.full_attention(x, _attention_half(params, 0, 3), 3, 1e-6, 500000.0)
     assert float(jnp.abs(own - halves[0]).max()) > 1e-3  # the statistic matters
-    with jax.default_matmul_precision("highest"):
-        got_half = _attention(3).apply({"params": _attention_half(params, 0, 3)}, x)
-        got_whole = _attention(H_ALL).apply({"params": params}, x)
+    got_half, got_whole = the_program_s(params, x)
     np.testing.assert_allclose(np.asarray(got_half), np.asarray(own), atol=2e-5, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(got_whole), np.asarray(whole), atol=2e-5, rtol=1e-4)
 
@@ -390,18 +419,24 @@ def test_post_norm_block_against_a_hand_written_block(remat):
         use_bias=False, qk_norm=True, head_dim=DH, norm_placement="post", mlp="gated_silu", remat=remat,
     )
     x = jax.random.normal(jax.random.key(3), (2, 24, D))
-    params = _moved(block.init(jax.random.key(0), x)["params"])
+    params = _moved(jax.jit(block.init)(jax.random.key(0), x)["params"])
     assert sorted(params) == ["attn", "ln1", "ln2", "mlp"]
-    with jax.default_matmul_precision("highest"):
-        got = block.apply({"params": params}, x)
-        grads = jax.grad(lambda p: jnp.sum(jnp.sin(block.apply({"params": p}, x))))(params)
-        mixed = _attention(3).apply({"params": params["attn"]}, x)
-        h = x + _rms(mixed, params["ln1"]["scale"])
-        m = params["mlp"]
-        ffn = (jax.nn.silu(h @ m["gate"]["kernel"]) * (h @ m["up"]["kernel"])) @ m["down"]["kernel"]
-        want = h + _rms(ffn, params["ln2"]["scale"])
-        # The pre-norm block on the same weights is another function.
-        pre = block.clone(norm_placement="pre").apply({"params": params}, x)
+
+    @jax.jit
+    def every_side(params, x):
+        with jax.default_matmul_precision("highest"):
+            loss = lambda p: (lambda y: (jnp.sum(jnp.sin(y)), y))(block.apply({"params": p}, x))
+            (_, got), grads = jax.value_and_grad(loss, has_aux=True)(params)
+            mixed = _attention(3).apply({"params": params["attn"]}, x)
+            h = x + _rms(mixed, params["ln1"]["scale"])
+            m = params["mlp"]
+            ffn = (jax.nn.silu(h @ m["gate"]["kernel"]) * (h @ m["up"]["kernel"])) @ m["down"]["kernel"]
+            want = h + _rms(ffn, params["ln2"]["scale"])
+            # The pre-norm block on the same weights is another function.
+            pre = block.clone(norm_placement="pre").apply({"params": params}, x)
+        return got, grads, want, pre
+
+    got, grads, want, pre = every_side(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
     assert float(jnp.abs(pre - got).max()) > 1e-2
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
@@ -415,18 +450,23 @@ def test_only_the_attention_layers_rotate():
     tokens = jax.random.randint(jax.random.key(1), (2, 40), 0, 97)
     rope = get_model("transformer_lm", **kw)
     none = get_model("transformer_lm", **{**kw, "pos_encoding": "none"})
-    params = rope.init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(rope.init)(jax.random.key(0), tokens)["params"]
     assert "pos_embedding" not in params
-    assert jax.tree.structure(params) == jax.tree.structure(none.init(jax.random.key(0), tokens)["params"])
-    blocks = lambda m: m.apply(
-        {"params": params}, tokens, capture_intermediates=lambda mdl, _: isinstance(mdl, tlm.Block),
-        mutable=["intermediates"],
-    )[1]["intermediates"]
-    a, b = blocks(rope), blocks(none)
-    first = lambda t: t["blocks_0"]["__call__"][0]
-    second = lambda t: t["blocks_1"]["__call__"][0]
-    np.testing.assert_array_equal(np.asarray(first(a)), np.asarray(first(b)))
-    assert float(jnp.abs(second(a) - second(b)).max()) > 1e-3
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(none.init, jax.random.key(0), tokens)["params"]
+    )
+
+    def blocks(m):
+        """The two blocks' outputs."""
+        captured = lambda p: m.apply(
+            {"params": p}, tokens, capture_intermediates=lambda mdl, _: isinstance(mdl, tlm.Block),
+            mutable=["intermediates"],
+        )[1]["intermediates"]
+        return jax.jit(lambda p: [captured(p)[f"blocks_{i}"]["__call__"][0] for i in (0, 1)])(params)
+
+    (first_a, second_a), (first_b, second_b) = blocks(rope), blocks(none)
+    np.testing.assert_array_equal(np.asarray(first_a), np.asarray(first_b))
+    assert float(jnp.abs(second_a - second_b).max()) > 1e-3
 
 
 @pytest.mark.parametrize(
@@ -453,8 +493,10 @@ def test_recomputing_each_half_changes_no_value_and_no_leaf():
     on = get_model("transformer_lm", **SMALL)
     off = get_model("transformer_lm", **{**SMALL, "remat": False})
     assert on.remat and not off.remat
-    params = on.init(jax.random.key(0), tokens)["params"]
-    assert jax.tree.structure(params) == jax.tree.structure(off.init(jax.random.key(0), tokens)["params"])
+    params = jax.jit(on.init)(jax.random.key(0), tokens)["params"]
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(off.init, jax.random.key(0), tokens)["params"]
+    )
     loss = lambda m: lambda p: jnp.sum(jnp.sin(m.apply({"params": p}, tokens)[0]))
     with jax.default_matmul_precision("highest"):
         # Jitted: one compile a side, where op by op is some hundred.
@@ -504,39 +546,3 @@ def test_olmo_hybrid_parameter_tree():
     assert la["query"]["kernel"].shape == (64, 36) and la["gate"]["kernel"].shape == (64, 72)
     assert sorted(params["blocks_3"]["attn"]) == ["k_norm", "key", "out", "q_norm", "query", "value"]
     assert not any("bias" == str(p[-1].key) for p, _ in jax.tree_util.tree_leaves_with_path(params))
-
-
-def test_fit_trains_the_olmo_hybrid_program_config(tmp_path):
-    """The normal path: ``get_config("olmo_hybrid")`` through ``fit`` at a
-    small size, with the fused head; the routes are counted (three
-    delta-rule layers and one attention, ``model.init`` and the step), the
-    scopes are in the step's map, the loss falls."""
-    from distributed_tensorflow_models_tpu.core import mesh as meshlib
-    from distributed_tensorflow_models_tpu.harness import train as trainlib
-
-    kw = {k: v for k, v in SMALL.items() if k != "dtype"}
-    cfg = get_config(
-        "olmo_hybrid", model_kwargs=kw, vocab_size=97, num_steps=40, global_batch_size=2,
-        train_steps=12, log_every_steps=2, fused_unembed=True, trace_export=True,
-    )
-    assert cfg.optimizer.warmup_steps == 2000 and cfg.optimizer.clip_global_norm == 1.0
-    workdir = str(tmp_path / "fit")
-    before = _gdn_traced_calls()
-    result = trainlib.fit(cfg, workdir, mesh=meshlib.data_parallel_mesh(jax.devices()[:1]))
-    assert int(result.state.step) == 12
-    with open(os.path.join(workdir, "metrics.jsonl")) as f:
-        rows = [json.loads(line) for line in f if line.strip()]
-    losses = [r["loss"] for r in rows if "loss" in r]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
-    assert not any(k.startswith("moe_") for k in rows[-1])
-    with open(os.path.join(workdir, "telemetry.json")) as f:
-        telemetry = json.load(f)["metrics"]
-    assert telemetry["gdn/route_plain"] == 6 and "gdn/route_kernel" not in telemetry
-    assert telemetry["attention/route_blockwise"] == 2 and telemetry["kda/route_plain"] == 0
-    assert telemetry["unembed/grad_in_forward"] == 1
-    assert _gdn_traced_calls() - before == 6
-    with open(os.path.join(workdir, "step_scopes_p0.json")) as f:
-        scopes = f.read()
-    for name in ("linear_attn", "gdn_core", "attention_core", "unembed_loss", "optimizer"):
-        assert name in scopes
-    assert "kda_core" not in scopes

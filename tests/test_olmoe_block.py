@@ -2,10 +2,12 @@
 expert layer of ``parallel/moe.py`` at a small size on the CPU, float32.
 
 The plain reference's side of it (logits, losses, every gradient,
-prefill then decode) is ``tests/benchmark/test_bench_reference_olmoe.py``.
+prefill then decode) is ``tests/benchmark/test_bench_reference_olmoe.py``;
+``fit`` through the program config on the data mesh is a case of
+``tests/test_lm_fit_smoke.py``.
 """
 
-import tempfile
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +57,11 @@ def _routing_case(case, kind="gated_silu"):
     return {**params, "router": router}, x
 
 
+@functools.partial(jax.jit, static_argnames="top_k")
 def _dense_masked(params, x, top_k):
     """Every expert on every token, masked by the top-k choice: the
-    formulation the sorted, grouped layer has to equal."""
+    formulation the sorted, grouped layer has to equal.  (Jitted, like
+    ``_grouped``: one compile a kind of expert, where op by op is dozens.)"""
     with jax.default_matmul_precision("highest"):
         h = x.reshape(-1, x.shape[-1])
         probs = jax.nn.softmax(h @ params["router"], axis=-1)
@@ -75,6 +79,7 @@ def _dense_masked(params, x, top_k):
     return out.reshape(x.shape), counts
 
 
+@functools.partial(jax.jit, static_argnames="top_k")
 def _grouped(params, x, top_k=K):
     with jax.default_matmul_precision("highest"):
         return moelib.topk_moe_ffn(params, x, top_k=top_k, dtype=jnp.float32)
@@ -111,8 +116,8 @@ def test_grouped_layer_equals_the_dense_masked_formulation(case, kind):
 def test_grouped_layer_gradients_equal_the_dense_masked_ones(case, kind):
     params, x = _routing_case(case, kind)
     probe = jax.random.normal(jax.random.key(3), x.shape)
-    want = jax.grad(lambda p, y: jnp.sum(_dense_masked(p, y, K)[0] * probe), argnums=(0, 1))(params, x)
-    got = jax.grad(lambda p, y: jnp.sum(_grouped(p, y).out * probe), argnums=(0, 1))(params, x)
+    want = jax.jit(jax.grad(lambda p, y: jnp.sum(_dense_masked(p, y, K)[0] * probe), argnums=(0, 1)))(params, x)
+    got = jax.jit(jax.grad(lambda p, y: jnp.sum(_grouped(p, y).out * probe), argnums=(0, 1)))(params, x)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5, rtol=1e-4)
 
@@ -171,12 +176,12 @@ def test_every_rank_of_a_data_mesh_routes_its_own_tokens(spec):
     probe = jax.random.normal(jax.random.key(6), x.shape)
     with jax.default_matmul_precision("highest"):
         g_mesh = jax.jit(jax.grad(lambda p: jnp.sum(on_mesh(p, x).out * probe)))(params)
-        g_one = jax.grad(
+        g_one = jax.jit(jax.grad(
             lambda p: sum(
                 jnp.sum(_grouped(p, x[i : i + rows]).out * probe[i : i + rows])
                 for i in range(0, 4, rows)
             )
-        )(params)
+        ))(params)
     for g, w in zip(jax.tree.leaves(g_mesh), jax.tree.leaves(g_one)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5, rtol=1e-4)
 
@@ -242,8 +247,8 @@ def test_gpt2_block_output_is_the_parent_s():
     cfg = get_config("transformer_lm")
     model = get_model("transformer_lm", **cfg.model_kwargs, dtype=jnp.float32)
     tokens = jax.random.randint(jax.random.key(1), (2, 24), 0, 10000)
-    params = model.init(jax.random.key(0), tokens)["params"]
-    logits, _ = model.apply({"params": params}, tokens, train=False)
+    params = jax.jit(model.init)(jax.random.key(0), tokens)["params"]
+    logits, _ = jax.jit(lambda p: model.apply({"params": p}, tokens, train=False))(params)
     flat = np.asarray(logits, np.float64)
     assert flat.shape == (2, 24, 10000)
     assert float(flat.sum()) == pytest.approx(PARENT_OUTPUT["sum"], rel=1e-5, abs=1e-2)
@@ -310,34 +315,20 @@ def test_prefill_then_decode_is_the_full_forward():
     kw = {**SMALL, "dtype": jnp.float32}
     model, decoder = get_model("transformer_lm", **kw), get_model("transformer_lm", **kw, decode=True)
     tokens = jax.random.randint(jax.random.key(2), (3, 20), 0, 97)
-    params = model.init(jax.random.key(0), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(0), tokens)["params"]
+    # Three programs: the full forward, the prefill, one decode step (run eight times).
+    full = jax.jit(lambda p, t: model.apply({"params": p}, t, train=False))
+    prefill = jax.jit(lambda p, t: decoder.apply({"params": p}, t, mutable=["cache"]))
+    decode = jax.jit(lambda p, cache, t: decoder.apply({"params": p, "cache": cache}, t, mutable=["cache"]))
     with jax.default_matmul_precision("highest"):
-        want, _ = model.apply({"params": params}, tokens, train=False)
-        got, state = decoder.apply({"params": params}, tokens[:, :12], mutable=["cache"])
+        want, _ = full(params, tokens)
+        got, state = prefill(params, tokens[:, :12])
         steps = [got[0]]
         for t in range(12, 20):
-            got, state = decoder.apply(
-                {"params": params, "cache": state["cache"]}, tokens[:, t : t + 1], mutable=["cache"]
-            )
+            got, state = decode(params, state["cache"], tokens[:, t : t + 1])
             steps.append(got[0])
     # float32 on the CPU; the cached path attends with the reference
     # softmax, training with the blockwise one: reduction order only.
     np.testing.assert_allclose(
         np.asarray(jnp.concatenate(steps, axis=1)), np.asarray(want), atol=5e-5, rtol=1e-5
     )
-
-
-def test_fit_trains_the_olmoe_program_config_on_a_data_mesh():
-    cfg = get_config(
-        "olmoe", model_kwargs=SMALL, vocab_size=97, num_steps=32,
-        global_batch_size=8, train_steps=6, log_every_steps=2,
-    )
-    res = trainlib.fit(cfg, tempfile.mkdtemp())
-    assert res.steps_run == 6
-    final = res.final_metrics
-    assert np.isfinite(final["loss"]) and final["loss"] > final["nll"]
-    assert final["moe_load_max_over_mean"] >= 1.0
-    # The weighted router losses are what ``losses`` adds to the objective
-    # (two layers: the means over layers times two).
-    weighted = 2 * (0.01 * final["moe_aux_loss"] + 0.001 * final["moe_z_loss"])
-    assert final["aux_loss"] == pytest.approx(weighted, rel=0.2)

@@ -1,6 +1,8 @@
 """RoPE properties: relative-position invariance, decode parity, and the
 train->generate round trip with pos_encoding='rope'."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,13 @@ import pytest
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import rotary
 from distributed_tensorflow_models_tpu.models import get_model
+
+
+@functools.cache
+def _jitted(model, mutable=False):
+    """``model.apply`` under ``jit``: one compile a shape, where the bare
+    call compiles every operation of every new length by itself."""
+    return jax.jit(lambda variables, *args: model.apply(variables, *args, train=False, mutable=mutable))
 
 
 def test_rope_is_relative():
@@ -65,7 +74,7 @@ def rope_lm():
         attn_impl="reference",
         pos_encoding="rope",
     )
-    params = model.init(
+    params = jax.jit(model.init)(
         jax.random.key(0), jnp.zeros((1, 4), jnp.int32)
     )["params"]
     return model, params
@@ -82,7 +91,7 @@ def test_rope_decode_matches_full_forward(rope_lm):
     model, params = rope_lm
     rng = np.random.RandomState(2)
     tokens = jnp.asarray(rng.randint(0, 50, (2, 10)), jnp.int32)
-    full_logits, _ = model.apply({"params": params}, tokens, train=False)
+    full_logits, _ = _jitted(model)({"params": params}, tokens)
 
     decode_model = model.clone(decode=True)
     cache = {}
@@ -91,8 +100,8 @@ def test_rope_decode_matches_full_forward(rope_lm):
         variables = {"params": params}
         if cache:
             variables["cache"] = cache
-        (lg, _), mut = decode_model.apply(
-            variables, tokens[:, t : t + 1], train=False, mutable=["cache"]
+        (lg, _), mut = _jitted(decode_model, mutable=("cache",))(
+            variables, tokens[:, t : t + 1]
         )
         cache = mut["cache"]
         outs.append(lg[:, 0])
@@ -110,7 +119,7 @@ def test_rope_generate_matches_naive(rope_lm):
     out = generate(model, params, prompt, 5)
     toks = prompt
     for _ in range(5):
-        logits, _ = model.apply({"params": params}, toks, train=False)
+        logits, _ = _jitted(model)({"params": params}, toks)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         toks = jnp.concatenate([toks, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(toks))

@@ -46,8 +46,8 @@ def tiny_cfg(**overrides):
 def test_forward_shapes_and_carry_passthrough():
     model = get_model("transformer_lm", **TINY)
     tokens = jnp.zeros((2, 16), jnp.int32)
-    variables = model.init(jax.random.key(0), tokens)
-    logits, carry = model.apply(variables, tokens)
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
+    logits, carry = jax.jit(model.apply)(variables, tokens)
     assert logits.shape == (2, 16, 10000)
     assert logits.dtype == jnp.float32
     assert carry is None
@@ -58,11 +58,11 @@ def test_causality():
     model = get_model("transformer_lm", **TINY)
     rng = np.random.RandomState(0)
     toks = rng.randint(0, 10000, (1, 16)).astype(np.int32)
-    variables = model.init(jax.random.key(0), jnp.asarray(toks))
-    out1, _ = model.apply(variables, jnp.asarray(toks))
+    variables = jax.jit(model.init)(jax.random.key(0), jnp.asarray(toks))
+    out1, _ = jax.jit(model.apply)(variables, jnp.asarray(toks))
     toks2 = toks.copy()
     toks2[0, -1] = (toks2[0, -1] + 1) % 10000
-    out2, _ = model.apply(variables, jnp.asarray(toks2))
+    out2, _ = jax.jit(model.apply)(variables, jnp.asarray(toks2))
     np.testing.assert_allclose(
         np.asarray(out1[0, :-1]), np.asarray(out2[0, :-1]), atol=1e-5
     )
@@ -216,9 +216,9 @@ def test_moe_matches_reference_oracle_at_init():
     tokens = jnp.asarray(
         np.random.RandomState(0).randint(0, 10000, (4, 16)), jnp.int32
     )
-    variables = plain.init(jax.random.key(0), tokens)
-    ref, _ = plain.apply(variables, tokens)
-    got, _ = meshy.apply(variables, tokens)
+    variables = jax.jit(plain.init)(jax.random.key(0), tokens)
+    ref, _ = jax.jit(plain.apply)(variables, tokens)
+    got, _ = jax.jit(meshy.apply)(variables, tokens)
     np.testing.assert_allclose(
         np.asarray(ref), np.asarray(got), atol=2e-2, rtol=2e-2
     )
@@ -271,9 +271,9 @@ class TestPipelineParallel:
         toks = jnp.asarray(
             np.random.RandomState(0).randint(0, 10000, (16, 16)), jnp.int32
         )
-        variables = seq_model.init(jax.random.key(0), toks)
-        ref, _ = seq_model.apply(variables, toks)
-        got, _ = pipe_model.apply(variables, toks)
+        variables = jax.jit(seq_model.init)(jax.random.key(0), toks)
+        ref, _ = jax.jit(seq_model.apply)(variables, toks)
+        got, _ = jax.jit(pipe_model.apply)(variables, toks)
         np.testing.assert_allclose(
             np.asarray(ref), np.asarray(got), atol=2e-5, rtol=2e-5
         )
@@ -346,15 +346,15 @@ def test_pipelined_dropout_matches_sequential():
     toks = jnp.asarray(
         np.random.RandomState(0).randint(0, 10000, (16, 16)), jnp.int32
     )
-    variables = seq_model.init(jax.random.key(0), toks)
+    variables = jax.jit(seq_model.init)(jax.random.key(0), toks)
     rngs = {"dropout": jax.random.key(3)}
-    ref, _ = seq_model.apply(variables, toks, train=True, rngs=rngs)
-    got, _ = pipe_model.apply(variables, toks, train=True, rngs=rngs)
+    dropped = lambda m: jax.jit(lambda v, r: m.apply(v, toks, train=True, rngs=r))(variables, rngs)
+    (ref, _), (got, _) = dropped(seq_model), dropped(pipe_model)
     np.testing.assert_allclose(
         np.asarray(ref), np.asarray(got), atol=2e-5, rtol=2e-5
     )
     # Dropout actually fires (train vs eval outputs differ).
-    ev, _ = seq_model.apply(variables, toks)
+    ev, _ = jax.jit(seq_model.apply)(variables, toks)
     assert float(jnp.abs(ref - ev).max()) > 1e-3
 
 
