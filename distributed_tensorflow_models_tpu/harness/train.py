@@ -1341,7 +1341,8 @@ def _trace_counts() -> dict[str, float]:
     """What the ops count at trace time (``attention(impl="auto")``'s
     routes, ``chunked_kda``'s, ``KDAMixer``'s two placements,
     ``chunked_gdn``'s and ``chunked_ssd``'s traced calls, the fused head's
-    gradient-in-forward calls), as the process-global registry holds it now."""
+    gradient-in-forward calls, what the recomputed halves keep), as the
+    process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
@@ -1355,6 +1356,7 @@ def _trace_counts() -> dict[str, float]:
             telemetry.GDN_ROUTE_PLAIN,
             telemetry.SSD_ROUTE_KERNEL, telemetry.SSD_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
+            telemetry.REMAT_PRODUCTS_KEPT, telemetry.REMAT_BYTES_KEPT,
         )
     }
 

@@ -96,6 +96,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributed_tensorflow_models_tpu.models import remat as rematlib
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.ops import ssm as ssmlib
@@ -311,7 +312,7 @@ class Mamba2Mixer(nn.Module):
         H, P, N, K = self.num_heads, self.head_dim, self.state_dim, self.conv_size
         groups = self.num_groups
         inner, mixed = H * P, H * P + 2 * groups * N
-        zxbcdt = _dense(inner + mixed + H, self.dtype, "in_proj")(x)
+        zxbcdt = rematlib.kept(_dense(inner + mixed + H, self.dtype, "in_proj")(x))
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + mixed], axis=-1)
         # torch's Conv1d default, weight and bias: uniform(+-1/sqrt(fan_in)),
         # fan_in K for a depth-wise convolution.

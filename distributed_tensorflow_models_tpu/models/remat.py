@@ -1,0 +1,78 @@
+"""What a recomputed half of a block keeps (``transformer_lm.Block.remat``).
+
+``Block`` recomputes its mixer half and its feed-forward half in the
+backward pass, each on its own.  A half keeps its input and **the
+outputs of its wide input products**; everything else (norms,
+activations, gates, convolutions, every Pallas kernel: the scan, the
+attention core, the grouped expert products) runs again.  A product
+``[T, d_in] x [d_in, d_out]`` kept saves ``d_in`` FLOP for each byte of
+output it holds, so the set is the products with the most to save a
+byte, the same for every model (ISSUE 42; PERF.md section 6):
+
+1. the feed-forward's output where a post-norm reads it (``Block``,
+   ``norm_placement="post"``: the norm's backward needs it, so without it
+   all three products of the half run twice; before a pre-norm block's
+   residual nothing needs it and nothing is named);
+2. the feed-forwards' wide products, dense and shared alike: ``gate`` and
+   ``up`` of ``GatedMLP``, ``up`` of ``MLP``;
+3. ``Mamba2Mixer``'s ``in_proj``.
+
+The modules name those outputs with :func:`kept`; :func:`half` is the
+``nn.remat`` whose policy saves that name and nothing else.  Outside a
+recomputed half :func:`kept` returns its argument, so a model that
+recomputes nothing traces the program it traced before.  What a traced
+half holds is counted like the ops' routes, at trace time in the
+process-global registry (``remat/products_kept``, ``remat/bytes_kept``).
+"""
+
+from __future__ import annotations
+
+import threading
+
+import flax.linen as nn
+import jax
+from jax.ad_checkpoint import checkpoint_name
+
+from distributed_tensorflow_models_tpu.telemetry.registry import (
+    REMAT_BYTES_KEPT,
+    REMAT_PRODUCTS_KEPT,
+    get_registry,
+)
+
+KEPT_NAME = "kept_product"
+
+
+class _Tracing(threading.local):
+    """How many recomputed halves this thread is tracing (the AOT thread
+    and the loop's trace the step side by side)."""
+
+    halves = 0
+
+
+_tracing = _Tracing()
+
+
+def half(fn):
+    """``fn(module, y)`` recomputed in the backward pass but for what
+    :func:`kept` names inside it."""
+
+    def traced(mdl, y):
+        _tracing.halves += 1
+        try:
+            return fn(mdl, y)
+        finally:
+            _tracing.halves -= 1
+
+    return nn.remat(
+        traced, policy=jax.checkpoint_policies.save_only_these_names(KEPT_NAME)
+    )
+
+
+def kept(x):
+    """``x``, and inside a recomputed half the array that half keeps."""
+    if not _tracing.halves:
+        return x
+    registry = get_registry()
+    registry.counter(REMAT_PRODUCTS_KEPT).inc()
+    registry.counter(REMAT_BYTES_KEPT).inc(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, KEPT_NAME)

@@ -65,7 +65,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_models_tpu.models import mixers, register
+from distributed_tensorflow_models_tpu.models import mixers, register, remat as rematlib
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops.embed import TokenEmbed
 
@@ -210,7 +210,7 @@ class MLP(nn.Module):
         dense = lambda name, feats: nn.Dense(
             feats, dtype=self.dtype, use_bias=self.use_bias, name=name
         )
-        h = dense("up", self.d_ff)(x)
+        h = rematlib.kept(dense("up", self.d_ff)(x))
         if self.activation == "relu2":
             from distributed_tensorflow_models_tpu.parallel.moe import squared_relu
 
@@ -236,8 +236,8 @@ class GatedMLP(nn.Module):
         dense = lambda name, feats: nn.Dense(
             feats, dtype=self.dtype, use_bias=False, name=name
         )
-        h = nn.silu(dense("gate", self.d_ff)(x)) * dense("up", self.d_ff)(x)
-        return dense("down", self.d_model)(h)
+        wide = lambda name: rematlib.kept(dense(name, self.d_ff)(x))
+        return dense("down", self.d_model)(nn.silu(wide("gate")) * wide("up"))
 
 
 _MIXERS = ("attention", "kda", "gdn", "mla", "ssm")
@@ -458,9 +458,9 @@ class Block(nn.Module):
     # "relu2" one (the MLP with a squared ReLU).
     mlp: str = "gelu"
     # Recompute in the backward pass, the mixer's half of the block and
-    # the feed-forward's each on its own (each with its norm): while one
-    # half runs backward the other keeps nothing but its input.  A layer
-    # of one sub-layer has one half.
+    # the feed-forward's each on its own (each with its norm): a half keeps
+    # its input and its wide input products (models/remat.py), no more.
+    # A layer of one sub-layer has one half.
     remat: bool = False
 
     def _mix(self, h, train):
@@ -495,7 +495,7 @@ class Block(nn.Module):
         ).astype(self.dtype)
         if self.norm_placement == "post":
             mix = lambda mdl, y: norm("ln1", mdl._mix(y, train))
-            feed = lambda mdl, y: norm("ln2", mdl._ffn()(y, train=train))
+            feed = lambda mdl, y: norm("ln2", rematlib.kept(mdl._ffn()(y, train=train)))
         else:
             mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
             feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
@@ -505,7 +505,7 @@ class Block(nn.Module):
             )
             mix, feed = branch(mix), branch(feed)
         if self.remat:
-            mix, feed = nn.remat(mix), nn.remat(feed)
+            mix, feed = rematlib.half(mix), rematlib.half(feed)
         if self.mixer != "none":
             x = x + mix(self, x)
         if self.feed:

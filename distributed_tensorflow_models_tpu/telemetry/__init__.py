@@ -71,6 +71,8 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     PREFETCH_FILL,
     PRODUCER_WAIT,
     REASSEMBLY_WAIT,
+    REMAT_BYTES_KEPT,
+    REMAT_PRODUCTS_KEPT,
     RESTARTS,
     ROLLBACKS,
     SHARD,

@@ -100,6 +100,12 @@ GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter
 # TPU for whole tiles, the plain ``jax.numpy`` form everywhere else.
 SSD_ROUTE_KERNEL = "ssd/route_kernel"  # counter
 SSD_ROUTE_PLAIN = "ssd/route_plain"  # counter
+# What the recomputed halves of a stack's blocks keep beside their inputs
+# (``models/remat.py``: the wide input products a half names), counted at
+# trace time like the routes: one increment per kept product per traced
+# call of the model, and its bytes.  0 where nothing is recomputed.
+REMAT_PRODUCTS_KEPT = "remat/products_kept"  # counter
+REMAT_BYTES_KEPT = "remat/bytes_kept"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -9,13 +9,15 @@ data-parallel step over four devices compiling on every later PR.
 
 Nothing runs and nothing here is a measurement.  Only the fast compiles
 are kept; the whole ResNet-50 b256 step (~40 s) and the ``conv2d_mxu``
-gradient at 56x56x64 (~18 s) stay in the builder's rehearsal.  Three
+gradient at 56x56x64 (~18 s) stay in the builder's rehearsal.  Four
 whole steps are here all the same, under ``slow`` (345 to 440 s each
 from an empty cache beside five other workers, ISSUE 41):
-``olmo_hybrid_train``'s, ``granite_h_train``'s and ``nemotron_h_train``'s,
-because those cells' batch and the scan's chunk were chosen by what the
-compiler places; the chip run of every PR holds the same guard, and the
-tier-1 run keeps each cell's step at one period of its layers.  A route
+``olmo_hybrid_train``'s, ``granite_h_train``'s, ``nemotron_h_train``'s and
+``kimi_linear_train``'s, because those cells' batch, the scan's chunk and
+what a recomputed half keeps (``models/remat.py``: every one of the four
+at 15.0 GiB or under, ISSUE 42) were chosen by what the compiler places;
+the chip run of every PR holds the same guard, and the tier-1 run keeps
+three of the cells' steps at one period of their layers.  A route
 or a kernel count does not depend on the length beyond two chunks, so a
 case compiles the smallest shape its kernel admits unless its assertion
 is about the cell's shape (it then says so).  The persistent cache is
@@ -470,7 +472,7 @@ def _cell_step_compiled(one_chip, cell_name, period=None):
             **overrides["model_kwargs"], "layer_mixers": period, "num_layers": len(period), "vocab_size": 2048,
         }
     cfg = get_config(cell.config["program_config"], **overrides, global_batch_size=per_chip)
-    assert (per_chip, cfg.num_steps, cfg.fused_unembed) == (1, 8192, True)
+    assert (cfg.num_steps, cfg.fused_unembed) == (8192, True) and per_chip in (1, 2)
     model = get_model(cfg.model, **cfg.model_kwargs)
     kernel_route = reglib.get_registry().counter(reglib.SSD_ROUTE_KERNEL)
     before = kernel_route.value
@@ -520,12 +522,12 @@ _ONE_PERIOD = {
 }
 
 
-def _scopes_are_on(text, cell_name):
+def _scopes_are_on(text, cell_name, scopes=None):
     """Every scope the cell's per-layer readers look for, as a whole path
     element of some instruction's ``op_name``; nothing the compiler
     rematerialized of its own."""
     assert not re.search(r"\.remat\d*", text)
-    for scope in _ONE_PERIOD[cell_name]["scopes"]:
+    for scope in scopes or _ONE_PERIOD[cell_name]["scopes"]:
         assert re.search(rf"[/(]{scope}[/)]", text), scope
 
 
@@ -534,7 +536,9 @@ def test_a_cell_s_step_at_one_period_of_its_layers_takes_its_routes_for_v5e(one_
     """The quick sibling of the three whole steps below (which are
     ``slow``): the cell's configuration with one layer of each kind and a
     vocabulary of 2,048, the same sequence of 8,192, compiled for one
-    described v5e.  The kernel routes are taken, the compiler
+    described v5e.  The kernel routes are taken (a recomputed half keeps
+    its wide input products and no kernel's output, so every kernel is
+    still there forward, recomputed and backward), the compiler
     rematerializes nothing of its own and every scope the per-layer
     readers look for is on the step; what it holds is the whole step's
     and the chip run's to say."""
@@ -553,13 +557,15 @@ def test_a_cell_s_step_at_one_period_of_its_layers_takes_its_routes_for_v5e(one_
 def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(one_chip):
     """The whole ``olmo_hybrid_train`` step (the cell's configuration
     through ``benchmark/lib/cells.py``, Adam with the clip, the fused
-    head, per-half recomputation, one sequence of 8,192) for one
-    described v5e: it fits the chip's 15.75 GiB with room (13.15 GiB when
-    the cell was added: 8.56 of state, 4.41 of temporaries; PERF.md, PR
-    32), the compiler rematerializes nothing of its own, the attention
-    layer runs the fused kernels and the three scopes are on the step."""
+    head, each half recomputed but for the three products of its
+    feed-forward, which the post-norm makes it keep, one sequence of
+    8,192) for one described v5e: it fits the chip's 15.75 GiB with room
+    (13.54 GiB: 8.56 of state, 4.80 of temporaries; PERF.md, PR 42; 13.15
+    when a half kept its input alone), the compiler rematerializes nothing
+    of its own, the attention layer runs the fused kernels and the three
+    scopes are on the step."""
     compiled, held, _, _ = _cell_step_compiled(one_chip, "olmo_hybrid_train")
-    assert 12.0 < held < 14.5, held
+    assert 12.5 < held < 14.5, held
     text = compiled.as_text()
     # The attention layer: forward, the recomputed forward, the backward.
     assert text.count("tpu_custom_call") >= 3
@@ -614,19 +620,21 @@ def test_chunked_ssd_compiles_for_v5e(one_chip, dtype, groups):
 def test_granite_h_step_compiles_for_v5e_under_its_memory(one_chip):
     """The whole ``granite_h_train`` step (the cell's configuration through
     ``benchmark/lib/cells.py``, Adam with the clip, the fused head fed from
-    the tied embedding, per-half recomputation, one sequence of 8,192, the
-    scan's chunk of 256) for one described v5e: it fits the chip's 15.75
-    GiB with room (12.13 GiB when the cell was added: 8.63 of state, 3.25
-    of temporaries; at a chunk of 64 it did not fit without 254
-    rematerialized clones; PERF.md, PR 38), the compiler rematerializes
-    nothing of its own, the attention layer runs the fused kernels over
-    its grouped heads and the scopes are on the step."""
+    the tied embedding, each half recomputed but for the feed-forwards'
+    ``gate`` and ``up`` and the state-space mixers' ``in_proj``, which it
+    keeps, one sequence of 8,192, the scan's chunk of 256) for one
+    described v5e: it fits the chip's 15.75 GiB with room (14.17 GiB: 8.63
+    of state, 5.50 of temporaries; PERF.md, PR 42; 11.24 when a half kept
+    its input alone, 12.13 when the cell was added; at a chunk of 64 it
+    did not fit without 254 rematerialized clones: PR 38), the compiler
+    rematerializes nothing of its own, the attention layer runs the fused
+    kernels over its grouped heads and the scopes are on the step."""
     compiled, held, state, kernel_route = _cell_step_compiled(one_chip, "granite_h_train")
     assert "head" not in state.params  # tied
     # Nine state-space layers, ``model.init`` and the step: the generalised
     # scan (groups of heads, PR 40) still takes its kernels at one group.
     assert kernel_route == 18
-    assert 11.0 < held < 13.5, held
+    assert 13.2 < held < 15.0, held
     text = compiled.as_text()
     # The attention layer: forward, the recomputed forward, the backward.
     assert text.count("tpu_custom_call") >= 3
@@ -638,9 +646,10 @@ def test_nemotron_h_step_compiles_for_v5e_under_its_memory(one_chip):
     """The whole ``nemotron_h_train`` step (nine one-sub-layer layers
     ``MEMEM*EME`` at the published widths, 8 of 128 experts held, an eighth
     of the vocabulary, Adam with the clip, the fused head, every layer
-    recomputed, one sequence of 8,192) for one described v5e: it fits the
-    chip's 15.75 GiB with room (10.97 GiB when the cell was added: 7.45 of
-    state, 3.34 of temporaries; PERF.md, PR 40), the compiler
+    recomputed but for the mixers' ``in_proj`` and the shared experts'
+    ``up``, one sequence of 8,192) for one described v5e: it fits the
+    chip's 15.75 GiB with room (11.21 GiB: 7.45 of state, 3.58 of
+    temporaries; PERF.md, PR 42; 10.97 when the cell was added), the compiler
     rematerializes nothing of its own, the four state-space layers take
     the grouped scan's kernels (``model.init`` and the step: 8), the
     attention layer the fused kernels over sixteen-fold groups, and the
@@ -650,9 +659,41 @@ def test_nemotron_h_step_compiles_for_v5e_under_its_memory(one_chip):
     assert "w_gate" not in state.params["blocks_1"]["moe"] and "head" in state.params
     assert sum(x.size for x in jax.tree.leaves(state.params)) == 666_962_944
     assert kernel_route == 8
-    assert 10.0 < held < 12.5, held
+    assert 10.2 < held < 12.5, held
     text = compiled.as_text()
     # Four scans and the attention layer, each forward, recomputed and
     # backward; the experts' grouped products.
     assert text.count("tpu_custom_call") >= 15
     _scopes_are_on(text, "nemotron_h_train")
+
+
+@pytest.mark.slow
+def test_kimi_linear_step_compiles_for_v5e_under_its_memory(one_chip):
+    """The whole ``kimi_linear_train`` step (published layers 1-5 at the
+    published widths, 8 of 256 experts held, an eighth of the vocabulary,
+    Adam with the clip, the fused head, each half recomputed but for the
+    dense layer's and the four shared experts' ``gate`` and ``up``, two
+    sequences of 8,192) for one described v5e: it fits the chip's 15.75
+    GiB with room (12.52 GiB: 6.73 of state, 5.52 of temporaries; PERF.md,
+    PR 42; 12.35 when a half kept its input alone), the compiler
+    rematerializes nothing of its own, the four KDA layers take the delta rule's kernels
+    and the fused passes (``model.init`` and the step: 8 each), and the
+    scopes of every piece are on the step."""
+    from distributed_tensorflow_models_tpu.telemetry import registry as reglib
+
+    counters = [reglib.get_registry().counter(name) for name in (reglib.KDA_ROUTE_KERNEL, reglib.KDA_MIXER_FUSED)]
+    before = [c.value for c in counters]
+    compiled, held, state, _ = _cell_step_compiled(one_chip, "kimi_linear_train")
+    assert [c.value - was for c, was in zip(counters, before)] == [8, 8]
+    assert sorted(state.params["blocks_0"]) == ["linear_attn", "ln1", "ln2", "mlp"]
+    assert 11.5 < held < 13.5, held
+    text = compiled.as_text()
+    # Four KDA layers of three core kernels and fifteen fused passes (each
+    # forward, recomputed and backward), the latent attention's three, and
+    # four expert layers' three grouped products (those three times, and
+    # once more for the weights' gradients).
+    assert text.count("tpu_custom_call") >= 4 * (3 + 15) + 3 + 4 * 3 * 4
+    _scopes_are_on(text, "kimi_linear_train", (
+        "linear_attn", "kda_core", "kda_pass", "attention_core", "moe", "moe_dispatch", "moe_experts", "moe_shared",
+        "unembed_loss", "optimizer",
+    ))

@@ -1,0 +1,183 @@
+"""What a recomputed half of a block keeps (``models/remat.py``), at a
+small size on the CPU in float32, for a two-layer ``TransformerLM`` of
+each kind that reaches a named product: the gated feed-forward behind a
+pre-norm and behind a post-norm, the squared-ReLU one in a layer of its
+own, the state-space mixer with one group and with two, an expert layer
+with a shared expert, the GELU MLP.
+
+For each: (a) the loss and every gradient are those of the bare
+``nn.remat`` this replaced, bit for bit, and those of the model that
+recomputes nothing to float32 rounding (XLA fuses a recomputed half
+otherwise than the first pass: 5e-7 of the largest entry, with the bare
+``nn.remat`` too); (b) the differentiated program makes each named
+product once where the bare ``nn.remat`` makes it twice, while the
+attention projections, the scan and the grouped expert products are
+still made twice; (c) ``remat/products_kept`` and ``remat/bytes_kept``
+read what the shapes say, and 0 where nothing is recomputed.
+"""
+
+import collections
+import functools
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import pytest
+from jax._src import core as jax_core
+
+from distributed_tensorflow_models_tpu.models import get_model
+from distributed_tensorflow_models_tpu.models import remat as rematlib
+from distributed_tensorflow_models_tpu.telemetry import registry as reglib
+
+B, T, D = 2, 32, 64
+# Four query heads and two key/value heads of 8: the projections' kernels
+# are [64, 32], [64, 16] twice and [32, 64], none the shape of a
+# feed-forward's.
+BASE = dict(
+    vocab_size=97, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8, d_model=D, d_ff=96, max_len=T,
+    norm="rmsnorm", use_bias=False, pos_encoding="none", mlp="gated_silu", dtype=jnp.float32, attn_impl="reference",
+)
+QUERY, KEY_VALUE, OUT = (D, 32), (D, 16), (32, D)
+SSM = dict(layer_mixers=("ssm", "attention"), ssm_num_heads=4, ssm_head_dim=8, ssm_state_dim=16, ssm_chunk=16)
+
+# kind -> (model kwargs; kernel shape -> products of that shape a forward
+# pass makes, for the products a half keeps and for some it does not;
+# name of a jitted function -> calls a forward pass makes that are
+# recomputed).  ``in_proj`` is ``2 d_inner + 2 G N + H`` wide.
+KINDS = {
+    "gated_silu_pre_norm": (BASE, {(D, 96): 4}, {QUERY: 2, KEY_VALUE: 4}, {}),
+    # The post-norm reads each sub-layer's output: ``down`` is kept, the
+    # attention's ``out`` is made again.
+    "gated_silu_post_norm": (
+        {**BASE, "norm_placement": "post"}, {(D, 96): 4, (96, D): 2}, {QUERY: 2, KEY_VALUE: 4, OUT: 2}, {},
+    ),
+    "relu2_ffn_only": (
+        {**BASE, "mlp": "relu2", "layer_mixers": ("attention_only", "ffn_only")},
+        {(D, 96): 1}, {QUERY: 1, KEY_VALUE: 2}, {},
+    ),
+    "ssm_one_group": (
+        {**BASE, **SSM}, {(D, 100): 1, (D, 96): 4}, {QUERY: 1, KEY_VALUE: 2}, {"plain_ssd": 1},
+    ),
+    "ssm_two_groups": (
+        {**BASE, **SSM, "ssm_num_groups": 2}, {(D, 132): 1, (D, 96): 4}, {QUERY: 1, KEY_VALUE: 2}, {"plain_ssd": 1},
+    ),
+    # Two expert layers of four experts of 24, top-2, one shared expert of 40:
+    # three grouped products a layer.
+    "shared_expert": (
+        {**BASE, "d_ff": 24, "moe_router": "topk", "moe_layers": "all", "num_experts": 4, "moe_top_k": 2,
+         "moe_shared_experts": 1, "moe_shared_d_ff": 40},
+        {(D, 40): 4}, {QUERY: 2, KEY_VALUE: 4}, {"gmm": 6},
+    ),
+    "gelu_mlp": (
+        {**BASE, "mlp": "gelu", "norm": "layernorm", "use_bias": True, "pos_encoding": "learned"},
+        {(D, 96): 2}, {QUERY: 2, KEY_VALUE: 4}, {},
+    ),
+}
+kinds = pytest.mark.parametrize("kind", sorted(KINDS))
+
+
+def _tokens():
+    return jnp.arange(B * T).reshape(B, T) % 97
+
+
+def _loss_of(kind, remat):
+    model = get_model("transformer_lm", **KINDS[kind][0], remat=remat)
+
+    def loss(params):
+        (logits, _), _ = model.apply(params, _tokens(), mutable=["losses", "moe_stats"])
+        return jnp.mean(jnp.square(logits))
+
+    return model, loss
+
+
+@functools.lru_cache(maxsize=None)
+def _params(kind):
+    return jax.jit(_loss_of(kind, False)[0].init)(jax.random.key(0), _tokens())
+
+
+def _value_and_grad(kind, remat):
+    return jax.jit(jax.value_and_grad(_loss_of(kind, remat)[1]))(_params(kind))
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax_core.jaxprs_in_params(eqn.params):
+            yield from _walk(inner)
+
+
+def _made(kind, remat):
+    """``(products by kernel shape, jitted calls by name, names)`` of the
+    loss's gradient as traced: the products are the ``x @ kernel`` of a
+    ``Dense`` (the backward pass's contract other dimensions)."""
+    jaxpr = jax.make_jaxpr(jax.grad(_loss_of(kind, remat)[1]))(_params(kind)).jaxpr
+    products, calls, names = collections.Counter(), collections.Counter(), 0
+    for eqn in _walk(jaxpr):
+        if eqn.primitive.name == "dot_general" and eqn.params["dimension_numbers"] == (((2,), (0,)), ((), ())):
+            products[tuple(eqn.invars[1].aval.shape)] += 1
+        elif eqn.primitive.name in ("jit", "pjit"):
+            calls[eqn.params["name"]] += 1
+        names += eqn.primitive.name == "name"
+    return products, calls, names
+
+
+def _counted(fn):
+    registry = reglib.get_registry()
+    read = lambda: tuple(
+        registry.counter(name).value for name in (reglib.REMAT_PRODUCTS_KEPT, reglib.REMAT_BYTES_KEPT)
+    )
+    before = read()
+    fn()
+    return tuple(after - was for after, was in zip(read(), before))
+
+
+@kinds
+def test_a_recomputed_half_that_keeps_its_products_computes_what_it_computed(kind, monkeypatch):
+    loss, grads = _value_and_grad(kind, True)
+    plain_loss, plain_grads = _value_and_grad(kind, False)
+    monkeypatch.setattr(rematlib, "half", nn.remat)  # what ``Block`` wrapped its halves in before
+    bare_loss, bare_grads = _value_and_grad(kind, True)
+    assert loss == bare_loss
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(bare_grads)):
+        assert jnp.array_equal(got, want), jax.tree_util.keystr(path)
+    assert jnp.allclose(loss, plain_loss, rtol=1e-6)
+    # Against the tree's largest entry: a key bias's gradient is rounding alone.
+    room = 1e-5 * max(float(jnp.max(jnp.abs(want))) for want in jax.tree.leaves(plain_grads))
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(plain_grads)):
+        assert float(jnp.max(jnp.abs(got - want))) <= room, jax.tree_util.keystr(path)
+
+
+@kinds
+def test_the_differentiated_program_makes_a_kept_product_once_and_the_others_twice(kind, monkeypatch):
+    _, kept, twice, recomputed_calls = KINDS[kind]
+    once, plain_calls, plain_names = _made(kind, False)
+    assert plain_names == 0  # a model that recomputes nothing traces no name
+    assert {shape: once[shape] for shape in (*kept, *twice)} == {**kept, **twice}
+    products, calls, names = _made(kind, True)
+    assert names == sum(kept.values())
+    assert {shape: products[shape] for shape in kept} == kept
+    assert {shape: products[shape] for shape in twice} == {shape: 2 * n for shape, n in twice.items()}
+    for name, n in recomputed_calls.items():
+        assert calls[name] == plain_calls[name] + n, name
+    # The bare ``nn.remat`` made every one of them twice.
+    monkeypatch.setattr(rematlib, "half", nn.remat)
+    bare, _, _ = _made(kind, True)
+    assert {shape: bare[shape] for shape in kept} == {shape: 2 * n for shape, n in kept.items()}
+
+
+@kinds
+def test_the_counters_read_what_the_shapes_say(kind):
+    _, kept, _, _ = KINDS[kind]
+    trace = lambda remat: lambda: jax.eval_shape(jax.grad(_loss_of(kind, remat)[1]), _params(kind))
+    assert _counted(trace(False)) == (0, 0)
+    want = sum(kept.values()), sum(n * B * T * shape[1] * 4 for shape, n in kept.items())
+    assert _counted(trace(True)) == want
+    # ``model.init`` runs the halves under ``nn.remat`` too, as the routes count.
+    model = _loss_of(kind, True)[0]
+    assert _counted(lambda: jax.eval_shape(model.init, jax.random.key(0), _tokens())) == want
+
+
+def test_kept_is_the_identity_outside_a_recomputed_half():
+    x = jnp.ones((4, 8))
+    assert _counted(lambda: rematlib.kept(x)) == (0, 0) and rematlib.kept(x) is x
+    assert "name" not in str(jax.make_jaxpr(rematlib.kept)(x))
