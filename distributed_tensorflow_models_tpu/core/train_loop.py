@@ -198,6 +198,7 @@ def make_train_step(
     loss_fn: LossFn,
     rng_names: Sequence[str] = ("dropout",),
     donate: bool | None = None,
+    state_shardings: Optional[PyTree] = None,
 ) -> Callable[[TrainState, Batch, jax.Array], tuple[TrainState, dict]]:
     """Build the jitted ``(state, batch, rng) -> (state, metrics)`` step.
 
@@ -215,9 +216,10 @@ def make_train_step(
     a small host thread pool (one partition never reaches the rendezvous;
     the runtime aborts after 40 s).  CPU is only used for fake-mesh
     testing, where donation buys nothing anyway.
+
+    ``state_shardings`` is the layout of the state the program is built
+    for (:func:`_jit_state_program`).
     """
-    if donate is None:
-        donate = default_donate()
     step_fn = make_train_step_fn(loss_fn, rng_names)
 
     def one_step(state: TrainState, batch: Batch, rng: jax.Array):
@@ -238,7 +240,7 @@ def make_train_step(
         new_state, rows = jax.lax.scan(body, state, chunk)
         return new_state, jax.tree.map(lambda x: x[0], rows)
 
-    return jax.jit(one_step, donate_argnums=(0,) if donate else ())
+    return _jit_state_program(one_step, donate, state_shardings)
 
 
 def default_donate() -> bool:
@@ -253,6 +255,7 @@ def make_multi_step(
     unroll: int = 1,
     rng_names: Sequence[str] = ("dropout",),
     donate: bool | None = None,
+    state_shardings: Optional[PyTree] = None,
 ) -> Callable[[TrainState, Batch, jax.Array], tuple[TrainState, dict]]:
     """Fused K-step train program: one dispatch, one device→host metrics
     transfer per *chunk* of K steps instead of per step.
@@ -282,33 +285,43 @@ def make_multi_step(
     K plus the few shrunken boundary tails.  ``unroll`` is forwarded to
     ``lax.scan`` (bigger compiled program, more cross-step overlap for
     XLA to find; 1 — the default — compiles fastest).
+    ``state_shardings`` as in :func:`make_train_step`.
     """
-    if donate is None:
-        donate = default_donate()
-    return _jit_multi_step(
-        make_train_step_fn(loss_fn, rng_names), unroll=unroll, donate=donate
-    )
-
-
-def _jit_multi_step(
-    step_fn: Callable,
-    unroll: int = 1,
-    donate: bool | None = None,
-) -> Callable:
-    """Jit ``lax.scan`` of an already-built raw step (the
-    :func:`make_train_step_fn` contract) over stacked batches — the
-    entry point for callers that hold a step fn rather than a loss fn."""
-    if donate is None:
-        donate = default_donate()
+    step_fn = make_train_step_fn(loss_fn, rng_names)
 
     def multi_step_fn(state: TrainState, batches: Batch, rng: jax.Array):
         def body(s, batch):
-            s, metrics = step_fn(s, batch, rng)
-            return s, metrics
+            return step_fn(s, batch, rng)
 
         return jax.lax.scan(body, state, batches, unroll=unroll)
 
-    return jax.jit(multi_step_fn, donate_argnums=(0,) if donate else ())
+    return _jit_state_program(multi_step_fn, donate, state_shardings)
+
+
+def _jit_state_program(
+    fn: Callable, donate: bool | None, state_shardings: Optional[PyTree]
+) -> Callable:
+    """The one jit site of every program ``(state, batch, rng) -> (state,
+    metrics)``.
+
+    ``state_shardings`` is a ``TrainState``-shaped tree of shardings: the
+    ``.sharding`` of every leaf of the placed state the program is built
+    for (:func:`state_layout` decides them).  The program is compiled to
+    hand the state back under exactly those, so every later step's input
+    has the layout of the first: the compiler's propagation cannot move
+    a leaf nobody named (on a mesh with a second axis it did, and the
+    next call compiled again or, ahead of time, was refused), and
+    donation aliases every leaf.  The metrics' layout is the compiler's
+    choice, and with ``None`` (a caller with no placed state) the
+    state's is too.
+    """
+    if donate is None:
+        donate = default_donate()
+    return jax.jit(
+        fn,
+        donate_argnums=(0,) if donate else (),
+        out_shardings=(state_shardings, None),
+    )
 
 
 def _abstract(args: PyTree) -> PyTree:
@@ -358,10 +371,8 @@ class InstrumentedStep:
     invisible: a recompile storm (shape or sharding instability re-paying
     the compile cost every few steps) and compile time masquerading as
     slow steps.  This wrapper surfaces both without changing execution
-    semantics — every call still goes through the wrapped jit, keeping
-    its implicit-resharding tolerance (an AOT ``lower().compile()``
-    executable is stricter: it *rejects* inputs whose sharding drifted,
-    e.g. a checkpoint-restored TP state, where jit just recompiles).
+    semantics — every call still goes through the wrapped jit, or
+    through the AOT executable compiled from it.
 
     - **Compile events**: the jit's compilation-cache size is read before
       and after each call (~0.05 µs); a growth means that call compiled,
@@ -580,8 +591,13 @@ class InstrumentedMultiStep(InstrumentedStep):
         aot: Optional[object] = None,
     ):
         super().__init__(multi_fn, registry, aot=aot)
+        # Lowered for its cost, never run: nothing to donate or to pin.
         self._flops_fn = (
-            jax.jit(flops_step_fn) if flops_step_fn is not None else None
+            _jit_state_program(
+                flops_step_fn, donate=False, state_shardings=None
+            )
+            if flops_step_fn is not None
+            else None
         )
 
     def _lower_for_flops(self, state, batches, rng):
@@ -697,9 +713,12 @@ def state_is_finite(state: TrainState) -> bool:
     rollback target, or the retry replays the poison
     ``rollback_budget`` times; opt_state matters as much as params (an
     inf Adam second moment zeroes its update, leaving params finite
-    while the optimizer is already poisoned).  One reduction per leaf,
-    one scalar sync total — cheap enough for the (rare) rollback path,
-    never on the hot path."""
+    while the optimizer is already poisoned).  One program and one
+    scalar sync — cheap enough for the (rare) rollback path, never on
+    the hot path.  One program, not an eager reduction a leaf: over a
+    sharded state each of those is a collective of its own, and the CPU
+    backend's in-process rendezvous wedges (and aborts after 40 s) when
+    a few hundred are in flight on a busy host."""
     leaves = [
         leaf
         for tree in (
@@ -714,9 +733,12 @@ def state_is_finite(state: TrainState) -> bool:
     ]
     if not leaves:
         return True
-    return bool(
-        jnp.all(jnp.stack([jnp.all(jnp.isfinite(leaf)) for leaf in leaves]))
-    )
+    return bool(_all_finite(leaves))
+
+
+@jax.jit
+def _all_finite(leaves: list) -> jax.Array:
+    return jnp.all(jnp.stack([jnp.all(jnp.isfinite(x)) for x in leaves]))
 
 
 def make_eval_step(
@@ -781,12 +803,16 @@ def _collective_free_put(x, s):
     return jax.make_array_from_single_device_arrays(x.shape, s, arrs)
 
 
-def place_state(
+def state_layout(
     state: TrainState,
     mesh: Mesh,
     param_rules: Sequence[shardlib.ShardingRule] = (),
 ) -> TrainState:
-    """Lay the train state out on the mesh.
+    """The layout of a train state on the mesh: a ``TrainState``-shaped
+    tree with a ``NamedSharding`` for every array leaf.  THE one decision
+    — :func:`place_state` applies it and the step programs are compiled
+    to return it (:func:`_jit_state_program`).  ``state`` may be abstract
+    (``jax.eval_shape``): only shapes are read.
 
     With no rules everything is replicated — classic data parallelism, the
     reference's sync mode minus the parameter servers.  ``param_rules``
@@ -794,53 +820,51 @@ def place_state(
     parallelism); optimizer slots and EMA shadows follow their parameters'
     sharding automatically, the analogue of TF slot variables inheriting
     their primary's PS placement (TF optimizer.py:463,
-    device_setter.py:92-125).  Placement is collective-free: every
-    process holds the full initial state, so global arrays are assembled
-    from local shards (``_collective_free_put``) rather than broadcast.
+    device_setter.py:92-125).  ``step``, ``batch_stats`` and whatever of
+    the optimizer state parallels no parameter (counts) are replicated;
+    the recurrent carry is batch-major activation state and shards over
+    ``data``.
     """
+    rep = shardlib.replicated(mesh)
     param_sh = shardlib.tree_param_shardings(mesh, state.params, param_rules)
 
-    def follow(template_sh, tree):
-        """Shard `tree` leaves like the params leaf they parallel, replicating
-        anything that has no parameter analogue (counts, scalars)."""
-        flat_params = {
-            shardlib._path_str(p): s
-            for p, s in jax.tree_util.tree_leaves_with_path(template_sh)
-        }
-
-        def one(path, leaf):
-            name = shardlib._path_str(path)
-            for pname, s in flat_params.items():
-                if name.endswith(pname) and leaf.ndim == len(s.spec):
-                    return _collective_free_put(leaf, s)
-            return _collective_free_put(leaf, shardlib.replicated(mesh))
-
-        return jax.tree_util.tree_map_with_path(one, tree)
+    def like_param(slot, param, sh):
+        # A slot of another shape than its parameter (a factored moment)
+        # has no dimension the parameter's spec names.
+        return sh if slot.shape == param.shape else rep
 
     return state.replace(
-        step=_collective_free_put(state.step, shardlib.replicated(mesh)),
-        params=jax.tree.map(_collective_free_put, state.params, param_sh),
-        batch_stats=jax.tree.map(
-            lambda x: _collective_free_put(x, shardlib.replicated(mesh)),
-            state.batch_stats,
+        step=rep,
+        params=param_sh,
+        batch_stats=jax.tree.map(lambda _: rep, state.batch_stats),
+        # Every copy of the parameter tree inside the optimizer state,
+        # found by where ``tx.init`` puts its argument.
+        opt_state=optax.tree_utils.tree_map_params(
+            state.tx,
+            like_param,
+            state.opt_state,
+            state.params,
+            param_sh,
+            transform_non_params=lambda _: rep,
         ),
-        opt_state=follow(param_sh, state.opt_state),
-        ema_params=(
-            None
-            if state.ema_params is None
-            else jax.tree.map(
-                _collective_free_put, state.ema_params, param_sh
-            )
-        ),
-        # Recurrent carry is batch-major activation state: shard over data.
+        ema_params=None if state.ema_params is None else param_sh,
         carry=(
             None
             if state.carry is None
-            else jax.tree.map(
-                lambda x: _collective_free_put(
-                    x, shardlib.batch_sharding(mesh, x.ndim)
-                ),
-                state.carry,
-            )
+            else shardlib.tree_batch_shardings(mesh, state.carry)
         ),
+    )
+
+
+def place_state(
+    state: TrainState,
+    mesh: Mesh,
+    param_rules: Sequence[shardlib.ShardingRule] = (),
+) -> TrainState:
+    """Lay the train state out on the mesh as :func:`state_layout` says.
+    Placement is collective-free: every process holds the full initial
+    state, so global arrays are assembled from local shards
+    (``_collective_free_put``) rather than broadcast."""
+    return jax.tree.map(
+        _collective_free_put, state, state_layout(state, mesh, param_rules)
     )

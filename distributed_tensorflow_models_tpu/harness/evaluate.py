@@ -48,7 +48,7 @@ def evaluate_classification(
     template = trainlib.build_state(cfg, mesh)
     manager = ckptlib.CheckpointManager(workdir, keep=cfg.keep_checkpoints)
     state, _ = manager.restore(template)
-    state = train_loop.place_state(state, mesh)
+    state = trainlib.place(cfg, state, mesh)
     eval_step = train_loop.make_eval_step(
         state.apply_fn, use_ema=use_ema and state.ema_params is not None
     )
@@ -116,7 +116,7 @@ def evaluate_lm(
     template = trainlib.build_state(cfg, mesh)
     manager = ckptlib.CheckpointManager(workdir, keep=cfg.keep_checkpoints)
     state, _ = manager.restore(template)
-    state = train_loop.place_state(state, mesh)
+    state = trainlib.place(cfg, state, mesh)
 
     @jax.jit
     def lm_eval_step(state, carry, batch):

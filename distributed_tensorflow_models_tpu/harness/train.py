@@ -240,6 +240,15 @@ def build_state(cfg: ExperimentConfig, mesh) -> TrainState:
         state = TrainState.create(
             model, tx, jax.random.key(cfg.seed), sample, ema_decay=cfg.ema_decay
         )
+    return place(cfg, state, mesh)
+
+
+def place(cfg: ExperimentConfig, state: TrainState, mesh) -> TrainState:
+    """Lay ``state`` out on ``mesh`` as ``cfg`` says (its tensor-parallel
+    rule set).  The one call to ``place_state``: the fresh template, a
+    restored or rolled-back state and the evaluators' all go through
+    here, so none of them can come back replicated where the run
+    shards."""
     from distributed_tensorflow_models_tpu.parallel import tensor as tensorlib
 
     return train_loop.place_state(
@@ -278,8 +287,22 @@ def build_loss(cfg: ExperimentConfig, state: TrainState):
     )
 
 
+def _shardings(state: TrainState):
+    """The layout ``state`` was placed in, leaf by leaf: what its step
+    programs are compiled to hand back.  None on one device, where there
+    is no layout to drift and naming one makes the chip's compiler a
+    fifth slower (``gpt2m_train``'s step: 134 -> 161 s; PERF.md section
+    6, PR 43)."""
+    if state.step.sharding.num_devices == 1:
+        return None
+    return jax.tree.map(lambda x: x.sharding, state)
+
+
 def build_step(cfg: ExperimentConfig, state: TrainState):
-    return train_loop.make_train_step(build_loss(cfg, state))
+    """The step program for the placed ``state``."""
+    return train_loop.make_train_step(
+        build_loss(cfg, state), state_shardings=_shardings(state)
+    )
 
 
 def build_multi_step(cfg: ExperimentConfig, state: TrainState):
@@ -289,7 +312,9 @@ def build_multi_step(cfg: ExperimentConfig, state: TrainState):
     InstrumentedMultiStep's docstring)."""
     loss_fn = build_loss(cfg, state)
     return (
-        train_loop.make_multi_step(loss_fn),
+        train_loop.make_multi_step(
+            loss_fn, state_shardings=_shardings(state)
+        ),
         train_loop.make_train_step_fn(loss_fn),
     )
 
@@ -525,17 +550,6 @@ def fit(
     )
     steps_per_loop = max(1, int(cfg.steps_per_loop))
 
-    from distributed_tensorflow_models_tpu.parallel import tensor as tensorlib
-
-    def _place(s: TrainState) -> TrainState:
-        # Restored arrays arrive with default placement; re-lay them out on
-        # the mesh exactly as the fresh template was — including the
-        # tensor-parallel rules, or a resumed TP run would silently come
-        # back fully replicated.  (Also the rollback path's re-placement.)
-        return train_loop.place_state(
-            s, mesh, tensorlib.get_rules(cfg.param_rules)
-        )
-
     raw_step = None
     aot = None
     try:
@@ -562,7 +576,7 @@ def fit(
         startup.mark(telemetry.STARTUP_BUILD_STEP)
         state, data_state, restored = ckptlib.restore_or_init(manager, state)
         if restored:
-            state = _place(state)
+            state = place(cfg, state, mesh)
         if restored and manager.last_resize is not None:
             # Crossing a fleet resize is incident-grade: drop a flight
             # record on EVERY host so both sides of the crossing are
@@ -959,7 +973,7 @@ def fit(
         except FileNotFoundError as e:  # incl. NoValidCheckpointError
             log.error("rollback: no finite checkpoint to restore (%s)", e)
             return False
-        state = _place(restored_state)
+        state = place(cfg, restored_state, mesh)
         step = int(state.step)
         # Delete the abandoned timeline's checkpoints (anything newer
         # than the restore point): they hold post-divergence state that

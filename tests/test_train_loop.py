@@ -358,3 +358,122 @@ def test_bn_model_train_step(mesh8):
         np.asarray(stats_before), np.asarray(stats_after)
     )
     assert np.isfinite(float(metrics["loss"]))
+
+
+# --------------------------------------------------------------------------
+# The state's layout (``state_layout``) against the placement it replaced
+# --------------------------------------------------------------------------
+
+
+def _placement_by_suffix(state, mesh, rules):
+    """The placement ``place_state`` decided leaf by leaf until PR 43, kept
+    as the plain reference: an optimizer slot goes where the first
+    parameter goes whose path is the tail of the slot's and whose spec has
+    the slot's rank, else it is replicated."""
+    rep = shardlib.replicated(mesh)
+    param_sh = shardlib.tree_param_shardings(mesh, state.params, rules)
+    by_path = {
+        shardlib._path_str(p): s
+        for p, s in jax.tree_util.tree_leaves_with_path(param_sh)
+    }
+
+    def follow(path, leaf):
+        name = shardlib._path_str(path)
+        for pname, s in by_path.items():
+            if name.endswith(pname) and leaf.ndim == len(s.spec):
+                return s
+        return rep
+
+    return state.replace(
+        step=rep,
+        params=param_sh,
+        batch_stats=jax.tree.map(lambda _: rep, state.batch_stats),
+        opt_state=jax.tree_util.tree_map_with_path(follow, state.opt_state),
+        ema_params=None if state.ema_params is None else param_sh,
+        carry=(
+            None
+            if state.carry is None
+            else shardlib.tree_batch_shardings(mesh, state.carry)
+        ),
+    )
+
+
+def _tiny_program_config(config):
+    """One of the seven program configs the benchmark names, at the size
+    its own test runs it at, with the EMA shadows on; its own rule set
+    off, so that the state is built whatever the rules would divide."""
+    from test_lm_fit_smoke import FITS
+    from test_transformer import TINY
+
+    from distributed_tensorflow_models_tpu.harness.config import get_config
+
+    if config == "resnet50_synthetic":
+        return get_config(
+            config, image_size=32, global_batch_size=8, ema_decay=0.999,
+            model_kwargs={"num_classes": 16}, param_rules="",
+        )
+    model = (
+        TINY
+        if config == "transformer_lm"
+        else {**FITS[config]["model"], "vocab_size": 97}
+    )
+    return get_config(
+        config, model_kwargs={**get_config(config).model_kwargs, **model},
+        num_steps=32, global_batch_size=8, ema_decay=0.999, param_rules="",
+    )
+
+
+@pytest.fixture(scope="module")
+def abstract_states():
+    """``config -> (mesh, abstract state)``, each traced once for its two
+    cases; data 4 x model 2 over the fake devices."""
+    import functools
+
+    from distributed_tensorflow_models_tpu.core import mesh as meshlib
+    from distributed_tensorflow_models_tpu.harness import train as trainlib
+
+    mesh = meshlib.create_mesh(meshlib.MeshSpec(data=-1, model=2))
+
+    @functools.cache
+    def one(config):
+        cfg = _tiny_program_config(config)
+        return mesh, jax.eval_shape(lambda: trainlib.build_state(cfg, mesh))
+
+    return one
+
+
+@pytest.mark.parametrize("tensor_parallel", [False, True], ids=["dp", "tp"])
+@pytest.mark.parametrize(
+    "config",
+    ["resnet50_synthetic", "transformer_lm", "olmoe", "kimi_linear",
+     "olmo_hybrid", "granite_h_micro", "nemotron3_nano"],
+)
+def test_state_layout_is_the_placement_by_suffix(
+    abstract_states, config, tensor_parallel
+):
+    """``state_layout`` finds the optimizer's copies of the parameter tree
+    by structure; for every program config the benchmark names it decides
+    what the match of every slot's path against every parameter's did.
+    Shapes only: nothing is placed or compiled."""
+    from distributed_tensorflow_models_tpu.parallel import tensor as tensorlib
+
+    mesh, state = abstract_states(config)
+    family = "cnn_tp" if config == "resnet50_synthetic" else "transformer_tp"
+    rules = tensorlib.get_rules(family if tensor_parallel else "")
+    got = train_loop.state_layout(state, mesh, rules)
+    want = _placement_by_suffix(state, mesh, rules)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert jax.tree.structure(got) == jax.tree.structure(state)
+    differ = [
+        (jax.tree_util.keystr(path), g.spec, w.spec)
+        for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)
+        )
+        if g != w
+    ]
+    assert not differ, differ
+    sharded = [
+        s for s in jax.tree.leaves(got.opt_state) if s.spec != shardlib.P()
+    ]
+    assert bool(sharded) == tensor_parallel  # the rules reached the slots
+    assert jax.tree.leaves(got.ema_params) == jax.tree.leaves(got.params)

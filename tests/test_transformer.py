@@ -5,6 +5,8 @@ parallelism, tensor parallelism, and expert-parallel MoE must be reachable
 from harness configs, trained through ``fit`` — not library shelf-ware.
 """
 
+import json
+import os
 import re
 import tempfile
 
@@ -14,6 +16,7 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_models_tpu.core import mesh as meshlib
+from distributed_tensorflow_models_tpu.core import train_loop
 from distributed_tensorflow_models_tpu.harness import cli
 from distributed_tensorflow_models_tpu.harness import train as trainlib
 from distributed_tensorflow_models_tpu.harness.config import get_config
@@ -87,19 +90,23 @@ def test_tp_rules_cover_params():
         assert any(re.search(pattern, p) for p in paths), pattern
 
 
-def test_fit_data_parallel():
-    res = trainlib.fit(tiny_cfg(), tempfile.mkdtemp())
-    assert res.steps_run == 3
-    assert np.isfinite(res.final_metrics["loss"])
+@pytest.fixture(scope="module")
+def dp_fit():
+    """``fit(tiny_cfg())`` on the data mesh, once for its three readers."""
+    return trainlib.fit(tiny_cfg(), tempfile.mkdtemp())
+
+
+def test_fit_data_parallel(dp_fit):
+    assert dp_fit.steps_run == 3
+    assert np.isfinite(dp_fit.final_metrics["loss"])
 
 
 class TestParallelismEquivalence:
     """All parallel layouts must reproduce the pure-DP trajectory."""
 
     @pytest.fixture(scope="class")
-    def dp_loss(self):
-        res = trainlib.fit(tiny_cfg(), tempfile.mkdtemp())
-        return res.final_metrics["loss"]
+    def dp_loss(self, dp_fit):
+        return dp_fit.final_metrics["loss"]
 
     def test_ring_sequence_parallel(self, dp_loss):
         res = trainlib.fit(
@@ -202,6 +209,48 @@ def test_fit_moe_expert_parallel():
     assert res.steps_run == 3
     assert res.final_metrics["aux_loss"] > 0
     assert np.isfinite(res.final_metrics["loss"])
+
+
+# A second mesh axis each, three steps; ``seq2_ring`` on the lazy jit path
+# (its sibling above compiles ahead of time), ``model2_k4`` the fused
+# K-step program, two chunks of four.
+LAYOUT_RUNS = {
+    "model2": dict(mesh_model=2),
+    "seq2_ring": dict(mesh_seq=2, seq_impl="ring", aot_compile=False),
+    "pipe2": dict(global_batch_size=16, mesh_pipe=2),
+    "expert2": dict(model_kwargs={**TINY, "num_experts": 4}, mesh_expert=2),
+    "model2_k4": dict(
+        mesh_model=2, steps_per_loop=4, train_steps=8, log_every_steps=4
+    ),
+}
+
+
+@pytest.mark.parametrize("run", sorted(LAYOUT_RUNS))
+def test_fit_hands_the_state_back_in_its_layout(tmp_path, run):
+    """Every step program returns the state under the shardings
+    ``state_layout`` names, so the second call finds the layout the first
+    was compiled for: one compile event for the run's one batch
+    signature, ahead of time or lazily."""
+    cfg = tiny_cfg(**LAYOUT_RUNS[run])
+    res = trainlib.fit(cfg, str(tmp_path))
+    assert res.steps_run == cfg.train_steps
+    want = train_loop.state_layout(
+        res.state,
+        trainlib.mesh_from_config(cfg),
+        tensorlib.get_rules(cfg.param_rules),
+    )
+    moved = [
+        (jax.tree_util.keystr(path), leaf.sharding.spec, sharding.spec)
+        for (path, leaf), sharding in zip(
+            jax.tree_util.tree_leaves_with_path(res.state),
+            jax.tree.leaves(want),
+            strict=True,
+        )
+        if not leaf.sharding.is_equivalent_to(sharding, leaf.ndim)
+    ]
+    assert not moved, moved
+    with open(os.path.join(tmp_path, "telemetry.json")) as f:
+        assert json.load(f)["metrics"]["train/compile/count"] == 1
 
 
 def test_moe_matches_reference_oracle_at_init():
@@ -309,23 +358,35 @@ def test_pipe_rejects_tp_combo():
         trainlib.fit(cfg, tempfile.mkdtemp())
 
 
-def test_eval_lm_on_seq_mesh(tmp_path):
+def test_eval_lm_on_seq_mesh(tmp_path, monkeypatch):
     """Eval must build the same 5-axis mesh as training (mesh_from_config)
-    — a transformer trained with ring SP evaluates on the seq mesh."""
+    — a transformer trained with ring SP evaluates on the seq mesh — and
+    lay the state out as training did (``trainlib.place``)."""
     from distributed_tensorflow_models_tpu.harness import evaluate as evallib
 
-    cfg = tiny_cfg(mesh_seq=2, seq_impl="ring", train_steps=2)
+    cfg = tiny_cfg(mesh_model=2, mesh_seq=2, seq_impl="ring", train_steps=2)
     trainlib.fit(cfg, str(tmp_path))
+    placed = []
+    place = trainlib.place
+
+    def recording_place(*args):
+        placed.append(place(*args))
+        return placed[-1]
+
+    monkeypatch.setattr(trainlib, "place", recording_place)
     res = evallib.evaluate_lm(cfg, str(tmp_path), max_batches=2)
     assert res.step == 2
     assert np.isfinite(res.metrics["perplexity"])
+    # The tensor-parallel checkpoint is evaluated sharded by the rules.
+    kernel = placed[-1].params["blocks_0"]["attn"]["query"]["kernel"]
+    assert meshlib.AxisNames.MODEL in kernel.sharding.spec, kernel.sharding
 
 
-def test_remat_matches_non_remat():
+def test_remat_matches_non_remat(dp_fit):
     """remat changes memory scheduling, not math: same trajectory up to
     bf16 recompute rounding (backward re-runs the forward in bf16, which
     reassociates roundings — observed delta ~2e-4 after 3 steps)."""
-    r1 = trainlib.fit(tiny_cfg(), tempfile.mkdtemp())
+    r1 = dp_fit
     r2 = trainlib.fit(
         tiny_cfg(model_kwargs={**TINY, "remat": True}), tempfile.mkdtemp()
     )
