@@ -737,6 +737,72 @@ _add(
 )
 
 
+# Phi-4-mini-flash-reasoning (config.json of
+# microsoft/Phi-4-mini-flash-reasoning, 2025-07, model_type phi4flash; the
+# design is SambaY: Ren et al. 2025, arXiv:2507.06607, a Samba
+# self-decoder, arXiv:2406.07522, under a cross-decoder): 32 pre-norm
+# LayerNorm (1e-5) layers of width 2560 without any position encoding,
+# each a mixer and a gated SiLU feed-forward of 10240 without bias;
+# mb_per_layer 2 puts a Mamba-1 mixer (Gu and Dao 2023, arXiv:2312.00752:
+# d_inner 5120, state 16, convolution 4, dt_rank 160, a decay for every
+# channel and state) in the even layers up to 16 and differential
+# attention (Ye et al. 2024, arXiv:2410.05258: 40 query and 20 key/value
+# heads of 64 in pairs, biases on the projections) in the odd ones, under
+# a window of 512 up to layer 15 and full in layer 17; from layer 18 on
+# the even layers are gated memory units over layer 16's scan output and
+# the odd ones cross-attentions (a query and an output projection) to
+# layer 17's keys and values; vocabulary 200064, the head tied to the
+# embedding.  What config.json does not say is listed under ``assumed`` in
+# benchmark/configs/phi4_mini_flash.json.  Adam 3e-4 with clip 1.0 behind
+# the 2,000-step warm-up of the other large language models, per-half
+# recomputation, sequences of 8,192.  Every size is the published one; no
+# single chip holds the 3.85 B parameters in training
+# (benchmark/configs/phi4_mini_flash.json runs published layers 0, 1, 16,
+# 17, 18, 19, every kind of layer once, and an eighth of the vocabulary).
+PHI4_FLASH_LAYERS = tuple(
+    ("gmu" if i % 2 == 0 else "cross") if i > 17
+    else "mamba1" if i % 2 == 0
+    else "attention" if i < 17 else "attention_full"
+    for i in range(32)
+)
+_add(
+    _CONFIGS["transformer_lm"].replace(
+        name="phi4_mini_flash",
+        model_kwargs={
+            "vocab_size": 200064,
+            "num_layers": 32,
+            "num_heads": 40,
+            "num_kv_heads": 20,
+            "d_model": 2560,
+            "d_ff": 10240,
+            "max_len": 262144,
+            "dropout_rate": 0.0,
+            "pos_encoding": "none",
+            "norm": "layernorm",
+            "norm_eps": 1e-5,
+            "use_bias": False,
+            "attn_bias": True,
+            "mlp": "gated_silu",
+            "layer_mixers": PHI4_FLASH_LAYERS,
+            "attn_window": 512,
+            "attn_differential": True,
+            "mamba1_inner": 5120,
+            "mamba1_state_dim": 16,
+            "mamba1_conv_size": 4,
+            "mamba1_dt_rank": 160,
+            "tie_embeddings": True,
+            "remat": True,
+        },
+        global_batch_size=1,
+        num_steps=8192,
+        vocab_size=200064,
+        optimizer=dataclasses.replace(
+            _CONFIGS["transformer_lm"].optimizer, warmup_steps=2000
+        ),
+    )
+)
+
+
 def get_config(name: str, **overrides) -> ExperimentConfig:
     if name not in _CONFIGS:
         raise KeyError(f"unknown config {name!r}; have {sorted(_CONFIGS)}")

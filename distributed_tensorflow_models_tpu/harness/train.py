@@ -1354,9 +1354,9 @@ def _export_trace(
 def _trace_counts() -> dict[str, float]:
     """What the ops count at trace time (``attention(impl="auto")``'s
     routes, ``chunked_kda``'s, ``KDAMixer``'s two placements,
-    ``chunked_gdn``'s and ``chunked_ssd``'s traced calls, the fused head's
-    gradient-in-forward calls, what the recomputed halves keep), as the
-    process-global registry holds it now."""
+    ``chunked_gdn``'s, ``chunked_ssd``'s and ``selective_scan``'s traced
+    calls, the fused head's gradient-in-forward calls, what the recomputed
+    halves keep), as the process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
@@ -1369,6 +1369,7 @@ def _trace_counts() -> dict[str, float]:
             telemetry.KDA_MIXER_PLAIN,
             telemetry.GDN_ROUTE_PLAIN,
             telemetry.SSD_ROUTE_KERNEL, telemetry.SSD_ROUTE_PLAIN,
+            telemetry.SSCAN_ROUTE_KERNEL, telemetry.SSCAN_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
             telemetry.REMAT_PRODUCTS_KEPT, telemetry.REMAT_BYTES_KEPT,
         )

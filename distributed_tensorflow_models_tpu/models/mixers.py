@@ -1,5 +1,6 @@
 """Token mixers beside full attention, for stacks whose layers differ:
-four mixers: two delta rules, a latent attention, a state-space layer.
+two delta rules, a latent attention, two state-space layers and a unit
+that reads an earlier layer's scan.
 
 Kimi Linear (Kimi Linear technical report, Moonshot AI 2025,
 arXiv:2510.26692; ``config.json`` and the published modelling code of
@@ -77,6 +78,24 @@ Nemotron 3 Nano (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
 ``n_groups``) runs the same mixer with eight groups, in layers that are
 this mixer alone (``transformer_lm``'s ``layer_mixers``).
 
+Phi-4-mini-flash-reasoning (microsoft/Phi-4-mini-flash-reasoning
+``config.json``, ``model_type`` ``phi4flash``; SambaY: Ren et al. 2025,
+arXiv:2507.06607) is a self-decoder of Mamba-1 and attention layers
+(Samba, arXiv:2406.07522) under a cross-decoder whose layers compute no
+state of their own:
+
+- :class:`Mamba1Mixer`: the Mamba-1 layer (Gu and Dao 2023,
+  arXiv:2312.00752).  ``[x, z] = W_in u`` (``d_inner`` each); ``x =
+  silu(conv(x) + b_conv)``; ``[r, B, C] = W_x x`` (``dt_rank``, ``N``,
+  ``N``); ``dt = softplus(W_dt r + b_dt)`` a channel; ``A = -exp(A_log)``
+  ``[d_inner, N]``, **a decay for every channel and state**; the scan of
+  :func:`...ops.selective_scan.selective_scan` with its ``D`` skip; ``out
+  = W_out (y * silu(z))``, no norm.  With ``hand_on`` it also returns
+  ``y`` before the gate: the **memory** later layers read.
+- :class:`GatedMemoryUnit`: ``out = W_out (m * silu(W_in u))`` with ``m``
+  an earlier Mamba-1 layer's memory: a mixer without a scan, a
+  convolution or a state, two projections around an element-wise gate.
+
 All compute in ``dtype`` over float32 parameters; the norms, the decay,
 ``b_t``, ``dt``, the l2 norms and the output gate's norm are float32 (the
 fused passes of :class:`KDAMixer` hold float32 from the projections'
@@ -99,6 +118,7 @@ import jax.numpy as jnp
 from distributed_tensorflow_models_tpu.models import remat as rematlib
 from distributed_tensorflow_models_tpu.ops import attention as attnlib
 from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
+from distributed_tensorflow_models_tpu.ops import selective_scan as sscanlib
 from distributed_tensorflow_models_tpu.ops import ssm as ssmlib
 from distributed_tensorflow_models_tpu.telemetry.registry import (
     KDA_MIXER_FUSED,
@@ -110,9 +130,14 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (
 # either kind; the chunk-wise core inside it is
 # ``ops/linear_attention.py::KDA_CORE_SCOPE`` or ``GDN_CORE_SCOPE``.
 LINEAR_ATTN_SCOPE = "linear_attn"
-# The same of a whole state-space mixer (:class:`Mamba2Mixer`); the scan
-# inside it is ``ops/ssm.py::SSD_CORE_SCOPE``.
+# The same of a whole state-space mixer (:class:`Mamba2Mixer`,
+# :class:`Mamba1Mixer`) and of the unit that reads one's memory
+# (:class:`GatedMemoryUnit`); the scan inside is
+# ``ops/ssm.py::SSD_CORE_SCOPE`` or
+# ``ops/selective_scan.py::SSCAN_CORE_SCOPE``, the unit's own work
+# :data:`GMU_SCOPE`.
 SSM_SCOPE = "ssm"
+GMU_SCOPE = "gmu"
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -314,13 +339,8 @@ class Mamba2Mixer(nn.Module):
         inner, mixed = H * P, H * P + 2 * groups * N
         zxbcdt = rematlib.kept(_dense(inner + mixed + H, self.dtype, "in_proj")(x))
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + mixed], axis=-1)
-        # torch's Conv1d default, weight and bias: uniform(+-1/sqrt(fan_in)),
-        # fan_in K for a depth-wise convolution.
-        uniform = lambda shape: lambda rng: jax.random.uniform(
-            rng, shape, jnp.float32, -(K**-0.5), K**-0.5
-        )
-        taps = self.param("conv", uniform((K, mixed)))
-        bias = self.param("conv_bias", uniform((mixed,)))
+        taps = self.param("conv", _conv_uniform(K, (K, mixed)))
+        bias = self.param("conv_bias", _conv_uniform(K, (mixed,)))
         xbc = jax.nn.silu(causal_depthwise_conv(xbc, taps) + bias.astype(self.dtype))
         xs, b, c = jnp.split(xbc, [inner, inner + groups * N], axis=-1)
         if groups > 1:
@@ -353,6 +373,77 @@ class Mamba2Mixer(nn.Module):
         else:
             y = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32, name="norm")(y)
         return _dense(self.d_model, self.dtype, "out_proj")(y.astype(self.dtype))
+
+
+def _conv_uniform(taps: int, shape):
+    """torch's Conv1d default, weight and bias: uniform(+-1/sqrt(fan_in)),
+    fan_in ``taps`` for a depth-wise convolution."""
+    return lambda rng: jax.random.uniform(
+        rng, shape, jnp.float32, -(taps**-0.5), taps**-0.5
+    )
+
+
+class Mamba1Mixer(nn.Module):
+    """The Mamba-1 layer (module docstring): ``d_inner`` channels, each
+    over a state of ``state_dim``; ``dt`` through ``dt_rank`` channels."""
+
+    d_inner: int
+    state_dim: int
+    dt_rank: int
+    d_model: int
+    conv_size: int = 4
+    chunk: int = 256  # the plain scan's, not the model's (ops/selective_scan.py)
+    hand_on: bool = False
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        inner, N, R, K = self.d_inner, self.state_dim, self.dt_rank, self.conv_size
+        xz = rematlib.kept(_dense(2 * inner, self.dtype, "in_proj")(u))
+        x, z = jnp.split(xz, 2, axis=-1)
+        taps = self.param("conv", _conv_uniform(K, (K, inner)))
+        bias = self.param("conv_bias", _conv_uniform(K, (inner,)))
+        x = jax.nn.silu(causal_depthwise_conv(x, taps) + bias.astype(self.dtype))
+        r, b, c = jnp.split(_dense(R + 2 * N, self.dtype, "x_proj")(x), [R, R + N], axis=-1)
+        # mamba_ssm's defaults: W_dt uniform(+-dt_rank^-0.5), the bias the
+        # inverse softplus of a step drawn log-uniformly from [1e-3, 1e-1],
+        # A_log = log(1..N) in every channel, D ones.
+        dt = nn.Dense(
+            inner, dtype=self.dtype, use_bias=False, name="dt_proj",
+            kernel_init=lambda rng, shape, dtype=jnp.float32: jax.random.uniform(
+                rng, shape, dtype, -(R**-0.5), R**-0.5
+            ),
+        )(r)
+        dt_bias = self.param("dt_bias", _dt_bias_init(inner))
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        a_log = self.param(
+            "A_log",
+            lambda rng: jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (inner, N)
+            ),
+        )
+        d_skip = self.param("D", nn.initializers.ones, (inner,), jnp.float32)
+
+        y = sscanlib.selective_scan(x, dt, a_log, b, c, d_skip, chunk=self.chunk)
+
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        out = _dense(self.d_model, self.dtype, "out_proj")(gated.astype(self.dtype))
+        return (out, rematlib.kept(y)) if self.hand_on else out
+
+
+class GatedMemoryUnit(nn.Module):
+    """``W_out (m * silu(W_in u))``, ``m`` ``[B, T, width]`` the memory an
+    earlier :class:`Mamba1Mixer` handed on (module docstring)."""
+
+    d_model: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u, memory):
+        with jax.named_scope(GMU_SCOPE):
+            gate = rematlib.kept(_dense(memory.shape[-1], self.dtype, "in_proj")(u))
+            gated = memory.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+            return _dense(self.d_model, self.dtype, "out_proj")(gated.astype(self.dtype))
 
 
 class LatentAttention(nn.Module):

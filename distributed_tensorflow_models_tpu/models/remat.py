@@ -15,7 +15,11 @@ byte, the same for every model (ISSUE 42; PERF.md section 6):
    residual nothing needs it and nothing is named);
 2. the feed-forwards' wide products, dense and shared alike: ``gate`` and
    ``up`` of ``GatedMLP``, ``up`` of ``MLP``;
-3. ``Mamba2Mixer``'s ``in_proj``.
+3. ``Mamba2Mixer``'s, ``Mamba1Mixer``'s and ``GatedMemoryUnit``'s
+   ``in_proj``;
+4. what a source layer hands on to the layers that read it (the scan
+   output a gated memory unit reads, the keys and values a
+   cross-attention reads): later halves hold them as inputs anyway.
 
 The modules name those outputs with :func:`kept`; :func:`half` is the
 ``nn.remat`` whose policy saves that name and nothing else.  Outside a
@@ -53,13 +57,16 @@ _tracing = _Tracing()
 
 
 def half(fn):
-    """``fn(module, y)`` recomputed in the backward pass but for what
-    :func:`kept` names inside it."""
+    """``fn(module, y, *read)`` recomputed in the backward pass but for
+    what :func:`kept` names inside it.  ``read`` is what a half reads of
+    an earlier layer beside the residual stream (a memory, keys and
+    values): an input like ``y``, so kept and not recomputed; what a half
+    hands on to later layers it names with :func:`kept`."""
 
-    def traced(mdl, y):
+    def traced(mdl, *args):
         _tracing.halves += 1
         try:
-            return fn(mdl, y)
+            return fn(mdl, *args)
         finally:
             _tracing.halves -= 1
 

@@ -45,6 +45,19 @@ each), and its feed-forwards, dense, shared and routed, may be **two
 matrices around a squared ReLU without a gate** (``mlp="relu2"``,
 ``moe_expert="relu2"``; the experts of the other models are three with a
 SiLU gate).
+What a layer makes may also be read by later layers
+(Phi-4-mini-flash-reasoning, ``harness/config.py::phi4_mini_flash``: a
+self-decoder of Mamba-1 and differential-attention layers, a window in
+all but its last, under a cross-decoder): a ``"gmu"`` layer reads the scan
+output (the **memory**) of the nearest earlier ``"mamba1"`` layer, a
+``"cross"`` layer the keys and values of the nearest earlier
+``"attention_full"`` layer and projects a query alone.  What those source
+layers hand on travels down the stack beside the residual stream, is an
+input of a recomputed half (kept, not recomputed) and takes its gradient
+as the sum over its readers.  ``attn_window`` is the one span: an
+``"attention"`` layer runs under it, an ``"attention_full"`` layer never.
+``attn_differential`` makes every attention of the stack, cross-attention
+included, the differential one (:class:`SelfAttention`).
 Granite's four scalars (``embedding_multiplier`` on the embedding,
 ``residual_multiplier`` on each sub-layer's output before it joins the
 residual, ``attention_multiplier`` for the scores' scale,
@@ -59,6 +72,7 @@ materialized [T, T] mask when the blockwise/fused paths run).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -122,9 +136,68 @@ class SelfAttention(nn.Module):
     head_dim: int = 0
     # The scores' scale where it is not ``head size ** -0.5`` (None).
     scale: Optional[float] = None
+    # Differential attention (Ye et al. 2024, arXiv:2410.05258) where set,
+    # to the layer's ``lambda_init``: heads pair up (even and odd query
+    # heads ``q1``, ``q2``; likewise ``k1``, ``k2``; a pair's values its two
+    # heads' side by side), ``a = softmax(q1 k1^T) v - lambda softmax(q2
+    # k2^T) v`` with ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    # lambda_init`` from four learned vectors, and ``(1 - lambda_init)
+    # rmsnorm(a)`` (a weight, per pair) goes to ``out``.  None: softmax
+    # attention, and the traced program of before.
+    diff_lambda_init: Optional[float] = None
+    # ``query_only``: no key and value projections, the call's ``kv`` (an
+    # earlier layer's) instead; ``hand_on_kv``: the call also returns its
+    # keys and values, for such layers.
+    query_only: bool = False
+    hand_on_kv: bool = False
+
+    def _differential(self, q, k, v):
+        """``[B, T, H * Dh]`` of the differential attention of ``q`` ``[B,
+        T, H, Dh]`` over ``k``, ``v`` ``[B, T, Hkv, Dh]``.  One call of the
+        core: query head ``2 j + s`` reads key head ``2 m + s`` and the
+        value pair ``m`` (``2 Dh`` wide), ``m = j // (H / Hkv)`` the pair's
+        group; under 128 value channels the fused kernels pad the 64
+        query/key channels to a lane block (``ops/attention.py``), which
+        costs 256 multiply-adds a score where two calls on the values'
+        halves would cost 512 and take every exponential twice."""
+        B, T, H, Dh = q.shape
+        pairs, kv_pairs = H // 2, k.shape[2] // 2
+        group = pairs // kv_pairs
+        over_group = lambda y, *per_head: jnp.broadcast_to(
+            y.reshape(B, T, kv_pairs, 1, *per_head),
+            (B, T, kv_pairs, group, 2, per_head[-1]),
+        ).reshape(B, T, H, per_head[-1])
+        a = attnlib.attention(
+            q, over_group(k, 2, Dh), over_group(v, 1, 2 * Dh), causal=True,
+            impl=self.attn_impl, window=self.attn_window,
+            scale=self.scale if self.scale is not None else Dh**-0.5,
+        ).astype(jnp.float32).reshape(B, T, pairs, 2, 2 * Dh)
+        vector = lambda name: self.param(
+            name, nn.initializers.normal(0.1), (Dh,), jnp.float32
+        )
+        lam = (
+            jnp.exp(jnp.sum(vector("lambda_q1") * vector("lambda_k1")))
+            - jnp.exp(jnp.sum(vector("lambda_q2") * vector("lambda_k2")))
+            + self.diff_lambda_init
+        )
+        a = a[:, :, :, 0] - lam * a[:, :, :, 1]
+        a = nn.RMSNorm(
+            epsilon=self.norm_eps or 1e-5, dtype=jnp.float32, name="subln"
+        )(a) * (1.0 - self.diff_lambda_init)
+        return a.astype(self.dtype).reshape(B, T, H * Dh)
+
+    def _core(self, q, k, v):
+        """``[B, T, H * Dh]`` of a differential layer, or of one that hands
+        on or reads keys and values: no positions, no cache."""
+        if self.diff_lambda_init is not None:
+            return self._differential(q, k, v)
+        return attnlib.attention(
+            q, k, v, causal=True, impl=self.attn_impl, window=self.attn_window,
+            scale=self.scale,
+        ).reshape(*q.shape[:2], -1)
 
     @nn.compact
-    def __call__(self, x, train: bool = False):
+    def __call__(self, x, train: bool = False, kv=None):
         from distributed_tensorflow_models_tpu.ops import rotary
 
         B, T, _ = x.shape
@@ -135,15 +208,23 @@ class SelfAttention(nn.Module):
             feats, dtype=self.dtype, use_bias=self.use_bias, name=name
         )
         q = dense("query", H * Dh)(x)
-        k = dense("key", Hkv * Dh)(x)
-        if self.qk_norm:
-            norm = lambda name, y: make_norm("rmsnorm", self.norm_eps, name)(
-                y
-            ).astype(self.dtype)
-            q, k = norm("q_norm", q), norm("k_norm", k)
-        q = q.reshape(B, T, H, Dh)
-        k = k.reshape(B, T, Hkv, Dh)
-        v = dense("value", Hkv * Dh)(x).reshape(B, T, Hkv, Dh)
+        if self.query_only:
+            q, (k, v) = q.reshape(B, T, H, Dh), kv
+        else:
+            k = dense("key", Hkv * Dh)(x)
+            if self.qk_norm:
+                norm = lambda name, y: make_norm("rmsnorm", self.norm_eps, name)(
+                    y
+                ).astype(self.dtype)
+                q, k = norm("q_norm", q), norm("k_norm", k)
+            q = q.reshape(B, T, H, Dh)
+            k = k.reshape(B, T, Hkv, Dh)
+            v = dense("value", Hkv * Dh)(x).reshape(B, T, Hkv, Dh)
+        if self.query_only or self.hand_on_kv or self.diff_lambda_init is not None:
+            out = dense("out", self.d_model)(self._core(q, k, v))
+            if self.dropout_rate:
+                out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
+            return (out, (rematlib.kept(k), rematlib.kept(v))) if self.hand_on_kv else out
         if self.use_rope and not self.decode:
             pos = jnp.arange(T)
             q = rotary.apply_rope(q, pos, self.rope_theta)
@@ -240,7 +321,14 @@ class GatedMLP(nn.Module):
         return dense("down", self.d_model)(nn.silu(wide("gate")) * wide("up"))
 
 
-_MIXERS = ("attention", "kda", "gdn", "mla", "ssm")
+# "attention" runs under ``attn_window`` where the stack states one,
+# "attention_full" never; "cross" projects a query alone and reads the
+# nearest earlier "attention_full" layer's keys and values; "gmu" reads the
+# nearest earlier "mamba1" layer's scan output.
+_ATTENTIONS = ("attention", "attention_full", "cross")
+_MIXERS = _ATTENTIONS + ("kda", "gdn", "mla", "ssm", "mamba1", "gmu")
+# What a reader reads, by its kind: the source layer's kind.
+_READS = {"gmu": "mamba1", "cross": "attention_full"}
 # What an entry of ``TransformerLM.layer_mixers`` may say.
 _LAYER_KINDS = _MIXERS + tuple(f"{m}_only" for m in _MIXERS) + ("ffn_only",)
 
@@ -248,6 +336,11 @@ _LAYER_KINDS = _MIXERS + tuple(f"{m}_only" for m in _MIXERS) + ("ffn_only",)
 # the layer's ``moe``): what every token goes through beside its routed
 # experts.
 MOE_SHARED_SCOPE = "moe_shared"
+
+
+def _first(fn, res):
+    """``fn`` on a half's output, beside what the half hands on."""
+    return (fn(res[0]), *res[1:]) if isinstance(res, tuple) else fn(res)
 
 
 class MoEFFN(nn.Module):
@@ -462,12 +555,30 @@ class Block(nn.Module):
     # its input and its wide input products (models/remat.py), no more.
     # A layer of one sub-layer has one half.
     remat: bool = False
+    # A source layer ("mamba1", "attention_full") that later layers read:
+    # the call returns ``(x, handed)``.  A reader ("gmu", "cross") takes
+    # what its source handed on as the call's ``read``.
+    hands_on: bool = False
+    # Differential attention's ``lambda_init`` of this layer (None: softmax
+    # attention); biases on the attention projections where that is not
+    # ``use_bias`` (None).
+    diff_lambda_init: Optional[float] = None
+    attn_bias: Optional[bool] = None
 
-    def _mix(self, h, train):
-        if self.mixer == "attention":
-            return self._attention(h, train)
+    def _mix(self, h, train, *read):
+        if self.mixer in _ATTENTIONS:
+            return self._attention(h, train, *read)
         sizes = dict(self.mixer_kwargs or ())
-        if self.mixer in ("kda", "gdn"):
+        if self.mixer == "mamba1":
+            out = mixers.Mamba1Mixer(
+                d_model=self.d_model, dtype=self.dtype, hand_on=self.hands_on,
+                name=mixers.SSM_SCOPE, **sizes,
+            )(h)
+        elif self.mixer == "gmu":
+            out = mixers.GatedMemoryUnit(
+                self.d_model, self.dtype, name=mixers.SSM_SCOPE
+            )(h, *read)
+        elif self.mixer in ("kda", "gdn"):
             kind = mixers.KDAMixer if self.mixer == "kda" else mixers.GatedDeltaNetMixer
             out = kind(
                 d_model=self.d_model, norm_eps=self.norm_eps or 1e-6,
@@ -485,32 +596,38 @@ class Block(nn.Module):
                 attn_impl=self.attn_impl, name="attn", **sizes,
             )(h)
         if self.dropout_rate:
-            out = nn.Dropout(self.dropout_rate, deterministic=not train)(out)
+            out = _first(nn.Dropout(self.dropout_rate, deterministic=not train), out)
         return out
 
     @nn.compact
-    def __call__(self, x, train: bool = False):
+    def __call__(self, x, train: bool = False, read=None):
         norm = lambda name, y: make_norm(self.norm, self.norm_eps, name)(
             y
         ).astype(self.dtype)
         if self.norm_placement == "post":
-            mix = lambda mdl, y: norm("ln1", mdl._mix(y, train))
+            mix = lambda mdl, y, *r: _first(
+                lambda out: norm("ln1", out), mdl._mix(y, train, *r)
+            )
             feed = lambda mdl, y: norm("ln2", rematlib.kept(mdl._ffn()(y, train=train)))
         else:
-            mix = lambda mdl, y: mdl._mix(norm("ln1", y), train)
+            mix = lambda mdl, y, *r: mdl._mix(norm("ln1", y), train, *r)
             feed = lambda mdl, y: mdl._ffn()(norm("ln2", y), train=train)
         if self.residual_multiplier != 1.0:
-            branch = lambda half: lambda mdl, y: (
-                self.residual_multiplier * half(mdl, y)
+            branch = lambda half: lambda mdl, *ys: _first(
+                lambda out: self.residual_multiplier * out, half(mdl, *ys)
             )
             mix, feed = branch(mix), branch(feed)
         if self.remat:
             mix, feed = rematlib.half(mix), rematlib.half(feed)
+        handed = None
         if self.mixer != "none":
-            x = x + mix(self, x)
+            out = mix(self, x, *(() if read is None else (read,)))
+            if self.hands_on:
+                out, handed = out
+            x = x + out
         if self.feed:
             x = x + feed(self, x)
-        return x
+        return (x, handed) if self.hands_on else x
 
     def _ffn(self) -> nn.Module:
         if self.use_moe and self.moe_router == "topk":
@@ -552,7 +669,7 @@ class Block(nn.Module):
             name="mlp",
         )
 
-    def _attention(self, h, train):
+    def _attention(self, h, train, *read):
         return SelfAttention(
             self.num_heads,
             self.d_model,
@@ -563,16 +680,19 @@ class Block(nn.Module):
             decode=self.decode,
             max_len=self.max_len,
             num_kv_heads=self.num_kv_heads,
-            attn_window=self.attn_window,
+            attn_window=self.attn_window if self.mixer == "attention" else None,
             use_rope=self.use_rope,
             rope_theta=self.rope_theta,
-            use_bias=self.use_bias,
+            use_bias=self.use_bias if self.attn_bias is None else self.attn_bias,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
             head_dim=self.head_dim,
             scale=self.attn_scale,
+            diff_lambda_init=self.diff_lambda_init,
+            query_only=self.mixer == "cross",
+            hand_on_kv=self.hands_on,
             name="attn",
-        )(h, train=train)
+        )(h, train, *read)
 
 
 class PipelinedBlocks(nn.Module):
@@ -788,7 +908,8 @@ class TransformerLM(nn.Module):
     # decode cache by num_heads/num_kv_heads.
     num_kv_heads: int = 0
     # Sliding-window (local) attention span; None = full causal.  Applies
-    # to the dense non-pipelined stack (and decode).
+    # to the dense non-pipelined stack (and decode): to every "attention"
+    # layer of ``layer_mixers``, to no "attention_full" or "cross" layer.
     attn_window: Any = None
     # Position encoding: "learned" absolute table (the default), "rope"
     # rotary relative positions applied inside full attention (the other
@@ -841,9 +962,12 @@ class TransformerLM(nn.Module):
     # an expert's ``d_ff`` (0: the same).
     mlp: str = "gelu"
     dense_d_ff: int = 0
-    # Each layer's token mixer, "attention" | "kda" | "gdn" | "mla" | "ssm"
-    # (None: full attention everywhere), and the sizes models/mixers.py
-    # takes.  A layer named so is the mixer and then the feed-forward;
+    # Each layer's token mixer, "attention" | "attention_full" | "cross" |
+    # "kda" | "gdn" | "mla" | "ssm" | "mamba1" | "gmu" (None: "attention"
+    # everywhere), and the sizes models/mixers.py takes.  A "gmu" layer
+    # reads the nearest earlier "mamba1" layer's scan output, a "cross"
+    # layer the nearest earlier "attention_full" layer's keys and values.
+    # A layer named so is the mixer and then the feed-forward;
     # "<mixer>_only" is a layer that is the mixer alone behind its one
     # norm, "ffn_only" one that is the feed-forward alone (dense or
     # experts, by ``moe_layers`` as everywhere): the Nemotron-H family's
@@ -866,6 +990,20 @@ class TransformerLM(nn.Module):
     ssm_num_groups: int = 1  # of heads that share a B and a C
     ssm_conv_size: int = 4
     ssm_chunk: int = 256  # the scan's chunk: the program's, not the model's
+    mamba1_inner: int = 0  # 0: 2 x d_model
+    mamba1_state_dim: int = 16
+    mamba1_conv_size: int = 4
+    mamba1_dt_rank: int = 0  # 0: ceil(d_model / 16)
+    mamba1_chunk: int = 256  # the plain scan's chunk: the program's
+    # Differential attention in every attention layer, cross-attention
+    # included (``SelfAttention.diff_lambda_init``); ``lambda_init`` is 0.8
+    # - 0.6 exp(-0.3 l) at the layer's published index l: ``layer_ids``
+    # where this stack is a cut of a deeper one (None: 0, 1, 2, ...).
+    attn_differential: bool = False
+    layer_ids: Any = None
+    # Biases on the attention projections where that is not ``use_bias``
+    # (None): a tied head has none, the attention of the model may.
+    attn_bias: Optional[bool] = None
     # Granite's four scalars: the embedding times ``embedding_multiplier``,
     # each sub-layer's output times ``residual_multiplier`` before it
     # joins the residual, attention scores times ``attention_multiplier``
@@ -890,6 +1028,23 @@ class TransformerLM(nn.Module):
             return entry[: -len("_only")], False
         return entry, True
 
+    def _sources(self) -> dict:
+        """``{reader layer: source layer}``: for each layer that reads
+        what an earlier one made, the nearest earlier layer of the kind it
+        reads (``_READS``); a reader without one raises."""
+        kinds = [self._halves(entry)[0] for entry in self._mixers()]
+        sources = {}
+        for i, kind in enumerate(kinds):
+            if kind in _READS:
+                earlier = [j for j in range(i) if kinds[j] == _READS[kind]]
+                if not earlier:
+                    raise ValueError(
+                        f"layer_mixers[{i}] {kind!r} reads an earlier "
+                        f"{_READS[kind]!r} layer and there is none"
+                    )
+                sources[i] = earlier[-1]
+        return sources
+
     def _check_settings(self):
         """Refusals that depend on no input: raised when the model is
         first called, before anything is traced."""
@@ -912,6 +1067,40 @@ class TransformerLM(nn.Module):
         if len(self._mixers()) != self.num_layers:
             raise ValueError(
                 f"layer_mixers names {len(self._mixers())} layers, "
+                f"num_layers is {self.num_layers}"
+            )
+        kinds = {self._halves(entry)[0] for entry in self._mixers()}
+        handing = kinds & {"attention_full", "cross", "mamba1", "gmu"}
+        if (handing or self.attn_differential) and (
+            self.decode or self.attention_fn is not None
+            or self.pipelined or self.pipe_mesh is not None
+        ):
+            raise ValueError(
+                f"{sorted(handing) or 'differential attention'} neither "
+                "decodes nor runs in the pipelined stack or under a "
+                "sequence-parallel attention_fn: serving/kv_slots.py gives "
+                "every layer keys and values of its own (here one layer's "
+                "are read by several, and a query-only layer has none), has "
+                "no per-channel recurrent state (d_inner x state with the "
+                "convolution's tail) nor the scan output a gated memory "
+                "unit reads, and the cache holds no pair of softmaxes; "
+                "PipelinedBlocks ships the residual stream alone between "
+                "stages, not what a source layer hands on (ROADMAP Queue 2, "
+                "M11)"
+            )
+        self._sources()
+        if ("cross" in kinds or self.attn_differential) and (
+            self.pos_encoding == "rope" or self.qk_norm
+        ):
+            raise ValueError(
+                "differential attention and the layers that hand on or read "
+                "keys and values rotate and norm no query or key "
+                "(SelfAttention._core): pos_encoding='rope' and qk_norm "
+                "would be dropped in silence"
+            )
+        if self.layer_ids is not None and len(self.layer_ids) != self.num_layers:
+            raise ValueError(
+                f"layer_ids names {len(self.layer_ids)} layers, "
                 f"num_layers is {self.num_layers}"
             )
         plain = set(self._mixers()) == {"attention"}
@@ -1105,9 +1294,24 @@ class TransformerLM(nn.Module):
                     ("conv_size", self.ssm_conv_size),
                     ("chunk", self.ssm_chunk),
                 ),
+                "mamba1": (
+                    ("d_inner", self.mamba1_inner or 2 * self.d_model),
+                    ("state_dim", self.mamba1_state_dim),
+                    ("dt_rank", self.mamba1_dt_rank or -(-self.d_model // 16)),
+                    ("conv_size", self.mamba1_conv_size),
+                    ("chunk", self.mamba1_chunk),
+                ),
             }
+            # What the source layers hand on, beside ``x`` down the stack:
+            # ``handed[j]`` is layer j's memory, or its keys and values.
+            sources, handed = self._sources(), {}
+            hands_on = set(sources.values())
+            layer_ids = self.layer_ids or range(self.num_layers)
             for i, entry in enumerate(self._mixers()):
                 mixer, feed = self._halves(entry)
+                lambda_init = None
+                if self.attn_differential and mixer in _ATTENTIONS:
+                    lambda_init = 0.8 - 0.6 * math.exp(-0.3 * layer_ids[i])
                 use_moe = self.num_experts > 0 and (
                     i >= self.moe_first_dense
                     if self.moe_layers == "all"
@@ -1153,8 +1357,13 @@ class TransformerLM(nn.Module):
                     norm_placement=self.norm_placement,
                     mlp=self.mlp,
                     remat=self.remat,
+                    hands_on=i in hands_on,
+                    diff_lambda_init=lambda_init,
+                    attn_bias=self.attn_bias,
                     name=f"blocks_{i}",
-                )(x, train)
+                )(x, train, *((handed[sources[i]],) if i in sources else ()))
+                if i in hands_on:
+                    x, handed[i] = x
         x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         if self.logits_scaling != 1.0:
             # Folded into the hidden states, so that the fused head sees

@@ -36,6 +36,7 @@ Layout convention everywhere: ``[batch, seq, heads, head_dim]`` (BTHD).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -55,6 +56,8 @@ NEG_INF = -1e30  # finite "-inf": keeps exp(s - m) well-defined in masked rows
 # element of every instruction's ``op_name`` in the compiled step, which
 # ``step_scopes_p<i>.json`` carries to the device trace (PERF.md section 3).
 ATTENTION_CORE_SCOPE = "attention_core"
+# Inside it, the core of a call with a sliding window, whatever the route.
+SWA_CORE_SCOPE = "swa_core"
 
 
 def _scale(q, scale: Optional[float]) -> float:
@@ -955,21 +958,30 @@ _FUSED_VMEM_BYTES = 64 * 1024 * 1024  # of v5e's 128 MiB; tiles need ~10 MiB
 
 
 def _fused_tile(T: int) -> Optional[int]:
+    """The largest tile the length divides, under a window too: at 8,192
+    positions and 40 heads a window of 512 took 7.37 ms forward and
+    backward at tiles of 512 (2 tiles a query row, half of them masked),
+    9.49 at 256 (3 a row) and 18.93 at 128 (5 a row), the full causal
+    layer 20.83 (my chip run, PR 44): the steps cost more than the masked
+    halves."""
     return next((t for t in _FUSED_TILES if T % t == 0), None)
 
 
 def fused_admissible(
     q, k, v, *, window: Optional[int] = None,
     q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
+    causal: bool = True,
 ) -> bool:
     """Whether the fused route takes this call: self-attention shapes
     (grouped KV heads too: ``attention`` repeats them over their groups
     before the kernels, which want equal head counts), head size 64 (an
     even number of heads) or 128, or 128 value channels under another
-    query/key width (latent attention; ``attention`` pads those), a length
-    some tile divides, no sliding window, static zero offsets.  All of it
-    is visible at trace time; the backend is the caller's question."""
-    if window is not None:
+    query/key width (latent and differential attention; ``attention`` pads
+    those), a length some tile divides, static zero offsets; a sliding
+    window only with the causal mask (the caller's: the kernels drop the
+    block pairs outside it).  All of it is visible at trace time; the
+    backend is the caller's question."""
+    if window is not None and not causal:
         return False
     if not (isinstance(q_offset, int) and isinstance(kv_offset, int)):
         return False
@@ -989,13 +1001,18 @@ def fused_admissible(
     return _fused_tile(T) is not None
 
 
-def _fused_pairs(n_q, n_kv, block_q, block_kv, causal, kv_major):
+def _fused_pairs(n_q, n_kv, block_q, block_kv, causal, kv_major, window=None):
     """The (q block, kv block) pairs in which some query sees some key,
     as two int32 vectors: q-major for the forward (a q block's kv blocks
-    are consecutive, ascending), kv-major for the backward."""
+    are consecutive, ascending), kv-major for the backward.  Under a
+    window the pairs wholly older than it are left out as the pairs above
+    the diagonal are: :func:`_block_should_run`'s test, on numbers."""
     pairs = [
         (i, j) for i in range(n_q) for j in range(n_kv)
-        if not causal or (i + 1) * block_q - 1 >= j * block_kv
+        if _block_should_run(
+            i, j, 0, 0, causal=causal, block_q=block_q, block_kv=block_kv,
+            window=window,
+        )
     ]
     if kv_major:
         pairs.sort(key=lambda ij: (ij[1], ij[0]))
@@ -1022,18 +1039,23 @@ def _join_heads(parts, D):
 
 def _fused_fwd_kernel(
     i_ref, j_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale, causal, block_q, block_kv, head_dim, n_kv,
+    *, scale, causal, block_q, block_kv, head_dim, n_kv, window=None,
 ):
     """Grid (B, H*D/128, pairs).  ``m_scr``/``l_scr`` hold one
     lane-replicated ``[block_q, 128]`` row statistic per head of the
-    block; LSE leaves as lane-dense rows ``[heads, block_q]``."""
+    block; LSE leaves as lane-dense rows ``[heads, block_q]``.  Under a
+    ``window`` a q block's first kv block is the oldest that some query
+    of it still sees (:func:`_fused_pairs` lists no older one)."""
     import jax.experimental.pallas as pl
 
     hp = _LANES // head_dim
     p_idx = pl.program_id(2)
     i, j = i_ref[p_idx], j_ref[p_idx]
+    j_first = 0
+    if window is not None:
+        j_first = jnp.maximum(i * block_q - window + 1, 0) // block_kv
 
-    @pl.when(j == 0)
+    @pl.when(j == j_first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -1049,6 +1071,7 @@ def _fused_fwd_kernel(
             s = _masked_scores(
                 qa, k, i, j, 0, 0, scale=scale, causal=causal,
                 block_q=block_q, block_kv=block_kv, apply_mask=apply_mask,
+                window=window,
             )  # [bq, bkv] f32
             m_prev, l_prev = m_scr[a], l_scr[a]  # [bq, 128], replicated
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -1068,7 +1091,7 @@ def _fused_fwd_kernel(
 
     _dispatch_masked(
         pl, _step, True, i, j, 0, 0,
-        causal=causal, block_q=block_q, block_kv=block_kv,
+        causal=causal, block_q=block_q, block_kv=block_kv, window=window,
     )
     j_last = n_kv - 1
     if causal:
@@ -1088,20 +1111,24 @@ def _fused_fwd_kernel(
 def _fused_bwd_kernel(
     i_ref, j_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-    *, scale, causal, block_q, block_kv, head_dim, n_q, n_pairs,
+    *, scale, causal, block_q, block_kv, head_dim, n_q, n_pairs, window=None,
 ):
     """Grid (B, H*D/128, pairs), kv-major.  Transposed orientation:
       S^T = K Q^T * scale,  P^T = exp(S^T - LSE),  dP^T = V dO^T,
       dS^T = P^T o (dP^T - delta),
       dV_j += P^T dO,  dK_j += dS^T Q,  dQ_i += dS K   (scale at the end).
     dK/dV accumulate over a kv block's q sweep; dQ over the whole pair
-    list, in ``dq_scr`` ``[T, 128]``."""
+    list, in ``dq_scr`` ``[T, 128]``.  Under a ``window`` a kv block's
+    sweep ends at the last q block that still sees it."""
     import jax.experimental.pallas as pl
 
     hp = _LANES // head_dim
     p_idx = pl.program_id(2)
     i, j = i_ref[p_idx], j_ref[p_idx]
     i_first = (j * block_kv) // block_q if causal else 0
+    i_last = n_q - 1
+    if window is not None:
+        i_last = jnp.minimum(i_last, (window + (j + 1) * block_kv - 2) // block_q)
 
     @pl.when(p_idx == 0)
     def _init_dq():
@@ -1132,7 +1159,10 @@ def _fused_bwd_kernel(
                 qi = i * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, st.shape, 1
                 )
-                st = jnp.where(qi >= kj, st, NEG_INF)
+                valid = qi >= kj
+                if window is not None:
+                    valid = valid & (qi - kj < window)
+                st = jnp.where(valid, st, NEG_INF)
             pt = jnp.exp(st - lse[a:a + 1, :])
             dpt = jax.lax.dot_general(
                 va, do, (((1,), (1,)), ((), ())),
@@ -1158,10 +1188,10 @@ def _fused_bwd_kernel(
 
     _dispatch_masked(
         pl, _step, True, i, j, 0, 0,
-        causal=causal, block_q=block_q, block_kv=block_kv,
+        causal=causal, block_q=block_q, block_kv=block_kv, window=window,
     )
 
-    @pl.when(i == n_q - 1)
+    @pl.when(i == i_last)
     def _finish_dkv():
         dk_ref[0] = (scale * dk_scr[...]).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -1227,7 +1257,9 @@ def _fused_specs(pl, block_q, block_kv, hp, ql):
     )
 
 
-def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
+def _fused_forward(
+    q, k, v, *, causal, scale, block_q, block_kv, interpret, window=None
+):
     """Returns ``(out [B,T,H,D], lse [B, H*D/128, 128/D, T] f32)``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1236,7 +1268,7 @@ def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
         q, v, block_q, block_kv
     )
     n_q, n_kv = T // block_q, T // block_kv
-    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, False)
+    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, False, window)
     flat = lambda x: x.reshape(B, T, -1)
     qspec, kspec, vspec, ospec, rowspec = _fused_specs(
         pl, block_q, block_kv, hp, ql
@@ -1246,6 +1278,7 @@ def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
         functools.partial(
             _fused_fwd_kernel, scale=_scale(q, scale), causal=causal,
             block_q=block_q, block_kv=block_kv, head_dim=D, n_kv=n_kv,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1272,7 +1305,8 @@ def _fused_forward(q, k, v, *, causal, scale, block_q, block_kv, interpret):
 
 
 def _fused_backward(
-    q, k, v, out, lse, g, *, causal, scale, block_q, block_kv, interpret
+    q, k, v, out, lse, g, *, causal, scale, block_q, block_kv, interpret,
+    window=None,
 ):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1281,7 +1315,7 @@ def _fused_backward(
         q, v, block_q, block_kv
     )
     n_q, n_kv = T // block_q, T // block_kv
-    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, True)
+    i_idx, j_idx = _fused_pairs(n_q, n_kv, block_q, block_kv, causal, True, window)
     flat = lambda x: x.reshape(B, T, -1)
     # delta_i = rowsum(dO o O), as lane-dense rows beside the LSE's.
     delta = jnp.sum(
@@ -1298,7 +1332,7 @@ def _fused_backward(
         functools.partial(
             _fused_bwd_kernel, scale=_scale(q, scale), causal=causal,
             block_q=block_q, block_kv=block_kv, head_dim=D, n_q=n_q,
-            n_pairs=len(i_idx),
+            n_pairs=len(i_idx), window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -1345,6 +1379,7 @@ def mosaic_can_lower() -> bool:
 def auto_route(
     q, k, v, *, window: Optional[int] = None,
     q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
+    causal: bool = True,
 ) -> str:
     """What ``attention(impl="auto")`` runs for this call: ``"fused"`` on
     a TPU for the calls the fused kernels admit, where a Mosaic kernel
@@ -1352,7 +1387,8 @@ def auto_route(
     if (
         jax.default_backend() == "tpu"
         and fused_admissible(
-            q, k, v, window=window, q_offset=q_offset, kv_offset=kv_offset
+            q, k, v, window=window, q_offset=q_offset, kv_offset=kv_offset,
+            causal=causal,
         )
         and mosaic_can_lower()
     ):
@@ -1360,7 +1396,7 @@ def auto_route(
     return "blockwise"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def fused_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1370,31 +1406,35 @@ def fused_attention(
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """The fused self-attention kernels (see the section comment), BTHD
     in and out; what ``attention(impl="auto")`` runs on a TPU for the
     calls :func:`fused_admissible` admits.  ``None`` tiles resolve to the
-    largest of 512/256/128 the length divides; ``interpret=True`` runs
-    the same kernels on the CPU for tests."""
+    largest of 512/256/128 the length divides;
+    ``window`` (with ``causal``): a query sees the last ``window``
+    positions, itself among them, and the block pairs wholly outside that
+    are never run; ``interpret=True`` runs the same kernels on the CPU for
+    tests."""
     return _fused_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_kv=block_kv, interpret=interpret,
+        block_kv=block_kv, interpret=interpret, window=window,
     )[0]
 
 
-def _fused_fwd(q, k, v, causal, scale, block_q, block_kv, interpret):
+def _fused_fwd(q, k, v, causal, scale, block_q, block_kv, interpret, window):
     out, lse = _fused_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_kv=block_kv, interpret=interpret,
+        block_kv=block_kv, interpret=interpret, window=window,
     )
     return out, (q, k, v, out, lse)
 
 
-def _fused_bwd(causal, scale, block_q, block_kv, interpret, res, g):
+def _fused_bwd(causal, scale, block_q, block_kv, interpret, window, res, g):
     q, k, v, out, lse = res
     return _fused_backward(
         q, k, v, out, lse, g, causal=causal, scale=scale, block_q=block_q,
-        block_kv=block_kv, interpret=interpret,
+        block_kv=block_kv, interpret=interpret, window=window,
     )
 
 
@@ -1419,17 +1459,24 @@ def attention(
 
     ``auto`` chooses from what the call can observe, at trace time: on a
     TPU, a call that :func:`fused_admissible` admits (self-attention
-    shapes, head size 64 or 128, a length 128 divides, no window) runs
-    the fused kernels (:func:`fused_attention`; measured on the chip,
-    PERF.md PR 26), grouped key/value heads repeated over their groups
-    first; every other call (the CPU, odd lengths, a sliding window, and
-    a ``jit`` over several devices outside ``shard_map``, where a Mosaic
-    kernel cannot be partitioned) runs :func:`blockwise_attention`.  The
-    choice is counted once per traced
+    shapes, head size 64 or 128, a length 128 divides, a sliding window
+    under the causal mask alone) runs the fused kernels
+    (:func:`fused_attention`; measured on the chip, PERF.md PR 26),
+    grouped key/value heads repeated over their groups first; every other
+    call (the CPU, odd lengths, and a ``jit`` over several devices outside
+    ``shard_map``, where a Mosaic kernel cannot be partitioned) runs
+    :func:`blockwise_attention`.  The choice is counted once per traced
     call (``attention/route_fused`` / ``attention/route_blockwise``).  A
-    named ``impl`` means what it says."""
+    named ``impl`` means what it says.  A call with a ``window`` runs
+    under ``swa_core`` inside the core's scope, whatever the route."""
+    windowed = contextlib.nullcontext() if window is None else jax.named_scope(SWA_CORE_SCOPE)
+    with windowed:
+        return _attention(q, k, v, causal, scale, impl, window)
+
+
+def _attention(q, k, v, causal, scale, impl, window):
     if impl == "auto":
-        impl = auto_route(q, k, v, window=window)
+        impl = auto_route(q, k, v, window=window, causal=causal)
         get_registry().counter(
             ATTN_ROUTE_FUSED if impl == "fused" else ATTN_ROUTE_BLOCKWISE
         ).inc()
@@ -1440,7 +1487,7 @@ def attention(
                 scale = _scale(q, scale)
                 widen = ((0, 0),) * 3 + ((0, -q.shape[-1] % _LANES),)
                 q, k = jnp.pad(q, widen), jnp.pad(k, widen)
-            return fused_attention(q, k, v, causal, scale)
+            return fused_attention(q, k, v, causal, scale, window=window)
     if impl == "reference":
         return reference_attention(
             q, k, v, causal=causal, scale=scale, window=window

@@ -79,6 +79,8 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     SKIPPED_BATCHES,
     SSD_ROUTE_KERNEL,
     SSD_ROUTE_PLAIN,
+    SSCAN_ROUTE_KERNEL,
+    SSCAN_ROUTE_PLAIN,
     STARTUP_AOT_COMPILE,
     STARTUP_AOT_JOIN,
     STARTUP_AOT_LOWER,

@@ -100,6 +100,11 @@ GDN_ROUTE_PLAIN = "gdn/route_plain"  # counter
 # TPU for whole tiles, the plain ``jax.numpy`` form everywhere else.
 SSD_ROUTE_KERNEL = "ssd/route_kernel"  # counter
 SSD_ROUTE_PLAIN = "ssd/route_plain"  # counter
+# The same of ``ops/selective_scan.py::selective_scan`` (Mamba-1's scan, a
+# decay for every channel and state): the Pallas kernels on a TPU for
+# channels in whole blocks of 1,024, the plain form everywhere else.
+SSCAN_ROUTE_KERNEL = "sscan/route_kernel"  # counter
+SSCAN_ROUTE_PLAIN = "sscan/route_plain"  # counter
 # What the recomputed halves of a stack's blocks keep beside their inputs
 # (``models/remat.py``: the wide input products a half names), counted at
 # trace time like the routes: one increment per kept product per traced

@@ -801,7 +801,7 @@ def _route_counts():
         # ... and what the kernels do not take.
         ("tpu", 1, (2, 200, 4, 64), 4, None, False, "blockwise"),  # 128 does not divide
         ("tpu", 1, (2, 256, 4, 64), 4, None, True, "blockwise"),  # traced offsets
-        ("tpu", 1, (2, 256, 4, 64), 4, 64, False, "blockwise"),  # sliding window
+        ("tpu", 1, (2, 256, 4, 64), 4, 64, False, "fused"),  # sliding window (since PR 44)
         # Grouped KV heads: repeated over their groups into the kernels (PR 38).
         ("tpu", 1, (2, 256, 4, 64), 2, None, False, "fused"),
         ("tpu", 1, (2, 256, 3, 64), 3, None, False, "blockwise"),  # half a lane block

@@ -697,3 +697,92 @@ def test_kimi_linear_step_compiles_for_v5e_under_its_memory(one_chip):
         "linear_attn", "kda_core", "kda_pass", "attention_core", "moe", "moe_dispatch", "moe_experts", "moe_shared",
         "unembed_loss", "optimizer",
     ))
+
+
+# ``phi4_flash_train``'s call of Mamba-1's selective scan: one sequence of
+# 8,192 tokens, 5,120 channels over a state of 16, a decay for every
+# channel and state.
+_SSCAN_SHAPES = [(1, 8192, 5120), (1, 8192, 5120), (5120, 16), (1, 8192, 16), (1, 8192, 16), (5120,)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32_highest"])
+def test_selective_scan_compiles_for_v5e(one_chip, dtype):
+    """``selective_scan`` forward and backward at the cell's shape, as the
+    cell runs it (bf16) and as the comparison with the reference runs the
+    float32 program: both take the Pallas kernels, one forward and one
+    backward Mosaic body under the ``sscan_core`` scope, no ``while`` left
+    of the plain route's scan over the chunks, and nothing the size of a
+    state per token (``8192 x 5120 x 16`` float32: 2.68 GB) in the
+    program: the states at the chunks' starts (21 MB) and the operands'
+    float32 tiles."""
+    from distributed_tensorflow_models_tpu.ops import selective_scan as sscanlib
+
+    wide = (0, 3, 4)  # x, B, C in the model's dtype; dt, A_log, D in float32
+    args = [
+        jax.ShapeDtypeStruct(s, dtype if i in wide else jnp.float32, sharding=one_chip)
+        for i, s in enumerate(_SSCAN_SHAPES)
+    ]
+    assert sscanlib.selective_scan_route(args[0], args[2]) == "kernel"
+
+    def fwd_bwd(*x):
+        loss = lambda *x: jnp.sum(sscanlib.selective_scan(*x).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5))(*x)
+
+    with _as_the_comparison_runs(dtype):
+        compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = _mosaic_kernels(text)
+    assert len(kernels) == 2 and "ssd_core" not in text
+    assert all(re.search(r"[/(]sscan_core[/)]", line) for line in kernels)
+    assert sum("transpose(" in line for line in kernels) == 1
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(r"f32\[(\d+,)*8192,(5120,16|16,5120|16,8,640)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0 * 2**30
+
+
+def test_window_differential_attention_compiles_for_v5e(one_chip):
+    """The window layer's core as ``SelfAttention._differential`` calls it
+    (40 query heads of 64 over 128 value channels, 8,192 positions, a
+    window of 512) takes the fused route: the forward and the one backward
+    kernel under ``swa_core`` inside ``attention_core``, and no ``while``
+    left of the blockwise scan."""
+    q, v = ((1, 8192, 40, d) for d in (64, 128))
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in (q, q, v)]
+    assert attnlib.auto_route(*args, window=512) == "fused"
+
+    def fwd_bwd(*x):
+        loss = lambda *x: jnp.sum(attnlib.attention(*x, causal=True, window=512).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(*x)
+
+    text = jax.jit(fwd_bwd).lower(*args).compile().as_text()
+    _two_fused_attention_kernels(text)
+    assert all(re.search(r"[/(]swa_core[/)]", line) for line in _mosaic_kernels(text))
+
+
+@pytest.mark.slow
+def test_phi4_flash_step_compiles_for_v5e_under_its_memory(one_chip):
+    """The whole ``phi4_flash_train`` step (published layers 0, 1, 16, 17,
+    18, 19 at the published widths, an eighth of the vocabulary, Adam with
+    the clip, the fused head from the tied embedding, each half recomputed
+    but for its wide input products and what the two source layers hand
+    on, one sequence of 8,192) for one described v5e: it fits the chip's
+    15.75 GiB with room (12.37 GiB: 7.79 of state, 4.46 of temporaries;
+    PERF.md, PR 44), the compiler rematerializes nothing of its own, both
+    Mamba-1 layers take the scan's kernels and all three attention layers
+    the fused kernels (``model.init`` and the step), no buffer holds a
+    state per token, and the scopes of every piece are on the step."""
+    from distributed_tensorflow_models_tpu.telemetry import registry as reglib
+
+    counters = [reglib.get_registry().counter(name) for name in (reglib.SSCAN_ROUTE_KERNEL, reglib.ATTN_ROUTE_FUSED)]
+    before = [c.value for c in counters]
+    compiled, held, state, _ = _cell_step_compiled(one_chip, "phi4_flash_train")
+    assert [c.value - was for c, was in zip(counters, before)] == [4, 6]
+    assert sum(x.size for x in jax.tree.leaves(state.params)) == 697_094_272
+    assert 11.5 < held < 13.5, held
+    text = compiled.as_text()
+    # Two scans and three attention cores, each forward, recomputed and backward.
+    assert text.count("tpu_custom_call") == 15
+    assert not re.search(r"f32\[(\d+,)*8192,(5120,16|16,5120|16,8,640)\]", text)
+    _scopes_are_on(text, "phi4_flash_train", (
+        "ssm", "sscan_core", "gmu", "attention_core", "swa_core", "unembed_loss", "optimizer",
+    ))

@@ -394,7 +394,8 @@ def test_grouped_heads_take_the_fused_route_and_calls_without_groups_are_routed_
     # What the fused route still does not take, groups or not.
     assert not attnlib.fused_admissible(spec(4), spec(3), spec(3))  # no whole groups
     assert not attnlib.fused_admissible(spec(4), spec(2), spec(4))  # keys and values disagree
-    assert not attnlib.fused_admissible(spec(4), spec(2), spec(2), window=64)
+    assert attnlib.fused_admissible(spec(4), spec(2), spec(2), window=64)  # since PR 44, under the causal mask
+    assert not attnlib.fused_admissible(spec(4), spec(2), spec(2), window=64, causal=False)
     assert not attnlib.fused_admissible(spec(3), spec(1), spec(1))  # half a lane block of queries
     assert not attnlib.fused_admissible(spec(4, t=200), spec(2, t=200), spec(2, t=200))
     # Without groups: as before.
@@ -405,7 +406,7 @@ def test_grouped_heads_take_the_fused_route_and_calls_without_groups_are_routed_
     # over their groups), and a call without groups repeats nothing.
     seen = []
 
-    def fake(q, k, v, causal, scale):
+    def fake(q, k, v, causal, scale, window=None):
         seen.append((q.shape, k.shape, v.shape, scale))
         return q
 

@@ -601,7 +601,9 @@ def test_which_shapes_the_fused_kernels_admit(shape_qk, shape_v, want):
     q = jax.ShapeDtypeStruct(shape_qk, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape_v, jnp.bfloat16)
     assert attnlib.fused_admissible(q, q, v) is want
-    assert attnlib.fused_admissible(q, q, v, window=128) is False
+    # A window is admitted with the causal mask (PR 44), never without it.
+    assert attnlib.fused_admissible(q, q, v, window=128) is want
+    assert attnlib.fused_admissible(q, q, v, window=128, causal=False) is False
 
 
 def test_fused_attention_itself_refuses_channels_that_are_not_whole_lane_blocks():

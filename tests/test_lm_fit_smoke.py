@@ -154,6 +154,20 @@ FITS = {
                 "unembed_loss", "optimizer"),
         absent=("gdn_core", "linear_attn"),
     ),
+    # Every kind of layer of the decoder-hybrid-decoder: a Mamba-1 layer, window and full
+    # differential attention, a memory unit and a cross-attention over what two of them hand on.
+    "phi4_mini_flash": dict(
+        model={**_NARROW, "num_heads": 8, "num_kv_heads": 4, "num_layers": 6, "d_ff": 96, "attn_window": 16,
+               "layer_mixers": ("mamba1", "attention", "mamba1", "attention_full", "gmu", "cross"),
+               "layer_ids": (0, 1, 16, 17, 18, 19), "mamba1_inner": 128, "mamba1_state_dim": 4,
+               "mamba1_dt_rank": 4, "mamba1_chunk": 16},
+        fit={**_FUSED_HEAD, "optimizer": _QUICK_WARMUP}, falls=True, recipe=True, head=False,
+        telemetry={"sscan/route_plain": 4, "sscan/route_kernel": 0, "attention/route_blockwise": 6,
+                   "ssd/route_plain": 0, "unembed/grad_in_forward": 1},
+        traced={reglib.SSCAN_ROUTE_PLAIN: 4},
+        scopes=("ssm", "sscan_core", "gmu", "attention_core", "swa_core", "unembed_loss", "optimizer"),
+        absent=("ssd_core", "linear_attn"),
+    ),
 }
 
 
