@@ -327,8 +327,10 @@ class TensorBoardHook(Hook):
 # metrics (core/train_loop.py::lm_loss_fn), always the three together.
 MOE_KEYS = ("moe_load_max_over_mean", "moe_aux_loss", "moe_z_loss")
 # Beside them where the expert layers hold a range of the router's
-# experts only: the share of the step's assignments that fell on it.
-MOE_HELD_KEY = "moe_held_share"
+# experts only, the two together: the share of the step's assignments
+# that fell on it, and the slabs of rows a layer worked through them in
+# (1: an ordinary step; ``parallel/moe.py::_held_experts``).
+MOE_HELD_KEYS = ("moe_held_share", "moe_held_slabs")
 
 
 class TelemetryHook(Hook):
@@ -371,8 +373,9 @@ class TelemetryHook(Hook):
       previous firing, replacing the firing step's own value.  The
       steps' device scalars are kept and fetched at the cadence, where
       the loss row is fetched anyway: no further device sync.
-      ``moe_held_share`` joins them, the same way, where the step
-      reports it (expert layers that hold a share of the experts).
+      ``moe_held_share`` and ``moe_held_slabs`` join them, the same way,
+      where the step reports them (expert layers that hold a share of
+      the experts).
 
     Multi-host: steps/sec and stall fraction are allgathered
     (``multihost_utils.process_allgather`` — a collective, so the hook
@@ -417,7 +420,7 @@ class TelemetryHook(Hook):
 
     def after_step(self, state, metrics, step):
         if MOE_KEYS[0] in metrics:
-            keys = MOE_KEYS + (MOE_HELD_KEY,) * (MOE_HELD_KEY in metrics)
+            keys = MOE_KEYS + MOE_HELD_KEYS * (MOE_HELD_KEYS[0] in metrics)
             self._moe.append(tuple(metrics[k] for k in keys))
         if step % self._every:
             return
@@ -484,7 +487,7 @@ class TelemetryHook(Hook):
         }
         if self._moe:
             means = np.mean(np.asarray(jax.device_get(self._moe)), axis=0)
-            out.update(zip(MOE_KEYS + (MOE_HELD_KEY,), map(float, means)))
+            out.update(zip(MOE_KEYS + MOE_HELD_KEYS, map(float, means)))
             self._moe = []
         if self._nproc > 1:
             from jax.experimental import multihost_utils

@@ -21,12 +21,27 @@ byte, the same for every model (ISSUE 42; PERF.md section 6):
    output a gated memory unit reads, the keys and values a
    cross-attention reads): later halves hold them as inputs anyway.
 
+Beside the products, one thing that is no product and is kept for what it
+costs to make, not for its FLOPs (ISSUE 45):
+
+5. the routing plan of an expert layer that holds a range of its
+   router's experts (``parallel/moe.py::RoutingPlan``, through
+   :func:`kept_plan`): the router's float32 logits ``[T, E]``, the
+   top-k's scores and experts ``[T, k]``, the sorted order of the
+   assignments ``[T k]`` and the experts' counts ``[E]``; 4 (E + 3 k) T
+   bytes, 18.4 MB a layer at Kimi Linear's 256 experts, top-8 and 16,384
+   tokens, 4.8 MB at Nemotron 3 Nano's 128, top-6 and 8,192.  Without it
+   the backward pass runs the router's product (float32 at full
+   precision: six bf16 passes), the top-k, the sort and the count again to
+   rebuild a few MB of indices.
+
 The modules name those outputs with :func:`kept`; :func:`half` is the
 ``nn.remat`` whose policy saves that name and nothing else.  Outside a
-recomputed half :func:`kept` returns its argument, so a model that
-recomputes nothing traces the program it traced before.  What a traced
-half holds is counted like the ops' routes, at trace time in the
-process-global registry (``remat/products_kept``, ``remat/bytes_kept``).
+recomputed half :func:`kept` and :func:`kept_plan` return their argument,
+so a model that recomputes nothing traces the program it traced before.
+What a traced half holds is counted like the ops' routes, at trace time
+in the process-global registry (``remat/products_kept``,
+``remat/bytes_kept``, ``moe/plan_kept``).
 """
 
 from __future__ import annotations
@@ -38,6 +53,7 @@ import jax
 from jax.ad_checkpoint import checkpoint_name
 
 from distributed_tensorflow_models_tpu.telemetry.registry import (
+    MOE_PLAN_KEPT,
     REMAT_BYTES_KEPT,
     REMAT_PRODUCTS_KEPT,
     get_registry,
@@ -75,11 +91,25 @@ def half(fn):
     )
 
 
+def _named(x):
+    get_registry().counter(REMAT_BYTES_KEPT).inc(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, KEPT_NAME)
+
+
 def kept(x):
-    """``x``, and inside a recomputed half the array that half keeps."""
+    """``x``, a product's output, and inside a recomputed half the array
+    that half keeps."""
     if not _tracing.halves:
         return x
-    registry = get_registry()
-    registry.counter(REMAT_PRODUCTS_KEPT).inc()
-    registry.counter(REMAT_BYTES_KEPT).inc(x.size * x.dtype.itemsize)
-    return checkpoint_name(x, KEPT_NAME)
+    get_registry().counter(REMAT_PRODUCTS_KEPT).inc()
+    return _named(x)
+
+
+def kept_plan(plan):
+    """``plan``, an expert layer's routing plan (a tuple of arrays), and
+    inside a recomputed half the arrays that half keeps: counted as one
+    plan and by their bytes, not as products."""
+    if not _tracing.halves:
+        return plan
+    get_registry().counter(MOE_PLAN_KEPT).inc()
+    return jax.tree.map(_named, plan)

@@ -422,8 +422,9 @@ class TopKExpertsFFN(nn.Module):
     there); the unweighted values and the load statistic into
     ``moe_stats``, which the loss function averages over layers into the
     step's metrics.  ``held = (first, count)``: the expert stacks hold
-    that range of the router's ``num_experts`` (``held_share`` joins the
-    statistics); ``shared_experts``: a feed-forward that many experts
+    that range of the router's ``num_experts`` (``held_share`` and
+    ``held_slabs`` join the statistics, and a recomputed half keeps the
+    layer's routing plan: ``models/remat.py``); ``shared_experts``: a feed-forward that many experts
     wide (each ``shared_d_ff`` where that is not an expert's ``d_ff``) on
     every token, added to the routed result."""
 
@@ -463,6 +464,7 @@ class TopKExpertsFFN(nn.Module):
         res = moelib.topk_moe_ffn(
             params, x, top_k=self.top_k, mesh=self.mesh, dtype=self.dtype,
             routing=self.routing or moelib.Routing(), held=self.held,
+            keep=rematlib.kept_plan,
         )
         scalar = dict(
             reduce_fn=lambda a, b: a + b,
@@ -478,7 +480,7 @@ class TopKExpertsFFN(nn.Module):
             ("load_max_over_mean", res.load_max_over_mean),
         ]
         if self.held is not None:
-            stats.append(("held_share", res.held_share))
+            stats += [("held_share", res.held_share), ("held_slabs", res.held_slabs)]
         for name, value in stats:
             self.sow("moe_stats", name, value, **scalar)
         out = res.out.astype(x.dtype)

@@ -47,11 +47,27 @@ come out zero).  What the absent experts would add is left out; no
 assignment to a held expert is dropped, whatever the imbalance.  The
 scores may be a sigmoid in place of the softmax, renormalised over the
 chosen experts and scaled, as the models that state it have them.
+
+Such a layer keeps one assignment in 16 or 32, so what it does with all
+of them is made once and what it does again and again follows the kept
+rows (ISSUE 45; PERF.md section 6).  What routing decided is a
+:class:`RoutingPlan` (the float32 logits, the top-k's scores and experts,
+the sorted order, the counts: 18.4 MB a layer at Kimi Linear's shapes,
+4.8 MB at Nemotron 3 Nano's); a caller that recomputes the layer in the
+backward pass is handed it to keep (``keep``), and then runs no router
+product, top-k, sort or count a second time: every later use, the
+gradient's too, reads the plan (:func:`_at_choice`).  A slab is the even
+share of the held experts times ``_HELD_SLAB_MARGIN``, not a multiple of
+it, since every row of a slab is gathered and scatter-added whether kept
+or not; the shares are gathered, and their gradients scattered, for a
+slab's rows and not for all assignments (``held_slabs`` says how many
+slabs a step's routing took: 1 in an ordinary step).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -264,6 +280,7 @@ class TopKMoEOutput(NamedTuple):
     z_loss: jax.Array  # mean(logsumexp(router logits)^2) (unweighted)
     load_max_over_mean: jax.Array  # fullest expert's assignments / mean
     held_share: jax.Array  # share of the assignments that fell on held experts
+    held_slabs: jax.Array  # slabs of sorted rows the held experts' took (1: an ordinary step)
 
 
 @jax.custom_vjp
@@ -342,35 +359,50 @@ class Routing(NamedTuple):
     scale: float = 1.0
 
 
+def _router_logits(router: jax.Array, x: jax.Array):
+    """The router's product on tokens ``x`` [n, d], in float32 at full
+    precision: a bf16 product here moves near-ties across the top-k
+    boundary."""
+    return jnp.dot(
+        x.astype(jnp.float32),
+        router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+
+
+def _scores(logits: jax.Array, routing: Routing):
+    if routing.scoring == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    if routing.scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    raise ValueError(
+        f"unknown router scoring {routing.scoring!r} "
+        "(want 'softmax' or 'sigmoid')"
+    )
+
+
+def _chosen_weight(weight: jax.Array, routing: Routing):
+    """The top-k's scores [n, top_k], renormalised and scaled as
+    ``routing`` says."""
+    if routing.renormalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if routing.scale != 1.0:
+        weight = weight * routing.scale
+    return weight
+
+
 def route_topk(
     router: jax.Array, x: jax.Array, top_k: int, routing: Routing = Routing()
 ):
     """``(logits, probs, weight, expert)`` of tokens ``x`` [n, d]: the
     router's product, its scores (``routing.scoring``) and the choice, in
-    float32 at full precision: a bf16 product here moves near-ties across
-    the top-k boundary.  ``weight`` and ``expert`` are [n, top_k], largest
-    first, ties to the lower expert index; ``weight`` is the chosen
-    scores, renormalised and scaled as ``routing`` says."""
-    logits = jnp.dot(
-        x.astype(jnp.float32),
-        router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    )
-    if routing.scoring == "softmax":
-        probs = jax.nn.softmax(logits, axis=-1)
-    elif routing.scoring == "sigmoid":
-        probs = jax.nn.sigmoid(logits)
-    else:
-        raise ValueError(
-            f"unknown router scoring {routing.scoring!r} "
-            "(want 'softmax' or 'sigmoid')"
-        )
+    float32 at full precision.  ``weight`` and ``expert`` are [n, top_k],
+    largest first, ties to the lower expert index; ``weight`` is the
+    chosen scores, renormalised and scaled as ``routing`` says."""
+    logits = _router_logits(router, x)
+    probs = _scores(logits, routing)
     weight, expert = lax.top_k(probs, top_k)
-    if routing.renormalize:
-        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-    if routing.scale != 1.0:
-        weight = weight * routing.scale
-    return logits, probs, weight, expert
+    return logits, probs, _chosen_weight(weight, routing), expert
 
 
 def squared_relu(x):
@@ -416,13 +448,14 @@ def _routing_statistics(counts, probs, logits, n: int, top_k: int):
 
 def _topk_local(
     params: dict, x: jax.Array, top_k: int, dtype,
-    routing: Routing = Routing(), held: Optional[tuple[int, int]] = None,
+    routing: Routing, held: Optional[tuple[int, int]], keep,
 ):
     """The layer on one rank's tokens ``x`` [n, d].  ``held`` None: every
     expert is here; ``(first, count)``: the expert stacks hold those
-    ``count`` of the router's experts (:func:`_held_local`)."""
+    ``count`` of the router's experts (:func:`_held_local`, which alone
+    hands its :class:`RoutingPlan` to ``keep``)."""
     if held is not None:
-        return _held_local(params, x, top_k, dtype, routing, held)
+        return _held_local(params, x, top_k, dtype, routing, held, keep)
     n, d = x.shape
     num_experts = params["router"].shape[-1]
     with jax.named_scope(MOE_DISPATCH_SCOPE):
@@ -448,20 +481,78 @@ def _topk_local(
             back.astype(jnp.float32) * weight[..., None], axis=1
         ).astype(dtype)
         aux, z, load = _routing_statistics(counts, probs, logits, n, top_k)
-    return out, aux, z, load, jnp.ones((), jnp.float32)
+    one = jnp.ones((), jnp.float32)
+    return out, aux, z, load, one, one
 
 
 # Of the sorted assignments, the held experts' come first.  The layer
 # works through them in slabs of this many times the rows an even routing
-# would give the held experts: one slab in an ordinary step (a Zipf
-# stream's routing is uneven: a layer's held share reads up to twice the
-# even one), as many as the step's routing needs otherwise.
-_HELD_SLAB_MARGIN = 4
+# would give the held experts, in whole row tiles: one slab in an ordinary
+# step, as many as the step's routing needs otherwise (``held_slabs`` says
+# how many it was).  Every row of a slab is gathered, multiplied by its
+# share and scatter-added whether a held expert's or not, so the margin is
+# what an ordinary step pays for rows it does not keep, and a second slab
+# costs a whole one.  Timed on the chip at 1, 1.5 and 2 in both cells that
+# hold a share (PERF.md section 6, PR 45: ``kimi_linear_train`` 27,558 |
+# 27,631 | 27,527 tokens/s, ``nemotron_h_train`` 35,703 | 36,031 | 35,725,
+# one seed), where the held share, a mean over four layers, read 0.4 to
+# 1.5 times the even one (1.2-4.7% against 3.125%, 2.1-7.9% against 6.25%)
+# and a single layer more: at 1 a step walked 1.18 and 1.41 slabs a layer,
+# at 1.5 1.00-1.38 and 1.06-1.21, at 2 1.00 and 1.13.
+_HELD_SLAB_MARGIN = 1.5
 
 
 def _slab_rows(assignments: int, count: int, num_experts: int, tile: int) -> int:
-    rows = min(assignments, _HELD_SLAB_MARGIN * assignments * count // num_experts)
+    """Rows of a slab: the held experts' even share of the assignments
+    times the margin, in whole row tiles (one at least)."""
+    even = -(-assignments * count // num_experts)
+    rows = min(assignments, math.ceil(_HELD_SLAB_MARGIN * even))
     return max(tile, -(-rows // tile) * tile)
+
+
+class RoutingPlan(NamedTuple):
+    """What a layer's routing decided, and what its backward pass reads of
+    it: the router's ``logits`` [n, E] (float32), the top-k's chosen
+    scores ``weight`` (as the top-k gives them: not yet renormalised or
+    scaled) and ``expert`` [n, k], the assignment ids in their sorted
+    ``order`` [n k] (the held experts first) and the assignments each
+    expert got, ``counts`` [E].  Everything else of the dispatch (the
+    scores, the shares, each row's token, the held experts' offsets) is
+    element-wise in these or a few elements long.  Inside a recomputed
+    half of a block the half keeps the plan (``topk_moe_ffn``'s ``keep``),
+    so that the backward pass does not route again."""
+
+    logits: jax.Array
+    weight: jax.Array
+    expert: jax.Array
+    order: jax.Array
+    counts: jax.Array
+
+
+@jax.custom_vjp
+def _at_choice(probs, expert, weight):
+    """``weight`` [n, k], the scores ``probs`` [n, E] at the chosen
+    ``expert`` [n, k] as the top-k gave them, as a function of ``probs``:
+    the backward pass puts each weight's cotangent at its expert (a
+    token's experts differ, so by comparison, no scatter) and reads
+    ``expert`` alone, where the top-k's own would read the top-k's."""
+    del probs, expert
+    return weight
+
+
+def _at_choice_fwd(probs, expert, weight):
+    # An empty array carries the experts' number and the scores' type.
+    return weight, (expert, jnp.zeros((0, probs.shape[-1]), probs.dtype))
+
+
+def _at_choice_bwd(residuals, g):
+    expert, like = residuals
+    here = expert[:, None, :] == jnp.arange(like.shape[-1])[None, :, None]
+    dprobs = jnp.sum(jnp.where(here, g[:, None, :], 0), axis=-1).astype(like.dtype)
+    return dprobs, None, jnp.zeros_like(g)
+
+
+_at_choice.defvjp(_at_choice_fwd, _at_choice_bwd)
 
 
 def _slab(x, stacks, share, token, sizes, dtype):
@@ -481,8 +572,9 @@ def _slab(x, stacks, share, token, sizes, dtype):
         return down.astype(jnp.float32) * share[:, None]
 
 
-def _slab_inputs(i, rows: int, share, token, offsets):
-    """Slab ``i``'s slices of the sorted ``share`` and ``token``, and its
+def _slab_inputs(i, rows: int, weight, order, offsets):
+    """Slab ``i`` of the sorted order: its assignment ids ``[rows]``, the
+    share (``weight`` [n, k] at the id) and the token of each, and its
     group sizes from the held experts' ``offsets`` [count + 1] into the
     sorted order."""
     start = i * rows
@@ -490,77 +582,86 @@ def _slab_inputs(i, rows: int, share, token, offsets):
     hi = jnp.clip(offsets[1:], start, start + rows)
     held = hi - lo
     sizes = jnp.concatenate([held, (rows - jnp.sum(held))[None]])
-    cut = lambda a: lax.dynamic_slice_in_dim(a, start, rows)
-    return cut(share), cut(token), sizes
+    ids = lax.dynamic_slice_in_dim(order, start, rows)
+    with jax.named_scope(MOE_DISPATCH_SCOPE):
+        share = weight.reshape(-1)[ids]
+    return ids, share, ids // weight.shape[-1], sizes
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_experts(x, stacks, share, token, offsets, rows: int, dtype):
-    """``out[t] = sum over t's held assignments of share * expert(x[t])``
-    for assignments sorted with the held experts first: ``share`` and
-    ``token`` [slabs * rows] in that order, ``offsets`` [count + 1] where
-    each held expert's rows start (the last: where they end).  Slab after
-    slab while there are held rows left (a ``while_loop``: its length is
-    the step's routing), each gathered, multiplied and added into the
-    tokens; nothing the size of all assignments exists.  The backward
-    pass walks the same slabs, recomputing each."""
-    return _held_experts_fwd(x, stacks, share, token, offsets, rows, dtype)[0]
+def _held_experts(x, stacks, weight, order, offsets, rows: int, dtype):
+    """``out[t] = sum over t's held assignments of weight * expert(x[t])``
+    for assignments sorted with the held experts first: ``weight`` [n,
+    k] by assignment id, ``order`` [slabs * rows] the ids in sorted order
+    (padded with ids past the last assignment: rows of no expert, which
+    the gathers clamp and the scatters drop), ``offsets`` [count + 1] where each
+    held expert's rows start (the last: where they end).  Slab after slab
+    while there are held rows left (a ``while_loop``: its length is the
+    step's routing), each slab's rows and shares gathered, multiplied and
+    added into the tokens; nothing the size of all assignments is moved.
+    The backward pass walks the same slabs, recomputing each."""
+    return _held_experts_fwd(x, stacks, weight, order, offsets, rows, dtype)[0]
 
 
-def _held_experts_fwd(x, stacks, share, token, offsets, rows, dtype):
+def _held_experts_fwd(x, stacks, weight, order, offsets, rows, dtype):
     def body(state):
         i, out = state
-        share_i, token_i, sizes = _slab_inputs(i, rows, share, token, offsets)
-        weighted = _slab(x, stacks, share_i, token_i, sizes, dtype)
+        _, share, token, sizes = _slab_inputs(i, rows, weight, order, offsets)
+        weighted = _slab(x, stacks, share, token, sizes, dtype)
         with jax.named_scope(MOE_DISPATCH_SCOPE):
-            return i + 1, out.at[token_i].add(weighted)
+            return i + 1, out.at[token].add(weighted)
 
     _, out = lax.while_loop(
         lambda state: state[0] * rows < offsets[-1],
         body,
         (jnp.zeros((), jnp.int32), jnp.zeros(x.shape, jnp.float32)),
     )
-    return out.astype(dtype), (x, stacks, share, token, offsets)
+    return out.astype(dtype), (x, stacks, weight, order, offsets)
 
 
 def _held_experts_bwd(rows, dtype, residuals, g):
-    x, stacks, share, token, offsets = residuals
+    x, stacks, weight, order, offsets = residuals
     g = g.astype(jnp.float32)
 
     def body(state):
-        i, dx, dstacks, dshare = state
-        share_i, token_i, sizes = _slab_inputs(i, rows, share, token, offsets)
+        i, dx, dstacks, dflat = state
+        ids, share, token, sizes = _slab_inputs(i, rows, weight, order, offsets)
         _, pull = jax.vjp(
-            lambda x_, stacks_, share_: _slab(x_, stacks_, share_, token_i, sizes, dtype),
-            x, stacks, share_i,
+            lambda x_, stacks_, share_: _slab(x_, stacks_, share_, token, sizes, dtype),
+            x, stacks, share,
         )
         with jax.named_scope(MOE_DISPATCH_SCOPE):
-            dx_i, dstacks_i, dshare_i = pull(g[token_i])
-            dshare = lax.dynamic_update_slice_in_dim(dshare, dshare_i, i * rows, 0)
+            dx_i, dstacks_i, dshare = pull(g[token])
+            dflat = dflat.at[ids].add(dshare)
             dx = dx + dx_i.astype(jnp.float32)
-        return i + 1, dx, jax.tree.map(jnp.add, dstacks, dstacks_i), dshare
+        return i + 1, dx, jax.tree.map(jnp.add, dstacks, dstacks_i), dflat
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
-    _, dx, dstacks, dshare = lax.while_loop(
+    _, dx, dstacks, dflat = lax.while_loop(
         lambda state: state[0] * rows < offsets[-1],
         body,
-        (jnp.zeros((), jnp.int32), zeros(x), jax.tree.map(zeros, stacks), zeros(share)),
+        (jnp.zeros((), jnp.int32), zeros(x), jax.tree.map(zeros, stacks), zeros(weight.reshape(-1))),
     )
     cast = lambda d, a: d.astype(a.dtype)
-    return cast(dx, x), jax.tree.map(cast, dstacks, stacks), dshare, None, None
+    return (
+        cast(dx, x), jax.tree.map(cast, dstacks, stacks),
+        cast(dflat, weight).reshape(weight.shape), None, None,
+    )
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _held_local(params, x, top_k, dtype, routing, held):
+def _held_local(params, x, top_k, dtype, routing, held, keep):
     """One chip's share of the layer on tokens ``x`` [n, d]: the router
     and the top-k run over every expert, the grouped products over the
     ``count`` held ones, ``first`` onwards.  The assignments are sorted
     with the held experts first, so that their rows are a prefix of the
     sorted order, and :func:`_held_experts` works through that prefix and
     no further: no assignment to a held expert is left out, whatever the
-    imbalance, and an ordinary step touches one slab of rows."""
+    imbalance, and an ordinary step touches one slab of rows.  Everything
+    after the :class:`RoutingPlan` reads the plan as ``keep`` hands it
+    back."""
     n, d = x.shape
     num_experts = params["router"].shape[-1]
     first, count = held
@@ -568,29 +669,34 @@ def _held_local(params, x, top_k, dtype, routing, held):
     rows = _slab_rows(assignments, count, num_experts, _tiling(dtype)[0])
     with jax.named_scope(MOE_DISPATCH_SCOPE):
         x = x.astype(dtype)
-        logits, probs, weight, expert = route_topk(
-            params["router"], x, top_k, routing
-        )
+        logits = _router_logits(params["router"], x)
+        # The choice itself is not differentiated: the scores' gradient
+        # goes through the plan (:func:`_at_choice`).
+        weight, expert = lax.top_k(lax.stop_gradient(_scores(logits, routing)), top_k)
         flat = expert.reshape(assignments)
-        # Assignment ids by expert, the held experts first.
-        order = jnp.argsort((flat - first) % num_experts, stable=True)
-        counts = jnp.sum(
-            jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0
-        )
+        plan = keep(RoutingPlan(
+            logits, weight, expert,
+            # Assignment ids by expert, the held experts first.
+            order=jnp.argsort((flat - first) % num_experts, stable=True),
+            counts=jnp.sum(jax.nn.one_hot(flat, num_experts, dtype=jnp.int32), axis=0),
+        ))
+        probs = _scores(plan.logits, routing)
+        weight = _chosen_weight(_at_choice(probs, plan.expert, plan.weight), routing)
         offsets = jnp.concatenate([
             jnp.zeros((1,), jnp.int32),
-            jnp.cumsum(lax.dynamic_slice_in_dim(counts, first, count)),
+            jnp.cumsum(lax.dynamic_slice_in_dim(plan.counts, first, count)),
         ])
-        pad = (0, -assignments % rows)
-        share = jnp.pad(weight.reshape(assignments)[order], pad)
-        token = jnp.pad(order // top_k, pad)
+        pad = -assignments % rows
+        order = jnp.concatenate([plan.order, assignments + jnp.arange(pad, dtype=jnp.int32)])
     out = _held_experts(
-        x, _expert_stacks(params), share, token, offsets, rows, dtype
+        x, _expert_stacks(params), weight, order, offsets, rows, dtype
     )
     with jax.named_scope(MOE_DISPATCH_SCOPE):
-        aux, z, load = _routing_statistics(counts, probs, logits, n, top_k)
-        held_share = offsets[-1].astype(jnp.float32) / assignments
-    return out, aux, z, load, held_share
+        aux, z, load = _routing_statistics(plan.counts, probs, plan.logits, n, top_k)
+        held_rows = offsets[-1]
+        held_share = held_rows.astype(jnp.float32) / assignments
+        held_slabs = jnp.maximum(1, -(-held_rows // rows)).astype(jnp.float32)
+    return out, aux, z, load, held_share, held_slabs
 
 
 @jax.named_scope(MOE_SCOPE)
@@ -603,6 +709,7 @@ def topk_moe_ffn(
     dtype=jnp.bfloat16,
     routing: Routing = Routing(),
     held: Optional[tuple[int, int]] = None,
+    keep=lambda plan: plan,
 ) -> TopKMoEOutput:
     """Top-k routing over experts, exactly: ``y = sum_{e in top_k} p_e *
     W_down_e (silu(W_gate_e h) * W_up_e h)`` with the ``p_e`` the
@@ -613,7 +720,12 @@ def topk_moe_ffn(
     h)^2`` (two matrices, no gate).  With ``held = (first, count)``
     the stacks hold ``count`` experts, ``first`` onwards, of the router's
     ``E``, and the sum runs over the chosen experts that are held (module
-    docstring); ``held_share`` says how many of the assignments that was.
+    docstring); ``held_share`` says how many of the assignments that was
+    and ``held_slabs`` in how many slabs of rows they were worked through.
+    ``keep`` is handed such a layer's :class:`RoutingPlan` and returns
+    it: a caller that recomputes the layer in the backward pass names the
+    plan's arrays there for keeping (``models/remat.py::kept_plan``), and
+    the recomputed pass then routes nothing again.
 
     On a mesh every rank routes its own tokens (``x`` sharded
     ``[data, seq, ...]``, the experts replicated) and the statistics
@@ -631,7 +743,7 @@ def topk_moe_ffn(
             f"matrices and {num_experts} router outputs"
         )
     local = lambda p, xl: _topk_local(
-        p, xl.reshape(-1, d), top_k, dtype, routing, held
+        p, xl.reshape(-1, d), top_k, dtype, routing, held, keep
     )
     if mesh is None:
         out, *stats = local(params, x)

@@ -67,6 +67,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     KDA_MIXER_PLAIN,
     KDA_ROUTE_KERNEL,
     KDA_ROUTE_PLAIN,
+    MOE_PLAN_KEPT,
     PIPELINE_BYTES,
     PREFETCH_FILL,
     PRODUCER_WAIT,

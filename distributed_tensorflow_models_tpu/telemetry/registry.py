@@ -111,6 +111,13 @@ SSCAN_ROUTE_PLAIN = "sscan/route_plain"  # counter
 # call of the model, and its bytes.  0 where nothing is recomputed.
 REMAT_PRODUCTS_KEPT = "remat/products_kept"  # counter
 REMAT_BYTES_KEPT = "remat/bytes_kept"  # counter
+# Expert layers whose routing plan a recomputed half keeps
+# (``parallel/moe.py::RoutingPlan``, named through
+# ``models/remat.py::kept_plan``: the backward pass then routes nothing
+# again), one increment per such layer per traced call like the routes;
+# the plan's bytes are in ``remat/bytes_kept``, and it is no product.
+# 0 where every expert is held or nothing is recomputed.
+MOE_PLAN_KEPT = "moe/plan_kept"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

@@ -221,8 +221,10 @@ INPUT_WORK_KEYS = ("assemble_s", "shard_s")
 MOE_KEYS = ("moe_load_max_over_mean", "moe_aux_loss", "moe_z_loss")
 # Beside them where the expert layers hold a share of the router's
 # experts: the share of the assignments that fell on it, in [0, 1], and
-# never without the three.
+# never without the three; with it since PR 45 the slabs of rows a layer
+# worked through them in, 1 at least, and never without the share.
 MOE_HELD_KEY = "moe_held_share"
+MOE_HELD_SLABS_KEY = "moe_held_slabs"
 
 
 def _is_number(v) -> bool:
@@ -353,6 +355,16 @@ def check_lines(
             if _is_number(value) and not 0.0 <= value <= 1.0:
                 errors.append(
                     f"line {i}: {MOE_HELD_KEY!r} is outside [0, 1]: {value!r}"
+                )
+        if MOE_HELD_SLABS_KEY in row:
+            value = row[MOE_HELD_SLABS_KEY]
+            if MOE_HELD_KEY not in row:
+                errors.append(
+                    f"line {i}: {MOE_HELD_SLABS_KEY!r} without {MOE_HELD_KEY!r}"
+                )
+            if _is_number(value) and value < 1.0:
+                errors.append(
+                    f"line {i}: {MOE_HELD_SLABS_KEY!r} is below 1: {value!r}"
                 )
         for key in moe_present:
             value = row[key]

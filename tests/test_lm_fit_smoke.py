@@ -71,6 +71,9 @@ def _kimi_linear(run):
     # 4 of 16 experts held: about a quarter of the assignments, and the
     # three routing statistics beside it; no auxiliary loss in the objective.
     assert 0.05 < final["moe_held_share"] < 0.6
+    # The held rows of so small a step fit one slab, and the recomputed half keeps
+    # its one expert layer's routing plan (``model.init`` and the step program).
+    assert final["moe_held_slabs"] == 1.0 and run.telemetry["moe/plan_kept"] == 2
     assert final["moe_load_max_over_mean"] >= 1.0 and "moe_aux_loss" in final
     assert "aux_loss" not in final and final["loss"] == pytest.approx(final["nll"])
     check = subprocess.run(
@@ -93,6 +96,8 @@ def _nemotron_h(run):
     held = [r["moe_held_share"] for r in run.rows if "moe_held_share" in r]
     assert held and all(0.0 < h < 1.0 for h in held)
     assert all("moe_load_max_over_mean" in r for r in run.rows if "moe_held_share" in r)
+    assert all(1.0 <= r["moe_held_slabs"] <= 2.0 for r in run.rows if "moe_held_share" in r)
+    assert run.telemetry["moe/plan_kept"] == 2 and run.telemetry["remat/products_kept"] > 0
 
 
 _NARROW = {"num_heads": 4, "d_model": 64, "max_len": 40}

@@ -181,7 +181,7 @@ def _dense_masked(params, x, held=(0, E)):
 def _held_layer(share, x, held):
     with jax.default_matmul_precision("highest"):
         out = moe.topk_moe_ffn(share, x, top_k=K, dtype=jnp.float32, routing=ROUTING, held=held)
-    return out.out, out.held_share
+    return out.out, (out.held_share, out.held_slabs)
 
 
 @functools.partial(jax.jit, static_argnames=("f", "held"))
@@ -221,7 +221,7 @@ def test_a_share_computes_its_own_experts_part_and_drops_nothing(layer_params, h
         )
     probe, share = jax.random.normal(jax.random.key(3), x.shape), _share(params, *held)
     (want, chosen), want_grads = _probed(_dense_masked, share, x, probe, held)
-    (got, held_share), got_grads = _probed(_held_layer, share, x, probe, held)
+    (got, (held_share, _)), got_grads = _probed(_held_layer, share, x, probe, held)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=1e-5)
     on_share = float(chosen.sum()) / (K * N)
     assert float(held_share) == pytest.approx(on_share, abs=1e-6)
@@ -290,14 +290,49 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_whole_layer(ki
 E64, SLAB_HELD = 64, (8, 4)
 
 
-@pytest.mark.parametrize("skew", [0.0, 0.08, 0.2], ids=["one_slab", "several_slabs", "every_assignment"])
-def test_held_rows_in_one_slab_or_in_many(skew):
+def _skewed(skew):
+    """Random inputs; the held experts' router columns raised by ``skew``."""
+    def build(params, keys, n):
+        x = jnp.abs(jax.random.normal(keys[4], (1, n, D))) + 0.1
+        router = params["router"].at[:, 8:12].add(skew * (1.0 + 0.1 * jnp.arange(4)))
+        return router, x
+    return build
+
+
+def _decided(tokens_on_share):
+    """The first feature decides: +1 in that many tokens, whose four
+    choices are then the four held experts, and -1 in the others, which
+    choose none of them; the other features are too small to matter."""
+    def build(params, keys, n):
+        x = 0.05 * (jnp.abs(jax.random.normal(keys[4], (1, n, D))) + 0.1)
+        x = x.at[0, :, 0].set(jnp.where(jnp.arange(n) < tokens_on_share, 1.0, -1.0))
+        router = params["router"].at[0, 8:12].set(3.0 + 0.1 * jnp.arange(4))
+        return router, x
+    return build
+
+
+# name -> (router and inputs, held rows: None where the routing is random, slabs)
+SLAB_CASES = {
+    "one_slab": (_skewed(0.01), None, 1),
+    "several_slabs": (_skewed(0.03), None, 4),
+    "every_assignment": (_skewed(0.2), 2048, 8),
+    "no_held_row": (_decided(0), 0, 1),
+    "rows_end_at_a_slab_boundary": (_decided(128), 512, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_held_rows_in_one_slab_or_in_many(case):
     """64 experts of which 4 are held, 2,048 assignments: the layer works
-    through the held experts' sorted rows in slabs of 512 (four times an even
-    routing's 128): one slab, several, or all four.  Each against the
-    dense masked formulation, forward and gradient."""
+    through the held experts' sorted rows in slabs of 256 (one and a half
+    times an even routing's 128, in whole row tiles): one slab, several,
+    all eight, none at all (one slab is walked all the same), and two
+    that the held rows fill to the last.  Each against the dense masked
+    formulation, forward and gradient, and ``held_slabs`` says how many
+    slabs it was."""
     from distributed_tensorflow_models_tpu.parallel.moe import _slab_rows
 
+    build, rows_held, slabs = SLAB_CASES[case]
     n = 512
     keys = jax.random.split(jax.random.key(11), 5)
     params = {
@@ -306,20 +341,21 @@ def test_held_rows_in_one_slab_or_in_many(skew):
         "w_up": jax.random.normal(keys[2], (4, D, F)) * D**-0.5,
         "w_down": jax.random.normal(keys[3], (4, F, D)) * F**-0.5,
     }
-    x = jnp.abs(jax.random.normal(keys[4], (1, n, D))) + 0.1
-    params["router"] = params["router"].at[:, 8:12].add(skew * (1.0 + 0.1 * jnp.arange(4)))
+    params["router"], x = build(params, keys, n)
     probe = jax.random.normal(jax.random.key(3), x.shape)
 
     (want, chosen), w = _probed(_dense_masked, params, x, probe, SLAB_HELD)
-    on_share = chosen.sum()
+    on_share = int(chosen.sum())
     prefix = _slab_rows(n * K, SLAB_HELD[1], E64, 256)
-    assert prefix == 512
-    assert (int(on_share) > prefix) == (skew > 0), int(on_share)
-    if skew == 0.2:
-        assert int(on_share) == n * K
-    (got, held_share), g = _probed(_held_layer, params, x, probe, SLAB_HELD)
+    assert prefix == 256
+    if rows_held is not None:
+        assert on_share == rows_held
+    want_slabs = max(1, -(-on_share // prefix))
+    assert want_slabs == slabs, on_share
+    (got, (held_share, held_slabs)), g = _probed(_held_layer, params, x, probe, SLAB_HELD)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=1e-5)
-    assert float(held_share) == pytest.approx(int(on_share) / (n * K))
+    assert float(held_share) == pytest.approx(on_share / (n * K))
+    assert float(held_slabs) == want_slabs
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-3)
 
@@ -382,6 +418,6 @@ def test_with_everything_held_the_layer_is_bit_for_bit_the_parent_s(dtype):
     np.testing.assert_array_equal(np.asarray(res.out.reshape(-1, D), np.float32), np.asarray(out, np.float32))
     for a, b in ((res.aux_loss, aux), (res.z_loss, z), (res.load_max_over_mean, load)):
         assert float(a) == float(b)
-    assert float(res.held_share) == 1.0
+    assert float(res.held_share) == 1.0 and float(res.held_slabs) == 1.0
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -181,3 +181,68 @@ def test_kept_is_the_identity_outside_a_recomputed_half():
     x = jnp.ones((4, 8))
     assert _counted(lambda: rematlib.kept(x)) == (0, 0) and rematlib.kept(x) is x
     assert "name" not in str(jax.make_jaxpr(rematlib.kept)(x))
+
+
+# --- the routing plan of a held expert layer (ISSUE 45) ------------------
+
+# Two expert layers that hold experts 2-5 of their router's eight, top-2 by
+# a renormalised sigmoid, one shared expert of 40 beside them.
+EXPERTS, TOP_K = 8, 2
+KINDS["held_experts"] = (
+    {**BASE, "d_ff": 24, "moe_router": "topk", "moe_layers": "all", "num_experts": EXPERTS, "moe_top_k": TOP_K,
+     "moe_held": (2, 4), "moe_scoring": "sigmoid", "moe_renormalize": True, "moe_routed_scale": 2.446,
+     "moe_shared_experts": 1, "moe_shared_d_ff": 40},
+    {(D, 40): 4}, {QUERY: 2, KEY_VALUE: 4}, {},
+)
+# float32 logits [n, E], the top-k's scores and experts [n, k], the sorted
+# order [n k] and the counts [E], four bytes each.
+PLAN_BYTES = 4 * (B * T * (EXPERTS + 3 * TOP_K) + EXPERTS)
+_ROUTER_PRODUCT = (((1,), (0,)), ((), ()))  # [n, d] x [d, E], the tokens flat
+
+
+def _routing_made(remat):
+    """How often the gradient of ``held_experts``'s loss, as traced, makes
+    the router's forward product, a top-k and a sort."""
+    jaxpr = jax.make_jaxpr(jax.grad(_loss_of("held_experts", remat)[1]))(_params("held_experts")).jaxpr
+    made = collections.Counter()
+    for eqn in _walk(jaxpr):
+        name = eqn.primitive.name
+        if name in ("top_k", "sort"):
+            made[name] += 1
+        elif name == "dot_general" and eqn.params["dimension_numbers"] == _ROUTER_PRODUCT:
+            made["router"] += tuple(eqn.invars[1].aval.shape) == (D, EXPERTS)
+    return dict(made)
+
+
+def test_a_recomputed_held_expert_layer_routes_once(monkeypatch):
+    once = {"router": 2, "top_k": 2, "sort": 2}  # one of each a layer
+    assert _routing_made(False) == once
+    assert _routing_made(True) == once
+    monkeypatch.setattr(rematlib, "half", nn.remat)  # the parent's halves: everything runs again
+    assert _routing_made(True) == {what: 2 * n for what, n in once.items()}
+
+
+def test_the_kept_routing_plan_changes_no_value(monkeypatch):
+    loss, grads = _value_and_grad("held_experts", True)
+    monkeypatch.setattr(rematlib, "half", nn.remat)
+    bare_loss, bare_grads = _value_and_grad("held_experts", True)
+    assert loss == bare_loss
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(bare_grads)):
+        assert jnp.array_equal(got, want), jax.tree_util.keystr(path)
+    assert float(jnp.max(jnp.abs(grads["params"]["blocks_1"]["moe"]["router"]))) > 0
+
+
+def test_a_kept_plan_counts_as_a_plan_and_by_its_bytes_not_as_a_product():
+    registry = reglib.get_registry()
+    plans = registry.counter(reglib.MOE_PLAN_KEPT)
+    trace = lambda remat: lambda: jax.eval_shape(jax.grad(_loss_of("held_experts", remat)[1]), _params("held_experts"))
+    before = plans.value
+    assert _counted(trace(False)) == (0, 0) and plans.value == before
+    # The shared experts' ``gate`` and ``up`` are the products; the plans are two more names and no product.
+    products = 4 * B * T * 40 * 4
+    assert _counted(trace(True)) == (4, products + 2 * PLAN_BYTES)
+    assert plans.value == before + 2
+    # Where every expert is held nothing is named but the products.
+    before = plans.value
+    assert _counted(lambda: jax.eval_shape(jax.grad(_loss_of("shared_expert", True)[1]), _params("shared_expert")))[0] == 4
+    assert plans.value == before

@@ -493,13 +493,18 @@ def test_smoke_train_produces_telemetry_artifacts(mesh8, tmp_path):
     assert not os.path.exists(tmp_path / "flight_recorder_p0.json")
 
 
-def test_schema_lint_catches_violations(tmp_path):
+def _schema_lint():
     from importlib import util as importutil
 
     spec = importutil.spec_from_file_location("check_metrics_schema",
                                              SCHEMA_LINT)
     mod = importutil.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def test_schema_lint_catches_violations(tmp_path):
+    mod = _schema_lint()
 
     good = [json.dumps({"step": 1, "time": 1.0, "loss": 0.5}),
             json.dumps({"step": 2, "time": 2.0, "loss": 0.4,
@@ -529,3 +534,21 @@ def test_schema_lint_catches_violations(tmp_path):
     p2 = tmp_path / "good.jsonl"
     p2.write_text("\n".join(good) + "\n")
     assert mod.main([str(p2), "--require-telemetry"]) == 0
+
+
+_MOE_ROW = {"step": 1, "time": 1.0, "moe_load_max_over_mean": 2.0, "moe_aux_loss": 1.0, "moe_z_loss": 0.5}
+
+
+@pytest.mark.parametrize(
+    "more,errors",
+    [
+        ({"moe_held_share": 0.03, "moe_held_slabs": 1.25}, 0),
+        ({"moe_held_share": 0.03}, 0),  # a row from before the slabs were reported
+        ({"moe_held_slabs": 1.0}, 1),  # never without the share
+        ({"moe_held_share": 0.03, "moe_held_slabs": 0.5}, 1),  # a layer walks one slab at least
+    ],
+    ids=["both", "share_alone", "slabs_alone", "under_one_slab"],
+)
+def test_schema_lint_holds_the_held_experts_keys_together(more, errors):
+    found, _, _ = _schema_lint().check_lines([json.dumps({**_MOE_ROW, **more})])
+    assert len(found) == errors, found
