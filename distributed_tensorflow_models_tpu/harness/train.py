@@ -1356,8 +1356,8 @@ def _trace_counts() -> dict[str, float]:
     routes, ``chunked_kda``'s, ``KDAMixer``'s two placements,
     ``chunked_gdn``'s, ``chunked_ssd``'s and ``selective_scan``'s traced
     calls, the fused head's gradient-in-forward calls, what the recomputed
-    halves keep, routing plans among it), as the process-global registry
-    holds it now."""
+    halves keep, routing plans and cores' results among it), as the
+    process-global registry holds it now."""
     shared = telemetry.get_registry()
     return {
         name: shared.counter(name).value
@@ -1373,7 +1373,7 @@ def _trace_counts() -> dict[str, float]:
             telemetry.SSCAN_ROUTE_KERNEL, telemetry.SSCAN_ROUTE_PLAIN,
             telemetry.UNEMBED_GRAD_IN_FORWARD,
             telemetry.REMAT_PRODUCTS_KEPT, telemetry.REMAT_BYTES_KEPT,
-            telemetry.MOE_PLAN_KEPT,
+            telemetry.MOE_PLAN_KEPT, telemetry.REMAT_CORES_KEPT,
         )
     }
 

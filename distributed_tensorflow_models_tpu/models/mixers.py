@@ -257,7 +257,7 @@ class KDAMixer(nn.Module):
             q, k, v, g = linattn.kda_prologue(
                 xq, xk, xv, f, wq, wk, wv, dt_bias, a_log
             )
-            o = linattn.chunked_kda_flat(q, k, v, g, beta)
+            o = linattn.chunked_kda_flat(q, k, v, g, beta, keep=rematlib.kept_core)
             scale = _HeadScale(D, name="o_norm")()
             o = linattn.kda_epilogue(o, gate, scale, eps=self.norm_eps)
             return _dense(self.d_model, self.dtype, "out")(o)
@@ -473,7 +473,8 @@ class LatentAttention(nn.Module):
         k_r = jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, self.rope_dim))
         k = jnp.concatenate([k_n, k_r], axis=-1)
         out = attnlib.attention(
-            q, k, v, causal=True, scale=qk**-0.5, impl=self.attn_impl
+            q, k, v, causal=True, scale=qk**-0.5, impl=self.attn_impl,
+            keep=rematlib.kept_core,
         )
         return _dense(self.d_model, self.dtype, "out")(
             out.reshape(B, T, H * self.v_dim)

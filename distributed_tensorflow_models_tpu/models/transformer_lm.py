@@ -171,6 +171,7 @@ class SelfAttention(nn.Module):
             q, over_group(k, 2, Dh), over_group(v, 1, 2 * Dh), causal=True,
             impl=self.attn_impl, window=self.attn_window,
             scale=self.scale if self.scale is not None else Dh**-0.5,
+            keep=rematlib.kept_core,
         ).astype(jnp.float32).reshape(B, T, pairs, 2, 2 * Dh)
         vector = lambda name: self.param(
             name, nn.initializers.normal(0.1), (Dh,), jnp.float32
@@ -193,7 +194,7 @@ class SelfAttention(nn.Module):
             return self._differential(q, k, v)
         return attnlib.attention(
             q, k, v, causal=True, impl=self.attn_impl, window=self.attn_window,
-            scale=self.scale,
+            scale=self.scale, keep=rematlib.kept_core,
         ).reshape(*q.shape[:2], -1)
 
     @nn.compact
@@ -266,6 +267,7 @@ class SelfAttention(nn.Module):
             out = attnlib.attention(
                 q, k, v, causal=True, impl=self.attn_impl,
                 window=self.attn_window, scale=self.scale,
+                keep=rematlib.kept_core,
             )
         out = out.reshape(B, T, H * Dh)
         out = dense("out", self.d_model)(out)

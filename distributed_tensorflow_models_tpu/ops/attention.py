@@ -43,6 +43,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from distributed_tensorflow_models_tpu.telemetry.registry import (
     ATTN_ROUTE_BLOCKWISE,
@@ -951,6 +952,16 @@ flash_attention_chunk.defvjp(_flash_chunk_fwd, _flash_chunk_bwd)
 # input dtype with f32 accumulation, the scale in f32 after the product,
 # (m, l, acc) in f32, P cast to the value dtype for P.V, exact exp and
 # division.  f32 inputs keep f32 products.
+#
+# What the forward rule hands the backward one beside the inputs is what
+# the forward kernel writes (:func:`fused_attention_results`: the output,
+# and the log-sum-exp at 4 bytes a head and token; nothing the size of a
+# score).  A caller that recomputes the call's surroundings keeps those
+# two under a name (``attention``'s ``keep``, ``fused_attention``'s
+# ``kept_as``; ``models/remat.py::kept_core``) and makes ``q``, ``k``,
+# ``v`` again: the recomputed copy of the forward ``pallas_call`` then has
+# every result kept and is gone from the differentiated program, which
+# holds the forward kernel once (ISSUE 47).  Nothing in the kernels knows.
 
 _LANES = 128
 _FUSED_TILES = (512, 256, 128)  # largest the length divides, measured first
@@ -1257,6 +1268,21 @@ def _fused_specs(pl, block_q, block_kv, hp, ql):
     )
 
 
+def fused_attention_results(q, v):
+    """The shapes of what the forward kernel writes, and its ``out_shape``:
+    the output ``[B, T, H D]`` and the scores' log-sum-exp ``[B, H D /
+    128, 128 / D, T]`` float32.  What the backward kernel needs beside the
+    inputs and the output's cotangent, and so what a caller that
+    recomputes the call's surroundings keeps to have no forward kernel in
+    its backward pass."""
+    B, T, H, D = v.shape
+    like = functools.partial(jax.ShapeDtypeStruct, vma=_vma(q))
+    return (
+        like((B, T, H * D), q.dtype),
+        like((B, H * D // _LANES, _LANES // D, T), jnp.float32),
+    )
+
+
 def _fused_forward(
     q, k, v, *, causal, scale, block_q, block_kv, interpret, window=None
 ):
@@ -1273,7 +1299,6 @@ def _fused_forward(
     qspec, kspec, vspec, ospec, rowspec = _fused_specs(
         pl, block_q, block_kv, hp, ql
     )
-    vma = _vma(q)
     out, lse = pl.pallas_call(
         functools.partial(
             _fused_fwd_kernel, scale=_scale(q, scale), causal=causal,
@@ -1291,10 +1316,7 @@ def _fused_forward(
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, H * D), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, HB, hp, T), jnp.float32, vma=vma),
-        ],
+        out_shape=list(fused_attention_results(q, v)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FUSED_VMEM_BYTES,
@@ -1396,7 +1418,7 @@ def auto_route(
     return "blockwise"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def fused_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1407,6 +1429,7 @@ def fused_attention(
     block_kv: Optional[int] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    kept_as: Optional[str] = None,
 ) -> jax.Array:
     """The fused self-attention kernels (see the section comment), BTHD
     in and out; what ``attention(impl="auto")`` runs on a TPU for the
@@ -1415,22 +1438,27 @@ def fused_attention(
     ``window`` (with ``causal``): a query sees the last ``window``
     positions, itself among them, and the block pairs wholly outside that
     are never run; ``interpret=True`` runs the same kernels on the CPU for
-    tests."""
+    tests.  ``kept_as``: the ``checkpoint_name`` the forward rule gives
+    :func:`fused_attention_results`, None for none (:func:`attention`'s
+    ``keep``)."""
     return _fused_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
         block_kv=block_kv, interpret=interpret, window=window,
     )[0]
 
 
-def _fused_fwd(q, k, v, causal, scale, block_q, block_kv, interpret, window):
+def _fused_fwd(q, k, v, causal, scale, block_q, block_kv, interpret, window, kept_as):
     out, lse = _fused_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q,
         block_kv=block_kv, interpret=interpret, window=window,
     )
+    if kept_as is not None:
+        out, lse = checkpoint_name(out, kept_as), checkpoint_name(lse, kept_as)
     return out, (q, k, v, out, lse)
 
 
-def _fused_bwd(causal, scale, block_q, block_kv, interpret, window, res, g):
+def _fused_bwd(causal, scale, block_q, block_kv, interpret, window, kept_as, res, g):
+    del kept_as  # the forward rule's
     q, k, v, out, lse = res
     return _fused_backward(
         q, k, v, out, lse, g, causal=causal, scale=scale, block_q=block_q,
@@ -1454,6 +1482,7 @@ def attention(
     scale: Optional[float] = None,
     impl: str = "auto",
     window: Optional[int] = None,
+    keep=lambda results: None,
 ) -> jax.Array:
     """Dispatching entry point: ``impl`` in {auto, reference, blockwise}.
 
@@ -1468,13 +1497,24 @@ def attention(
     :func:`blockwise_attention`.  The choice is counted once per traced
     call (``attention/route_fused`` / ``attention/route_blockwise``).  A
     named ``impl`` means what it says.  A call with a ``window`` runs
-    under ``swa_core`` inside the core's scope, whatever the route."""
+    under ``swa_core`` inside the core's scope, whatever the route.
+
+    ``keep`` is shown the shapes of :func:`fused_attention_results` where
+    the fused kernels run and says under which ``checkpoint_name`` their
+    forward rule hands them on, or None (the default) for as they are: a
+    caller whose ``jax.checkpoint`` saves that name
+    (``models/remat.py::kept_core``) holds the forward kernel once in its
+    differentiated program and not twice.  It is asked here, where the
+    caller is being traced: the rule is traced when the call is
+    differentiated, after the caller's function has returned.  The other
+    routes have no rule of their own whose residual could be named and do
+    not ask."""
     windowed = contextlib.nullcontext() if window is None else jax.named_scope(SWA_CORE_SCOPE)
     with windowed:
-        return _attention(q, k, v, causal, scale, impl, window)
+        return _attention(q, k, v, causal, scale, impl, window, keep)
 
 
-def _attention(q, k, v, causal, scale, impl, window):
+def _attention(q, k, v, causal, scale, impl, window, keep):
     if impl == "auto":
         impl = auto_route(q, k, v, window=window, causal=causal)
         get_registry().counter(
@@ -1487,7 +1527,8 @@ def _attention(q, k, v, causal, scale, impl, window):
                 scale = _scale(q, scale)
                 widen = ((0, 0),) * 3 + ((0, -q.shape[-1] % _LANES),)
                 q, k = jnp.pad(q, widen), jnp.pad(k, widen)
-            return fused_attention(q, k, v, causal, scale, window=window)
+            kept_as = keep(fused_attention_results(q, v))
+            return fused_attention(q, k, v, causal, scale, window=window, kept_as=kept_as)
     if impl == "reference":
         return reference_attention(
             q, k, v, causal=causal, scale=scale, window=window

@@ -80,8 +80,15 @@ yardstick keep meaning the core.
 The backward pass of the plain routes is autodiff through all of it
 except ``T``, whose cotangent is ``-T^T dT T^T`` (no pass through the
 substitution); the kernels' is by hand (the section comment below).
-What either keeps is per chunk (the state at each chunk's start and
-``T``; the plain routes also ``U``), never a state per token.
+What the plain routes keep is per chunk (the state at each chunk's start,
+``T`` and ``U``); the kernels keep ``T`` per chunk and one state a grid
+step's token block (eight chunks at most), from which the backward kernel
+walks the block's chunks forward again (:func:`_carry_terms`: what carries
+the state given ``T``, a fraction of a forward pass); never a state per
+token.  That residual and the output are small enough for a caller that
+recomputes the call's surroundings to keep (:func:`kernel_kda_results`,
+:func:`chunked_kda_flat`'s ``keep``): its backward pass then runs no
+forward kernel.
 """
 
 from __future__ import annotations
@@ -93,6 +100,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -374,10 +382,19 @@ def plain_gdn(
 # either side; :func:`kernel_kda` folds ``[B, T, H, d]`` arguments, which
 # on the chip's ``(8, 128)`` tiles is a relayout and not a bitcast (PERF.md,
 # PR 33).  Per chunk nothing leaves VMEM but the output and,
-# where a backward pass will follow, the state at the chunk's start and
-# ``T``.  The backward kernel sweeps the token blocks in reverse with the
-# state's cotangent in scratch and makes every other intermediate of a
-# chunk again from the inputs and what was kept.
+# where a backward pass will follow, ``T``; per grid step, then, the state
+# at the token block's start too (a state a chunk was 537 MB a layer at
+# ``kimi_linear_train``'s call, two thirds of what the backward was
+# handed; a state a block of eight chunks is 67 MB: ISSUE 47).  The
+# backward kernel sweeps the token blocks in reverse with the state's
+# cotangent in scratch.  At the start of a grid step it walks the block's
+# chunks forward once from the kept state and puts every chunk's start
+# state into scratch: ``G``, ``e^G``, ``w = T (b k e^G)``, ``u = T (b v) - w
+# S^T``, ``S <- S e^{G_end} + u^T (k e^{G_end - G})``, the forward kernel's
+# own expressions in its order and dtypes (:func:`_carry_terms`), so its
+# states bit for bit, with no pair loop, no level products and no output.
+# The reverse sweep then makes every other intermediate of a chunk again
+# from the inputs and what was kept.
 #
 # A chunk and head is a chain of some dozen small dependent products, so
 # the kernels are bound by that chain's latency and by the passes of its
@@ -494,8 +511,6 @@ def _chunk_terms(q, k, v, g, b, St, G_scr, kf_scr, X_scr, a, *, sub, T=None):
     G = _sum_rows(lower, g.astype(_F32))  # the running sum
     G_scr[a] = G
     kf_scr[a] = kf
-    E = jnp.exp(G)
-    G_end = G[C - 1:, :]
     trow = lax.broadcasted_iota(jnp.int32, (C, dk), 0)
     in_block = trow & (sub - 1)
 
@@ -554,6 +569,25 @@ def _chunk_terms(q, k, v, g, b, St, G_scr, kf_scr, X_scr, a, *, sub, T=None):
         size *= 2
     T = X if T is None else T
 
+    x = _carry_terms(kf, vf, G, b, St, T, dtype)
+    qi32 = qf * x.E
+    return types.SimpleNamespace(
+        qf=qf, kf=kf, vf=vf, G=G, scale_t=scale_t, kt=kt, qt=qt, es=es, ks=ks,
+        kk_strict=kk_strict, qk=qk, T=T, qi32=qi32, qi=qi32.astype(dtype),
+        row=row, col=col, same_block=same_block, in_block=in_block,
+        **vars(x),
+    )
+
+
+def _carry_terms(kf, vf, G, b, St, T, dtype):
+    """What carries the state ``S^T`` over a chunk once ``T`` is there:
+    ``u`` (what the delta rule writes) and the factors of the update, from
+    the float32 keys and values ``[C, d]``, the running sum ``G``, ``b``
+    ``[C, 1]`` and the state at the chunk's start.  The forward pass's own
+    expressions (:func:`_chunk_terms` ends in them), so the backward
+    kernel's walk over a block's chunks makes the forward's states."""
+    E = jnp.exp(G)
+    G_end = G[G.shape[0] - 1:, :]
     Tb = T.astype(dtype)
     rv = (b * vf).astype(dtype)
     rk32 = b * kf * E
@@ -564,14 +598,15 @@ def _chunk_terms(q, k, v, g, b, St, G_scr, kf_scr, X_scr, a, *, sub, T=None):
     u = (u_own - _mm(w, Sd, _NT)).astype(dtype)
     e_end = jnp.exp(G_end - G)
     ke32 = kf * e_end
-    qi32 = qf * E
     return types.SimpleNamespace(
-        qf=qf, kf=kf, vf=vf, G=G, E=E, e_end=e_end, decay=jnp.exp(G_end),
-        scale_t=scale_t, kt=kt, qt=qt, es=es, ks=ks, kk_strict=kk_strict,
-        qk=qk, T=T, Tb=Tb, rv=rv, rk32=rk32, rk=rk, w=w, Sd=Sd, u=u,
-        ke32=ke32, ke=ke32.astype(dtype), qi32=qi32, qi=qi32.astype(dtype),
-        row=row, col=col, same_block=same_block, in_block=in_block,
+        E=E, e_end=e_end, decay=jnp.exp(G_end), Tb=Tb, rv=rv, rk32=rk32,
+        rk=rk, w=w, Sd=Sd, u=u, ke32=ke32, ke=ke32.astype(dtype),
     )
+
+
+def _next_state(St, x):
+    """``S^T`` at the chunk's end from :func:`_carry_terms`' ``x``."""
+    return St * x.decay + _mm(x.u, x.ke, _TN)
 
 
 def _beta_column(b_ref, rows, h):
@@ -587,10 +622,10 @@ def _kda_fwd_kernel(
 ):
     """Grid (B, token blocks, H / heads).  ``S_scr`` ``[H, dv, dk]`` holds
     every head's ``S^T``; ``s_ref`` and ``t_ref`` (kept for a backward
-    pass) take the state at each chunk's start and its ``T``.  The
-    ``heads`` of a step are independent chains of small dependent
-    products: written one after the other in one block of code, the
-    compiler's scheduler runs them side by side."""
+    pass) take the state at the token block's start and every chunk's
+    ``T``.  The ``heads`` of a step are independent chains of small
+    dependent products: written one after the other in one block of code,
+    the compiler's scheduler runs them side by side."""
     s_ref, t_ref = rest[:2] if keep_states else (None, None)
     S_scr, G_scr, kf_scr, X_scr = rest[-4:]
     dk, dv = G_scr.shape[-1], S_scr.shape[-2]
@@ -600,13 +635,14 @@ def _kda_fwd_kernel(
     def _start():
         S_scr[pl.ds(first, heads)] = jnp.zeros((heads,) + S_scr.shape[1:], _F32)
 
+    if keep_states:
+        s_ref[0, 0] = S_scr[pl.ds(first, heads)]
+
     def one_chunk(c, _):
         rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
         for a in range(heads):
             kl, vl = slice(a * dk, (a + 1) * dk), slice(a * dv, (a + 1) * dv)
             St = S_scr[first + a]
-            if keep_states:
-                s_ref[0, c, a] = St
             x = _chunk_terms(
                 q_ref[0, rows, kl], k_ref[0, rows, kl], v_ref[0, rows, vl],
                 g_ref[0, rows, kl], _beta_column(b_ref, rows, first + a), St,
@@ -616,16 +652,17 @@ def _kda_fwd_kernel(
                 t_ref[0, c, a] = x.T
             out = _mm(x.qi, x.Sd, _NT) + _mm(x.qk.astype(x.u.dtype), x.u)
             o_ref[0, rows, vl] = (scale * out).astype(o_ref.dtype)
-            S_scr[first + a] = St * x.decay + _mm(x.u, x.ke, _TN)
+            S_scr[first + a] = _next_state(St, x)
         return 0
 
     lax.fori_loop(0, chunks, one_chunk, 0)
 
 
 def _kernel_geometry(q, v, beta, chunk):
-    """From the flat views ``[B, T, H * d]`` and ``beta`` ``[B, T, H]``."""
+    """From the flat views ``[B, T, H * d]`` and ``beta`` ``[B, T, H]``;
+    ``n`` whole chunks (the kernels are handed the length padded to them)."""
     B, T, H = beta.shape
-    n = T // chunk
+    n = -(-T // chunk)
     chunks = next(m for m in _KERNEL_BLOCK_CHUNKS if n % m == 0)
     heads = next(m for m in _KERNEL_HEADS if H % m == 0)
     return B, T, H, q.shape[-1] // H, v.shape[-1] // H, n, chunks, heads
@@ -634,7 +671,7 @@ def _kernel_geometry(q, v, beta, chunk):
 def _kernel_specs(chunk, H, dk, dv, chunks, heads, order):
     """Block specs over the grid (batch, token block, head group): ``[block,
     heads * d]`` tiles of the ``[B, T, H * d]`` views, the ``[block, H]``
-    tile of ``beta``, the ``chunks`` states of a block and their ``T``;
+    tile of ``beta``, a block's state and its ``chunks`` chunks' ``T``;
     ``order`` maps the grid's token block to the array's (the backward
     sweeps in reverse)."""
     block = chunks * chunk
@@ -645,7 +682,7 @@ def _kernel_specs(chunk, H, dk, dv, chunks, heads, order):
         tile(dk), tile(dv),
         pl.BlockSpec((1, block, H), lambda b, t, h: (b, order(t), 0)),
         pl.BlockSpec(
-            (1, chunks, heads, dv, dk), lambda b, t, h: (b, order(t), h, 0, 0)
+            (1, 1, heads, dv, dk), lambda b, t, h: (b, order(t), h, 0, 0)
         ),
         pl.BlockSpec(
             (1, chunks, heads, chunk, chunk),
@@ -666,21 +703,14 @@ def _kernel_scratch(H, dk, dv, chunk, heads):
 
 
 def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpret):
-    """``(out [B, T, H * dv], kept)`` from the flat views, ``kept`` the
-    states ``[B, T/chunk, H, dv, dk]`` and every chunk's ``T`` ``[B,
-    T/chunk, H, chunk, chunk]`` (float32) or ``()``; ``T`` a multiple of
-    ``chunk``."""
+    """The output ``[B, T, H * dv]`` from the flat views and, with
+    ``keep_states``, the rest of :func:`kernel_kda_results`; ``T`` a
+    multiple of ``chunk``."""
     B, T, H, dk, dv, n, chunks, heads = _kernel_geometry(q, v, beta, chunk)
     kspec, vspec, bspec, sspec, tspec = _kernel_specs(
         chunk, H, dk, dv, chunks, heads, lambda t: t
     )
-    vma = _vma(q)
-    out_shape = [jax.ShapeDtypeStruct((B, T, H * dv), v.dtype, vma=vma)]
-    out_specs = [vspec]
-    if keep_states:
-        out_shape.append(jax.ShapeDtypeStruct((B, n, H, dv, dk), _F32, vma=vma))
-        out_shape.append(jax.ShapeDtypeStruct((B, n, H, chunk, chunk), _F32, vma=vma))
-        out_specs += [sspec, tspec]
+    results = (vspec, sspec, tspec) if keep_states else (vspec,)
     call = pl.pallas_call(
         functools.partial(
             _kda_fwd_kernel, scale=scale, chunk=chunk, sub=sub, chunks=chunks,
@@ -688,8 +718,8 @@ def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpr
         ),
         grid=(B, n // chunks, H // heads),
         in_specs=[kspec, kspec, vspec, kspec, bspec],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=list(results),
+        out_shape=list(kernel_kda_results(q, v, beta, chunk)[:len(results)]),
         scratch_shapes=_kernel_scratch(H, dk, dv, chunk, heads),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
@@ -698,21 +728,23 @@ def _kernel_forward(q, k, v, g, beta, *, scale, chunk, sub, keep_states, interpr
         interpret=interpret,
     )
     with jax.named_scope(KDA_CORE_SCOPE):
-        res = call(q, k, v, g, beta)
-    return res[0], tuple(res[1:])
+        return tuple(call(q, k, v, g, beta))
 
 
 def _kda_bwd_kernel(
     q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, t_ref, do_ref,
     dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
-    dS_scr, G_scr, kf_scr, X_scr, dkc_scr,
+    dS_scr, G_scr, kf_scr, X_scr, dkc_scr, S_scr,
     *, scale, chunk, sub, chunks, heads,
 ):
     """Grid (B, token blocks in reverse, H / heads).  ``dS_scr`` ``[H, dv, dk]``
     holds the cotangent of every head's ``S^T`` at the end of the chunk
     being worked on; ``dkc_scr`` takes the rows of the pair loop's key
-    cotangent.  ``db_ref`` is the ``[block, H]`` tile every head of the
-    block writes its column of."""
+    cotangent; ``S_scr`` ``[heads, chunks, dv, dk]`` the state at the start
+    of each of the block's chunks, walked forward from the one state the
+    block was handed (``s_ref``) before the reverse sweep reads them.
+    ``db_ref`` is the ``[block, H]`` tile every head of the block writes
+    its column of."""
     first = pl.program_id(2) * heads
     n = chunk // sub
     dk, dv = G_scr.shape[-1], dS_scr.shape[-2]
@@ -721,10 +753,43 @@ def _kda_bwd_kernel(
     def _start():
         dS_scr[pl.ds(first, heads)] = jnp.zeros((heads,) + dS_scr.shape[1:], _F32)
 
+    def chunk_rows(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    # The chunks' states again: what carries ``S`` from chunk to chunk
+    # given ``T`` (no pair loop, no level products, no output), the
+    # forward kernel's expressions, so its states bit for bit.
+    for a in range(heads):
+        S_scr[a, 0] = s_ref[0, 0, a]
+
+    def walk(c, _):
+        rows = chunk_rows(c)
+        lower = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1) <= lax.broadcasted_iota(
+            jnp.int32, (chunk, chunk), 0
+        )
+        for a in range(heads):
+            kl, vl = slice(a * dk, (a + 1) * dk), slice(a * dv, (a + 1) * dv)
+            v = v_ref[0, rows, vl]
+            St = S_scr[a, c]
+            x = _carry_terms(
+                k_ref[0, rows, kl].astype(_F32), v.astype(_F32),
+                _sum_rows(lower, g_ref[0, rows, kl].astype(_F32)),
+                _beta_column(b_ref, rows, first + a), St, t_ref[0, c, a], v.dtype,
+            )
+            S_scr[a, c + 1] = _next_state(St, x)
+        return 0
+
+    # Unrolled, so that what a chunk's walk makes without the state
+    # (``G``, the exponentials, ``T``'s two products) may be scheduled
+    # beside the chunk before's products with the state: at most seven
+    # short bodies (PERF.md section 6, PR 47, has what was read of it).
+    if chunks > 1:
+        lax.fori_loop(0, chunks - 1, walk, 0, unroll=True)
+
     def one_head(a, c, rows):
         h = first + a
         kl, vl = slice(a * dk, (a + 1) * dk), slice(a * dv, (a + 1) * dv)
-        St = s_ref[0, c, a]
+        St = S_scr[a, c]
         b = _beta_column(b_ref, rows, h)
         v = v_ref[0, rows, vl]
         dtype = v.dtype
@@ -821,9 +886,8 @@ def _kda_bwd_kernel(
 
     def one_chunk(i, _):
         c = chunks - 1 - i
-        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
         for a in range(heads):
-            one_head(a, c, rows)
+            one_head(a, c, chunk_rows(c))
         return 0
 
     lax.fori_loop(0, chunks, one_chunk, 0)
@@ -847,7 +911,10 @@ def _kernel_backward(q, k, v, g, beta, states, ts, do, *, scale, chunk, sub, int
         out_specs=[kspec, kspec, vspec, kspec, bspec],
         out_shape=[like(q), like(k), like(v), like(g), like(beta)],
         scratch_shapes=_kernel_scratch(H, dk, dv, chunk, heads)
-        + [pltpu.VMEM((heads, chunk, dk), _F32)],
+        + [
+            pltpu.VMEM((heads, chunk, dk), _F32),
+            pltpu.VMEM((heads, chunks, dv, dk), _F32),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BYTES,
@@ -862,33 +929,57 @@ def _padded(x, pad):
     return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def kernel_kda_flat(q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def kernel_kda_flat(
+    q, k, v, g, beta, scale=None, chunk=_KERNEL_CHUNK, interpret=False, kept_as=None
+):
     """The chunk-wise delta rule as Pallas kernels (section comment above),
     forward and backward, on the flat views the kernels read: ``q``, ``k``,
     ``g`` ``[B, T, H * dk]``, ``v`` ``[B, T, H * dv]``, ``beta`` ``[B, T,
     H]``; returns ``[B, T, H * dv]``.  For a caller that holds such views
     (the fused route of ``models/mixers.py::KDAMixer``): no ``[B, T, H,
     d]`` array exists on either side.  ``interpret=True`` runs the same
-    kernels on the CPU for tests."""
-    return _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, False)[0]
+    kernels on the CPU for tests.  ``kept_as``: the ``checkpoint_name``
+    the forward rule gives :func:`kernel_kda_results`, None for none
+    (:func:`chunked_kda_flat`)."""
+    return _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, None, False)[0]
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
-def _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, keep_states=True):
+def kernel_kda_results(q, v, beta, chunk=_KERNEL_CHUNK):
+    """The shapes of what the forward kernel writes where a backward pass
+    will follow, from the flat views: the output (the length in whole
+    chunks), the state ``S^T`` at the start of each grid step's token
+    block and every chunk's ``T``, float32 both.  What the backward
+    kernel needs beside the inputs and the output's cotangent, and so what
+    a caller that recomputes the call's surroundings keeps to have no
+    forward kernel in its backward pass."""
+    B, T, H, dk, dv, n, chunks, _ = _kernel_geometry(q, v, beta, chunk)
+    like = functools.partial(jax.ShapeDtypeStruct, vma=_vma(q))
+    return (
+        like((B, n * chunk, H * dv), v.dtype),
+        like((B, n // chunks, H, dv, dk), _F32),
+        like((B, n, H, chunk, chunk), _F32),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _kernel_fwd(q, k, v, g, beta, scale, chunk, interpret, kept_as, keep_states=True):
     T, H = beta.shape[1:]
     scale = (q.shape[-1] // H) ** -0.5 if scale is None else scale
     pad = -T % chunk
     padded = tuple(_padded(x, pad) for x in (q, k, v, g, beta))
-    out, kept = _kernel_forward(
+    out, *kept = _kernel_forward(
         *padded, scale=scale, chunk=chunk, sub=_KERNEL_SUB,
         keep_states=keep_states, interpret=interpret,
     )
-    return out[:, :T], padded + kept
+    if kept_as is not None:
+        out, *kept = (checkpoint_name(x, kept_as) for x in (out, *kept))
+    return out[:, :T], padded + tuple(kept)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _kernel_bwd(scale, chunk, interpret, res, do):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _kernel_bwd(scale, chunk, interpret, kept_as, res, do):
+    del kept_as  # the forward rule's
     T, H = do.shape[1], res[4].shape[-1]
     scale = (res[0].shape[-1] // H) ** -0.5 if scale is None else scale
     grads = _kernel_backward(
@@ -1464,13 +1555,24 @@ def kda_mixer_route(xq, xk, xv, *, heads: int, taps: int) -> str:
 
 
 @jax.named_scope(KDA_CORE_SCOPE)
-def chunked_kda_flat(q, k, v, g, beta, *, scale: Optional[float] = None, interpret: bool = False):
+def chunked_kda_flat(
+    q, k, v, g, beta, *, scale: Optional[float] = None, interpret: bool = False,
+    keep=lambda results: None,
+):
     """:func:`chunked_kda` for a caller on the fused route
     (:func:`kda_mixer_route` said so: the kernels take the call), on the
     flat views ``[B, T, H * d]`` and ``beta`` ``[B, T, H]``, under the
-    same scope and counted as the same route."""
+    same scope and counted as the same route.  ``keep`` is shown the
+    shapes of :func:`kernel_kda_results` and says under which
+    ``checkpoint_name`` the forward rule hands them on, or None (the
+    default) for as they are: a caller whose ``jax.checkpoint`` saves that
+    name (``models/remat.py::kept_core``) holds the forward kernel once in
+    its differentiated program and not twice.  It is asked here, where the
+    caller is being traced: the rule is traced when the call is
+    differentiated, after the caller's function has returned."""
     get_registry().counter(KDA_ROUTE_KERNEL).inc()
-    return kernel_kda_flat(q, k, v, g, beta, scale, _KERNEL_CHUNK, interpret)
+    kept_as = keep(kernel_kda_results(q, v, beta))
+    return kernel_kda_flat(q, k, v, g, beta, scale, _KERNEL_CHUNK, interpret, kept_as)
 
 
 # --- One decay a head and step: the gated delta rule -----------------------

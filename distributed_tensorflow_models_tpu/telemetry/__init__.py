@@ -73,6 +73,7 @@ from distributed_tensorflow_models_tpu.telemetry.registry import (  # noqa: F401
     PRODUCER_WAIT,
     REASSEMBLY_WAIT,
     REMAT_BYTES_KEPT,
+    REMAT_CORES_KEPT,
     REMAT_PRODUCTS_KEPT,
     RESTARTS,
     ROLLBACKS,

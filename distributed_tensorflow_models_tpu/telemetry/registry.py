@@ -118,6 +118,16 @@ REMAT_BYTES_KEPT = "remat/bytes_kept"  # counter
 # the plan's bytes are in ``remat/bytes_kept``, and it is no product.
 # 0 where every expert is held or nothing is recomputed.
 MOE_PLAN_KEPT = "moe/plan_kept"  # counter
+# Cores (a Pallas kernel pair under a ``custom_vjp``) whose forward
+# results a recomputed half keeps (``models/remat.py::kept_core``; the
+# chunk-wise delta rule's output, block states and ``T``,
+# ``ops/linear_attention.py::chunked_kda_flat``; the fused attention's
+# output and log-sum-exp, ``ops/attention.py::attention``): the
+# differentiated step then holds the core's forward kernel once and not
+# twice.  One increment per traced call of such a core like the routes;
+# the bytes are in ``remat/bytes_kept``.  0 where nothing is recomputed
+# and on the routes that run no kernel.
+REMAT_CORES_KEPT = "remat/cores_kept"  # counter
 # Worker-pool producer (HostPipeline num_workers>1).  WORKER_BUSY is a
 # per-worker utilization gauge family — one gauge per worker at
 # ``pipeline/worker_busy/<i>`` (fraction of wall time spent assembling

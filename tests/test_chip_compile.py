@@ -15,7 +15,7 @@ from an empty cache beside five other workers, ISSUE 41):
 ``olmo_hybrid_train``'s, ``granite_h_train``'s, ``nemotron_h_train``'s and
 ``kimi_linear_train``'s, because those cells' batch, the scan's chunk and
 what a recomputed half keeps (``models/remat.py``: every one of the four
-at 15.0 GiB or under, ISSUE 42) were chosen by what the compiler places;
+at 15.0 GiB or under, ISSUEs 42 and 47) were chosen by what the compiler places;
 the chip run of every PR holds the same guard, and the tier-1 run keeps
 three of the cells' steps at one period of their layers.  A route
 or a kernel count does not depend on the length beyond two chunks, so a
@@ -83,6 +83,24 @@ def one_chip(v5e, monkeypatch):
 def _mosaic_kernels(text):
     """The compiled text's lines of Mosaic kernels (interpret mode leaves none)."""
     return [line for line in text.splitlines() if "tpu_custom_call" in line and "pallas_call" in line]
+
+
+def _core_kernels(text, scope):
+    """``(in the forward pass, in the backward pass)``: the compiled
+    text's Mosaic kernels under ``scope``, the backward pass's those under
+    a ``transpose(``, a recomputed half's second forward pass among them.
+    A core whose results a recomputed half keeps (``models/remat.py``) is
+    there once and once a layer (its forward kernel, its backward kernel);
+    one the half runs again, once and twice."""
+    lines = [line for line in _mosaic_kernels(text) if re.search(rf"[/(]{scope}[/)]", line)]
+    backward = sum("transpose(" in line for line in lines)
+    return len(lines) - backward, backward
+
+
+def _cores_kept():
+    from distributed_tensorflow_models_tpu.telemetry import registry as reglib
+
+    return reglib.get_registry().counter(reglib.REMAT_CORES_KEPT).value
 
 
 def _as_the_comparison_runs(dtype):
@@ -501,21 +519,21 @@ def _cell_step_compiled(one_chip, cell_name, period=None):
 _ONE_PERIOD = {
     "olmo_hybrid_train": dict(
         period=("gdn", "attention"), ssd_kernel_calls=0,
-        # The attention layer: forward, the recomputed forward, the backward.
-        custom_calls=3,
+        # The attention layer: forward and backward (the half keeps what the forward writes).
+        custom_calls=2,
         scopes=("linear_attn", "gdn_core", "attention_core", "unembed_loss", "optimizer"),
     ),
     "granite_h_train": dict(
         # One state-space layer, ``model.init`` and the step.
         period=("ssm", "attention"), ssd_kernel_calls=2,
-        # The scan and the attention layer, each forward, recomputed and backward.
-        custom_calls=6,
+        # The scan forward, recomputed and backward; the attention layer forward and backward.
+        custom_calls=5,
         scopes=("ssm", "ssd_core", "attention_core", "unembed_loss", "optimizer"),
     ),
     "nemotron_h_train": dict(
         period=("ssm_only", "ffn_only", "attention_only"), ssd_kernel_calls=2,
         # The same two, and the experts' grouped products.
-        custom_calls=7,
+        custom_calls=6,
         scopes=("ssm", "ssd_core", "moe", "moe_dispatch", "moe_experts", "moe_shared", "attention_core",
                 "unembed_loss", "optimizer"),
     ),
@@ -536,20 +554,26 @@ def test_a_cell_s_step_at_one_period_of_its_layers_takes_its_routes_for_v5e(one_
     """The quick sibling of the three whole steps below (which are
     ``slow``): the cell's configuration with one layer of each kind and a
     vocabulary of 2,048, the same sequence of 8,192, compiled for one
-    described v5e.  The kernel routes are taken (a recomputed half keeps
-    its wide input products and no kernel's output, so every kernel is
-    still there forward, recomputed and backward), the compiler
-    rematerializes nothing of its own and every scope the per-layer
-    readers look for is on the step; what it holds is the whole step's
-    and the chip run's to say."""
+    described v5e.  The kernel routes are taken (a recomputed half of these
+    cells keeps its wide input products and what its attention core's
+    forward kernel writes, so that core is there once forward and once
+    backward, and every other kernel forward, recomputed and backward),
+    the compiler rematerializes nothing of its own and every scope the
+    per-layer readers look for is on the step; what it holds is the whole
+    step's and the chip run's to say."""
     want = _ONE_PERIOD[cell_name]
+    kept = _cores_kept()
     compiled, _, state, kernel_route = _cell_step_compiled(one_chip, cell_name, want["period"])
+    assert _cores_kept() - kept == 2  # the attention layer's, ``model.init`` and the step
     assert sorted(k for k in state.params if k.startswith("blocks_")) == [
         f"blocks_{i}" for i in range(len(want["period"]))
     ]
     assert kernel_route == want["ssd_kernel_calls"]
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= want["custom_calls"]
+    assert _core_kernels(text, "attention_core") == (1, 1)
+    if want["ssd_kernel_calls"]:
+        assert _core_kernels(text, "ssd_core") == (1, 2)
     _scopes_are_on(text, cell_name)
 
 
@@ -560,15 +584,18 @@ def test_olmo_hybrid_step_compiles_for_v5e_under_its_memory(one_chip):
     head, each half recomputed but for the three products of its
     feed-forward, which the post-norm makes it keep, one sequence of
     8,192) for one described v5e: it fits the chip's 15.75 GiB with room
-    (13.54 GiB: 8.56 of state, 4.80 of temporaries; PERF.md, PR 42; 13.15
-    when a half kept its input alone), the compiler rematerializes nothing
-    of its own, the attention layer runs the fused kernels and the three
-    scopes are on the step."""
+    (13.54 GiB: 8.56 of state, 4.80 of temporaries, my chip-less compile,
+    PR 47; 13.54 before a half kept what its attention core's forward
+    kernel writes, 31 MB, too: PERF.md, PR 42; 13.15 when a half kept its
+    input alone), the compiler rematerializes nothing of its own, the
+    attention layer runs the fused kernels, forward once and backward once,
+    and the three scopes are on the step."""
+    kept = _cores_kept()
     compiled, held, _, _ = _cell_step_compiled(one_chip, "olmo_hybrid_train")
+    assert _cores_kept() - kept == 2  # ``model.init`` and the step
     assert 12.5 < held < 14.5, held
     text = compiled.as_text()
-    # The attention layer: forward, the recomputed forward, the backward.
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == 2 and _core_kernels(text, "attention_core") == (1, 1)
     _scopes_are_on(text, "olmo_hybrid_train")
 
 
@@ -623,21 +650,27 @@ def test_granite_h_step_compiles_for_v5e_under_its_memory(one_chip):
     the tied embedding, each half recomputed but for the feed-forwards'
     ``gate`` and ``up`` and the state-space mixers' ``in_proj``, which it
     keeps, one sequence of 8,192, the scan's chunk of 256) for one
-    described v5e: it fits the chip's 15.75 GiB with room (14.17 GiB: 8.63
-    of state, 5.50 of temporaries; PERF.md, PR 42; 11.24 when a half kept
+    described v5e: it fits the chip's 15.75 GiB with room (14.21 GiB: 8.63
+    of state, 5.53 of temporaries, my chip-less compile, PR 47;
+    14.17 before a half kept what its attention core's forward kernel
+    writes, 34 MB: PERF.md, PR 42; 11.24 when a half kept
     its input alone, 12.13 when the cell was added; at a chunk of 64 it
     did not fit without 254 rematerialized clones: PR 38), the compiler
     rematerializes nothing of its own, the attention layer runs the fused
-    kernels over its grouped heads and the scopes are on the step."""
+    kernels over its grouped heads, forward once and backward once, and
+    the scopes are on the step."""
+    kept = _cores_kept()
     compiled, held, state, kernel_route = _cell_step_compiled(one_chip, "granite_h_train")
+    assert _cores_kept() - kept == 2  # ``model.init`` and the step
     assert "head" not in state.params  # tied
     # Nine state-space layers, ``model.init`` and the step: the generalised
     # scan (groups of heads, PR 40) still takes its kernels at one group.
     assert kernel_route == 18
     assert 13.2 < held < 15.0, held
     text = compiled.as_text()
-    # The attention layer: forward, the recomputed forward, the backward.
-    assert text.count("tpu_custom_call") >= 3
+    # Nine scans forward, recomputed and backward; the attention layer forward and backward.
+    assert _core_kernels(text, "ssd_core") == (9, 18) and _core_kernels(text, "attention_core") == (1, 1)
+    assert text.count("tpu_custom_call") == 29
     _scopes_are_on(text, "granite_h_train")
 
 
@@ -648,22 +681,27 @@ def test_nemotron_h_step_compiles_for_v5e_under_its_memory(one_chip):
     of the vocabulary, Adam with the clip, the fused head, every layer
     recomputed but for the mixers' ``in_proj`` and the shared experts'
     ``up``, one sequence of 8,192) for one described v5e: it fits the
-    chip's 15.75 GiB with room (11.21 GiB: 7.45 of state, 3.58 of
-    temporaries; PERF.md, PR 42; 10.97 when the cell was added), the compiler
+    chip's 15.75 GiB with room (10.91 GiB: 7.45 of state, 3.30 of
+    temporaries, my chip-less compile, PR 47; 11.21 before a half kept
+    what its attention core's forward kernel writes, 67 MB: PERF.md, PR 42;
+    10.97 when the cell was added), the compiler
     rematerializes nothing of its own, the four state-space layers take
     the grouped scan's kernels (``model.init`` and the step: 8), the
-    attention layer the fused kernels over sixteen-fold groups, and the
-    scopes of every piece are on the step."""
+    attention layer the fused kernels over sixteen-fold groups, forward
+    once and backward once, and the scopes of every piece are on the step."""
+    kept = _cores_kept()
     compiled, held, state, kernel_route = _cell_step_compiled(one_chip, "nemotron_h_train")
+    assert _cores_kept() - kept == 2  # ``model.init`` and the step
     assert sorted(state.params["blocks_0"]) == ["ln1", "ssm"] and sorted(state.params["blocks_1"]) == ["ln2", "moe"]
     assert "w_gate" not in state.params["blocks_1"]["moe"] and "head" in state.params
     assert sum(x.size for x in jax.tree.leaves(state.params)) == 666_962_944
     assert kernel_route == 8
-    assert 10.2 < held < 12.5, held
+    assert 9.9 < held < 11.9, held
     text = compiled.as_text()
-    # Four scans and the attention layer, each forward, recomputed and
-    # backward; the experts' grouped products.
-    assert text.count("tpu_custom_call") >= 15
+    # Four scans forward, recomputed and backward, the attention layer
+    # forward and backward; the experts' grouped products.
+    assert _core_kernels(text, "ssd_core") == (4, 8) and _core_kernels(text, "attention_core") == (1, 1)
+    assert text.count("tpu_custom_call") >= 14
     _scopes_are_on(text, "nemotron_h_train")
 
 
@@ -672,27 +710,37 @@ def test_kimi_linear_step_compiles_for_v5e_under_its_memory(one_chip):
     """The whole ``kimi_linear_train`` step (published layers 1-5 at the
     published widths, 8 of 256 experts held, an eighth of the vocabulary,
     Adam with the clip, the fused head, each half recomputed but for the
-    dense layer's and the four shared experts' ``gate`` and ``up``, two
-    sequences of 8,192) for one described v5e: it fits the chip's 15.75
-    GiB with room (12.52 GiB: 6.73 of state, 5.52 of temporaries; PERF.md,
-    PR 42; 12.35 when a half kept its input alone), the compiler
-    rematerializes nothing of its own, the four KDA layers take the delta rule's kernels
-    and the fused passes (``model.init`` and the step: 8 each), and the
-    scopes of every piece are on the step."""
+    dense layer's and the four shared experts' ``gate`` and ``up``, the
+    expert layers' routing plans and what the forward kernels of the four
+    delta-rule cores and of the latent attention's core write, two
+    sequences of 8,192) for one described v5e: it fits the chip's 15.75 GiB
+    with room (13.17 GiB: 6.73 of state, 6.20 of temporaries, my chip-less
+    compile, PR 47; 12.52 before a half kept its core's results,
+    335 MB a KDA layer and 136 MB the latent one: PERF.md, PR 42; 12.35
+    when a half kept its input alone), the compiler rematerializes nothing
+    of its own, the four KDA layers take the delta rule's kernels and the
+    fused passes (``model.init`` and the step: 8 each), all five mixer
+    halves keep their cores' results (10), the step holds each core's
+    forward kernel once, and the scopes of every piece are on the step."""
     from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
-    counters = [reglib.get_registry().counter(name) for name in (reglib.KDA_ROUTE_KERNEL, reglib.KDA_MIXER_FUSED)]
+    counters = [
+        reglib.get_registry().counter(name)
+        for name in (reglib.KDA_ROUTE_KERNEL, reglib.KDA_MIXER_FUSED, reglib.REMAT_CORES_KEPT)
+    ]
     before = [c.value for c in counters]
     compiled, held, state, _ = _cell_step_compiled(one_chip, "kimi_linear_train")
-    assert [c.value - was for c, was in zip(counters, before)] == [8, 8]
+    assert [c.value - was for c, was in zip(counters, before)] == [8, 8, 10]
     assert sorted(state.params["blocks_0"]) == ["linear_attn", "ln1", "ln2", "mlp"]
-    assert 11.5 < held < 13.5, held
+    assert 12.2 < held < 14.2, held
     text = compiled.as_text()
-    # Four KDA layers of three core kernels and fifteen fused passes (each
-    # forward, recomputed and backward), the latent attention's three, and
-    # four expert layers' three grouped products (those three times, and
-    # once more for the weights' gradients).
-    assert text.count("tpu_custom_call") >= 4 * (3 + 15) + 3 + 4 * 3 * 4
+    # Four KDA layers of two core kernels (the forward, whose results the
+    # half keeps, and the backward) and fifteen fused passes (each forward,
+    # recomputed and backward), the latent attention's two, and four
+    # expert layers' three grouped products (those three times, and once
+    # more for the weights' gradients).
+    assert _core_kernels(text, "kda_core") == (4, 4) and _core_kernels(text, "attention_core") == (1, 1)
+    assert text.count("tpu_custom_call") >= 4 * (2 + 15) + 2 + 4 * 3 * 4
     _scopes_are_on(text, "kimi_linear_train", (
         "linear_attn", "kda_core", "kda_pass", "attention_core", "moe", "moe_dispatch", "moe_experts", "moe_shared",
         "unembed_loss", "optimizer",
@@ -766,22 +814,30 @@ def test_phi4_flash_step_compiles_for_v5e_under_its_memory(one_chip):
     the clip, the fused head from the tied embedding, each half recomputed
     but for its wide input products and what the two source layers hand
     on, one sequence of 8,192) for one described v5e: it fits the chip's
-    15.75 GiB with room (12.37 GiB: 7.79 of state, 4.46 of temporaries;
-    PERF.md, PR 44), the compiler rematerializes nothing of its own, both
-    Mamba-1 layers take the scan's kernels and all three attention layers
-    the fused kernels (``model.init`` and the step), no buffer holds a
-    state per token, and the scopes of every piece are on the step."""
+    15.75 GiB with room (12.56 GiB: 7.79 of state, 4.66 of temporaries, my
+    chip-less compile, PR 47; 12.37 before a half kept what its
+    attention core's forward kernel writes, 84 MB a core: PERF.md, PR 44),
+    the compiler rematerializes nothing of its own, both Mamba-1 layers
+    take the scan's kernels and all three attention layers the fused
+    kernels (``model.init`` and the step), each forward once and backward
+    once, the layers that read handed-on keys and values among them, no
+    buffer holds a state per token, and the scopes of every piece are on
+    the step."""
     from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
-    counters = [reglib.get_registry().counter(name) for name in (reglib.SSCAN_ROUTE_KERNEL, reglib.ATTN_ROUTE_FUSED)]
+    counters = [
+        reglib.get_registry().counter(name)
+        for name in (reglib.SSCAN_ROUTE_KERNEL, reglib.ATTN_ROUTE_FUSED, reglib.REMAT_CORES_KEPT)
+    ]
     before = [c.value for c in counters]
     compiled, held, state, _ = _cell_step_compiled(one_chip, "phi4_flash_train")
-    assert [c.value - was for c, was in zip(counters, before)] == [4, 6]
+    assert [c.value - was for c, was in zip(counters, before)] == [4, 6, 6]
     assert sum(x.size for x in jax.tree.leaves(state.params)) == 697_094_272
-    assert 11.5 < held < 13.5, held
+    assert 11.6 < held < 13.6, held
     text = compiled.as_text()
-    # Two scans and three attention cores, each forward, recomputed and backward.
-    assert text.count("tpu_custom_call") == 15
+    # Two scans forward, recomputed and backward; three attention cores forward and backward.
+    assert _core_kernels(text, "sscan_core") == (2, 4) and _core_kernels(text, "attention_core") == (3, 3)
+    assert text.count("tpu_custom_call") == 12
     assert not re.search(r"f32\[(\d+,)*8192,(5120,16|16,5120|16,8,640)\]", text)
     _scopes_are_on(text, "phi4_flash_train", (
         "ssm", "sscan_core", "gmu", "attention_core", "swa_core", "unembed_loss", "optimizer",

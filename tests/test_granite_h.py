@@ -406,7 +406,7 @@ def test_grouped_heads_take_the_fused_route_and_calls_without_groups_are_routed_
     # over their groups), and a call without groups repeats nothing.
     seen = []
 
-    def fake(q, k, v, causal, scale, window=None):
+    def fake(q, k, v, causal, scale, window=None, kept_as=None):
         seen.append((q.shape, k.shape, v.shape, scale))
         return q
 

@@ -11,6 +11,7 @@ case of ``tests/test_lm_fit_smoke.py``; the plain reference's side of it
 ``tests/benchmark/test_bench_reference_kimi_linear.py``.
 """
 
+import contextlib
 import functools
 
 import flax.linen as nn
@@ -165,6 +166,11 @@ KERNEL_CASES = [
     (150, "mixed"),       # three grid steps of one chunk: the carried state
     (72, "near_one"),     # a state that hardly forgets, carried into a remainder
     (384, "mixed"),       # three grid steps of two chunks
+    # One state a grid step's token block is kept (ISSUE 47): the backward
+    # kernel walks a block's chunks forward from it.
+    (50, "mild"),         # one chunk, padded: a block the walk makes nothing for
+    (500, "mixed"),       # one grid step of eight chunks, the last one padded
+    (1500, "near_one"),   # three grid steps of eight chunks: a kept state a block, the last chunk padded
 ]
 
 
@@ -220,7 +226,7 @@ def test_the_kernels_take_keys_of_two_lane_blocks_and_an_odd_number_of_heads():
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
-@pytest.mark.parametrize("T,decay", [(128, "mild"), (150, "mixed")])  # of KERNEL_CASES: their float32 results
+@pytest.mark.parametrize("T,decay", [(128, "mild"), (150, "mixed"), (500, "mixed")])  # of KERNEL_CASES: their float32 results
 def test_the_kernels_in_bf16_are_the_plain_route_in_bf16(T, decay, what):
     """Both routes round the same operands to bfloat16 and accumulate in
     float32; they differ in the order of float32 sums and in which
@@ -236,6 +242,83 @@ def test_the_kernels_in_bf16_are_the_plain_route_in_bf16(T, decay, what):
         scale = float(jnp.abs(e).max())
         assert float(jnp.abs(g - p).max()) <= 0.03 * scale, name
         assert float(jnp.abs(g - e).max()) <= 2 * float(jnp.abs(p - e).max()) + 4e-3 * scale, name
+
+
+def _forward_rule_results(x):
+    """The shapes of the output and of what the kernels' forward rule
+    hands the backward one beside the padded inputs (the states, ``T``)."""
+    B, T = x[4].shape[:2]
+    flat = lambda a: a.reshape(B, T, -1)
+    out, res = jax.eval_shape(
+        lambda *a: linattn._kernel_fwd(*a, None, 64, True, None), *map(flat, x[:4]), x[4]
+    )
+    return (out, *res[5:])
+
+
+@pytest.mark.parametrize(
+    "T, blocks, chunks",
+    [(50, 1, 1), (128, 1, 2), (150, 3, 1), (500, 1, 8), (1500, 3, 8)],
+)
+def test_the_kernels_keep_one_state_a_token_block_and_every_chunks_inverse(T, blocks, chunks):
+    """What the forward rule hands the backward one beside the padded
+    inputs, as traced: the state at the start of each grid step's token
+    block ``[B, n / chunks, H, dv, dk]`` and ``T`` for each of the ``n``
+    chunks; :func:`kernel_kda_results` says the same shapes."""
+    q, k, v, g, beta = x = _kda_inputs(T, T, "mild", B=1, H=2, dk=128, dv=256)
+    flat = lambda a: a.reshape(1, T, -1)
+    out, states, ts = _forward_rule_results(x)
+    assert out.shape == (1, T, 2 * 256)
+    assert states.shape == (1, blocks, 2, 256, 128) and states.dtype == jnp.float32
+    assert ts.shape == (1, blocks * chunks, 2, 64, 64) and ts.dtype == jnp.float32
+    said = linattn.kernel_kda_results(flat(q), flat(v), beta)
+    assert [(x.shape, x.dtype) for x in said] == [
+        ((1, blocks * chunks * 64, 2 * 256), out.dtype), (states.shape, states.dtype), (ts.shape, ts.dtype)
+    ]
+
+
+@contextlib.contextmanager
+def _chunks_a_block(monkeypatch, chunks):
+    """The kernels with ``chunks`` chunks a grid step.  The rules are
+    jitted, so their traces at eight chunks a block go, here and when the
+    patch does."""
+    clear = lambda: [f.clear_cache() for f in (linattn._kernel_fwd, linattn._kernel_bwd)]
+    with monkeypatch.context() as patch:
+        clear()
+        patch.setattr(linattn, "_KERNEL_BLOCK_CHUNKS", (chunks,))
+        try:
+            yield
+        finally:
+            clear()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [1, 2])
+@pytest.mark.parametrize("T,decay", [(500, "mixed")])  # of KERNEL_CASES: eight chunks a block
+def test_the_walked_states_are_the_forward_kernels_bit_for_bit(T, decay, chunks, dtype, monkeypatch):
+    """The backward kernel makes a block's chunks' states again from the
+    one state it is handed and ``T``, with the forward kernel's own
+    expressions: the output and the five cotangents at eight chunks a block
+    (seven states made again) equal, to the last bit, those at one chunk a
+    block, where every chunk's state is the forward kernel's own and
+    nothing is made again, and those at two.  But for one array, which is
+    XLA:CPU's and not the walk's: at one chunk a block the kernels' loop
+    over a block's chunks has one trip and dissolves, the interpreted
+    backward kernel is another program around ``dG``'s sums, and ``dg`` in
+    float32 moves in its last bit (1e-7 of its largest entry; the kernels
+    that kept a state a chunk, ISSUE 47's parent, did the same between
+    eight chunks a block and one, and equal these at eight bit for bit)."""
+    walked = _kda_result("kernel", T, decay, dtype)
+    x = _kda_inputs(T, T, decay, B=1, H=2, dk=128, dv=128)
+    with _chunks_a_block(monkeypatch, chunks):
+        assert _forward_rule_results(x)[1].shape[1] == 8 // chunks
+        out, *grads = _probed(_kernel_route, x, dtype)  # not through the cache of results: the patched kernels'
+    assert jnp.array_equal(out, walked["forward"][0])
+    for name, g, w in zip(_NAMES, grads, walked["gradient"]):
+        assert g.dtype == w.dtype, name
+        if (name, chunks, dtype) == ("g", 1, "float32"):
+            assert float(jnp.abs(g - w).max()) <= 1e-6 * float(jnp.abs(w).max())
+        else:
+            assert jnp.array_equal(g, w), name
 
 
 def _kda_route_counts():

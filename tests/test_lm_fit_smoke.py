@@ -86,6 +86,8 @@ def _kimi_linear(run):
     assert run.telemetry["attention/route_blockwise"] >= 1 and run.telemetry.get("attention/route_fused", 0) == 0
     assert run.telemetry["kda/route_plain"] >= 1
     assert run.telemetry["kda/mixer_plain"] == run.telemetry["kda/route_plain"]
+    # The plain route has no core whose results a half could keep; the counter is copied all the same.
+    assert run.telemetry["remat/cores_kept"] == 0
 
 
 def _olmo_hybrid(run):

@@ -14,6 +14,14 @@ product once where the bare ``nn.remat`` makes it twice, while the
 attention projections, the scan and the grouped expert products are
 still made twice; (c) ``remat/products_kept`` and ``remat/bytes_kept``
 read what the shapes say, and 0 where nothing is recomputed.
+
+Two kinds of thing a half keeps that are no product have sections of their
+own below: the routing plan of an expert layer that holds a range of its
+router's experts (ISSUE 45), and what a core's forward kernel writes
+(ISSUE 47: the chunk-wise delta rule's in a KDA mixer half and the fused
+attention's in a plain, a latent and a differential attention half, every
+kernel interpreted; the core's forward kernel once in the differentiated
+program).
 """
 
 import collections
@@ -27,6 +35,8 @@ from jax._src import core as jax_core
 
 from distributed_tensorflow_models_tpu.models import get_model
 from distributed_tensorflow_models_tpu.models import remat as rematlib
+from distributed_tensorflow_models_tpu.ops import attention as attnlib
+from distributed_tensorflow_models_tpu.ops import linear_attention as linattn
 from distributed_tensorflow_models_tpu.telemetry import registry as reglib
 
 B, T, D = 2, 32, 64
@@ -246,3 +256,174 @@ def test_a_kept_plan_counts_as_a_plan_and_by_its_bytes_not_as_a_product():
     before = plans.value
     assert _counted(lambda: jax.eval_shape(jax.grad(_loss_of("shared_expert", True)[1]), _params("shared_expert")))[0] == 4
     assert plans.value == before
+
+
+# --- what a core's forward kernel writes (ISSUE 47) -----------------------
+
+# One layer of each kind of core whose results a half keeps, at whole lane
+# blocks and with every kernel interpreted, as the chip runs them: the KDA
+# mixer on its fused route (two heads of 128, 1,024 tokens: two grid steps
+# of eight chunks), and the fused attention of a plain layer (four heads of
+# 64 over two key/value heads: a head pair a lane block, the keys and
+# values repeated outside the kernels), of a latent layer (two heads of 128
+# + 64 query/key channels, padded to 256, over 128 value channels) and of a
+# differential layer under a window (two pairs of heads of 64 over one
+# key/value pair, values 128 wide), 256 tokens each.
+KDA_T, KDA_H, KDA_D = 1024, 2, 128
+ATTN_T = 256
+_ATTN = {**BASE, "num_layers": 1, "max_len": ATTN_T, "attn_impl": "auto", "head_dim": 64}
+_KDA_KERNELS, _ATTN_KERNELS = ("_kda_fwd_kernel", "_kda_bwd_kernel"), ("_fused_fwd_kernel", "_fused_bwd_kernel")
+# kind -> (model kwargs, tokens, the core's (forward, backward) kernels,
+# bytes of the results in float32, the mixer's leaf).
+CORES = {
+    # The output [1, T, H D], the two block states [1, 2, H, D, D] and the
+    # sixteen chunks' T [1, 16, H, 64, 64].
+    "kda": (
+        {**BASE, "num_layers": 1, "layer_mixers": ("kda",), "kda_num_heads": KDA_H, "kda_head_dim": KDA_D,
+         "max_len": KDA_T},
+        KDA_T, _KDA_KERNELS, 4 * (KDA_T * KDA_H * KDA_D + 2 * KDA_H * KDA_D * KDA_D + 16 * KDA_H * 64 * 64),
+        "linear_attn",
+    ),
+    # The output [1, T, 4, 64] and a log-sum-exp a head and token.
+    "attention": (_ATTN, ATTN_T, _ATTN_KERNELS, 4 * (ATTN_T * 4 * 64 + 4 * ATTN_T), "attn"),
+    "latent_attention": (
+        {**_ATTN, "layer_mixers": ("mla",), "num_heads": 2, "num_kv_heads": 0, "head_dim": 0,
+         "mla_kv_lora_rank": 32, "mla_nope_dim": 128, "mla_rope_dim": 64, "mla_v_dim": 128},
+        ATTN_T, _ATTN_KERNELS, 4 * (ATTN_T * 2 * 128 + 2 * ATTN_T), "attn",
+    ),
+    # The core's heads are the four query heads, each over its pair's 128 value channels.
+    "differential_window": (
+        {**_ATTN, "attn_differential": True, "attn_window": 128},
+        ATTN_T, _ATTN_KERNELS, 4 * (ATTN_T * 4 * 128 + 4 * ATTN_T), "attn",
+    ),
+}
+cores = pytest.mark.parametrize("kind", sorted(CORES))
+
+
+@pytest.fixture
+def cores_on_the_kernel_routes(monkeypatch):
+    """What the chip runs, interpreted: the KDA mixer's fused route and the
+    fused attention for every ``attention(impl="auto")``."""
+    monkeypatch.setattr(linattn, "kda_mixer_route", lambda *a, **k: "fused")
+    for name in ("kda_prologue", "kda_epilogue", "chunked_kda_flat"):
+        monkeypatch.setattr(linattn, name, functools.partial(getattr(linattn, name), interpret=True))
+    monkeypatch.setattr(attnlib, "auto_route", lambda *a, **k: "fused")
+    fused = attnlib.fused_attention
+    monkeypatch.setattr(
+        attnlib, "fused_attention",
+        lambda q, k, v, causal, scale, **kw: fused(q, k, v, causal, scale, None, None, True, **kw),
+    )
+
+
+def _core_loss(kind, remat, **kwargs):
+    model = get_model("transformer_lm", **{**CORES[kind][0], **kwargs}, remat=remat)
+    n = CORES[kind][1]
+    tokens = jnp.arange(n).reshape(1, n) % 97
+    loss = lambda params: jnp.mean(jnp.square(model.apply(params, tokens)[0]))
+    return model, tokens, loss
+
+
+@functools.lru_cache(maxsize=None)
+def _core_params(kind):
+    model, tokens, _ = _core_loss(kind, False, attn_impl="reference")
+    return jax.jit(model.init)(jax.random.key(0), tokens)
+
+
+def _core_made(loss, params):
+    """``(Pallas kernels by the name of the kernel's function, ``Dense``
+    products by kernel shape)`` in the gradient of ``loss`` as traced."""
+    kernels, products = collections.Counter(), collections.Counter()
+    for eqn in _walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernels[eqn.params["jaxpr"].debug_info.func_name] += 1
+        elif eqn.primitive.name == "dot_general" and eqn.params["dimension_numbers"] == (((2,), (0,)), ((), ())):
+            products[tuple(eqn.invars[1].aval.shape)] += 1
+    return kernels, products
+
+
+@cores
+def test_a_recomputed_half_holds_its_cores_forward_kernel_once(kind, cores_on_the_kernel_routes, monkeypatch):
+    params, (forward, backward) = _core_params(kind), CORES[kind][2]
+    plain, plain_products = _core_made(_core_loss(kind, False)[2], params)
+    assert (plain[forward], plain[backward]) == (1, 1)
+    made, products = _core_made(_core_loss(kind, True)[2], params)
+    assert (made[forward], made[backward]) == (1, 1)
+    if kind == "kda":
+        # The fused passes around the core are not kept and run again.
+        assert plain["_conv_fwd_kernel"] == 3
+        assert (made["_conv_fwd_kernel"], made["_decay_fwd_kernel"], made["_gate_fwd_kernel"]) == (6, 2, 2)
+    else:
+        # So are the projections that make the core's inputs: the half keeps the results alone.
+        query = (D, CORES[kind][0]["num_heads"] * (CORES[kind][0]["head_dim"] or 192))
+        assert (plain_products[query], products[query]) == (1, 2)
+    monkeypatch.setattr(rematlib, "half", nn.remat)  # the parent's halves: the core's forward kernel twice
+    bare, _ = _core_made(_core_loss(kind, True)[2], params)
+    assert (bare[forward], bare[backward]) == (2, 1)
+
+
+@cores
+def test_a_kept_core_changes_no_value(kind, cores_on_the_kernel_routes, monkeypatch):
+    """The loss and every gradient with the core's results kept are those
+    with the core run again (the bare ``nn.remat``) bit for bit, and those
+    of the model that recomputes nothing to float32 rounding, as for the
+    products above (XLA:CPU fuses a recomputed half otherwise: 2e-7 of the
+    largest entry here, with the bare ``nn.remat`` too)."""
+    params = _core_params(kind)
+    value_and_grad = lambda remat: jax.jit(jax.value_and_grad(_core_loss(kind, remat)[2]))(params)
+    (loss, grads), (plain_loss, plain_grads) = value_and_grad(True), value_and_grad(False)
+    monkeypatch.setattr(rematlib, "half", nn.remat)
+    bare_loss, bare_grads = value_and_grad(True)
+    assert loss == bare_loss == plain_loss
+    room = 1e-5 * max(float(jnp.max(jnp.abs(want))) for want in jax.tree.leaves(plain_grads))
+    for (path, got), want, plain in zip(
+        jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(bare_grads), jax.tree.leaves(plain_grads)
+    ):
+        assert jnp.array_equal(got, want), jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(got - plain))) <= room, jax.tree_util.keystr(path)
+    for leaf in jax.tree.leaves(grads["params"]["blocks_0"][CORES[kind][4]]):
+        assert float(jnp.max(jnp.abs(leaf))) > 0
+
+
+@cores
+def test_a_kept_core_counts_as_a_core_and_by_its_bytes(kind, cores_on_the_kernel_routes):
+    counter = reglib.get_registry().counter(reglib.REMAT_CORES_KEPT)
+    params, core_bytes = _core_params(kind), CORES[kind][3]
+    trace = lambda remat, **kw: lambda: jax.eval_shape(jax.grad(_core_loss(kind, remat, **kw)[2]), params)
+    before = counter.value
+    assert _counted(trace(False)) == (0, 0) and counter.value == before
+    # The feed-forward's ``gate`` and ``up`` are the products; the core is no product.
+    products = 2 * CORES[kind][1] * 96 * 4
+    assert _counted(trace(True)) == (2, products + core_bytes)
+    assert counter.value == before + 1
+    model, tokens, _ = _core_loss(kind, True)
+    assert _counted(lambda: jax.eval_shape(model.init, jax.random.key(0), tokens)) == (2, products + core_bytes)
+    assert counter.value == before + 2
+    if kind != "kda":
+        # The blockwise route has no rule whose residual could be named: the products alone.
+        assert _counted(trace(True, attn_impl="blockwise")) == (2, products)
+        assert counter.value == before + 2
+
+
+def _grad_jaxpr(core, *x):
+    return jax.make_jaxpr(jax.grad(lambda *x: jnp.sum(core(*x)), argnums=tuple(range(len(x)))))(*x)
+
+
+@pytest.mark.parametrize("kind", ["kda", "attention"])
+def test_kept_core_names_nothing_outside_a_recomputed_half(kind, cores_on_the_kernel_routes):
+    counter = reglib.get_registry().counter(reglib.REMAT_CORES_KEPT)
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    if kind == "kda":
+        flat, beta = spec(1, 128, 2 * 128), spec(1, 128, 2)
+        results, x = linattn.kernel_kda_results(flat, flat, beta), (flat, flat, flat, flat, beta)
+        core = lambda **kw: lambda *x: linattn.chunked_kda_flat(*x, **kw)
+    else:
+        heads = spec(1, 128, 2, 64)
+        results, x = attnlib.fused_attention_results(heads, heads), (heads, heads, heads)
+        core = lambda **kw: lambda *x: attnlib.attention(*x, causal=True, **kw)
+    before = counter.value
+    assert _counted(lambda: rematlib.kept_core(results)) == (0, 0)
+    assert rematlib.kept_core(results) is None and counter.value == before
+    # The core as the model calls it and as any other caller does: one program, nothing named.
+    kept, bare = _grad_jaxpr(core(keep=rematlib.kept_core), *x), _grad_jaxpr(core(), *x)
+    assert not any(eqn.primitive.name == "name" for eqn in _walk(kept.jaxpr))
+    assert str(kept) == str(bare)
